@@ -14,9 +14,8 @@ import jax as _jax
 
 # Prefix-stable jax.random.split is a documented invariant of the decode
 # paths (models/generate.py: streaming == non-streaming sample streams;
-# chunked decode slicing a pre-split key array). Newer JAX defaults to
-# the partitionable threefry that guarantees it; pin it explicitly so
-# older JAX (where the default was off) honors the same contract.
+# chunked decode slicing a pre-split key array). The partitionable
+# threefry guarantees it; pin it explicitly.
 _jax.config.update("jax_threefry_partitionable", True)
 
 from oryx_tpu.config import (  # noqa: F401

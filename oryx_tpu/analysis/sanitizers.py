@@ -113,6 +113,9 @@ class _CompileLogHandler(logging.Handler):
                     if isinstance(record.args, tuple)
                     else record.args
                 )
+            # jax names the computation "jit(<fn>)"; count under <fn>.
+            if fn.startswith("jit(") and fn.endswith(")"):
+                fn = fn[4:-1]
             self._callback(fn)
         # fault-boundary: a broken sanitizer must never break the run
         except Exception:

@@ -391,6 +391,9 @@ def main(argv: list[str] | None = None) -> None:
         help="weight-only int8 for single-chip serving",
     )
     args = ap.parse_args(argv)
+    from oryx_tpu.utils.compile_cache import configure_compile_cache
+
+    configure_compile_cache()
     if args.quantize and args.shard:
         ap.error("--quantize is single-chip serving; drop --shard")
 

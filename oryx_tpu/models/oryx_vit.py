@@ -139,8 +139,8 @@ def forward(
     # doesn't guess intermediates. "sp" rides along: to the vision tower
     # the patch axis is pure data, so sequence-parallel devices take
     # patch shards too — at the 256-frame long-video scale the 27-layer
-    # residual stacks over 16k patches/chip are the memory (TPU_VALIDATION
-    # round 5); an sp-less mesh drops the axis (constrain).
+    # residual stacks over 16k patches/chip are the memory; an sp-less
+    # mesh drops the axis (constrain).
     pk_spec = (None, ("dp", "fsdp", "sp"), None)
     h = constrain(emb[None], *pk_spec)  # [1, P, H]
     seg = segment_ids[None]  # [1, P]

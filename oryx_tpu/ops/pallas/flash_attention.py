@@ -41,15 +41,14 @@ NEG = -0.7 * float(jnp.finfo(jnp.float32).max)
 # Tile sizes. 512×512 keeps the fp32 logits tile at 1 MB of VMEM while
 # amortizing DMA and per-tile softmax state updates; q/k/v/acc tiles add
 # ~0.8 MB — comfortably inside the ~16 MB VMEM budget with double
-# buffering. Validated on-chip (v5e, see TPU_VALIDATION.md): 512x512 beat
-# the 256/1024 variants on the bench shapes. Env-overridable for sweeps.
+# buffering. Tile choice not measured on the current chip.
+# Env-overridable for sweeps.
 BLOCK_Q = int(os.environ.get("ORYX_FLASH_BLOCK_Q", "512"))
 BLOCK_K = int(os.environ.get("ORYX_FLASH_BLOCK_K", "512"))
 # Backward kernels take independent tile sizes: the dq/dkv kernels
 # stream three extra operands (do, lse, Δ) per tile and accumulate into
-# VMEM scratch, so their DMA/compute balance differs. On-chip (v5e,
-# TPU_VALIDATION.md) 1024×1024 backward tiles beat the 512×512 forward
-# tiling by ~2-3% of attention fwd+bwd at both T=2048 and T=4096;
+# VMEM scratch, so their DMA/compute balance differs. The 1024×1024
+# backward default is not measured on the current chip;
 # shorter/indivisible sequences fall back to the forward tiling
 # (_bwd_block). Env: unset → the 1024 default; 0 → None = inherit the
 # forward value AT CALL TIME; any other value → itself.

@@ -1,10 +1,11 @@
 """Pallas TPU paged attention (ragged KV through block tables).
 
-Two kernels share one skeleton here:
+One kernel, two entry points:
 
-  * `ragged_decode_attention` — the original single-token decode twin
-    of `ops.paged_kv.ragged_decode_attention`: [B, 1] queries, one
-    sequence per batch row.
+  * `ragged_decode_attention` — the single-token decode twin of
+    `ops.paged_kv.ragged_decode_attention`: [B, 1] queries, one
+    sequence per batch row. A decode row is a length-1 ragged lane,
+    so it runs the packed kernel with segment b, position len-1.
   * `ragged_paged_attention` — the PACKED ragged kernel (arXiv
     2604.15464): R query rows drawn from many sequences with MIXED
     query lengths (decode steps and chunked-prefill suffix tokens side
@@ -12,9 +13,9 @@ Two kernels share one skeleton here:
     scalar-prefetched (segment, position) metadata and causally masked
     at its own position. This is the kernel behind the serving
     engine's one-dispatch-per-step path
-    (models/generate.paged_ragged_step); its grid/tile parameters come
-    from a (head_dim, page_size)-keyed grid table that is autotuned
-    once per shape class and cached (`ragged_grid_config`).
+    (models/generate.paged_ragged_step); its kv-head tile comes from
+    `ragged_heads_per_block`, which offers only sizes the TPU lowering
+    accepts.
 
     Speculative decoding rides the SAME kernel unchanged: a slot's 1+k
     verify lanes (ops/paged_kv.spec_lane_metadata) are just 1+k more
@@ -25,7 +26,7 @@ Two kernels share one skeleton here:
     per-row page walk, dead-tile DMA elision and tail masking are
     position-driven and need no notion of "draft".
 
-The TPU win in both: attention over a sequence's pages happens IN
+The point of both: attention over a sequence's pages happens IN
 PLACE — the block table is a scalar-prefetch operand, so each kv
 tile's DMA source address is computed from it before the tile runs,
 and no [B, max_len] contiguous copy of the cache is ever materialized
@@ -33,186 +34,32 @@ and no [B, max_len] contiguous copy of the cache is ever materialized
 shapes that gather IS the decode bandwidth bill).
 
 Shares the flash-attention kernel skeleton (ops/pallas/
-flash_attention.py): grid (B, Hk, num_pages_per_seq) with the page
-dimension innermost and sequential, online-softmax (m, l, acc) state in
-VMEM scratch, fp32 logits/softmax, probs·V in the value dtype. The GQA
-group dimension rides INSIDE the tile (q is reshaped [B, Hk, G, D]), so
-every grid step issues one [G, page_size] logit matmul per kv head —
-the decode-shaped analogue of the prefill kernel's [block_q, block_k]
-tiles.
+flash_attention.py): pages innermost and sequential, online-softmax
+(m, l, acc) state in VMEM scratch, fp32 logits/softmax, probs·V in the
+value dtype. The GQA group dimension rides INSIDE the tile (q is
+reshaped [R, Hk, G, D]), so every grid step issues one [G, page_size]
+logit matmul per kv head — the decode-shaped analogue of the prefill
+kernel's [block_q, block_k] tiles. Raggedness is handled per packed
+row (see the comment above `_ragged_kernel`).
 
-Ragged handling, per row b with `kv_lengths[b] = n`:
-  * tiles wholly past n skip their compute (`pl.when`) AND their DMA —
-    the index map clamps dead page ids to the last live page, and
-    Pallas elides a DMA whose source block repeats the previous step's.
-  * the tail tile masks slots >= n to -inf before the softmax.
-  * sentinel block-table entries (unallocated tails) clip into the pool
-    for address safety; they are only reachable masked.
-
-Interpret mode runs the same kernel on CPU for tests.
+Interpret mode runs the same kernel on CPU for tests; on a TPU the
+Mosaic kernel runs (tests/test_pallas_topology_compile.py compiles it
+at Oryx-7B geometry for a described v5e).
 """
 
 from __future__ import annotations
 
 import functools
+import os
 
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from oryx_tpu.ops.pallas import flash_attention as _flash
+
 NEG = -0.7 * float(jnp.finfo(jnp.float32).max)
-
-
-def _decode_kernel(
-    bt_ref,  # [B, maxp] SMEM (scalar prefetch)
-    len_ref,  # [B] SMEM (scalar prefetch)
-    *refs,  # q, k, [k_scale], v, [v_scale], o, scratch x3
-    scale: float,
-    page_size: int,
-    num_groups: int,
-    dequant_dtype: str | None = None,
-):
-    # Quantized pool: each page tile arrives as storage-dtype codes
-    # plus its [1, ps] scale block (fetched through the SAME
-    # block-table-driven index map), and the dequant happens HERE, in
-    # the page walk — int8 is what crossed HBM. The multiply matches
-    # ops.paged_kv.gather_pages' dequant elementwise (same dtype, same
-    # broadcast), preserving the kernels' bit-parity contract.
-    if dequant_dtype is None:
-        q_ref, k_ref, v_ref, o_ref, m_scr, l_scr, acc_scr = refs
-        ks_ref = vs_ref = None
-    else:
-        (q_ref, k_ref, ks_ref, v_ref, vs_ref, o_ref,
-         m_scr, l_scr, acc_scr) = refs
-    b, ik = pl.program_id(0), pl.program_id(2)
-    nk = pl.num_programs(2)
-    G = num_groups
-
-    @pl.when(ik == 0)
-    def _init():
-        m_scr[:] = jnp.full_like(m_scr, NEG)
-        l_scr[:] = jnp.zeros_like(l_scr)
-        acc_scr[:] = jnp.zeros_like(acc_scr)
-
-    length = len_ref[b]
-    run = ik * page_size < length
-
-    @pl.when(run)
-    def _step():
-        q = q_ref[0, 0]  # [G, D]
-        k = k_ref[0, :, 0, :]  # [ps, D]
-        v = v_ref[0, :, 0, :]
-        if ks_ref is not None:
-            dq = jnp.dtype(dequant_dtype)
-            k = k.astype(dq) * ks_ref[0].astype(dq)[:, None]
-            v = v.astype(dq) * vs_ref[0].astype(dq)[:, None]
-        s = jax.lax.dot_general(
-            q, k, (((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32,
-        ) * scale  # [G, ps] fp32
-
-        slot = ik * page_size + jax.lax.broadcasted_iota(
-            jnp.int32, (1, page_size), 1
-        )
-        s = jnp.where(slot < length, s, NEG)
-
-        m_prev = m_scr[:G, :1]
-        m_new = jnp.maximum(m_prev, jnp.max(s, axis=-1, keepdims=True))
-        alpha = jnp.exp(m_prev - m_new)
-        p = jnp.exp(s - m_new)  # [G, ps] fp32
-        l_new = l_scr[:G, :1] * alpha + jnp.sum(p, axis=-1, keepdims=True)
-        m_scr[:G, :] = jnp.broadcast_to(m_new, (G, m_scr.shape[1]))
-        l_scr[:G, :] = jnp.broadcast_to(l_new, (G, l_scr.shape[1]))
-        pv = jax.lax.dot_general(
-            p.astype(v.dtype), v, (((1,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32,
-        )
-        acc_scr[:G, :] = acc_scr[:G, :] * alpha + pv
-
-    @pl.when(ik == nk - 1)
-    def _finalize():
-        l = l_scr[:G, :1]
-        out = acc_scr[:G, :] / jnp.where(l == 0.0, 1.0, l)
-        o_ref[0, 0] = out.astype(o_ref.dtype)
-
-
-@functools.partial(
-    jax.jit,
-    static_argnames=("scale", "page_size", "interpret", "dequant_dtype"),
-)
-def _paged_decode(
-    q,  # [B, Hk, G, D]
-    k_pages,  # [P, ps, Hk, D] (codes when quantized)
-    v_pages,
-    block_tables,  # [B, maxp] int32
-    kv_lengths,  # [B] int32
-    k_scale=None,  # [P, ps] fp32 per-page scale blocks (quantized pool)
-    v_scale=None,
-    *,
-    scale: float,
-    page_size: int,
-    interpret: bool,
-    dequant_dtype: str | None = None,
-):
-    B, Hk, G, D = q.shape
-    P = k_pages.shape[0]
-    maxp = block_tables.shape[1]
-
-    def _page(b, ik, bt_ref, len_ref):
-        # Clamp dead tiles onto the last live page (DMA elision — see
-        # module docstring) and sentinel entries into the pool.
-        last = jnp.maximum(len_ref[b] - 1, 0) // page_size
-        page = bt_ref[b, jnp.minimum(ik, last)]
-        return jnp.minimum(page, P - 1)
-
-    def kv_map(b, hk, ik, bt_ref, len_ref):
-        return (_page(b, ik, bt_ref, len_ref), 0, hk, 0)
-
-    def sc_map(b, hk, ik, bt_ref, len_ref):
-        # The page's scale block rides the same block-table-driven
-        # stream as its code tile (one address computation, two DMAs).
-        return (_page(b, ik, bt_ref, len_ref), 0)
-
-    grid = (B, Hk, maxp)
-    Gp = max(G, 8)  # scratch sublane floor
-    quant = dequant_dtype is not None
-    in_specs = [
-        pl.BlockSpec((1, 1, G, D), lambda b, hk, ik, *_: (b, hk, 0, 0)),
-        pl.BlockSpec((1, page_size, 1, D), kv_map),
-    ]
-    operands = [q, k_pages]
-    if quant:
-        in_specs.append(pl.BlockSpec((1, page_size), sc_map))
-        operands.append(k_scale)
-    in_specs.append(pl.BlockSpec((1, page_size, 1, D), kv_map))
-    operands.append(v_pages)
-    if quant:
-        in_specs.append(pl.BlockSpec((1, page_size), sc_map))
-        operands.append(v_scale)
-    out = pl.pallas_call(
-        functools.partial(
-            _decode_kernel, scale=scale, page_size=page_size,
-            num_groups=G, dequant_dtype=dequant_dtype,
-        ),
-        grid_spec=pltpu.PrefetchScalarGridSpec(
-            num_scalar_prefetch=2,
-            grid=grid,
-            in_specs=in_specs,
-            out_specs=pl.BlockSpec(
-                (1, 1, G, D), lambda b, hk, ik, *_: (b, hk, 0, 0)
-            ),
-            scratch_shapes=[
-                pltpu.VMEM((Gp, 128), jnp.float32),
-                pltpu.VMEM((Gp, 128), jnp.float32),
-                pltpu.VMEM((Gp, D), jnp.float32),
-            ],
-        ),
-        out_shape=jax.ShapeDtypeStruct((B, Hk, G, D), q.dtype),
-        interpret=interpret,
-    )(block_tables.astype(jnp.int32), kv_lengths.astype(jnp.int32),
-      *operands)
-    return out
 
 
 def ragged_decode_attention(
@@ -225,37 +72,22 @@ def ragged_decode_attention(
     scale: float | None = None,
     interpret: bool | None = None,
 ):
-    """Drop-in for ops.paged_kv.ragged_decode_attention (same contract);
-    pages are read in place through the block table. A quantized pool
-    (ops.paged_kv.QuantPages planes) is read as codes + per-page scale
-    blocks and dequantized inside the page walk."""
+    """Drop-in for ops.paged_kv.ragged_decode_attention (same contract).
+    A decode row is a length-1 ragged lane: row b is packed row b of
+    segment b at position kv_lengths[b] - 1, so this runs the packed
+    kernel below (a row with kv_lengths == 0 sees no tile and returns
+    zeros)."""
     squeezed = q.ndim == 3
     if squeezed:
         q = q[:, None]
     B, Tq, Hq, D = q.shape
-    assert Tq == 1, f"paged decode kernel is single-token (got Tq={Tq})"
-    Hk = k_pages.shape[2]
-    assert Hq % Hk == 0, f"GQA requires Hq % Hk == 0, got {Hq=} {Hk=}"
-    G = Hq // Hk
-    if scale is None:
-        scale = D**-0.5
-    if interpret is None:
-        interpret = jax.default_backend() != "tpu"
-    k_scale = v_scale = None
-    dequant = None
-    if _is_quant(k_pages):
-        k_pages, k_scale, v_pages, v_scale, dequant = _split_quant(
-            k_pages, v_pages
-        )
-    # h = hk * G + g (the repo's GQA head order: h // G == hk).
-    qg = q[:, 0].reshape(B, Hk, G, D)
-    out = _paged_decode(
-        qg, k_pages, v_pages, block_tables, kv_lengths,
-        k_scale, v_scale,
-        scale=float(scale), page_size=int(k_pages.shape[1]),
-        interpret=bool(interpret), dequant_dtype=dequant,
+    assert Tq == 1, f"paged decode is single-token (got Tq={Tq})"
+    out = ragged_paged_attention(
+        q[:, 0], k_pages, v_pages, block_tables,
+        jnp.arange(B, dtype=jnp.int32),
+        kv_lengths.astype(jnp.int32) - 1,
+        scale=scale, interpret=interpret,
     )
-    out = out.reshape(B, Hq, D)
     return out if squeezed else out[:, None]
 
 
@@ -301,136 +133,73 @@ def _split_quant(k_pages, v_pages):
 #   * sentinel block-table entries clip into the pool for address
 #     safety (only reachable masked).
 
-# The grid table: (head_dim, page_size) -> tile parameters. HB
-# (kv heads per tile) trades DMA count against VMEM residency:
-# doubling HB halves page-walk DMAs but doubles the kv tile and the
-# scratch footprint, so the sweet spot moves with head_dim x page_size
-# bytes. Seeded with VMEM-budget defaults; `autotune_ragged_grid`
-# measures the candidates once on real TPU and the winner is cached
-# per shape class for the life of the process (the serving engine
-# compiles one program per shape class, so the choice must be stable
-# — autotune ONCE, never per call).
-_RAGGED_GRID_CACHE: dict[tuple[int, int], dict] = {}
+# heads_per_block (HB, kv heads per page tile) trades DMA count against
+# VMEM residency. The TPU lowering only takes a kv tile
+# [1, ps, HB, D] over the [P, ps, Hk, D] pool when HB is the whole kv
+# head axis or a multiple of 8 that divides it, so those are the only
+# tile sizes ever offered; anything else is an error naming the value,
+# on every backend, so an interpret-mode test cannot pass on a tile the
+# chip's compiler would refuse.
 
 # Keep the double-buffered kv tile (2 * ps * HB * D * 4B fp32) within a
 # conservative slice of VMEM alongside q/out/scratch.
 _RAGGED_KV_TILE_BUDGET = 1 << 21  # 2 MiB
 
 
-def _default_heads_per_block(head_dim: int, page_size: int) -> int:
-    """VMEM-budget default, honoring the $ORYX_RPA_HEADS_PER_BLOCK
-    operator pin (every cache-seeding path must route through this, or
-    a pinned tile size would be silently discarded for the life of the
-    process)."""
-    import os
+def legal_heads_per_block(num_kv_heads: int) -> tuple[int, ...]:
+    """The kv-head tile sizes the TPU lowering accepts, ascending."""
+    Hk = int(num_kv_heads)
+    return tuple(
+        hb for hb in range(1, Hk + 1)
+        if Hk % hb == 0 and (hb == Hk or hb % 8 == 0)
+    )
 
-    env = os.environ.get("ORYX_RPA_HEADS_PER_BLOCK")
-    if env:
-        return max(1, int(env))
-    hb = 1
-    while (
-        hb < 8
-        and 2 * page_size * (hb * 2) * head_dim * 4
-        <= _RAGGED_KV_TILE_BUDGET
-    ):
-        hb *= 2
+
+def check_heads_per_block(
+    heads_per_block: int, num_kv_heads: int, origin: str = "heads_per_block"
+) -> int:
+    hb = int(heads_per_block)
+    legal = legal_heads_per_block(num_kv_heads)
+    if hb not in legal:
+        raise ValueError(
+            f"{origin}={hb} cannot compile for num_kv_heads="
+            f"{int(num_kv_heads)}: the TPU lowering takes only the whole "
+            f"kv head axis or a multiple of 8 dividing it (legal: "
+            f"{list(legal)})"
+        )
     return hb
 
 
-def ragged_grid_config(
+def ragged_heads_per_block(
     head_dim: int, page_size: int, num_kv_heads: int
-) -> dict:
-    """Tile parameters for the ragged kernel, keyed by shape class.
-
-    Resolution order: process-lifetime cache (autotuned or first-use
-    default) -> $ORYX_RPA_HEADS_PER_BLOCK override -> VMEM-budget
-    default. The returned heads_per_block always divides num_kv_heads
-    (clamped by gcd at use, so a cached choice from one model geometry
-    stays safe for another)."""
-    import math
-
-    key = (int(head_dim), int(page_size))
-    cfg = _RAGGED_GRID_CACHE.get(key)
-    if cfg is None:
-        cfg = {
-            "heads_per_block": _default_heads_per_block(
-                head_dim, page_size
-            ),
-            "autotuned": False,
-        }
-        _RAGGED_GRID_CACHE[key] = cfg
-    hb = math.gcd(cfg["heads_per_block"], int(num_kv_heads))
-    return {**cfg, "heads_per_block": max(1, hb)}
+) -> int:
+    """kv heads per tile for the ragged kernel: the
+    $ORYX_RPA_HEADS_PER_BLOCK operator pin if set (an illegal pin is an
+    error, never clamped), else the largest legal tile inside the VMEM
+    budget (the smallest legal one when none fits)."""
+    env = os.environ.get("ORYX_RPA_HEADS_PER_BLOCK")
+    if env:
+        return check_heads_per_block(
+            int(env), num_kv_heads, "$ORYX_RPA_HEADS_PER_BLOCK"
+        )
+    legal = legal_heads_per_block(num_kv_heads)
+    fits = [
+        hb for hb in legal
+        if 2 * page_size * hb * head_dim * 4 <= _RAGGED_KV_TILE_BUDGET
+    ]
+    return fits[-1] if fits else legal[0]
 
 
-def autotune_ragged_grid(
-    head_dim: int, page_size: int, num_kv_heads: int,
-    *, candidates=(1, 2, 4, 8), trials: int = 3,
-) -> dict:
-    """Time the heads_per_block candidates once on the real backend and
-    cache the winner for this (head_dim, page_size) shape class. On a
-    non-TPU backend (or if timing fails) the VMEM-budget default is
-    cached instead — the point is a STABLE choice per shape class, not
-    a per-call search."""
-    import math
-    import time as _time
-
-    key = (int(head_dim), int(page_size))
-    cached = _RAGGED_GRID_CACHE.get(key)
-    if cached is not None and cached.get("autotuned"):
-        return ragged_grid_config(head_dim, page_size, num_kv_heads)
-    if jax.default_backend() != "tpu":
-        _RAGGED_GRID_CACHE[key] = {
-            "heads_per_block": _default_heads_per_block(
-                head_dim, page_size
-            ),
-            "autotuned": False,
-        }
-        return ragged_grid_config(head_dim, page_size, num_kv_heads)
-    # Tiny synthetic problem in the target shape class.
-    R, S, maxp, P = 16, 8, 8, 64
-    Hk = int(num_kv_heads)
-    # Independent subkeys: drawing q and the KV pages from one key
-    # correlates the synthetic operands (identical leading random
-    # stream), skewing the softmax mass the candidate grids are timed
-    # against (found by oryxlint key-linearity self-application,
-    # oryx_tpu/ops/pallas/paged_attention.py:395).
-    kq, kk = jax.random.split(jax.random.key(0))
-    q = jax.random.normal(kq, (R, Hk * 2, head_dim), jnp.float32)
-    kp = jax.random.normal(kk, (P, page_size, Hk, head_dim), jnp.float32)
-    bt = jnp.tile(jnp.arange(maxp, dtype=jnp.int32)[None], (S, 1))
-    seg = jnp.arange(R, dtype=jnp.int32) % S
-    pos = jnp.full((R,), maxp * page_size - 1, jnp.int32)
-    best, best_dt, skipped = None, None, []
-    for hb in candidates:
-        if math.gcd(hb, Hk) != hb:
-            continue
-        try:
-            fn = lambda: ragged_paged_attention(  # noqa: E731
-                q, kp, kp, bt, seg, pos, heads_per_block=hb,
-                interpret=False,
-            ).block_until_ready()
-            fn()  # compile
-            t0 = _time.perf_counter()
-            for _ in range(trials):
-                fn()
-            dt = _time.perf_counter() - t0
-        except Exception as e:
-            # An untunable candidate (VMEM overflow, lowering limit)
-            # is a skipped data point, not a fatal error — but it is
-            # recorded so the cached choice is explainable.
-            skipped.append((hb, f"{type(e).__name__}: {e}"))
-            continue
-        if best_dt is None or dt < best_dt:
-            best, best_dt = hb, dt
-    _RAGGED_GRID_CACHE[key] = {
-        "heads_per_block": best or _default_heads_per_block(
-            head_dim, page_size
-        ),
-        "autotuned": best is not None,
-        "skipped": skipped,
-    }
-    return ragged_grid_config(head_dim, page_size, num_kv_heads)
+def _scale_column(row):
+    """[1, ps] scale row -> [ps, 1] column. The scale tile arrives
+    lane-major (one contiguous DMA per page); Mosaic has no
+    lane-to-sublane reshape, so select the diagonal of the broadcast
+    row and reduce over lanes — exact: each sum has one nonzero term."""
+    n = row.shape[1]
+    diag = jax.lax.broadcasted_iota(
+        jnp.int32, (n, n), 0
+    ) == jax.lax.broadcasted_iota(jnp.int32, (n, n), 1)
+    return jnp.sum(jnp.where(diag, row, 0.0), axis=1, keepdims=True)
 
 
 def _ragged_kernel(
@@ -444,10 +213,12 @@ def _ragged_kernel(
     heads_per_block: int,
     dequant_dtype: str | None = None,
 ):
-    # Quantized pool: code tiles + their [1, ps] per-page scale blocks
-    # arrive through the same scalar-prefetched block-table stream and
-    # dequantize HERE (see _decode_kernel) — the page walk reads int8
-    # off HBM and multiplies out to the logical dtype per tile.
+    # Quantized pool: each page tile arrives as storage-dtype codes
+    # plus its [1, 1, ps] scale block (fetched through the SAME
+    # block-table-driven index map), and the dequant happens HERE, in
+    # the page walk — int8 is what crossed HBM. The multiply matches
+    # ops.paged_kv.gather_pages' dequant elementwise (same dtype, same
+    # broadcast), preserving the kernels' bit-parity contract.
     if dequant_dtype is None:
         q_ref, k_ref, v_ref, o_ref, m_scr, l_scr, acc_scr = refs
         ks_ref = vs_ref = None
@@ -473,14 +244,17 @@ def _ragged_kernel(
         slot = ik * page_size + jax.lax.broadcasted_iota(
             jnp.int32, (1, page_size), 1
         )
+        if ks_ref is not None:
+            dq = jnp.dtype(dequant_dtype)
+            k_sc = _scale_column(ks_ref[0]).astype(dq)  # [ps, 1]
+            v_sc = _scale_column(vs_ref[0]).astype(dq)
         for h in range(HB):  # static unroll over the kv-head tile
             q = q_ref[0, h]  # [G, D]
             k = k_ref[0, :, h, :]  # [ps, D]
             v = v_ref[0, :, h, :]
             if ks_ref is not None:
-                dq = jnp.dtype(dequant_dtype)
-                k = k.astype(dq) * ks_ref[0].astype(dq)[:, None]
-                v = v.astype(dq) * vs_ref[0].astype(dq)[:, None]
+                k = k.astype(dq) * k_sc
+                v = v.astype(dq) * v_sc
             s = jax.lax.dot_general(
                 q, k, (((1,), (1,)), ((), ())),
                 preferred_element_type=jnp.float32,
@@ -532,7 +306,7 @@ def _ragged_paged(
     block_tables,  # [S, maxp] int32
     q_segments,  # [R] int32
     q_positions,  # [R] int32
-    k_scale=None,  # [P, ps] fp32 per-page scale blocks (quantized pool)
+    k_scale=None,  # [P, 1, ps] fp32 per-page scale blocks (quantized pool)
     v_scale=None,
     *,
     scale: float,
@@ -561,7 +335,7 @@ def _ragged_paged(
     def sc_map(r, hb, ik, bt_ref, seg_ref, pos_ref):
         # The scale block rides the same block-table stream as its
         # code tile.
-        return (_page(r, ik, bt_ref, seg_ref, pos_ref), 0)
+        return (_page(r, ik, bt_ref, seg_ref, pos_ref), 0, 0)
 
     grid = (R, Hk // HB, maxp)
     Gp = max(G, 8)  # scratch sublane floor
@@ -572,12 +346,12 @@ def _ragged_paged(
     ]
     operands = [q, k_pages]
     if quant:
-        in_specs.append(pl.BlockSpec((1, page_size), sc_map))
+        in_specs.append(pl.BlockSpec((1, 1, page_size), sc_map))
         operands.append(k_scale)
     in_specs.append(pl.BlockSpec((1, page_size, HB, D), kv_map))
     operands.append(v_pages)
     if quant:
-        in_specs.append(pl.BlockSpec((1, page_size), sc_map))
+        in_specs.append(pl.BlockSpec((1, 1, page_size), sc_map))
         operands.append(v_scale)
     out = pl.pallas_call(
         functools.partial(
@@ -619,10 +393,11 @@ def ragged_paged_attention(
 ):
     """Drop-in for ops.paged_kv.ragged_paged_attention (same contract):
     R packed query rows with mixed query lengths, each reading its own
-    sequence's pages in place through the block table. Tile parameters
-    come from the (head_dim, page_size) grid table unless pinned. A
-    quantized pool (ops.paged_kv.QuantPages planes) is read as codes +
-    per-page scale blocks and dequantized inside the page walk."""
+    sequence's pages in place through the block table. The kv-head
+    tile is `ragged_heads_per_block`'s unless pinned; a pin the TPU
+    lowering would refuse raises. A quantized pool
+    (ops.paged_kv.QuantPages planes) is read as codes + per-page scale
+    blocks and dequantized inside the page walk."""
     R, Hq, D = q.shape
     Hk = k_pages.shape[2]
     assert Hq % Hk == 0, f"GQA requires Hq % Hk == 0, got {Hq=} {Hk=}"
@@ -630,20 +405,25 @@ def ragged_paged_attention(
     if scale is None:
         scale = D**-0.5
     if interpret is None:
-        interpret = jax.default_backend() != "tpu"
+        interpret = _flash._use_interpret()
     if heads_per_block is None:
-        heads_per_block = ragged_grid_config(
+        heads_per_block = ragged_heads_per_block(
             D, int(k_pages.shape[1]), Hk
-        )["heads_per_block"]
-    import math
-
-    heads_per_block = max(1, math.gcd(int(heads_per_block), Hk))
+        )
+    else:
+        heads_per_block = check_heads_per_block(heads_per_block, Hk)
     k_scale = v_scale = None
     dequant = None
     if _is_quant(k_pages):
         k_pages, k_scale, v_pages, v_scale, dequant = _split_quant(
             k_pages, v_pages
         )
+        # The stored scale plane stays [P, ps]; the lowering needs a
+        # block whose last two dims are whole, so present it [P, 1, ps]
+        # (a free reshape: same bytes, same order).
+        P, ps = k_scale.shape
+        k_scale = k_scale.reshape(P, 1, ps)
+        v_scale = v_scale.reshape(P, 1, ps)
     # h = hk * G + g (the repo's GQA head order: h // G == hk).
     qg = q.reshape(R, Hk, G, D)
     out = _ragged_paged(

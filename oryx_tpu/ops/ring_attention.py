@@ -23,10 +23,7 @@ import jax
 import jax.numpy as jnp
 from jax.ad_checkpoint import checkpoint_name
 from jax.sharding import Mesh, PartitionSpec as P
-try:
-    from jax import shard_map
-except ImportError:  # jax < 0.5 keeps shard_map under experimental
-    from jax.experimental.shard_map import shard_map
+from jax import shard_map
 
 NEG = -0.7 * float(jnp.finfo(jnp.float32).max)
 
@@ -344,15 +341,7 @@ def ring_attention(
     batch = tuple(a for a in batch_axes if a in names) or None
     seq = P(batch, axis_name, None, None)
     tok = P(batch, axis_name)
-    import inspect
-
-    # Replication checking is off (the accumulator update is manual);
-    # the flag was renamed check_rep -> check_vma across JAX versions.
-    check_kw = (
-        "check_vma"
-        if "check_vma" in inspect.signature(shard_map).parameters
-        else "check_rep"
-    )
+    # Replication checking is off: the accumulator update is manual.
     fn = shard_map(
         partial(
             ring_attention_shard, axis_name=axis_name, causal=causal,
@@ -361,6 +350,6 @@ def ring_attention(
         mesh=resolved,
         in_specs=(seq, seq, seq, tok, tok, tok),
         out_specs=seq,
-        **{check_kw: False},
+        check_vma=False,
     )
     return fn(q, k, v, positions, positions, kv_valid)

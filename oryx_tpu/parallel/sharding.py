@@ -80,8 +80,8 @@ def mesh_rules(mode: str) -> dict[str, str | tuple[str, ...] | None]:
         # across both axes). On an sp=1 mesh this is plain fsdp; on a
         # long-video mesh like fsdp=16 x sp=4 it keeps the full 64-way
         # state sharding — fsdp-only sharding there quadruples per-chip
-        # state (measured: the 34B/v5e-64 sp=4 compile OOMs without
-        # this, TPU_VALIDATION round 5).
+        # state (the 34B/v5e-64 sp=4 compile runs out of memory without
+        # this).
         base["embed"] = ("fsdp", "sp")
     elif mode not in ("zero2", "ddp"):
         raise ValueError(f"unknown sharding mode {mode!r}")
@@ -264,31 +264,19 @@ def shard_paged_kv(kv_pages, mesh, *, num_kv_heads: int | None = None):
 
 
 def ambient_mesh():
-    """The ambient named mesh, across JAX versions: the abstract mesh
-    (jax >= 0.5, set via `jax.sharding.set_mesh`) or the thread-local
-    physical mesh (older JAX, set via `with mesh:`). Returns None when
-    no mesh is ambient."""
-    get = getattr(jax.sharding, "get_abstract_mesh", None)
-    if get is not None:
-        return get()
-    from jax._src import mesh as _mesh_lib
-
-    m = _mesh_lib.thread_resources.env.physical_mesh
-    return None if m.empty else m
+    """The ambient abstract mesh (set via `jax.sharding.set_mesh`);
+    empty when none is ambient."""
+    return jax.sharding.get_abstract_mesh()
 
 
 def mesh_scope(mesh):
-    """Context manager making `mesh` ambient for `constrain`/jit calls:
-    `jax.sharding.set_mesh` on new JAX, the legacy `with mesh:` resource
-    env on old. `mesh=None` is a no-op scope."""
+    """Context manager making `mesh` ambient for `constrain`/jit calls.
+    `mesh=None` is a no-op scope."""
     from contextlib import nullcontext
 
     if mesh is None:
         return nullcontext()
-    set_mesh = getattr(jax.sharding, "set_mesh", None)
-    if set_mesh is not None:
-        return set_mesh(mesh)
-    return mesh  # Mesh is itself a context manager on older JAX
+    return jax.sharding.set_mesh(mesh)
 
 
 def constrain(x, *axes):

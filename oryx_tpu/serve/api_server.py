@@ -1845,6 +1845,9 @@ def main(argv: list[str] | None = None) -> None:
         "HBM; mutually exclusive with --shard)",
     )
     args = ap.parse_args(argv)
+    from oryx_tpu.utils.compile_cache import configure_compile_cache
+
+    configure_compile_cache()
     if args.quantize and args.shard:
         ap.error("--quantize is single-chip serving; drop --shard")
     if args.engine == "sharded" and not args.shard:

@@ -45,6 +45,9 @@ def main(argv: list[str] | None = None) -> None:
         "HBM; mutually exclusive with --shard)",
     )
     args = ap.parse_args(argv)
+    from oryx_tpu.utils.compile_cache import configure_compile_cache
+
+    configure_compile_cache()
     if args.question is None and not args.interactive:
         ap.error("--question is required unless --interactive")
     if args.quantize and args.shard:

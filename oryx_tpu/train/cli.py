@@ -101,6 +101,9 @@ def load_params(args, cfg: OryxConfig):
 
 def main(argv: list[str] | None = None) -> None:
     args = build_argparser().parse_args(argv)
+    from oryx_tpu.utils.compile_cache import configure_compile_cache
+
+    configure_compile_cache()
     from oryx_tpu.utils import faults
 
     if faults.configure_from_env():

@@ -24,21 +24,8 @@ from oryx_tpu.utils.retry import BackoffPolicy, retry_call
 Params = dict[str, Any]
 
 
-class _Placeholder:
-    """Stand-in for `ocp.PLACEHOLDER` on orbax versions that predate it
-    (restore_partial then falls back to a full host restore and drops
-    these leaves afterwards)."""
-
-    def __repr__(self) -> str:  # pragma: no cover - debugging nicety
-        return "PLACEHOLDER"
-
-
 # Leaf marker for restore_partial targets: "do not restore this leaf".
-# Native on new orbax; emulated on old (see restore_partial).
-PLACEHOLDER = getattr(ocp, "PLACEHOLDER", None)
-_NATIVE_PLACEHOLDER = PLACEHOLDER is not None
-if PLACEHOLDER is None:
-    PLACEHOLDER = _Placeholder()
+PLACEHOLDER = ocp.PLACEHOLDER
 
 
 class CheckpointManager:
@@ -149,22 +136,6 @@ class CheckpointManager:
             if step is None:
                 raise FileNotFoundError(f"no checkpoints in {self.directory}")
         path = os.path.join(self.directory, str(step), "default")
-
-        if not _NATIVE_PLACEHOLDER:
-            # orbax predates PLACEHOLDER: restore the whole tree on the
-            # host, then place only the wanted leaves per the target's
-            # sharding/dtype; placeholder positions pass the restored
-            # value through (callers drop those subtrees anyway).
-            full = ocp.PyTreeCheckpointer().restore(path)
-
-            def place(t, r):
-                if isinstance(t, jax.ShapeDtypeStruct):
-                    return jax.device_put(
-                        np.asarray(r).astype(t.dtype), t.sharding
-                    )
-                return r
-
-            return jax.tree.map(place, target, full)
 
         # PyTreeRestore takes placement from restore_args, NOT from the
         # target's ShapeDtypeStruct.sharding (which it silently ignores,

@@ -12,30 +12,22 @@ policy inflate MFU.
 
 from __future__ import annotations
 
-# Peak dense bf16 FLOPs/s per chip kind (public spec sheets). Substring
-# match against device_kind.lower(); ordered so the more specific tag
-# wins (v5p before v5).
-PEAK_FLOPS = (
-    ("v6", 918e12),
-    ("v5p", 459e12),
-    ("v5e", 197e12),
-    ("v5 lite", 197e12),
-    ("v5litepod", 197e12),
-    ("v5", 459e12),
-    ("v4", 275e12),
-    ("v3", 123e12),
-)
+# Peak dense bf16 FLOPs/s per chip, keyed by the exact
+# `jax.devices()[0].device_kind` string. Only kinds a run of this repo
+# has printed are listed ("TPU v5 lite" is what a v5e reports,
+# chip_smoke.py PR 21; 197 TFLOP/s is Google Cloud's "TPU v5e" page).
+# A new chip gets a row when a run has shown its string — never a
+# neighbour's number.
+PEAK_FLOPS = {
+    "TPU v5 lite": 197e12,
+}
 
 
 def chip_peak_flops(device_kind: str) -> float | None:
-    """Peak dense bf16 FLOPs/s for a device kind string, None when
-    unknown (CPU, exotic backends) — callers must then skip MFU rather
-    than fake it."""
-    kl = (device_kind or "").lower()
-    for tag, f in PEAK_FLOPS:
-        if tag in kl:
-            return f
-    return None
+    """Peak dense bf16 FLOPs/s for an exact device_kind, None when the
+    kind is not in the table (CPU, a chip nobody has run on) — callers
+    must then skip MFU rather than fake it."""
+    return PEAK_FLOPS.get(device_kind)
 
 
 def count_llm_params(c) -> int:

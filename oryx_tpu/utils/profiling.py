@@ -24,25 +24,17 @@ def start_server(port: int = 9999) -> None:
 
 
 def _start_trace(logdir: str, *, host_tracer_level: int = 2) -> None:
-    """jax.profiler.start_trace with the ProfileOptions fallback —
-    newer jax takes options, older versions take none and default to
-    host tracing on; one helper so every capture path (the trace()
-    context manager, the continuous DeviceTimeSampler) shares it."""
-    if hasattr(jax.profiler, "ProfileOptions"):
-        opts = jax.profiler.ProfileOptions()
-        opts.host_tracer_level = host_tracer_level
-        jax.profiler.start_trace(logdir, profiler_options=opts)
-    else:
-        jax.profiler.start_trace(logdir)
+    """jax.profiler.start_trace at a host tracer level; one helper so
+    every capture path (the trace() context manager, the continuous
+    DeviceTimeSampler) shares it."""
+    opts = jax.profiler.ProfileOptions()
+    opts.host_tracer_level = host_tracer_level
+    jax.profiler.start_trace(logdir, profiler_options=opts)
 
 
 @contextlib.contextmanager
 def trace(logdir: str, *, host_tracer_level: int = 2) -> Iterator[None]:
-    """Capture a trace viewable in TensorBoard/Perfetto.
-
-    ProfileOptions only exists on newer jax; older versions take no
-    options and default to host tracing on — fall back rather than
-    making every profile capture version-locked."""
+    """Capture a trace viewable in TensorBoard/Perfetto."""
     _start_trace(logdir, host_tracer_level=host_tracer_level)
     try:
         yield
@@ -77,7 +69,7 @@ class OpProfile:
 
 
 def op_profile(
-    fn, *args, trace_dir: str, steps: int = 3, top_n: int = 25, sync=None
+    fn, *args, trace_dir: str, steps: int = 3, top_n: int = 25
 ) -> OpProfile:
     """Run `fn(*args)` `steps` times under a trace and return an
     OpProfile: top ops by total device time — self-contained: the
@@ -87,20 +79,16 @@ def op_profile(
     via `.source`.
 
     fn should already be compiled (call it once beforehand) — compile
-    time inside the trace would swamp the profile. `sync` receives the
-    last result and must block on it (default: jax.block_until_ready;
-    pass a device_get-based sync over remote transports where
-    block_until_ready is a no-op)."""
+    time inside the trace would swamp the profile."""
     from oryx_tpu.utils import trace as trace_lib
     from oryx_tpu.utils import xplane
 
-    sync = sync or jax.block_until_ready
     with trace(trace_dir):
         t_start = trace_lib.now_ns()
         out = None
         for _ in range(steps):
             out = fn(*args)
-        sync(out)
+        jax.block_until_ready(out)
         t_end = trace_lib.now_ns()
     files = xplane.find_xplane_files(trace_dir)
     if not files:

@@ -4,8 +4,7 @@ analog, measurable without a TPU (this is all host CPU work).
 Times visual preprocessing (resize+normalize+patchify, the pipeline's
 hot loop) through pack_raw_images on a 64-frame 224px video request —
 native C++ path (native/loader.cpp thread pool) vs the pure-numpy
-fallback, frames/sec. Prints one JSON line; numbers land in
-TPU_VALIDATION.md.
+fallback, frames/sec. Prints one JSON line.
 """
 
 from __future__ import annotations

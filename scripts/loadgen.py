@@ -1366,8 +1366,8 @@ def run(argv=None) -> dict:
         # Provenance stamps (scripts/bench_compare.py refuses
         # comparisons across any of these): the git revision this run
         # measured, the backend class (a cpu self-boot is a labeled
-        # cpu_proxy run, the same convention as bench.py — never
-        # comparable against a TPU baseline), the target's own
+        # cpu_proxy run — never comparable against a TPU baseline),
+        # the target's own
         # build_info identity, and the engine flags in effect.
         import jax
 

@@ -2,10 +2,10 @@
 # 34B long-video SFT on a v5e-64 (BASELINE config 5: 256-frame records,
 # ZeRO-3 at pod scale): ring attention over sp=4 with the ZeRO state
 # sharded over the COMBINED fsdp x sp width, vision patch shards riding
-# sp, bf16 moments, block remat, grad_accum 8 — the configuration the
-# real XLA:TPU compiler proves fits 16 GB/chip (14.71 GB,
-# TPU_VALIDATION.md round 5; scripts/estimate_7b_mesh_memory.py with
-# AOT_CONFIG=scripts/configs/oryx_34b_longvideo.json AOT_FRAMES=256).
+# sp, bf16 moments, block remat, grad_accum 8 — sized with
+# scripts/estimate_7b_mesh_memory.py
+# (AOT_CONFIG=scripts/configs/oryx_34b_longvideo.json AOT_FRAMES=256).
+# Not run on the current chip.
 set -euo pipefail
 cd "$(dirname "$0")/.."
 

@@ -174,8 +174,8 @@ def test_pod_configs_v5e64_tpu_aot_memory(config, frames):
     sharded train step compiled for a v5e:8x8 (64-chip) target via the
     topology API — no extrapolation, the actual buffer assignment.
 
-    Pins the round-5 recipe that makes pod-scale 32B/34B fit 16 GB/chip
-    (TPU_VALIDATION round 5): ZeRO-3 over the COMBINED fsdp x sp width
+    Pins the recipe that makes pod-scale 32B/34B fit 16 GB/chip:
+    ZeRO-3 over the COMBINED fsdp x sp width
     + vision patch shards riding sp + grad_accum 8 (512 tokens/chip/
     microbatch) + bf16 moments + block remat (34B long-video measured
     14.71 GB, 32B 13.67; the pre-round-5 pure-FSDP accum-2 configs OOM

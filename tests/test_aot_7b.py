@@ -1,6 +1,5 @@
 """Oryx-7B on a v5e-16: AOT per-chip memory proof (SURVEY.md §7 hard
-part 5; VERDICT r4 "prove the 7B-on-a-mesh memory math end-to-end in
-AOT").
+part 5).
 
 Drives scripts/estimate_7b_mesh_memory.py, which compiles the FULL
 sharded train step for the shipped `scripts/configs/oryx_7b_sft.json`
@@ -16,7 +15,10 @@ local libtpu, no chips attached — and pins:
 
 The script re-execs itself into a clean CPU-client child; the TPU
 *compiler* target comes from the topology API, so this runs anywhere
-libtpu is installed. Numbers recorded in TPU_VALIDATION.md (round 5).
+libtpu is installed. (Its Pallas kernels compile in interpret mode
+under JAX_PLATFORMS=cpu — the memory figures are of the emulation, not
+of the Mosaic kernels; tests/test_pallas_topology_compile.py compiles
+those.)
 """
 
 import json

@@ -5,8 +5,7 @@ claim, compiled against the actual XLA:TPU compiler (chipless v5e
 topology) at the true Oryx-7B geometry via
 scripts/estimate_serving_memory.py: the 64-frame visual encode and the
 jitted prefill+decode generate program, both over the int8 param tree
-(int8 kernels + embedding, bf16 elsewhere). Numbers recorded in
-TPU_VALIDATION.md round 5.
+(int8 kernels + embedding, bf16 elsewhere).
 """
 
 import json

@@ -1,6 +1,6 @@
 """Query-chunked XLA attention (ops/attention.py): the memory-bounded
 `lax.map` path must equal the dense path bit-for-bit per chunk math, so the
-biggest packed-video buckets (VERDICT weak #9: 65536-bucket fallback) stay
+biggest packed-video buckets (the 65536-bucket fallback) stay
 serviceable without O(P^2) logits."""
 
 import numpy as np

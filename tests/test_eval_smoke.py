@@ -2,7 +2,7 @@
 CLI path: scripts/make_smoke_eval.py builds a model dir with an on-disk
 HF tokenizer, then eval.harness.main loads the pipeline from disk, runs
 batched decode over the committed media, scores, and writes the result
-JSON (SURVEY.md §3.5; VERDICT r3 next-round #6)."""
+JSON (SURVEY.md §3.5)."""
 
 import json
 import os

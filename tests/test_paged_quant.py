@@ -200,7 +200,7 @@ def test_ragged_kernel_matches_reference_on_quant_pool():
     seg = jnp.zeros((6,), jnp.int32)
     pos = jnp.asarray([3, 10, 17, 25, 33, 39], jnp.int32)
     ref = paged_kv.ragged_paged_attention(q, pool, pool, bt_s, seg, pos)
-    for hb in (1, 2):
+    for hb in (2, None):  # Hk=2: the whole axis is the one legal tile
         ker = ppa.ragged_paged_attention(
             q, pool, pool, bt_s, seg, pos,
             heads_per_block=hb, interpret=True,

@@ -1,4 +1,4 @@
-"""Full-geometry parity hardening (VERDICT r2 #5; BASELINE logit-parity
+"""Full-geometry parity hardening (BASELINE logit-parity
 row): random-weight logits parity vs HF transformers at the EXACT Oryx-7B
 backbone width — hidden 3584, 28 q / 4 kv heads (group 7), head_dim 128,
 vocab 152064, Qwen2 attention bias — at reduced depth (2 layers), plus a

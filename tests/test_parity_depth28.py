@@ -1,4 +1,4 @@
-"""FULL-DEPTH parity leg (VERDICT r3 next-round #5; SURVEY.md §4 "Unit",
+"""FULL-DEPTH parity leg (SURVEY.md §4 "Unit",
 §7 hard part 2): random-weight logits parity vs HF transformers at the
 Oryx-7B backbone's exact DEPTH (num_layers=28) with head_dim 128, GQA
 group 7, vocab 152064 and Qwen2 attention bias kept — width reduced to

@@ -155,13 +155,16 @@ def test_write_pages_packed_matches_write_pages():
     np.testing.assert_array_equal(np.asarray(w_sent), kp)
 
 
-def test_pallas_ragged_matches_reference_across_tiles():
+@pytest.mark.parametrize(
+    "Hk,hb", [(4, 4), (1, 1), (16, 8), (16, 16), (4, None)]
+)
+def test_pallas_ragged_matches_reference_across_tiles(Hk, hb):
     """The Pallas kernel (interpret mode on CPU) matches the packed
-    reference across grid-table tile variants, page-boundary positions
-    and position 0."""
-    bt, kp, vp, _ = _pool(seed=4, Hk=4, lengths=(8, 17, 32))
+    reference across the kv-head tiles the TPU lowering accepts,
+    page-boundary positions and position 0."""
+    bt, kp, vp, _ = _pool(seed=4, Hk=Hk, lengths=(8, 17, 32))
     rng = np.random.default_rng(5)
-    Hq, D = 8, 16
+    Hq, D = 2 * Hk, 16
     seg = np.array([0, 1, 2, 1, 1, 0], np.int32)
     pos = np.array([7, 16, 31, 8, 3, 0], np.int32)  # 7,8: page edges
     q = rng.standard_normal((len(seg), Hq, D)).astype(np.float32)
@@ -169,35 +172,56 @@ def test_pallas_ragged_matches_reference_across_tiles():
         jnp.asarray(q), jnp.asarray(kp), jnp.asarray(vp),
         jnp.asarray(bt), jnp.asarray(seg), jnp.asarray(pos),
     )
-    for hb in (1, 2, 4):
-        got = ppa.ragged_paged_attention(
-            jnp.asarray(q), jnp.asarray(kp), jnp.asarray(vp),
-            jnp.asarray(bt), jnp.asarray(seg), jnp.asarray(pos),
-            heads_per_block=hb,
-        )
-        np.testing.assert_allclose(
-            np.asarray(got), np.asarray(ref), atol=2e-6, rtol=2e-6,
-            err_msg=f"heads_per_block={hb}",
-        )
-
-
-def test_grid_table_caches_and_clamps():
-    """The grid table answers once per (head_dim, page_size) shape
-    class, clamps heads_per_block to divide the model's kv heads, and
-    autotune on a non-TPU backend caches the budget default (a STABLE
-    choice, never a per-call search)."""
-    ppa._RAGGED_GRID_CACHE.pop((64, 16), None)
-    a = ppa.ragged_grid_config(64, 16, 8)
-    assert a["heads_per_block"] >= 1
-    assert (64, 16) in ppa._RAGGED_GRID_CACHE
-    # A 3-head model must get a divisor even from a cached pow2 choice.
-    b = ppa.ragged_grid_config(64, 16, 3)
-    assert 3 % b["heads_per_block"] == 0
-    tuned = ppa.autotune_ragged_grid(64, 16, 8)
-    assert tuned["heads_per_block"] >= 1
-    assert not ppa._RAGGED_GRID_CACHE[(64, 16)]["autotuned"] or (
-        jax.default_backend() == "tpu"
+    got = ppa.ragged_paged_attention(
+        jnp.asarray(q), jnp.asarray(kp), jnp.asarray(vp),
+        jnp.asarray(bt), jnp.asarray(seg), jnp.asarray(pos),
+        heads_per_block=hb,
     )
+    np.testing.assert_allclose(
+        np.asarray(got), np.asarray(ref), atol=2e-6, rtol=2e-6,
+    )
+
+
+def test_pallas_decode_is_a_length_one_ragged_lane():
+    """`ragged_decode_attention` runs the packed kernel (row b = segment
+    b at position len-1): equal to the XLA decode reference, zeros for
+    an empty row."""
+    bt, kp, vp, _ = _pool(seed=6, Hk=2, lengths=(8, 17, 32))
+    rng = np.random.default_rng(7)
+    q = rng.standard_normal((3, 1, 4, 16)).astype(np.float32)
+    lens = jnp.asarray([8, 9, 0], jnp.int32)
+    ref = paged_kv.ragged_decode_attention(
+        jnp.asarray(q), jnp.asarray(kp), jnp.asarray(vp),
+        jnp.asarray(bt), lens,
+    )
+    got = ppa.ragged_decode_attention(
+        jnp.asarray(q), jnp.asarray(kp), jnp.asarray(vp),
+        jnp.asarray(bt), lens,
+    )
+    np.testing.assert_allclose(
+        np.asarray(got)[:2], np.asarray(ref)[:2], atol=2e-6, rtol=2e-6
+    )
+    assert not np.asarray(got)[2].any()
+
+
+def test_heads_per_block_offers_only_what_the_lowering_takes(monkeypatch):
+    """Only the whole kv-head axis or a multiple of 8 dividing it is a
+    legal tile; the default picks one, and an illegal explicit value or
+    $ORYX_RPA_HEADS_PER_BLOCK pin raises naming it (never a clamp)."""
+    assert ppa.legal_heads_per_block(4) == (4,)
+    assert ppa.legal_heads_per_block(16) == (8, 16)
+    assert ppa.legal_heads_per_block(3) == (3,)
+    monkeypatch.delenv("ORYX_RPA_HEADS_PER_BLOCK", raising=False)
+    assert ppa.ragged_heads_per_block(128, 64, 4) == 4
+    assert ppa.ragged_heads_per_block(128, 256, 16) == 8  # VMEM budget
+    assert ppa.ragged_heads_per_block(64, 16, 16) == 16
+    with pytest.raises(ValueError, match="heads_per_block=2"):
+        ppa.check_heads_per_block(2, 4)
+    monkeypatch.setenv("ORYX_RPA_HEADS_PER_BLOCK", "8")
+    assert ppa.ragged_heads_per_block(128, 64, 16) == 8
+    monkeypatch.setenv("ORYX_RPA_HEADS_PER_BLOCK", "2")
+    with pytest.raises(ValueError, match=r"\$ORYX_RPA_HEADS_PER_BLOCK=2"):
+        ppa.ragged_heads_per_block(128, 64, 4)
 
 
 # ---------------------------------------------------------------------------
@@ -422,41 +446,3 @@ def test_dispatch_metrics_split_mode(pipe):
     text = sm.render()
     assert "oryx_serving_dispatches_total" in text
     assert "oryx_serving_dispatch_rows_bucket" in text
-
-
-def test_autotune_synthetic_operands_draw_independent_keys():
-    """Regression for the autotune key-reuse defect (oryxlint
-    key-linearity self-application, finding at
-    oryx_tpu/ops/pallas/paged_attention.py:395): `autotune_ragged_grid`
-    drew its synthetic q AND its synthetic KV pages from the same
-    `jax.random.key(0)`, so the operands the candidate grids are timed
-    against shared their key material. The fix splits the seed into
-    independent subkeys; this test runs the key-linearity dataflow over
-    the real module so the shape cannot come back, and proves the guard
-    is live by linting the pre-fix construction."""
-    import pathlib
-
-    from oryx_tpu.analysis import make_checkers, run_lint
-
-    path = pathlib.Path(ppa.__file__.replace(".pyc", ".py"))
-    res = run_lint(
-        [(str(path), path.read_text())],
-        make_checkers("key-linearity"),
-    )
-    assert [f.line for f in res.findings] == []
-    old_shape = (
-        "import jax\n"
-        "import jax.numpy as jnp\n"
-        "def autotune(head_dim):\n"
-        "    key_ = jax.random.key(0)\n"
-        "    q = jax.random.normal(key_, (16, 8, head_dim), jnp.float32)\n"
-        "    kp = jax.random.normal(key_, (64, 16, 8, head_dim), jnp.float32)\n"
-        "    return q, kp\n"
-    )
-    res = run_lint(
-        [("autotune_defect.py", old_shape)],
-        make_checkers("key-linearity"),
-    )
-    assert [(f.line, f.rule) for f in res.findings] == [
-        (6, "key-linearity")
-    ]

@@ -213,26 +213,7 @@ def test_corrupt_batch_hits_skip_guard(tmp_path):
 # ---------------------------------------------------------------------------
 
 
-@pytest.fixture
-def _no_persistent_cache():
-    """Disable the persistent compilation cache for this test: the
-    jax-0.4.37 deserialized-executable donation quirk (see conftest)
-    would otherwise make EVERY run's params stale and the comparison
-    vacuous-or-flaky depending on cache temperature. Fresh compiles
-    are correct on every jax."""
-    from jax._src import compilation_cache as _cc
-
-    old = jax.config.jax_compilation_cache_dir
-    jax.config.update("jax_compilation_cache_dir", None)
-    _cc.reset_cache()
-    yield
-    jax.config.update("jax_compilation_cache_dir", old)
-    _cc.reset_cache()
-
-
-def test_injected_crash_auto_resumes_bit_identical(
-    tmp_path, _no_persistent_cache
-):
+def test_injected_crash_auto_resumes_bit_identical(tmp_path):
     if jax.device_count() < 8:
         pytest.skip("needs the 8-device CPU mesh (conftest)")
     steps = 4

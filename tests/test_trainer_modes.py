@@ -153,7 +153,7 @@ def test_trainer_step_traces_and_phase_metrics(tmp_path):
 
 
 def test_trainer_rejects_packed_text_under_ring():
-    """VERDICT item 4 (satellite): the ring x packed-text trap fails
+    """The ring x packed-text trap fails
     fast at the trainer boundary with an actionable message instead of
     dying deep in jit (or training silently wrong)."""
     import numpy as np

@@ -72,8 +72,10 @@ def test_launch_configs_load(name):
         cfg = OryxConfig.from_json(f.read())
     assert cfg.mesh.num_devices >= 4
     # Sequence-parallel meshes train under ring attention ("ring" = xla
-    # inner loop, "ring_flash" = Pallas inner — the 32B/34B pod recipe,
-    # TPU_VALIDATION round 5); dense meshes use the Pallas kernel.
+    # inner loop, "ring_flash" = Pallas inner — the 32B/34B pod
+    # recipe); dense meshes name the Pallas kernel. (Neither has run on
+    # the current chip: its compiler refuses a Mosaic kernel under a
+    # mesh until the call is wrapped in a shard_map — ROADMAP S8.)
     if cfg.mesh.sp > 1:
         assert cfg.attn_impl.startswith("ring")
     else:

@@ -122,7 +122,6 @@ def test_op_profile_end_to_end(tmp_path):
     f(x)  # compile outside the trace
     prof = profiling.op_profile(
         f, x, trace_dir=str(tmp_path), steps=2, top_n=10,
-        sync=jax.device_get,
     )
     assert prof.source in ("tpu_xla_ops", "host_fallback")
     assert prof.top and all(ms >= 0 for _, ms in prof.top)
