@@ -172,6 +172,17 @@ _LOG = logging.getLogger("oryx.serve.scheduler")
 # implicitly available below the ladder.
 FUSE_AUTO_LADDER: tuple[int, ...] = (4, 16)
 
+# The engine loop's phases (utils/profiling.PhaseClock): the labels of
+# oryx_serving_engine_phase_seconds_total{phase=} and, prefixed
+# `oryx.engine.`, the host events a profiler capture holds. Exclusive
+# seconds: every instant of the loop is in exactly one. The host WAITS
+# in idle (no work), first_token (the tok0 read in _activate) and
+# harvest; it works in the rest (docs/OBSERVABILITY.md "Engine phases").
+ENGINE_PHASES = (
+    "idle", "housekeeping", "admit", "prompt_prep", "embed", "prefill",
+    "first_token", "decode", "harvest", "emit",
+)
+
 
 class AdmissionRejected(RuntimeError):
     """submit() refused the request without queueing it: backpressure
@@ -735,6 +746,16 @@ class ContinuousScheduler:
         # it exists for would be the wrong default, and the disarmed
         # cost is one dict build per dispatch.
         self.timeline = timeline or StepTimeline()
+        # Where the engine thread's time goes (ENGINE_PHASES). The
+        # children are made here so every phase renders from the first
+        # scrape and a phase boundary costs one locked add.
+        fam = self.metrics.registry.counter(
+            "engine_phase_seconds_total", ("phase",)
+        )
+        self._phase_seconds = {
+            p: fam.labels(phase=p).inc for p in ENGINE_PHASES
+        }
+        self._phases = self._new_phase_clock()
         # Wide-event request log (utils/request_log.py): one canonical
         # JSONL event per terminal request, merging the cost ledger,
         # span wall-times, outcome and routing identity. engine_label/
@@ -1232,7 +1253,7 @@ class ContinuousScheduler:
                 "engine_restart_replay", slot=s,
                 replay_tokens=req.processed,
             )
-            req.qw_span = req.trace.begin("queue_wait", requeued=True)
+            self._requeue_spans(req)
             with self._cond:
                 self._queue.appendleft(req)
         with self._cond:
@@ -1523,7 +1544,10 @@ class ContinuousScheduler:
             "decode_tokens": req.cost_decode_tokens,
             "page_seconds": round(req.cost_page_seconds, 6),
             "queue_s": round(by.get("queue_wait", 0.0), 6),
-            "prefill_s": round(by.get("prefill", 0.0), 6),
+            # Queue head -> first token (the `admission` spans): prompt
+            # prep, the wait for pages, every prefill chunk and what
+            # ran between them. The `prefill` spans time an enqueue.
+            "prefill_s": round(by.get("admission", 0.0), 6),
             "decode_s": round(by.get("decode_chunk", 0.0), 6),
             "e2e_s": round(time.monotonic() - req.submit_time, 6),
             "peak_pages": req.peak_pages,
@@ -1749,7 +1773,22 @@ class ContinuousScheduler:
 
     # ---- scheduling loop -------------------------------------------------
 
+    def _new_phase_clock(self) -> profiling_lib.PhaseClock:
+        return profiling_lib.PhaseClock(
+            "oryx.engine",
+            lambda name, seconds: self._phase_seconds[name](seconds),
+            base="housekeeping",
+        )
+
+    def _phase(self, name: str, kind: str = "host"):
+        """`with self._phase(...)`: the engine thread is in this phase
+        (ENGINE_PHASES) until the block ends; loop time outside every
+        block is housekeeping."""
+        return self._phases.phase(name, kind)
+
     def _run(self) -> None:
+        # A restarted loop runs on a new thread: its own clock.
+        self._phases = self._new_phase_clock()
         while True:
             if self.replay_feeder is not None:
                 # Offline replay (scripts/replay_journal.py): feed the
@@ -1815,17 +1854,19 @@ class ContinuousScheduler:
                 self._update_degraded()
                 if self.watchdog is not None:
                     self.watchdog.set_active(False)
-                if self.auditor.pending():
-                    # Idle quiesce point: run ONE queued shadow-parity
-                    # replay, then re-check for live work — an arrival
-                    # never waits behind a second replay, and a replay
-                    # can never interleave with a live dispatch (the
-                    # never-perturb contract, serve/audit.py).
-                    self.auditor.run_one()
-                    continue
-                with self._cond:
-                    if not self._queue and not self._shutdown:
-                        self._cond.wait(timeout=0.1)
+                with self._phase("idle", "blocked"):
+                    if self.auditor.pending():
+                        # Idle quiesce point: run ONE queued shadow-
+                        # parity replay, then re-check for live work —
+                        # an arrival never waits behind a second
+                        # replay, and a replay can never interleave
+                        # with a live dispatch (the never-perturb
+                        # contract, serve/audit.py).
+                        self.auditor.run_one()
+                        continue
+                    with self._cond:
+                        if not self._queue and not self._shutdown:
+                            self._cond.wait(timeout=0.1)
                 continue
             if self.watchdog is not None:
                 self.watchdog.set_active(True)
@@ -1848,8 +1889,9 @@ class ContinuousScheduler:
             if take is not None:
                 self._adopt_profile(take)
             try:
-                self._update_degraded()
-                self._enforce_deadlines()
+                with self._phase("housekeeping"):
+                    self._update_degraded()
+                    self._enforce_deadlines()
                 self._admit()
                 if self.ragged:
                     # Fused path: prefill lanes and decode lanes ride
@@ -1868,7 +1910,8 @@ class ContinuousScheduler:
                     if any(
                         r is not None and r.activated for r in self.slots
                     ):
-                        self._ensure_capacity()
+                        with self._phase("housekeeping"):
+                            self._ensure_capacity()
                         self._step_chunk()
             except Exception as e:  # surface to every in-flight client
                 msg = f"{type(e).__name__}: {e}"
@@ -2051,172 +2094,188 @@ class ContinuousScheduler:
             self._cache_shed = False
 
     def _admit(self) -> None:
-        gen = self.cfg.generation
         while True:
-            if self.replay_feeder is not None:
-                # Replay feeding re-checks its step gates HERE as well
-                # as at the loop top: an unchunked prefill dispatches
-                # inside this while (advancing steps_run mid-
-                # iteration), and the live run may have admitted the
-                # next queued request immediately after it — the
-                # feeder must be able to inject that request between
-                # two admissions, not one engine iteration later.
-                self.replay_feeder(self)
-            if any(r is not None and not r.activated for r in self.slots):
-                # A chunked prefill is in flight: the engine-step budget
-                # for prompt work is ONE prefill chunk, so no further
-                # admission until it activates (its donation then lands
-                # before the next look-alike's lookup).
+            with self._phase("admit"):
+                placed = self._admit_next()
+            if placed is None:
                 break
-            free = [s for s, r in enumerate(self.slots) if r is None]
-            if not free:
-                break
-            with self._cond:
-                if not self._queue:
-                    break
-                req = self._queue[0]
-            if req.handle.cancelled:
-                with self._cond:
-                    # Safe check-then-act: the engine thread is the
-                    # queue's ONLY consumer (submit appends at the
-                    # tail; restart appendlefts only once this thread
-                    # is dead), so the head peeked above cannot have
-                    # changed.
-                    self._queue.popleft()  # oryxlint: disable=atomicity
-                    depth = len(self._queue)
-                    # Every pop must refresh the gauge: without this a
-                    # pre-admission cancel left queue_depth one high
-                    # until the next submit.
-                    self.metrics.set_gauge("queue_depth", depth)
-                if self.anomaly is not None:
-                    # Drain-side observation, same invariant as the
-                    # engine-failure drain: a backlog that empties via
-                    # client cancels must re-arm the queue_depth_slo
-                    # episode, or the next burst fires no event.
-                    self.anomaly.observe_queue_depth(depth)
-                # A cancelled-in-queue request still gets a ledger
-                # (zero resources, real queue_s): its trace lands in
-                # /debug/requests?state=done, and the every-finished-
-                # request-has-a-complete-ledger audit must hold there
-                # too.
-                self._cancel_queued(req)
-                continue
-            if req.embeds is None:
-                # The request reached the queue head: queue_wait ends,
-                # admission (prompt prep + validation + the wait for
-                # pages + prefill) begins.
-                req.trace.end(req.qw_span)
-                req.qw_span = -1
-                req.adm_span = req.trace.begin("admission")
-                try:
-                    with req.trace.span("prompt_prep"):
-                        ids, imgs, factors, caps = (
-                            self.pipe._prepare_request(req.request)
-                        )
-                        with self.pipe._mesh_scope():
-                            req.embeds, req.length = (
-                                self.pipe._prompt_embeds(
-                                    self.cfg, ids, imgs, factors, caps
-                                )
-                            )
-                        # Text-only prompts key the prefix cache by
-                        # token ids (ids == the logical KV stream);
-                        # multimodal streams key visual slots
-                        # positionally and bypass it.
-                        req.cache_tokens = (
-                            None if imgs else np.asarray(ids, np.int64)
-                        )
-                    s_ = req.sampling
-                    req.temp = float(
-                        s_.get("temperature", gen.temperature) or 0.0
-                    )
-                    req.topp = float(s_.get("top_p", gen.top_p) or 1.0)
-                    req.topk = int(s_.get("top_k", gen.top_k) or 0)
-                    req.key0 = jax.random.key(int(s_.get("seed") or 0))
-                    with self._cond:
-                        mode = self._degraded
-                    if (
-                        mode >= 2
-                        and req.max_new > self.degraded_clamp_tokens
-                    ):
-                        # Degraded mode 2: cap the decode budget so the
-                        # backlog turns over faster; the client sees a
-                        # "length" finish and the clamp in debug.
-                        req.max_new = self.degraded_clamp_tokens
-                        req.handle.debug["clamped_max_tokens"] = (
-                            self.degraded_clamp_tokens
-                        )
-                    if req.length + req.max_new > self.max_ctx:
-                        raise ValueError(
-                            f"prompt ({req.length}) + max_tokens "
-                            f"({req.max_new}) exceeds max_ctx {self.max_ctx}"
-                        )
-                    need = self.allocator.pages_for(
-                        req.length + self._win
-                    )
-                    if need > self.num_pages:
-                        raise ValueError(
-                            f"prompt needs {need} KV pages but the whole "
-                            f"pool holds {self.num_pages} (raise "
-                            "--num-pages, or lower the prompt length / "
-                            "--max-ctx)"
-                        )
-                except Exception as e:
-                    with self._cond:
-                        # Single-consumer head pop (see the cancel
-                        # branch above).
-                        self._queue.popleft()  # oryxlint: disable=atomicity
-                        depth = len(self._queue)
-                        self.metrics.set_gauge("queue_depth", depth)
-                    if self.anomaly is not None:
-                        # Same drain-side invariant as the cancel and
-                        # engine-failure pops: a backlog emptied by
-                        # rejections must re-arm the queue_depth_slo
-                        # episode.
-                        self.anomaly.observe_queue_depth(depth)
-                    msg = f"{type(e).__name__}: {e}"
-                    cost = self._finalize_cost(None, req)
-                    req.handle.error = msg
-                    if isinstance(e, ValueError):
-                        req.handle.error_kind = "invalid_request"
-                    req.handle.events.put(("error", msg))
-                    req.handle.done.set()
-                    req.trace.finish(error=msg, cost=cost)
-                    self._emit_request_event(
-                        req, status="error",
-                        error_kind=req.handle.error_kind,
-                    )
-                    _LOG.info(
-                        "request %s rejected at admission: %s",
-                        req.trace.id, msg,
-                    )
-                    continue
-            s = free[0]
-            # Splice the cached prefix and take pages for the prompt
-            # plus the first chunk's writes. FIFO head-of-line: if the
-            # head doesn't fit, nobody jumps it (that is the
-            # no-starvation guarantee).
-            if not self._splice_and_grow(s, req):
-                break
-            with self._cond:
-                # Single-consumer head pop (see the cancel branch).
-                self._queue.popleft()  # oryxlint: disable=atomicity
-                depth = len(self._queue)
-                self.metrics.set_gauge("queue_depth", depth)
-            if self.anomaly is not None:
-                # Drain-side observations re-arm the hysteresis: with
-                # submit-only feeding, the detector would only ever see
-                # depths >= 1 and a queue_depth_slo of 1 could never
-                # re-arm after its first firing.
-                self.anomaly.observe_queue_depth(depth)
-            self._place(s, req)
-            if self.prefill_chunk is None:
+            if placed and self.prefill_chunk is None:
                 # Unchunked: complete the (single-dispatch) prefill now,
                 # so the slot activates — and donates its prompt pages —
                 # before the next queue head is examined. A burst of
                 # look-alike requests therefore admits cold exactly
                 # once; the rest splice.
-                self._advance_prefill(s, req)
+                self._advance_prefill(*placed)
+
+    def _admit_next(self) -> tuple | None:
+        """Examine the queue head once. None: admission stops for this
+        engine step (a chunked prefill in flight, no free slot, an
+        empty queue, or a head that does not fit yet); (): the head
+        was dropped (cancelled or rejected) and the next one is due;
+        (slot, request): the head was placed."""
+        gen = self.cfg.generation
+        if self.replay_feeder is not None:
+            # Replay feeding re-checks its step gates HERE as well
+            # as at the loop top: an unchunked prefill dispatches
+            # inside this while (advancing steps_run mid-
+            # iteration), and the live run may have admitted the
+            # next queued request immediately after it — the
+            # feeder must be able to inject that request between
+            # two admissions, not one engine iteration later.
+            self.replay_feeder(self)
+        if any(r is not None and not r.activated for r in self.slots):
+            # A chunked prefill is in flight: the engine-step budget
+            # for prompt work is ONE prefill chunk, so no further
+            # admission until it activates (its donation then lands
+            # before the next look-alike's lookup).
+            return None
+        free = [s for s, r in enumerate(self.slots) if r is None]
+        if not free:
+            return None
+        with self._cond:
+            if not self._queue:
+                return None
+            req = self._queue[0]
+        if req.handle.cancelled:
+            with self._cond:
+                # Safe check-then-act: the engine thread is the
+                # queue's ONLY consumer (submit appends at the
+                # tail; restart appendlefts only once this thread
+                # is dead), so the head peeked above cannot have
+                # changed.
+                self._queue.popleft()  # oryxlint: disable=atomicity
+                depth = len(self._queue)
+                # Every pop must refresh the gauge: without this a
+                # pre-admission cancel left queue_depth one high
+                # until the next submit.
+                self.metrics.set_gauge("queue_depth", depth)
+            if self.anomaly is not None:
+                # Drain-side observation, same invariant as the
+                # engine-failure drain: a backlog that empties via
+                # client cancels must re-arm the queue_depth_slo
+                # episode, or the next burst fires no event.
+                self.anomaly.observe_queue_depth(depth)
+            # A cancelled-in-queue request still gets a ledger
+            # (zero resources, real queue_s): its trace lands in
+            # /debug/requests?state=done, and the every-finished-
+            # request-has-a-complete-ledger audit must hold there
+            # too.
+            self._cancel_queued(req)
+            return ()
+        if req.embeds is None:
+            # The request reached the queue head: queue_wait ends,
+            # admission (prompt prep + validation + the wait for
+            # pages + prefill) begins.
+            req.trace.end(req.qw_span)
+            req.qw_span = -1
+            req.adm_span = req.trace.begin("admission")
+            try:
+                with req.trace.span("prompt_prep"):
+                    with self._phase("prompt_prep"):
+                        ids, imgs, factors, caps = (
+                            self.pipe._prepare_request(req.request)
+                        )
+                    # Patch packing, staging and the enqueue of
+                    # mm_embeds (or of the embedding gather); nothing
+                    # here waits for the device.
+                    with self._phase("embed"), self.pipe._mesh_scope():
+                        req.embeds, req.length = (
+                            self.pipe._prompt_embeds(
+                                self.cfg, ids, imgs, factors, caps
+                            )
+                        )
+                    # Text-only prompts key the prefix cache by
+                    # token ids (ids == the logical KV stream);
+                    # multimodal streams key visual slots
+                    # positionally and bypass it.
+                    req.cache_tokens = (
+                        None if imgs else np.asarray(ids, np.int64)
+                    )
+                s_ = req.sampling
+                req.temp = float(
+                    s_.get("temperature", gen.temperature) or 0.0
+                )
+                req.topp = float(s_.get("top_p", gen.top_p) or 1.0)
+                req.topk = int(s_.get("top_k", gen.top_k) or 0)
+                req.key0 = jax.random.key(int(s_.get("seed") or 0))
+                with self._cond:
+                    mode = self._degraded
+                if (
+                    mode >= 2
+                    and req.max_new > self.degraded_clamp_tokens
+                ):
+                    # Degraded mode 2: cap the decode budget so the
+                    # backlog turns over faster; the client sees a
+                    # "length" finish and the clamp in debug.
+                    req.max_new = self.degraded_clamp_tokens
+                    req.handle.debug["clamped_max_tokens"] = (
+                        self.degraded_clamp_tokens
+                    )
+                if req.length + req.max_new > self.max_ctx:
+                    raise ValueError(
+                        f"prompt ({req.length}) + max_tokens "
+                        f"({req.max_new}) exceeds max_ctx {self.max_ctx}"
+                    )
+                need = self.allocator.pages_for(
+                    req.length + self._win
+                )
+                if need > self.num_pages:
+                    raise ValueError(
+                        f"prompt needs {need} KV pages but the whole "
+                        f"pool holds {self.num_pages} (raise "
+                        "--num-pages, or lower the prompt length / "
+                        "--max-ctx)"
+                    )
+            except Exception as e:
+                with self._cond:
+                    # Single-consumer head pop (see the cancel
+                    # branch above).
+                    self._queue.popleft()  # oryxlint: disable=atomicity
+                    depth = len(self._queue)
+                    self.metrics.set_gauge("queue_depth", depth)
+                if self.anomaly is not None:
+                    # Same drain-side invariant as the cancel and
+                    # engine-failure pops: a backlog emptied by
+                    # rejections must re-arm the queue_depth_slo
+                    # episode.
+                    self.anomaly.observe_queue_depth(depth)
+                msg = f"{type(e).__name__}: {e}"
+                cost = self._finalize_cost(None, req)
+                req.handle.error = msg
+                if isinstance(e, ValueError):
+                    req.handle.error_kind = "invalid_request"
+                req.handle.events.put(("error", msg))
+                req.handle.done.set()
+                req.trace.finish(error=msg, cost=cost)
+                self._emit_request_event(
+                    req, status="error",
+                    error_kind=req.handle.error_kind,
+                )
+                _LOG.info(
+                    "request %s rejected at admission: %s",
+                    req.trace.id, msg,
+                )
+                return ()
+        s = free[0]
+        # Splice the cached prefix and take pages for the prompt
+        # plus the first chunk's writes. FIFO head-of-line: if the
+        # head doesn't fit, nobody jumps it (that is the
+        # no-starvation guarantee).
+        if not self._splice_and_grow(s, req):
+            return None
+        with self._cond:
+            # Single-consumer head pop (see the cancel branch).
+            self._queue.popleft()  # oryxlint: disable=atomicity
+            depth = len(self._queue)
+            self.metrics.set_gauge("queue_depth", depth)
+        if self.anomaly is not None:
+            # Drain-side observations re-arm the hysteresis: with
+            # submit-only feeding, the detector would only ever see
+            # depths >= 1 and a queue_depth_slo of 1 could never
+            # re-arm after its first firing.
+            self.anomaly.observe_queue_depth(depth)
+        self._place(s, req)
+        return s, req
 
     def _splice_and_grow(self, s: int, req: _Request) -> bool:
         """Splice the longest cached prefix of `req`'s prompt into slot
@@ -2365,15 +2424,17 @@ class ContinuousScheduler:
         start its prefill. The slot stays `finished` on device — decode
         chunks skip it — until `_activate` flips it live; the prefill
         itself advances chunk-by-chunk in `_prefill_step`."""
-        # Close whichever wait span is open: first admission closes the
-        # "admission" span opened at the queue head; a re-admission
-        # after eviction closes the reopened "queue_wait".
-        if req.adm_span >= 0:
-            req.trace.end(req.adm_span)
-            req.adm_span = -1
+        # The "admission" span opened at the queue head stays open
+        # until the first token is read (_activate): it holds the
+        # prompt prep, the wait for pages, every prefill chunk and
+        # whatever ran between them. A re-admission after eviction
+        # closes the reopened "queue_wait" and opens an admission span
+        # of its own around the replayed prefill.
         if req.qw_span >= 0:
             req.trace.end(req.qw_span)
             req.qw_span = -1
+        if req.adm_span < 0:
+            req.adm_span = req.trace.begin("admission", replay=True)
         self.slots[s] = req
         req.activated = False
         self.finished[s] = True
@@ -2443,54 +2504,60 @@ class ContinuousScheduler:
         # contained by _run's catch-all (requests errored, pool reset).
         faults.fault_point("prefill_dispatch")
         hot_dispatch("scheduler._advance_prefill")
-        B1 = np.newaxis
-        off = req.prefill_pos
-        L = req.length
-        if self.prefill_chunk is None and off == 0:
-            # Cold single-shot: the original full-embeds program.
-            emb, end = req.embeds, L
-        elif self.prefill_chunk is None:
-            # Cached suffix in one dispatch, bucketed so it shares the
-            # cold path's compiled prefill shapes.
-            width = round_up_bucket(L - off)
-            emb = generate_lib.slice_embeds(
-                generate_lib.pad_embeds_for_chunks(req.embeds, width),
-                jnp.asarray(off, jnp.int32), width=width,
-            )
-            end = L
-        else:
-            width = self.prefill_chunk
-            if req.embeds_p is None:
-                req.embeds_p = generate_lib.pad_embeds_for_chunks(
-                    req.embeds, width
+        # The phase and the request's `prefill` span both end when the
+        # ENQUEUE returns: they mark a dispatch, not the device's work.
+        # The wait for this prompt's first token is `first_token`
+        # (_activate); queue head -> first token is the request's
+        # `admission` span, which the cost ledger's prefill_s reads.
+        with self._phase("prefill", "dispatch"):
+            B1 = np.newaxis
+            off = req.prefill_pos
+            L = req.length
+            if self.prefill_chunk is None and off == 0:
+                # Cold single-shot: the original full-embeds program.
+                emb, end = req.embeds, L
+            elif self.prefill_chunk is None:
+                # Cached suffix in one dispatch, bucketed so it shares the
+                # cold path's compiled prefill shapes.
+                width = round_up_bucket(L - off)
+                emb = generate_lib.slice_embeds(
+                    generate_lib.pad_embeds_for_chunks(req.embeds, width),
+                    jnp.asarray(off, jnp.int32), width=width,
                 )
-            emb = generate_lib.slice_embeds(
-                req.embeds_p, jnp.asarray(off, jnp.int32), width=width,
+                end = L
+            else:
+                width = self.prefill_chunk
+                if req.embeds_p is None:
+                    req.embeds_p = generate_lib.pad_embeds_for_chunks(
+                        req.embeds, width
+                    )
+                emb = generate_lib.slice_embeds(
+                    req.embeds_p, jnp.asarray(off, jnp.int32), width=width,
+                )
+                end = min(off + width, L)
+            pf = req.trace.begin(
+                "prefill", slot=s, start=off, tokens=end - off,
+                cached=req.spliced > 0, replay=req.replay > 0,
             )
-            end = min(off + width, L)
-        pf = req.trace.begin(
-            "prefill", slot=s, start=off, tokens=end - off,
-            cached=req.spliced > 0, replay=req.replay > 0,
-        )
-        sampled = self._profile_dispatch_begin()
-        t0 = time.monotonic()
-        t0_ns = trace_lib.now_ns()
-        with self.pipe._mesh_scope():
-            kv, tok0, key = generate_lib.paged_prefill(
-                self.pipe.params["llm"], self.cfg.llm,
-                emb,
-                jnp.asarray([end], np.int32),
-                jnp.asarray(self.bt[s][B1]),
-                self.kv_pages,
-                jnp.asarray([off], np.int32),
-                req.key0[B1],
-                jnp.asarray([req.temp], np.float32),
-                jnp.asarray([req.topp], np.float32),
-                jnp.asarray([req.topk], np.int32),
-                attn_impl=self.cfg.attn_impl,
-                compute_dtype=oryx.compute_dtype(self.cfg),
-            )
-        req.trace.end(pf)
+            sampled = self._profile_dispatch_begin()
+            t0 = time.monotonic()
+            t0_ns = trace_lib.now_ns()
+            with self.pipe._mesh_scope():
+                kv, tok0, key = generate_lib.paged_prefill(
+                    self.pipe.params["llm"], self.cfg.llm,
+                    emb,
+                    jnp.asarray([end], np.int32),
+                    jnp.asarray(self.bt[s][B1]),
+                    self.kv_pages,
+                    jnp.asarray([off], np.int32),
+                    req.key0[B1],
+                    jnp.asarray([req.temp], np.float32),
+                    jnp.asarray([req.topp], np.float32),
+                    jnp.asarray([req.topk], np.int32),
+                    attn_impl=self.cfg.attn_impl,
+                    compute_dtype=oryx.compute_dtype(self.cfg),
+                )
+            req.trace.end(pf)
         self.kv_pages = kv
         req.prefill_pos = end
         req.cost_prefill_tokens += end - off
@@ -2533,35 +2600,45 @@ class ContinuousScheduler:
         would make sampled streams depend on scheduling history, and
         break eviction replay)."""
         req.activated = True
-        self.tok[s] = int(np.asarray(tok0)[0])
-        self.lengths[s] = req.length
-        self.finished[s] = False
-        self.temp[s] = req.temp
-        self.top_p[s] = req.topp
-        self.top_k[s] = req.topk
-        self.recent[s] = -2
-        self.keys = self.keys.at[s].set(key[0])
-        if not req.ttft_done:
-            req.ttft_done = True
-            ttft = time.monotonic() - req.submit_time
-            self.metrics.observe(
-                "ttft_seconds", ttft, buckets=TTFT_BUCKETS,
-            )
-            req.handle.debug["ttft_s"] = ttft
-            if self.anomaly is not None:
-                self.anomaly.observe_ttft(ttft, request_id=req.trace.id)
-            req.handle.debug["admit_chunk"] = self.chunks_run
-        self.metrics.inc("admitted")
-        self._donate_prefix(s, req, req.length)
-        self._occupancy_gauge()
-        # tok0 is this slot's first generated token — process it now so
-        # a max_tokens=1 request never occupies a chunk. The chunk
-        # program re-emits tok0 as its first output (the scan step emits
-        # the token it was FED, dense-path semantics), so one extra
-        # replay skip keeps the stream exactly-once.
-        self._advance(s, [int(self.tok[s])])
-        if self.slots[s] is not None:
-            req.replay += 1
+        # The engine's SECOND blocking point (the first is the harvest):
+        # the prompt's last prefill chunk, and every dispatch enqueued
+        # before it, must finish before this read returns.
+        with self._phase("first_token", "blocked"):
+            self.tok[s] = int(np.asarray(tok0)[0])
+        if req.adm_span >= 0:
+            req.trace.end(req.adm_span)
+            req.adm_span = -1
+        with self._phase("emit"):
+            self.lengths[s] = req.length
+            self.finished[s] = False
+            self.temp[s] = req.temp
+            self.top_p[s] = req.topp
+            self.top_k[s] = req.topk
+            self.recent[s] = -2
+            self.keys = self.keys.at[s].set(key[0])
+            if not req.ttft_done:
+                req.ttft_done = True
+                ttft = time.monotonic() - req.submit_time
+                self.metrics.observe(
+                    "ttft_seconds", ttft, buckets=TTFT_BUCKETS,
+                )
+                req.handle.debug["ttft_s"] = ttft
+                if self.anomaly is not None:
+                    self.anomaly.observe_ttft(
+                        ttft, request_id=req.trace.id
+                    )
+                req.handle.debug["admit_chunk"] = self.chunks_run
+            self.metrics.inc("admitted")
+            self._donate_prefix(s, req, req.length)
+            self._occupancy_gauge()
+            # tok0 is this slot's first generated token — process it now so
+            # a max_tokens=1 request never occupies a chunk. The chunk
+            # program re-emits tok0 as its first output (the scan step emits
+            # the token it was FED, dense-path semantics), so one extra
+            # replay skip keeps the stream exactly-once.
+            self._advance(s, [int(self.tok[s])])
+            if self.slots[s] is not None:
+                req.replay += 1
 
     def _donate_prefix(self, s: int, req: _Request, tokens: int) -> None:
         """Index the full-page prefix of slot s's first `tokens` logical
@@ -2631,6 +2708,16 @@ class ContinuousScheduler:
                     )
                     break
 
+    @staticmethod
+    def _requeue_spans(req: _Request) -> None:
+        """A slot holder goes back to the queue head (eviction, engine
+        restart): an admission it was still in ends here, and it waits
+        again."""
+        if req.adm_span >= 0:
+            req.trace.end(req.adm_span)
+            req.adm_span = -1
+        req.qw_span = req.trace.begin("queue_wait", requeued=True)
+
     # obligations: _clear_slot, queue_depth, evicted
     def _evict(self, s: int) -> None:
         """Free slot s and requeue its request at the FRONT; replay
@@ -2644,7 +2731,7 @@ class ContinuousScheduler:
         req.prefill_pos = 0
         self._clear_slot(s)
         req.trace.event("evicted", slot=s, replay_tokens=req.processed)
-        req.qw_span = req.trace.begin("queue_wait", requeued=True)
+        self._requeue_spans(req)
         if self.journal is not None:
             self.journal.append(journal_lib.build_journal_event(
                 kind="evict", step=self.steps_run, slot=s,
@@ -2742,28 +2829,30 @@ class ContinuousScheduler:
         # held would serialize submit()/scrapes/debug reads on device
         # latency — the runtime twin of the static hot-path rule.
         hot_dispatch("scheduler._step_chunk")
-        sampled = self._profile_dispatch_begin()
-        numer = self._numerics_due()
-        t0 = time.monotonic()
-        t0_ns = trace_lib.now_ns()
-        with self.pipe._mesh_scope():
-            out = generate_lib.paged_decode_chunk(
-                self.pipe.params["llm"], self.cfg.llm, self.kv_pages,
-                jnp.asarray(self.bt),
-                jnp.asarray(self.tok),
-                jnp.asarray(self.lengths),
-                jnp.asarray(self.finished),
-                jnp.asarray(self.recent),
-                self.keys,
-                jnp.asarray(self.temp),
-                jnp.asarray(self.top_p),
-                jnp.asarray(self.top_k),
-                self.stop_sequences,
-                chunk=self.chunk, eos=self.cfg.generation.eos_token_id,
-                attn_impl=self.cfg.attn_impl,
-                compute_dtype=oryx.compute_dtype(self.cfg),
-                numerics=numer,
-            )
+        with self._phase("decode", "dispatch"):
+            sampled = self._profile_dispatch_begin()
+            numer = self._numerics_due()
+            t0 = time.monotonic()
+            t0_ns = trace_lib.now_ns()
+            with self.pipe._mesh_scope():
+                out = generate_lib.paged_decode_chunk(
+                    self.pipe.params["llm"], self.cfg.llm, self.kv_pages,
+                    jnp.asarray(self.bt),
+                    jnp.asarray(self.tok),
+                    jnp.asarray(self.lengths),
+                    jnp.asarray(self.finished),
+                    jnp.asarray(self.recent),
+                    self.keys,
+                    jnp.asarray(self.temp),
+                    jnp.asarray(self.top_p),
+                    jnp.asarray(self.top_k),
+                    self.stop_sequences,
+                    chunk=self.chunk,
+                    eos=self.cfg.generation.eos_token_id,
+                    attn_impl=self.cfg.attn_impl,
+                    compute_dtype=oryx.compute_dtype(self.cfg),
+                    numerics=numer,
+                )
         nstats = out[8] if numer else None
         (self.kv_pages, tok, lengths, finished, recent, self.keys,
          toks, fin) = out[:8]
@@ -2771,16 +2860,18 @@ class ContinuousScheduler:
             tok, lengths, finished, recent, toks, fin
         )
         dt = time.monotonic() - t0
-        dev_us = self._profile_dispatch_end(sampled, "decode", t0_ns)
-        self._record_numerics(nstats)
-        live = [
-            s for s, r in enumerate(self.slots)
-            if r is not None and r.activated
-        ]
-        self._finish_dispatch(
-            "decode", len(live), live, toks, t0_ns, dt, device_us=dev_us
-        )
-        self._occupancy_gauge()
+        with self._phase("emit"):
+            dev_us = self._profile_dispatch_end(sampled, "decode", t0_ns)
+            self._record_numerics(nstats)
+            live = [
+                s for s, r in enumerate(self.slots)
+                if r is not None and r.activated
+            ]
+            self._finish_dispatch(
+                "decode", len(live), live, toks, t0_ns, dt,
+                device_us=dev_us,
+            )
+            self._occupancy_gauge()
 
     def _finish_dispatch(
         self, kind: str, rows: int, live: list[int], toks, t0_ns, dt,
@@ -2947,18 +3038,21 @@ class ContinuousScheduler:
         result — callers measure dt AFTER this, or async dispatch makes
         the window (and the per-token histogram) cover only dispatch
         time, and the span<->xplane join would land the decode ops
-        outside every window. This is the engine's ONE deliberate sync
-        point per chunk (the harvest the chunk exists to amortize) —
-        anything else host-syncing on the step paths is a regression
-        the host-sync rule catches."""
+        outside every window. This is the engine's deliberate sync
+        point per chunk (the harvest the chunk exists to amortize).
+        There is a second one, per admission: the read of the first
+        token in `_activate` (phase `first_token`). Anything else
+        host-syncing on the step paths is a regression the host-sync
+        rule catches."""
         self.metrics.inc("harvest_total")
-        # oryxlint: off=host-sync
-        self.tok = np.asarray(tok).copy()
-        self.lengths = np.asarray(lengths).copy()
-        self.finished = np.asarray(finished).copy()
-        self.recent = np.asarray(recent).copy()
-        out = np.asarray(toks), np.asarray(fin)
-        # oryxlint: on=host-sync
+        with self._phase("harvest", "blocked"):
+            # oryxlint: off=host-sync
+            self.tok = np.asarray(tok).copy()
+            self.lengths = np.asarray(lengths).copy()
+            self.finished = np.asarray(finished).copy()
+            self.recent = np.asarray(recent).copy()
+            out = np.asarray(toks), np.asarray(fin)
+            # oryxlint: on=host-sync
         return out
 
     # hot-path
@@ -2995,7 +3089,8 @@ class ContinuousScheduler:
             if req.handle.cancelled:
                 self._cancel_slot(s, req, "mid-prefill")
         if any(r is not None and r.activated for r in self.slots):
-            self._ensure_capacity()  # may evict — recompute live below
+            with self._phase("housekeeping"):
+                self._ensure_capacity()  # may evict: live is recomputed
         live = [
             s for s, r in enumerate(self.slots)
             if r is not None and r.activated
@@ -3037,31 +3132,34 @@ class ContinuousScheduler:
         pf_span = -1
         pf_off = pf_len = 0
         if pf_req is not None:
-            pf_off, pf_len = pf_req.prefill_pos, pf_req.length
-            window = generate_lib.pack_prefill_window(
-                pf_req.embeds_np, pf_off, win_tokens
-            )
-            pf_span = pf_req.trace.begin(
-                "prefill", slot=pf_s, start=pf_off,
-                tokens=min(win_tokens, pf_len - pf_off),
-                cached=pf_req.spliced > 0, replay=pf_req.replay > 0,
-                ragged=True,
-            )
-            pfw = self.prefill_chunk if self.speculate else W
-            slot_c, len_c, active_c, key_c, temp_c, topp_c, topk_c = (
-                pf_req.pf_consts
-            )
-            pf_args = (
-                jnp.asarray(window),
-                slot_c,
-                jnp.asarray(pf_off, jnp.int32),
-                len_c,
-                active_c,
-                key_c,
-                temp_c,
-                topp_c,
-                topk_c,
-            )
+            # Packing the prompt's window is the prefill's host part;
+            # its enqueue is the fused dispatch below.
+            with self._phase("prefill"):
+                pf_off, pf_len = pf_req.prefill_pos, pf_req.length
+                window = generate_lib.pack_prefill_window(
+                    pf_req.embeds_np, pf_off, win_tokens
+                )
+                pf_span = pf_req.trace.begin(
+                    "prefill", slot=pf_s, start=pf_off,
+                    tokens=min(win_tokens, pf_len - pf_off),
+                    cached=pf_req.spliced > 0, replay=pf_req.replay > 0,
+                    ragged=True,
+                )
+                pfw = self.prefill_chunk if self.speculate else W
+                slot_c, len_c, active_c, key_c, temp_c, topp_c, topk_c = (
+                    pf_req.pf_consts
+                )
+                pf_args = (
+                    jnp.asarray(window),
+                    slot_c,
+                    jnp.asarray(pf_off, jnp.int32),
+                    len_c,
+                    active_c,
+                    key_c,
+                    temp_c,
+                    topp_c,
+                    topk_c,
+                )
         else:
             # Pure-decode shape class: zero prefill lanes (pf_width=0
             # is STATIC, so this is the second — and last — compiled
@@ -3077,71 +3175,75 @@ class ContinuousScheduler:
             # Host-side self-drafting BEFORE the dispatch (the drafter
             # needs the token history the device never holds); the
             # whole fleet's proposals then verify in the one forward.
-            drafts, dlen = self._propose_drafts(live)
-            with self.pipe._mesh_scope():
-                (self.kv_pages, tok, lengths, finished, self.keys,
-                 toks, n_new, acc, pf_tok0, pf_key) = (
-                    generate_lib.paged_spec_step(
+            with self._phase("decode", "dispatch"):
+                drafts, dlen = self._propose_drafts(live)
+                with self.pipe._mesh_scope():
+                    (self.kv_pages, tok, lengths, finished, self.keys,
+                     toks, n_new, acc, pf_tok0, pf_key) = (
+                        generate_lib.paged_spec_step(
+                            self.pipe.params["llm"], self.cfg.llm,
+                            self.kv_pages,
+                            jnp.asarray(self.bt),
+                            jnp.asarray(self.tok),
+                            jnp.asarray(self.lengths),
+                            jnp.asarray(self.finished),
+                            self.keys,
+                            jnp.asarray(self.temp),
+                            jnp.asarray(self.top_p),
+                            jnp.asarray(self.top_k),
+                            jnp.asarray(drafts),
+                            jnp.asarray(dlen),
+                            *pf_args,
+                            k=self.speculate, pf_width=pfw,
+                            eos=self.cfg.generation.eos_token_id,
+                            attn_impl=self.cfg.attn_impl,
+                            compute_dtype=dtype,
+                        )
+                    )
+            toks, n_new, acc = self._harvest_spec(
+                tok, lengths, finished, toks, n_new, acc
+            )
+            dt = time.monotonic() - t0
+            with self._phase("emit"):
+                dev_us = self._profile_dispatch_end(sampled, "spec", t0_ns)
+                if live:
+                    self.metrics.inc(
+                        "draft_proposed_total", int(dlen[live].sum())
+                    )
+                    self.metrics.inc(
+                        "draft_accepted_total", int(acc[live].sum())
+                    )
+                rows = len(live) * (1 + self.speculate) + (
+                    min(pfw, pf_len - pf_off) if pf_req is not None else 0
+                )
+                self._finish_dispatch(
+                    "spec", rows, live, toks, t0_ns, dt, n_new=n_new,
+                    device_us=dev_us,
+                )
+        else:
+            with self._phase("decode", "dispatch"):
+                numer = self._numerics_due() and bool(live)
+                with self.pipe._mesh_scope():
+                    out = generate_lib.paged_ragged_step(
                         self.pipe.params["llm"], self.cfg.llm,
                         self.kv_pages,
                         jnp.asarray(self.bt),
                         jnp.asarray(self.tok),
                         jnp.asarray(self.lengths),
                         jnp.asarray(self.finished),
+                        jnp.asarray(self.recent),
                         self.keys,
                         jnp.asarray(self.temp),
                         jnp.asarray(self.top_p),
                         jnp.asarray(self.top_k),
-                        jnp.asarray(drafts),
-                        jnp.asarray(dlen),
+                        self.stop_sequences,
                         *pf_args,
-                        k=self.speculate, pf_width=pfw,
+                        chunk=self.chunk, pf_width=pfw,
                         eos=self.cfg.generation.eos_token_id,
                         attn_impl=self.cfg.attn_impl,
                         compute_dtype=dtype,
+                        numerics=numer,
                     )
-                )
-            toks, n_new, acc = self._harvest_spec(
-                tok, lengths, finished, toks, n_new, acc
-            )
-            dt = time.monotonic() - t0
-            dev_us = self._profile_dispatch_end(sampled, "spec", t0_ns)
-            if live:
-                self.metrics.inc(
-                    "draft_proposed_total", int(dlen[live].sum())
-                )
-                self.metrics.inc(
-                    "draft_accepted_total", int(acc[live].sum())
-                )
-            rows = len(live) * (1 + self.speculate) + (
-                min(pfw, pf_len - pf_off) if pf_req is not None else 0
-            )
-            self._finish_dispatch(
-                "spec", rows, live, toks, t0_ns, dt, n_new=n_new,
-                device_us=dev_us,
-            )
-        else:
-            numer = self._numerics_due() and bool(live)
-            with self.pipe._mesh_scope():
-                out = generate_lib.paged_ragged_step(
-                    self.pipe.params["llm"], self.cfg.llm, self.kv_pages,
-                    jnp.asarray(self.bt),
-                    jnp.asarray(self.tok),
-                    jnp.asarray(self.lengths),
-                    jnp.asarray(self.finished),
-                    jnp.asarray(self.recent),
-                    self.keys,
-                    jnp.asarray(self.temp),
-                    jnp.asarray(self.top_p),
-                    jnp.asarray(self.top_k),
-                    self.stop_sequences,
-                    *pf_args,
-                    chunk=self.chunk, pf_width=pfw,
-                    eos=self.cfg.generation.eos_token_id,
-                    attn_impl=self.cfg.attn_impl,
-                    compute_dtype=dtype,
-                    numerics=numer,
-                )
             nstats = out[10] if numer else None
             (self.kv_pages, tok, lengths, finished, recent, self.keys,
              toks, fin, pf_tok0, pf_key) = out[:10]
@@ -3149,17 +3251,21 @@ class ContinuousScheduler:
                 tok, lengths, finished, recent, toks, fin
             )
             dt = time.monotonic() - t0
-            dev_us = self._profile_dispatch_end(sampled, "ragged", t0_ns)
-            self._record_numerics(nstats)
-            # Decode billing covers only slots live DURING the dispatch
-            # — a slot activated below joins the next dispatch, and its
-            # toks row this time was frozen filler.
-            rows = len(live) + (
-                min(W, pf_len - pf_off) if pf_req is not None else 0
-            )
-            self._finish_dispatch(
-                "ragged", rows, live, toks, t0_ns, dt, device_us=dev_us
-            )
+            with self._phase("emit"):
+                dev_us = self._profile_dispatch_end(
+                    sampled, "ragged", t0_ns
+                )
+                self._record_numerics(nstats)
+                # Decode billing covers only slots live DURING the
+                # dispatch — a slot activated below joins the next
+                # dispatch, and its toks row this time was frozen
+                # filler.
+                rows = len(live) + (
+                    min(W, pf_len - pf_off) if pf_req is not None else 0
+                )
+                self._finish_dispatch(
+                    "ragged", rows, live, toks, t0_ns, dt, device_us=dev_us
+                )
         # Prefill bookkeeping + activation (after harvest by design).
         if pf_req is not None:
             pf_req.trace.end(pf_span)
@@ -3238,7 +3344,8 @@ class ContinuousScheduler:
         # device cannot grow tables mid-flight); eviction under this
         # larger horizon is deterministic in journaled state, so replay
         # re-derives it exactly.
-        self._ensure_capacity(self._win * k_steps)
+        with self._phase("housekeeping"):
+            self._ensure_capacity(self._win * k_steps)
         live = [
             s for s, r in enumerate(self.slots)
             if r is not None and r.activated
@@ -3251,74 +3358,78 @@ class ContinuousScheduler:
         t0 = time.monotonic()
         t0_ns = trace_lib.now_ns()
         if self.speculate:
-            draft_ctx, draft_clen = self._build_draft_ctx(live)
-            with self.pipe._mesh_scope():
-                (self.kv_pages, tok, lengths, finished, self.keys,
-                 toks, n_new, acc) = generate_lib.paged_fused_spec_steps(
-                    self.pipe.params["llm"], self.cfg.llm, self.kv_pages,
-                    jnp.asarray(self.bt),
-                    jnp.asarray(self.tok),
-                    jnp.asarray(self.lengths),
-                    jnp.asarray(self.finished),
-                    self.keys,
-                    jnp.asarray(self.temp),
-                    jnp.asarray(self.top_p),
-                    jnp.asarray(self.top_k),
-                    self.drafter.device_params(),
-                    jnp.asarray(draft_ctx),
-                    jnp.asarray(draft_clen),
-                    k=self.speculate, k_steps=k_steps, eos=eos,
-                    attn_impl=self.cfg.attn_impl, compute_dtype=dtype,
-                    draft_apply=self.drafter.device_apply,
-                )
+            with self._phase("decode", "dispatch"):
+                draft_ctx, draft_clen = self._build_draft_ctx(live)
+                with self.pipe._mesh_scope():
+                    (self.kv_pages, tok, lengths, finished, self.keys,
+                     toks, n_new, acc) = generate_lib.paged_fused_spec_steps(
+                        self.pipe.params["llm"], self.cfg.llm, self.kv_pages,
+                        jnp.asarray(self.bt),
+                        jnp.asarray(self.tok),
+                        jnp.asarray(self.lengths),
+                        jnp.asarray(self.finished),
+                        self.keys,
+                        jnp.asarray(self.temp),
+                        jnp.asarray(self.top_p),
+                        jnp.asarray(self.top_k),
+                        self.drafter.device_params(),
+                        jnp.asarray(draft_ctx),
+                        jnp.asarray(draft_clen),
+                        k=self.speculate, k_steps=k_steps, eos=eos,
+                        attn_impl=self.cfg.attn_impl, compute_dtype=dtype,
+                        draft_apply=self.drafter.device_apply,
+                    )
             toks, n_new, acc = self._harvest_spec(
                 tok, lengths, finished, toks, n_new, acc
             )
             dt = time.monotonic() - t0
-            dev_us = self._profile_dispatch_end(
-                sampled, "fused_spec", t0_ns
-            )
-            # Draft economics: the device chain proposes k tokens for
-            # every row still decoding at that logical step (n_new==0
-            # marks a row that entered the step frozen — its masked
-            # lanes proposed nothing, same as the K=1 accounting).
-            self.metrics.inc(
-                "draft_proposed_total",
-                int(self.speculate * (n_new[live] > 0).sum()),
-            )
-            self.metrics.inc("draft_accepted_total", int(acc[live].sum()))
-            rows = len(live) * (1 + self.speculate)
-            self._finish_megastep(
-                "fused_spec", rows, live, toks, t0_ns, dt, k_steps,
-                n_new=n_new, device_us=dev_us,
-            )
-        else:
-            with self.pipe._mesh_scope():
-                (self.kv_pages, tok, lengths, finished, recent,
-                 self.keys, toks, fin) = generate_lib.paged_fused_steps(
-                    self.pipe.params["llm"], self.cfg.llm, self.kv_pages,
-                    jnp.asarray(self.bt),
-                    jnp.asarray(self.tok),
-                    jnp.asarray(self.lengths),
-                    jnp.asarray(self.finished),
-                    jnp.asarray(self.recent),
-                    self.keys,
-                    jnp.asarray(self.temp),
-                    jnp.asarray(self.top_p),
-                    jnp.asarray(self.top_k),
-                    self.stop_sequences,
-                    chunk=self.chunk, k_steps=k_steps, eos=eos,
-                    attn_impl=self.cfg.attn_impl, compute_dtype=dtype,
+            with self._phase("emit"):
+                dev_us = self._profile_dispatch_end(
+                    sampled, "fused_spec", t0_ns
                 )
+                # Draft economics: the device chain proposes k tokens for
+                # every row still decoding at that logical step (n_new==0
+                # marks a row that entered the step frozen — its masked
+                # lanes proposed nothing, same as the K=1 accounting).
+                self.metrics.inc(
+                    "draft_proposed_total",
+                    int(self.speculate * (n_new[live] > 0).sum()),
+                )
+                self.metrics.inc("draft_accepted_total", int(acc[live].sum()))
+                rows = len(live) * (1 + self.speculate)
+                self._finish_megastep(
+                    "fused_spec", rows, live, toks, t0_ns, dt, k_steps,
+                    n_new=n_new, device_us=dev_us,
+                )
+        else:
+            with self._phase("decode", "dispatch"):
+                with self.pipe._mesh_scope():
+                    (self.kv_pages, tok, lengths, finished, recent,
+                     self.keys, toks, fin) = generate_lib.paged_fused_steps(
+                        self.pipe.params["llm"], self.cfg.llm, self.kv_pages,
+                        jnp.asarray(self.bt),
+                        jnp.asarray(self.tok),
+                        jnp.asarray(self.lengths),
+                        jnp.asarray(self.finished),
+                        jnp.asarray(self.recent),
+                        self.keys,
+                        jnp.asarray(self.temp),
+                        jnp.asarray(self.top_p),
+                        jnp.asarray(self.top_k),
+                        self.stop_sequences,
+                        chunk=self.chunk, k_steps=k_steps, eos=eos,
+                        attn_impl=self.cfg.attn_impl, compute_dtype=dtype,
+                    )
             toks, fin = self._harvest_chunk(
                 tok, lengths, finished, recent, toks, fin
             )
             dt = time.monotonic() - t0
-            dev_us = self._profile_dispatch_end(sampled, "fused", t0_ns)
-            self._finish_megastep(
-                "fused", len(live), live, toks, t0_ns, dt, k_steps,
-                device_us=dev_us,
-            )
+            with self._phase("emit"):
+                dev_us = self._profile_dispatch_end(sampled, "fused", t0_ns)
+                self._finish_megastep(
+                    "fused", len(live), live, toks, t0_ns, dt, k_steps,
+                    device_us=dev_us,
+                )
         self._occupancy_gauge()
 
     def _finish_megastep(
@@ -3509,12 +3620,13 @@ class ContinuousScheduler:
         the finished vector + the EOS the accepted span carries). Same
         one-deliberate-sync-per-step contract."""
         self.metrics.inc("harvest_total")
-        # oryxlint: off=host-sync
-        self.tok = np.asarray(tok).copy()
-        self.lengths = np.asarray(lengths).copy()
-        self.finished = np.asarray(finished).copy()
-        out = np.asarray(toks), np.asarray(n_new), np.asarray(acc)
-        # oryxlint: on=host-sync
+        with self._phase("harvest", "blocked"):
+            # oryxlint: off=host-sync
+            self.tok = np.asarray(tok).copy()
+            self.lengths = np.asarray(lengths).copy()
+            self.finished = np.asarray(finished).copy()
+            out = np.asarray(toks), np.asarray(n_new), np.asarray(acc)
+            # oryxlint: on=host-sync
         return out
 
     def _occupancy_gauge(self) -> None:

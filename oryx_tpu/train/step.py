@@ -144,10 +144,10 @@ def train_step_fn(
     accum = jax.tree.leaves(batch)[0].shape[0]
     act_absmax = None
 
-    # named_scope: phase names land in the XLA op metadata, so xplane
-    # profiles (scripts/capture_trace.py) and the span<->device join can
-    # attribute device time to forward/backward vs optimizer — the
-    # device-side half of the trainer's host-side phase spans.
+    # named_scope: phase names land in the XLA op metadata, so a
+    # profiler capture can attribute device time to forward/backward
+    # vs optimizer — the device-side half of the trainer's host-side
+    # phases (oryx.train.*, in the same capture).
     if accum == 1:
         # No accumulation: skip the scan and its fp32 zeros buffer (a full
         # param-sized temp — ~17 GB/device for 34B on an 8-way mesh).

@@ -9,7 +9,8 @@ endpoint (`--metrics-port`):
   GET /metrics — oryx_train_* series: per-step loss / grad-norm / lr,
                  tokens/sec(/chip), MFU (the shared 6N model in
                  utils/flops.py — same arithmetic as bench.py), phase
-                 seconds (data / dispatch / sync / checkpoint), goodput
+                 seconds (data / h2d / dispatch / sync / log /
+                 checkpoint: the trainer's PhaseClock), goodput
                  accounting, HBM telemetry, process collectors, plus
                  the cross-source oryx_anomaly_total{kind=} counter.
   GET /healthz — process liveness.
@@ -110,7 +111,8 @@ class TrainTelemetry:
         self._phase = {
             k: r.counter(f"{k}_seconds_total")  # oryxlint: disable=metric-name
             for k in ("productive", "checkpoint", "restore",
-                      "data_wait", "dispatch", "device_sync")
+                      "data_wait", "h2d", "dispatch", "device_sync",
+                      "log")
         }
         self._t0 = time.perf_counter()
         self._ready = False
@@ -190,8 +192,10 @@ class TrainTelemetry:
         *,
         step_seconds: float,
         data_s: float = 0.0,
+        h2d_s: float = 0.0,
         dispatch_s: float = 0.0,
         sync_s: float = 0.0,
+        log_s: float = 0.0,
         checkpoint_s: float = 0.0,
         flops: float | None = None,
         lr: float | None = None,
@@ -222,8 +226,10 @@ class TrainTelemetry:
             self._skipped.inc()
         self._step_time.observe(step_seconds)
         self._phase["data_wait"].inc(max(0.0, data_s))
+        self._phase["h2d"].inc(max(0.0, h2d_s))
         self._phase["dispatch"].inc(max(0.0, dispatch_s))
         self._phase["device_sync"].inc(max(0.0, sync_s))
+        self._phase["log"].inc(max(0.0, log_s))
         if checkpoint_s > 0:
             self._phase["checkpoint"].inc(checkpoint_s)
             self._checkpoints.inc()

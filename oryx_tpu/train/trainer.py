@@ -28,6 +28,7 @@ from oryx_tpu.utils import trace as trace_lib
 from oryx_tpu.utils.anomaly import AnomalyThresholds
 from oryx_tpu.utils.checkpoint import CheckpointManager
 from oryx_tpu.utils.metrics import MetricLogger, rank0_print
+from oryx_tpu.utils.profiling import PhaseClock
 
 
 def validate_train_batch(cfg: OryxConfig, batch: dict) -> None:
@@ -138,8 +139,11 @@ class Trainer:
         self._lr_fn = make_schedule(cfg.train, cfg.train.learning_rate)
         # Per-step flight recorder (same Trace/Span model as serving):
         # each step records data / h2d / step_dispatch / device_sync /
-        # checkpoint_save spans, and the phase seconds also land in the
-        # MetricLogger record. stall_timeout arms a watchdog that dumps
+        # checkpoint_save spans. The same blocks are the step loop's
+        # phases (fit's PhaseClock: oryx.train.data / .h2d / .dispatch /
+        # .sync / .checkpoint, and .log for the rest of an iteration),
+        # whose seconds land in the MetricLogger record and the
+        # telemetry counters. stall_timeout arms a watchdog that dumps
         # thread stacks + the recorder tail when no step completes in
         # time (a hung collective, a wedged data loader, ...).
         self.tracer = tracer or trace_lib.Tracer(flight_recorder_size)
@@ -281,18 +285,18 @@ class Trainer:
 
         return {k: put(k, v) for k, v in batch.items()}
 
-    def _next_batch(self, batches: Iterator, tr) -> tuple[dict, Any]:
+    def _next_batch(self, batches: Iterator, tr,
+                    clock: PhaseClock) -> dict:
         """Fetch the next host batch with skip-and-requeue containment:
         a transient loader exception (injectable at the
         `data_loader_next` chaos site) logs, counts, and fetches the
         NEXT batch instead of killing the run; `max_data_faults`
         consecutive failures still abort loudly. StopIteration (data
-        genuinely exhausted) passes through untouched. Returns
-        (batch, data_span)."""
+        genuinely exhausted) passes through untouched."""
         consecutive = 0
         while True:
             try:
-                with tr.span("data") as sp_data:
+                with clock.phase("data"), tr.span("data"):
                     # corrupt=1 at this site poisons one float leaf
                     # with NaN instead of raising — driving the
                     # existing skip_nonfinite guard end-to-end.
@@ -300,7 +304,7 @@ class Trainer:
                     batch = next(batches)
                     if corrupt:
                         batch = _poison_one_float_leaf(batch)
-                    return batch, sp_data
+                    return batch
             except StopIteration:
                 raise
             # fault-boundary: transient data fault -> skip this fetch
@@ -337,6 +341,21 @@ class Trainer:
 
             batches = prefetcher = PrefetchIterator(batches, depth=prefetch)
         consecutive_skipped = 0
+        # Where the loop's wall time goes, in exclusive seconds: what is
+        # in no named phase is `log` (the metric record, telemetry,
+        # numerics, the checkpoint decision), so the phases between two
+        # syncs add up to the wall time between them. `data` is not
+        # "blocked": oryx.train.host then runs unbroken from the sync's
+        # return to the next dispatch.
+        phase_s = dict.fromkeys(
+            ("data", "h2d", "dispatch", "sync", "log", "checkpoint"), 0.0
+        )
+        seen = dict(phase_s)
+
+        def bill(name: str, seconds: float) -> None:
+            phase_s[name] += seconds
+
+        clock = PhaseClock("oryx.train", bill, base="log")
         if self.watchdog is not None and start < num_steps:
             self.watchdog.set_active(True)
         if self.telemetry is not None:
@@ -354,15 +373,13 @@ class Trainer:
                         "train_step", label=f"step {step_i + 1}"
                     )
                     try:
-                        host_batch, sp_data = self._next_batch(
-                            batches, tr
-                        )
+                        host_batch = self._next_batch(batches, tr, clock)
                     except StopIteration:
                         tr.finish(exhausted=True)
                         rank0_print("data exhausted; stopping")
                         break
                     validate_train_batch(cfg, host_batch)
-                    with tr.span("h2d"):
+                    with clock.phase("h2d"), tr.span("h2d"):
                         batch = self._device_batch(host_batch)
                     # Must use self._step (out_shardings pinned): the plain
                     # step_lib.train_step jit lets GSPMD reshard zero2's
@@ -372,7 +389,8 @@ class Trainer:
                         self.numerics_every > 0
                         and step_i % self.numerics_every == 0
                     )
-                    with tr.span("step_dispatch") as sp_disp:
+                    with clock.phase("dispatch", "dispatch"), \
+                            tr.span("step_dispatch"):
                         self.state, metrics = self._step(
                             self.state, batch, cfg=cfg, tx=self.tx,
                             sharding_mode=self.sharding_mode,
@@ -383,7 +401,8 @@ class Trainer:
                     # the compile on step 1). The step loop's ONE
                     # deliberate sync: everything downstream (logging,
                     # anomaly detection) needs host scalars.
-                    with tr.span("device_sync") as sp_sync:
+                    with clock.phase("sync", "blocked"), \
+                            tr.span("device_sync"):
                         host_metrics = jax.device_get(metrics)  # oryxlint: disable=host-sync
                     if self.watchdog is not None:
                         self.watchdog.beat()
@@ -401,10 +420,15 @@ class Trainer:
                         )
                     # Phase seconds ride the metric record too, so the
                     # JSONL/TensorBoard stream shows where a slow step
-                    # went without pulling the flight recorder.
-                    host_metrics["data_s"] = sp_data.dur_ns / 1e9
-                    host_metrics["dispatch_s"] = sp_disp.dur_ns / 1e9
-                    host_metrics["sync_s"] = sp_sync.dur_ns / 1e9
+                    # went without pulling the flight recorder. log_s
+                    # runs from the previous step's sync to this one's
+                    # (this iteration's own log phase has just begun).
+                    step_s = {
+                        f"{k}_s": phase_s[k] - seen[k]
+                        for k in ("data", "h2d", "dispatch", "sync", "log")
+                    }
+                    seen = dict(phase_s)
+                    host_metrics.update(step_s)
                     self.logger.log_step(step_i + 1, host_metrics)
                     if int(host_metrics.get("skipped", 0)):
                         consecutive_skipped += 1
@@ -422,11 +446,10 @@ class Trainer:
                             )
                     else:
                         consecutive_skipped = 0
-                    ckpt_s = 0.0
                     if (step_i + 1) % cfg.train.checkpoint_every == 0:
-                        with tr.span("checkpoint_save") as sp_ckpt:
+                        with clock.phase("checkpoint"), \
+                                tr.span("checkpoint_save"):
                             self.ckpt.save(step_i + 1, self.state)
-                        ckpt_s = sp_ckpt.dur_ns / 1e9
                     tr.finish(
                         step=step_i + 1,
                         skipped=int(host_metrics.get("skipped", 0)),
@@ -437,10 +460,10 @@ class Trainer:
                         self.telemetry.record_step(
                             step_i + 1, host_metrics,
                             step_seconds=time.perf_counter() - t_step0,
-                            data_s=sp_data.dur_ns / 1e9,
-                            dispatch_s=sp_disp.dur_ns / 1e9,
-                            sync_s=sp_sync.dur_ns / 1e9,
-                            checkpoint_s=ckpt_s,
+                            **step_s,
+                            checkpoint_s=(
+                                phase_s["checkpoint"] - seen["checkpoint"]
+                            ),
                             flops=telemetry_lib.batch_flops(
                                 cfg, host_batch
                             ),

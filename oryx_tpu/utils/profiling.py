@@ -42,10 +42,68 @@ def trace(logdir: str, *, host_tracer_level: int = 2) -> Iterator[None]:
         jax.profiler.stop_trace()
 
 
-def annotate(name: str):
-    """Context manager naming a region in the profiler timeline. Wrap host
-    dispatch of model phases (vit / compressor / decoder / data)."""
-    return jax.profiler.TraceAnnotation(name)
+class PhaseClock:
+    """Names where ONE thread's loop spends its wall time. Every phase
+    is written twice: as a `jax.profiler.TraceAnnotation`
+    `<prefix>.<name>` — a host event in the profiler's own trace, on
+    the device trace's clock, whenever a capture is running (an atomic
+    load when none is) — and as EXCLUSIVE seconds handed to
+    `record(name, seconds)`: entering a phase bills the time since the
+    last boundary to the phase that was running (`base` when none is
+    open), so a nested phase's seconds are not counted again in its
+    parent and the recorded seconds of a window add up to its wall
+    time.
+
+    `kind` places the one enclosing annotation, `<prefix>.host`: it
+    opens when a top-level "blocked" phase returns (the device has
+    drained, the host holds the pace) and closes after the next
+    "dispatch" phase (the device has work again) or before the next
+    blocked one. A device idle gap made of several short phases is
+    then covered by one named host event instead of none.
+
+    Bound to the thread that enters its phases (a TraceAnnotation
+    cannot cross threads); a loop that restarts on a new thread makes
+    a new clock."""
+
+    def __init__(self, prefix: str, record, *, base: str):
+        self._prefix = prefix + "."
+        self._record = record
+        self._open = [base]  # innermost last; base never closes
+        self._t = time.perf_counter()
+        self._host = None
+
+    def _switch(self) -> None:
+        now = time.perf_counter()
+        self._record(self._open[-1], now - self._t)
+        self._t = now
+
+    def _end_host(self) -> None:
+        if self._host is not None:
+            self._host.__exit__(None, None, None)
+            self._host = None
+
+    @contextlib.contextmanager
+    def phase(self, name: str, kind: str = "host") -> Iterator[None]:
+        """kind: "host" (the host works), "dispatch" (host work that
+        ends with a device program enqueued) or "blocked" (the host
+        waits: for the device, for data, for a request)."""
+        if kind == "blocked":
+            self._end_host()
+        self._switch()
+        self._open.append(name)
+        try:
+            with jax.profiler.TraceAnnotation(self._prefix + name):
+                yield
+        finally:
+            self._switch()
+            self._open.pop()
+            if kind == "dispatch":
+                self._end_host()
+            elif kind == "blocked" and len(self._open) == 1:
+                self._host = jax.profiler.TraceAnnotation(
+                    self._prefix + "host"
+                )
+                self._host.__enter__()
 
 
 @dataclasses.dataclass
@@ -59,13 +117,6 @@ class OpProfile:
     source: str
     xplane_path: str
     plane_names: list[str]
-    # Wall-clock ns bracketing the profiler session: xplane lines may
-    # stamp timestamps on a process-local clock, and
-    # xplane.attribute_device_time aligns them by anchoring the last
-    # event end at trace_end_ns when joining host spans to device
-    # events.
-    trace_start_ns: int = 0
-    trace_end_ns: int = 0
 
 
 def op_profile(
@@ -80,16 +131,13 @@ def op_profile(
 
     fn should already be compiled (call it once beforehand) — compile
     time inside the trace would swamp the profile."""
-    from oryx_tpu.utils import trace as trace_lib
     from oryx_tpu.utils import xplane
 
     with trace(trace_dir):
-        t_start = trace_lib.now_ns()
         out = None
         for _ in range(steps):
             out = fn(*args)
         jax.block_until_ready(out)
-        t_end = trace_lib.now_ns()
     files = xplane.find_xplane_files(trace_dir)
     if not files:
         raise RuntimeError(f"no xplane.pb written under {trace_dir}")
@@ -99,16 +147,13 @@ def op_profile(
         planes, n=top_n, plane_filter="TPU", line_filter="Ops"
     )
     if device:
-        return OpProfile(
-            device, "tpu_xla_ops", files[-1], names, t_start, t_end
-        )
+        return OpProfile(device, "tpu_xla_ops", files[-1], names)
     host = [
         xplane.Plane(p.name, [l for l in p.lines if "Modules" not in l.name])
         for p in planes
     ]
     return OpProfile(
-        xplane.top_ops(host, n=top_n), "host_fallback", files[-1], names,
-        t_start, t_end,
+        xplane.top_ops(host, n=top_n), "host_fallback", files[-1], names
     )
 
 
@@ -149,7 +194,7 @@ def attribute_capture(
     if not spans:
         spans = xplane.busiest_line_spans(
             planes, line_exclude="Modules",
-            session_end_ns=session_end_ns,
+            session_end_ns=session_end_ns, event_exclude="oryx.",
         )
         source = "host_fallback"
     out: dict = {"by_kind_us": {}, "other_us": 0, "source": source}

@@ -36,7 +36,10 @@ from typing import Any
 STEP_RECORD_KEYS = (
     "step",             # monotone step ordinal (1-based, never wraps)
     "ts_unix_s",        # wall-clock time the dispatch COMPLETED
-    "dur_s",            # step wall time (dispatch + harvest sync)
+    "dur_s",            # step wall time (dispatch + harvest sync); a
+                        # split-engine `prefill` record has no harvest,
+                        # so its dur_s is the ENQUEUE (the wait is the
+                        # engine's first_token phase)
     "kind",             # ragged | spec | prefill | decode
     "rows",             # valid query rows the dispatch carried
     "live_slots",       # slots decoding during the dispatch

@@ -12,10 +12,12 @@ Three pieces, all dependency-free stdlib:
   * ``Trace`` / ``Tracer`` — a thread-safe span tracer. A Trace is one
     request (serving) or one step (training): a flat append-only list
     of ``Span``s with parent indices, timed on a perf_counter clock
-    anchored to wall nanoseconds at import so span windows are directly
-    comparable to xplane device timestamps (utils/xplane.py). Exports
-    as Chrome trace-event JSON (loads in Perfetto / chrome://tracing)
-    and as structured JSONL.
+    anchored to wall nanoseconds at import. That clock is this
+    recorder's own: what must be read against a device trace (the
+    engine and trainer loops' phases) is written into the profiler's
+    trace instead (utils/profiling.PhaseClock). Exports as Chrome
+    trace-event JSON (loads in Perfetto / chrome://tracing) and as
+    structured JSONL.
   * a bounded in-memory **flight recorder** — the Tracer keeps the last
     N traces (in-flight and finished); ``GET /debug/requests`` serves
     its summaries and ``GET /debug/trace?id=`` one span tree.
@@ -51,7 +53,7 @@ from oryx_tpu.analysis.sanitizers import named_lock
 
 # perf_counter anchored to the wall clock once at import: spans get the
 # monotonicity of perf_counter AND absolute unix-ns starts comparable
-# across processes and to xplane device timestamps.
+# across processes (to the second or so that wall clocks agree).
 _WALL_ANCHOR_NS = time.time_ns()
 _PERF_ANCHOR = time.perf_counter()
 
@@ -328,9 +330,8 @@ class Tracer:
         return {"traceEvents": events, "displayTimeUnit": "ms"}
 
     def write_jsonl(self, path: str) -> int:
-        """Append every recorded trace as one JSON object per line;
-        returns the number written. The post-hoc xplane join
-        (scripts/capture_trace.py) reads this format back."""
+        """Append every recorded trace as one JSON object per line
+        (`Trace.to_dict`); returns the number written."""
         traces = self.traces()
         with open(path, "a") as f:
             for tr in traces:
@@ -388,41 +389,6 @@ def event(name: str, **args) -> None:
     tr = _active.get()
     if tr is not None:
         tr.event(name, **args)
-
-
-# ---------------------------------------------------------------------------
-# Post-hoc span <-> xplane join helpers
-# ---------------------------------------------------------------------------
-
-
-def windows_from_traces(
-    traces: Iterable[dict[str, Any]], span_name: str = "decode_chunk"
-) -> list[tuple[str, int, int]]:
-    """Flight-recorder JSONL/`to_dict` records → (label, start_ns,
-    end_ns) windows for `span_name` spans, the input shape
-    utils/xplane.attribute_device_time expects. Labels are
-    ``<trace-id>:<span-name>[<ordinal>]``."""
-    windows: list[tuple[str, int, int]] = []
-    for rec in traces:
-        n = 0
-        for s in rec.get("spans", []):
-            if s.get("name") != span_name or s.get("dur_ns") is None:
-                continue
-            windows.append((
-                f"{rec.get('id', '?')}:{span_name}[{n}]",
-                int(s["start_ns"]),
-                int(s["start_ns"]) + int(s["dur_ns"]),
-            ))
-            n += 1
-    return windows
-
-
-def windows_from_jsonl(
-    path: str, span_name: str = "decode_chunk"
-) -> list[tuple[str, int, int]]:
-    with open(path) as f:
-        recs = [json.loads(line) for line in f if line.strip()]
-    return windows_from_traces(recs, span_name)
 
 
 # ---------------------------------------------------------------------------

@@ -16,12 +16,13 @@ Field numbers follow tsl/profiler/protobuf/xplane.proto:
   XStat.metadata_id=1 .uint64_value=3 .int64_value=4.
 
 Timestamps: an event's absolute start is line.timestamp_ns +
-event.offset_ps/1000 (unix-epoch ns, the same clock utils/trace.py
-anchors host spans to) — which is what lets attribute_device_time()
-join device op time back onto host-side decode-chunk/step spans.
+event.offset_ps/1000. Host work is put on that clock by the program
+itself: the engine and trainer loops' phases are
+`jax.profiler.TraceAnnotation`s (utils/profiling.PhaseClock), so a
+capture holds them in its host plane beside the device's ops.
 
 No dependency on tensorflow or protobuf. Used by
-scripts/capture_trace.py for the on-chip "profile, iterate" loop.
+utils/profiling.py (op_profile, the DeviceTimeSampler).
 """
 
 from __future__ import annotations
@@ -299,6 +300,7 @@ def busiest_line_spans(
     line_filter: str = "",
     line_exclude: str = "",
     session_end_ns: int = 0,
+    event_exclude: str = "",
 ) -> list[tuple[int, int]]:
     """The merged busy intervals (epoch ns) of the BUSIEST matching
     line — precomputed ONCE per capture; per-window attribution is
@@ -312,9 +314,12 @@ def busiest_line_spans(
     busiest line (rather than summing lines) keeps the host-event
     fallback honest — host captures carry one line per python thread
     and summing them would charge idle threads' tracer overhead as
-    device time. Clock alignment follows attribute_device_time: epoch
-    timestamps pass through, relative planes anchor on the file's own
-    profile_start_time stat, else on session_end_ns."""
+    device time. Clock alignment: epoch timestamps pass through,
+    relative planes anchor on the file's own profile_start_time stat,
+    else on session_end_ns. Events whose name starts with
+    `event_exclude` are left out: the program's own phase annotations
+    (`oryx.`, utils/profiling.PhaseClock) tile their thread's line and
+    are not work the runtime did."""
     start_anchor = profile_start_time_ns(planes)
     best: list[tuple[int, int]] = []
     best_total = 0
@@ -340,6 +345,8 @@ def busiest_line_spans(
                 (base + ev.offset_ps // 1000,
                  base + (ev.offset_ps + ev.duration_ps) // 1000)
                 for ev in line.events
+                if not (event_exclude
+                        and ev.name.startswith(event_exclude))
             ])
             total = sum(e - s for s, e in merged)
             if total > best_total:
@@ -409,73 +416,3 @@ def chrome_trace(planes: list[Plane], limit: int = 50000) -> dict:
         "displayTimeUnit": "ms",
         "truncated": truncated,
     }
-
-
-def attribute_device_time(
-    planes: list[Plane],
-    windows: list[tuple[str, int, int]],
-    plane_filter: str = "",
-    line_filter: str = "",
-    session_end_ns: int = 0,
-) -> dict[str, int]:
-    """Attribute device-event time onto host-side span windows.
-
-    windows: (label, start_ns, end_ns) in unix-epoch ns — e.g. the
-    decode-chunk / train-step spans a utils/trace.py flight recorder
-    produced (trace.windows_from_traces). Each matching device event is
-    credited, by its midpoint, to the window containing it; events
-    outside every window land in "_unattributed". Returns
-    label -> total duration_ps. Windows with zero matching events still
-    appear (value 0), so a run whose clocks don't line up reads as
-    all-unattributed instead of silently empty.
-
-    Relative (non-epoch) line timestamps are offsets from the
-    profiler-session start, which the file itself records (the "Task
-    Environment" plane's profile_start_time stat) — that is the
-    preferred anchor. session_end_ns (wall-clock ns at
-    jax.profiler.stop_trace; profiling.op_profile records it as
-    OpProfile.trace_end_ns) is the fallback for writers without the
-    stat: the plane's last event end is anchored at it. Epoch-stamped
-    planes need no alignment.
-    """
-    totals: dict[str, int] = {label: 0 for label, _, _ in windows}
-    totals["_unattributed"] = 0
-    spans = sorted(windows, key=lambda w: w[1])
-    start_anchor = profile_start_time_ns(planes)
-    for plane in planes:
-        if plane_filter and plane_filter not in plane.name:
-            continue
-        relative = any(
-            line.timestamp_ns < _EPOCH_THRESHOLD_NS
-            for line in plane.lines if line.events
-        )
-        shift = 0
-        if relative:
-            shift = start_anchor or _plane_shift_ns(
-                plane, session_end_ns
-            )
-        for line in plane.lines:
-            if line_filter and line_filter not in line.name:
-                continue
-            base = line.timestamp_ns + shift
-            for ev in line.events:
-                mid_ns = base + (
-                    ev.offset_ps + ev.duration_ps // 2
-                ) // 1000
-                hits = [
-                    label for label, t0, t1 in spans
-                    if t0 <= mid_ns < t1
-                ]
-                if not hits:
-                    totals["_unattributed"] += ev.duration_ps
-                    continue
-                # Overlapping windows split the credit: the scheduler
-                # stamps one shared decode dispatch onto EVERY live
-                # request, so identical windows are the normal case in
-                # a live-recorder join — first-match-wins would hand
-                # all device time to one request and 0 to the rest.
-                share = ev.duration_ps // len(hits)
-                for label in hits:
-                    totals[label] += share
-                totals[hits[0]] += ev.duration_ps - share * len(hits)
-    return totals

@@ -1,0 +1,285 @@
+"""The host's time, named (PR 24): the engine and trainer loops' phases
+(utils/profiling.PhaseClock) as exclusive seconds in /metrics and as
+host events in a profiler capture, read back with the benchmark's own
+trace reader; and `prefill_s`, which now is the `admission` span."""
+
+import dataclasses
+import statistics
+import time
+
+import numpy as np
+import pytest
+
+import jax
+
+from benchmark import trace as bench_trace
+from oryx_tpu import config as cfg_lib
+from oryx_tpu.models import oryx
+from oryx_tpu.serve.pipeline import OryxInference
+from oryx_tpu.serve.scheduler import ENGINE_PHASES, ContinuousScheduler
+from oryx_tpu.train.trainer import Trainer
+from oryx_tpu.utils import faults
+from oryx_tpu.utils.metrics import ServingMetrics
+from oryx_tpu.utils.profiling import PhaseClock
+from test_scheduler import FakeTokenizer
+from test_trainer_modes import _batch as train_batch
+from test_trainer_modes import _cfg as train_cfg
+
+TRAIN_PHASES = ("data", "h2d", "dispatch", "sync", "log")
+ENGINES = {
+    "split": dict(prefill_chunk=16),
+    "ragged": dict(prefill_chunk=16, ragged=True),
+}
+
+
+@pytest.fixture(scope="module")
+def pipe():
+    cfg = cfg_lib.oryx_tiny()
+    params = oryx.init_params(cfg, jax.random.key(0))
+    return OryxInference(FakeTokenizer(), params, cfg)
+
+
+def requests():
+    """Short and long prompts (the long one takes several prefill
+    chunks of 16) and one image, so every engine phase has work."""
+    img = np.random.default_rng(7).integers(
+        0, 255, size=(40, 56, 3), dtype=np.uint8
+    )
+    return [
+        ({"question": "hello there"}, 6),
+        ({"question": "tell me more about " + "this and that " * 5}, 9),
+        ({"question": "what is this?", "images": [img]}, 5),
+        ({"question": "what now?"}, 7),
+    ]
+
+
+def run_engine(pipe, engine: str, *, idle_s: float = 0.0):
+    """One scheduler's life: submit, start, collect, close. Returns
+    (replies, phase seconds, the loop's wall seconds, handles)."""
+    metrics = ServingMetrics()
+    sched = ContinuousScheduler(
+        pipe, num_slots=2, page_size=16, chunk=4, max_ctx=512,
+        metrics=metrics, autostart=False, **ENGINES[engine],
+    )
+    handles = [sched.submit(req, cap, None) for req, cap in requests()]
+    t0 = time.perf_counter()
+    sched.start()
+    replies = [h.result(timeout=600) for h in handles]
+    time.sleep(idle_s)
+    sched.close()  # joins the engine thread: its last phase is billed
+    wall = time.perf_counter() - t0
+    fam = metrics.registry.existing("engine_phase_seconds_total")
+    seconds = {p: fam.labels(phase=p).value for p in ENGINE_PHASES}
+    return replies, seconds, wall, handles
+
+
+# ---- the primitive -------------------------------------------------------
+
+
+def test_phase_seconds_are_exclusive_and_add_up():
+    got = {}
+    t0 = time.perf_counter()
+    clock = PhaseClock(
+        "oryx.test", lambda n, s: got.__setitem__(n, got.get(n, 0) + s),
+        base="rest",
+    )
+    with clock.phase("outer"):
+        time.sleep(0.02)
+        with clock.phase("inner", "blocked"):
+            time.sleep(0.03)
+        time.sleep(0.01)
+    with clock.phase("last", "dispatch"):
+        pass
+    wall = time.perf_counter() - t0
+    # a nested phase's seconds are not counted again in its parent ...
+    assert got["inner"] == pytest.approx(0.03, abs=0.008)
+    assert got["outer"] == pytest.approx(0.03, abs=0.008)
+    # ... and with the base phase everything adds up to the wall time
+    assert set(got) == {"rest", "outer", "inner", "last"}
+    assert sum(got.values()) == pytest.approx(wall, abs=1e-3)
+
+
+def test_a_phase_with_no_capture_running_costs_microseconds():
+    clock = PhaseClock("oryx.test", lambda n, s: None, base="rest")
+    cost = []
+    for _ in range(1000):
+        t = time.perf_counter()
+        with clock.phase("x"):
+            pass
+        cost.append(time.perf_counter() - t)
+    assert statistics.median(cost) < 50e-6
+
+
+# ---- (a) the counters are exhaustive -------------------------------------
+
+
+@pytest.fixture(scope="module")
+def plain_runs(pipe):
+    return {e: run_engine(pipe, e, idle_s=0.5) for e in ENGINES}
+
+
+@pytest.mark.parametrize("engine", list(ENGINES))
+def test_engine_phase_seconds_add_up_to_the_loops_wall_time(
+    plain_runs, engine
+):
+    _, seconds, wall, _ = plain_runs[engine]
+    # every phase of the table ran on this path (idle: the wait before
+    # close; prompt_prep / embed: the image; first_token: each admission)
+    assert all(seconds[p] > 0 for p in ENGINE_PHASES), seconds
+    assert sum(seconds.values()) == pytest.approx(wall, rel=0.02)
+
+
+# ---- (b) the phases are in a profiler capture ----------------------------
+
+
+@pytest.fixture(scope="module")
+def captured(pipe, tmp_path_factory):
+    """ONE capture on the CPU around a split-engine run and three
+    trainer steps, read back with the benchmark's reader."""
+    tmp = tmp_path_factory.mktemp("phases")
+    cfg = train_cfg(tmp, "ckpt")
+    cfg = dataclasses.replace(
+        cfg, train=dataclasses.replace(cfg.train, num_train_steps=3)
+    )
+    batch = train_batch(cfg)
+    trainer = Trainer(cfg, sharding_mode="fsdp")
+    opts = jax.profiler.ProfileOptions()
+    opts.python_tracer_level = 0  # as the benchmark captures
+    jax.profiler.start_trace(str(tmp / "trace"), profiler_options=opts)
+    try:
+        replies, *_ = run_engine(pipe, "split")
+        trainer.fit(iter([batch] * 3), num_steps=3, resume=False,
+                    prefetch=0)
+    finally:
+        jax.profiler.stop_trace()
+        trainer.close()
+    planes = bench_trace.parse_xspace(
+        bench_trace.find_xplane_files(str(tmp / "trace"))[-1]
+    )
+    stats = next(p.stats for p in planes if "profile_start_time" in p.stats)
+    length_ps = 1000 * (
+        stats["profile_stop_time"] - stats["profile_start_time"]
+    )
+    spans = [s for s in bench_trace.host_span_list(planes)
+             if s[2].startswith("oryx.")]
+    return {"replies": replies, "spans": spans, "length_ps": length_ps}
+
+
+def _nested(spans) -> bool:
+    """Any two events of one thread are disjoint or one holds the
+    other."""
+    spans = sorted(spans, key=lambda s: (s[0], -s[1]))
+    stack = []
+    for s, e, _ in spans:
+        while stack and stack[-1] <= s:
+            stack.pop()
+        if stack and e > stack[-1]:
+            return False
+        stack.append(e)
+    return True
+
+
+@pytest.mark.parametrize("prefix,phases", [
+    ("oryx.engine.", tuple(p for p in ENGINE_PHASES if p != "idle")
+     + ("host",)),
+    ("oryx.train.", TRAIN_PHASES[:-1] + ("host",)),  # log has no block
+])
+def test_a_capture_holds_every_phase_inside_its_bounds_and_nested(
+    captured, prefix, phases
+):
+    mine = [s for s in captured["spans"] if s[2].startswith(prefix)]
+    assert {prefix + p for p in phases} <= {name for _, _, name in mine}
+    assert all(0 <= s < e <= captured["length_ps"] for s, e, _ in mine)
+    assert _nested(mine)
+
+
+def test_a_gap_between_two_dispatches_is_labelled_with_an_engine_phase(
+    captured
+):
+    spans = captured["spans"]
+    harvests = sorted(s for s in spans if s[2] == "oryx.engine.harvest")
+    enqueues = sorted(s for s in spans if s[2] in (
+        "oryx.engine.prefill", "oryx.engine.decode"))
+    assert len(harvests) >= 2
+    labels = []
+    for _, h_end, _ in harvests[:-1]:
+        # the device would idle from the harvest's return to the next
+        # enqueue: made of emit, housekeeping, admit ... each too short
+        # to cover it alone
+        nxt = min(e for s, e, _ in enqueues if s >= h_end)
+        labels.append(bench_trace._covering_span(spans, h_end, nxt))
+    assert all(label.startswith("oryx.engine.") for label in labels), labels
+    assert "oryx.engine.host" in labels
+
+
+# ---- (d) tracing does not change a token ---------------------------------
+
+
+def test_replies_are_token_identical_with_a_capture_running(
+    pipe, plain_runs, captured
+):
+    plain = plain_runs["split"][0]
+    assert captured["replies"] == plain
+    for (req, cap), (reply, _, usage) in zip(requests(), plain):
+        assert usage[1] == cap
+        if "images" not in req:
+            assert reply == pipe.chat(req["question"], max_new_tokens=cap)
+
+
+# ---- (c) prefill_s is the admission span ---------------------------------
+
+
+def _long_request_cost(pipe, *, resident: bool):
+    """prefill_s of a 4-chunk prompt, admitted alone or behind a
+    resident stream whose decode chunks (each 50 ms slower by an
+    injected delay) run between its prefill chunks."""
+    metrics = ServingMetrics()
+    sched = ContinuousScheduler(
+        pipe, num_slots=2, page_size=16, chunk=4, max_ctx=512,
+        metrics=metrics, autostart=False, prefill_chunk=16,
+        prefix_cache=False,
+    )
+    handles = []
+    if resident:
+        handles.append(sched.submit({"question": "hi"}, 40, None))
+    long_q = "tell me more about " + "this and that " * 3
+    handles.append(sched.submit({"question": long_q}, 2, None))
+    faults.configure("decode_dispatch:delay=0.05")
+    try:
+        sched.start()
+        for h in handles:
+            h.result(timeout=600)
+    finally:
+        faults.reset()
+        sched.close()
+    return handles[-1], handles[-1].trace, metrics
+
+
+def test_prefill_s_is_queue_head_to_first_token(pipe):
+    h, tr, metrics = _long_request_cost(pipe, resident=False)
+    by = tr.span_seconds()
+    chunks = [s for s in tr.spans if s.name == "prefill"]
+    assert len(chunks) >= 3
+    cost = h.debug["cost"]
+    # the ledger and the histogram read the admission span ...
+    assert cost["prefill_s"] == pytest.approx(by["admission"], abs=2e-6)
+    hist = metrics.registry.existing("request_prefill_seconds").labels()
+    assert (hist.total, hist.sum) == (1, cost["prefill_s"])
+    # ... which holds every prefill enqueue and ends with the first token
+    assert cost["prefill_s"] >= by["prefill"]
+    assert cost["prefill_s"] <= h.debug["ttft_s"]
+    (adm,) = [s for s in tr.spans if s.name == "admission"]
+    assert all(
+        adm.start_ns <= c.start_ns
+        and c.start_ns + c.dur_ns <= adm.start_ns + adm.dur_ns
+        for c in chunks
+    )
+
+    # a decode chunk between two of its chunks makes it longer: the
+    # enqueue-timed `prefill` spans could not see that
+    h2, tr2, _ = _long_request_cost(pipe, resident=True)
+    between = len([s for s in tr2.spans if s.name == "prefill"]) - 1
+    assert h2.debug["cost"]["prefill_s"] >= (
+        cost["prefill_s"] + 0.05 * between * 0.9
+    )
+    assert tr2.span_seconds()["prefill"] < 0.05 * between

@@ -99,7 +99,7 @@ def test_contextvar_propagation_and_noop():
     assert seen == [None]
 
 
-def test_jsonl_roundtrip_and_windows(tmp_path):
+def test_jsonl_roundtrip(tmp_path):
     tracer = trace_lib.Tracer()
     tr = tracer.start_trace("request", id="rid1")
     tr.add_complete("decode_chunk", 1_000, 500)
@@ -108,10 +108,11 @@ def test_jsonl_roundtrip_and_windows(tmp_path):
     tr.finish()
     path = tmp_path / "flight.jsonl"
     assert tracer.write_jsonl(str(path)) == 1
-    windows = trace_lib.windows_from_jsonl(str(path))
-    assert windows == [
-        ("rid1:decode_chunk[0]", 1_000, 1_500),
-        ("rid1:decode_chunk[1]", 2_000, 2_700),
+    (rec,) = [json.loads(line) for line in path.read_text().splitlines()]
+    assert rec["id"] == "rid1" and rec["done"]
+    assert [(s["name"], s["start_ns"], s["dur_ns"]) for s in rec["spans"]] == [
+        ("decode_chunk", 1_000, 500), ("decode_chunk", 2_000, 700),
+        ("emission", 3_000, 10),
     ]
 
 

@@ -132,13 +132,16 @@ def test_goodput_attribution_unit():
     tel.record_restore(2.0)
     tel.record_step(
         1, {"loss": 1.0, "num_tokens": 100}, step_seconds=1.0,
-        data_s=0.2, dispatch_s=0.1, sync_s=0.6, checkpoint_s=0.25,
+        data_s=0.2, h2d_s=0.04, dispatch_s=0.1, sync_s=0.6, log_s=0.01,
+        checkpoint_s=0.25,
     )
     r = tel.registry
     assert r.get("productive_seconds_total") == pytest.approx(0.75)
     assert r.get("checkpoint_seconds_total") == pytest.approx(0.25)
     assert r.get("restore_seconds_total") == pytest.approx(2.0)
     assert r.get("data_wait_seconds_total") == pytest.approx(0.2)
+    assert r.get("h2d_seconds_total") == pytest.approx(0.04)
+    assert r.get("log_seconds_total") == pytest.approx(0.01)
     assert r.get("checkpoints_total") == 1
     ratio = r.get("goodput_ratio")
     assert 0 < ratio <= 1.0

@@ -144,8 +144,11 @@ def test_trainer_step_traces_and_phase_metrics(tmp_path):
     assert all(s.dur_ns is not None for s in tr.spans)
 
     rec = json.loads(mpath.read_text().splitlines()[-1])
-    for key in ("data_s", "dispatch_s", "sync_s"):
+    for key in ("data_s", "h2d_s", "dispatch_s", "sync_s", "log_s"):
         assert key in rec and rec[key] >= 0
+    # the record's phases are the loop's time from the previous sync
+    # (here: fit's start) to this step's: they cannot exceed the step
+    assert 0 < rec["h2d_s"] + rec["dispatch_s"] + rec["sync_s"]
     # Chrome export of a step trace is loadable JSON with X events.
     body = t.tracer.chrome_trace([tr])
     assert any(e.get("ph") == "X" for e in body["traceEvents"])

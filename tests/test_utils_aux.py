@@ -24,14 +24,19 @@ def test_step_timer_rolls():
     assert len(t._times) == 3  # window bound
 
 
-def test_annotate_and_trace_smoke(tmp_path):
+def test_phase_annotation_smoke():
     import jax.numpy as jnp
 
     from oryx_tpu.utils import profiling
 
-    with profiling.annotate("unit-test-region"):
+    billed = []
+    clock = profiling.PhaseClock(
+        "oryx.test", lambda name, s: billed.append(name), base="rest"
+    )
+    with clock.phase("unit-test-region"):
         x = jnp.ones((4,)) + 1
     assert float(x.sum()) == 8.0
+    assert billed == ["rest", "unit-test-region"]
 
 
 def test_metric_logger_writes_jsonl(tmp_path):

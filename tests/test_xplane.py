@@ -207,107 +207,3 @@ def test_event_offsets_and_line_timestamps(tmp_path):
     path2.write_bytes(_field(1, 2, old))
     ln2 = xplane.parse_xspace(str(path2))[0].lines[0]
     assert ln2.timestamp_ns == 0 and ln2.events[0].offset_ps == 0
-
-
-def test_attribute_device_time_midpoint_rule():
-    """Events land in the window containing their midpoint; outside
-    events land in _unattributed; empty windows still appear. Line
-    timestamps here are epoch-scale (a TPU device plane), so no
-    alignment shift applies."""
-    T0 = 1_700_000_000_000_000_000  # epoch ns
-    planes = [xplane.Plane("/device:TPU:0", [xplane.Line(
-        "XLA Ops",
-        events=[
-            # offsets/durations in ps: a mid = T0+1_000ns,
-            # b mid = T0+5_000ns, c mid = T0+91_000ns.
-            xplane.Event("a", duration_ps=2_000_000, offset_ps=0),
-            xplane.Event("b", duration_ps=2_000_000, offset_ps=4_000_000),
-            xplane.Event("c", duration_ps=2_000_000, offset_ps=90_000_000),
-        ],
-        timestamp_ns=T0,
-    )])]
-    windows = [
-        ("w1", T0, T0 + 2_000),          # catches a
-        ("w2", T0 + 4_000, T0 + 6_000),  # catches b
-        ("empty", T0 + 40_000, T0 + 41_000),
-    ]
-    got = xplane.attribute_device_time(
-        planes, windows, plane_filter="TPU", line_filter="Ops"
-    )
-    assert got == {
-        "w1": 2_000_000, "w2": 2_000_000, "empty": 0,
-        "_unattributed": 2_000_000,
-    }
-    # Overlapping (here: identical) windows SPLIT the credit — the
-    # scheduler stamps one shared decode dispatch on every live
-    # request, so this is the normal live-join case; first-match-wins
-    # would hand all device time to one request and zero to the rest.
-    shared = [("r1", T0, T0 + 2_000), ("r2", T0, T0 + 2_000)]
-    got2 = xplane.attribute_device_time(
-        planes, shared, plane_filter="TPU", line_filter="Ops"
-    )
-    assert got2["r1"] == got2["r2"] == 1_000_000
-    assert got2["_unattributed"] == 4_000_000
-
-
-def test_attribute_device_time_relative_timeline_aligns_on_end():
-    """A plane stamped with a process-local clock (tiny timestamps) is
-    aligned by anchoring its last event end at session_end_ns."""
-    T0 = 1_700_000_000_000_000_000
-    planes = [xplane.Plane("/host:CPU", [xplane.Line(
-        "python",
-        events=[
-            xplane.Event("step", duration_ps=2_000_000, offset_ps=0),
-            # Last event ends at rel 10_000ns + (8e6+2e6)/1e3 ns = 20_000.
-            xplane.Event("tail", duration_ps=2_000_000, offset_ps=8_000_000),
-        ],
-        timestamp_ns=10_000,  # clearly not epoch
-    )])]
-    # session end T0+20_000 -> shift maps rel 20_000 -> T0+20_000:
-    # "step" mid rel 11_000 -> T0+11_000.
-    got = xplane.attribute_device_time(
-        planes, [("w", T0 + 10_000, T0 + 12_000)],
-        session_end_ns=T0 + 20_000,
-    )
-    assert got == {"w": 2_000_000, "_unattributed": 2_000_000}
-    # No anchor given: nothing lines up, everything lands unattributed
-    # (reported, not silently dropped).
-    got0 = xplane.attribute_device_time(
-        planes, [("w", T0 + 10_000, T0 + 12_000)]
-    )
-    assert got0["w"] == 0 and got0["_unattributed"] == 4_000_000
-
-
-def test_span_xplane_join_smoke(tmp_path):
-    """CPU smoke of the capture_trace.py loop-closer: host spans from
-    utils/trace.py joined against a REAL jax profiler trace — the
-    recorded host-plane events must land inside the span windows (the
-    clocks genuinely line up)."""
-    import jax
-    import jax.numpy as jnp
-
-    from oryx_tpu.utils import trace as trace_lib
-
-    f = jax.jit(lambda x: jnp.sum(x @ x))
-    x = jnp.ones((128, 128))
-    jax.device_get(f(x))  # compile outside the trace
-    tracer = trace_lib.Tracer()
-    tr = tracer.start_trace("profile", id="smoke")
-    with jax.profiler.trace(str(tmp_path)):
-        for _ in range(3):
-            with tr.span("train_step"):
-                jax.device_get(f(x))
-    tr.finish()
-    files = xplane.find_xplane_files(str(tmp_path))
-    assert files
-    planes = xplane.parse_xspace(files[-1])
-    # The file is self-anchoring: the Task Environment plane's
-    # profile_start_time stat (epoch ns) rebases relative timelines.
-    assert xplane.profile_start_time_ns(planes) > 10**15
-    windows = trace_lib.windows_from_traces([tr.to_dict()], "train_step")
-    assert len(windows) == 3
-    got = xplane.attribute_device_time(planes, windows)
-    # EVERY step window catches device/host event time — the clocks
-    # genuinely line up, not just approximately overlap.
-    for label, _, _ in windows:
-        assert got[label] > 0, got
