@@ -109,7 +109,14 @@ def init_paged_kv_cache(
     planes: codes + per-page scale blocks, quantize-on-write /
     dequantize-in-the-page-walk — roughly doubling resident KV tokens
     per HBM byte; `dtype` then names the dequant target the kernels
-    multiply out into."""
+    multiply out into.
+
+    Stored layout: every leaf is [L, P, page, ...] (codes and, on a
+    quantized pool, the [L, P, page] scale planes), and that is what
+    `copy_pages`, `fetch_page`, `upload_page`, the prefix cache, the
+    spill tier and `sharding.paged_kv_spec` address. `forward` views it
+    as L*P pages while it runs (see its `block_tables` contract) and
+    hands it back in this layout."""
     shape = (
         cfg.num_layers, num_pages, page_size, cfg.num_kv_heads, cfg.head_dim
     )
@@ -383,6 +390,19 @@ def forward(
         cache writes (finished/empty serving slots). kv_lengths [B] (valid
         kv count incl. the current token) enables the in-place Pallas
         ragged decode kernel for single-token steps under attn_impl=pallas.
+        THE POOL IS CARRIED, NOT SCANNED: whenever kv_cache and
+        block_tables are both given, the layer scan carries each plane
+        as one flat [L*P, page, Hk, D] buffer (a free reshape; the
+        returned cache has the stored [L, P, ...] layout again) and
+        layer l reads and writes it through tables offset by l*P, so a
+        donated pool is updated in place: no per-layer slice, no second
+        stacked buffer, no copy back. Callers pass tables over ONE
+        layer's pages (entries 0..P-1, sentinel P) exactly as the
+        allocator hands them out; the offset is forward's own. A
+        sentinel entry (>= P) becomes L*P at every layer, one past the
+        whole pool: its write drops out of bounds as it did past one
+        layer's P pages (it must not become page 0 of layer l+1), and
+        its read clamps to a page that is masked or ignored.
       q_segments: packed RAGGED paged mode ([B=1, T] int32, requires
         block_tables): the T axis is a packed buffer of query rows from
         many sequences with MIXED query lengths — q_segments names each
@@ -501,12 +521,7 @@ def forward(
                 **kw,
             )
 
-    def body(carry, xs):
-        h = carry
-        if kv_cache is not None:
-            lp, ck, cv = xs
-        else:
-            lp, ck, cv = xs, None, None
+    def block(h, lp, ck, cv, tables):
         h, ck, cv = _block(
             cfg, h, lp, cos, sin,
             positions=attn_positions,
@@ -514,36 +529,62 @@ def forward(
             write_slots=write_slots,
             kv_mask=kv_mask,
             attn_fn=attn_fn,
-            block_tables=block_tables,
+            block_tables=tables,
             write_mask=write_mask,
             kv_lengths=kv_lengths,
             q_segments=q_segments,
             attn_impl=attn_impl,
         )
-        h = constrain(h, *hs_spec)
-        return h, (ck, cv) if kv_cache is not None else None
+        return constrain(h, *hs_spec), ck, cv
 
-    body = wrap_remat(body, remat)
+    new_cache = None
+    if kv_cache is not None and block_tables is not None:
+        # Paged pool: the scan's CARRY, one flat [L*P, page, ...] buffer a
+        # plane behind layer-offset tables (the `block_tables` contract
+        # above), so XLA updates the donated pool in place.
+        L, P = kv_cache["k"].shape[:2]
+        flat = jax.tree_util.tree_map(
+            lambda a: a.reshape((L * P,) + a.shape[2:]), kv_cache
+        )
 
-    if kv_cache is not None:
-        xs = (params["layers"], kv_cache["k"], kv_cache["v"])
+        def body(carry, xs):
+            h, ck, cv = carry
+            lp, layer = xs
+            tables = jnp.where(
+                block_tables >= P, L * P, block_tables + layer * P
+            )
+            return block(h, lp, ck, cv, tables), None
+
+        (h, ck, cv), _ = jax.lax.scan(
+            wrap_remat(body, remat), (h, flat["k"], flat["v"]),
+            (params["layers"], jnp.arange(L, dtype=block_tables.dtype)),
+        )
+        new_cache = jax.tree_util.tree_map(
+            lambda a: a.reshape((L, P) + a.shape[1:]), {"k": ck, "v": cv}
+        )
     else:
+        # Dense [L, B, S, Hk, D] cache (generate / generate_stream) as
+        # the scan's xs/ys, or no cache at all (training).
+        def body(h, xs):
+            lp, ck, cv = (xs, None, None) if kv_cache is None else xs
+            h, ck, cv = block(h, lp, ck, cv, None)
+            return h, None if kv_cache is None else (ck, cv)
+
         xs = params["layers"]
-    h, ys = jax.lax.scan(body, h, xs)
+        if kv_cache is not None:
+            xs = (xs, kv_cache["k"], kv_cache["v"])
+        h, ys = jax.lax.scan(wrap_remat(body, remat), h, xs)
+        if kv_cache is not None:
+            new_cache = {"k": ys[0], "v": ys[1]}
 
     h = rms_norm(h, params["final_norm"]["weight"], cfg.rms_norm_eps)
     if return_hidden:
         # Final hidden states pre-lm_head: the chunked-CE training path
         # (train/loss.chunked_causal_lm_loss) projects to the vocab
         # per-chunk instead of materializing [B, T, V] logits.
-        return h, ({"k": ys[0], "v": ys[1]} if kv_cache is not None else None)
+        return h, new_cache
     if cfg.tie_word_embeddings:
         logits = h @ params["embed"]["weight"].astype(h.dtype).T
     else:
         logits = h @ params["lm_head"]["kernel"].astype(h.dtype)
-    logits = logits.astype(logits_dtype)
-
-    new_cache = None
-    if kv_cache is not None:
-        new_cache = {"k": ys[0], "v": ys[1]}
-    return logits, new_cache
+    return logits.astype(logits_dtype), new_cache

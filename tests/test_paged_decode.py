@@ -299,6 +299,158 @@ def test_paged_decode_pallas_matches_xla(tiny_llm):
 
 
 # ---------------------------------------------------------------------------
+# The pool is the layer scan's carry: layer-offset tables on one flat pool
+# ---------------------------------------------------------------------------
+
+_P, _PS = 6, 4  # pages a layer (the sentinel is _P), page size
+# Three sequences: 0 live on pages 3, 5; 1 finished (write_mask False) on
+# pages 1, 2; 2 live with ONE page, so its slots >= 4 route through the
+# sentinel. Page 0 belongs to nobody: a sentinel entry offset like a real
+# one (6 + l*6) would land on page 0 of layer l + 1.
+_TABLES = np.array([[3, 5], [1, 2], [4, _P]], np.int32)
+
+
+def _carried_pool_case(mode):
+    """(forward kwargs, the (page, offset) slots a layer may write, the
+    rows whose every visible slot is allocated: only their logits mean
+    anything) for one `forward` per paged entry shape."""
+    slot = np.arange(2 * _PS, dtype=np.int32)[None]
+    if mode == "packed":
+        seg = np.array([[0, 0, 1, 2, 2]], np.int32)
+        pos = np.array([[4, 5, 6, 3, 4]], np.int32)
+        kw = dict(
+            input_ids=np.array([[9, 8, 7, 6, 5]], np.int32), positions=pos,
+            q_segments=seg,
+            write_mask=np.array([[True, True, False, True, True]]),
+        )
+        # seq 2's token at slot 4 routes through the sentinel and sees it.
+        return _on_device(kw), [(5, 0), (5, 1), (4, 3)], np.s_[0, :4]
+    if mode == "prefill":
+        start = np.array([2, 4, 2], np.int32)
+        lengths = np.array([6, 8, 4], np.int32)  # seq 2: 2 real + 2 pad rows
+        kw = dict(
+            input_ids=np.arange(1, 13, dtype=np.int32).reshape(3, 4),
+            positions=start[:, None] + np.arange(4, dtype=np.int32)[None],
+            write_slots=start,
+            kv_mask=(slot < lengths[:, None]).astype(np.int32),
+            write_mask=np.array([True, False, True]), kv_lengths=lengths,
+        )
+        written = [(3, 2), (3, 3), (5, 0), (5, 1), (4, 2), (4, 3)]
+        return _on_device(kw), written, np.s_[:2]
+    cur = np.array([5, 6, 4], np.int32)  # seq 2 writes slot 4: dropped
+    kw = dict(
+        input_ids=np.array([[9], [8], [7]], np.int32), positions=cur[:, None],
+        write_slots=cur, kv_mask=(slot <= cur[:, None]).astype(np.int32),
+        write_mask=np.array([True, False, True]), kv_lengths=cur + 1,
+        attn_impl="pallas" if mode == "decode-pallas" else "xla",
+    )
+    return _on_device(kw), [(5, 1)], np.s_[:2]
+
+
+def _on_device(kw):
+    kw = {
+        k: v if isinstance(v, str) else jnp.asarray(v) for k, v in kw.items()
+    }
+    return dict(kw, block_tables=jnp.asarray(_TABLES))
+
+
+def _noise_pool(cfg, pool, seed=0):
+    """A [L, _P, _PS, Hk, D] pool with no zero byte anywhere, so a write
+    that strays shows wherever it lands."""
+    rng = np.random.default_rng(seed)
+    shape = (cfg.num_layers, _P, _PS, cfg.num_kv_heads, cfg.head_dim)
+
+    def plane():
+        if pool == "int8":
+            return paged_kv.QuantPages(
+                jnp.asarray(rng.integers(1, 128, shape), jnp.int8),
+                jnp.asarray(rng.uniform(0.01, 0.02, shape[:3]), jnp.float32),
+            )
+        return jnp.asarray(rng.standard_normal(shape), jnp.float32)
+
+    return {"k": plane(), "v": plane()}
+
+
+def _forward_layer_by_layer(params, cfg, kv, *, input_ids, positions,
+                            attn_impl="xla", **kw):
+    """`qwen2.forward`'s paged route as it was before the pool became the
+    scan's carry: the pool is the scan's xs, so `_block` sees `pool[l]`,
+    a [P, ps, Hk, D] slice of its own, through the UNSHIFTED tables, and
+    the written slices are restacked as ys. (A Python loop over the layers
+    is the same arithmetic but not the same fusions: on the CPU it is one
+    ulp off EVERY scanned forward, this one and the old one alike.)"""
+    from oryx_tpu.ops import norms, rope
+
+    def attn_fn(q, k, v, slot_positions=False, **kw):
+        return att_lib.attention(q, k, v, causal=True, **kw)
+
+    kw = {"write_slots": None, "kv_mask": None, **kw}
+    cos, sin = rope.rope_cos_sin(positions, cfg.head_dim, cfg.rope_theta)
+
+    def body(h, xs):
+        lp, ck, cv = xs
+        h, ck, cv = qwen2._block(
+            cfg, h, lp, cos, sin, positions=positions, cache_k=ck,
+            cache_v=cv, attn_fn=attn_fn, attn_impl=attn_impl, **kw,
+        )
+        return h, {"k": ck, "v": cv}
+
+    h, new = jax.lax.scan(
+        body, params["embed"]["weight"][input_ids],
+        (params["layers"], kv["k"], kv["v"]),
+    )
+    h = norms.rms_norm(h, params["final_norm"]["weight"], cfg.rms_norm_eps)
+    return h @ params["lm_head"]["kernel"], new
+
+
+def _bytes(kv):
+    return [np.asarray(a) for a in jax.tree_util.tree_leaves(kv)]
+
+
+_MODES = ["decode", "decode-pallas", "prefill", "packed"]
+
+
+@pytest.mark.parametrize("pool", ["bf16", "int8"])
+@pytest.mark.parametrize("mode", _MODES)
+def test_carried_pool_writes_only_the_rows_layer_slots(tiny_llm, mode, pool):
+    """A write masked by `write_mask`, or routed through the sentinel, at
+    layer l drops: after one `forward` every byte of the pool is as it
+    was, save the live rows' own slots in each layer's own pages."""
+    cfg, params = tiny_llm
+    kw, written, _ = _carried_pool_case(mode)
+    kv = _noise_pool(cfg, pool)
+    before = _bytes(kv)
+    _, new = qwen2.forward(params, cfg, kv_cache=kv, **kw)
+    for was, now in zip(before, _bytes(new)):
+        assert was.shape == now.shape and was.dtype == now.dtype
+        may = np.zeros(was.shape[:3], bool)
+        for page, offset in written:
+            may[:, page, offset] = True
+        changed = (now != was).reshape(*may.shape, -1).any(-1)
+        np.testing.assert_array_equal(changed, may)
+
+
+@pytest.mark.parametrize("pool", ["bf16", "int8"])
+@pytest.mark.parametrize("mode", _MODES)
+def test_carried_pool_matches_layer_by_layer_reference(tiny_llm, mode, pool):
+    """Same logits and the same pool, byte for byte, as the per-layer
+    slices the carried pool replaced."""
+    cfg, params = tiny_llm
+    kw, _, meaningful = _carried_pool_case(mode)
+    want_logits, want = _forward_layer_by_layer(
+        params, cfg, _noise_pool(cfg, pool), **kw
+    )
+    got_logits, got = qwen2.forward(
+        params, cfg, kv_cache=_noise_pool(cfg, pool), **kw
+    )
+    np.testing.assert_array_equal(
+        np.asarray(got_logits)[meaningful], np.asarray(want_logits)[meaningful]
+    )
+    for a, b in zip(_bytes(got), _bytes(want)):
+        np.testing.assert_array_equal(a, b)
+
+
+# ---------------------------------------------------------------------------
 # Samplers
 # ---------------------------------------------------------------------------
 

@@ -182,6 +182,86 @@ def test_paged_decode_compiles_for_v5e(one_chip, pool):
     )
 
 
+def _lower_serve_program(program, pool, one_chip):
+    """(lowered `paged_decode_chunk` (chunk 8) or `paged_prefill`
+    (1 x 256), the pool's bytes, one layer's bf16 K plane's bytes) over
+    abstract arguments: the Oryx-7B decoder's widths at depth 4, 16
+    slots x 4096 tokens, page 64, the Pallas kernels. The vocabulary is
+    cut to 1024: it is the sampler's width and no pool op's, and at
+    152,064 the TPU compiler spends 18 of a case's 20 s on the
+    sampler's sorts, beside the suite's other workers."""
+    from oryx_tpu.config import LLMConfig
+    from oryx_tpu.models import generate, qwen2
+
+    def on_chip(make):
+        return jax.tree_util.tree_map(
+            lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=one_chip),
+            jax.eval_shape(make),
+        )
+
+    cfg = LLMConfig(num_layers=4, vocab_size=1024)
+    pages, page_size = 16 * 4096 // 64, 64
+    S = 16 if program == "paged_decode_chunk" else 1
+    rows = lambda dtype, *tail: jax.ShapeDtypeStruct(  # noqa: E731
+        (S, *tail), dtype, sharding=one_chip
+    )
+    params = on_chip(
+        lambda: qwen2.init_params(cfg, jax.random.key(0), dtype=BF16)
+    )
+    kv = on_chip(lambda: qwen2.init_paged_kv_cache(
+        cfg, pages, page_size, dtype=BF16,
+        kv_dtype=None if pool == "bf16" else pool,
+    ))
+    tables = rows(jnp.int32, 4096 // page_size)
+    sampling = (
+        on_chip(lambda: jax.random.split(jax.random.key(0), S)),
+        rows(jnp.float32), rows(jnp.float32), rows(jnp.int32),
+    )
+    common = dict(attn_impl="pallas", compute_dtype=BF16)
+    if program == "paged_decode_chunk":
+        lowered = generate.paged_decode_chunk.lower(
+            params, cfg, kv, tables, rows(jnp.int32), rows(jnp.int32),
+            rows(jnp.bool_), rows(jnp.int32, 0), *sampling,
+            chunk=8, eos=0, **common,
+        )
+    else:
+        lowered = generate.paged_prefill.lower(
+            params, cfg, rows(BF16, 256, cfg.hidden_size), rows(jnp.int32),
+            tables, kv, rows(jnp.int32), *sampling, **common,
+        )
+    pool_bytes = sum(
+        a.size * a.dtype.itemsize for a in jax.tree_util.tree_leaves(kv)
+    )
+    return lowered, pool_bytes, pages * page_size * HK * D * 2
+
+
+@pytest.mark.parametrize("pool", ["bf16", "int8"])
+@pytest.mark.parametrize("program", ["paged_decode_chunk", "paged_prefill"])
+def test_serve_program_keeps_the_kv_pool_in_place(
+    one_chip, mosaic, program, pool
+):
+    """The two programs every serve cell runs, whole: the donated pool
+    is aliased to the output and NO temporary of a layer's K plane or
+    more exists. `qwen2.forward` carries the pool through its layer
+    scan; with the pool as the scan's xs/ys the same compile shows a
+    slice and an update-slice of it in every layer, a copy of all of it
+    in every decode step, and 0.69 / 0.81 GB of temporaries against a
+    0.54 GB pool. `_use_interpret` MUST be patched: unpatched, the text
+    holds the interpreter's `while` over the kernel's grid with its own
+    dynamic slices of the pool, which the chip never runs and this
+    bound does not describe. (At the full vocabulary the same compiles
+    give 25 and 80 MB of temporaries, 78 MB of the latter being
+    `paged_prefill`'s [1, 256, vocab] logits: not pool traffic.)"""
+    lowered, pool_bytes, layer_k_plane = _lower_serve_program(
+        program, pool, one_chip
+    )
+    compiled = lowered.compile()
+    assert "tpu_custom_call" in compiled.as_text()
+    memory = compiled.memory_analysis()
+    assert memory.alias_size_in_bytes == pool_bytes
+    assert memory.temp_size_in_bytes < layer_k_plane
+
+
 def test_illegal_heads_per_block_pin_raises_with_its_name(
     one_chip, monkeypatch
 ):
