@@ -1,0 +1,15 @@
+"""Model step: share of a block dispatch's forwards that only commit, %:
+`diffusion_forwards_total{kind="commit"}` / all forwards over the
+window, 1 / (T + 1) under the static rule (33.3 % at T 2). What fusing
+the commit forward into the next block's first denoising forward would
+take off the step.
+
+Reads run["counters"]. None where the program has no such counter."""
+LAYER = "model step"
+
+
+def read(run):
+    c = run["counters"]
+    commit = c.get('diffusion_forwards_total{kind="commit"}')
+    total = c.get("diffusion_forwards_total")
+    return 100.0 * commit / total if total and commit is not None else None

@@ -35,6 +35,43 @@ class LLMConfig:
     tie_word_embeddings: bool = False
     # Qwen2 uses bias on q/k/v projections (not o); Yi/Llama-class uses none.
     attention_bias: bool = True
+    # Per-head RMSNorm of q and k over head_dim, before RoPE (the
+    # qwen3_moe lineage); adds q_norm/k_norm [L, D] weights.
+    qk_norm: bool = False
+    # Sparse expert MLP in EVERY layer when num_experts > 0 (the dense
+    # gate/up/down of `intermediate_size` is then absent): a float32
+    # router over num_experts, the num_experts_per_tok largest softmax
+    # probabilities (renormalized to sum 1 when norm_topk_prob), SwiGLU
+    # experts of width moe_intermediate_size, no shared expert.
+    num_experts: int = 0
+    num_experts_per_tok: int = 0
+    moe_intermediate_size: int = 0
+    norm_topk_prob: bool = True
+    # Generation by diffusion over blocks when > 0: attention is full
+    # inside a block of block_length positions and causal across blocks
+    # (M[i, j] = j // B <= i // B), logits at position i predict token i
+    # itself, and masked positions carry mask_token_id
+    # (models/generate.paged_block_step). A power of two, so that every
+    # page, prefill chunk and attention tile edge is a block edge.
+    block_length: int = 0
+    mask_token_id: int = 0
+
+    def __post_init__(self):
+        B = self.block_length
+        if B < 0 or B & (B - 1):
+            raise ValueError(
+                f"block_length must be 0 or a power of two, got {B}"
+            )
+        if self.num_experts and not (
+            0 < self.num_experts_per_tok <= self.num_experts
+            and self.moe_intermediate_size > 0
+        ):
+            raise ValueError(
+                f"num_experts={self.num_experts} needs 0 < "
+                f"num_experts_per_tok ({self.num_experts_per_tok}) <= "
+                "num_experts and moe_intermediate_size > 0 "
+                f"({self.moe_intermediate_size})"
+            )
 
 
 @dataclass(frozen=True)
@@ -183,6 +220,9 @@ class LoraConfig:
         return self.alpha / (self.r**0.5 if self.use_rslora else self.r)
 
 
+REMASKING_RULES = ("low_confidence_static", "low_confidence_dynamic")
+
+
 @dataclass(frozen=True)
 class GenerationConfig:
     max_new_tokens: int = 128
@@ -190,6 +230,26 @@ class GenerationConfig:
     top_p: float = 1.0
     top_k: int = 0
     eos_token_id: int = 151645  # <|im_end|> for Qwen2-Instruct
+    # Block-diffusion decoding (LLMConfig.block_length > 0), server side:
+    # denoising forwards a block (0 = block_length) and the unmasking
+    # rule. "low_confidence_static" unmasks an even ceil-spread share of
+    # the still-masked positions each step, most confident first;
+    # "low_confidence_dynamic" unmasks every masked position whose
+    # confidence passes confidence_threshold, and always the best one.
+    denoising_steps: int = 0
+    remasking: str = "low_confidence_dynamic"
+    confidence_threshold: float = 0.9
+
+    def __post_init__(self):
+        if self.remasking not in REMASKING_RULES:
+            raise ValueError(
+                f"remasking={self.remasking!r}: use "
+                f"{'|'.join(REMASKING_RULES)}"
+            )
+        if self.denoising_steps < 0:
+            raise ValueError(
+                f"denoising_steps must be >= 0, got {self.denoising_steps}"
+            )
 
 
 @dataclass(frozen=True)
@@ -197,7 +257,9 @@ class OryxConfig:
     """Root config for the multimodal model + runtime."""
 
     llm: LLMConfig = field(default_factory=LLMConfig)
-    vision: VisionConfig = field(default_factory=VisionConfig)
+    # None = a text-only model: no vit/compressor parameter subtrees, and
+    # the server answers a request that carries media with a 400.
+    vision: VisionConfig | None = field(default_factory=VisionConfig)
     compressor: CompressorConfig = field(default_factory=CompressorConfig)
     mesh: MeshConfig = field(default_factory=MeshConfig)
     train: TrainConfig = field(default_factory=TrainConfig)
@@ -262,6 +324,10 @@ def _collect_field_types(root):
             continue
         seen.add(tp)
         for name, hint in typing.get_type_hints(tp).items():
+            # `VisionConfig | None`: the dataclass inside the union.
+            for arg in typing.get_args(hint):
+                if dataclasses.is_dataclass(arg):
+                    hint = arg
             if dataclasses.is_dataclass(hint):
                 out[(tp, name)] = hint
                 stack.append(hint)
@@ -366,6 +432,55 @@ def oryx_1_5_7b() -> OryxConfig:
 def oryx_1_5_32b() -> OryxConfig:
     """Oryx-1.5-32B: Qwen2.5-32B backbone, same vision/compressor stack."""
     return OryxConfig(llm=qwen2_5_32b())
+
+
+def sdar_30b_a3b() -> OryxConfig:
+    """SDAR-30B-A3B-Chat (JetLM, `model_type: sdar_moe`): a text-only
+    128-expert top-8 decoder that generates by diffusion over blocks.
+    Widths from its config.json; q/k norm, block length 4, the mask
+    token and the unmasking defaults are the family's convention (the
+    config has no key for them)."""
+    return OryxConfig(
+        llm=LLMConfig(
+            vocab_size=151936,
+            hidden_size=2048,
+            intermediate_size=6144,  # published, unused: every layer is sparse
+            num_layers=48,
+            num_heads=32,
+            num_kv_heads=4,
+            head_dim=128,
+            rope_theta=1_000_000.0,
+            rms_norm_eps=1e-6,
+            max_position_embeddings=32768,
+            attention_bias=False,
+            qk_norm=True,
+            num_experts=128,
+            num_experts_per_tok=8,
+            moe_intermediate_size=768,
+            norm_topk_prob=True,
+            block_length=4,
+            mask_token_id=151669,
+        ),
+        vision=None,
+    )
+
+
+def sdar_tiny() -> OryxConfig:
+    """Tiny block-diffusion expert decoder for tests (CPU-fast)."""
+    return OryxConfig(
+        llm=dataclasses.replace(
+            tiny_llm(),
+            attention_bias=False,
+            qk_norm=True,
+            num_experts=8,
+            num_experts_per_tok=2,
+            moe_intermediate_size=32,
+            block_length=4,
+            mask_token_id=511,
+        ),
+        vision=None,
+        dtype="float32",
+    )
 
 
 def oryx_tiny() -> OryxConfig:

@@ -457,7 +457,7 @@ def generate_stream(
 
 @partial(
     jax.jit,
-    static_argnames=("cfg", "attn_impl", "compute_dtype"),
+    static_argnames=("cfg", "attn_impl", "compute_dtype", "return_routing"),
     donate_argnames=("kv_pages",),
 )
 def paged_prefill(
@@ -475,6 +475,7 @@ def paged_prefill(
     *,
     attn_impl: str = "xla",
     compute_dtype=None,
+    return_routing: bool = False,
 ):
     """Prompt prefill into a PAGED cache + first sampled token.
 
@@ -483,7 +484,9 @@ def paged_prefill(
     With `start` > 0 only the suffix is prefilled at absolute positions
     (prefix KV reuse). Sampling is per-row (`sample_token_rows`) so one
     compiled prefill serves every sampling config at a given prompt
-    bucket. Returns (kv_pages, tok0 [B], advanced keys [B])."""
+    bucket. Returns (kv_pages, tok0 [B], advanced keys [B]), and with
+    return_routing (a static twin for the benchmark's comparison) the
+    expert layers' routing, `qwen2.forward`'s, as a fourth value."""
     B, T, _ = inputs_embeds.shape
     start = jnp.broadcast_to(start.astype(jnp.int32), (B,))
     positions = start[:, None] + jnp.arange(T, dtype=jnp.int32)[None, :]
@@ -492,12 +495,13 @@ def paged_prefill(
     kv_mask = (
         jnp.arange(K, dtype=jnp.int32)[None, :] < lengths[:, None]
     ).astype(jnp.int32)
-    logits, kv_pages = qwen2.forward(
+    logits, kv_pages, *routing = qwen2.forward(
         params, cfg,
         inputs_embeds=inputs_embeds, positions=positions,
         kv_cache=kv_pages, write_slots=start, kv_mask=kv_mask,
         block_tables=block_tables, kv_lengths=lengths,
         attn_impl=attn_impl, compute_dtype=compute_dtype,
+        return_routing=return_routing,
     )
     last = jnp.take_along_axis(
         logits, (lengths - 1 - start)[:, None, None].astype(jnp.int32),
@@ -507,7 +511,7 @@ def paged_prefill(
     tok0 = sample_token_rows(
         last, pair[:, 1], temperature=temperature, top_p=top_p, top_k=top_k
     )
-    return kv_pages, tok0, pair[:, 0]
+    return (kv_pages, tok0, pair[:, 0], *routing)
 
 
 @partial(jax.jit, static_argnames=("width",))
@@ -1393,6 +1397,256 @@ def paged_spec_step(
     return (
         kv_pages, nxt, lengths + inc, new_finished, keys_next,
         out_toks, n_new, acc, pf_tok0, pf_key_next,
+    )
+
+
+# ---------------------------------------------------------------------------
+# Block diffusion: a dispatch commits one BLOCK of tokens per slot
+# ---------------------------------------------------------------------------
+
+
+# What `paged_block_step`'s counts["stats"] holds, in order.
+BLOCK_STATS = (
+    "forwards", "unmasked", "moe_rows_routed", "moe_rows_max",
+    "moe_experts_hit",
+)
+
+
+def block_unmask(
+    masked: jnp.ndarray,  # [S, B] bool: positions still masked
+    conf: jnp.ndarray,  # [S, B] float32 confidence of each position's x0
+    step: jnp.ndarray,  # [] int32 denoising step, from 0
+    *,
+    steps: int,
+    remasking: str,
+    threshold: float,
+) -> jnp.ndarray:
+    """Which masked positions this denoising step fixes ([S, B] bool).
+    "low_confidence_static": the ceil(m / (steps - step)) most confident
+    of a slot's m masked positions (ties: the lower position), so any m
+    is used up in `steps` steps (4 over 3: 2, 1, 1).
+    "low_confidence_dynamic": every masked position whose confidence
+    passes `threshold`, and always the most confident one."""
+    c = jnp.where(masked, conf, -jnp.inf)
+    order = jnp.argsort(-c, axis=-1)  # stable: ties to the lower position
+    rank = jnp.argsort(order, axis=-1)
+    if remasking == "low_confidence_static":
+        m = jnp.sum(masked, axis=-1)
+        left = jnp.maximum(steps - step, 1)
+        take = -(-m // left)
+    else:
+        take = jnp.maximum(jnp.sum(masked & (conf > threshold), axis=-1), 1)
+    return masked & (rank < take[:, None])
+
+
+def _block_lanes_forward(
+    params, cfg: LLMConfig, kv_pages, block_tables, ids, lengths, live,
+    *, attn_impl, compute_dtype,
+):
+    """One forward of S x B packed lanes, slot-major: slot s's block
+    `ids[s]` at positions lengths[s]..lengths[s]+B-1 of its own pages,
+    through the (segment, position) ragged path; live slots' lanes write
+    their K/V there. Returns (logits [S*B, V], kv_pages, routing or
+    None on a dense config)."""
+    from oryx_tpu.parallel.sharding import constrain
+
+    S, B = ids.shape
+    seg = jnp.repeat(jnp.arange(S, dtype=jnp.int32), B)
+    pos = (
+        lengths[:, None].astype(jnp.int32)
+        + jnp.arange(B, dtype=jnp.int32)[None, :]
+    ).reshape(-1)
+    e = constrain(params["embed"]["weight"], None, None)[ids.reshape(-1)]
+    if compute_dtype is not None:
+        e = e.astype(compute_dtype)
+    logits, kv_pages, *routing = qwen2.forward(
+        params, cfg,
+        inputs_embeds=e[None], positions=pos[None],
+        kv_cache=kv_pages, block_tables=block_tables,
+        q_segments=seg[None], write_mask=jnp.repeat(live, B)[None],
+        attn_impl=attn_impl, compute_dtype=compute_dtype,
+        return_routing=bool(cfg.num_experts),
+    )
+    return logits[0], kv_pages, routing[0] if routing else None
+
+
+@partial(
+    jax.jit,
+    static_argnames=("cfg", "attn_impl", "compute_dtype"),
+    donate_argnames=("kv_pages",),
+)
+def paged_block_forward(
+    params, cfg: LLMConfig, kv_pages, block_tables, ids, lengths, live,
+    *, attn_impl: str = "xla", compute_dtype=None,
+):
+    """`paged_block_step`'s own forward as a program of its own, for the
+    comparisons that need a forward's logits (benchmark/
+    correctness_sdar.py, the tests): `_block_lanes_forward`, jitted."""
+    return _block_lanes_forward(
+        params, cfg, kv_pages, block_tables, ids, lengths, live,
+        attn_impl=attn_impl, compute_dtype=compute_dtype,
+    )
+
+
+@partial(
+    jax.jit,
+    static_argnames=(
+        "cfg", "steps", "remasking", "threshold", "eos", "attn_impl",
+        "compute_dtype",
+    ),
+    donate_argnames=("kv_pages",),
+)
+def paged_block_step(
+    params,
+    cfg: LLMConfig,
+    kv_pages: dict,  # donated
+    block_tables: jnp.ndarray,  # [S, max_pages] int32
+    block: jnp.ndarray,  # [S, B] the block's known tokens, then anything
+    n_known: jnp.ndarray,  # [S] int32 known tokens at the block's head
+    lengths: jnp.ndarray,  # [S] committed kv tokens, a multiple of B
+    finished: jnp.ndarray,  # [S] bool (True for finished AND empty slots)
+    keys: jax.Array,  # [S] per-slot PRNG keys
+    temperature: jnp.ndarray,  # [S]
+    top_p: jnp.ndarray,  # [S]
+    top_k: jnp.ndarray,  # [S]
+    *,
+    steps: int,
+    remasking: str,
+    threshold: float,
+    eos: int,
+    attn_impl: str = "xla",
+    compute_dtype=None,
+):
+    """ONE device dispatch that generates one block of
+    B = cfg.block_length tokens for every live slot by diffusion: the
+    sixth step program, and the only one whose forward yields something
+    other than one next token a sequence.
+
+    Every slot rides B packed lanes (slot-major, through the same
+    (segment, position) ragged path as `paged_spec_step`) at positions
+    lengths..lengths+B-1 of its own pages, which the scheduler grew
+    beforehand. A block starts as its `n_known` known tokens (a
+    prompt's tail `len % B`) and cfg.mask_token_id elsewhere. An
+    on-device loop runs denoising forwards, at most `steps` of them
+    under the static rule and at most B under the dynamic one, and
+    stops when no live slot has a masked position left; a slot that has
+    none rides along unchanged. Each forward takes x0 (argmax, or a
+    sample) and its softmax probability in float32 as confidence at
+    every lane, and `block_unmask` fixes some of the masked ones.
+    Greedy rows cost an argmax and a logsumexp; the sorting sampler
+    (`sample_token_rows`) runs only in a dispatch in which some live
+    row has temperature > 0. Then ONE commit forward over the final
+    tokens writes the block's K/V (every forward writes the lanes' K/V
+    at their positions, past the committed length; the last write is
+    what stays), so a block costs T + 1 forwards of S x B lanes.
+
+    Returns (kv_pages, tokens [S, B], n_new [S] = B - n_known for live
+    slots, lengths + B for live slots, finished | a new token is EOS,
+    keys, counts). counts holds what the counters read: `stats` [5]
+    int32 in the order of BLOCK_STATS (forwards run; tokens fixed by
+    denoising; (token, expert) pairs routed; the fullest expert's rows
+    and the experts that took a row, each summed over layer-forwards;
+    the last three 0 on a dense config), one array so that the host
+    reads them in one copy; `slot_forwards` [S], the forwards a live
+    slot took part in with work to do (its denoising forwards with a
+    mask left, and the commit); `expert_rows` [L, E], the rows every
+    expert took over all forwards."""
+    S, B = block.shape
+    if B != cfg.block_length:
+        raise ValueError(f"block is {B} wide, cfg.block_length is "
+                         f"{cfg.block_length}")
+    L, E = cfg.num_layers, max(cfg.num_experts, 1)
+    live = ~finished
+    lane = jnp.arange(B, dtype=jnp.int32)
+    masked0 = (lane[None, :] >= n_known[:, None]) & live[:, None]
+    block = jnp.where(masked0, cfg.mask_token_id, block).astype(jnp.int32)
+    greedy_only = ~jnp.any(live & (temperature > 0.0))
+
+    def forward(kv, ids):
+        lg, kv, routing = _block_lanes_forward(
+            params, cfg, kv, block_tables, ids, lengths, live,
+            attn_impl=attn_impl, compute_dtype=compute_dtype,
+        )
+        rows = (
+            jnp.zeros((L, E), jnp.int32) if routing is None
+            else routing["counts"]
+        )
+        return lg, kv, rows
+
+    def stats(fixed, rows):
+        """One forward's share of BLOCK_STATS."""
+        return jnp.stack([
+            1, fixed, jnp.sum(rows), jnp.sum(jnp.max(rows, axis=-1)),
+            jnp.sum(rows > 0),
+        ]).astype(jnp.int32)
+
+    def pick(lg, keys):
+        """x0 and its confidence at every lane: [S*B, V] -> [S, B] x2."""
+        pair = jax.vmap(lambda k: jax.random.split(k, 2))(keys)
+
+        def sampled():
+            lane_keys = jax.vmap(lambda k: jax.random.split(k, B))(
+                pair[:, 1]
+            ).reshape(S * B)
+            return sample_token_rows(
+                lg, lane_keys, temperature=jnp.repeat(temperature, B),
+                top_p=jnp.repeat(top_p, B), top_k=jnp.repeat(top_k, B),
+            )
+
+        x0 = jax.lax.cond(
+            greedy_only,
+            lambda: jnp.argmax(lg, axis=-1).astype(jnp.int32), sampled,
+        )
+        conf = jnp.exp(
+            jnp.take_along_axis(lg, x0[:, None], axis=-1)[:, 0]
+            - jax.nn.logsumexp(lg, axis=-1)
+        )
+        return x0.reshape(S, B), conf.reshape(S, B), pair[:, 0]
+
+    max_steps = steps if remasking == "low_confidence_static" else B
+
+    def cond(c):
+        return (c["t"] < max_steps) & jnp.any(c["masked"])
+
+    def body(c):
+        lg, kv, rows = forward(c["kv"], c["block"])
+        x0, conf, keys = pick(lg, c["keys"])
+        fix = block_unmask(
+            c["masked"], conf, c["t"],
+            steps=steps, remasking=remasking, threshold=threshold,
+        )
+        return {
+            "t": c["t"] + 1, "kv": kv, "keys": keys,
+            "block": jnp.where(fix, x0, c["block"]),
+            "masked": c["masked"] & ~fix,
+            "slot_forwards": c["slot_forwards"] + jnp.any(
+                c["masked"], axis=-1),
+            "expert_rows": c["expert_rows"] + rows,
+            "stats": c["stats"] + stats(jnp.sum(fix), rows),
+        }
+
+    c = jax.lax.while_loop(cond, body, {
+        "t": jnp.zeros((), jnp.int32), "kv": kv_pages, "keys": keys,
+        "block": block, "masked": masked0,
+        "slot_forwards": jnp.zeros((S,), jnp.int32),
+        "expert_rows": jnp.zeros((L, E), jnp.int32),
+        "stats": jnp.zeros((len(BLOCK_STATS),), jnp.int32),
+    })
+    # The commit: its logits are not read, so the head is not computed.
+    _, kv_pages, rows = forward(c["kv"], c["block"])
+    toks = c["block"]
+    n_new = jnp.where(live, B - n_known, 0).astype(jnp.int32)
+    new_eos = jnp.any(
+        (toks == eos) & (lane[None, :] >= n_known[:, None]), axis=-1
+    )
+    counts = {
+        "stats": c["stats"] + stats(0, rows),
+        "slot_forwards": c["slot_forwards"] + live,
+        "expert_rows": c["expert_rows"] + rows,
+    }
+    return (
+        kv_pages, toks, n_new, lengths + jnp.where(live, B, 0),
+        finished | (live & new_eos), c["keys"], counts,
     )
 
 

@@ -83,6 +83,11 @@ def import_qwen2(
     Accepts either `model.`-prefixed names (full ForCausalLM dict) or the
     bare inner-model names; the bare form carries no `lm_head.weight`, so it
     requires `cfg.tie_word_embeddings` (a clear KeyError otherwise).
+
+    An expert config (`sdar_moe` / `qwen3_moe` checkpoints) maps
+    `mlp.gate.weight` to the float32 router, `mlp.experts.M.{gate,up,
+    down}_proj.weight` into the stacked `[L, E, in, out]` expert kernels,
+    and with cfg.qk_norm `self_attn.{q,k}_norm.weight`.
     """
     p = "model." if any(k.startswith("model.") for k in sd) else ""
     L = cfg.num_layers
@@ -99,17 +104,35 @@ def import_qwen2(
         "k_proj": {"kernel": stacked("self_attn.k_proj.weight", _T)},
         "v_proj": {"kernel": stacked("self_attn.v_proj.weight", _T)},
         "o_proj": {"kernel": stacked("self_attn.o_proj.weight", _T)},
-        "gate_proj": {"kernel": stacked("mlp.gate_proj.weight", _T)},
-        "up_proj": {"kernel": stacked("mlp.up_proj.weight", _T)},
-        "down_proj": {"kernel": stacked("mlp.down_proj.weight", _T)},
     }
+    if cfg.num_experts:
+        def experts(proj: str) -> jnp.ndarray:
+            return jnp.stack([
+                stacked(f"mlp.experts.{e}.{proj}.weight", _T)
+                for e in range(cfg.num_experts)
+            ], axis=1)
+
+        layers["experts"] = {
+            "gate": experts("gate_proj"), "up": experts("up_proj"),
+            "down": experts("down_proj"),
+        }
+    else:
+        for proj in ("gate_proj", "up_proj", "down_proj"):
+            layers[proj] = {"kernel": stacked(f"mlp.{proj}.weight", _T)}
+    if cfg.qk_norm:
+        layers["q_norm"] = {"weight": stacked("self_attn.q_norm.weight")}
+        layers["k_norm"] = {"weight": stacked("self_attn.k_norm.weight")}
     if cfg.attention_bias:
         for proj in ("q_proj", "k_proj", "v_proj"):
             layers[proj]["bias"] = stacked(f"self_attn.{proj}.bias")
+    layers = {k: {kk: cast(vv) for kk, vv in v.items()}
+              for k, v in layers.items()}
+    if cfg.num_experts:  # the router stays float32 (qwen2.init_params)
+        layers["router"] = {"kernel": jnp.asarray(
+            stacked("mlp.gate.weight", _T), jnp.float32)}
     params: Params = {
         "embed": {"weight": cast(_get(sd, p + "embed_tokens.weight"))},
-        "layers": {k: {kk: cast(vv) for kk, vv in v.items()}
-                   for k, v in layers.items()},
+        "layers": layers,
         "final_norm": {"weight": cast(_get(sd, p + "norm.weight"))},
     }
     if not cfg.tie_word_embeddings:
@@ -133,10 +156,19 @@ def export_qwen2(params: Params, cfg: LLMConfig) -> dict[str, np.ndarray]:
         "self_attn.k_proj.weight": (lp["k_proj"]["kernel"], _T),
         "self_attn.v_proj.weight": (lp["v_proj"]["kernel"], _T),
         "self_attn.o_proj.weight": (lp["o_proj"]["kernel"], _T),
-        "mlp.gate_proj.weight": (lp["gate_proj"]["kernel"], _T),
-        "mlp.up_proj.weight": (lp["up_proj"]["kernel"], _T),
-        "mlp.down_proj.weight": (lp["down_proj"]["kernel"], _T),
     }
+    if cfg.num_experts:
+        names["mlp.gate.weight"] = (lp["router"]["kernel"], _T)
+        for e in range(cfg.num_experts):
+            for proj in ("gate", "up", "down"):
+                names[f"mlp.experts.{e}.{proj}_proj.weight"] = (
+                    lp["experts"][proj][:, e], _T)
+    else:
+        for proj in ("gate_proj", "up_proj", "down_proj"):
+            names[f"mlp.{proj}.weight"] = (lp[proj]["kernel"], _T)
+    if cfg.qk_norm:
+        names["self_attn.q_norm.weight"] = (lp["q_norm"]["weight"], _I)
+        names["self_attn.k_norm.weight"] = (lp["k_norm"]["weight"], _I)
     if cfg.attention_bias:
         for proj in ("q_proj", "k_proj", "v_proj"):
             names[f"self_attn.{proj}.bias"] = (lp[proj]["bias"], _I)

@@ -32,6 +32,8 @@ Params = dict[str, Any]
 
 def init_params(cfg: OryxConfig, key: jax.Array, dtype=jnp.float32) -> Params:
     k1, k2, k3 = jax.random.split(key, 3)
+    if cfg.vision is None:  # a text-only model: the decoder alone
+        return {"llm": qwen2.init_params(cfg.llm, k1, dtype)}
     return {
         "llm": qwen2.init_params(cfg.llm, k1, dtype),
         "vit": oryx_vit.init_params(cfg.vision, k2, dtype),

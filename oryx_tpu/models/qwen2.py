@@ -51,8 +51,8 @@ def init_params(
     I = cfg.intermediate_size
     keys = iter(jax.random.split(key, 16))
 
-    def dense(k, shape):
-        return (jax.random.normal(k, shape, jnp.float32) * 0.02).astype(dtype)
+    def dense(k, shape, dt=dtype):
+        return (jax.random.normal(k, shape, jnp.float32) * 0.02).astype(dt)
 
     def stack(shape):
         return dense(next(keys), (L, *shape))
@@ -66,12 +66,33 @@ def init_params(
             "k_proj": {"kernel": stack((H, Dkv))},
             "v_proj": {"kernel": stack((H, Dkv))},
             "o_proj": {"kernel": stack((Dq, H))},
-            "gate_proj": {"kernel": stack((H, I))},
-            "up_proj": {"kernel": stack((H, I))},
-            "down_proj": {"kernel": stack((I, H))},
         },
         "final_norm": {"weight": jnp.ones((H,), dtype)},
     }
+    if cfg.num_experts:
+        # Expert layer (`_moe`): the router stays float32 whatever the
+        # serving dtype (routing is a discontinuous function of it);
+        # expert kernels are stacked [L, E, in, out].
+        E, Ie = cfg.num_experts, cfg.moe_intermediate_size
+        params["layers"]["router"] = {
+            "kernel": dense(next(keys), (L, H, E), jnp.float32)
+        }
+        params["layers"]["experts"] = {
+            "gate": stack((E, H, Ie)),
+            "up": stack((E, H, Ie)),
+            "down": stack((E, Ie, H)),
+        }
+    else:
+        params["layers"]["gate_proj"] = {"kernel": stack((H, I))}
+        params["layers"]["up_proj"] = {"kernel": stack((H, I))}
+        params["layers"]["down_proj"] = {"kernel": stack((I, H))}
+    if cfg.qk_norm:
+        params["layers"]["q_norm"] = {
+            "weight": jnp.ones((L, cfg.head_dim), dtype)
+        }
+        params["layers"]["k_norm"] = {
+            "weight": jnp.ones((L, cfg.head_dim), dtype)
+        }
     if cfg.attention_bias:
         params["layers"]["q_proj"]["bias"] = jnp.zeros((L, Dq), dtype)
         params["layers"]["k_proj"]["bias"] = jnp.zeros((L, Dkv), dtype)
@@ -218,6 +239,108 @@ def merge_lora_params(params: Params) -> Params:
     return out
 
 
+def moe_route(cfg: LLMConfig, x: jnp.ndarray, router_kernel: jnp.ndarray):
+    """Router of the expert layer, in float32 at full matmul precision:
+    x [N, H] -> (weights [N, K] float32, expert ids [N, K] int32), the K
+    largest softmax probabilities (ties: the lower expert id first),
+    renormalized to sum 1 when cfg.norm_topk_prob."""
+    r = jnp.matmul(
+        x.astype(jnp.float32), router_kernel.astype(jnp.float32),
+        precision=jax.lax.Precision.HIGHEST,
+    )
+    w, idx = jax.lax.top_k(
+        jax.nn.softmax(r, axis=-1), cfg.num_experts_per_tok
+    )
+    if cfg.norm_topk_prob:
+        w = w / jnp.sum(w, axis=-1, keepdims=True)
+    return w, idx.astype(jnp.int32)
+
+
+# The grouped matmul that jax ships takes a whole [in, out] kernel of one
+# group as its tile (the products then add up in the order XLA's own
+# grouped product adds them: bit-equal results on the chip); two
+# buffers of it have to fit the kernel's VMEM.
+_GMM_ROW_TILE = 128
+_GMM_MAX_KERNEL = 2 * 1024 * 1024  # elements: 2048 x 768 is 1.5 M
+
+
+def _grouped_dot(rows: jnp.ndarray, kernels: jnp.ndarray,
+                 groups: jnp.ndarray, impl: str) -> jnp.ndarray:
+    """rows [M, in], sorted by group, x kernels [G, in, out] -> [M, out]:
+    row r times the kernel of the group it lies in (`groups` [G] row
+    counts, summing to M). `jax.lax.ragged_dot` everywhere; under
+    impl="pallas", at widths its tiles divide, the grouped matmul jax
+    ships (`megablox.gmm`). XLA's grouped product on the chip reads the
+    kernels at a quarter of the HBM rate when a group holds 8 rows (1.98
+    ms for 128 kernels of 2048 x 768 where gmm takes 0.64; PERF.md
+    section 6, PR 26), and a block step is bound by reading them."""
+    M, K = rows.shape
+    N = kernels.shape[-1]
+    if (impl != "pallas" or K % 128 or N % 128
+            or K * N > _GMM_MAX_KERNEL):
+        return jax.lax.ragged_dot(rows, kernels, groups)
+    import importlib
+
+    from oryx_tpu.ops.pallas.flash_attention import _use_interpret
+
+    # (the package's `gmm` attribute is its differentiable wrapper, which
+    # has no tiling of a whole kernel; the module holds the forward.)
+    gmm = importlib.import_module(
+        "jax.experimental.pallas.ops.tpu.megablox.gmm").gmm
+    pad = -M % _GMM_ROW_TILE  # rows past the last group: sliced off again
+    out = gmm(
+        jnp.pad(rows, ((0, pad), (0, 0))) if pad else rows, kernels, groups,
+        preferred_element_type=rows.dtype, tiling=(_GMM_ROW_TILE, K, N),
+        interpret=_use_interpret(),
+    )
+    return out[:M] if pad else out
+
+
+def _moe(cfg: LLMConfig, x: jnp.ndarray, router_kernel: jnp.ndarray,
+         experts: Params, layer: jnp.ndarray, impl: str = "xla"):
+    """Sparse expert MLP on x [N, H]: dropless, no capacity factor, no
+    padding to a capacity. The N*K (token, expert) pairs are sorted by
+    expert and the gate, up and down products run as grouped products
+    over the sorted rows (`_grouped_dot`: `jax.lax.ragged_dot`, or under
+    impl="pallas" the grouped matmul jax ships); the K results of a
+    token are gathered back and summed with the router's weights in
+    float32.
+
+    `experts` holds EVERY layer's kernels, flat [L*E, in, out], and the
+    products run over L*E groups of which only layer `layer`'s E have
+    rows. The grouped product is a kernel call, so its operand has to
+    exist in memory: a layer's [E, in, out] slice of the stacked weights
+    would be copied out in every layer of every forward (read, written
+    and read again: three times the bytes of a step that is bound by
+    reading them once), where the whole stack is passed as it lies.
+
+    Returns (y [N, H], routing: {"counts": per-expert rows [E] int32,
+    "ids": the chosen experts [N, K] int32})."""
+    N, K, E = x.shape[0], cfg.num_experts_per_tok, cfg.num_experts
+    w, idx = moe_route(cfg, x, router_kernel)
+    flat = idx.reshape(N * K)
+    order = jnp.argsort(flat)  # stable: pairs of one expert stay in row order
+    counts = jnp.bincount(flat, length=E).astype(jnp.int32)
+    groups = jax.lax.dynamic_update_slice(
+        jnp.zeros((experts["gate"].shape[0],), jnp.int32), counts,
+        (layer * E,),
+    )
+    xs = x[order // K]
+    gate = _grouped_dot(xs, experts["gate"].astype(x.dtype), groups, impl)
+    up = _grouped_dot(xs, experts["up"].astype(x.dtype), groups, impl)
+    ys = _grouped_dot(
+        jax.nn.silu(gate) * up, experts["down"].astype(x.dtype), groups, impl
+    )
+    # Unsort: pair p = token * K + slot sits at sorted row inv[p].
+    inv = jnp.zeros((N * K,), jnp.int32).at[order].set(
+        jnp.arange(N * K, dtype=jnp.int32)
+    )
+    y = jnp.einsum(
+        "nk,nkh->nh", w, ys[inv].reshape(N, K, -1).astype(jnp.float32)
+    )
+    return y.astype(x.dtype), {"counts": counts, "ids": idx}
+
+
 def _block(
     cfg: LLMConfig,
     h: jnp.ndarray,
@@ -236,13 +359,28 @@ def _block(
     kv_lengths: jnp.ndarray | None = None,
     q_segments: jnp.ndarray | None = None,
     attn_impl: str = "xla",
+    mask_positions: jnp.ndarray | None = None,
+    experts: tuple | None = None,
 ):
-    """One decoder block. h: [B, T, H]. Returns (h, new_k, new_v)."""
+    """One decoder block. h: [B, T, H]. Returns (h, new_k, new_v), and
+    on an expert config the expert layer's routing as a fourth value.
+    `experts`: an expert config's (flat kernels of every layer, this
+    layer's index), see `_moe`.
+
+    `positions` place the token: its RoPE angle and its cache slot.
+    `mask_positions` (default: the same) are what its query is masked
+    at, `kv_pos <= mask_position`: the last position of the token's
+    block under the block-diffusion mask (see `forward`)."""
     B, T, _ = h.shape
+    if mask_positions is None:
+        mask_positions = positions
     x = rms_norm(h, lp["input_norm"]["weight"], cfg.rms_norm_eps)
     q = _linear(x, lp["q_proj"]).reshape(B, T, cfg.num_heads, cfg.head_dim)
     k = _linear(x, lp["k_proj"]).reshape(B, T, cfg.num_kv_heads, cfg.head_dim)
     v = _linear(x, lp["v_proj"]).reshape(B, T, cfg.num_kv_heads, cfg.head_dim)
+    if cfg.qk_norm:
+        q = rms_norm(q, lp["q_norm"]["weight"], cfg.rms_norm_eps)
+        k = rms_norm(k, lp["k_norm"]["weight"], cfg.rms_norm_eps)
     q, k = apply_rope(q, k, cos, sin)
     # Post-rope tags for the "attn_qkv" remat policy (utils/remat.py):
     # saving here spares the backward both the projections and the rope.
@@ -263,6 +401,7 @@ def _block(
 
         seg = q_segments[0]
         pos = positions[0]
+        mpos = pos if mask_positions is positions else mask_positions[0]
         wm = None if write_mask is None else write_mask[0]
         cache_k = paged_kv.write_pages_packed(
             cache_k, k[0], block_tables, seg, pos, write_mask=wm
@@ -274,11 +413,11 @@ def _block(
             from oryx_tpu.ops.pallas import paged_attention as _ppa
 
             attn_out = _ppa.ragged_paged_attention(
-                q[0], cache_k, cache_v, block_tables, seg, pos
+                q[0], cache_k, cache_v, block_tables, seg, mpos
             )[None]
         else:
             attn_out = paged_kv.ragged_paged_attention(
-                q[0], cache_k, cache_v, block_tables, seg, pos
+                q[0], cache_k, cache_v, block_tables, seg, mpos
             )[None]
     elif cache_k is not None and block_tables is not None:
         # Paged cache: this layer's K/V pool is [P, page, Hk, D] and the
@@ -307,7 +446,7 @@ def _block(
             vc = paged_kv.gather_pages(cache_v, block_tables)
             attn_out = attn_fn(
                 q, kc, vc,
-                q_positions=positions,
+                q_positions=mask_positions,
                 kv_positions=None,
                 kv_mask=kv_mask,
             )
@@ -316,7 +455,7 @@ def _block(
         cache_v = _cache_write(cache_v, v, write_slots)
         attn_out = attn_fn(
             q, cache_k, cache_v,
-            q_positions=positions,
+            q_positions=mask_positions,
             kv_positions=None,  # arange over cache slots == absolute positions
             kv_mask=kv_mask,
         )
@@ -324,12 +463,14 @@ def _block(
         # Right-padded prefill: every valid token's position equals its
         # slot index, which lets the Pallas kernel skip causally-dead kv
         # tiles (DMA + compute) despite the explicit position arrays.
+        # (Under a block mask a query sees past its own slot, so the
+        # promise is not made; no serving path runs that case.)
         attn_out = attn_fn(
             q, k, v,
-            q_positions=positions,
+            q_positions=mask_positions,
             kv_positions=positions,
             kv_mask=kv_mask,
-            slot_positions=True,
+            slot_positions=mask_positions is positions,
         )
     attn_out = attn_out.reshape(B, T, -1)
     # "attn_o" tag: with remat_policy="attn_o" the residual-stream value
@@ -338,6 +479,12 @@ def _block(
     h = h + checkpoint_name(_linear(attn_out, lp["o_proj"]), "attn_o")
 
     x = rms_norm(h, lp["post_attn_norm"]["weight"], cfg.rms_norm_eps)
+    if cfg.num_experts:
+        y, routing = _moe(
+            cfg, x.reshape(B * T, -1), lp["router"]["kernel"], *experts,
+            impl=attn_impl,
+        )
+        return h + y.reshape(B, T, -1), cache_k, cache_v, routing
     gate = jax.nn.silu(_linear(x, lp["gate_proj"]))
     h = h + _linear(gate * _linear(x, lp["up_proj"]), lp["down_proj"])
     return h, cache_k, cache_v
@@ -365,6 +512,7 @@ def forward(
     logits_dtype: jnp.dtype = jnp.float32,
     return_hidden: bool = False,
     segment_ids: jnp.ndarray | None = None,
+    return_routing: bool = False,
 ) -> tuple[jnp.ndarray, Params | None]:
     """Full decoder forward.
 
@@ -508,6 +656,17 @@ def forward(
     # keeps the Pallas slot_positions DMA clamp valid despite the
     # restarting RoPE positions.
     attn_positions = positions
+    mask_positions = None
+    if cfg.block_length:
+        if segment_ids is not None:
+            raise ValueError(
+                "segment_ids (packed training) is not built for a "
+                "block-diffusion config (cfg.block_length > 0)"
+            )
+        Bl = cfg.block_length
+        mask_positions = positions - positions % Bl + (Bl - 1)
+    if return_routing and not cfg.num_experts:
+        raise ValueError("return_routing needs an expert config")
     if segment_ids is not None:
         attn_positions = jnp.broadcast_to(
             jnp.arange(T, dtype=jnp.int32), (B, T)
@@ -521,8 +680,18 @@ def forward(
                 **kw,
             )
 
-    def block(h, lp, ck, cv, tables):
-        h, ck, cv = _block(
+    # An expert config's kernels stay out of the scan's xs: they are
+    # passed whole, flat [L*E, in, out], with the layer's index (`_moe`).
+    layers, experts_flat = params["layers"], None
+    if cfg.num_experts:
+        layers = {k: v for k, v in layers.items() if k != "experts"}
+        experts_flat = jax.tree_util.tree_map(
+            lambda a: a.reshape((-1,) + a.shape[2:]),
+            params["layers"]["experts"],
+        )
+
+    def block(h, lp, ck, cv, tables, layer=None):
+        h, ck, cv, *routing = _block(
             cfg, h, lp, cos, sin,
             positions=attn_positions,
             cache_k=ck, cache_v=cv,
@@ -534,8 +703,10 @@ def forward(
             kv_lengths=kv_lengths,
             q_segments=q_segments,
             attn_impl=attn_impl,
+            mask_positions=mask_positions,
+            experts=None if experts_flat is None else (experts_flat, layer),
         )
-        return constrain(h, *hs_spec), ck, cv
+        return constrain(h, *hs_spec), ck, cv, routing[0] if routing else None
 
     new_cache = None
     if kv_cache is not None and block_tables is not None:
@@ -553,11 +724,12 @@ def forward(
             tables = jnp.where(
                 block_tables >= P, L * P, block_tables + layer * P
             )
-            return block(h, lp, ck, cv, tables), None
+            h, ck, cv, counts = block(h, lp, ck, cv, tables, layer)
+            return (h, ck, cv), counts
 
-        (h, ck, cv), _ = jax.lax.scan(
+        (h, ck, cv), expert_counts = jax.lax.scan(
             wrap_remat(body, remat), (h, flat["k"], flat["v"]),
-            (params["layers"], jnp.arange(L, dtype=block_tables.dtype)),
+            (layers, jnp.arange(L, dtype=block_tables.dtype)),
         )
         new_cache = jax.tree_util.tree_map(
             lambda a: a.reshape((L, P) + a.shape[1:]), {"k": ck, "v": cv}
@@ -566,14 +738,19 @@ def forward(
         # Dense [L, B, S, Hk, D] cache (generate / generate_stream) as
         # the scan's xs/ys, or no cache at all (training).
         def body(h, xs):
+            layer = None
+            if experts_flat is not None:
+                xs, layer = xs
             lp, ck, cv = (xs, None, None) if kv_cache is None else xs
-            h, ck, cv = block(h, lp, ck, cv, None)
-            return h, None if kv_cache is None else (ck, cv)
+            h, ck, cv, counts = block(h, lp, ck, cv, None, layer)
+            return h, (None if kv_cache is None else (ck, cv), counts)
 
-        xs = params["layers"]
+        xs = layers
         if kv_cache is not None:
             xs = (xs, kv_cache["k"], kv_cache["v"])
-        h, ys = jax.lax.scan(wrap_remat(body, remat), h, xs)
+        if experts_flat is not None:
+            xs = (xs, jnp.arange(cfg.num_layers, dtype=jnp.int32))
+        h, (ys, expert_counts) = jax.lax.scan(wrap_remat(body, remat), h, xs)
         if kv_cache is not None:
             new_cache = {"k": ys[0], "v": ys[1]}
 
@@ -582,9 +759,15 @@ def forward(
         # Final hidden states pre-lm_head: the chunked-CE training path
         # (train/loss.chunked_causal_lm_loss) projects to the vocab
         # per-chunk instead of materializing [B, T, V] logits.
-        return h, new_cache
-    if cfg.tie_word_embeddings:
-        logits = h @ params["embed"]["weight"].astype(h.dtype).T
+        out = h
+    elif cfg.tie_word_embeddings:
+        out = (h @ params["embed"]["weight"].astype(h.dtype).T).astype(
+            logits_dtype
+        )
     else:
-        logits = h @ params["lm_head"]["kernel"].astype(h.dtype)
-    return logits.astype(logits_dtype), new_cache
+        out = (h @ params["lm_head"]["kernel"].astype(h.dtype)).astype(
+            logits_dtype
+        )
+    if return_routing:
+        return out, new_cache, expert_counts
+    return out, new_cache

@@ -796,6 +796,13 @@ def build_server(
     # begin_drain(), read by /readyz and every POST.
     draining = threading.Event()
     if engine == "window":
+        if pipe.cfg.llm.block_length:
+            raise ValueError(
+                "a block-diffusion model (block_length="
+                f"{pipe.cfg.llm.block_length}) is served by the "
+                "continuous engine only: the window batcher decodes one "
+                "causal token a step"
+            )
         batcher = Batcher(
             pipe, window=batch_window, max_batch=max_batch,
             device_lock=stream_lock, metrics=metrics, tracer=tracer,
@@ -1208,6 +1215,10 @@ def build_server(
                 question, history, images = parse_messages(
                     req["messages"], allow_local_files=allow_local_files
                 )
+                if images and pipe.cfg.vision is None:
+                    from oryx_tpu.serve.pipeline import TEXT_ONLY_MESSAGE
+
+                    raise ValueError(TEXT_ONLY_MESSAGE)
                 raw_max = req.get(
                     "max_tokens", req.get("max_completion_tokens")
                 )

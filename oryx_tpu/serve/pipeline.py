@@ -173,6 +173,12 @@ def _jit_ll_suffix(params, cfg: OryxConfig, cache, cont_ids, length, k,
     return vec[:, 0], cache
 
 
+TEXT_ONLY_MESSAGE = (
+    "this model is text-only (the configuration has no vision tower): "
+    "a request may not carry images or video"
+)
+
+
 class OryxInference:
     """Stateless-per-call chat interface over a loaded model.
 
@@ -231,6 +237,19 @@ class OryxInference:
         from oryx_tpu.parallel.sharding import mesh_scope
 
         return mesh_scope(self.mesh)
+
+    def _causal_only(self, what: str) -> None:
+        """The pipe's own decode loops (dense cache, one causal token a
+        step) are not a block-diffusion model's generation: refuse them
+        rather than run a wrong loop under a real model's name."""
+        if self.cfg.llm.block_length:
+            raise NotImplementedError(
+                f"{what} decodes one causal token a step; a block-"
+                f"diffusion model (block_length="
+                f"{self.cfg.llm.block_length}) generates through the "
+                "continuous engine only (api_server.build_server("
+                "engine='continuous'))"
+            )
 
     def session_prefix_cache(self, capacity: int = 4):
         """Pipe-level cross-SESSION prefix cache (lazily created): pass
@@ -349,6 +368,8 @@ class OryxInference:
         source of the prep policy for batch AND streaming paths."""
         cfgv = self.cfg.vision
         images = list(req.get("images") or [])
+        if images and cfgv is None:
+            raise ValueError(TEXT_ONLY_MESSAGE)
         is_video = bool(req.get("is_video")) and len(images) > 0
         modality = infer_modality(len(images), is_video)
         prompt = self.build_prompt(
@@ -409,6 +430,7 @@ class OryxInference:
         Return shape grows in flag order:
         replies[, reasons][, counts].
         """
+        self._causal_only("chat_batch")
         cfg = self._sampling_cfg(temperature, top_p)
         stop_seqs = self._stop_for(stop)
         max_new = max_new_tokens or cfg.generation.max_new_tokens
@@ -544,6 +566,7 @@ class OryxInference:
         a COLD cache_state seeds from the index's longest stored prefix
         and the post-turn state is donated back.
         """
+        self._causal_only("chat_stream")
         cfg = self._sampling_cfg(temperature, top_p)
         stop_seqs = self._stop_for(stop)
         max_new = max_new_tokens or cfg.generation.max_new_tokens
@@ -827,6 +850,7 @@ class OryxInference:
         system prompt), and the new state is donated back after the
         turn. Text-only lookup (pre-splice ids == the flat stream);
         multimodal turns still donate and reuse within a session."""
+        self._causal_only("chat_cached")
         cfg = self._sampling_cfg(temperature, top_p)
         stop_seqs = self._stop_for(stop)
         max_new = max_new_tokens or cfg.generation.max_new_tokens
@@ -919,6 +943,7 @@ class OryxInference:
         newline-separated continuations (the harness's MCQ protocol)
         are unaffected; for free-text options include any leading
         space/punctuation in the option string itself."""
+        self._causal_only("score_options")
         ids, imgs, factors, caps = self._prepare_request({
             "question": question, "images": list(images or []),
             "is_video": is_video, "history": list(history or []),

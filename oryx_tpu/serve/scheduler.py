@@ -182,6 +182,13 @@ ENGINE_PHASES = (
     "idle", "housekeeping", "admit", "prompt_prep", "embed", "prefill",
     "first_token", "decode", "harvest", "emit",
 )
+# A block-diffusion engine (cfg.llm.block_length > 0) dispatches
+# `paged_block_step` under `denoise` in place of `decode`, and has no
+# first-token read: a block's tokens all arrive with its harvest.
+BLOCK_ENGINE_PHASES = tuple(
+    "denoise" if p == "decode" else p
+    for p in ENGINE_PHASES if p != "first_token"
+)
 
 
 class AdmissionRejected(RuntimeError):
@@ -422,6 +429,56 @@ class ContinuousScheduler:
                 "packed lanes of the fused ragged dispatch (the split "
                 "engine has no packed buffer to extend)"
             )
+        # Block mode (docs/DESIGN.md "Block diffusion"): on iff the
+        # model generates by diffusion over blocks. The split engine's
+        # admission and chunked prefill stay; the decode chunk's program
+        # is `paged_block_step`, and a dispatch advances a slot by one
+        # block. What is not built for it is refused here, one sentence
+        # each, never silently run.
+        self.block = int(pipe.cfg.llm.block_length)
+        if self.block:
+            gen = pipe.cfg.generation
+            refusals = (
+                (ragged, "ragged=True fuses prefill into a one-token-a-"
+                 "lane decode dispatch that block mode does not have"),
+                (speculate, "speculate drafts a causal stream; a block "
+                 "is not one"),
+                (fuse_steps != 1, "fuse_steps scans the ragged decode "
+                 "step, which block mode does not run"),
+                (getattr(pipe, "mesh", None) is not None, "the tensor-"
+                 "parallel engine's sharded pool is not built for the "
+                 "block step"),
+                (prefill_chunk is None, "block mode admits through "
+                 "chunked prefill only; set prefill_chunk"),
+                (kv_dtype != "bf16", "a quantized KV pool is not built "
+                 "for the block step"),
+                (audit_sample_every, "the output auditor replays one-"
+                 "token decode steps, which block mode does not run"),
+                (numerics_every, "the block step carries no numerics "
+                 "probe"),
+            )
+            for bad, why in refusals:
+                if bad:
+                    raise ValueError(
+                        f"block mode (block_length={self.block}): {why}"
+                    )
+            for name, v in (
+                ("page_size", page_size), ("prefill_chunk", prefill_chunk),
+                ("max_ctx", max_ctx),
+            ):
+                if v % self.block:
+                    raise ValueError(
+                        f"block mode: {name}={v} is not a multiple of "
+                        f"block_length={self.block} (a cached page, a "
+                        "prefill chunk and the context must each end "
+                        "on a block edge)"
+                    )
+            if (gen.denoising_steps or self.block) > self.block:
+                raise ValueError(
+                    f"denoising_steps={gen.denoising_steps} exceeds "
+                    f"block_length={self.block}: a step fixes at least "
+                    "one position"
+                )
         # Optional SLO watcher (utils/anomaly.py): TTFT and queue-depth
         # breaches fire oryx_anomaly_total{kind=} + events.jsonl.
         self.anomaly = anomaly
@@ -469,6 +526,8 @@ class ContinuousScheduler:
                 else generate_lib.NgramDrafter()
             )
         self._win = (1 + self.speculate) if self.speculate else chunk
+        if self.block:
+            self._win = self.block  # a dispatch writes one block a slot
         # Fused multi-step decode (docs/DESIGN.md "Fused multi-step
         # decode"): K engine steps per device dispatch — the decode
         # megastep. An int K pins the fusion depth; "auto" adapts K
@@ -613,6 +672,21 @@ class ContinuousScheduler:
         # (the chaos suite reconciles it against the injection
         # schedule) backing the bounded ring /debug/oom serves.
         reg.counter("oom_forensics_total", ("trigger",))
+        if self.block:
+            # Block-diffusion and expert-layer accounting, per block
+            # dispatch (docs/OBSERVABILITY.md "Block diffusion"). The
+            # moe_* families count the block step's forwards only (the
+            # prefill program returns no counts) and stay 0 on a dense
+            # model. rows_max / rows_mean over a window is the expert
+            # imbalance; experts_hit sizes the weight bytes a forward
+            # had to read.
+            reg.counter("diffusion_blocks_total")
+            reg.counter("diffusion_forwards_total", ("kind",))
+            reg.counter("diffusion_tokens_unmasked_total")
+            reg.counter("moe_rows_routed_total")
+            reg.counter("moe_expert_rows_max_total")
+            reg.counter("moe_expert_rows_mean_total")
+            reg.counter("moe_experts_hit_total")
         self.allocator = paged_kv.PageAllocator(self.num_pages, page_size)
         # Page-pool observatory (utils/pagemap.py): oryx_pool_* gauges
         # refreshed at scrape time + the free-time page-lifetime/idle
@@ -677,6 +751,10 @@ class ContinuousScheduler:
         )
         self.recent = np.full((S, stop_L), -2, np.int32)
         self.keys = jax.random.split(jax.random.key(seed), S)
+        # Block mode: the slot's open block (its known tokens first; the
+        # device fills the rest with the mask id) and how many are known.
+        self.blk = np.zeros((S, max(self.block, 1)), np.int32)
+        self.blk_known = np.zeros((S,), np.int32)
         self._ragged_blanks = None
         if self.ragged:
             # The pure-decode shape class's constant prefill operands,
@@ -753,7 +831,8 @@ class ContinuousScheduler:
             "engine_phase_seconds_total", ("phase",)
         )
         self._phase_seconds = {
-            p: fam.labels(phase=p).inc for p in ENGINE_PHASES
+            p: fam.labels(phase=p).inc
+            for p in (BLOCK_ENGINE_PHASES if self.block else ENGINE_PHASES)
         }
         self._phases = self._new_phase_clock()
         # Wide-event request log (utils/request_log.py): one canonical
@@ -1313,6 +1392,8 @@ class ContinuousScheduler:
         self.lengths[:] = 0
         self.tok[:] = 0
         self.recent[:] = -2
+        self.blk[:] = 0
+        self.blk_known[:] = 0
         self._check_pool_invariant()
 
     def _check_pool_invariant(self) -> None:
@@ -1709,6 +1790,8 @@ class ContinuousScheduler:
         self.top_p[s] = 1.0
         self.top_k[s] = 0
         self.recent[s] = -2
+        self.blk[s] = 0
+        self.blk_known[s] = 0
 
     def _grow_slot(self, s: int, tokens: int,
                    req: _Request | None = None) -> bool:
@@ -1912,7 +1995,10 @@ class ContinuousScheduler:
                     ):
                         with self._phase("housekeeping"):
                             self._ensure_capacity()
-                        self._step_chunk()
+                        if self.block:
+                            self._block_step()
+                        else:
+                            self._step_chunk()
             except Exception as e:  # surface to every in-flight client
                 msg = f"{type(e).__name__}: {e}"
                 for s, req in enumerate(self.slots):
@@ -2304,6 +2390,13 @@ class ContinuousScheduler:
                 self.prefix_cache.lookup_tiered(req.cache_tokens)
             )
         limit = max(req.length - 1, 0)
+        if self.block:
+            # Block mode prefills whole blocks only (the prompt's tail
+            # opens the first generated block) and needs no logit from
+            # the prefill, so all of them may come from the cache; a
+            # splice ends on a block edge.
+            limit = req.length - req.length % self.block
+            matched -= matched % self.block
         use = min(matched, limit)
         full = use // ps
         # Feasibility screen BEFORE any share or COW device copy: the
@@ -2361,7 +2454,7 @@ class ContinuousScheduler:
                 if reloaded:
                     host_reloaded = len(reloaded)
                     pages = pages + reloaded
-                    matched = len(pages) * ps
+                    matched = len(pages) * ps  # a block edge: ps % B == 0
                     use = min(matched, limit)
                     full = use // ps
         if cache_on:
@@ -2504,6 +2597,14 @@ class ContinuousScheduler:
         # contained by _run's catch-all (requests errored, pool reset).
         faults.fault_point("prefill_dispatch")
         hot_dispatch("scheduler._advance_prefill")
+        # Block mode prefills the prompt's whole blocks; its tail opens
+        # the first generated block (_activate).
+        L = req.length - (req.length % self.block if self.block else 0)
+        if req.prefill_pos >= L:
+            # Nothing (left) to prefill: a prompt shorter than a block,
+            # or whole blocks all spliced from the prefix cache.
+            self._activate(s, req, None, None)
+            return
         # The phase and the request's `prefill` span both end when the
         # ENQUEUE returns: they mark a dispatch, not the device's work.
         # The wait for this prompt's first token is `first_token`
@@ -2512,7 +2613,6 @@ class ContinuousScheduler:
         with self._phase("prefill", "dispatch"):
             B1 = np.newaxis
             off = req.prefill_pos
-            L = req.length
             if self.prefill_chunk is None and off == 0:
                 # Cold single-shot: the original full-embeds program.
                 emb, end = req.embeds, L
@@ -2600,6 +2700,9 @@ class ContinuousScheduler:
         would make sampled streams depend on scheduling history, and
         break eviction replay)."""
         req.activated = True
+        if self.block:
+            self._activate_block(s, req)
+            return
         # The engine's SECOND blocking point (the first is the harvest):
         # the prompt's last prefill chunk, and every dispatch enqueued
         # before it, must finish before this read returns.
@@ -2616,18 +2719,7 @@ class ContinuousScheduler:
             self.top_k[s] = req.topk
             self.recent[s] = -2
             self.keys = self.keys.at[s].set(key[0])
-            if not req.ttft_done:
-                req.ttft_done = True
-                ttft = time.monotonic() - req.submit_time
-                self.metrics.observe(
-                    "ttft_seconds", ttft, buckets=TTFT_BUCKETS,
-                )
-                req.handle.debug["ttft_s"] = ttft
-                if self.anomaly is not None:
-                    self.anomaly.observe_ttft(
-                        ttft, request_id=req.trace.id
-                    )
-                req.handle.debug["admit_chunk"] = self.chunks_run
+            self._observe_ttft(req)
             self.metrics.inc("admitted")
             self._donate_prefix(s, req, req.length)
             self._occupancy_gauge()
@@ -2639,6 +2731,44 @@ class ContinuousScheduler:
             self._advance(s, [int(self.tok[s])])
             if self.slots[s] is not None:
                 req.replay += 1
+
+    def _observe_ttft(self, req: _Request) -> None:
+        """The request's first token exists: observe its TTFT once."""
+        if req.ttft_done:
+            return
+        req.ttft_done = True
+        ttft = time.monotonic() - req.submit_time
+        self.metrics.observe("ttft_seconds", ttft, buckets=TTFT_BUCKETS)
+        req.handle.debug["ttft_s"] = ttft
+        if self.anomaly is not None:
+            self.anomaly.observe_ttft(ttft, request_id=req.trace.id)
+        req.handle.debug["admit_chunk"] = self.chunks_run
+
+    def _activate_block(self, s: int, req: _Request) -> None:
+        """Block mode's activation: the prompt's whole blocks are in the
+        cache (prefilled or spliced) and its tail `length % B` opens the
+        slot's first block. Nothing is read from the device: the first
+        tokens arrive with the first block's harvest, which is also
+        where TTFT is observed and the admission span ends. The slot's
+        key is the request's own key0 whatever was prefilled or
+        spliced, so a sampled stream replays the same after an
+        eviction."""
+        B = self.block
+        with self._phase("emit"):
+            tail = req.length % B
+            self.lengths[s] = req.length - tail
+            self.blk[s] = 0
+            if tail:
+                self.blk[s, :tail] = req.cache_tokens[req.length - tail:]
+            self.blk_known[s] = tail
+            self.finished[s] = False
+            self.temp[s] = req.temp
+            self.top_p[s] = req.topp
+            self.top_k[s] = req.topk
+            self.keys = self.keys.at[s].set(req.key0)
+            self.metrics.inc("admitted")
+            self._donate_prefix(s, req, req.length)
+            self._occupancy_gauge()
 
     def _donate_prefix(self, s: int, req: _Request, tokens: int) -> None:
         """Index the full-page prefix of slot s's first `tokens` logical
@@ -2873,9 +3003,102 @@ class ContinuousScheduler:
             )
             self._occupancy_gauge()
 
+    # hot-path
+    def _block_step(self) -> None:
+        """Block mode's engine step: ONE `paged_block_step` dispatch in
+        which every live slot generates its open block by diffusion
+        (T denoising forwards + one commit forward of num_slots x B
+        lanes), then the one harvest. The block's new tokens go through
+        `_advance` in position order, as one emission (one SSE chunk a
+        block); EOS and max_tokens cut inside it. A slot that goes on
+        opens an all-masked block at its new length."""
+        faults.fault_point("decode_dispatch")
+        hot_dispatch("scheduler._block_step")
+        gen = self.cfg.generation
+        with self._phase("denoise", "dispatch"):
+            sampled = self._profile_dispatch_begin()
+            t0 = time.monotonic()
+            t0_ns = trace_lib.now_ns()
+            with self.pipe._mesh_scope():
+                out = generate_lib.paged_block_step(
+                    self.pipe.params["llm"], self.cfg.llm, self.kv_pages,
+                    jnp.asarray(self.bt),
+                    jnp.asarray(self.blk),
+                    jnp.asarray(self.blk_known),
+                    jnp.asarray(self.lengths),
+                    jnp.asarray(self.finished),
+                    self.keys,
+                    jnp.asarray(self.temp),
+                    jnp.asarray(self.top_p),
+                    jnp.asarray(self.top_k),
+                    steps=gen.denoising_steps or self.block,
+                    remasking=gen.remasking,
+                    threshold=gen.confidence_threshold,
+                    eos=gen.eos_token_id,
+                    attn_impl=self.cfg.attn_impl,
+                    compute_dtype=oryx.compute_dtype(self.cfg),
+                )
+        self.kv_pages, toks, _, _, _, self.keys, counts = out
+        self.metrics.inc("harvest_total")
+        with self._phase("harvest", "blocked"):
+            # Three blocking copies (each costs about a millisecond
+            # once the first has waited, PERF.md section 6, PR 24): the
+            # tokens, the five statistics as one array, and the
+            # per-slot forwards. Lengths advance on the host.
+            # oryxlint: off=host-sync
+            toks = np.asarray(toks)
+            stats = dict(zip(
+                generate_lib.BLOCK_STATS,
+                (int(x) for x in np.asarray(counts["stats"])),
+            ))
+            slot_forwards = np.asarray(counts["slot_forwards"])
+            # oryxlint: on=host-sync
+        dt = time.monotonic() - t0
+        with self._phase("emit"):
+            dev_us = self._profile_dispatch_end(sampled, "block", t0_ns)
+            live = [
+                s for s, r in enumerate(self.slots)
+                if r is not None and r.activated
+            ]
+            self.lengths[~self.finished] += self.block  # the device's `live`
+            known = self.blk_known.copy()
+            # A slot that goes on opens an all-masked block; one that
+            # finishes below is cleared by _finish.
+            self.blk[:] = 0
+            self.blk_known[:] = 0
+            for s in live:
+                req = self.slots[s]
+                self._observe_ttft(req)
+                if req.adm_span >= 0:
+                    req.trace.end(req.adm_span)
+                    req.adm_span = -1
+            self._count_block_dispatch(len(live), stats)
+            self._finish_dispatch(
+                "block", len(live) * self.block, live,
+                {s: [int(t) for t in toks[s, known[s]:]] for s in live},
+                t0_ns, dt, device_us=dev_us,
+                slot_forwards=slot_forwards, forwards=stats["forwards"],
+            )
+            self._occupancy_gauge()
+
+    def _count_block_dispatch(self, live: int, stats: dict) -> None:
+        """The diffusion_* and moe_* families for one block dispatch
+        (`stats`: generate.BLOCK_STATS by name)."""
+        m = self.metrics
+        m.inc("diffusion_blocks_total", live)
+        m.inc("diffusion_forwards_total", stats["forwards"] - 1,
+              labels={"kind": "denoise"})
+        m.inc("diffusion_forwards_total", 1, labels={"kind": "commit"})
+        m.inc("diffusion_tokens_unmasked_total", stats["unmasked"])
+        m.inc("moe_rows_routed_total", stats["moe_rows_routed"])
+        m.inc("moe_expert_rows_max_total", stats["moe_rows_max"])
+        m.inc("moe_expert_rows_mean_total",
+              stats["moe_rows_routed"] / max(1, self.cfg.llm.num_experts))
+        m.inc("moe_experts_hit_total", stats["moe_experts_hit"])
+
     def _finish_dispatch(
         self, kind: str, rows: int, live: list[int], toks, t0_ns, dt,
-        n_new=None, device_us=None,
+        n_new=None, device_us=None, slot_forwards=None, forwards=None,
     ) -> None:
         """Post-dispatch accounting shared by the split decode chunk,
         the fused ragged step and the speculative step — ONE definition
@@ -2897,7 +3120,14 @@ class ContinuousScheduler:
         compute, visible as wasted steps), tokens consumed are the
         n_new prefix, and the accepted_tokens_per_step histogram
         observes each live slot's advance — its sum/count mean is the
-        speculation headline the bench gates on."""
+        speculation headline the bench gates on.
+
+        slot_forwards / forwards (block mode; `toks` is then already
+        {slot: the block's new tokens}): the dispatch ran `forwards`
+        forwards of every slot's lanes, and slot s had work to do in
+        slot_forwards[s] of them. The decode_steps family keeps its
+        meaning, slot-forwards dispatched and those of live slots with
+        work, and TPOT is the dispatch over the tokens a slot got."""
         self.chunks_run += 1
         self.metrics.inc("chunks")
         self.metrics.inc("dispatches_total", labels={"kind": kind})
@@ -2909,11 +3139,17 @@ class ContinuousScheduler:
         lane_steps = (
             1 + self.speculate if n_new is not None else self.chunk
         )
+        block = forwards is not None
+        if block:
+            lane_steps = forwards
+        # The dispatch gave a slot a varying number of tokens.
+        multi = block or n_new is not None
         useful = 0
         emitted = 0
-        for s, tokens in generate_lib.unpack_ragged_rows(
-            toks, live
-        ).items():
+        per_slot = toks if block else (
+            generate_lib.unpack_ragged_rows(toks, live)
+        )
+        for s, tokens in per_slot.items():
             req = self.slots[s]
             if req is None:
                 continue
@@ -2939,7 +3175,11 @@ class ContinuousScheduler:
             # fresh while neighbors splice and release shared pages.
             req.cost_decode_steps += lane_steps
             self._accrue_page_seconds(s)
-            useful += self._advance(s, tokens)
+            got = self._advance(s, tokens)
+            if block:
+                emitted += got
+                got = int(slot_forwards[s])
+            useful += got
         if live and n_new is not None and self.anomaly is not None:
             # Speculation drift guard (default-armed whenever
             # --speculate is set): the mean tokens a live slot advanced
@@ -2952,9 +3192,7 @@ class ContinuousScheduler:
             # Per-token latency: tokens per slot this dispatch is
             # `chunk` for the scan paths, the mean accepted advance for
             # the speculative path (the whole point: dt buys >1 token).
-            per_tok = (
-                emitted / len(live) if n_new is not None else self.chunk
-            )
+            per_tok = emitted / len(live) if multi else self.chunk
             self.metrics.observe(
                 "time_per_output_token_seconds", dt / max(1.0, per_tok)
             )
@@ -2964,7 +3202,7 @@ class ContinuousScheduler:
             self.metrics.inc("decode_steps_wasted", total - useful)
         self._timeline_record(
             dur_s=dt, kind=kind, rows=rows,
-            accepted=emitted if n_new is not None else useful,
+            accepted=emitted if multi else useful,
             device_us=device_us,
         )
 

@@ -164,7 +164,9 @@ def op_profile(
 
 # The dispatch kinds the serving engine emits (utils/timeline.py) plus
 # the "other" bucket for capture time outside every window.
-DISPATCH_KINDS = ("ragged", "spec", "prefill", "decode", "other")
+DISPATCH_KINDS = (
+    "ragged", "spec", "prefill", "decode", "block", "other",
+)
 
 
 def attribute_capture(

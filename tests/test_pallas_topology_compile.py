@@ -262,6 +262,63 @@ def test_serve_program_keeps_the_kv_pool_in_place(
     assert memory.temp_size_in_bytes < layer_k_plane
 
 
+def test_block_step_passes_the_expert_kernels_whole(one_chip, mosaic):
+    """`paged_block_step` of the SDAR-30B-A3B widths at depth 2 (32
+    slots x 4 lanes, 128 experts top-8 at width 768; vocabulary 1024 as
+    above): the grouped expert products are Mosaic kernels (the grouped
+    matmul jax ships, which `_grouped_dot` picks under attn_impl
+    "pallas" at these widths; no `ragged-dot` of XLA's is left and no
+    masked dense product), the donated pool is aliased, and no
+    temporary as large as ONE expert tensor of a layer exists. With the layer's `[E, in, out]` kernels as the layer scan's
+    xs the same compile puts a copy of each (403 MB, three a layer) in
+    front of every kernel call and holds 0.452 GB of temporaries; with
+    the stack passed whole, flat over layers (`qwen2._moe`), 0.048 GB."""
+    import dataclasses
+
+    from oryx_tpu import config as cfg_lib
+    from oryx_tpu.models import generate, qwen2
+
+    def on_chip(make):
+        return jax.tree_util.tree_map(
+            lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=one_chip),
+            jax.eval_shape(make),
+        )
+
+    cfg = dataclasses.replace(
+        cfg_lib.sdar_30b_a3b().llm, num_layers=2, vocab_size=1024,
+        mask_token_id=1023,
+    )
+    S, page_size, ctx = 32, 64, 1024
+    rows = lambda dtype, *tail: jax.ShapeDtypeStruct(  # noqa: E731
+        (S, *tail), dtype, sharding=one_chip
+    )
+    params = on_chip(
+        lambda: qwen2.init_params(cfg, jax.random.key(0), dtype=BF16)
+    )
+    kv = on_chip(lambda: qwen2.init_paged_kv_cache(
+        cfg, S * ctx // page_size, page_size, dtype=BF16))
+    compiled = generate.paged_block_step.lower(
+        params, cfg, kv, rows(jnp.int32, ctx // page_size),
+        rows(jnp.int32, cfg.block_length), rows(jnp.int32), rows(jnp.int32),
+        rows(jnp.bool_),
+        on_chip(lambda: jax.random.split(jax.random.key(0), S)),
+        rows(jnp.float32), rows(jnp.float32), rows(jnp.int32),
+        steps=2, remasking="low_confidence_static", threshold=0.9,
+        eos=0, attn_impl="pallas", compute_dtype=BF16,
+    ).compile()
+    text = compiled.as_text()
+    assert "tpu_custom_call" in text and "ragged-dot" not in text
+    memory = compiled.memory_analysis()
+    pool_bytes = sum(
+        a.size * a.dtype.itemsize for a in jax.tree_util.tree_leaves(kv)
+    )
+    assert memory.alias_size_in_bytes == pool_bytes
+    one_expert_tensor = (
+        cfg.num_experts * cfg.hidden_size * cfg.moe_intermediate_size * 2
+    )
+    assert memory.temp_size_in_bytes < one_expert_tensor // 4
+
+
 def test_illegal_heads_per_block_pin_raises_with_its_name(
     one_chip, monkeypatch
 ):
