@@ -76,12 +76,20 @@ def truncate_logits_rows(
     t = jnp.where(is_greedy, 1.0, temperature)[:, None]
     l = logits / t
     tk = jnp.clip(top_k.astype(jnp.int32), 0, V)
-    srt = jnp.sort(l, axis=-1)  # ascending
+    # ONE sort serves both cuts: the k-th largest is srt_d[tk - 1], and
+    # the top-k-masked row sorted descending is srt_d with everything
+    # under the k-th value at -inf (the same multiset in the same
+    # order, ties at the k-th place kept). Only the sorted VALUES are
+    # read, and equal values are interchangeable, so the sort need not
+    # be stable: a stable one drags an index operand along and takes
+    # twice as long on the TPU.
+    srt_d = jnp.sort(l, axis=-1, stable=False)[:, ::-1]
     kth = jnp.take_along_axis(
-        srt, jnp.clip(V - tk, 0, V - 1)[:, None], axis=-1
+        srt_d, jnp.clip(tk - 1, 0, V - 1)[:, None], axis=-1
     )
-    l = jnp.where((tk > 0)[:, None] & (l < kth), -jnp.inf, l)
-    srt_d = jnp.sort(l, axis=-1)[:, ::-1]
+    has_k = (tk > 0)[:, None]
+    l = jnp.where(has_k & (l < kth), -jnp.inf, l)
+    srt_d = jnp.where(has_k & (srt_d < kth), -jnp.inf, srt_d)
     probs = jax.nn.softmax(srt_d, axis=-1)
     cum = jnp.cumsum(probs, axis=-1)
     # Smallest prefix with cumulative prob >= top_p (keeps the top token).
@@ -105,18 +113,30 @@ def sample_token_rows(
     traced arrays and its own key, so a row's draw is a function of that
     row alone — admitting or finishing a neighbor never perturbs an
     in-flight request's sample stream, and mixed sampling configs share
-    ONE compiled decode."""
+    ONE compiled decode.
+
+    The cost follows what the rows ask for: a call whose rows are ALL
+    greedy (empty and retired slots carry temperature 0) takes the
+    conditional's argmax branch, and only a call that holds a sampled
+    row pays for the sort and the noise. Callers split `keys` outside,
+    so the branch taken never moves a row's random stream."""
     V = logits.shape[-1]
     greedy = jnp.argmax(logits, axis=-1).astype(jnp.int32)
-    l, is_greedy = truncate_logits_rows(
-        logits, temperature=temperature, top_p=top_p, top_k=top_k
+
+    def sampled_rows():
+        l, is_greedy = truncate_logits_rows(
+            logits, temperature=temperature, top_p=top_p, top_k=top_k
+        )
+        # Per-row Gumbel-max with per-row keys (categorical over one
+        # shared key would couple a row's draw to its batch position).
+        u = jax.vmap(lambda k: jax.random.uniform(k, (V,)))(keys)
+        g = -jnp.log(-jnp.log(jnp.maximum(u, jnp.finfo(jnp.float32).tiny)))
+        sampled = jnp.argmax(l + g, axis=-1).astype(jnp.int32)
+        return jnp.where(is_greedy, greedy, sampled)
+
+    return jax.lax.cond(
+        jnp.all(temperature <= 0.0), lambda: greedy, sampled_rows
     )
-    # Per-row Gumbel-max with per-row keys (categorical over one shared
-    # key would couple a row's draw to its batch position).
-    u = jax.vmap(lambda k: jax.random.uniform(k, (V,)))(keys)
-    g = -jnp.log(-jnp.log(jnp.maximum(u, jnp.finfo(jnp.float32).tiny)))
-    sampled = jnp.argmax(l + g, axis=-1).astype(jnp.int32)
-    return jnp.where(is_greedy, greedy, sampled)
 
 
 def make_stop_sequences(
@@ -1533,9 +1553,9 @@ def paged_block_step(
     none rides along unchanged. Each forward takes x0 (argmax, or a
     sample) and its softmax probability in float32 as confidence at
     every lane, and `block_unmask` fixes some of the masked ones.
-    Greedy rows cost an argmax and a logsumexp; the sorting sampler
-    (`sample_token_rows`) runs only in a dispatch in which some live
-    row has temperature > 0. Then ONE commit forward over the final
+    Greedy rows cost an argmax and a logsumexp: `sample_token_rows`
+    sorts only in a dispatch in which some row has temperature > 0
+    (retired rows carry 0). Then ONE commit forward over the final
     tokens writes the block's K/V (every forward writes the lanes' K/V
     at their positions, past the committed length; the last write is
     what stays), so a block costs T + 1 forwards of S x B lanes.
@@ -1560,7 +1580,6 @@ def paged_block_step(
     lane = jnp.arange(B, dtype=jnp.int32)
     masked0 = (lane[None, :] >= n_known[:, None]) & live[:, None]
     block = jnp.where(masked0, cfg.mask_token_id, block).astype(jnp.int32)
-    greedy_only = ~jnp.any(live & (temperature > 0.0))
 
     def forward(kv, ids):
         lg, kv, routing = _block_lanes_forward(
@@ -1583,19 +1602,12 @@ def paged_block_step(
     def pick(lg, keys):
         """x0 and its confidence at every lane: [S*B, V] -> [S, B] x2."""
         pair = jax.vmap(lambda k: jax.random.split(k, 2))(keys)
-
-        def sampled():
-            lane_keys = jax.vmap(lambda k: jax.random.split(k, B))(
-                pair[:, 1]
-            ).reshape(S * B)
-            return sample_token_rows(
-                lg, lane_keys, temperature=jnp.repeat(temperature, B),
-                top_p=jnp.repeat(top_p, B), top_k=jnp.repeat(top_k, B),
-            )
-
-        x0 = jax.lax.cond(
-            greedy_only,
-            lambda: jnp.argmax(lg, axis=-1).astype(jnp.int32), sampled,
+        lane_keys = jax.vmap(lambda k: jax.random.split(k, B))(
+            pair[:, 1]
+        ).reshape(S * B)
+        x0 = sample_token_rows(
+            lg, lane_keys, temperature=jnp.repeat(temperature, B),
+            top_p=jnp.repeat(top_p, B), top_k=jnp.repeat(top_k, B),
         )
         conf = jnp.exp(
             jnp.take_along_axis(lg, x0[:, None], axis=-1)[:, 0]

@@ -631,6 +631,10 @@ class ContinuousScheduler:
         # only, one per step) and the packed-buffer occupancy each one
         # carried (docs/OBSERVABILITY.md).
         reg.counter("dispatches_total", ("kind",))
+        # ... and how many of them held a row with temperature > 0: the
+        # dispatches whose sampler took its sorting branch
+        # (generate.sample_token_rows decides on the same array).
+        reg.counter("sampler_sort_dispatches_total", ("kind",))
         reg.histogram("dispatch_rows", DISPATCH_ROWS_BUCKETS)
         # Fused-decode observability: the K currently in effect (gauge,
         # so a dashboard sees adaptive-K transitions) and how many
@@ -2666,12 +2670,7 @@ class ContinuousScheduler:
             "prefill_chunk_tokens", end - off,
             buckets=PREFILL_CHUNK_BUCKETS,
         )
-        self.metrics.inc(
-            "dispatches_total", labels={"kind": "prefill"}
-        )
-        self.metrics.observe(
-            "dispatch_rows", end - off, buckets=DISPATCH_ROWS_BUCKETS
-        )
+        self._count_dispatch("prefill", end - off, req.temp)
         # Split-path prefill dispatches are engine steps too: record
         # them so timeline dispatch-kind counts reconcile with
         # oryx_serving_dispatches_total on every engine mode.
@@ -3096,9 +3095,27 @@ class ContinuousScheduler:
               stats["moe_rows_routed"] / max(1, self.cfg.llm.num_experts))
         m.inc("moe_experts_hit_total", stats["moe_experts_hit"])
 
+    def _count_dispatch(self, kind: str, rows: int, *temps) -> None:
+        """ONE device dispatch happened: its kind, its rows, and whether
+        a row handed to it asked for sampling. `temps` are the
+        temperatures the dispatch was given (the slots' array, a
+        prefilled prompt's own), read before any slot retires. The
+        sampler's conditional (generate.sample_token_rows) decides on
+        the same values, so the second counter counts the dispatches
+        that paid for a sort without a read from the device."""
+        self.metrics.inc("dispatches_total", labels={"kind": kind})
+        if any(np.any(np.asarray(t) > 0) for t in temps):
+            self.metrics.inc(
+                "sampler_sort_dispatches_total", labels={"kind": kind}
+            )
+        self.metrics.observe(
+            "dispatch_rows", rows, buckets=DISPATCH_ROWS_BUCKETS
+        )
+
     def _finish_dispatch(
         self, kind: str, rows: int, live: list[int], toks, t0_ns, dt,
         n_new=None, device_us=None, slot_forwards=None, forwards=None,
+        pf_temp: float = 0.0,
     ) -> None:
         """Post-dispatch accounting shared by the split decode chunk,
         the fused ragged step and the speculative step — ONE definition
@@ -3122,6 +3139,10 @@ class ContinuousScheduler:
         observes each live slot's advance — its sum/count mean is the
         speculation headline the bench gates on.
 
+        pf_temp: the temperature of the prompt whose window rode in
+        this dispatch (ragged and speculative steps), for
+        `_count_dispatch`.
+
         slot_forwards / forwards (block mode; `toks` is then already
         {slot: the block's new tokens}): the dispatch ran `forwards`
         forwards of every slot's lanes, and slot s had work to do in
@@ -3130,10 +3151,7 @@ class ContinuousScheduler:
         work, and TPOT is the dispatch over the tokens a slot got."""
         self.chunks_run += 1
         self.metrics.inc("chunks")
-        self.metrics.inc("dispatches_total", labels={"kind": kind})
-        self.metrics.observe(
-            "dispatch_rows", rows, buckets=DISPATCH_ROWS_BUCKETS
-        )
+        self._count_dispatch(kind, rows, self.temp, pf_temp)
         if self.watchdog is not None:
             self.watchdog.beat()
         lane_steps = (
@@ -3369,6 +3387,7 @@ class ContinuousScheduler:
         dtype = oryx.compute_dtype(self.cfg)
         pf_span = -1
         pf_off = pf_len = 0
+        pf_temp = pf_req.temp if pf_req is not None else 0.0
         if pf_req is not None:
             # Packing the prompt's window is the prefill's host part;
             # its enqueue is the fused dispatch below.
@@ -3456,7 +3475,7 @@ class ContinuousScheduler:
                 )
                 self._finish_dispatch(
                     "spec", rows, live, toks, t0_ns, dt, n_new=n_new,
-                    device_us=dev_us,
+                    device_us=dev_us, pf_temp=pf_temp,
                 )
         else:
             with self._phase("decode", "dispatch"):
@@ -3502,7 +3521,8 @@ class ContinuousScheduler:
                     min(W, pf_len - pf_off) if pf_req is not None else 0
                 )
                 self._finish_dispatch(
-                    "ragged", rows, live, toks, t0_ns, dt, device_us=dev_us
+                    "ragged", rows, live, toks, t0_ns, dt,
+                    device_us=dev_us, pf_temp=pf_temp,
                 )
         # Prefill bookkeeping + activation (after harvest by design).
         if pf_req is not None:
@@ -3686,10 +3706,7 @@ class ContinuousScheduler:
         j+1.. — its remaining device columns are frozen filler the
         sequential path would never have dispatched, discarded here
         the same way."""
-        self.metrics.inc("dispatches_total", labels={"kind": kind})
-        self.metrics.observe(
-            "dispatch_rows", rows, buckets=DISPATCH_ROWS_BUCKETS
-        )
+        self._count_dispatch(kind, rows, self.temp)
         if self.watchdog is not None:
             self.watchdog.beat()
         width = (1 + self.speculate) if n_new is not None else self.chunk

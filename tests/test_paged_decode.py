@@ -516,3 +516,217 @@ def test_sample_token_rows_per_row_behavior():
     )
     np.testing.assert_array_equal(np.asarray(clamped),
                                   np.asarray(unfiltered))
+
+
+# ---------------------------------------------------------------------------
+# The sampler does only what its rows ask for: an all-greedy call takes
+# a conditional's argmax branch, any other call sorts once
+# ---------------------------------------------------------------------------
+
+
+def _truncate_two_sorts(logits, *, temperature, top_p, top_k):
+    """`truncate_logits_rows` as it stood before the single sort, kept
+    verbatim: the reference the one-sort code is held to, bit for bit."""
+    V = logits.shape[-1]
+    is_greedy = temperature <= 0.0
+    t = jnp.where(is_greedy, 1.0, temperature)[:, None]
+    l = logits / t
+    tk = jnp.clip(top_k.astype(jnp.int32), 0, V)
+    srt = jnp.sort(l, axis=-1)  # ascending
+    kth = jnp.take_along_axis(
+        srt, jnp.clip(V - tk, 0, V - 1)[:, None], axis=-1
+    )
+    l = jnp.where((tk > 0)[:, None] & (l < kth), -jnp.inf, l)
+    srt_d = jnp.sort(l, axis=-1)[:, ::-1]
+    probs = jax.nn.softmax(srt_d, axis=-1)
+    cum = jnp.cumsum(probs, axis=-1)
+    # Smallest prefix with cumulative prob >= top_p (keeps the top token).
+    cutoff_idx = jnp.sum(cum < top_p[:, None], axis=-1)
+    cutoff = jnp.take_along_axis(srt_d, cutoff_idx[:, None], axis=-1)
+    l = jnp.where((top_p < 1.0)[:, None] & (l < cutoff), -jnp.inf, l)
+    return l, is_greedy
+
+
+def _sample_two_sorts(logits, keys, *, temperature, top_p, top_k):
+    """`sample_token_rows` as it stood before its conditional, kept
+    verbatim: every call sorts twice and draws its noise."""
+    V = logits.shape[-1]
+    greedy = jnp.argmax(logits, axis=-1).astype(jnp.int32)
+    l, is_greedy = _truncate_two_sorts(
+        logits, temperature=temperature, top_p=top_p, top_k=top_k
+    )
+    u = jax.vmap(lambda k: jax.random.uniform(k, (V,)))(keys)
+    g = -jnp.log(-jnp.log(jnp.maximum(u, jnp.finfo(jnp.float32).tiny)))
+    sampled = jnp.argmax(l + g, axis=-1).astype(jnp.int32)
+    return jnp.where(is_greedy, greedy, sampled)
+
+
+_SV = 64  # the sampler cases' vocabulary
+
+
+def _sampler_rows(k):
+    """[8, _SV] logits: free rows, rows on a grid of 0.5 (ties
+    everywhere), and a row whose sorted places k-2..k+2 hold one value
+    (a tie AT the k-th place, when k lies inside the row)."""
+    rng = np.random.default_rng(7)
+    x = rng.standard_normal((8, _SV)).astype(np.float32) * 3.0
+    x[2:5] = np.round(x[2:5] * 2.0) / 2.0
+    kk = int(np.clip(k, 3, _SV - 3))
+    order = np.argsort(-x[5])
+    x[5, order[kk - 3:kk + 2]] = x[5, order[kk - 1]]
+    return jnp.asarray(x)
+
+
+def _bits(a):
+    return np.asarray(a).view(np.int32)
+
+
+@pytest.mark.parametrize("top_p", [0.1, 0.8, 1.0])
+@pytest.mark.parametrize("top_k", [0, 1, 20, _SV, _SV + 5])
+@pytest.mark.parametrize("temperature", [0.0, 0.7, 1.3])
+def test_one_sort_sampler_matches_the_two_sort_code_bit_for_bit(
+    temperature, top_k, top_p
+):
+    """Truncated logits and sampled ids equal the two-sort code's for
+    equal keys: a call of eight rows under one setting, and the same
+    rows with greedy and otherwise-set neighbours mixed in."""
+    logits = _sampler_rows(top_k)
+    S = logits.shape[0]
+    uniform = (
+        jnp.full((S,), temperature), jnp.full((S,), top_p),
+        jnp.full((S,), top_k, jnp.int32),
+    )
+    mixed = (
+        uniform[0].at[::3].set(0.0).at[1].set(0.9),
+        uniform[1].at[1].set(0.5), uniform[2].at[1].set(3),
+    )
+    for t, p, k in (uniform, mixed):
+        kw = dict(temperature=t, top_p=p, top_k=k)
+        got, greedy = gen_lib.truncate_logits_rows(logits, **kw)
+        want, want_greedy = _truncate_two_sorts(logits, **kw)
+        np.testing.assert_array_equal(_bits(got), _bits(want))
+        np.testing.assert_array_equal(
+            np.asarray(greedy), np.asarray(want_greedy))
+        # Equal ids need equal noise: both sides split the same seed.
+        ids = gen_lib.sample_token_rows(
+            logits, jax.random.split(jax.random.key(11), S), **kw)
+        want_ids = _sample_two_sorts(
+            logits, jax.random.split(jax.random.key(11), S), **kw)
+        np.testing.assert_array_equal(np.asarray(ids), np.asarray(want_ids))
+
+
+@pytest.mark.parametrize("neighbour", ["greedy", "sampled"])
+def test_a_rows_draw_does_not_depend_on_the_branch_its_call_takes(
+    tiny_llm, neighbour
+):
+    """A row's tokens and its advanced key are the same whether the
+    dispatch took the argmax branch or the sorting one: a greedy row
+    beside a greedy or a sampled neighbour, a sampled row likewise."""
+    cfg, params = tiny_llm
+    ps, S = 8, 2
+    mine = 0.0 if neighbour == "sampled" else 0.8
+
+    def run(other):
+        kv = qwen2.init_paged_kv_cache(cfg, 4, ps, dtype=jnp.float32)
+        out = gen_lib.paged_decode_chunk(
+            params, cfg, kv, jnp.asarray([[0, 1], [2, 3]], jnp.int32),
+            jnp.asarray([5, 9], jnp.int32), jnp.zeros((S,), jnp.int32),
+            jnp.zeros((S,), bool), jnp.zeros((S, 0), jnp.int32),
+            jax.random.split(jax.random.key(3), S),
+            jnp.asarray([mine, other]), jnp.asarray([0.9, 0.9]),
+            jnp.asarray([0, 12], jnp.int32), None, chunk=4, eos=-1,
+        )
+        return np.asarray(out[6])[:, 0], jax.random.key_data(out[5])[0]
+
+    # `mine` beside a greedy neighbour, then beside a sampled one: with
+    # mine == 0 the first call is all greedy and the second is not.
+    toks_a, key_a = run(0.0)
+    toks_b, key_b = run(1.1)
+    np.testing.assert_array_equal(toks_a, toks_b)
+    np.testing.assert_array_equal(np.asarray(key_a), np.asarray(key_b))
+
+
+def _sorts_in(jaxpr, inside=False):
+    """[(is inside a conditional's branch)] for every sort in a jaxpr,
+    through every nested program (scan bodies, calls, branches)."""
+    found = []
+    for eqn in jaxpr.eqns:
+        if eqn.primitive.name == "sort":
+            found.append(inside)
+        below = inside or eqn.primitive.name == "cond"
+        for value in eqn.params.values():
+            for sub in value if isinstance(value, (tuple, list)) else (value,):
+                sub = getattr(sub, "jaxpr", sub)
+                if hasattr(sub, "eqns"):
+                    found += _sorts_in(sub, below)
+    return found
+
+
+@pytest.mark.parametrize("program", ["paged_decode_chunk", "paged_prefill"])
+def test_step_programs_sort_once_and_only_inside_the_conditional(
+    tiny_llm, program
+):
+    """One sampler call a program, one sort in it, and that sort in a
+    branch of a conditional that survives lowering (the two-sort code
+    had two, outside any conditional)."""
+    cfg, params = tiny_llm
+    S = 3 if program == "paged_decode_chunk" else 1
+    kv = qwen2.init_paged_kv_cache(cfg, 8, 8, dtype=jnp.float32)
+    tables = jnp.zeros((S, 2), jnp.int32)
+    sampling = (
+        jax.random.split(jax.random.key(0), S), jnp.zeros((S,)),
+        jnp.ones((S,)), jnp.zeros((S,), jnp.int32),
+    )
+    if program == "paged_decode_chunk":
+        traced = gen_lib.paged_decode_chunk.trace(
+            params, cfg, kv, tables, jnp.zeros((S,), jnp.int32),
+            jnp.zeros((S,), jnp.int32), jnp.zeros((S,), bool),
+            jnp.zeros((S, 0), jnp.int32), *sampling, None, chunk=4, eos=-1,
+        )
+    else:
+        traced = gen_lib.paged_prefill.trace(
+            params, cfg, jnp.zeros((S, 16, cfg.hidden_size)),
+            jnp.asarray([9], jnp.int32), tables, kv,
+            jnp.zeros((S,), jnp.int32), *sampling,
+        )
+    assert _sorts_in(traced.jaxpr.jaxpr) == [True]
+    assert "stablehlo.case" in traced.lower().as_text()
+
+
+@pytest.mark.parametrize("temperature", [0.0, 0.9])
+def test_block_step_tokens_equal_the_two_sort_samplers(
+    monkeypatch, temperature
+):
+    """`paged_block_step` through the shared sampler gives the tokens
+    and keys it gave with its own conditional around the two-sort
+    sampler, for a greedy and for a sampled dispatch (a finished slot
+    with temperature 0 rides along in both)."""
+    cfg = cfg_lib.sdar_tiny().llm
+    params = qwen2.init_params(cfg, jax.random.key(0))
+    S, B = 3, cfg.block_length
+
+    def run(step):
+        kv = qwen2.init_paged_kv_cache(cfg, 6, 16, dtype=jnp.float32)
+        out = step(
+            params, cfg, kv, jnp.arange(6, dtype=jnp.int32).reshape(S, 2),
+            jnp.asarray(np.arange(S * B).reshape(S, B) % 90, jnp.int32),
+            jnp.asarray([0, 2, 0], jnp.int32), jnp.zeros((S,), jnp.int32),
+            jnp.asarray([False, False, True]),
+            jax.random.split(jax.random.key(5), S),
+            jnp.asarray([temperature, temperature, 0.0]),
+            jnp.asarray([0.9, 1.0, 1.0]), jnp.asarray([0, 7, 0], jnp.int32),
+            steps=2, remasking="low_confidence_static", threshold=0.9,
+            eos=-1,
+        )
+        return np.asarray(out[1]), np.asarray(jax.random.key_data(out[5]))
+
+    toks, keys = run(gen_lib.paged_block_step)
+    # The same program traced anew over the two-sort sampler.
+    monkeypatch.setattr(gen_lib, "sample_token_rows", _sample_two_sorts)
+    old_toks, old_keys = run(jax.jit(
+        gen_lib.paged_block_step.__wrapped__,
+        static_argnames=("cfg", "steps", "remasking", "threshold", "eos",
+                         "attn_impl", "compute_dtype"),
+    ))
+    np.testing.assert_array_equal(toks, old_toks)
+    np.testing.assert_array_equal(keys, old_keys)

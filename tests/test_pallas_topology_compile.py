@@ -188,8 +188,8 @@ def _lower_serve_program(program, pool, one_chip):
     abstract arguments: the Oryx-7B decoder's widths at depth 4, 16
     slots x 4096 tokens, page 64, the Pallas kernels. The vocabulary is
     cut to 1024: it is the sampler's width and no pool op's, and at
-    152,064 the TPU compiler spends 18 of a case's 20 s on the
-    sampler's sorts, beside the suite's other workers."""
+    152,064 the TPU compiler spends 10 of a case's 12-13 s on the
+    sampler's sort, beside the suite's other workers."""
     from oryx_tpu.config import LLMConfig
     from oryx_tpu.models import generate, qwen2
 

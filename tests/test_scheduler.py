@@ -636,3 +636,69 @@ def test_stop_string_mid_chunk_not_billed_useful(pipe):
     assert h2.usage == (4, 1)
     assert useful == 1, f"EOS after the stop billed useful: {useful}"
     sched.close()
+
+
+def _sorts_and_dispatches(metrics, kind):
+    reg = metrics.registry
+    return (
+        reg.counter("sampler_sort_dispatches_total", ("kind",))
+        .labels(kind=kind).value,
+        reg.counter("dispatches_total", ("kind",)).labels(kind=kind).value,
+    )
+
+
+@pytest.mark.parametrize("mode", ["split", "block"])
+def test_sampler_sort_counter_counts_the_dispatches_with_a_sampled_row(
+    pipe, monkeypatch, mode
+):
+    """`sampler_sort_dispatches_total{kind=}` stays 0 over greedy
+    requests, and over a mix it counts exactly the dispatches whose
+    temperature argument held a value above 0: read off the arguments
+    the device programs were given, which is what the sampler's
+    conditional decides on."""
+    from oryx_tpu.serve import scheduler as sched_lib
+
+    if mode == "block":
+        cfg = cfg_lib.sdar_tiny()
+        pipe = OryxInference(
+            FakeTokenizer(), oryx.init_params(cfg, jax.random.key(0)), cfg)
+        kw = {"prefill_chunk": 32}
+        step = ("block", "paged_block_step")
+    else:
+        kw = {"chunk": 4}
+        step = ("decode", "paged_decode_chunk")
+    # (program, position of its temperature argument)
+    seen = {"prefill": [], step[0]: []}
+    for kind, name, at in (("prefill", "paged_prefill", 8), (*step, 9)):
+        real = getattr(sched_lib.generate_lib, name)
+
+        def spy(*a, _real=real, _kind=kind, _at=at, **k):
+            seen[_kind].append(bool(np.any(np.asarray(a[_at]) > 0)))
+            return _real(*a, **k)
+
+        monkeypatch.setattr(sched_lib.generate_lib, name, spy)
+
+    def run(reqs):
+        metrics = ServingMetrics()
+        sched = ContinuousScheduler(
+            pipe, num_slots=2, page_size=16, max_ctx=256, metrics=metrics,
+            autostart=False, **kw,
+        )
+        for v in seen.values():
+            v.clear()
+        _run_all(sched, reqs)
+        assert "sampler_sort_dispatches_total" in metrics.registry.render()
+        return {kind: _sorts_and_dispatches(metrics, kind) for kind in seen}
+
+    greedy = [("hello there, friend", 9, None), ("what now?", 6, None),
+              ("tell me more, then", 5, {"temperature": 0.0})]
+    for kind, (sorts, dispatches) in run(greedy).items():
+        assert sorts == 0 and dispatches == len(seen[kind]) > 0
+    mixed = [("hello there, friend", 14, None),
+             ("what now? and then what, and why", 3,
+              {"temperature": 0.9, "top_p": 0.9, "seed": 3}),
+             ("tell me more, then", 5, None)]
+    for kind, (sorts, dispatches) in run(mixed).items():
+        assert dispatches == len(seen[kind])
+        assert sorts == sum(seen[kind])
+        assert 0 < sorts < dispatches
