@@ -43,9 +43,8 @@ from __future__ import annotations
 
 # The manifest: one declaration, read by the static rule from this
 # comment and by the runtime sanitizer from the tuple beneath it.
-# lock-order: server.stream_lock < scheduler._cond < anomaly._lock < trace._lock < tracer._lock < request_log._lock < forensics._lock < audit._lock < watchdog._lock < router._lock < registry._lock < metrics.family
+# lock-order: scheduler._cond < anomaly._lock < trace._lock < tracer._lock < request_log._lock < forensics._lock < audit._lock < watchdog._lock < router._lock < registry._lock < metrics.family
 LOCK_ORDER: tuple[str, ...] = (
-    "server.stream_lock",   # window-engine device lock (api_server)
     "scheduler._cond",      # admission queue + control flags
     "anomaly._lock",        # anomaly episode state + events.jsonl sink
     "trace._lock",          # one request's span list

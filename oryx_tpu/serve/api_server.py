@@ -1,4 +1,4 @@
-"""OpenAI-compatible chat API server with dynamic batching.
+"""OpenAI-compatible chat API server over the continuous-batching engine.
 
 Beyond-parity serving front-end (the reference ships only a CLI/Gradio
 demo; SURVEY.md §2 "Inference example / demo"): an HTTP endpoint speaking
@@ -69,18 +69,15 @@ Content may be a plain string or OpenAI content-part lists; image parts
 history maps onto the conversation template; media bind to the FIRST
 user turn (as everywhere in this framework) and are rejected elsewhere
 with a 400. `temperature`, `top_p`, `stop` and `seed` are honored per
-request (requests batch together only when they match); `n > 1` and
-`logprobs` are rejected with a 400 rather than silently ignored.
+request (per slot: differing requests share one resident batch); `n > 1`
+and `logprobs` are rejected with a 400 rather than silently ignored.
 
-Dynamic batching: non-streaming requests arriving within `batch_window`
-seconds are decoded as ONE `chat_batch` program (the TPU batching win);
-`stream=true` requests run singly via `chat_stream` and emit SSE chunks.
-With `--engine continuous`, ALL requests (streaming and not) instead
-flow through the continuous-batching scheduler (serve/scheduler.py):
-a fixed slot array decoding over a paged KV cache, with admission and
-retirement at chunk boundaries. `GET /metrics` (Prometheus text format)
-reports queue depth, slot occupancy, admitted/evicted counts and
-TTFT / per-token latency histograms for either engine.
+One request path: every request (streaming and not) goes from the HTTP
+handler through `Engine.submit` into the continuous-batching scheduler
+(serve/scheduler.py): a fixed slot array decoding over a paged KV cache,
+with admission and retirement at chunk boundaries. `GET /metrics`
+(Prometheus text format) reports queue depth, slot occupancy,
+admitted/evicted counts and TTFT / per-token latency histograms.
 
     python -m oryx_tpu.serve.api_server --model-path models/oryx7b-sft \
         [--shard tp=8] [--port 8000]
@@ -93,7 +90,6 @@ import base64
 import io
 import json
 import os
-import queue
 import subprocess
 import threading
 import time
@@ -105,7 +101,6 @@ from typing import Any
 import numpy as np
 
 from oryx_tpu.analysis import sanitizers
-from oryx_tpu.analysis.sanitizers import named_lock
 from oryx_tpu.serve import journal as journal_lib
 from oryx_tpu.utils import faults
 from oryx_tpu.utils import trace as trace_lib
@@ -315,184 +310,10 @@ class EngineSupervisor(threading.Thread):
                 traceback.print_exc()
 
 
-def _decode_bucket(max_new: int) -> int:
-    """Decode-length bucket: next power of two, floor 16. Requests whose
-    max_tokens fall in the same bucket batch TOGETHER — the group decodes
-    the bucket length and each row trims to its own cap
-    (pipeline.chat_batch per_row_max). Also bounds the compiled-program
-    count: one decode program per bucket, not per distinct max_tokens."""
-    return max(16, 1 << (max_new - 1).bit_length())
-
-
-class _Pending:
-    def __init__(
-        self, request: dict[str, Any], max_new: int,
-        sampling: dict[str, Any] | None = None,
-        trace: trace_lib.Trace | None = None,
-    ):
-        self.request = request
-        self.max_new = max_new
-        # Decode-program parameters: requests batch together only when
-        # ALL of these match (they share one compiled decode).
-        self.sampling = sampling or {}
-        self.done = threading.Event()
-        self.reply: str | None = None
-        self.finish_reason: str = "stop"
-        self.usage: tuple[int, int] | None = None
-        self.error: str | None = None
-        self.trace = trace
-        self.request_id = trace.id if trace else trace_lib.new_request_id()
-        self._qw = trace.begin("queue_wait") if trace else -1
-
-    @property
-    def batch_key(self) -> tuple:
-        s = self.sampling
-        # A sampled row's draw depends on its ROW INDEX in the batch
-        # (per-row Gumbel noise), so an explicitly seeded request only
-        # reproduces at a fixed row — run it solo (unique key) instead
-        # of batching it with look-alikes.
-        solo = id(self) if "seed" in s else None
-        return (
-            _decode_bucket(self.max_new), s.get("temperature"),
-            s.get("top_p"), tuple(s.get("stop") or ()), s.get("seed"),
-            solo,
-        )
-
-
-class Batcher:
-    """Groups concurrent non-streaming requests into one chat_batch call.
-
-    A single worker thread drains the queue: it waits `window` seconds
-    after the first pending request for company (requests batch together
-    when their max_tokens share a decode-length BUCKET and their
-    sampling parameters match — each row trims to its own cap), then
-    runs the whole group as one compiled decode. `device_lock`
-    serializes the device against concurrent streaming requests; HTTP
-    threads only enqueue and wait.
-    """
-
-    def __init__(
-        self,
-        pipe,
-        *,
-        window: float = 0.02,
-        max_batch: int = 8,
-        device_lock: threading.Lock | None = None,
-        metrics=None,
-        tracer: trace_lib.Tracer | None = None,
-    ):
-        from oryx_tpu.utils.metrics import ServingMetrics
-
-        self.pipe = pipe
-        self.window = window
-        self.max_batch = max_batch
-        self.device_lock = device_lock or threading.Lock()  # lock-name: server.stream_lock
-        self.metrics = metrics or ServingMetrics()
-        # Same span vocabulary as the continuous scheduler (queue_wait /
-        # decode / emission in one "decode" window here), so /debug
-        # traces from both engines are directly comparable.
-        self.tracer = tracer or trace_lib.Tracer()
-        self.q: queue.Queue[_Pending] = queue.Queue()
-        # A request popped from the queue whose max_tokens mismatched the
-        # group in flight; it LEADS the next group (FIFO — re-queueing to
-        # the tail could starve it under sustained mixed traffic).
-        self._carry: _Pending | None = None
-        self._thread = threading.Thread(target=self._run, daemon=True)
-        self._thread.start()
-
-    def submit(
-        self, request: dict[str, Any], max_new: int,
-        sampling: dict[str, Any] | None = None,
-        request_id: str | None = None,
-    ) -> _Pending:
-        # The tracer atomically mints a fresh id on collision — an id
-        # names ONE request.
-        tr = self.tracer.start_trace(
-            "request", label=f"chat max_new={max_new}", id=request_id,
-        )
-        p = _Pending(request, max_new, sampling, trace=tr)
-        self.q.put(p)
-        return p
-
-    def _run(self) -> None:
-        while True:
-            first = self._carry or self.q.get()
-            self._carry = None
-            group = [first]
-            deadline = time.monotonic() + self.window
-            while len(group) < self.max_batch:
-                left = deadline - time.monotonic()
-                if left <= 0:
-                    break
-                try:
-                    nxt = self.q.get(timeout=left)
-                except queue.Empty:
-                    break
-                if nxt.batch_key != first.batch_key:
-                    # Different decode program → it LEADS the next group.
-                    self._carry = nxt
-                    break
-                group.append(nxt)
-            s = first.sampling
-            for p in group:
-                if p.trace is not None:
-                    p.trace.end(p._qw)
-            t0_ns = trace_lib.now_ns()
-            try:
-                with self.device_lock:
-                    replies, reasons, counts = self.pipe.chat_batch(
-                        [p.request for p in group],
-                        max_new_tokens=_decode_bucket(first.max_new),
-                        per_row_max=[p.max_new for p in group],
-                        return_finish_reasons=True,
-                        return_token_counts=True,
-                        temperature=s.get("temperature"),
-                        top_p=s.get("top_p"),
-                        stop=s.get("stop"),
-                        seed=s.get("seed") or 0,
-                    )
-                for p, r, why, use in zip(group, replies, reasons, counts):
-                    p.reply, p.finish_reason, p.usage = r, why, use
-                    if p.trace is not None:
-                        # One shared window-batch decode: the whole
-                        # group's device call lands on each member, the
-                        # parity view of the scheduler's decode_chunk.
-                        p.trace.add_complete(
-                            "decode", t0_ns,
-                            batch_size=len(group),
-                            bucket=_decode_bucket(first.max_new),
-                        )
-                        p.trace.finish(
-                            finish_reason=why,
-                            prompt_tokens=use[0],
-                            completion_tokens=use[1],
-                        )
-                # Wasted-step accounting (scripts/bench_serving_sched.py
-                # compares this against the continuous scheduler): the
-                # whole group decodes the BUCKET length; a row's useful
-                # steps are the tokens it actually kept.
-                bucket = _decode_bucket(first.max_new)
-                useful = sum(c for _, c in counts)
-                self.metrics.inc("decode_steps_total", len(group) * bucket)
-                self.metrics.inc("decode_steps_useful", useful)
-                self.metrics.inc(
-                    "decode_steps_wasted", len(group) * bucket - useful
-                )
-                self.metrics.inc("completed", len(group))
-            except Exception as e:  # surface per-request, keep serving
-                for p in group:
-                    p.error = f"{type(e).__name__}: {e}"
-                    if p.trace is not None:
-                        p.trace.finish(error=p.error)
-            for p in group:
-                p.done.set()
-            self.metrics.set_gauge("queue_depth", self.q.qsize())
-
-
 def _parse_sampling(req: dict[str, Any]) -> dict[str, Any]:
-    """Validate OpenAI sampling fields → kwargs for chat_batch /
-    chat_stream. Unsupported values raise (→ 400) instead of being
-    silently ignored."""
+    """Validate OpenAI sampling fields → the per-request sampling dict
+    `Engine.submit` takes. Unsupported values raise (→ 400) instead of
+    being silently ignored."""
     if int(req.get("n", 1)) != 1:
         raise ValueError("n > 1 is not supported")
     if req.get("logprobs"):
@@ -587,11 +408,9 @@ def build_server(
     model_name: str = "oryx-tpu",
     host: str = "127.0.0.1",
     port: int = 8000,
-    batch_window: float = 0.02,
-    max_batch: int = 8,
     allow_local_files: bool = False,
     max_tokens_limit: int = 2048,
-    engine: str = "window",
+    engine: str = "continuous",
     num_slots: int = 4,
     page_size: int = 64,
     decode_chunk: int = 8,
@@ -627,14 +446,12 @@ def build_server(
 ) -> ThreadingHTTPServer:
     """Construct (not start) the HTTP server around a pipeline.
 
-    engine: "window" groups non-streaming requests that arrive within
-    `batch_window` into one decode and runs streams solo (the legacy
-    batcher); any other name resolves through the Engine registry
-    (serve/engine.py) — "continuous" routes EVERYTHING — streaming and
-    not — through the continuous-batching scheduler (serve/scheduler.py):
-    a fixed slot array over a paged KV cache, admission at chunk
-    boundaries, per-slot sampling; "sharded" is the same scheduler with
-    a tensor-parallel mesh REQUIRED (KV pool heads-sharded over tp).
+    engine: a name in the Engine registry (serve/engine.py).
+    "continuous" routes EVERYTHING — streaming and not — through the
+    continuous-batching scheduler (serve/scheduler.py): a fixed slot
+    array over a paged KV cache, admission at chunk boundaries, per-slot
+    sampling; "sharded" is the same scheduler with a tensor-parallel
+    mesh REQUIRED (KV pool heads-sharded over tp).
     Every engine exports GET /metrics; GET /readyz reports the
     engine's own readiness() (loop alive, un-stalled, not draining) so
     load balancers never have to probe with real completions.
@@ -648,7 +465,7 @@ def build_server(
     (utils/anomaly.py): breaches increment oryx_anomaly_total{kind=}
     and, with events_path, append structured JSONL events.
 
-    Failure containment (continuous engine; docs/OBSERVABILITY.md
+    Failure containment (docs/OBSERVABILITY.md
     "Failure playbook"): max_queue bounds admission (full -> 429 +
     Retry-After), request_timeout deadlines every request (-> 504),
     the SLO detectors drive a degraded-mode ladder (gauge
@@ -659,29 +476,18 @@ def build_server(
     the deterministic fault-injection registry (utils/faults.py) —
     chaos testing only, never in production config.
     """
+    from oryx_tpu.serve import engine as engine_lib
     from oryx_tpu.utils.anomaly import AnomalyMonitor, AnomalyThresholds
     from oryx_tpu.utils.metrics import ServingMetrics
+    from oryx_tpu.utils.request_log import RequestLog
 
     if faults_spec:
         faults.configure(faults_spec)
 
-    if engine == "window" and (ttft_slo or queue_depth_slo):
-        # Only scheduler-family engines feed the SLO detectors; a
-        # window-engine server accepting these flags would look armed
-        # while every breach went unobserved.
-        raise ValueError(
-            "--ttft-slo/--queue-depth-slo require a scheduler engine "
-            "(the window batcher does not feed the SLO detectors)"
-        )
-    if engine == "window" and ragged:
-        raise ValueError(
-            "--ragged requires a scheduler engine (the window batcher "
-            "has no paged dispatch to fuse)"
-        )
     if speculate and not ragged:
-        # Same fail-fast contract: drafts are extra lanes of the fused
-        # ragged dispatch — accepting the flag without --ragged would
-        # promise multi-token steps that never happen.
+        # Fail fast: drafts are extra lanes of the fused ragged
+        # dispatch — accepting the flag without --ragged would promise
+        # multi-token steps that never happen.
         raise ValueError(
             "--speculate requires --ragged (draft tokens ride the "
             "fused packed dispatch as extra verify lanes)"
@@ -690,11 +496,6 @@ def build_server(
         # Fused multi-step decode (docs/DESIGN.md "Fused multi-step
         # decode"): the megastep is a scan over the fused ragged step,
         # so it needs that step to exist — same fail-fast contract.
-        if engine == "window":
-            raise ValueError(
-                "--fuse-steps requires a scheduler engine (the window "
-                "batcher has no engine step to fuse)"
-            )
         if not ragged:
             raise ValueError(
                 "--fuse-steps requires --ragged (the megastep is a "
@@ -711,44 +512,6 @@ def build_server(
             "--draft-model requires --speculate (the draft model "
             "proposes speculative tokens; without a verify lane count "
             "it would never be consulted)"
-        )
-    if engine == "window" and request_timeout:
-        # Same fail-fast contract for the containment knob: deadlines
-        # are enforced by the engine loop; accepting the flag on the
-        # window batcher would promise 504s that never fire.
-        raise ValueError(
-            "--request-timeout requires a scheduler engine (the "
-            "window batcher does not enforce per-request deadlines)"
-        )
-    if engine == "window" and profile_sample_every:
-        raise ValueError(
-            "--profile-sample-every requires a scheduler engine (the "
-            "window batcher has no engine step loop to sample)"
-        )
-    if engine == "window" and (audit_sample_every or numerics_every):
-        # Same fail-fast contract: the auditor replays through the
-        # scheduler's paged path and the numerics probe rides its
-        # dispatches — accepting the flags on the window batcher would
-        # promise audits/probes that never run.
-        raise ValueError(
-            "--audit-sample-every/--numerics-every require a scheduler "
-            "engine (the window batcher has no paged replay path or "
-            "engine step loop)"
-        )
-    if engine == "window" and (kv_dtype != "bf16" or host_cache_bytes):
-        # Same fail-fast contract: only the scheduler family owns a
-        # paged pool to quantize or a prefix cache to tier.
-        raise ValueError(
-            "--kv-dtype/--host-cache-bytes require a scheduler engine "
-            "(the window batcher has no paged KV pool or prefix cache)"
-        )
-    if engine == "window" and journal_path:
-        # Same fail-fast contract: the decision journal records the
-        # scheduler's decision stream — arming it on the window
-        # batcher would write a header and nothing else.
-        raise ValueError(
-            "--journal requires a scheduler engine (the window "
-            "batcher has no decision stream to record)"
         )
     # $ORYX_LOCK_SANITIZER=1 arms the lock-order sanitizer + race
     # detector for this server (chaos/test runs). Armed BEFORE the
@@ -784,91 +547,67 @@ def build_server(
     # by GET /debug/requests, with per-request span trees (queue-wait →
     # prefill → decode chunks → emission) at GET /debug/trace?id=.
     tracer = trace_lib.Tracer(flight_recorder_size)
-    # chat_stream is not thread-safe against itself or chat_batch (one
-    # device, one program at a time) — streaming requests serialize with
-    # each other and with the batcher through this lock. (Continuous
-    # engine: the scheduler thread owns the device; no lock needed.)
-    # First in the declared lock order: it is held across whole decode
-    # streams, so nothing else may be held when taking it.
-    stream_lock = named_lock("server.stream_lock")
-    batcher = scheduler = supervisor = None
     # Drain state shared across handler threads: set once by
     # begin_drain(), read by /readyz and every POST.
     draining = threading.Event()
-    if engine == "window":
-        if pipe.cfg.llm.block_length:
-            raise ValueError(
-                "a block-diffusion model (block_length="
-                f"{pipe.cfg.llm.block_length}) is served by the "
-                "continuous engine only: the window batcher decodes one "
-                "causal token a step"
-            )
-        batcher = Batcher(
-            pipe, window=batch_window, max_batch=max_batch,
-            device_lock=stream_lock, metrics=metrics, tracer=tracer,
+    # Wide-event request log (utils/request_log.py): one JSONL event per
+    # terminal request, in-memory always (the
+    # /debug/requests?format=jsonl export), on disk when --requests-log
+    # names a path (size-capped rotation).
+    request_log = RequestLog(
+        requests_log_path, max_bytes=requests_log_max_bytes
+    )
+    # Decision journal (serve/journal.py): the engine flight recorder
+    # scripts/replay_journal.py replays offline. The server stamps the
+    # workload-level identity here; the scheduler stamps its effective
+    # geometry and seals the header. None when --journal was not given —
+    # every instrumentation site in the scheduler then costs one
+    # attribute check.
+    journal = None
+    if journal_path:
+        journal = journal_lib.DecisionJournal(
+            journal_path, max_bytes=journal_max_bytes
         )
-    else:
-        from oryx_tpu.serve import engine as engine_lib
-        from oryx_tpu.utils.request_log import RequestLog
+        journal.stamp_header(
+            model=model_name, faults_spec=faults_spec or None,
+            max_tokens_limit=max_tokens_limit,
+        )
+    # Trained draft model (models/generate.NeuralDrafter): a checkpoint
+    # path or an "init:V:D:W:SEED" spec. Replaces the default n-gram
+    # drafter and — because it implements the device params/apply
+    # contract — unlocks fused speculative megasteps. Its `source`
+    # string lands in the journal header (draft_model) so replay
+    # rebuilds the identical proposer.
+    drafter = None
+    if draft_model:
+        from oryx_tpu.models import generate as generate_lib
 
-        # Wide-event request log (utils/request_log.py): one JSONL
-        # event per terminal request, in-memory always (the
-        # /debug/requests?format=jsonl export), on disk when
-        # --requests-log names a path (size-capped rotation).
-        request_log = RequestLog(
-            requests_log_path, max_bytes=requests_log_max_bytes
-        )
-        # Decision journal (serve/journal.py): the engine flight
-        # recorder scripts/replay_journal.py replays offline. The
-        # server stamps the workload-level identity here; the
-        # scheduler stamps its effective geometry and seals the
-        # header. None when --journal was not given — every
-        # instrumentation site in the scheduler then costs one
-        # attribute check.
-        journal = None
-        if journal_path:
-            journal = journal_lib.DecisionJournal(
-                journal_path, max_bytes=journal_max_bytes
-            )
-            journal.stamp_header(
-                model=model_name, faults_spec=faults_spec or None,
-                max_tokens_limit=max_tokens_limit,
-            )
-        # Trained draft model (models/generate.NeuralDrafter): a
-        # checkpoint path or an "init:V:D:W:SEED" spec. Replaces the
-        # default n-gram drafter and — because it implements the
-        # device params/apply contract — unlocks fused speculative
-        # megasteps. Its `source` string lands in the journal header
-        # (draft_model) so replay rebuilds the identical proposer.
-        drafter = None
-        if draft_model:
-            from oryx_tpu.models import generate as generate_lib
-
-            drafter = generate_lib.NeuralDrafter.from_spec(draft_model)
-        # Engine registry (serve/engine.py): "continuous", "sharded",
-        # and whatever later shapes register — all drop-in behind this
-        # server and the supervisor through the Engine protocol.
-        scheduler = engine_lib.create_engine(
-            engine, pipe, num_slots=num_slots, page_size=page_size,
-            chunk=decode_chunk, max_ctx=max_ctx, metrics=metrics,
-            tracer=tracer, stall_timeout=stall_timeout, anomaly=anomaly,
-            prefill_chunk=prefill_chunk, prefix_cache=prefix_cache,
-            ragged=ragged, speculate=speculate,
-            fuse_steps=fuse_steps, drafter=drafter,
-            kv_dtype=kv_dtype, host_cache_bytes=host_cache_bytes,
-            audit_tol_maxdiff=audit_tol_maxdiff,
-            audit_tol_kl=audit_tol_kl,
-            profile_sample_every=profile_sample_every,
-            audit_sample_every=audit_sample_every,
-            numerics_every=numerics_every,
-            max_queue=max_queue, request_timeout=request_timeout,
-            degraded_cooldown=degraded_cooldown,
-            request_log=request_log, engine_label=engine,
-            replica_id=replica_id, journal=journal,
-        )
-        if supervise:
-            supervisor = EngineSupervisor(scheduler)
-            supervisor.start()
+        drafter = generate_lib.NeuralDrafter.from_spec(draft_model)
+    # Engine registry (serve/engine.py): "continuous", "sharded", and
+    # whatever later shapes register — all drop-in behind this server
+    # and the supervisor through the Engine protocol.
+    scheduler = engine_lib.create_engine(
+        engine, pipe, num_slots=num_slots, page_size=page_size,
+        chunk=decode_chunk, max_ctx=max_ctx, metrics=metrics,
+        tracer=tracer, stall_timeout=stall_timeout, anomaly=anomaly,
+        prefill_chunk=prefill_chunk, prefix_cache=prefix_cache,
+        ragged=ragged, speculate=speculate,
+        fuse_steps=fuse_steps, drafter=drafter,
+        kv_dtype=kv_dtype, host_cache_bytes=host_cache_bytes,
+        audit_tol_maxdiff=audit_tol_maxdiff,
+        audit_tol_kl=audit_tol_kl,
+        profile_sample_every=profile_sample_every,
+        audit_sample_every=audit_sample_every,
+        numerics_every=numerics_every,
+        max_queue=max_queue, request_timeout=request_timeout,
+        degraded_cooldown=degraded_cooldown,
+        request_log=request_log, engine_label=engine,
+        replica_id=replica_id, journal=journal,
+    )
+    supervisor = None
+    if supervise:
+        supervisor = EngineSupervisor(scheduler)
+        supervisor.start()
 
     def _ready() -> tuple[bool, str]:
         """Readiness = the engine loop is genuinely able to make
@@ -880,35 +619,27 @@ def build_server(
         routers eject a draining or crash-looping replica on it."""
         if draining.is_set():
             return False, "draining"
-        if scheduler is not None:
-            if (
-                not scheduler.alive()
-                and supervisor is not None and supervisor.gave_up
-            ):
-                return False, (
-                    "engine dead (supervisor gave up after "
-                    f"{supervisor.max_restarts} restarts in "
-                    f"{supervisor.window_s:g}s)"
-                )
-            return scheduler.readiness()
-        if not batcher._thread.is_alive():
-            return False, "batcher loop dead"
-        return True, "ok"
+        if (
+            not scheduler.alive()
+            and supervisor is not None and supervisor.gave_up
+        ):
+            return False, (
+                "engine dead (supervisor gave up after "
+                f"{supervisor.max_restarts} restarts in "
+                f"{supervisor.window_s:g}s)"
+            )
+        return scheduler.readiness()
 
     class Handler(BaseHTTPRequestHandler):
         def log_message(self, *a):  # quiet access log
             pass
 
-        def _ring_debug(self, get_ring, *, unavailable: str,
-                        default_n: int) -> None:
+        def _ring_debug(self, ring, *, default_n: int) -> None:
             """Shared shape of the ring-backed debug endpoints
-            (/debug/timeline, /debug/oom, /debug/audit): scheduler-only
-            guard, ONE ?n= contract, engine label + the ring's
-            to_dict(n) body — so the three views can never drift on
+            (/debug/timeline, /debug/oom, /debug/audit,
+            /debug/journal): ONE ?n= contract, engine label + the
+            ring's to_dict(n) body — so the views can never drift on
             parsing or error semantics."""
-            if scheduler is None:
-                self._json(400, {"error": unavailable})
-                return
             q = urllib.parse.parse_qs(
                 urllib.parse.urlsplit(self.path).query
             )
@@ -922,7 +653,7 @@ def build_server(
                 })
                 return
             body = {"engine": engine}
-            body.update(get_ring().to_dict(n or None))
+            body.update(ring.to_dict(n or None))
             self._json(200, body)
 
         def _json(self, code: int, body: dict[str, Any],
@@ -978,13 +709,6 @@ def build_server(
                     # Wide-event export: the canonical one-line-per-
                     # terminal-request log (utils/request_log.py),
                     # schema REQUEST_EVENT_KEYS. ?limit= bounds it.
-                    if scheduler is None:
-                        self._json(400, {
-                            "error": "wide events require a scheduler "
-                            "engine (the window batcher has no "
-                            "request log)",
-                        })
-                        return
                     data = scheduler.request_log.export_jsonl(
                         limit or None
                     ).encode()
@@ -1027,25 +751,13 @@ def build_server(
                 # newest-first per-step records plus cumulative
                 # dispatch-kind counts that reconcile against
                 # oryx_serving_dispatches_total.
-                self._ring_debug(
-                    lambda: scheduler.timeline, default_n=64,
-                    unavailable="the step timeline requires a "
-                    "scheduler engine (the window batcher has no "
-                    "engine step loop)",
-                )
+                self._ring_debug(scheduler.timeline, default_n=64)
             elif self.path.split("?", 1)[0] == "/debug/pages":
                 # Page-pool observatory (utils/pagemap.py): the live
                 # ownership map — per page free/slot/cache/shared,
                 # refcount, owner tags, tenancy age — plus the derived
                 # summary whose state counts must reconcile with the
                 # oryx_pool_* gauges on a quiesced engine.
-                if scheduler is None:
-                    self._json(400, {
-                        "error": "the page map requires a scheduler "
-                        "engine (the window batcher has no paged "
-                        "pool)",
-                    })
-                    return
                 q = urllib.parse.parse_qs(
                     urllib.parse.urlsplit(self.path).query
                 )
@@ -1077,35 +789,21 @@ def build_server(
                 # top-K residents with ledgers, cache LRU tail,
                 # timeline tail — captured at every OutOfPagesError
                 # and degraded-mode escalation.
-                self._ring_debug(
-                    lambda: scheduler.forensics, default_n=16,
-                    unavailable="OOM forensics require a scheduler "
-                    "engine (the window batcher has no paged pool)",
-                )
+                self._ring_debug(scheduler.forensics, default_n=16)
             elif self.path.split("?", 1)[0] == "/debug/audit":
                 # Output-quality observatory (serve/audit.py): the
                 # bounded ring of shadow-parity audit records plus the
                 # monotone verdict counts /debug consumers reconcile
                 # against oryx_audit_total{verdict=}.
-                self._ring_debug(
-                    lambda: scheduler.auditor, default_n=16,
-                    unavailable="output audits require a scheduler "
-                    "engine (the window batcher has no paged replay "
-                    "path)",
-                )
+                self._ring_debug(scheduler.auditor, default_n=16)
             elif self.path.split("?", 1)[0] == "/debug/journal":
                 # Decision journal (serve/journal.py): the engine
                 # flight recorder's bounded ring — header + newest-
                 # first entries + per-kind counts. Disarmed replicas
                 # serve the same body shape with armed=false.
                 self._ring_debug(
-                    lambda: (
-                        scheduler.journal or journal_lib.DISARMED
-                    ),
+                    scheduler.journal or journal_lib.DISARMED,
                     default_n=64,
-                    unavailable="the decision journal requires a "
-                    "scheduler engine (the window batcher has no "
-                    "decision stream to record)",
                 )
             elif self.path.split("?", 1)[0] == "/debug/profile":
                 # On-demand device-time capture: bracket the next
@@ -1113,13 +811,6 @@ def build_server(
                 # capture and return the Perfetto-loadable Chrome
                 # trace + per-kind device-time attribution. Needs live
                 # traffic — an idle engine answers 503.
-                if scheduler is None:
-                    self._json(400, {
-                        "error": "profiling requires a scheduler "
-                        "engine (the window batcher has no engine "
-                        "step loop)",
-                    })
-                    return
                 q = urllib.parse.parse_qs(
                     urllib.parse.urlsplit(self.path).query
                 )
@@ -1174,8 +865,6 @@ def build_server(
                 body["request"] = tr.summary()
                 self._json(200, body, request_id=rid)
             elif self.path == "/metrics":
-                if batcher is not None:
-                    metrics.set_gauge("queue_depth", batcher.q.qsize())
                 data = metrics.render().encode()
                 self.send_response(200)
                 self.send_header(
@@ -1230,9 +919,9 @@ def build_server(
                         raise ValueError(
                             f"max_tokens must be >= 1, got {max_new}"
                         )
-                    # Decode length is a compiled-program dimension and
-                    # the decode runs under the device lock — an
-                    # unbounded client value is a denial of service.
+                    # A request holds its slot and its pages for up to
+                    # max_new steps — an unbounded client value is a
+                    # denial of service.
                     if max_new > max_tokens_limit:
                         raise ValueError(
                             f"max_tokens must be <= {max_tokens_limit}, "
@@ -1286,150 +975,20 @@ def build_server(
                 "question": question, "images": images,
                 "is_video": is_video, "history": history,
             }
-            if scheduler is not None:
-                self._continuous(
-                    req, request_dict, max_new, sampling,
-                    request_id=rid_pref, routed=routed,
-                    router_parent=router_parent,
-                )
-                return
-            if req.get("stream"):
-                # A producer thread owns the device (and the lock); this
-                # handler thread only writes to the socket, so a slow or
-                # stalled client can never block the device for others.
-                # The queue is bounded and `gone` signals a dead client:
-                # the producer then stops decoding between chunks instead
-                # of holding stream_lock for up to max_tokens of decode.
-                deltas: queue.Queue[tuple[str, str | None]] = queue.Queue(
-                    maxsize=64
-                )
-                gone = threading.Event()
-
-                def put(item) -> bool:
-                    while not gone.is_set():
-                        try:
-                            deltas.put(item, timeout=0.5)
-                            return True
-                        except queue.Full:
-                            continue
-                    return False
-
-                want_usage = bool(
-                    (req.get("stream_options") or {}).get("include_usage")
-                )
-                usage: dict[str, int] = {}
-                # Solo streams bypass the Batcher, so they get their own
-                # flight-recorder entry; activate() propagates it into
-                # chat_stream's prefill / decode_chunk spans.
-                tr = tracer.start_trace(
-                    "request", label=f"stream max_new={max_new}",
-                    id=rid_pref,  # atomically minted on collision
-                )
-
-                def produce():
-                    gen = pipe.chat_stream(
-                        question, images=images or None,
-                        is_video=is_video, history=history,
-                        max_new_tokens=max_new, usage_out=usage,
-                        **sampling,
-                    )
-                    try:
-                        with stream_lock, trace_lib.activate(tr):
-                            while not gone.is_set():
-                                try:
-                                    d = next(gen)
-                                except StopIteration as s:
-                                    # Generator return value = reason.
-                                    reason = s.value or "stop"
-                                    tr.finish(
-                                        finish_reason=reason,
-                                        **usage,
-                                    )
-                                    put(("end", reason))
-                                    return
-                                if not put(("delta", d)):
-                                    tr.finish(cancelled=True)
-                                    return
-                            # Client gone at the loop-top check: the
-                            # trace must still close, or the recorder
-                            # shows a forever-in-flight request.
-                            tr.finish(cancelled=True)
-                    except Exception as e:
-                        msg = f"{type(e).__name__}: {e}"
-                        tr.finish(error=msg)
-                        put(("error", msg))
-                    finally:
-                        gen.close()
-
-                threading.Thread(target=produce, daemon=True).start()
-                cid = f"chatcmpl-{tr.id}"
-                try:
-                    self.send_response(200)
-                    self.send_header("Content-Type", "text/event-stream")
-                    self.send_header("Cache-Control", "no-cache")
-                    self.send_header("X-Request-Id", tr.id)
-                    self.end_headers()
-                    while True:
-                        kind, payload = deltas.get()
-                        if kind == "delta":
-                            self._sse(_chunk_body(
-                                model_name, cid, payload,
-                                usage_field=want_usage,
-                            ))
-                        elif kind == "error":
-                            self._sse({"error": {"message": payload}})
-                            break
-                        else:
-                            self._sse(_chunk_body(
-                                model_name, cid, None, payload,
-                                usage_field=want_usage,
-                            ))
-                            break
-                    if want_usage:
-                        # One final empty-choices chunk with the totals.
-                        # The OpenAI contract promises this chunk when
-                        # stream_options.include_usage is set, so it is
-                        # emitted on the error path too, with whatever
-                        # counts the producer managed to fill (zeros if
-                        # it died before accounting).
-                        p = usage.get("prompt_tokens", 0)
-                        c = usage.get("completion_tokens", 0)
-                        self._sse(_chunk_body(
-                            model_name, cid, None,
-                            usage_field=True,
-                            usage={
-                                "prompt_tokens": p,
-                                "completion_tokens": c,
-                                "total_tokens": p + c,
-                            },
-                        ))
-                    self.wfile.write(b"data: [DONE]\n\n")
-                    self.wfile.flush()
-                except (BrokenPipeError, ConnectionResetError, OSError):
-                    gone.set()  # stop the producer at its next chunk
-                return
-
-            pending = batcher.submit(
-                request_dict, max_new, sampling, request_id=rid_pref
+            self._submit(
+                req, request_dict, max_new, sampling,
+                request_id=rid_pref, routed=routed,
+                router_parent=router_parent,
             )
-            pending.done.wait()
-            if pending.error is not None:
-                self._json(500, {"error": {"message": pending.error}},
-                           request_id=pending.request_id)
-            else:
-                self._json(200, _completion_body(
-                    model_name, pending.reply, pending.finish_reason,
-                    usage=pending.usage, request_id=pending.request_id,
-                ), request_id=pending.request_id)
 
-        def _continuous(self, req, request_dict, max_new, sampling,
-                        request_id=None, routed=False,
-                        router_parent=None) -> None:
-            """Route one request through the continuous-batching
-            scheduler. The scheduler thread owns the device; this
-            handler thread only drains the handle's event queue, so a
-            slow client never blocks decode (a dead one flips
-            `cancelled` and the slot frees at the next harvest)."""
+        def _submit(self, req, request_dict, max_new, sampling,
+                    request_id=None, routed=False,
+                    router_parent=None) -> None:
+            """Hand one validated request to the engine and answer it.
+            The scheduler thread owns the device; this handler thread
+            only drains the handle's event queue, so a slow client
+            never blocks decode (a dead one flips `cancelled` and the
+            slot frees at the next harvest)."""
             from oryx_tpu.serve.scheduler import AdmissionRejected
 
             try:
@@ -1574,17 +1133,14 @@ def build_server(
     srv = ThreadingHTTPServer((host, port), Handler)
     srv.metrics = metrics
     srv.scheduler = scheduler
-    srv.batcher = batcher
     srv.tracer = tracer
     srv.anomaly = anomaly
     srv.supervisor = supervisor
-    srv.request_log = (
-        scheduler.request_log if scheduler is not None else None
-    )
-    srv.timeline = scheduler.timeline if scheduler is not None else None
-    srv.forensics = scheduler.forensics if scheduler is not None else None
-    srv.auditor = scheduler.auditor if scheduler is not None else None
-    srv.journal = scheduler.journal if scheduler is not None else None
+    srv.request_log = scheduler.request_log
+    srv.timeline = scheduler.timeline
+    srv.forensics = scheduler.forensics
+    srv.auditor = scheduler.auditor
+    srv.journal = scheduler.journal
 
     def begin_drain() -> None:
         """Drain-on-shutdown, step 1: /readyz flips 503 NOW (router
@@ -1592,8 +1148,7 @@ def build_server(
         continuous engine stops admission and finishes resident
         decodes. Callers then `scheduler.drain()` and shutdown()."""
         draining.set()
-        if scheduler is not None:
-            scheduler.begin_drain()
+        scheduler.begin_drain()
 
     srv.begin_drain = begin_drain
     return srv
@@ -1606,15 +1161,11 @@ def main(argv: list[str] | None = None) -> None:
     ap.add_argument("--model-name", default="oryx-tpu")
     ap.add_argument("--host", default="127.0.0.1")
     ap.add_argument("--port", type=int, default=8000)
-    ap.add_argument("--batch-window", type=float, default=0.02)
-    ap.add_argument("--max-batch", type=int, default=8)
     from oryx_tpu.serve.engine import engine_names
 
     ap.add_argument(
-        "--engine", choices=["window"] + engine_names(),
-        default="window",
-        help="request batching engine: the window batcher (group within "
-        "--batch-window), the continuous-batching scheduler over a "
+        "--engine", choices=engine_names(), default="continuous",
+        help="serving engine: the continuous-batching scheduler over a "
         "paged KV cache (admission at chunk boundaries, per-slot "
         "sampling, GET /metrics occupancy), or sharded — the same "
         "scheduler with a tensor-parallel mesh required (--shard tp=N; "
@@ -1776,14 +1327,12 @@ def main(argv: list[str] | None = None) -> None:
     ap.add_argument(
         "--ttft-slo", type=float, default=None,
         help="fire an oryx_anomaly_total{kind=\"ttft_slo\"} event when "
-        "a request's time-to-first-token exceeds this many seconds "
-        "(continuous engine only)",
+        "a request's time-to-first-token exceeds this many seconds",
     )
     ap.add_argument(
         "--queue-depth-slo", type=int, default=None,
         help="fire an oryx_anomaly_total{kind=\"queue_depth_slo\"} "
-        "event when the admission queue exceeds this depth "
-        "(continuous engine only)",
+        "event when the admission queue exceeds this depth",
     )
     ap.add_argument(
         "--events-path", default=None,
@@ -1844,7 +1393,7 @@ def main(argv: list[str] | None = None) -> None:
     ap.add_argument(
         "--max-tokens-limit", type=int, default=2048,
         help="reject requests asking for more than this many new tokens "
-        "(decode length is a compiled-program dimension)",
+        "(a request holds its slot and pages for that long)",
     )
     ap.add_argument(
         "--shard", default=None, metavar="MODE=N",
@@ -1871,7 +1420,7 @@ def main(argv: list[str] | None = None) -> None:
     if args.speculate < 0:
         ap.error("--speculate must be >= 0")
     # --fuse-steps: "auto" stays a string; anything else must parse as
-    # a positive int (build_server re-validates engine/ragged pairing).
+    # a positive int (build_server re-validates the ragged pairing).
     if args.fuse_steps == "auto":
         fuse_steps: int | str = "auto"
     else:
@@ -1903,7 +1452,6 @@ def main(argv: list[str] | None = None) -> None:
     )
     srv = build_server(
         pipe, model_name=args.model_name, host=args.host, port=args.port,
-        batch_window=args.batch_window, max_batch=args.max_batch,
         allow_local_files=args.allow_local_files,
         max_tokens_limit=args.max_tokens_limit,
         engine=args.engine, num_slots=args.num_slots,
@@ -1939,10 +1487,9 @@ def main(argv: list[str] | None = None) -> None:
     def _drain_and_exit() -> None:
         print("SIGTERM: draining (admission stopped, /readyz now 503)")
         srv.begin_drain()
-        if srv.scheduler is not None:
-            drained = srv.scheduler.drain(timeout=args.drain_timeout)
-            print("drain complete" if drained
-                  else f"drain timed out after {args.drain_timeout:g}s")
+        drained = srv.scheduler.drain(timeout=args.drain_timeout)
+        print("drain complete" if drained
+              else f"drain timed out after {args.drain_timeout:g}s")
         srv.shutdown()
 
     def _on_sigterm(signum, frame):

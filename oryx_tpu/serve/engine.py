@@ -34,10 +34,6 @@ Registered shapes:
     pipeline actually has a multi-device mesh whose tp axis splits the
     KV heads. Use it in deployments where "this replica is
     tensor-parallel" must be an invariant, not an accident of flags.
-
-(The legacy window Batcher predates the protocol and stays a special
-case inside api_server; it has no admission queue, drain ladder, or
-supervisor hooks to conform with.)
 """
 
 from __future__ import annotations

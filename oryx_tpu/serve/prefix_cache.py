@@ -19,8 +19,8 @@ its nodes meaning:
     pressure, refcount-1 entries (pages nobody but the cache holds) are
     LRU-evicted back to the free list — cached pages go before live
     requests ever do.
-  * `SessionPrefixCache` — the dense-cache plane for the pipeline /
-    window-engine path. Nodes hold whole `PrefixCacheState` snapshots,
+  * `SessionPrefixCache` — the dense-cache plane for the pipeline's
+    own `ChatSession` path. Nodes hold whole `PrefixCacheState` snapshots,
     so a fresh `ChatSession` over the same media + system prompt seeds
     itself from a finished session's KV instead of cold-prefilling.
     Capacity-bounded (dense caches are HBM-expensive), LRU.

@@ -1,7 +1,6 @@
 """Continuous-batching scheduler over the paged decode path.
 
-Replaces the window-batcher model ("wait `batch_window`, decode the
-whole group to the longest row") with a slot array + admission queue:
+A slot array + admission queue:
 
   * The device runs ONE compiled program shape forever —
     `paged_decode_chunk` over `num_slots` rows, `chunk` tokens per
@@ -343,8 +342,8 @@ class _Request:
 class ContinuousScheduler:
     """Slot map + admission queue + paged KV pool around one pipeline.
 
-    Drop-in replacement for api_server.Batcher at the submit() level;
-    also serves streaming consumers through RequestHandle.events.
+    Non-streaming callers wait on the handle submit() returns;
+    streaming consumers drain RequestHandle.events.
     """
 
     def __init__(
@@ -3954,9 +3953,7 @@ class ContinuousScheduler:
             # this also covers an EOS consumed after a stop completed,
             # which was billed but never appended to `emitted`).
             # Without this the wasted-step fraction under-counts
-            # whenever a slot finishes mid-chunk on a stop string
-            # (scripts/bench_serving_sched.py's A/B depends on this
-            # number being honest).
+            # whenever a slot finishes mid-chunk on a stop string.
             useful = min(useful, finish[1] - chunk_start)
         # Ledger: tokens of client-visible completion progress this
         # step (replay skips excluded, post-stop tokens clamped away) —

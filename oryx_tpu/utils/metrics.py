@@ -840,8 +840,8 @@ def register_device_memory_collector(reg: Registry,
 
 class ServingMetrics:
     """Thread-safe counters / gauges / histograms for the serving path —
-    a name-on-first-touch client of `Registry`, so the scheduler and the
-    window batcher never pre-register, while `GET /metrics` renders the
+    a name-on-first-touch client of `Registry`, so the scheduler never
+    pre-registers, while `GET /metrics` renders the
     shared Prometheus text exposition (device-memory gauges included)."""
 
     def __init__(self, prefix: str = "oryx_serving",

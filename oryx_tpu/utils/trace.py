@@ -276,7 +276,7 @@ class Trace:
 class Tracer:
     """Trace factory + bounded flight recorder of the last N traces.
 
-    One Tracer per engine (scheduler, window batcher, trainer) or one
+    One Tracer per engine (scheduler, trainer) or one
     shared — traces register at creation so in-flight work is visible
     in ``/debug/requests`` before it completes."""
 

@@ -247,8 +247,8 @@ def fetch_timeline(base: str, n: int = 24, timeout: float = 30.0) -> dict:
     """One replica's /debug/timeline snapshot (utils/timeline.py): the
     per-stage flight-data-recorder embed — reading the records at the
     knee stage replaces guessing engine state from counter deltas. A
-    target without the endpoint (window engine, old server) degrades
-    to an error entry, never a failed stage."""
+    target without the endpoint (an old server) degrades to an error
+    entry, never a failed stage."""
     try:
         with urllib.request.urlopen(
             base + f"/debug/timeline?n={n}", timeout=timeout
@@ -265,8 +265,8 @@ def fetch_timeline(base: str, n: int = 24, timeout: float = 30.0) -> dict:
 
 def fetch_pages_summary(base: str, timeout: float = 30.0) -> dict:
     """One target's /debug/pages?format=summary body (the page-pool
-    observatory). Targets without the endpoint (window engine, old
-    server) degrade to an error entry, never a failed stage."""
+    observatory). Targets without the endpoint (an old server)
+    degrade to an error entry, never a failed stage."""
     try:
         with urllib.request.urlopen(
             base + "/debug/pages?format=summary", timeout=timeout
@@ -898,8 +898,7 @@ def check_cost_ledger(base: str) -> list[str]:
     ) as r:
         body = json.load(r)
     if body.get("engine") not in ("continuous", "router"):
-        # The window batcher has no cost ledger (or SLO detectors):
-        # one clear reason beats N "missing every key" lines. The
+        # One clear reason beats N "missing every key" lines. The
         # router's merged recorder carries its replicas' ledgers.
         return [
             "cost-ledger audit requires a scheduler engine or a "

@@ -150,17 +150,6 @@ def test_queue_depth_slo_one_rearms_on_drain():
     ]
 
 
-def test_window_engine_rejects_slo_flags():
-    """The window batcher never feeds the SLO detectors; accepting the
-    flags there would look armed while every breach went unobserved."""
-    from oryx_tpu.serve import api_server
-
-    with pytest.raises(ValueError, match="scheduler engine"):
-        api_server.build_server(None, engine="window", ttft_slo=1.0)
-    with pytest.raises(ValueError, match="scheduler engine"):
-        api_server.build_server(None, engine="window", queue_depth_slo=4)
-
-
 def test_queue_depth_hysteresis():
     mon = AnomalyMonitor(
         source="serve",
@@ -375,19 +364,3 @@ def test_spec_accept_collapse_rolling_baseline():
         assert mon2.observe_spec_accept(1.0) == []
     assert mon2.counts.get("spec_accept_collapse", 0) == 0
     mon2.close()
-
-
-def test_window_engine_rejects_audit_and_numerics_flags():
-    """--audit-sample-every/--numerics-every on the window batcher must
-    fail fast (no paged replay path / engine step loop), same contract
-    as the SLO flags."""
-    from oryx_tpu.serve import api_server
-
-    with pytest.raises(ValueError, match="audit-sample-every"):
-        api_server.build_server(
-            object(), engine="window", audit_sample_every=1, port=0,
-        )
-    with pytest.raises(ValueError, match="numerics-every"):
-        api_server.build_server(
-            object(), engine="window", numerics_every=4, port=0,
-        )

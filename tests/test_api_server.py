@@ -1,8 +1,8 @@
 """OpenAI-compatible API server tests: message parsing, a live server
-round-trip (batched + streaming SSE), dynamic batching."""
+round-trip through the continuous engine (JSON + streaming SSE),
+concurrent clients sharing its slots."""
 
 import base64
-import contextlib
 import io
 import json
 import threading
@@ -78,8 +78,8 @@ def test_parse_messages_system_concat_and_local_files(tmp_path):
     assert images[0].shape == (8, 8, 3)
 
 
-def test_server_rejects_bad_max_tokens(server):
-    url, _ = server
+def test_server_rejects_bad_max_tokens(continuous_server):
+    url, _ = continuous_server
     for bad in (0, -5):
         try:
             _post(url, {
@@ -127,10 +127,10 @@ def test_parse_messages_rejects_bad_shapes():
     assert q == "be brief\nhi"
 
 
-def test_server_reports_length_finish_reason(server):
+def test_server_reports_length_finish_reason(continuous_server):
     """The tiny vocab never emits the EOS id, so every decode truncates:
     finish_reason must say 'length', not 'stop'."""
-    url, _ = server
+    url, _ = continuous_server
     with _post(url, {
         "messages": [{"role": "user", "content": "hello"}],
         "max_tokens": 3,
@@ -151,64 +151,24 @@ def test_server_reports_length_finish_reason(server):
     assert deltas_final == "length"
 
 
-def test_batcher_groups_and_fifo():
-    """Dynamic batcher: requests whose max_tokens share a decode bucket
-    group into one chat_batch call (decoding the bucket, each row capped
-    individually); a request from a DIFFERENT bucket is carried to LEAD
-    the next group (FIFO, no starvation) rather than re-queued to the
-    tail."""
-    calls = []
-
-    class StubPipe:
-        def chat_batch(self, requests, max_new_tokens,
-                       return_finish_reasons=False,
-                       return_token_counts=False, per_row_max=None,
-                       **sampling):
-            calls.append((
-                [r["question"] for r in requests], max_new_tokens,
-                list(per_row_max or []),
-            ))
-            replies = [r["question"].upper() for r in requests]
-            out = (replies,)
-            if return_finish_reasons:
-                out += (["stop"] * len(replies),)
-            if return_token_counts:
-                out += ([(3, 1)] * len(replies),)
-            return out[0] if len(out) == 1 else out
-
-    # Generous window: it only delays the first flush, and a tight one
-    # would flake under CI load (the grouping below assumes all four
-    # submits land inside one window).
-    b = api_server.Batcher(StubPipe(), window=2.0, max_batch=8)
-    pending = [
-        b.submit({"question": "a"}, 4),
-        b.submit({"question": "b"}, 9),   # same bucket (16) as a
-        b.submit({"question": "c"}, 60),  # bucket 64 -> leads next group
-        b.submit({"question": "d"}, 40),
-    ]
-    for p in pending:
-        assert p.done.wait(timeout=30)
-    assert [p.reply for p in pending] == ["A", "B", "C", "D"]
-    assert all(p.finish_reason == "stop" for p in pending)
-    # calls is complete here: Batcher._run appends inside chat_batch
-    # strictly before setting each done event. Two device calls:
-    # [a, b] decoding bucket 16 with per-row caps 4/9, then the
-    # carried-over [c, d] decoding bucket 64 (c led, was not lost).
-    assert calls == [
-        (["a", "b"], 16, [4, 9]),
-        (["c", "d"], 64, [60, 40]),
-    ], calls
+def _tiny_pipe() -> OryxInference:
+    cfg = cfg_lib.oryx_tiny()
+    params = oryx.init_params(cfg, jax.random.key(0))
+    return OryxInference(FakeTokenizer(), params, cfg)
 
 
 @pytest.fixture(scope="module")
-def server():
-    cfg = cfg_lib.oryx_tiny()
-    params = oryx.init_params(cfg, jax.random.key(0))
-    pipe = OryxInference(FakeTokenizer(), params, cfg)
-    srv = api_server.build_server(pipe, port=0, batch_window=0.1)
+def continuous_server():
+    """Server on the continuous-batching engine (paged KV scheduler)."""
+    pipe = _tiny_pipe()
+    srv = api_server.build_server(
+        pipe, port=0, engine="continuous", num_slots=2, page_size=16,
+        decode_chunk=4, max_ctx=512,
+    )
     thread = threading.Thread(target=srv.serve_forever, daemon=True)
     thread.start()
     yield f"http://127.0.0.1:{srv.server_address[1]}", pipe
+    srv.scheduler.close()
     srv.shutdown()
 
 
@@ -221,38 +181,69 @@ def _post(url, body):
     return urllib.request.urlopen(req, timeout=300)
 
 
-def test_server_completion_matches_pipeline(server):
-    url, pipe = server
+def _sse_chunks(raw: str) -> list[dict]:
+    """The JSON bodies of an SSE response, `[DONE]` left out."""
+    return [
+        json.loads(l[len("data: "):]) for l in raw.splitlines()
+        if l.startswith("data: ") and l != "data: [DONE]"
+    ]
+
+
+def _sse_text(chunks: list[dict]) -> str:
+    return "".join(
+        c["choices"][0]["delta"].get("content") or ""
+        for c in chunks if c.get("choices")
+    )
+
+
+@pytest.mark.parametrize("stream", [False, True], ids=["json", "sse"])
+def test_server_completion_matches_pipeline(continuous_server, stream):
+    """Non-streaming and streaming through the scheduler both return
+    exactly the solo pipeline reply, with real usage accounting."""
+    url, pipe = continuous_server
+    ref = pipe.chat("hello there", max_new_tokens=5)
     body = {
         "model": "oryx-tpu",
         "messages": [{"role": "user", "content": "hello there"}],
         "max_tokens": 5,
     }
-    with _post(url, body) as resp:
-        out = json.load(resp)
-    reply = out["choices"][0]["message"]["content"]
-    assert out["object"] == "chat.completion"
-    assert reply == pipe.chat("hello there", max_new_tokens=5)
-
-    # OpenAI usage accounting: real token counts, not padding.
-    usage = out["usage"]
+    if not stream:
+        with _post(url, body) as resp:
+            out = json.load(resp)
+        assert out["object"] == "chat.completion"
+        assert out["choices"][0]["message"]["content"] == ref
+        assert out["choices"][0]["finish_reason"] == "length"
+        # OpenAI usage accounting: real token counts, not padding.
+        usage = out["usage"]
+        # /v1/models and /healthz answer.
+        with urllib.request.urlopen(url + "/v1/models", timeout=30) as r:
+            assert json.load(r)["data"][0]["id"] == "oryx-tpu"
+        with urllib.request.urlopen(url + "/healthz", timeout=30) as r:
+            assert json.load(r)["status"] == "ok"
+    else:
+        with _post(url, {
+            **body, "stream": True,
+            "stream_options": {"include_usage": True},
+        }) as resp:
+            raw = resp.read().decode()
+        assert raw.strip().endswith("data: [DONE]")
+        chunks = _sse_chunks(raw)
+        deltas = _sse_text(chunks)
+        assert deltas == ref
+        with_usage = [c for c in chunks if c.get("usage")]
+        assert len(with_usage) == 1
+        usage = with_usage[0]["usage"]
     assert usage["prompt_tokens"] > 0
-    assert 0 < usage["completion_tokens"] <= 5
+    assert usage["completion_tokens"] == 5
     assert usage["total_tokens"] == (
         usage["prompt_tokens"] + usage["completion_tokens"]
     )
 
-    # /v1/models and /healthz answer.
-    with urllib.request.urlopen(url + "/v1/models", timeout=30) as r:
-        assert json.load(r)["data"][0]["id"] == "oryx-tpu"
-    with urllib.request.urlopen(url + "/healthz", timeout=30) as r:
-        assert json.load(r)["status"] == "ok"
 
-
-def test_server_streaming_usage_chunk(server):
+def test_server_streaming_usage_chunk(continuous_server):
     """stream_options.include_usage: a final empty-choices chunk carries
     the usage totals; without the option, no chunk has usage."""
-    url, pipe = server
+    url, pipe = continuous_server
     body = {
         "model": "oryx-tpu", "stream": True,
         "stream_options": {"include_usage": True},
@@ -261,11 +252,7 @@ def test_server_streaming_usage_chunk(server):
     }
     with _post(url, body) as resp:
         raw = resp.read().decode()
-    chunks = [
-        json.loads(l[len("data: "):])
-        for l in raw.splitlines()
-        if l.startswith("data: ") and l != "data: [DONE]"
-    ]
+    chunks = _sse_chunks(raw)
     # OpenAI contract: EVERY chunk carries the usage key — null on delta
     # chunks, totals (with empty choices) on the final one.
     assert all("usage" in c for c in chunks), chunks
@@ -295,8 +282,8 @@ def test_server_streaming_usage_chunk(server):
             assert e.code == 400
 
 
-def test_server_streaming_sse(server):
-    url, pipe = server
+def test_server_streaming_sse(continuous_server):
+    url, pipe = continuous_server
     body = {
         "model": "oryx-tpu", "stream": True,
         "messages": [{"role": "user", "content": "hello there"}],
@@ -321,34 +308,8 @@ def test_server_streaming_sse(server):
     assert "".join(deltas) == pipe.chat("hello there", max_new_tokens=5)
 
 
-def test_server_dynamic_batching(server):
-    url, pipe = server
-    qs = ["hello there", "what now?", "tell me more"]
-    refs = [pipe.chat(q, max_new_tokens=4) for q in qs]
-    results = [None] * len(qs)
-
-    def call(i):
-        body = {
-            "model": "m", "max_tokens": 4,
-            "messages": [{"role": "user", "content": qs[i]}],
-        }
-        with _post(url, body) as resp:
-            results[i] = json.load(
-                resp
-            )["choices"][0]["message"]["content"]
-
-    threads = [
-        threading.Thread(target=call, args=(i,)) for i in range(len(qs))
-    ]
-    for t in threads:
-        t.start()
-    for t in threads:
-        t.join(timeout=300)
-    assert results == refs
-
-
-def test_server_bad_request(server):
-    url, _ = server
+def test_server_bad_request(continuous_server):
+    url, _ = continuous_server
     try:
         _post(url, {"messages": [{"role": "assistant", "content": "x"}]})
         raise AssertionError("expected HTTP 400")
@@ -415,48 +376,25 @@ def test_parse_messages_rejects_misplaced_images():
     assert len(images) == 1
 
 
-def test_batcher_splits_on_sampling_params():
-    calls = []
-
-    class StubPipe:
-        def chat_batch(self, requests, max_new_tokens,
-                       return_finish_reasons=False,
-                       return_token_counts=False, **sampling):
-            calls.append((
-                [r["question"] for r in requests],
-                sampling.get("temperature"),
-            ))
-            replies = [r["question"].upper() for r in requests]
-            out = (replies, ["stop"] * len(replies))
-            if return_token_counts:
-                out += ([(3, 1)] * len(replies),)
-            return out
-
-    b = api_server.Batcher(StubPipe(), window=2.0, max_batch=8)
-    pending = [
-        b.submit({"question": "a"}, 4, {"temperature": 0.5}),
-        b.submit({"question": "b"}, 4, {"temperature": 0.5}),
-        b.submit({"question": "c"}, 4, {}),  # different program
-    ]
-    for p in pending:
-        assert p.done.wait(timeout=30)
-    assert [p.reply for p in pending] == ["A", "B", "C"]
-    assert calls == [(["a", "b"], 0.5), (["c"], None)], calls
-
-
-def test_server_sampling_roundtrip(server):
-    url, pipe = server
+def test_server_sampling_roundtrip(continuous_server):
+    url, pipe = continuous_server
     body = {
         "messages": [{"role": "user", "content": "hello there"}],
         "max_tokens": 5, "temperature": 0.9, "top_p": 0.95, "seed": 7,
     }
-    with _post(url, body) as resp:
-        reply = json.load(resp)["choices"][0]["message"]["content"]
-    # Same params through the pipeline directly -> identical sample.
-    assert reply == pipe.chat(
-        "hello there", max_new_tokens=5, temperature=0.9, top_p=0.95,
-        seed=7,
-    )
+
+    def ask(**over):
+        with _post(url, {**body, **over}) as resp:
+            return json.load(resp)["choices"][0]["message"]["content"]
+
+    # The sampling fields reach the slot: a seeded request reproduces
+    # its sample, and the draw is the seed's (the engine keys each
+    # request by its own seed, so the sample is not the dense
+    # `pipe.chat` loop's draw for the same seed).
+    reply = ask()
+    assert reply == ask()
+    assert reply != pipe.chat("hello there", max_new_tokens=5)
+    assert len({reply, ask(seed=8), ask(seed=9)}) > 1
     # Unsupported n > 1 is a 400, not a silent ignore.
     try:
         _post(url, {
@@ -467,73 +405,8 @@ def test_server_sampling_roundtrip(server):
         assert e.code == 400
 
 
-@contextlib.contextmanager
-def _spied_server(pipe, batch_window=1.0):
-    """Dedicated server with a wide batch window + a chat_batch spy —
-    `calls` records (n_rows, max_new_tokens, sorted per_row_max) per
-    device call; the pipe is restored and the server shut down on exit.
-    Shared by the co-batching and concurrency tests so the
-    monkeypatch/build_server/shutdown plumbing exists once."""
-    orig = pipe.chat_batch
-    calls = []
-
-    def spy(requests, **kw):
-        calls.append((len(requests), kw.get("max_new_tokens"),
-                      sorted(kw.get("per_row_max") or [])))
-        return orig(requests, **kw)
-
-    pipe.chat_batch = spy
-    srv = api_server.build_server(pipe, port=0, batch_window=batch_window)
-    threading.Thread(target=srv.serve_forever, daemon=True).start()
-    try:
-        yield f"http://127.0.0.1:{srv.server_address[1]}", calls, orig
-    finally:
-        pipe.chat_batch = orig
-        srv.shutdown()
-
-
-def test_mixed_max_tokens_batch_matches_solo(server):
-    """Requests with different max_tokens in one bucket batch into ONE
-    device call and still return exactly what a solo call with that cap
-    returns (greedy decode is prefix-stable across the longer shared
-    window). A dedicated server with a wide batch window + a chat_batch
-    spy makes the co-batching assertion deterministic."""
-    _, pipe = server
-    with _spied_server(pipe) as (url, calls, orig):
-        qs_caps = [("hello there", 3), ("what now?", 6),
-                   ("tell me more", 9)]
-        refs = [orig([{"question": q}], max_new_tokens=c)[0]
-                for q, c in qs_caps]
-        calls.clear()
-        results = [None] * len(qs_caps)
-
-        def call(i):
-            q, c = qs_caps[i]
-            with _post(url, {
-                "max_tokens": c,
-                "messages": [{"role": "user", "content": q}],
-            }) as resp:
-                results[i] = json.load(
-                    resp
-                )["choices"][0]["message"]["content"]
-
-        threads = [
-            threading.Thread(target=call, args=(i,))
-            for i in range(len(qs_caps))
-        ]
-        for t in threads:
-            t.start()
-        for t in threads:
-            t.join(timeout=300)
-        assert not any(t.is_alive() for t in threads), "client hung"
-        assert results == refs
-        # All three shared one decode of the bucket (16) with their own
-        # caps — not three solo batches.
-        assert (3, 16, [3, 6, 9]) in calls, calls
-
-
-def test_server_rejects_excessive_max_tokens(server):
-    url, _ = server
+def test_server_rejects_excessive_max_tokens(continuous_server):
+    url, _ = continuous_server
     try:
         _post(url, {
             "max_tokens": 10**9,
@@ -542,60 +415,6 @@ def test_server_rejects_excessive_max_tokens(server):
         raise AssertionError("expected HTTP 400")
     except urllib.error.HTTPError as e:
         assert e.code == 400
-
-
-@pytest.fixture(scope="module")
-def continuous_server():
-    """Server on the continuous-batching engine (paged KV scheduler)."""
-    cfg = cfg_lib.oryx_tiny()
-    params = oryx.init_params(cfg, jax.random.key(0))
-    pipe = OryxInference(FakeTokenizer(), params, cfg)
-    srv = api_server.build_server(
-        pipe, port=0, engine="continuous", num_slots=2, page_size=16,
-        decode_chunk=4, max_ctx=512,
-    )
-    thread = threading.Thread(target=srv.serve_forever, daemon=True)
-    thread.start()
-    yield f"http://127.0.0.1:{srv.server_address[1]}", pipe
-    srv.scheduler.close()
-    srv.shutdown()
-
-
-def test_continuous_server_matches_pipeline(continuous_server):
-    """Non-streaming and streaming through the scheduler both return
-    exactly the solo pipeline reply, with real usage accounting."""
-    url, pipe = continuous_server
-    ref = pipe.chat("hello there", max_new_tokens=5)
-    with _post(url, {
-        "messages": [{"role": "user", "content": "hello there"}],
-        "max_tokens": 5,
-    }) as r:
-        out = json.load(r)
-    assert out["choices"][0]["message"]["content"] == ref
-    assert out["choices"][0]["finish_reason"] == "length"
-    u = out["usage"]
-    assert u["prompt_tokens"] > 0 and u["completion_tokens"] == 5
-    assert u["total_tokens"] == u["prompt_tokens"] + u["completion_tokens"]
-
-    with _post(url, {
-        "messages": [{"role": "user", "content": "hello there"}],
-        "max_tokens": 5, "stream": True,
-        "stream_options": {"include_usage": True},
-    }) as r:
-        raw = r.read().decode()
-    assert raw.strip().endswith("data: [DONE]")
-    chunks = [
-        json.loads(l[6:]) for l in raw.splitlines()
-        if l.startswith("data: ") and l != "data: [DONE]"
-    ]
-    deltas = "".join(
-        c["choices"][0]["delta"].get("content") or ""
-        for c in chunks if c.get("choices")
-    )
-    assert deltas == ref
-    with_usage = [c for c in chunks if c.get("usage")]
-    assert len(with_usage) == 1
-    assert with_usage[0]["usage"]["completion_tokens"] == 5
 
 
 def _parse_prometheus(text: str) -> dict[str, float]:
@@ -678,114 +497,179 @@ def test_metrics_endpoint_under_concurrent_load(continuous_server):
     )
 
 
-def test_window_engine_metrics_endpoint(server):
-    """The legacy window engine exports /metrics too (queue depth +
-    batch accounting)."""
-    url, _ = server
-    # Ensure at least one request has flowed through the batcher.
-    with _post(url, {
-        "max_tokens": 3,
-        "messages": [{"role": "user", "content": "ping"}],
-    }) as r:
-        json.load(r)
-    with urllib.request.urlopen(url + "/metrics", timeout=30) as r:
-        text = r.read().decode()
-    values = _parse_prometheus(text)
-    assert values["oryx_serving_completed"] >= 1
-    assert "oryx_serving_queue_depth" in values
-    assert values["oryx_serving_decode_steps_total"] > 0
+def _timeline(url) -> dict:
+    with urllib.request.urlopen(url + "/debug/timeline?n=0",
+                                timeout=30) as r:
+        return json.load(r)
 
 
-def test_server_concurrent_mixed_clients(server):
+def _ask_concurrently(url, bodies: list[dict]) -> list[str]:
+    """POST every body from its own thread at once; the reply text of
+    each (SSE deltas joined for `stream` bodies), in `bodies` order.
+    Also asserts that the requests really shared the engine: some
+    dispatch of this burst decoded more than one live slot."""
+    steps_before = _timeline(url)["total_steps"]
+    results: list[str | None] = [None] * len(bodies)
+    errors: list[str] = []
+
+    def call(i):
+        try:
+            with _post(url, bodies[i]) as resp:
+                if not bodies[i].get("stream"):
+                    results[i] = json.load(
+                        resp
+                    )["choices"][0]["message"]["content"]
+                    return
+                raw = resp.read().decode()
+            results[i] = _sse_text(_sse_chunks(raw))
+        except Exception as e:  # surface in the main thread
+            errors.append(f"{bodies[i]}: {e!r}")
+
+    threads = [
+        threading.Thread(target=call, args=(i,))
+        for i in range(len(bodies))
+    ]
+    for t in threads:
+        t.start()
+    for t in threads:
+        t.join(timeout=600)
+    assert not any(t.is_alive() for t in threads), "client hung"
+    assert not errors, errors
+    tl = _timeline(url)
+    burst = tl["records"][:tl["total_steps"] - steps_before]
+    assert max(r["live_slots"] for r in burst) > 1, burst
+    return results
+
+
+def _ask(q, cap, **extra) -> dict:
+    return {
+        "max_tokens": cap,
+        "messages": [{"role": "user", "content": q}], **extra,
+    }
+
+
+def test_server_concurrent_mixed_clients(continuous_server):
     """>=8 genuinely simultaneous HTTP clients —
     mixed stream/non-stream, mixed text/image — through the
-    ThreadingHTTPServer + batch-window path. Every response must equal
-    its single-request answer and at least one >1-size batch must have
-    actually formed (the batcher is not just running solo rows)."""
-    _, pipe = server
+    ThreadingHTTPServer into two slots. Every response must equal
+    its single-request answer and the requests must have actually
+    shared dispatches (the engine is not just running solo rows)."""
+    url, pipe = continuous_server
     rng = np.random.default_rng(7)
     imgs = [
         rng.integers(0, 255, size=(24, 24, 3), dtype=np.uint8)
         for _ in range(2)
     ]
-    with _spied_server(pipe) as (url, calls, orig):
-        text_qs = [("hello there", 4), ("what now?", 6),
-                   ("tell me more", 8), ("and then?", 5)]
-        img_qs = [("what is this?", 4), ("describe it", 6)]
-        stream_qs = [("say something", 5), ("go on", 7)]
-        # Single-request references, computed before the server sees any
-        # traffic (greedy decode: order-independent).
-        refs = {}
-        for q, c in text_qs:
-            refs[q] = orig([{"question": q}], max_new_tokens=c)[0]
-        for (q, c), im in zip(img_qs, imgs):
-            refs[q] = orig(
-                [{"question": q, "images": [im]}], max_new_tokens=c
-            )[0]
-        for q, c in stream_qs:
-            refs[q] = "".join(
-                pipe.chat_stream(q, max_new_tokens=c)
-            )
-        calls.clear()
+    text_qs = [("hello there", 4), ("what now?", 6),
+               ("tell me more", 8), ("and then?", 5)]
+    img_qs = [("what is this?", 4), ("describe it", 6)]
+    stream_qs = [("say something", 5), ("go on", 7)]
+    # Single-request references (greedy decode: order-independent).
+    refs = [pipe.chat(q, max_new_tokens=c) for q, c in text_qs]
+    refs += [
+        pipe.chat(q, images=[im], max_new_tokens=c)
+        for (q, c), im in zip(img_qs, imgs)
+    ]
+    refs += [
+        "".join(pipe.chat_stream(q, max_new_tokens=c))
+        for q, c in stream_qs
+    ]
+    bodies = [_ask(q, c) for q, c in text_qs]
+    bodies += [
+        _ask([
+            {"type": "text", "text": q},
+            {"type": "image_url", "image_url": {"url": _data_uri(im)}},
+        ], c)
+        for (q, c), im in zip(img_qs, imgs)
+    ]
+    bodies += [_ask(q, c, stream=True) for q, c in stream_qs]
+    assert len(bodies) == 8
+    assert _ask_concurrently(url, bodies) == refs
 
-        results: dict[str, str] = {}
-        errors: list[str] = []
 
-        def nonstream(q, c, image=None):
-            content = q if image is None else [
-                {"type": "text", "text": q},
-                {"type": "image_url", "image_url": {"url": _data_uri(image)}},
-            ]
-            try:
-                with _post(url, {
-                    "max_tokens": c,
-                    "messages": [{"role": "user", "content": content}],
-                }) as resp:
-                    results[q] = json.load(
-                        resp
-                    )["choices"][0]["message"]["content"]
-            except Exception as e:  # surface in the main thread
-                errors.append(f"{q}: {e!r}")
+@pytest.mark.parametrize(
+    "caps", [(16, 16, 16), (12, 20, 28)],
+    ids=["equal-caps", "mixed-caps"],
+)
+def test_concurrent_requests_match_solo_across_max_tokens(
+    continuous_server, caps,
+):
+    """Concurrent non-streaming requests, resident together, each
+    return exactly what a solo `pipe.chat` with that request's own
+    `max_tokens` returns: a slot retires at its own cap whatever its
+    neighbours asked for. (Several chunks long, so that they overlap.)"""
+    url, pipe = continuous_server
+    qs = ["hello there", "what now?", "tell me more"]
+    refs = [pipe.chat(q, max_new_tokens=c) for q, c in zip(qs, caps)]
+    assert _ask_concurrently(
+        url, [_ask(q, c) for q, c in zip(qs, caps)]
+    ) == refs
 
-        def stream(q, c):
-            try:
-                with _post(url, {
-                    "max_tokens": c, "stream": True,
-                    "messages": [{"role": "user", "content": q}],
-                }) as resp:
-                    raw = resp.read().decode()
-                chunks = [
-                    json.loads(l[6:]) for l in raw.splitlines()
-                    if l.startswith("data: ") and l != "data: [DONE]"
-                ]
-                results[q] = "".join(
-                    c["choices"][0]["delta"].get("content") or ""
-                    for c in chunks if c.get("choices")
-                )
-            except Exception as e:
-                errors.append(f"{q}: {e!r}")
 
-        threads = (
-            [threading.Thread(target=nonstream, args=(q, c))
-             for q, c in text_qs]
-            + [threading.Thread(target=nonstream, args=(q, c, im))
-               for (q, c), im in zip(img_qs, imgs)]
-            + [threading.Thread(target=stream, args=(q, c))
-               for q, c in stream_qs]
+def test_concurrent_requests_match_solo_across_sampling_params(
+    continuous_server,
+):
+    """Requests that differ in temperature / top_p / stop share one
+    resident batch (sampling is per slot, nothing is split off) and
+    each still equals its solo output."""
+    url, pipe = continuous_server
+    greedy = pipe.chat("hello there", max_new_tokens=16)
+    # A stop string the greedy reply is sure to contain.
+    stop = greedy[2:4]
+    asks = [
+        ("hello there", {}),
+        ("hello there", {"stop": [stop]}),
+        ("what now?", {"temperature": 0.9, "top_p": 0.95, "seed": 7}),
+        ("tell me more", {"temperature": 0.5, "seed": 3}),
+    ]
+    bodies = [_ask(q, 16, **kw) for q, kw in asks]
+    # Solo: the dense loop for the greedy rows; for the seeded rows the
+    # same request alone through the server (the engine keys a request
+    # by its own seed, not by the dense loop's draw order).
+    refs = [pipe.chat(q, max_new_tokens=16, **kw) for q, kw in asks[:2]]
+    assert refs[1] != refs[0] and stop not in refs[1]
+    for body in bodies[2:]:
+        with _post(url, body) as resp:
+            refs.append(json.load(resp)["choices"][0]["message"]["content"])
+    assert _ask_concurrently(url, bodies) == refs
+
+
+def test_default_engine_is_continuous():
+    """`build_server` with no `engine` builds the scheduler the
+    benchmark measures, and `/metrics` says so."""
+    from oryx_tpu.serve.scheduler import ContinuousScheduler
+
+    pipe = _tiny_pipe()
+    srv = api_server.build_server(pipe, port=0)
+    threading.Thread(target=srv.serve_forever, daemon=True).start()
+    try:
+        assert isinstance(srv.scheduler, ContinuousScheduler)
+        url = f"http://127.0.0.1:{srv.server_address[1]}"
+        with urllib.request.urlopen(url + "/metrics", timeout=30) as r:
+            text = r.read().decode()
+        assert 'engine="continuous"' in next(
+            l for l in text.splitlines()
+            if l.startswith("oryx_serving_build_info{")
         )
-        assert len(threads) == 8
-        for t in threads:
-            t.start()
-        for t in threads:
-            t.join(timeout=600)
-        assert not any(t.is_alive() for t in threads), "client hung"
-        assert not errors, errors
-        for q, want in refs.items():
-            assert results.get(q) == want, (
-                f"{q!r}: {results.get(q)!r} != single-request {want!r}"
-            )
-        # A real multi-row batch formed out of the concurrent traffic.
-        assert max(n for n, _, _ in calls) > 1, calls
+    finally:
+        srv.scheduler.close()
+        srv.shutdown()
+
+
+def test_window_engine_is_unknown():
+    """The registry's own error names what is registered."""
+    with pytest.raises(ValueError, match="unknown engine 'window'"):
+        api_server.build_server(None, port=0, engine="window")
+
+
+@pytest.mark.parametrize("flag", [
+    ["--batch-window", "0.1"], ["--max-batch", "4"],
+    ["--engine", "window"],
+])
+def test_cli_refuses_the_window_engines_flags(flag):
+    with pytest.raises(SystemExit) as e:
+        api_server.main(["--model-path", "x", *flag])
+    assert e.value.code == 2
 
 
 def test_continuous_request_id_and_debug_endpoints(continuous_server):
@@ -856,10 +740,7 @@ def test_continuous_streaming_request_id(continuous_server):
         rid = r.headers["X-Request-Id"]
         raw = r.read().decode()
     assert rid
-    chunks = [
-        json.loads(l[6:]) for l in raw.splitlines()
-        if l.startswith("data: ") and l != "data: [DONE]"
-    ]
+    chunks = _sse_chunks(raw)
     assert all(c["id"] == f"chatcmpl-{rid}" for c in chunks)
     with urllib.request.urlopen(
         url + f"/debug/trace?id={rid}", timeout=30
@@ -900,55 +781,6 @@ def test_metrics_content_type_and_build_info(continuous_server):
     assert 'engine="continuous"' in labels
     assert 'revision="' in labels and 'revision=""' not in labels
     assert 'model="oryx-tpu"' in labels
-
-
-def test_window_engine_request_id_and_debug(server):
-    """The window engine gets the same observability surface: request
-    ids on responses, flight-recorder entries, and parity spans
-    (queue_wait + shared decode window; prefill/decode_chunk via
-    chat_stream for solo streams)."""
-    url, _ = server
-    with _post(url, {
-        "messages": [{"role": "user", "content": "hello there"}],
-        "max_tokens": 4,
-    }) as r:
-        rid = r.headers["X-Request-Id"]
-        json.load(r)
-    with urllib.request.urlopen(
-        url + f"/debug/trace?id={rid}", timeout=30
-    ) as r:
-        tj = json.load(r)
-    names = {e["name"] for e in tj["traceEvents"] if e.get("ph") == "X"}
-    assert {"queue_wait", "decode"} <= names
-    decode = next(
-        e for e in tj["traceEvents"] if e.get("name") == "decode"
-    )
-    assert decode["args"]["batch_size"] >= 1
-    assert tj["request"]["meta"]["finish_reason"] == "length"
-
-    # Streaming (solo chat_stream): pipeline spans via the active trace.
-    with _post(url, {
-        "messages": [{"role": "user", "content": "hello there"}],
-        "max_tokens": 4, "stream": True,
-    }) as r:
-        srid = r.headers["X-Request-Id"]
-        r.read()
-    with urllib.request.urlopen(
-        url + f"/debug/trace?id={srid}", timeout=30
-    ) as r:
-        snames = {
-            e["name"] for e in json.load(r)["traceEvents"]
-            if e.get("ph") == "X"
-        }
-    assert {"prefill", "decode_chunk", "emission"} <= snames
-
-    with urllib.request.urlopen(url + "/debug/requests", timeout=30) as r:
-        ids = [e["id"] for e in json.load(r)["requests"]]
-    assert rid in ids and srid in ids
-
-    # Window engine build_info says so.
-    with urllib.request.urlopen(url + "/metrics", timeout=30) as r:
-        assert 'engine="window"' in r.read().decode()
 
 
 def test_debug_requests_limit_and_state_filters(continuous_server):
@@ -1037,10 +869,7 @@ def test_cost_ledger_in_completion_and_final_sse_chunk(continuous_server):
         "stream_options": {"include_usage": True},
     }) as r:
         raw = r.read().decode()
-    chunks = [
-        json.loads(l[6:]) for l in raw.splitlines()
-        if l.startswith("data: ") and l != "data: [DONE]"
-    ]
+    chunks = _sse_chunks(raw)
     with_cost = [c for c in chunks if "oryx" in c]
     assert len(with_cost) == 1
     fin = with_cost[0]

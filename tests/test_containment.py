@@ -384,15 +384,6 @@ def test_dead_engine_without_supervisor_rejects_and_drains(pipe):
     sched.close()
 
 
-def test_window_engine_rejects_request_timeout(pipe):
-    """The window batcher does not enforce deadlines; accepting the
-    flag would promise 504s that never fire — fail at build."""
-    with pytest.raises(ValueError, match="request-timeout"):
-        api_server.build_server(
-            pipe, port=0, engine="window", request_timeout=5.0,
-        )
-
-
 # ---------------------------------------------------------------------------
 # Drain-on-shutdown
 # ---------------------------------------------------------------------------

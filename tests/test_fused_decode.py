@@ -231,8 +231,6 @@ def test_build_server_fuse_flag_pairing(pipe):
     with pytest.raises(ValueError, match="speculate"):
         build_server(pipe, ragged=True, draft_model="init:512:8:8:0",
                      **base)
-    with pytest.raises(ValueError, match="scheduler engine"):
-        build_server(pipe, engine="window", fuse_steps=4)
 
 
 def test_cli_fuse_flag_validation():

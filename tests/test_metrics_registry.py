@@ -157,7 +157,7 @@ def test_concurrent_increments_exact():
 
 def test_info_metric_replaces():
     r = Registry(prefix="s")
-    r.info("build_info", {"revision": "abc", "engine": "window"})
+    r.info("build_info", {"revision": "abc", "engine": "continuous"})
     r.info("build_info", {"revision": "def", "engine": "continuous"})
     v = parse_exposition(r.render())
     assert v == {

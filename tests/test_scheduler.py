@@ -161,28 +161,6 @@ def test_request_too_large_errors_cleanly(pipe):
     sched.close()
 
 
-@pytest.mark.slow
-def test_bench_wasted_step_fraction_drops_2x():
-    """Acceptance gate: on the skewed workload the scheduler's
-    wasted-step fraction is >= 2x lower than the window batcher's, and
-    occupancy is reported."""
-    import importlib.util
-    import os
-
-    spec = importlib.util.spec_from_file_location(
-        "bench_serving_sched",
-        os.path.join(
-            os.path.dirname(__file__), "..", "scripts",
-            "bench_serving_sched.py",
-        ),
-    )
-    mod = importlib.util.module_from_spec(spec)
-    spec.loader.exec_module(mod)
-    out = mod.run([])
-    assert out["wasted_frac_ratio"] >= 2.0, out
-    assert 0.0 < out["scheduler"]["step_utilization"] <= 1.0
-
-
 def test_request_traces_cover_lifecycle_and_eviction(pipe):
     """Flight-recorder span trees: every request records queue_wait ->
     admission -> prefill -> decode chunks -> emission; an evicted
@@ -584,9 +562,8 @@ def test_stop_string_mid_chunk_not_billed_useful(pipe):
     """Bugfix pin: a slot that finishes mid-chunk on a stop STRING
     (detected host-side, so the token loop consumed the whole chunk)
     must re-bill the steps past the stop completion as wasted —
-    without this, bench_serving_sched.py's wasted-step fraction
-    under-counts exactly when stop strings end rows early, flattering
-    whichever engine wastes more."""
+    without this, the wasted-step fraction under-counts exactly when
+    stop strings end rows early."""
     import time as time_lib
 
     from oryx_tpu.serve.scheduler import RequestHandle, _Request
