@@ -692,7 +692,9 @@ def paged_decode_chunk(
             input_ids=tok[:, None], positions=pos,
             kv_cache=kv_pages, write_slots=cur_len, kv_mask=kv_mask,
             block_tables=block_tables, write_mask=~finished,
-            kv_lengths=cur_len + 1,
+            # A finished or empty lane reads nothing (its token is
+            # replaced below): length 0 is no step of the page walk.
+            kv_lengths=jnp.where(finished, 0, cur_len + 1),
             attn_impl=attn_impl, compute_dtype=compute_dtype,
         )
         if numerics:

@@ -343,8 +343,8 @@ class QuantPages:
     on. Per-row scales make the encoding a pure function of the
     token's own value; the storage overhead is 4 bytes per Hk*D-byte
     row (<1%), and the layout is what rides the block-table stream
-    into the Pallas kernel (scales are fetched per page tile alongside
-    the code tile, addressed by the same scalar-prefetched table).
+    into the Pallas kernel (a block's scale rows are copied beside its
+    code pages, addressed through the same scalar-prefetched table).
 
     Registered as a pytree node, so everything downstream — the layer
     scan in qwen2.forward, jit donation, `copy_pages`' tree_map, host

@@ -5,46 +5,43 @@ One kernel, two entry points:
   * `ragged_decode_attention` — the single-token decode twin of
     `ops.paged_kv.ragged_decode_attention`: [B, 1] queries, one
     sequence per batch row. A decode row is a length-1 ragged lane,
-    so it runs the packed kernel with segment b, position len-1.
+    so it runs the packed kernel with segment b, position len-1; a
+    finished or empty lane is a row of length 0.
   * `ragged_paged_attention` — the PACKED ragged kernel (arXiv
     2604.15464): R query rows drawn from many sequences with MIXED
     query lengths (decode steps and chunked-prefill suffix tokens side
     by side), each walking its OWN sequence's block table via
     scalar-prefetched (segment, position) metadata and causally masked
     at its own position. This is the kernel behind the serving
-    engine's one-dispatch-per-step path
-    (models/generate.paged_ragged_step); its kv-head tile comes from
-    `ragged_heads_per_block`, which offers only sizes the TPU lowering
-    accepts.
+    engine's step programs (models/generate.paged_decode_chunk,
+    paged_block_step, paged_ragged_step).
 
-    Speculative decoding rides the SAME kernel unchanged: a slot's 1+k
-    verify lanes (ops/paged_kv.spec_lane_metadata) are just 1+k more
-    (segment, position) rows of the R-row grid — consecutive positions
-    of one segment, exactly the shape a chunked-prefill suffix already
-    exercises, so the R axis grows from S+pf to S*(1+k)+pf and nothing
-    else moves. The grid stays static per (S, k, pf_width) class; the
-    per-row page walk, dead-tile DMA elision and tail masking are
-    position-driven and need no notion of "draft".
+    Speculative decoding and block diffusion ride the SAME kernel
+    unchanged: a slot's 1+k verify lanes (ops/paged_kv.
+    spec_lane_metadata) or its B block lanes are just more (segment,
+    position) rows — consecutive or shared positions of one segment,
+    exactly the shape a chunked-prefill suffix already exercises. The
+    grid is static per row count; the walk and the tail masking are
+    position-driven and need no notion of "draft" or "block".
 
 The point of both: attention over a sequence's pages happens IN
-PLACE — the block table is a scalar-prefetch operand, so each kv
-tile's DMA source address is computed from it before the tile runs,
-and no [B, max_len] contiguous copy of the cache is ever materialized
-(the XLA reference gathers one per layer per step; at 7B serving
-shapes that gather IS the decode bandwidth bill).
+PLACE — the pool stays in HBM, the kernel copies a row's live pages
+out of it through the block table, and no [B, max_len] contiguous copy
+of the cache is ever materialized (the XLA reference gathers one per
+layer per step; at 7B serving shapes that gather IS the decode
+bandwidth bill). What a call costs follows the KV its rows can see:
+the walk takes a step for every `ragged_pages_per_block` pages a row
+reads and none for a page nobody reads (see the comment above
+`_ragged_kernel`).
 
-Shares the flash-attention kernel skeleton (ops/pallas/
-flash_attention.py): pages innermost and sequential, online-softmax
-(m, l, acc) state in VMEM scratch, fp32 logits/softmax, probs·V in the
-value dtype. The GQA group dimension rides INSIDE the tile (q is
-reshaped [R, Hk, G, D]), so every grid step issues one [G, page_size]
-logit matmul per kv head — the decode-shaped analogue of the prefill
-kernel's [block_q, block_k] tiles. Raggedness is handled per packed
-row (see the comment above `_ragged_kernel`).
+Shares the flash-attention kernel's mathematics (ops/pallas/
+flash_attention.py): online-softmax (m, l, acc) state in VMEM scratch
+carried across a row's walk, fp32 logits/softmax, probs·V in the value
+dtype, fp32 accumulation.
 
 Interpret mode runs the same kernel on CPU for tests; on a TPU the
 Mosaic kernel runs (tests/test_pallas_topology_compile.py compiles it
-at Oryx-7B geometry for a described v5e).
+at both serving geometries for a described v5e).
 """
 
 from __future__ import annotations
@@ -118,31 +115,50 @@ def _split_quant(k_pages, v_pages):
 # Packed ragged kernel: mixed query lengths, one grid, per-row block tables
 # ---------------------------------------------------------------------------
 #
-# Grid (R, Hk // HB, maxp): packed row outermost, kv-head tile, pages
-# innermost and sequential so the online-softmax scratch carries across
-# a row's page walk. Each grid step DMAs ONE page tile of HB kv heads
-# ([1, ps, HB, D], contiguous in the pool) and issues HB [G, ps] logit
-# matmuls. Raggedness per packed row r (seg = q_segments[r],
-# pos = q_positions[r]):
-#   * tiles wholly past pos skip compute AND DMA (index map clamps dead
-#     page ids onto the last live page; Pallas elides the repeat DMA);
-#   * the tail tile masks slots > pos to -inf before the softmax —
-#     the causal mask and the validity mask are the SAME mask here,
-#     which is what lets decode rows (pos = len-1) and prefill-suffix
-#     rows (consecutive pos) share the kernel;
+# Grid (R,): one step a packed row, in order. The pool stays in HBM
+# (memory space HBM), viewed [P, ps * Hk, D] — a free reshape, same
+# bytes in the same order — so a page is ONE contiguous copy into fully
+# used VMEM tiles, its rows interleaved token-major (row t * Hk + h is
+# kv head h of token t). The kernel fetches pages itself, through the
+# scalar-prefetched block table, into a double-buffered VMEM scratch.
+# The walk of packed row r (seg = q_segments[r], pos = q_positions[r],
+# length = pos + 1):
+#   * it takes ceil(length / (npb * ps)) steps of a loop whose bound is
+#     read from the prefetched positions — none for a row of length 0,
+#     which only zeroes its output. No step exists for a page nobody
+#     reads, and within a step only live pages are copied;
+#   * a step handles a block of npb pages. While it computes, the next
+#     block is in flight: the row's own next block or, on its last, the
+#     NEXT ROW's first block (the buffer slot is handed across grid
+#     steps in SMEM), so a row's first copy is hidden behind the row
+#     before it;
+#   * a step is one [HB * G, npb * ps * Hk] logit product against the
+#     block as it lies in VMEM, one softmax update and one p.V product
+#     for a tile of HB kv heads: a q head sees the columns of its own
+#     kv head and of tokens <= pos, every other column is masked before
+#     the softmax and so meets V as an exact zero. The interleaved rows
+#     are never unpacked, the MXU loads each K and V row as a weight
+#     once either way, and the causal mask and the validity mask are
+#     the SAME mask — which is what lets decode rows (pos = len - 1),
+#     prefill-suffix / verify rows (consecutive pos) and block rows
+#     share the kernel. Every packed row keeps its own mask and its own
+#     (m, l, acc);
 #   * sentinel block-table entries clip into the pool for address
-#     safety (only reachable masked).
+#     safety (only reachable masked). The V buffers are zeroed once a
+#     call: a masked column multiplies stale but finite VMEM.
 
-# heads_per_block (HB, kv heads per page tile) trades DMA count against
-# VMEM residency. The TPU lowering only takes a kv tile
-# [1, ps, HB, D] over the [P, ps, Hk, D] pool when HB is the whole kv
-# head axis or a multiple of 8 that divides it, so those are the only
-# tile sizes ever offered; anything else is an error naming the value,
-# on every backend, so an interpret-mode test cannot pass on a tile the
-# chip's compiler would refuse.
+# heads_per_block (HB) is the tile of kv heads whose q heads share one
+# logit product; it bounds the [HB * G, npb * ps * Hk] fp32 logit tile
+# and nothing about the copies (a page always arrives whole). The sizes
+# offered stay the ones the TPU lowering takes as a sublane tile: the
+# whole kv head axis or a multiple of 8 that divides it; anything else
+# is an error naming the value, on every backend, so an interpret-mode
+# test cannot pass on a tile the chip's compiler would refuse.
 
-# Keep the double-buffered kv tile (2 * ps * HB * D * 4B fp32) within a
-# conservative slice of VMEM alongside q/out/scratch.
+# Keep the double-buffered kv block (2 * npb * ps * Hk * D * 4B, counted
+# as fp32: a quantized block is dequantized whole) within a conservative
+# slice of VMEM alongside q/out/scratch. It sizes both the head tile's
+# default and the pages a step handles.
 _RAGGED_KV_TILE_BUDGET = 1 << 21  # 2 MiB
 
 
@@ -190,189 +206,281 @@ def ragged_heads_per_block(
     return fits[-1] if fits else legal[0]
 
 
+def ragged_pages_per_block(
+    head_dim: int, page_size: int, num_kv_heads: int, max_pages: int
+) -> int:
+    """Pages one step of a row's walk handles (npb), from shapes alone:
+    as many whole pages as the double-buffered block may hold under the
+    VMEM budget, at least one, at most the block table's width. At the
+    serving geometries (page 64, 4 kv heads, D 128) that is 8 pages,
+    512 tokens."""
+    page = 2 * page_size * num_kv_heads * head_dim * 4
+    return max(1, min(int(max_pages), _RAGGED_KV_TILE_BUDGET // page))
+
+
+def _block_scales(scale, block_tables, num_kv_heads: int, npb: int):
+    """[P, ps] scale plane -> [S, nblk, 1, npb * ps * Hk]: for every
+    block of every segment's walk, the scale of each row of the packed
+    block (a token's scale once a kv head, as the page interleaves
+    them), gathered through the SAME block table the code pages are
+    copied by. Mosaic copies nothing narrower than 128 lanes and a
+    page's scale row is ps wide, so the rows are laid out a block at a
+    time ahead of the kernel (4 bytes a token a segment; the codes are
+    Hk * D a token) and the walk copies a block's row beside its
+    pages."""
+    P = scale.shape[0]
+    S, maxp = block_tables.shape
+    nblk = -(-maxp // npb)
+    bt = jnp.pad(block_tables, ((0, 0), (0, nblk * npb - maxp)))
+    rows = jnp.repeat(scale[jnp.clip(bt, 0, P - 1)], num_kv_heads, axis=-1)
+    return rows.reshape(S, nblk, 1, -1)
+
+
 def _scale_column(row):
-    """[1, ps] scale row -> [ps, 1] column. The scale tile arrives
-    lane-major (one contiguous DMA per page); Mosaic has no
-    lane-to-sublane reshape, so select the diagonal of the broadcast
-    row and reduce over lanes — exact: each sum has one nonzero term."""
-    n = row.shape[1]
-    diag = jax.lax.broadcasted_iota(
-        jnp.int32, (n, n), 0
-    ) == jax.lax.broadcasted_iota(jnp.int32, (n, n), 1)
-    return jnp.sum(jnp.where(diag, row, 0.0), axis=1, keepdims=True)
+    """[1, n] scale row -> [n, 1] column (Mosaic transposes whole
+    sublane tiles: broadcast to one, transpose, keep a lane)."""
+    return jnp.broadcast_to(row, (8, row.shape[1])).T[:, :1]
 
 
 def _ragged_kernel(
     bt_ref,  # [S, maxp] SMEM (scalar prefetch)
     seg_ref,  # [R] SMEM
     pos_ref,  # [R] SMEM
-    *refs,  # q, k, [k_scale], v, [v_scale], o, scratch x3
+    *refs,  # q, k, [k_scale], v, [v_scale], o, scratch
     scale: float,
     page_size: int,
-    num_groups: int,
+    num_kv_heads: int,
+    pages_per_block: int,
     heads_per_block: int,
     dequant_dtype: str | None = None,
 ):
-    # Quantized pool: each page tile arrives as storage-dtype codes
-    # plus its [1, 1, ps] scale block (fetched through the SAME
-    # block-table-driven index map), and the dequant happens HERE, in
-    # the page walk — int8 is what crossed HBM. The multiply matches
-    # ops.paged_kv.gather_pages' dequant elementwise (same dtype, same
-    # broadcast), preserving the kernels' bit-parity contract.
-    if dequant_dtype is None:
-        q_ref, k_ref, v_ref, o_ref, m_scr, l_scr, acc_scr = refs
-        ks_ref = vs_ref = None
+    # Quantized pool: a page arrives as storage-dtype codes, a block's
+    # scales as one [1, brow] row (`_block_scales`), and the dequant
+    # happens HERE, in the page walk — int8 is what crossed HBM. The
+    # multiply matches ops.paged_kv.gather_pages' dequant elementwise
+    # (same dtype, same broadcast).
+    quant = dequant_dtype is not None
+    if quant:
+        (q_ref, k_hbm, ks_hbm, v_hbm, vs_hbm, o_ref, k_buf, v_buf,
+         ks_buf, vs_buf, sems, slot_ref, m_scr, l_scr, acc_scr) = refs
     else:
-        (q_ref, k_ref, ks_ref, v_ref, vs_ref, o_ref,
-         m_scr, l_scr, acc_scr) = refs
-    r, ik = pl.program_id(0), pl.program_id(2)
-    nk = pl.num_programs(2)
-    G, HB = num_groups, heads_per_block
-    Gp = m_scr.shape[0] // HB
+        (q_ref, k_hbm, v_hbm, o_ref, k_buf, v_buf,
+         sems, slot_ref, m_scr, l_scr, acc_scr) = refs
+        ks_hbm = vs_hbm = ks_buf = vs_buf = None
+    r, R = pl.program_id(0), pl.num_programs(0)
+    S, maxp = bt_ref.shape
+    P = k_hbm.shape[0]
+    ps, Hk, npb, HB = page_size, num_kv_heads, pages_per_block, heads_per_block
+    Hq = q_ref.shape[1]
+    G = Hq // Hk
+    prow = ps * Hk  # rows of one page in the packed view
+    brow = npb * prow  # rows of one block
 
-    @pl.when(ik == 0)
-    def _init():
-        m_scr[:] = jnp.full_like(m_scr, NEG)
-        l_scr[:] = jnp.zeros_like(l_scr)
-        acc_scr[:] = jnp.zeros_like(acc_scr)
+    def visible(row):
+        """(kv tokens, pages) packed row `row` reads."""
+        length = jnp.clip(pos_ref[row] + 1, 0, maxp * ps)
+        return length, pl.cdiv(length, ps)
 
-    length = pos_ref[r] + 1  # visible kv count for this packed row
-    run = ik * page_size < length
+    def block_copies(row, blk, slot):
+        """[(live, copies)] of block `blk` of `row`'s walk into buffer
+        `slot`: an entry a page, and one for a quantized block's scale
+        rows. Rebuilt with the same arguments to wait."""
+        seg = jnp.clip(seg_ref[row], 0, S - 1)
+        _, pages = visible(row)
+        out = []
+        for j in range(npb):
+            pg = blk * npb + j
+            page = jnp.clip(bt_ref[seg, jnp.minimum(pg, maxp - 1)], 0, P - 1)
+            dst = pl.ds(j * prow, prow)
+            cps = [
+                pltpu.make_async_copy(
+                    k_hbm.at[page], k_buf.at[slot, dst], sems.at[slot, 0]
+                ),
+                pltpu.make_async_copy(
+                    v_hbm.at[page], v_buf.at[slot, dst], sems.at[slot, 1]
+                ),
+            ]
+            out.append((pg < pages, cps))
+        if quant:
+            b = jnp.minimum(blk, ks_hbm.shape[1] - 1)
+            out.append((blk * npb < pages, [
+                pltpu.make_async_copy(
+                    ks_hbm.at[seg, b], ks_buf.at[slot], sems.at[slot, 2]
+                ),
+                pltpu.make_async_copy(
+                    vs_hbm.at[seg, b], vs_buf.at[slot], sems.at[slot, 3]
+                ),
+            ]))
+        return out
 
-    @pl.when(run)
-    def _step():
-        slot = ik * page_size + jax.lax.broadcasted_iota(
-            jnp.int32, (1, page_size), 1
-        )
-        if ks_ref is not None:
+    def each_copy(row, blk, slot, do):
+        for live, cps in block_copies(row, blk, slot):
+            @pl.when(live)
+            def _():
+                for cp in cps:
+                    do(cp)
+
+    def start(row, blk, slot):
+        each_copy(row, blk, slot, lambda cp: cp.start())
+
+    def wait(row, blk, slot):
+        each_copy(row, blk, slot, lambda cp: cp.wait())
+
+    @pl.when(r == 0)
+    def _first():
+        v_buf[...] = jnp.zeros_like(v_buf)
+        slot_ref[0] = 0
+        start(0, 0, 0)
+
+    m_scr[...] = jnp.full_like(m_scr, NEG)
+    l_scr[...] = jnp.zeros_like(l_scr)
+    acc_scr[...] = jnp.zeros_like(acc_scr)
+
+    length, pages = visible(r)
+    nblk = pl.cdiv(pages, npb)
+    nxt = jnp.minimum(r + 1, R - 1)
+    has_next = r + 1 < R
+    slot0 = slot_ref[0]
+
+    # Column c of a block is token c // Hk of the block, kv head c % Hk.
+    col = jax.lax.broadcasted_iota(jnp.int32, (1, brow), 1)
+    col_tok, col_head = col // Hk, col % Hk
+
+    def step(i, slot):
+        # Next in flight: this row's next block, else the next row's
+        # first, into the buffer the block before this one has left.
+        in_row = i + 1 < nblk
+
+        @pl.when(in_row | has_next)
+        def _():
+            start(
+                jnp.where(in_row, r, nxt), jnp.where(in_row, i + 1, 0),
+                1 - slot,
+            )
+
+        wait(r, i, slot)
+        k, v = k_buf[slot], v_buf[slot]  # [brow, D]
+        if quant:
             dq = jnp.dtype(dequant_dtype)
-            k_sc = _scale_column(ks_ref[0]).astype(dq)  # [ps, 1]
-            v_sc = _scale_column(vs_ref[0]).astype(dq)
-        for h in range(HB):  # static unroll over the kv-head tile
-            q = q_ref[0, h]  # [G, D]
-            k = k_ref[0, :, h, :]  # [ps, D]
-            v = v_ref[0, :, h, :]
-            if ks_ref is not None:
-                k = k.astype(dq) * k_sc
-                v = v.astype(dq) * v_sc
+            k = k.astype(dq) * _scale_column(ks_buf[slot]).astype(dq)
+            v = v.astype(dq) * _scale_column(vs_buf[slot]).astype(dq)
+        seen = i * (npb * ps) + col_tok < length
+        for t in range(Hk // HB):  # static unroll over kv-head tiles
+            lo, n = t * HB * G, HB * G
+            row_head = t * HB + jax.lax.broadcasted_iota(
+                jnp.int32, (n, 1), 0
+            ) // G
             s = jax.lax.dot_general(
-                q, k, (((1,), (1,)), ((), ())),
+                q_ref[0, lo:lo + n], k, (((1,), (1,)), ((), ())),
                 preferred_element_type=jnp.float32,
-            ) * scale  # [G, ps] fp32
+            ) * scale  # [HB * G, brow] fp32
             # Causal == validity: slots past this row's own position
             # are invisible, whether they belong to its future tokens
             # (prefill-suffix packing) or to nobody yet (decode).
-            s = jnp.where(slot < length, s, NEG)
-            lo = h * Gp
-            m_prev = m_scr[lo:lo + G, :1]
+            s = jnp.where(seen & (col_head == row_head), s, NEG)
+            m_prev = m_scr[lo:lo + n, :1]
             m_new = jnp.maximum(m_prev, jnp.max(s, axis=-1, keepdims=True))
             alpha = jnp.exp(m_prev - m_new)
-            p = jnp.exp(s - m_new)  # [G, ps] fp32
-            l_new = l_scr[lo:lo + G, :1] * alpha + jnp.sum(
+            p = jnp.exp(s - m_new)  # exact 0 in every masked column
+            l_new = l_scr[lo:lo + n, :1] * alpha + jnp.sum(
                 p, axis=-1, keepdims=True
             )
-            m_scr[lo:lo + G, :] = jnp.broadcast_to(
-                m_new, (G, m_scr.shape[1])
-            )
-            l_scr[lo:lo + G, :] = jnp.broadcast_to(
-                l_new, (G, l_scr.shape[1])
-            )
+            m_scr[lo:lo + n, :] = jnp.broadcast_to(m_new, (n, m_scr.shape[1]))
+            l_scr[lo:lo + n, :] = jnp.broadcast_to(l_new, (n, l_scr.shape[1]))
             pv = jax.lax.dot_general(
                 p.astype(v.dtype), v, (((1,), (0,)), ((), ())),
                 preferred_element_type=jnp.float32,
             )
-            acc_scr[lo:lo + G, :] = acc_scr[lo:lo + G, :] * alpha + pv
+            acc_scr[lo:lo + n, :] = acc_scr[lo:lo + n, :] * alpha + pv
+        return 1 - slot
 
-    @pl.when(ik == nk - 1)
-    def _finalize():
-        for h in range(HB):
-            lo = h * Gp
-            l = l_scr[lo:lo + G, :1]
-            out = acc_scr[lo:lo + G, :] / jnp.where(l == 0.0, 1.0, l)
-            o_ref[0, h] = out.astype(o_ref.dtype)
+    # A row with nothing to read still hands the walk on.
+    @pl.when((nblk == 0) & has_next)
+    def _():
+        start(nxt, 0, slot0)
+
+    slot_ref[0] = jax.lax.fori_loop(0, nblk, step, slot0)
+    l = l_scr[:Hq, :1]
+    out = acc_scr[:Hq, :] / jnp.where(l == 0.0, 1.0, l)
+    o_ref[0] = out.astype(o_ref.dtype)
 
 
 @functools.partial(
     jax.jit,
     static_argnames=(
-        "scale", "page_size", "heads_per_block", "interpret",
-        "dequant_dtype",
+        "scale", "heads_per_block", "interpret", "dequant_dtype",
     ),
 )
 def _ragged_paged(
-    q,  # [R, Hk, G, D]
+    q,  # [R, Hq, D]
     k_pages,  # [P, ps, Hk, D] (codes when quantized)
     v_pages,
     block_tables,  # [S, maxp] int32
     q_segments,  # [R] int32
     q_positions,  # [R] int32
-    k_scale=None,  # [P, 1, ps] fp32 per-page scale blocks (quantized pool)
+    k_scale=None,  # [P, ps] fp32 per-token scales (quantized pool)
     v_scale=None,
     *,
     scale: float,
-    page_size: int,
     heads_per_block: int,
     interpret: bool,
     dequant_dtype: str | None = None,
 ):
-    R, Hk, G, D = q.shape
-    P = k_pages.shape[0]
-    S, maxp = block_tables.shape
-    HB = heads_per_block
-
-    def _page(r, ik, bt_ref, seg_ref, pos_ref):
-        # Clamp dead tiles onto the row's last live page (DMA elision)
-        # and sentinel entries into the pool; the segment picks WHICH
-        # sequence's table this row walks.
-        s = jnp.clip(seg_ref[r], 0, S - 1)
-        last = jnp.maximum(pos_ref[r], 0) // page_size
-        page = bt_ref[s, jnp.minimum(ik, last)]
-        return jnp.minimum(page, P - 1)
-
-    def kv_map(r, hb, ik, bt_ref, seg_ref, pos_ref):
-        return (_page(r, ik, bt_ref, seg_ref, pos_ref), 0, hb, 0)
-
-    def sc_map(r, hb, ik, bt_ref, seg_ref, pos_ref):
-        # The scale block rides the same block-table stream as its
-        # code tile.
-        return (_page(r, ik, bt_ref, seg_ref, pos_ref), 0, 0)
-
-    grid = (R, Hk // HB, maxp)
-    Gp = max(G, 8)  # scratch sublane floor
+    R, Hq, D = q.shape
+    P, ps, Hk, _ = k_pages.shape
+    maxp = block_tables.shape[1]
+    npb = ragged_pages_per_block(D, ps, Hk, maxp)
+    brow = npb * ps * Hk
+    Hp = -(-Hq // 8) * 8  # scratch sublane floor
     quant = dequant_dtype is not None
-    in_specs = [
-        pl.BlockSpec((1, HB, G, D), lambda r, hb, ik, *_: (r, hb, 0, 0)),
-        pl.BlockSpec((1, page_size, HB, D), kv_map),
+    hbm = pl.BlockSpec(memory_space=pltpu.HBM)
+    row = pl.BlockSpec((1, Hq, D), lambda r, *_: (r, 0, 0))
+    # The packed view of the pool: same bytes, same order.
+    k_pages = k_pages.reshape(P, ps * Hk, D)
+    v_pages = v_pages.reshape(P, ps * Hk, D)
+    in_specs, operands = [row, hbm], [q, k_pages]
+    scratch = [
+        pltpu.VMEM((2, brow, D), k_pages.dtype),
+        pltpu.VMEM((2, brow, D), v_pages.dtype),
     ]
-    operands = [q, k_pages]
     if quant:
-        in_specs.append(pl.BlockSpec((1, 1, page_size), sc_map))
-        operands.append(k_scale)
-    in_specs.append(pl.BlockSpec((1, page_size, HB, D), kv_map))
-    operands.append(v_pages)
-    if quant:
-        in_specs.append(pl.BlockSpec((1, 1, page_size), sc_map))
-        operands.append(v_scale)
+        in_specs += [hbm, hbm, hbm]
+        operands += [
+            _block_scales(k_scale, block_tables, Hk, npb), v_pages,
+            _block_scales(v_scale, block_tables, Hk, npb),
+        ]
+        scratch += [
+            pltpu.VMEM((2, 1, brow), k_scale.dtype),
+            pltpu.VMEM((2, 1, brow), v_scale.dtype),
+        ]
+    else:
+        in_specs.append(hbm)
+        operands.append(v_pages)
+    scratch += [
+        pltpu.SemaphoreType.DMA((2, 4 if quant else 2)),
+        pltpu.SMEM((1,), jnp.int32),  # buffer slot of the block in flight
+        pltpu.VMEM((Hp, 128), jnp.float32),
+        pltpu.VMEM((Hp, 128), jnp.float32),
+        pltpu.VMEM((Hp, D), jnp.float32),
+    ]
     out = pl.pallas_call(
         functools.partial(
-            _ragged_kernel, scale=scale, page_size=page_size,
-            num_groups=G, heads_per_block=HB,
+            _ragged_kernel, scale=scale, page_size=ps, num_kv_heads=Hk,
+            pages_per_block=npb, heads_per_block=heads_per_block,
             dequant_dtype=dequant_dtype,
         ),
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=3,
-            grid=grid,
+            grid=(R,),
             in_specs=in_specs,
-            out_specs=pl.BlockSpec(
-                (1, HB, G, D), lambda r, hb, ik, *_: (r, hb, 0, 0)
-            ),
-            scratch_shapes=[
-                pltpu.VMEM((HB * Gp, 128), jnp.float32),
-                pltpu.VMEM((HB * Gp, 128), jnp.float32),
-                pltpu.VMEM((HB * Gp, D), jnp.float32),
-            ],
+            out_specs=row,
+            scratch_shapes=scratch,
         ),
-        out_shape=jax.ShapeDtypeStruct((R, Hk, G, D), q.dtype),
+        out_shape=jax.ShapeDtypeStruct((R, Hq, D), q.dtype),
+        # Rows run in order: a row starts the next row's first copy.
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("arbitrary",)
+        ),
         interpret=interpret,
     )(block_tables.astype(jnp.int32), q_segments.astype(jnp.int32),
       q_positions.astype(jnp.int32), *operands)
@@ -396,12 +504,11 @@ def ragged_paged_attention(
     sequence's pages in place through the block table. The kv-head
     tile is `ragged_heads_per_block`'s unless pinned; a pin the TPU
     lowering would refuse raises. A quantized pool
-    (ops.paged_kv.QuantPages planes) is read as codes + per-page scale
-    blocks and dequantized inside the page walk."""
+    (ops.paged_kv.QuantPages planes) is read as codes + per-token
+    scales and dequantized inside the page walk."""
     R, Hq, D = q.shape
     Hk = k_pages.shape[2]
     assert Hq % Hk == 0, f"GQA requires Hq % Hk == 0, got {Hq=} {Hk=}"
-    G = Hq // Hk
     if scale is None:
         scale = D**-0.5
     if interpret is None:
@@ -418,19 +525,11 @@ def ragged_paged_attention(
         k_pages, k_scale, v_pages, v_scale, dequant = _split_quant(
             k_pages, v_pages
         )
-        # The stored scale plane stays [P, ps]; the lowering needs a
-        # block whose last two dims are whole, so present it [P, 1, ps]
-        # (a free reshape: same bytes, same order).
-        P, ps = k_scale.shape
-        k_scale = k_scale.reshape(P, 1, ps)
-        v_scale = v_scale.reshape(P, 1, ps)
     # h = hk * G + g (the repo's GQA head order: h // G == hk).
-    qg = q.reshape(R, Hk, G, D)
-    out = _ragged_paged(
-        qg, k_pages, v_pages, block_tables, q_segments, q_positions,
+    return _ragged_paged(
+        q, k_pages, v_pages, block_tables, q_segments, q_positions,
         k_scale, v_scale,
-        scale=float(scale), page_size=int(k_pages.shape[1]),
-        heads_per_block=int(heads_per_block), interpret=bool(interpret),
+        scale=float(scale), heads_per_block=int(heads_per_block),
+        interpret=bool(interpret),
         dequant_dtype=dequant,
     )
-    return out.reshape(R, Hq, D)

@@ -1,5 +1,6 @@
 """Mosaic compiles of every kernel `attn_impl="pallas"` can select, at
-Oryx-7B geometry, for a DESCRIBED TPU v5e (no chip attached).
+Oryx-7B geometry (and the paged kernel's walk at SDAR's block geometry
+too), for a DESCRIBED TPU v5e (no chip attached).
 
 The TPU's compiler is installed with jax; `topologies.get_topology_desc`
 lets it compile for a chip that is described and not attached, so a
@@ -179,6 +180,42 @@ def test_paged_decode_compiles_for_v5e(one_chip, pool):
         decode, one_chip, ((S, 1, HQ, D), BF16),
         *_pool_shapes(pool, S * maxp, page_size),
         ((S, maxp), jnp.int32), ((S,), jnp.int32),
+    )
+
+
+# The page walk at the two serving geometries (benchmark/configs: 16
+# slots x 4096 tokens at depth 16; 32 slots x 4 lanes x 1024 tokens at
+# depth 7), each over the flat [layers * pages] pool its program holds.
+WALK_GEOMETRIES = {
+    "oryx_decode": dict(rows=16, Hq=28, slots=16, maxp=64, layers=16),
+    "sdar_block": dict(rows=128, Hq=32, slots=32, maxp=16, layers=7),
+}
+
+
+@pytest.mark.parametrize("pool", ["bf16", "int8"])
+@pytest.mark.parametrize("geometry", sorted(WALK_GEOMETRIES))
+def test_page_walk_compiles_at_serving_geometries(one_chip, geometry, pool):
+    """What interpret mode cannot show of the walk: the packed
+    [P, ps * Hk, D] view copied a page at a time into the VMEM blocks,
+    a quantized block's scale row beside it, the [Hq, npb * ps * Hk]
+    logit tile at 7 and at 8 q heads a kv head, all inside VMEM."""
+    from oryx_tpu.ops.pallas import paged_attention as ppa
+
+    g = WALK_GEOMETRIES[geometry]
+    page_size = 64
+    assert ppa.ragged_pages_per_block(D, page_size, HK, g["maxp"]) == 8
+    rows = ((g["rows"],), jnp.int32)
+
+    def walk(q, codes, scale, bt, seg, pos):
+        kv = _as_pool(pool, codes, scale)
+        return ppa.ragged_paged_attention(
+            q, kv, kv, bt, seg, pos, interpret=False
+        )
+
+    _compiled_text(
+        walk, one_chip, ((g["rows"], g["Hq"], D), BF16),
+        *_pool_shapes(pool, g["layers"] * g["slots"] * g["maxp"], page_size),
+        ((g["slots"], g["maxp"]), jnp.int32), rows, rows,
     )
 
 

@@ -225,6 +225,144 @@ def test_heads_per_block_offers_only_what_the_lowering_takes(monkeypatch):
 
 
 # ---------------------------------------------------------------------------
+# The page walk's edges, at the serving geometry (page 64, 4 kv heads,
+# D 128), where a step handles `ragged_pages_per_block` pages
+# ---------------------------------------------------------------------------
+
+W_PS, W_HK, W_D, W_MAXP, W_S, W_LAYER_PAGES = 64, 4, 128, 20, 16, 128
+W_NPB = ppa.ragged_pages_per_block(W_D, W_PS, W_HK, W_MAXP)
+W_BLOCK = W_NPB * W_PS  # tokens one step of the walk handles
+W_FULL = W_MAXP * W_PS  # the whole table; not a whole number of blocks
+
+
+def _decode_rows(*lengths):
+    """Decode rows: row i is segment i at position lengths[i] - 1."""
+    return [(i, n - 1) for i, n in enumerate(lengths)]
+
+
+def _block_rows(lengths, B=4):
+    """Block-diffusion rows as `qwen2.forward` masks them: slot s's B
+    lanes sit at lengths[s]..+B-1 and see up to the end of the block
+    their own position lies in."""
+    return [
+        (s, p - p % B + B - 1)
+        for s, n in enumerate(lengths) for p in range(n, n + B)
+    ]
+
+
+# name -> (packed rows [(segment, position)], layer of the flat pool)
+WALK_CASES = {
+    **{
+        f"length_{n}": (_decode_rows(n, 700, 0, 300), 0)
+        for n in (0, 1, W_PS - 1, W_PS, W_PS + 1, W_BLOCK - 1, W_BLOCK,
+                  W_BLOCK + 1, W_FULL)
+    },
+    "shuffled_tables_sentinel_tails": (
+        _decode_rows(*(37 + 53 * i for i in range(W_S))), 0,
+    ),
+    "layer_offset_tables": (_decode_rows(5, 640, 1000, W_FULL, 129), 1),
+    # One segment's consecutive positions, over a page edge and over
+    # the edge of a step's block.
+    "prefill_suffix": (
+        [(2, p) for p in range(W_PS - 3, W_PS + 3)]
+        + [(5, p) for p in range(W_BLOCK - 4, W_BLOCK + 4)], 0,
+    ),
+    "verify_lanes_1_plus_3": (
+        [(s, n + j) for s, n in ((0, 61), (3, 510), (7, 1021))
+         for j in range(4)], 0,
+    ),
+    # Four slots: tails 3, 1 and 0 (a slot's lanes then lie in two
+    # blocks), one over a page edge, one over a step's edge.
+    "block_rows": (
+        _block_rows([W_PS * 3 - 1, W_BLOCK - 3, 252, 2 * W_BLOCK - 2]), 0,
+    ),
+}
+
+
+def _walk_pool(pool: str, rows):
+    """A flat two-layer pool with shuffled tables: every segment owns
+    just the pages its longest row reads, the rest of its table is the
+    sentinel."""
+    rng = np.random.default_rng(11)
+    P = 2 * W_LAYER_PAGES
+    need = np.zeros(W_S, np.int64)
+    for seg, pos in rows:
+        need[seg] = max(need[seg], pos // W_PS + 1)
+    assert need.sum() <= W_LAYER_PAGES
+    bt = np.full((W_S, W_MAXP), P + 3, np.int32)
+    order = rng.permutation(W_LAYER_PAGES)
+    at = 0
+    for seg, n in enumerate(need):
+        bt[seg, :n] = order[at:at + n]
+        at += n
+    shape = (P, W_PS, W_HK, W_D)
+    if pool == "bf16":
+        kp = jnp.asarray(rng.standard_normal(shape), jnp.bfloat16)
+        vp = jnp.asarray(rng.standard_normal(shape), jnp.bfloat16)
+        return bt, kp, vp
+
+    def quant():
+        return paged_kv.QuantPages(
+            jnp.asarray(rng.integers(-127, 128, shape), jnp.int8),
+            jnp.asarray(rng.uniform(0.002, 0.03, shape[:2]), jnp.float32),
+            jnp.bfloat16,
+        )
+
+    return bt, quant(), quant()
+
+
+@pytest.mark.parametrize("pool", ["bf16", "int8"])
+@pytest.mark.parametrize("Hq", [28, 32], ids=["G7", "G8"])
+@pytest.mark.parametrize("case", sorted(WALK_CASES))
+def test_pallas_page_walk_edges(case, Hq, pool):
+    """The kernel's walk (interpret mode) against the packed reference
+    at every edge of it: nothing to read, a page's and a step's last,
+    first and next token, the whole table, tables that are shuffled,
+    end in sentinels and point into a flat pool's second layer,
+    consecutive positions of one segment, block rows; 7 and 8 q heads a
+    kv head; bf16 and int8 pools. Rows past the case's own are rows of
+    length 0 and give zeros."""
+    rows, layer = WALK_CASES[case]
+    bt, kp, vp = _walk_pool(pool, rows)
+    bt = np.where(bt < W_LAYER_PAGES, bt + layer * W_LAYER_PAGES, bt)
+    seg = np.zeros(W_S, np.int32)
+    pos = np.full(W_S, -1, np.int32)
+    seg[: len(rows)], pos[: len(rows)] = np.asarray(rows, np.int32).T
+    q = jnp.asarray(
+        np.random.default_rng(12).standard_normal((W_S, Hq, W_D)),
+        jnp.bfloat16,
+    )
+    args = (q, kp, vp, jnp.asarray(bt), jnp.asarray(seg), jnp.asarray(pos))
+    ref = np.asarray(paged_kv.ragged_paged_attention(*args), np.float32)
+    got = np.asarray(ppa.ragged_paged_attention(*args), np.float32)
+    live = pos >= 0
+    assert live.any() and np.isfinite(got).all()
+    np.testing.assert_allclose(got[live], ref[live], atol=1e-2, rtol=2e-2)
+    assert not got[~live].any()
+
+
+@pytest.mark.parametrize("pool", ["bf16", "int8"])
+@pytest.mark.parametrize("Hq", [28, 32], ids=["G7", "G8"])
+def test_pallas_finished_decode_lanes_beside_live_ones(Hq, pool):
+    """`paged_decode_chunk` hands a finished or empty lane kv_lengths 0:
+    it takes no step of the walk and returns zeros, and the live lanes
+    beside it (before, between and after) read what they read alone."""
+    lens = np.zeros(W_S, np.int32)
+    lens[[1, 2, 6, 15]] = [W_BLOCK + 1, 800, 1, W_FULL]
+    bt, kp, vp = _walk_pool(pool, _decode_rows(*lens))
+    q = jnp.asarray(
+        np.random.default_rng(13).standard_normal((W_S, 1, Hq, W_D)),
+        jnp.bfloat16,
+    )
+    args = (q, kp, vp, jnp.asarray(bt), jnp.asarray(lens))
+    ref = np.asarray(paged_kv.ragged_decode_attention(*args), np.float32)
+    got = np.asarray(ppa.ragged_decode_attention(*args), np.float32)
+    live = lens > 0
+    np.testing.assert_allclose(got[live], ref[live], atol=1e-2, rtol=2e-2)
+    assert not got[~live].any()
+
+
+# ---------------------------------------------------------------------------
 # Driver level: generate_paged(ragged=True)
 # ---------------------------------------------------------------------------
 
