@@ -55,6 +55,62 @@ class LLMConfig:
     # page, prefill chunk and attention tile edge is a block edge.
     block_length: int = 0
     mask_token_id: int = 0
+    # Latent attention (MLA) when kv_lora_rank > 0: queries go through a
+    # q_lora_rank bottleneck, keys and values through one kv_lora_rank
+    # latent a token plus ONE roped key of qk_rope_head_dim shared by
+    # every head; a head's key is [qk_nope_head_dim | qk_rope_head_dim]
+    # wide and its value v_head_dim. The cache keeps (latent, roped key)
+    # a token, never per-head K or V (`qwen2._mla`); num_kv_heads and
+    # head_dim are then unused. mla_scale_*: the family's modelling code
+    # multiplies the normed q latent by sqrt(hidden / q_lora_rank) and
+    # the normed kv latent by sqrt(hidden / kv_lora_rank). A latent
+    # model's layer is the shortcut-connected double layer: two
+    # (attention, dense FFN) sublayers in series with ONE expert layer
+    # whose input is taken after the first attention and whose output is
+    # added after the second FFN (`qwen2._double_block`). The dense FFNs
+    # are `intermediate_size` wide, the experts moe_intermediate_size.
+    kv_lora_rank: int = 0
+    q_lora_rank: int = 0
+    qk_nope_head_dim: int = 0
+    qk_rope_head_dim: int = 0
+    v_head_dim: int = 0
+    mla_scale_q_lora: bool = False
+    mla_scale_kv_lora: bool = False
+    # RoPE pairs (x[2j], x[2j+1]) (the DeepSeek-V3 lineage) instead of
+    # (x[j], x[j + D/2]).
+    rope_interleaved: bool = False
+    # Zero-compute experts: router outputs num_experts ..
+    # num_experts + zero_experts - 1 are identity experts, E(x) = x.
+    zero_experts: int = 0
+    # Weights of the chosen experts are routed_scaling_factor * p.
+    routed_scaling_factor: float = 1.0
+    # A per-expert correction bias [num_experts + zero_experts] added to
+    # the probabilities for SELECTION only; the weights stay p.
+    router_bias: bool = False
+    # (first, count) of the routed experts this chip holds, None = all.
+    # The router keeps its width and its experts per token; the expert
+    # layer computes the held experts' and the zero-compute experts'
+    # part of the result and leaves out what absent experts would add.
+    experts_held: tuple[int, int] | None = None
+
+    @property
+    def latent(self) -> bool:
+        return self.kv_lora_rank > 0
+
+    @property
+    def held(self) -> tuple[int, int]:
+        return self.experts_held or (0, self.num_experts)
+
+    @property
+    def latent_dim(self) -> int:
+        """Values a cached token holds: the latent and the roped key."""
+        return self.kv_lora_rank + self.qk_rope_head_dim
+
+    @property
+    def latent_page_dim(self) -> int:
+        """`latent_dim` padded to whole 128-lane tiles, which is what a
+        page row occupies in device memory either way."""
+        return -(-self.latent_dim // 128) * 128
 
     def __post_init__(self):
         B = self.block_length
@@ -71,6 +127,43 @@ class LLMConfig:
                 f"num_experts_per_tok ({self.num_experts_per_tok}) <= "
                 "num_experts and moe_intermediate_size > 0 "
                 f"({self.moe_intermediate_size})"
+            )
+        if self.experts_held is not None:
+            object.__setattr__(
+                self, "experts_held", tuple(int(v) for v in self.experts_held)
+            )
+        first, count = self.held
+        if self.num_experts and not (
+            0 <= first and 0 < count and first + count <= self.num_experts
+        ):
+            raise ValueError(
+                f"experts_held={self.experts_held} is not a range of the "
+                f"{self.num_experts} routed experts"
+            )
+        if (self.zero_experts or self.router_bias
+                or self.experts_held is not None) and not self.num_experts:
+            raise ValueError(
+                "zero_experts, router_bias and experts_held need an "
+                "expert config (num_experts > 0)"
+            )
+        if self.latent and not (
+            self.q_lora_rank > 0 and self.qk_nope_head_dim > 0
+            and self.qk_rope_head_dim > 0 and self.v_head_dim > 0
+            and self.qk_rope_head_dim % 2 == 0
+        ):
+            raise ValueError(
+                "latent attention (kv_lora_rank > 0) needs q_lora_rank, "
+                "qk_nope_head_dim, v_head_dim > 0 and an even "
+                f"qk_rope_head_dim, got {self}"
+            )
+        if self.latent and (
+            not self.num_experts or self.block_length or self.qk_norm
+            or self.attention_bias or self.tie_word_embeddings
+        ):
+            raise ValueError(
+                "latent attention is built for the shortcut-connected "
+                "double layer only (num_experts > 0), without attention "
+                "bias, q/k norm, tied embeddings or block diffusion"
             )
 
 
@@ -462,6 +555,103 @@ def sdar_30b_a3b() -> OryxConfig:
             mask_token_id=151669,
         ),
         vision=None,
+    )
+
+
+def longcat_flash_chat() -> OryxConfig:
+    """LongCat-Flash-Chat (meituan-longcat, config.json): 28 shortcut-
+    connected double layers of latent attention, 512 routed experts of
+    width 2048 plus 256 identity zero-compute experts, 12 a token,
+    weights 6 * p, not renormalised. Text-only."""
+    return OryxConfig(
+        llm=LLMConfig(
+            vocab_size=131072,
+            hidden_size=6144,
+            intermediate_size=12288,
+            num_layers=28,
+            num_heads=64,
+            num_kv_heads=1,  # unused: one shared latent a token
+            head_dim=192,  # unused; a head's key is 128 + 64 wide
+            rope_theta=10_000_000.0,
+            rms_norm_eps=1e-5,
+            max_position_embeddings=131072,
+            attention_bias=False,
+            num_experts=512,
+            num_experts_per_tok=12,
+            moe_intermediate_size=2048,
+            norm_topk_prob=False,
+            kv_lora_rank=512,
+            q_lora_rank=1536,
+            qk_nope_head_dim=128,
+            qk_rope_head_dim=64,
+            v_head_dim=128,
+            mla_scale_q_lora=True,
+            mla_scale_kv_lora=True,
+            rope_interleaved=True,
+            zero_experts=256,
+            routed_scaling_factor=6.0,
+            router_bias=True,
+        ),
+        vision=None,
+        generation=GenerationConfig(eos_token_id=2),
+    )
+
+
+def longcat_flash_chat_ep32() -> OryxConfig:
+    """One chip's share of LongCat-Flash-Chat where 32 chips share each
+    layer: 16 of the 512 routed experts held (the router keeps its 768
+    outputs and its 12 a token), attention and the dense FFNs whole,
+    an eighth of the vocabulary (rows 0..16383). Tokens are sampled
+    over the rows held here, so the end-of-sequence id is given as one
+    that another chip holds (the first row past this chip's): no lane
+    ends by it, as none would on a rank whose slice lacks it."""
+    cfg = longcat_flash_chat()
+    return dataclasses.replace(
+        cfg,
+        llm=dataclasses.replace(
+            cfg.llm, vocab_size=16384, experts_held=(0, 16)),
+        generation=dataclasses.replace(cfg.generation, eos_token_id=16384),
+    )
+
+
+def longcat_tiny() -> OryxConfig:
+    """Tiny latent-attention double-layer expert decoder for tests:
+    8 routed experts of which 4 are held, 4 zero-compute experts."""
+    return OryxConfig(
+        llm=LLMConfig(
+            vocab_size=512,
+            hidden_size=64,
+            intermediate_size=128,
+            num_layers=2,
+            num_heads=4,
+            num_kv_heads=1,
+            head_dim=24,
+            rope_theta=10000.0,
+            rms_norm_eps=1e-5,
+            max_position_embeddings=512,
+            attention_bias=False,
+            num_experts=8,
+            num_experts_per_tok=3,
+            moe_intermediate_size=32,
+            norm_topk_prob=False,
+            kv_lora_rank=32,
+            q_lora_rank=48,
+            qk_nope_head_dim=16,
+            qk_rope_head_dim=8,
+            v_head_dim=16,
+            mla_scale_q_lora=True,
+            mla_scale_kv_lora=True,
+            rope_interleaved=True,
+            zero_experts=4,
+            routed_scaling_factor=6.0,
+            router_bias=True,
+            experts_held=(2, 4),
+        ),
+        vision=None,
+        # Past the vocabulary, as the share's preset: seeded weights
+        # would sample a real id once in 512 tokens.
+        generation=GenerationConfig(eos_token_id=512),
+        dtype="float32",
     )
 
 

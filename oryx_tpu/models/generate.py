@@ -506,11 +506,13 @@ def paged_prefill(
     compiled prefill serves every sampling config at a given prompt
     bucket. Returns (kv_pages, tok0 [B], advanced keys [B]), and with
     return_routing (a static twin for the benchmark's comparison) the
-    expert layers' routing, `qwen2.forward`'s, as a fourth value."""
+    expert layers' routing, `qwen2.forward`'s, as a fourth value, with
+    the [B, V] logits the first token was sampled from under
+    "logits"."""
     B, T, _ = inputs_embeds.shape
     start = jnp.broadcast_to(start.astype(jnp.int32), (B,))
     positions = start[:, None] + jnp.arange(T, dtype=jnp.int32)[None, :]
-    page_size = kv_pages["k"].shape[2]
+    page_size = paged_kv_lib.pool_plane(kv_pages).shape[2]
     K = block_tables.shape[1] * page_size
     kv_mask = (
         jnp.arange(K, dtype=jnp.int32)[None, :] < lengths[:, None]
@@ -531,7 +533,8 @@ def paged_prefill(
     tok0 = sample_token_rows(
         last, pair[:, 1], temperature=temperature, top_p=top_p, top_k=top_k
     )
-    return (kv_pages, tok0, pair[:, 0], *routing)
+    return (kv_pages, tok0, pair[:, 0],
+            *(dict(r, logits=last) for r in routing))
 
 
 @partial(jax.jit, static_argnames=("width",))
@@ -623,6 +626,7 @@ def paged_prefill_chunks(
     jax.jit,
     static_argnames=(
         "cfg", "chunk", "eos", "attn_impl", "compute_dtype", "numerics",
+        "return_routing",
     ),
     donate_argnames=("kv_pages",),
 )
@@ -646,6 +650,7 @@ def paged_decode_chunk(
     attn_impl: str = "xla",
     compute_dtype=None,
     numerics: bool = False,
+    return_routing: bool = False,
 ):
     """`chunk` decode steps over a FIXED-SLOT batch with a paged cache —
     the continuous-batching inner loop. One compiled program per
@@ -666,8 +671,17 @@ def paged_decode_chunk(
     -stat accumulator (utils/numerics.py) folded over the chunk's live
     rows inside this same dispatch — token streams and every other
     output are bit-identical to the numerics=False program (the probe
-    only reads the logits the sampler already computed)."""
-    page_size = kv_pages["k"].shape[2]
+    only reads the logits the sampler already computed).
+
+    A config whose expert layer holds a share (`cfg.experts_held`) or has
+    zero-compute experts appends the [len(SHARE_STATS)] int32 sums of
+    `share_stats` over the chunk's steps, which the scheduler's moe_*
+    and decode_kv_tokens counters read. return_routing=True (the static
+    twin for the benchmark's comparison, as `paged_prefill`'s) appends
+    every step's logits [S, chunk, V] and expert ids [chunk, L, S, K],
+    last."""
+    page_size = paged_kv_lib.pool_plane(kv_pages).shape[2]
+    shared = bool(cfg.experts_held or cfg.zero_experts)
     K = block_tables.shape[1] * page_size
     slot_ar = jnp.arange(K, dtype=jnp.int32)[None, :]
 
@@ -680,22 +694,23 @@ def paged_decode_chunk(
         return jnp.any(jnp.all(m, axis=-1), axis=-1)
 
     def step(carry, _):
+        kv_pages, tok, cur_len, finished, recent, keys, *more = carry
         if numerics:
-            kv_pages, tok, cur_len, finished, recent, keys, nstats = carry
-        else:
-            kv_pages, tok, cur_len, finished, recent, keys = carry
+            nstats = more[0]
         pair = jax.vmap(lambda k: jax.random.split(k, 2))(keys)
         pos = cur_len[:, None]
         kv_mask = (slot_ar <= cur_len[:, None]).astype(jnp.int32)
-        logits, kv_pages = qwen2.forward(
+        logits, kv_pages, *routing = qwen2.forward(
             params, cfg,
             input_ids=tok[:, None], positions=pos,
             kv_cache=kv_pages, write_slots=cur_len, kv_mask=kv_mask,
             block_tables=block_tables, write_mask=~finished,
             # A finished or empty lane reads nothing (its token is
             # replaced below): length 0 is no step of the page walk.
-            kv_lengths=jnp.where(finished, 0, cur_len + 1),
+            kv_lengths=(kv_lengths := jnp.where(finished, 0, cur_len + 1)),
             attn_impl=attn_impl, compute_dtype=compute_dtype,
+            **({"return_routing": True} if shared or return_routing
+               else {}),
         )
         if numerics:
             # Live-row logit probe on the logits the sampler is about
@@ -715,16 +730,61 @@ def paged_decode_chunk(
         out = (kv_pages, nxt, cur_len, finished, recent, pair[:, 0])
         if numerics:
             out = out + (nstats,)
-        return out, (tok, finished)
+        if shared:
+            # `finished` here is this step's, as the forward saw it.
+            out = out + (more[-1] + share_stats(
+                cfg, routing[0]["ids"], kv_lengths),)
+        ys = (tok, finished)
+        if return_routing:
+            ys = ys + (logits[:, 0], routing[0]["ids"])
+        return out, ys
 
     carry0 = (kv_pages, tok, lengths, finished, recent, keys)
     if numerics:
         carry0 = carry0 + (numerics_lib.init_logit_stats(),)
-    carry, (toks, fin) = jax.lax.scan(step, carry0, None, length=chunk)
+    if shared:
+        carry0 = carry0 + (jnp.zeros((len(SHARE_STATS),), jnp.int32),)
+    carry, (toks, fin, *seen) = jax.lax.scan(
+        step, carry0, None, length=chunk)
     out = carry[:6] + (jnp.moveaxis(toks, 0, 1), jnp.moveaxis(fin, 0, 1))
-    if numerics:
-        out = out + (carry[6],)
+    out = out + carry[6:]
+    if return_routing:
+        out = out + (jnp.moveaxis(seen[0], 0, 1), seen[1])
     return out
+
+
+# What a decode step of a config with a share of the experts counts
+# (`share_stats`), in this order; per layer-forward like the block
+# step's moe_* statistics.
+SHARE_STATS = (
+    "layer_forwards", "pairs", "zero_pairs", "held_rows", "held_rows_max",
+    "held_hit", "kv_tokens",
+)
+
+
+def share_stats(cfg: LLMConfig, ids: jnp.ndarray, kv_lengths: jnp.ndarray):
+    """SHARE_STATS of one forward: ids [L, S, K] the chosen experts,
+    kv_lengths [S] what each lane read (0 = a lane that is not live).
+    pairs: (token, expert) pairs of live lanes; zero_pairs: those that
+    went to a zero-compute expert; held_rows: those that went to an
+    expert held here, held_rows_max the busiest held expert's a layer
+    summed over layers, held_hit the held experts with a row;
+    kv_tokens: cached tokens the live lanes' attention read a cache
+    layer."""
+    live = kv_lengths > 0
+    first, count = cfg.held
+    ids = jnp.where(live[None, :, None], ids, -1)
+    rows = jnp.sum(
+        ids[..., None] == first + jnp.arange(count, dtype=ids.dtype),
+        axis=(1, 2),
+    )  # [L, count]
+    any_live = jnp.any(live).astype(jnp.int32)
+    return jnp.stack([
+        ids.shape[0] * any_live,
+        jnp.sum(ids >= 0), jnp.sum(ids >= cfg.num_experts),
+        jnp.sum(rows), jnp.sum(jnp.max(rows, axis=1)), jnp.sum(rows > 0),
+        jnp.sum(kv_lengths),
+    ]).astype(jnp.int32)
 
 
 # ---------------------------------------------------------------------------

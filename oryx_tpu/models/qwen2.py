@@ -45,6 +45,8 @@ def init_params(
     cfg: LLMConfig, key: jax.Array, dtype: jnp.dtype = jnp.float32
 ) -> Params:
     """Random-normal init (scale 0.02, zero biases) in the stacked layout."""
+    if cfg.latent:
+        return _init_latent_params(cfg, key, dtype)
     L, H = cfg.num_layers, cfg.hidden_size
     Dq = cfg.num_heads * cfg.head_dim
     Dkv = cfg.num_kv_heads * cfg.head_dim
@@ -102,6 +104,75 @@ def init_params(
     return params
 
 
+def _init_latent_params(cfg: LLMConfig, key: jax.Array, dtype) -> Params:
+    """The latent-attention double layer's weights (`_double_block`).
+    `layers["sub0"]` and `["sub1"]` hold the two (attention, dense FFN)
+    sublayers, every leaf [L, ...] so that the layer scan slices a
+    kernel out as it lies; projections that the published checkpoint
+    keeps fused are stored as the parts they are used in (a fused
+    kernel sliced by column inside a step is copied whole, every
+    dispatch): Wq_b by columns into `q_b_nope` / `q_b_rope`, Wkv_a into
+    `kv_a_proj` (the latent) / `k_rope_proj` (the shared key), Wkv_b by
+    head into `w_uk` [Hq, dn, R] (transposed, as the absorbed decode
+    multiplies it) and `w_uv` [Hq, R, dv]. The router [L, H, E + Z]
+    and its selection bias [L, E + Z] stay float32; the expert stacks
+    hold the HELD experts only, [L, count, in, out] (expert first + j
+    at j). The bias is small against a probability of 1 / (E + Z) and
+    not 0, so that selecting by p + b and weighing by p can be told
+    apart."""
+    L, H, I = cfg.num_layers, cfg.hidden_size, cfg.intermediate_size
+    Hq, Rq, R = cfg.num_heads, cfg.q_lora_rank, cfg.kv_lora_rank
+    dn, dr, dv = cfg.qk_nope_head_dim, cfg.qk_rope_head_dim, cfg.v_head_dim
+    E, Z, Ie = cfg.num_experts, cfg.zero_experts, cfg.moe_intermediate_size
+    count = cfg.held[1]
+    keys = iter(jax.random.split(key, 40))
+
+    def dense(shape, dt=dtype, scale=0.02):
+        return (
+            jax.random.normal(next(keys), shape, jnp.float32) * scale
+        ).astype(dt)
+
+    def sublayer():
+        return {
+            "input_norm": {"weight": jnp.ones((L, H), dtype)},
+            "post_attn_norm": {"weight": jnp.ones((L, H), dtype)},
+            "q_a_proj": {"kernel": dense((L, H, Rq))},
+            "q_a_norm": {"weight": jnp.ones((L, Rq), dtype)},
+            "q_b_nope": {"kernel": dense((L, Rq, Hq * dn))},
+            "q_b_rope": {"kernel": dense((L, Rq, Hq * dr))},
+            "kv_a_proj": {"kernel": dense((L, H, R))},
+            "k_rope_proj": {"kernel": dense((L, H, dr))},
+            "kv_a_norm": {"weight": jnp.ones((L, R), dtype)},
+            "w_uk": dense((L, Hq, dn, R)),
+            "w_uv": dense((L, Hq, R, dv)),
+            "o_proj": {"kernel": dense((L, Hq * dv, H))},
+            "gate_proj": {"kernel": dense((L, H, I))},
+            "up_proj": {"kernel": dense((L, H, I))},
+            "down_proj": {"kernel": dense((L, I, H))},
+        }
+
+    layers = {
+        "sub0": sublayer(),
+        "sub1": sublayer(),
+        "router": {"kernel": dense((L, H, E + Z), jnp.float32)},
+        "experts": {
+            "gate": dense((L, count, H, Ie)),
+            "up": dense((L, count, H, Ie)),
+            "down": dense((L, count, Ie, H)),
+        },
+    }
+    if cfg.router_bias:
+        layers["router"]["bias"] = dense(
+            (L, E + Z), jnp.float32, scale=0.2 / (E + Z)
+        )
+    return {
+        "embed": {"weight": dense((cfg.vocab_size, H))},
+        "layers": layers,
+        "final_norm": {"weight": jnp.ones((H,), dtype)},
+        "lm_head": {"kernel": dense((H, cfg.vocab_size))},
+    }
+
+
 # ---------------------------------------------------------------------------
 # KV cache
 # ---------------------------------------------------------------------------
@@ -138,6 +209,18 @@ def init_paged_kv_cache(
     spill tier and `sharding.paged_kv_spec` address. `forward` views it
     as L*P pages while it runs (see its `block_tables` contract) and
     hands it back in this layout."""
+    if cfg.latent:
+        # One plane, two cache layers a model layer, no head axis: a
+        # token's row is (latent | roped shared key | zeros to whole
+        # lane tiles). See ops/paged_kv.LATENT.
+        from oryx_tpu.ops import paged_kv
+
+        if kv_dtype not in (None, "bf16", "fp"):
+            raise ValueError(unsupported_for_latent(f"kv_dtype={kv_dtype!r}"))
+        return {paged_kv.LATENT: jnp.zeros(
+            (2 * cfg.num_layers, num_pages, page_size, cfg.latent_page_dim),
+            dtype,
+        )}
     shape = (
         cfg.num_layers, num_pages, page_size, cfg.num_kv_heads, cfg.head_dim
     )
@@ -150,6 +233,18 @@ def init_paged_kv_cache(
         cfg.head_dim, fmt=kv_dtype, dequant_dtype=dtype,
     )
     return {"k": mk(), "v": mk()}
+
+
+def unsupported_for_latent(mode: str) -> str:
+    """The one refusal of a mode that is not built for a latent-attention
+    (MLA) config: its pool is one plane of latents with no head axis and
+    two cache layers a model layer, which only the split engine's
+    `paged_prefill` / `paged_decode_chunk` read."""
+    return (
+        f"latent attention (kv_lora_rank > 0): {mode} is not built for a "
+        "latent pool (one [2L, P, page, latent] plane, no K/V heads); it "
+        "serves through the continuous split engine with a bf16 pool only"
+    )
 
 
 def _cache_write(cache_layer: jnp.ndarray, new: jnp.ndarray, slots: jnp.ndarray):
@@ -239,20 +334,30 @@ def merge_lora_params(params: Params) -> Params:
     return out
 
 
-def moe_route(cfg: LLMConfig, x: jnp.ndarray, router_kernel: jnp.ndarray):
+def moe_route(cfg: LLMConfig, x: jnp.ndarray, router_kernel: jnp.ndarray,
+              router_bias: jnp.ndarray | None = None):
     """Router of the expert layer, in float32 at full matmul precision:
     x [N, H] -> (weights [N, K] float32, expert ids [N, K] int32), the K
     largest softmax probabilities (ties: the lower expert id first),
-    renormalized to sum 1 when cfg.norm_topk_prob."""
+    renormalized to sum 1 when cfg.norm_topk_prob. `router_bias` [E]
+    is added for the SELECTION only (the weights stay the
+    probabilities); cfg.routed_scaling_factor multiplies the weights."""
     r = jnp.matmul(
         x.astype(jnp.float32), router_kernel.astype(jnp.float32),
         precision=jax.lax.Precision.HIGHEST,
     )
-    w, idx = jax.lax.top_k(
-        jax.nn.softmax(r, axis=-1), cfg.num_experts_per_tok
-    )
+    p = jax.nn.softmax(r, axis=-1)
+    if router_bias is None:
+        w, idx = jax.lax.top_k(p, cfg.num_experts_per_tok)
+    else:
+        _, idx = jax.lax.top_k(
+            p + router_bias.astype(jnp.float32), cfg.num_experts_per_tok
+        )
+        w = jnp.take_along_axis(p, idx, axis=-1)
     if cfg.norm_topk_prob:
         w = w / jnp.sum(w, axis=-1, keepdims=True)
+    if cfg.routed_scaling_factor != 1.0:
+        w = w * cfg.routed_scaling_factor
     return w, idx.astype(jnp.int32)
 
 
@@ -262,6 +367,7 @@ def moe_route(cfg: LLMConfig, x: jnp.ndarray, router_kernel: jnp.ndarray):
 # buffers of it have to fit the kernel's VMEM.
 _GMM_ROW_TILE = 128
 _GMM_MAX_KERNEL = 2 * 1024 * 1024  # elements: 2048 x 768 is 1.5 M
+_GMM_TILE = 1024  # k and n tile of a kernel over that
 
 
 def _grouped_dot(rows: jnp.ndarray, kernels: jnp.ndarray,
@@ -276,8 +382,12 @@ def _grouped_dot(rows: jnp.ndarray, kernels: jnp.ndarray,
     section 6, PR 26), and a block step is bound by reading them."""
     M, K = rows.shape
     N = kernels.shape[-1]
-    if (impl != "pallas" or K % 128 or N % 128
-            or K * N > _GMM_MAX_KERNEL):
+    tk, tn = K, N
+    if K * N > _GMM_MAX_KERNEL:
+        # A kernel too large for one tile (6144 x 2048): tiles of
+        # 1024 x 1024, accumulated over the k tiles in float32.
+        tk, tn = min(K, _GMM_TILE), min(N, _GMM_TILE)
+    if impl != "pallas" or K % 128 or N % 128 or K % tk or N % tn:
         return jax.lax.ragged_dot(rows, kernels, groups)
     import importlib
 
@@ -290,14 +400,15 @@ def _grouped_dot(rows: jnp.ndarray, kernels: jnp.ndarray,
     pad = -M % _GMM_ROW_TILE  # rows past the last group: sliced off again
     out = gmm(
         jnp.pad(rows, ((0, pad), (0, 0))) if pad else rows, kernels, groups,
-        preferred_element_type=rows.dtype, tiling=(_GMM_ROW_TILE, K, N),
+        preferred_element_type=rows.dtype, tiling=(_GMM_ROW_TILE, tk, tn),
         interpret=_use_interpret(),
     )
     return out[:M] if pad else out
 
 
 def _moe(cfg: LLMConfig, x: jnp.ndarray, router_kernel: jnp.ndarray,
-         experts: Params, layer: jnp.ndarray, impl: str = "xla"):
+         experts: Params, layer: jnp.ndarray, impl: str = "xla",
+         router_bias: jnp.ndarray | None = None):
     """Sparse expert MLP on x [N, H]: dropless, no capacity factor, no
     padding to a capacity. The N*K (token, expert) pairs are sorted by
     expert and the gate, up and down products run as grouped products
@@ -314,16 +425,34 @@ def _moe(cfg: LLMConfig, x: jnp.ndarray, router_kernel: jnp.ndarray,
     and read again: three times the bytes of a step that is bound by
     reading them once), where the whole stack is passed as it lies.
 
-    Returns (y [N, H], routing: {"counts": per-expert rows [E] int32,
-    "ids": the chosen experts [N, K] int32})."""
+    The chip's share (cfg.experts_held = (first, count), with
+    cfg.zero_experts identity experts behind the routed ones): the router
+    keeps its E + Z outputs and its K a token, `experts` holds the HELD
+    experts only, flat [L*count, in, out], and the layer returns
+    sum_{k zero} w_k x + sum_{k held} w_k E_k(x); what an absent expert
+    would add is left out. Pairs are sorted held experts first, so the
+    grouped products' groups end where the live rows end and an absent
+    pair never enters a product; the zero-compute term is a scaled copy
+    of x, no product. With every expert held and none zero-compute this
+    is the plain layer, operation for operation.
+
+    Returns (y [N, H], routing: {"counts": rows of each held expert
+    [count] int32, "ids": the chosen experts [N, K] int32})."""
     N, K, E = x.shape[0], cfg.num_experts_per_tok, cfg.num_experts
-    w, idx = moe_route(cfg, x, router_kernel)
+    first, count = cfg.held
+    whole = count == E and not cfg.zero_experts
+    w, idx = moe_route(cfg, x, router_kernel, router_bias)
     flat = idx.reshape(N * K)
+    if not whole:
+        # Group id of a pair: its held expert's place, or `count`, a
+        # tail group that has no kernel and enters no product.
+        live = (idx >= first) & (idx < first + count)
+        flat = jnp.where(live.reshape(N * K), flat - first, count)
     order = jnp.argsort(flat)  # stable: pairs of one expert stay in row order
-    counts = jnp.bincount(flat, length=E).astype(jnp.int32)
+    counts = jnp.bincount(flat, length=count).astype(jnp.int32)
     groups = jax.lax.dynamic_update_slice(
         jnp.zeros((experts["gate"].shape[0],), jnp.int32), counts,
-        (layer * E,),
+        (layer * count,),
     )
     xs = x[order // K]
     gate = _grouped_dot(xs, experts["gate"].astype(x.dtype), groups, impl)
@@ -335,9 +464,14 @@ def _moe(cfg: LLMConfig, x: jnp.ndarray, router_kernel: jnp.ndarray,
     inv = jnp.zeros((N * K,), jnp.int32).at[order].set(
         jnp.arange(N * K, dtype=jnp.int32)
     )
-    y = jnp.einsum(
-        "nk,nkh->nh", w, ys[inv].reshape(N, K, -1).astype(jnp.float32)
-    )
+    yk = ys[inv].reshape(N, K, -1).astype(jnp.float32)
+    if not whole:
+        # A row past the last group is whatever the product left there.
+        yk = jnp.where(live[..., None], yk, 0.0)
+    y = jnp.einsum("nk,nkh->nh", w, yk)
+    if cfg.zero_experts:
+        zero_w = jnp.sum(jnp.where(idx >= E, w, 0.0), axis=-1)
+        y = y + zero_w[:, None] * x.astype(jnp.float32)
     return y.astype(x.dtype), {"counts": counts, "ids": idx}
 
 
@@ -485,9 +619,167 @@ def _block(
             impl=attn_impl,
         )
         return h + y.reshape(B, T, -1), cache_k, cache_v, routing
+    return h + _swiglu(x, lp), cache_k, cache_v
+
+
+def _swiglu(x: jnp.ndarray, lp: Params) -> jnp.ndarray:
+    """The dense FFN: down(silu(gate(x)) * up(x))."""
     gate = jax.nn.silu(_linear(x, lp["gate_proj"]))
-    h = h + _linear(gate * _linear(x, lp["up_proj"]), lp["down_proj"])
-    return h, cache_k, cache_v
+    return _linear(gate * _linear(x, lp["up_proj"]), lp["down_proj"])
+
+
+def _mla_expanded(cfg: LLMConfig, q_nope, q_rope, c, kr, w_uk, w_uv, *,
+                  q_positions, kv_positions, kv_mask, impl: str):
+    """Latent attention in its expanded form: the latents c [B, K, R]
+    are up-projected to per-head keys and values through `w_uk`
+    [Hq, dn, R] and `w_uv` [Hq, R, dv], the one roped key kr [B, K, dr]
+    is shared by every head, and ordinary causal attention runs with
+    (dn + dr)-wide keys and dv-wide values. 2 (dn + dr + dv) FLOP a query-key pair a
+    head where the absorbed form costs 2 (2 R + dr): the cheaper one
+    whenever many queries meet the same keys (prefill). Returns
+    [B, T, Hq, dv]."""
+    dn, dr = cfg.qk_nope_head_dim, cfg.qk_rope_head_dim
+    k_nope = jnp.einsum("bkc,hdc->bkhd", c, w_uk)
+    v = jnp.einsum("bkc,hcd->bkhd", c, w_uv)
+    k = jnp.concatenate([
+        k_nope, jnp.broadcast_to(kr[:, :, None, :], (*k_nope.shape[:3], dr)),
+    ], axis=-1)
+    q = jnp.concatenate([q_nope, q_rope], axis=-1)
+    scale = (dn + dr) ** -0.5
+    if impl == "pallas":
+        from oryx_tpu.ops.pallas import flash_attention as _fa
+
+        # Whole lane tiles for the kernel's q/k blocks (192 -> 256): the
+        # zero columns add nothing to a score.
+        pad = -(dn + dr) % 128
+        if pad:
+            widen = ((0, 0), (0, 0), (0, 0), (0, pad))
+            q, k = jnp.pad(q, widen), jnp.pad(k, widen)
+        return _fa.flash_attention(
+            q, k, v, causal=True, q_positions=q_positions,
+            kv_positions=kv_positions, kv_mask=kv_mask, scale=scale,
+        )
+    s = jnp.einsum(
+        "bqhd,bkhd->bhqk", q, k, preferred_element_type=jnp.float32
+    ) * scale
+    if kv_positions is None:
+        kv_positions = jnp.arange(k.shape[1], dtype=jnp.int32)[None, :]
+    seen = q_positions[:, :, None] >= kv_positions[:, None, :]
+    if kv_mask is not None:
+        seen = seen & kv_mask[:, None, :].astype(bool)
+    s = jnp.where(seen[:, None], s, jnp.finfo(jnp.float32).min)
+    p = jax.nn.softmax(s, axis=-1).astype(v.dtype)
+    return jnp.einsum("bhqk,bkhd->bqhd", p, v).astype(q.dtype)
+
+
+def _mla(cfg: LLMConfig, a: jnp.ndarray, p: Params, cos, sin, *,
+         positions, pool, tables, write_slots, kv_mask, write_mask,
+         kv_lengths, attn_impl: str):
+    """One latent-attention sublayer on the normed input a [B, T, H].
+    Returns (its output [B, T, H], the pool). What a token leaves in
+    the cache is (its kv latent after norm and scale, its ONE roped
+    key): cfg.latent_dim values, written to `pool` [P, page, Dp] through
+    `tables` before anything reads it.
+
+    Two paths, chosen from shapes. A decode step (T == 1 with
+    kv_lengths) runs the ABSORBED form over the paged latents in place:
+    q_lat = q_nope W_uk^T, scores q_lat . c + q_rope . kr against the
+    page rows as they lie, o_lat = sum p c, o = o_lat W_uv; a page is
+    read once for both products and no per-head key or value ever
+    exists (ops/pallas/paged_attention._latent_paged, or its XLA twin).
+    Anything longer (a prefill chunk over its cached prefix, a forward
+    with no cache) runs the EXPANDED form, `_mla_expanded`."""
+    from oryx_tpu.ops.rope import apply_rope_interleaved
+
+    B, T, H = a.shape
+    Hq, R = cfg.num_heads, cfg.kv_lora_rank
+    dn, dr, dv = cfg.qk_nope_head_dim, cfg.qk_rope_head_dim, cfg.v_head_dim
+    eps = cfg.rms_norm_eps
+    cq = rms_norm(_linear(a, p["q_a_proj"]), p["q_a_norm"]["weight"], eps)
+    if cfg.mla_scale_q_lora:
+        # (cq Wq_b) * sqrt(H / Rq), the factor taken on the narrow side.
+        cq = (cq * (H / cfg.q_lora_rank) ** 0.5).astype(a.dtype)
+    q_nope = _linear(cq, p["q_b_nope"]).reshape(B, T, Hq, dn)
+    q_rope = _linear(cq, p["q_b_rope"]).reshape(B, T, Hq, dr)
+    c = rms_norm(_linear(a, p["kv_a_proj"]), p["kv_a_norm"]["weight"], eps)
+    if cfg.mla_scale_kv_lora:
+        c = (c * (H / R) ** 0.5).astype(a.dtype)
+    rope = apply_rope_interleaved if cfg.rope_interleaved else (
+        lambda x, cos, sin: apply_rope(x, x, cos, sin)[0])
+    q_rope = rope(q_rope, cos, sin)
+    kr = rope(_linear(a, p["k_rope_proj"])[:, :, None], cos, sin)[:, :, 0]
+    w_uk, w_uv = p["w_uk"].astype(a.dtype), p["w_uv"].astype(a.dtype)
+    if pool is None:
+        o = _mla_expanded(
+            cfg, q_nope, q_rope, c, kr, w_uk, w_uv, q_positions=positions,
+            kv_positions=positions, kv_mask=kv_mask, impl=attn_impl,
+        )
+    else:
+        from oryx_tpu.ops import paged_kv
+
+        Dp = pool.shape[-1]
+        row = jnp.concatenate(
+            [c, kr, jnp.zeros((B, T, Dp - R - dr), c.dtype)], axis=-1
+        )
+        pool = paged_kv.write_pages(
+            pool[:, :, None, :], row[:, :, None, :], tables, write_slots,
+            write_mask=write_mask,
+        )[:, :, 0, :]
+        if T == 1 and kv_lengths is not None:
+            q_lat = jnp.einsum("bhd,hdc->bhc", q_nope[:, 0], w_uk)
+            qf = jnp.concatenate([
+                q_lat, q_rope[:, 0],
+                jnp.zeros((B, Hq, Dp - R - dr), q_lat.dtype),
+            ], axis=-1)
+            if attn_impl == "pallas":
+                from oryx_tpu.ops.pallas import paged_attention as _ppa
+
+                decode = _ppa.latent_decode_attention
+            else:
+                decode = paged_kv.latent_decode_attention
+            o_lat = decode(
+                qf, pool, tables, kv_lengths, scale=(dn + dr) ** -0.5,
+                value_dim=R,
+            )
+            o = jnp.einsum("bhc,hcd->bhd", o_lat, w_uv)[:, None]
+        else:
+            lat = paged_kv.gather_pages(pool[:, :, None, :], tables)[:, :, 0]
+            o = _mla_expanded(
+                cfg, q_nope, q_rope, lat[..., :R], lat[..., R:R + dr],
+                w_uk, w_uv, q_positions=positions, kv_positions=None,
+                kv_mask=kv_mask, impl=attn_impl,
+            )
+    return _linear(o.reshape(B, T, Hq * dv), p["o_proj"]), pool
+
+
+def _double_block(cfg: LLMConfig, h, lp: Params, cos, sin, *, pool, tables,
+                  experts: tuple, attn_impl: str, **attn):
+    """One shortcut-connected double layer on h [B, T, H]: two
+    (latent attention, dense FFN) sublayers in series and ONE expert
+    layer whose input is the normed state after the first attention and
+    whose output is added after the second FFN, so the expert layer
+    runs beside a whole attention and two dense FFNs. `tables`: the two
+    sublayers' block tables (cache layers 2l and 2l + 1), or None with
+    no pool. Returns (h, pool, the expert layer's routing)."""
+    B, T, _ = h.shape
+    eps = cfg.rms_norm_eps
+    for i in (0, 1):
+        sub = lp[f"sub{i}"]
+        a = rms_norm(h, sub["input_norm"]["weight"], eps)
+        att, pool = _mla(
+            cfg, a, sub, cos, sin, pool=pool,
+            tables=None if tables is None else tables[i],
+            attn_impl=attn_impl, **attn,
+        )
+        h = h + att
+        x = rms_norm(h, sub["post_attn_norm"]["weight"], eps)
+        if i == 0:
+            s, routing = _moe(
+                cfg, x.reshape(B * T, -1), lp["router"]["kernel"], *experts,
+                impl=attn_impl, router_bias=lp["router"].get("bias"),
+            )
+        h = h + _swiglu(x, sub)
+    return h + s.reshape(B, T, -1), pool, routing
 
 
 def forward(
@@ -592,7 +884,10 @@ def forward(
 
     if positions is None:
         positions = jnp.broadcast_to(jnp.arange(T, dtype=jnp.int32), (B, T))
-    cos, sin = rope_cos_sin(positions, cfg.head_dim, cfg.rope_theta)  # [B,T,D]
+    cos, sin = rope_cos_sin(
+        positions, cfg.qk_rope_head_dim if cfg.latent else cfg.head_dim,
+        cfg.rope_theta,
+    )  # [B,T,D]
 
     if kv_cache is not None and write_slots is None:
         write_slots = positions[:, 0]
@@ -709,7 +1004,67 @@ def forward(
         return constrain(h, *hs_spec), ck, cv, routing[0] if routing else None
 
     new_cache = None
-    if kv_cache is not None and block_tables is not None:
+    if cfg.latent:
+        from oryx_tpu.ops import paged_kv
+
+        if (q_segments is not None or segment_ids is not None
+                or attn_impl not in ("xla", "pallas")
+                or (kv_cache is not None and (
+                    block_tables is None
+                    or not paged_kv.is_latent_pool(kv_cache)))):
+            raise ValueError(unsupported_for_latent(
+                "a packed ragged step, packed training, ring attention or "
+                "a dense / per-head cache"
+            ))
+        attn = dict(
+            positions=positions, write_slots=write_slots, kv_mask=kv_mask,
+            write_mask=write_mask, kv_lengths=kv_lengths,
+        )
+        num_l = jnp.arange(cfg.num_layers, dtype=jnp.int32)
+        if kv_cache is None:
+            def body(h, xs):
+                lp, layer = xs
+                h, _, routing = _double_block(
+                    cfg, h, lp, cos, sin, pool=None, tables=None,
+                    experts=(experts_flat, layer), attn_impl=attn_impl,
+                    **attn,
+                )
+                return constrain(h, *hs_spec), routing
+
+            h, expert_counts = jax.lax.scan(
+                wrap_remat(body, remat), h, (layers, num_l)
+            )
+        else:
+            # The pool is the scan's carry, one flat [2L*P, page, Dp]
+            # buffer behind layer-offset tables, as below; cache layer
+            # 2l + i belongs to sublayer i of model layer l.
+            pool = kv_cache[paged_kv.LATENT]
+            L2, P = pool.shape[:2]
+
+            def body(carry, xs):
+                h, pool = carry
+                lp, layer = xs
+                tables = [
+                    jnp.where(block_tables >= P, L2 * P,
+                              block_tables + (2 * layer + i) * P)
+                    for i in (0, 1)
+                ]
+                h, pool, routing = _double_block(
+                    cfg, h, lp, cos, sin, pool=pool, tables=tables,
+                    experts=(experts_flat, layer), attn_impl=attn_impl,
+                    **attn,
+                )
+                return (constrain(h, *hs_spec), pool), routing
+
+            (h, pool), expert_counts = jax.lax.scan(
+                wrap_remat(body, remat),
+                (h, pool.reshape((L2 * P,) + pool.shape[2:])),
+                (layers, num_l.astype(block_tables.dtype)),
+            )
+            new_cache = {
+                paged_kv.LATENT: pool.reshape((L2, P) + pool.shape[1:])
+            }
+    elif kv_cache is not None and block_tables is not None:
         # Paged pool: the scan's CARRY, one flat [L*P, page, ...] buffer a
         # plane behind layer-offset tables (the `block_tables` contract
         # above), so XLA updates the donated pool in place.

@@ -59,7 +59,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from oryx_tpu.ops.attention import attention
+from oryx_tpu.ops.attention import NEG_INF, attention
 from oryx_tpu.utils import faults
 from oryx_tpu.utils import quant as quant_lib
 
@@ -353,6 +353,14 @@ class QuantPages:
     dequantize into."""
 
     def __init__(self, q, scale, dequant_dtype=jnp.float32):
+        # Tracers and pytree sentinels have no ndim worth checking.
+        if getattr(q, "ndim", 5) == 4 and getattr(scale, "ndim", 0) == 3:
+            raise ValueError(
+                "latent pool: a quantized pool is per-head K and V planes "
+                "[L, P, page, Hk, D] with one scale a token; a latent "
+                "(MLA) page [L, P, page, D] has no head axis and is not "
+                "built for it (kv_dtype must be bf16)"
+            )
         self.q = q
         self.scale = scale
         self.dequant_dtype = jnp.dtype(dequant_dtype)
@@ -406,10 +414,30 @@ def init_quant_pages(
     )
 
 
+def pool_plane(kv_pages):
+    """One plane of a pool pytree ([L, P, page, ...]; K of a per-head
+    pool, the one plane of a latent pool): what callers read the pool's
+    geometry off (`pool_plane(kv).shape[2]` is the page size)."""
+    if isinstance(kv_pages, dict):
+        return kv_pages["k"] if "k" in kv_pages else kv_pages[LATENT]
+    return kv_pages
+
+
+# The one plane of a latent (MLA) pool, [L2, P, page, Dp]: cache layer
+# 2l + i is attention sublayer i of model layer l; a token's row is its
+# kv latent, its roped shared key and zeros up to whole 128-lane tiles
+# (`LLMConfig.latent_page_dim`). No head axis.
+LATENT = "latent"
+
+
+def is_latent_pool(kv_pages) -> bool:
+    return isinstance(kv_pages, dict) and LATENT in kv_pages
+
+
 def kv_pool_dtype(kv_pages) -> str:
     """The pool's wire format: "int8" / "fp8_e4m3" for a quantized
     pool, else the dense leaf dtype's name (e.g. "float32")."""
-    leaf = kv_pages["k"] if isinstance(kv_pages, dict) else kv_pages
+    leaf = pool_plane(kv_pages)
     if isinstance(leaf, QuantPages):
         try:
             return _quant_fmt(leaf)
@@ -761,3 +789,38 @@ def ragged_paged_attention(
         scale=scale,
     )
     return out[:, 0]
+
+
+def latent_decode_attention(
+    q: jnp.ndarray,  # [B, Hq, Dp] absorbed queries: (q_lat | q_rope | 0)
+    pages: jnp.ndarray,  # [P, page_size, Dp] latent pool of one cache layer
+    block_tables: jnp.ndarray,  # [B, max_pages]
+    kv_lengths: jnp.ndarray,  # [B] valid kv count INCLUDING the current token
+    *,
+    scale: float,
+    value_dim: int,
+) -> jnp.ndarray:
+    """Pure-JAX reference of absorbed latent (MLA) decode over a paged
+    pool: every head of row b scores against the ONE shared key a
+    cached token holds (the whole page row) and sums the row's first
+    `value_dim` columns, the latent, as its value. Returns
+    [B, Hq, value_dim]; a row of length 0 reads nothing and returns 0.
+    The Pallas twin (ops/pallas/paged_attention.latent_decode_attention)
+    walks the live pages in place; this one gathers them."""
+    P, ps, Dp = pages.shape
+    lat = gather_pages(pages[:, :, None, :], block_tables)[:, :, 0]
+    s = jnp.einsum(
+        "bhd,bkd->bhk", q, lat, preferred_element_type=jnp.float32
+    ) * scale
+    seen = (
+        jnp.arange(lat.shape[1], dtype=jnp.int32)[None, :]
+        < kv_lengths[:, None]
+    )[:, None, :]
+    s = jnp.where(seen, s, NEG_INF)
+    p = jnp.where(seen, jnp.exp(s - jnp.max(s, axis=-1, keepdims=True)), 0.0)
+    l = jnp.sum(p, axis=-1, keepdims=True)
+    out = jnp.einsum(
+        "bhk,bkc->bhc", p.astype(lat.dtype), lat[..., :value_dim],
+        preferred_element_type=jnp.float32,
+    )
+    return (out / jnp.where(l == 0.0, 1.0, l)).astype(q.dtype)

@@ -220,6 +220,7 @@ def _mha_forward(
     """
     B, Hq, Tq, D = q.shape
     _, Hk, Tk, _ = k.shape
+    Dv = v.shape[-1]  # values may be narrower than keys (latent attention)
     G = Hq // Hk
     block_q = min(BLOCK_Q, Tq)
     block_k = min(BLOCK_K, Tk)
@@ -249,9 +250,9 @@ def _mha_forward(
     ck = _causal_kv_clamp(block_q, block_k, causal and kv_arange and q_arange)
 
     o_spec = pl.BlockSpec(
-        (1, 1, block_q, D), lambda b, h, iq, ik: (b, h, iq, 0)
+        (1, 1, block_q, Dv), lambda b, h, iq, ik: (b, h, iq, 0)
     )
-    o_shape = jax.ShapeDtypeStruct((B, Hq, Tq, D), q.dtype)
+    o_shape = jax.ShapeDtypeStruct((B, Hq, Tq, Dv), q.dtype)
     lse_spec = pl.BlockSpec(
         (1, 1, block_q, LANES), lambda b, h, iq, ik: (b, h, iq, 0)
     )
@@ -280,7 +281,7 @@ def _mha_forward(
                 lambda b, h, iq, ik: (b, h // G, ck(iq, ik), 0),
             ),
             pl.BlockSpec(
-                (1, 1, block_k, D),
+                (1, 1, block_k, Dv),
                 lambda b, h, iq, ik: (b, h // G, ck(iq, ik), 0),
             ),
         ],
@@ -289,7 +290,7 @@ def _mha_forward(
         scratch_shapes=[
             pltpu.VMEM((block_q, 128), jnp.float32),
             pltpu.VMEM((block_q, 128), jnp.float32),
-            pltpu.VMEM((block_q, D), jnp.float32),
+            pltpu.VMEM((block_q, Dv), jnp.float32),
         ],
         interpret=interpret,
     )(q_pos, kv_pos, q_seg, kv_seg, kv_valid, q, k, v)

@@ -242,6 +242,139 @@ def _scale_column(row):
     return jnp.broadcast_to(row, (8, row.shape[1])).T[:, :1]
 
 
+def _walk_row(
+    bt_ref,  # [S, maxp] SMEM (scalar prefetch)
+    seg_ref,  # [R] SMEM
+    pos_ref,  # [R] SMEM
+    slot_ref,  # [1] SMEM scratch: the buffer slot of the block in flight
+    scratch,  # (m, l, acc) VMEM: the row's running softmax
+    *,
+    num_pages: int,
+    page_size: int,
+    pages_per_block: int,
+    page_copies,  # (page, j, slot) -> copies of page j of a block
+    block_copies,  # (seg, blk, slot) -> a block's further copies, or None
+    zero,  # () -> None: buffers a masked column must find finite
+    block_step,  # (length) -> ((i, slot) -> None): fold block i in
+):
+    """One grid step of a page walk: packed row r reads its own live
+    pages, `pages_per_block` at a time, double-buffered. A block's
+    pages are copied through the scalar-prefetched block table while
+    the block before computes; a row's last block (or a row of length
+    0) starts the NEXT row's first copy, the buffer slot handed across
+    grid steps in `slot_ref`. What a page is and what a block adds to
+    the running softmax are the caller's (`_ragged_kernel`,
+    `_latent_kernel`)."""
+    r, R = pl.program_id(0), pl.num_programs(0)
+    S, maxp = bt_ref.shape
+    P, ps, npb = num_pages, page_size, pages_per_block
+    m_scr, l_scr, acc_scr = scratch
+
+    def visible(row):
+        """(kv tokens, pages) packed row `row` reads."""
+        length = jnp.clip(pos_ref[row] + 1, 0, maxp * ps)
+        return length, pl.cdiv(length, ps)
+
+    def copies(row, blk, slot):
+        """[(live, copies)] of block `blk` of `row`'s walk into buffer
+        `slot`: an entry a page, and one for a block's further copies.
+        Rebuilt with the same arguments to wait."""
+        seg = jnp.clip(seg_ref[row], 0, S - 1)
+        _, pages = visible(row)
+        out = []
+        for j in range(npb):
+            pg = blk * npb + j
+            page = jnp.clip(bt_ref[seg, jnp.minimum(pg, maxp - 1)], 0, P - 1)
+            cps = page_copies(page, j, slot)
+            out.append((pg < pages, cps))
+        if block_copies is not None:
+            cps = block_copies(seg, blk, slot)
+            out.append((blk * npb < pages, cps))
+        return out
+
+    def each_copy(row, blk, slot, do):
+        for live, cps in copies(row, blk, slot):
+            @pl.when(live)
+            def _():
+                for cp in cps:
+                    do(cp)
+
+    def start(row, blk, slot):
+        each_copy(row, blk, slot, lambda cp: cp.start())
+
+    def wait(row, blk, slot):
+        each_copy(row, blk, slot, lambda cp: cp.wait())
+
+    @pl.when(r == 0)
+    def _first():
+        zero()
+        slot_ref[0] = 0
+        start(0, 0, 0)
+
+    m_scr[...] = jnp.full_like(m_scr, NEG)
+    l_scr[...] = jnp.zeros_like(l_scr)
+    acc_scr[...] = jnp.zeros_like(acc_scr)
+
+    length, pages = visible(r)
+    nblk = pl.cdiv(pages, npb)
+    nxt = jnp.minimum(r + 1, R - 1)
+    has_next = r + 1 < R
+    slot0 = slot_ref[0]
+    fold = block_step(length)
+
+    def step(i, slot):
+        # Next in flight: this row's next block, else the next row's
+        # first, into the buffer the block before this one has left.
+        in_row = i + 1 < nblk
+
+        @pl.when(in_row | has_next)
+        def _():
+            start(
+                jnp.where(in_row, r, nxt), jnp.where(in_row, i + 1, 0),
+                1 - slot,
+            )
+
+        wait(r, i, slot)
+        fold(i, slot)
+        return 1 - slot
+
+    # A row with nothing to read still hands the walk on.
+    @pl.when((nblk == 0) & has_next)
+    def _():
+        start(nxt, 0, slot0)
+
+    slot_ref[0] = jax.lax.fori_loop(0, nblk, step, slot0)
+
+
+def _fold_block(s, v, rows, scratch):
+    """Masked logits s [n, brow] (fp32) and values v [brow, Dv] of one
+    block folded into rows `rows` of the running (m, l, acc)."""
+    m_scr, l_scr, acc_scr = scratch
+    n = s.shape[0]
+    m_prev = m_scr[rows, :1]
+    m_new = jnp.maximum(m_prev, jnp.max(s, axis=-1, keepdims=True))
+    alpha = jnp.exp(m_prev - m_new)
+    p = jnp.exp(s - m_new)  # exact 0 in every masked column
+    l_new = l_scr[rows, :1] * alpha + jnp.sum(p, axis=-1, keepdims=True)
+    m_scr[rows, :] = jnp.broadcast_to(m_new, (n, m_scr.shape[1]))
+    l_scr[rows, :] = jnp.broadcast_to(l_new, (n, l_scr.shape[1]))
+    pv = jax.lax.dot_general(
+        p.astype(v.dtype), v, (((1,), (0,)), ((), ())),
+        preferred_element_type=jnp.float32,
+    )
+    acc_scr[rows, :] = acc_scr[rows, :] * alpha + pv
+
+
+def _write_row(o_ref, scratch):
+    """The row's output from its running (m, l, acc): 0 where it read
+    nothing."""
+    _, l_scr, acc_scr = scratch
+    Hq = o_ref.shape[1]
+    l = l_scr[:Hq, :1]
+    out = acc_scr[:Hq, :] / jnp.where(l == 0.0, 1.0, l)
+    o_ref[0] = out.astype(o_ref.dtype)
+
+
 def _ragged_kernel(
     bt_ref,  # [S, maxp] SMEM (scalar prefetch)
     seg_ref,  # [R] SMEM
@@ -262,147 +395,81 @@ def _ragged_kernel(
     quant = dequant_dtype is not None
     if quant:
         (q_ref, k_hbm, ks_hbm, v_hbm, vs_hbm, o_ref, k_buf, v_buf,
-         ks_buf, vs_buf, sems, slot_ref, m_scr, l_scr, acc_scr) = refs
+         ks_buf, vs_buf, sems, slot_ref, *scratch) = refs
     else:
         (q_ref, k_hbm, v_hbm, o_ref, k_buf, v_buf,
-         sems, slot_ref, m_scr, l_scr, acc_scr) = refs
+         sems, slot_ref, *scratch) = refs
         ks_hbm = vs_hbm = ks_buf = vs_buf = None
-    r, R = pl.program_id(0), pl.num_programs(0)
-    S, maxp = bt_ref.shape
-    P = k_hbm.shape[0]
     ps, Hk, npb, HB = page_size, num_kv_heads, pages_per_block, heads_per_block
-    Hq = q_ref.shape[1]
-    G = Hq // Hk
+    G = q_ref.shape[1] // Hk
     prow = ps * Hk  # rows of one page in the packed view
     brow = npb * prow  # rows of one block
 
-    def visible(row):
-        """(kv tokens, pages) packed row `row` reads."""
-        length = jnp.clip(pos_ref[row] + 1, 0, maxp * ps)
-        return length, pl.cdiv(length, ps)
+    def page_copies(page, j, slot):
+        dst = pl.ds(j * prow, prow)
+        return [
+            pltpu.make_async_copy(
+                k_hbm.at[page], k_buf.at[slot, dst], sems.at[slot, 0]
+            ),
+            pltpu.make_async_copy(
+                v_hbm.at[page], v_buf.at[slot, dst], sems.at[slot, 1]
+            ),
+        ]
 
-    def block_copies(row, blk, slot):
-        """[(live, copies)] of block `blk` of `row`'s walk into buffer
-        `slot`: an entry a page, and one for a quantized block's scale
-        rows. Rebuilt with the same arguments to wait."""
-        seg = jnp.clip(seg_ref[row], 0, S - 1)
-        _, pages = visible(row)
-        out = []
-        for j in range(npb):
-            pg = blk * npb + j
-            page = jnp.clip(bt_ref[seg, jnp.minimum(pg, maxp - 1)], 0, P - 1)
-            dst = pl.ds(j * prow, prow)
-            cps = [
-                pltpu.make_async_copy(
-                    k_hbm.at[page], k_buf.at[slot, dst], sems.at[slot, 0]
-                ),
-                pltpu.make_async_copy(
-                    v_hbm.at[page], v_buf.at[slot, dst], sems.at[slot, 1]
-                ),
-            ]
-            out.append((pg < pages, cps))
-        if quant:
-            b = jnp.minimum(blk, ks_hbm.shape[1] - 1)
-            out.append((blk * npb < pages, [
-                pltpu.make_async_copy(
-                    ks_hbm.at[seg, b], ks_buf.at[slot], sems.at[slot, 2]
-                ),
-                pltpu.make_async_copy(
-                    vs_hbm.at[seg, b], vs_buf.at[slot], sems.at[slot, 3]
-                ),
-            ]))
-        return out
+    def scale_copies(seg, blk, slot):
+        b = jnp.minimum(blk, ks_hbm.shape[1] - 1)
+        return [
+            pltpu.make_async_copy(
+                ks_hbm.at[seg, b], ks_buf.at[slot], sems.at[slot, 2]
+            ),
+            pltpu.make_async_copy(
+                vs_hbm.at[seg, b], vs_buf.at[slot], sems.at[slot, 3]
+            ),
+        ]
 
-    def each_copy(row, blk, slot, do):
-        for live, cps in block_copies(row, blk, slot):
-            @pl.when(live)
-            def _():
-                for cp in cps:
-                    do(cp)
-
-    def start(row, blk, slot):
-        each_copy(row, blk, slot, lambda cp: cp.start())
-
-    def wait(row, blk, slot):
-        each_copy(row, blk, slot, lambda cp: cp.wait())
-
-    @pl.when(r == 0)
-    def _first():
+    def zero():
+        # A masked column multiplies stale but finite VMEM.
         v_buf[...] = jnp.zeros_like(v_buf)
-        slot_ref[0] = 0
-        start(0, 0, 0)
 
-    m_scr[...] = jnp.full_like(m_scr, NEG)
-    l_scr[...] = jnp.zeros_like(l_scr)
-    acc_scr[...] = jnp.zeros_like(acc_scr)
+    def block_step(length):
+        # Column c of a block is token c // Hk of the block, kv head
+        # c % Hk.
+        col = jax.lax.broadcasted_iota(jnp.int32, (1, brow), 1)
+        col_tok, col_head = col // Hk, col % Hk
 
-    length, pages = visible(r)
-    nblk = pl.cdiv(pages, npb)
-    nxt = jnp.minimum(r + 1, R - 1)
-    has_next = r + 1 < R
-    slot0 = slot_ref[0]
+        def fold(i, slot):
+            k, v = k_buf[slot], v_buf[slot]  # [brow, D]
+            if quant:
+                dq = jnp.dtype(dequant_dtype)
+                k = k.astype(dq) * _scale_column(ks_buf[slot]).astype(dq)
+                v = v.astype(dq) * _scale_column(vs_buf[slot]).astype(dq)
+            seen = i * (npb * ps) + col_tok < length
+            for t in range(Hk // HB):  # static unroll over kv-head tiles
+                lo, n = t * HB * G, HB * G
+                row_head = t * HB + jax.lax.broadcasted_iota(
+                    jnp.int32, (n, 1), 0
+                ) // G
+                s = jax.lax.dot_general(
+                    q_ref[0, lo:lo + n], k, (((1,), (1,)), ((), ())),
+                    preferred_element_type=jnp.float32,
+                ) * scale  # [HB * G, brow] fp32
+                # Causal == validity: slots past this row's own
+                # position are invisible, whether they belong to its
+                # future tokens (prefill-suffix packing) or to nobody
+                # yet (decode).
+                s = jnp.where(seen & (col_head == row_head), s, NEG)
+                _fold_block(s, v, slice(lo, lo + n), scratch)
 
-    # Column c of a block is token c // Hk of the block, kv head c % Hk.
-    col = jax.lax.broadcasted_iota(jnp.int32, (1, brow), 1)
-    col_tok, col_head = col // Hk, col % Hk
+        return fold
 
-    def step(i, slot):
-        # Next in flight: this row's next block, else the next row's
-        # first, into the buffer the block before this one has left.
-        in_row = i + 1 < nblk
-
-        @pl.when(in_row | has_next)
-        def _():
-            start(
-                jnp.where(in_row, r, nxt), jnp.where(in_row, i + 1, 0),
-                1 - slot,
-            )
-
-        wait(r, i, slot)
-        k, v = k_buf[slot], v_buf[slot]  # [brow, D]
-        if quant:
-            dq = jnp.dtype(dequant_dtype)
-            k = k.astype(dq) * _scale_column(ks_buf[slot]).astype(dq)
-            v = v.astype(dq) * _scale_column(vs_buf[slot]).astype(dq)
-        seen = i * (npb * ps) + col_tok < length
-        for t in range(Hk // HB):  # static unroll over kv-head tiles
-            lo, n = t * HB * G, HB * G
-            row_head = t * HB + jax.lax.broadcasted_iota(
-                jnp.int32, (n, 1), 0
-            ) // G
-            s = jax.lax.dot_general(
-                q_ref[0, lo:lo + n], k, (((1,), (1,)), ((), ())),
-                preferred_element_type=jnp.float32,
-            ) * scale  # [HB * G, brow] fp32
-            # Causal == validity: slots past this row's own position
-            # are invisible, whether they belong to its future tokens
-            # (prefill-suffix packing) or to nobody yet (decode).
-            s = jnp.where(seen & (col_head == row_head), s, NEG)
-            m_prev = m_scr[lo:lo + n, :1]
-            m_new = jnp.maximum(m_prev, jnp.max(s, axis=-1, keepdims=True))
-            alpha = jnp.exp(m_prev - m_new)
-            p = jnp.exp(s - m_new)  # exact 0 in every masked column
-            l_new = l_scr[lo:lo + n, :1] * alpha + jnp.sum(
-                p, axis=-1, keepdims=True
-            )
-            m_scr[lo:lo + n, :] = jnp.broadcast_to(m_new, (n, m_scr.shape[1]))
-            l_scr[lo:lo + n, :] = jnp.broadcast_to(l_new, (n, l_scr.shape[1]))
-            pv = jax.lax.dot_general(
-                p.astype(v.dtype), v, (((1,), (0,)), ((), ())),
-                preferred_element_type=jnp.float32,
-            )
-            acc_scr[lo:lo + n, :] = acc_scr[lo:lo + n, :] * alpha + pv
-        return 1 - slot
-
-    # A row with nothing to read still hands the walk on.
-    @pl.when((nblk == 0) & has_next)
-    def _():
-        start(nxt, 0, slot0)
-
-    slot_ref[0] = jax.lax.fori_loop(0, nblk, step, slot0)
-    l = l_scr[:Hq, :1]
-    out = acc_scr[:Hq, :] / jnp.where(l == 0.0, 1.0, l)
-    o_ref[0] = out.astype(o_ref.dtype)
+    _walk_row(
+        bt_ref, seg_ref, pos_ref, slot_ref, scratch,
+        num_pages=k_hbm.shape[0], page_size=ps, pages_per_block=npb,
+        page_copies=page_copies,
+        block_copies=scale_copies if quant else None,
+        zero=zero, block_step=block_step,
+    )
+    _write_row(o_ref, scratch)
 
 
 @functools.partial(
@@ -532,4 +599,137 @@ def ragged_paged_attention(
         scale=float(scale), heads_per_block=int(heads_per_block),
         interpret=bool(interpret),
         dequant_dtype=dequant,
+    )
+
+
+# ---------------------------------------------------------------------------
+# Latent (MLA) pool: absorbed decode over one shared key a token
+# ---------------------------------------------------------------------------
+
+# Tokens one step of a latent row's walk handles (whole pages): 512 x
+# 640 bf16 values are 640 KiB a buffer, two buffers in flight.
+_LATENT_BLOCK_TOKENS = 512
+
+
+def _latent_kernel(
+    bt_ref,  # [S, maxp] SMEM (scalar prefetch)
+    seg_ref,  # [R] SMEM
+    pos_ref,  # [R] SMEM
+    q_ref,  # [1, Hq, Dp] absorbed queries (q_lat | q_rope | 0)
+    c_hbm,  # [P, ps, Dp] the cache layer's pages
+    o_ref,  # [1, Hq, Dv]
+    c_buf, sems, slot_ref, *scratch,
+    scale: float,
+    page_size: int,
+    pages_per_block: int,
+    value_dim: int,
+):
+    """`_walk_row` over a pool with ONE plane and no head axis: every
+    query head scores against the whole page row, and the row's first
+    `value_dim` columns are the value, so a page is copied once for
+    both products."""
+    ps, npb = page_size, pages_per_block
+    brow = npb * ps
+
+    def page_copies(page, j, slot):
+        return [pltpu.make_async_copy(
+            c_hbm.at[page], c_buf.at[slot, pl.ds(j * ps, ps)], sems.at[slot],
+        )]
+
+    def zero():
+        # Rows of a buffer no copy has reached yet are weighed by an
+        # exact 0; they must not hold a NaN.
+        c_buf[...] = jnp.zeros_like(c_buf)
+
+    def block_step(length):
+        col = jax.lax.broadcasted_iota(jnp.int32, (1, brow), 1)
+
+        def fold(i, slot):
+            c = c_buf[slot]  # [brow, Dp]
+            s = jax.lax.dot_general(
+                q_ref[0], c, (((1,), (1,)), ((), ())),
+                preferred_element_type=jnp.float32,
+            ) * scale  # [Hq, brow] fp32
+            s = jnp.where(i * brow + col < length, s, NEG)
+            _fold_block(s, c[:, :value_dim], slice(None), scratch)
+
+        return fold
+
+    _walk_row(
+        bt_ref, seg_ref, pos_ref, slot_ref, scratch,
+        num_pages=c_hbm.shape[0], page_size=ps, pages_per_block=npb,
+        page_copies=page_copies, block_copies=None,
+        zero=zero, block_step=block_step,
+    )
+    _write_row(o_ref, scratch)
+
+
+@functools.partial(
+    jax.jit, static_argnames=("scale", "value_dim", "interpret"),
+)
+def _latent_paged(
+    q,  # [R, Hq, Dp]
+    pages,  # [P, ps, Dp]
+    block_tables,  # [S, maxp] int32
+    q_segments,  # [R] int32
+    q_positions,  # [R] int32
+    *,
+    scale: float,
+    value_dim: int,
+    interpret: bool,
+):
+    R, Hq, Dp = q.shape
+    P, ps, _ = pages.shape
+    npb = max(1, min(block_tables.shape[1], _LATENT_BLOCK_TOKENS // ps))
+    row = lambda d: pl.BlockSpec((1, Hq, d), lambda r, *_: (r, 0, 0))  # noqa: E731
+    return pl.pallas_call(
+        functools.partial(
+            _latent_kernel, scale=scale, page_size=ps, pages_per_block=npb,
+            value_dim=value_dim,
+        ),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=3,
+            grid=(R,),
+            in_specs=[row(Dp), pl.BlockSpec(memory_space=pltpu.HBM)],
+            out_specs=row(value_dim),
+            scratch_shapes=[
+                pltpu.VMEM((2, npb * ps, Dp), pages.dtype),
+                pltpu.SemaphoreType.DMA((2,)),
+                pltpu.SMEM((1,), jnp.int32),  # buffer slot in flight
+                pltpu.VMEM((Hq, 128), jnp.float32),
+                pltpu.VMEM((Hq, 128), jnp.float32),
+                pltpu.VMEM((Hq, value_dim), jnp.float32),
+            ],
+        ),
+        out_shape=jax.ShapeDtypeStruct((R, Hq, value_dim), q.dtype),
+        # Rows run in order: a row starts the next row's first copy.
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("arbitrary",)
+        ),
+        interpret=interpret,
+    )(block_tables.astype(jnp.int32), q_segments.astype(jnp.int32),
+      q_positions.astype(jnp.int32), q, pages)
+
+
+def latent_decode_attention(
+    q,  # [B, Hq, Dp] absorbed queries
+    pages,  # [P, page_size, Dp]
+    block_tables,  # [B, max_pages]
+    kv_lengths,  # [B] valid kv count INCLUDING the current token
+    *,
+    scale: float,
+    value_dim: int,
+    interpret: bool | None = None,
+):
+    """Drop-in for ops.paged_kv.latent_decode_attention: each row walks
+    its own live pages in place, once for scores and values both; a row
+    of length 0 takes no step of the walk and returns 0."""
+    if interpret is None:
+        interpret = _flash._use_interpret()
+    B = q.shape[0]
+    return _latent_paged(
+        q, pages, block_tables, jnp.arange(B, dtype=jnp.int32),
+        kv_lengths.astype(jnp.int32) - 1,
+        scale=float(scale), value_dim=int(value_dim),
+        interpret=bool(interpret),
     )

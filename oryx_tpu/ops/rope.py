@@ -62,3 +62,20 @@ def apply_rope(
         return out.astype(x.dtype)
 
     return rot(q), rot(k)
+
+
+def apply_rope_interleaved(
+    x: jnp.ndarray, cos: jnp.ndarray, sin: jnp.ndarray
+) -> jnp.ndarray:
+    """Rotary embedding over the pairs (x[2j], x[2j+1]) (the DeepSeek-V3
+    lineage's layout), each pair rotated in place by angle j.
+
+    x: [B, T, H, D]; cos/sin: [B, T, D] from `rope_cos_sin` (angle j in
+    column j of the first half). Rotation in fp32, output in x.dtype."""
+    half = x.shape[-1] // 2
+    c = cos[..., None, :half]
+    s = sin[..., None, :half]
+    xf = x.astype(jnp.float32).reshape(*x.shape[:-1], half, 2)
+    a, b = xf[..., 0], xf[..., 1]
+    out = jnp.stack([a * c - b * s, b * c + a * s], axis=-1)
+    return out.reshape(x.shape).astype(x.dtype)

@@ -202,7 +202,7 @@ def cast_params_for_compute(params: Params, dtype, mode: str = "fsdp"):
     return jax.tree.unflatten(treedef, out)
 
 
-def paged_kv_spec(mesh) -> P | None:
+def paged_kv_spec(mesh, kv_pages=None) -> P | None:
     """PartitionSpec for a paged KV pool leaf
     ([layers, pages, page_size, kv_heads, head_dim]) on `mesh`:
     sharded along KV HEADS over the tp axis, replicated otherwise.
@@ -221,11 +221,22 @@ def paged_kv_spec(mesh) -> P | None:
     index pages globally and a page-axis split would turn every
     table-addressed write into a cross-device scatter. Returns None
     (replicate) when the mesh has no tp axis or tp == 1 — an fsdp-only
-    serving mesh gathers weights but keeps the pool whole."""
+    serving mesh gathers weights but keeps the pool whole.
+
+    `kv_pages`: the pool to be placed, when the caller has it. A latent
+    (MLA) pool has no head axis to split and is refused on a tp mesh,
+    never replicated or split along its latent silently."""
     if mesh is None or "tp" not in mesh.axis_names:
         return None
     if mesh.shape["tp"] <= 1:
         return None
+    if kv_pages is not None:
+        from oryx_tpu.models.qwen2 import unsupported_for_latent
+        from oryx_tpu.ops.paged_kv import is_latent_pool
+
+        if is_latent_pool(kv_pages):
+            raise ValueError(unsupported_for_latent(
+                "a pool sharded over KV heads (tp > 1)"))
     return P(None, None, None, "tp", None)
 
 
@@ -235,7 +246,7 @@ def shard_paged_kv(kv_pages, mesh, *, num_kv_heads: int | None = None):
     the same pytree back — when the mesh doesn't split heads or the
     head count doesn't divide (a 2-kv-head model on tp=4 serves with a
     replicated pool rather than failing)."""
-    spec = paged_kv_spec(mesh)
+    spec = paged_kv_spec(mesh, kv_pages)
     if spec is None:
         return kv_pages
     heads = num_kv_heads

@@ -79,6 +79,7 @@ import numpy as np
 from oryx_tpu.analysis.sanitizers import named_lock
 from oryx_tpu.models import generate as generate_lib
 from oryx_tpu.models import oryx, qwen2
+from oryx_tpu.ops import paged_kv
 from oryx_tpu.ops.packing import round_up_bucket
 from oryx_tpu.utils import request_log as request_log_lib
 from oryx_tpu.utils.metrics import (
@@ -126,7 +127,7 @@ def audit_decode_step(
     hot path, where this exclusively runs.
 
     Returns (kv_pages, next_tok [1], logits [1, V] f32, keys')."""
-    page_size = kv_pages["k"].shape[2]
+    page_size = paged_kv.pool_plane(kv_pages).shape[2]
     K = block_tables.shape[1] * page_size
     slot_ar = jnp.arange(K, dtype=jnp.int32)[None, :]
     pair = jax.vmap(lambda k: jax.random.split(k, 2))(keys)

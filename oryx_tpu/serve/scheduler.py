@@ -478,6 +478,20 @@ class ContinuousScheduler:
                     f"block_length={self.block}: a step fixes at least "
                     "one position"
                 )
+        # A latent-attention (MLA) model's pool is one plane of latents
+        # that only the split engine's two programs read; every other
+        # step program and pool format is refused here, by name.
+        if pipe.cfg.llm.latent:
+            for bad, mode in (
+                (ragged, "ragged=True"),
+                (speculate, "speculate"),
+                (fuse_steps != 1, "fuse_steps"),
+                (kv_dtype != "bf16", f"kv_dtype={kv_dtype!r}"),
+                (getattr(pipe, "mesh", None) is not None,
+                 "the tensor-parallel engine (--engine sharded)"),
+            ):
+                if bad:
+                    raise ValueError(qwen2.unsupported_for_latent(mode))
         # Optional SLO watcher (utils/anomaly.py): TTFT and queue-depth
         # breaches fire oryx_anomaly_total{kind=} + events.jsonl.
         self.anomaly = anomaly
@@ -690,6 +704,21 @@ class ContinuousScheduler:
             reg.counter("moe_expert_rows_max_total")
             reg.counter("moe_expert_rows_mean_total")
             reg.counter("moe_experts_hit_total")
+        # A model whose expert layer holds a share of the routed experts
+        # or has zero-compute experts: per decode dispatch, from the
+        # routing the step returns (generate.SHARE_STATS; docs/
+        # OBSERVABILITY.md "Expert share"). rows_max / rows_mean count
+        # the HELD experts' rows.
+        self.share_stats = bool(
+            pipe.cfg.llm.experts_held or pipe.cfg.llm.zero_experts)
+        if self.share_stats:
+            reg.counter("moe_pairs_total")
+            reg.counter("moe_zero_pairs_total")
+            reg.counter("moe_held_experts_hit_total")
+            reg.counter("moe_held_expert_slots_total")
+            reg.counter("moe_expert_rows_max_total")
+            reg.counter("moe_expert_rows_mean_total")
+            reg.counter("decode_kv_tokens_total")
         self.allocator = paged_kv.PageAllocator(self.num_pages, page_size)
         # Page-pool observatory (utils/pagemap.py): oryx_pool_* gauges
         # refreshed at scrape time + the free-time page-lifetime/idle
@@ -2251,7 +2280,7 @@ class ContinuousScheduler:
             # too.
             self._cancel_queued(req)
             return ()
-        if req.embeds is None:
+        if not req.length:
             # The request reached the queue head: queue_wait ends,
             # admission (prompt prep + validation + the wait for
             # pages + prefill) begins.
@@ -2515,6 +2544,16 @@ class ContinuousScheduler:
             ))
         return True
 
+    def _ensure_embeds(self, req: _Request) -> None:
+        """Gather a text-only prompt's embeds again where `_activate`
+        released them (an eviction or a restart replays the prefill; the
+        auditor copies them at the finish)."""
+        if req.embeds is None:
+            with self._phase("embed"), self.pipe._mesh_scope():
+                req.embeds, _ = self.pipe._prompt_embeds(
+                    self.cfg, req.cache_tokens.tolist(), None, None, None
+                )
+
     def _place(self, s: int, req: _Request) -> None:
         """Claim slot s for `req` (pages already spliced+grown) and
         start its prefill. The slot stays `finished` on device — decode
@@ -2531,6 +2570,7 @@ class ContinuousScheduler:
             req.qw_span = -1
         if req.adm_span < 0:
             req.adm_span = req.trace.begin("admission", replay=True)
+        self._ensure_embeds(req)
         self.slots[s] = req
         req.activated = False
         self.finished[s] = True
@@ -2698,6 +2738,14 @@ class ContinuousScheduler:
         would make sampled streams depend on scheduling history, and
         break eviction replay)."""
         req.activated = True
+        if req.cache_tokens is not None:
+            # A text-only prompt's embeds are a gather of its ids, and
+            # the prompt is in the cache now: a live request would
+            # otherwise hold [1, bucket, H] twice over (100 + 113 MB at
+            # a 6k prompt 6144 wide, 2.3 GB over a dozen live slots; my
+            # chip run, PR 31). `_place` gathers them again if an
+            # eviction or a restart replays the prefill.
+            req.embeds = req.embeds_p = req.embeds_np = None
         if self.block:
             self._activate_block(s, req)
             return
@@ -2987,10 +3035,16 @@ class ContinuousScheduler:
         toks, fin = self._harvest_chunk(
             tok, lengths, finished, recent, toks, fin
         )
+        share = None
+        if self.share_stats:
+            with self._phase("harvest", "blocked"):
+                share = np.asarray(out[-1])  # oryxlint: disable=host-sync
         dt = time.monotonic() - t0
         with self._phase("emit"):
             dev_us = self._profile_dispatch_end(sampled, "decode", t0_ns)
             self._record_numerics(nstats)
+            if share is not None:
+                self._count_share(share)
             live = [
                 s for s, r in enumerate(self.slots)
                 if r is not None and r.activated
@@ -3093,6 +3147,21 @@ class ContinuousScheduler:
         m.inc("moe_expert_rows_mean_total",
               stats["moe_rows_routed"] / max(1, self.cfg.llm.num_experts))
         m.inc("moe_experts_hit_total", stats["moe_experts_hit"])
+
+    def _count_share(self, stats) -> None:
+        """The moe_* and decode_kv_tokens families for one decode
+        dispatch (`stats`: generate.SHARE_STATS, summed over its
+        steps)."""
+        st = dict(zip(generate_lib.SHARE_STATS, (int(x) for x in stats)))
+        count = self.cfg.llm.held[1]
+        m = self.metrics
+        m.inc("moe_pairs_total", st["pairs"])
+        m.inc("moe_zero_pairs_total", st["zero_pairs"])
+        m.inc("moe_held_experts_hit_total", st["held_hit"])
+        m.inc("moe_held_expert_slots_total", st["layer_forwards"] * count)
+        m.inc("moe_expert_rows_max_total", st["held_rows_max"])
+        m.inc("moe_expert_rows_mean_total", st["held_rows"] / count)
+        m.inc("decode_kv_tokens_total", st["kv_tokens"])
 
     def _count_dispatch(self, kind: str, rows: int, *temps) -> None:
         """ONE device dispatch happened: its kind, its rows, and whether
@@ -4017,6 +4086,8 @@ class ContinuousScheduler:
         # Output-audit sampling: every Nth finished request queues a
         # shadow-parity replay job (host copies only; the replay runs
         # later, at an idle point of this same thread).
+        if self.auditor.sample_every:
+            self._ensure_embeds(req)
         self.auditor.observe_finished(req)
         _LOG.info(
             "request %s finished (%s, %d tokens)",

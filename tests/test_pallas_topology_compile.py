@@ -356,6 +356,97 @@ def test_block_step_passes_the_expert_kernels_whole(one_chip, mosaic):
     assert memory.temp_size_in_bytes < one_expert_tensor // 4
 
 
+def test_latent_page_walk_compiles_at_the_cell_geometry(one_chip):
+    """`_latent_paged` at the LongCat-Flash cell's decode geometry: 64
+    rows of 64 heads against the one shared 640-wide key a token (576
+    values and the pad to whole lane tiles), page 64, 96 pages a row,
+    the 8 cache layers of 4 model layers in one flat pool: blocks of 8
+    pages (512 x 640 bf16, two in flight) and the [64, 512] logit tile
+    inside VMEM, the value read as the block's first 512 columns."""
+    from oryx_tpu.ops.pallas import paged_attention as ppa
+
+    rows, heads, Dp, ps, maxp = 64, 64, 640, 64, 96
+    text = _compiled_text(
+        lambda q, pages, bt, lens: ppa.latent_decode_attention(
+            q, pages, bt, lens, scale=192 ** -0.5, value_dim=512,
+            interpret=False),
+        one_chip, ((rows, heads, Dp), BF16),
+        ((8 * rows * maxp, ps, Dp), BF16), ((rows, maxp), jnp.int32),
+        ((rows,), jnp.int32),
+    )
+    assert "gather" not in text
+
+
+@pytest.mark.parametrize("program", ["paged_decode_chunk", "paged_prefill"])
+def test_latent_serve_program_keeps_the_pool_in_place(
+    one_chip, mosaic, program
+):
+    """The two programs of the latent-attention model at the published
+    LongCat-Flash widths, depth 1 (two cache layers), 64 slots x 6144,
+    vocabulary 1024 as above: the donated latent pool is aliased to the
+    output, the decode step holds no gather of it (the absorbed product
+    walks the pages in place) and its temporaries stay under one cache
+    layer's plane; the prefill chunk's stay under the two (it holds the
+    up-projected keys and values of one row's 6144-token prefix, 0.3
+    GB, by design: the expanded path); the grouped expert products are
+    the Mosaic grouped matmul over the held experts' kernels as they
+    lie."""
+    import dataclasses
+
+    from oryx_tpu import config as cfg_lib
+    from oryx_tpu.models import generate, qwen2
+
+    def on_chip(make):
+        return jax.tree_util.tree_map(
+            lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=one_chip),
+            jax.eval_shape(make),
+        )
+
+    cfg = dataclasses.replace(
+        cfg_lib.longcat_flash_chat_ep32().llm, num_layers=1, vocab_size=1024)
+    slots, page_size, ctx = 64, 64, 6144
+    S = slots if program == "paged_decode_chunk" else 1
+    rows = lambda dtype, *tail: jax.ShapeDtypeStruct(  # noqa: E731
+        (S, *tail), dtype, sharding=one_chip
+    )
+    params = on_chip(
+        lambda: qwen2.init_params(cfg, jax.random.key(0), dtype=BF16))
+    kv = on_chip(lambda: qwen2.init_paged_kv_cache(
+        cfg, slots * ctx // page_size, page_size, dtype=BF16))
+    tables = rows(jnp.int32, ctx // page_size)
+    sampling = (
+        on_chip(lambda: jax.random.split(jax.random.key(0), S)),
+        rows(jnp.float32), rows(jnp.float32), rows(jnp.int32),
+    )
+    common = dict(attn_impl="pallas", compute_dtype=BF16)
+    if program == "paged_decode_chunk":
+        lowered = generate.paged_decode_chunk.lower(
+            params, cfg, kv, tables, rows(jnp.int32), rows(jnp.int32),
+            rows(jnp.bool_), rows(jnp.int32, 0), *sampling,
+            chunk=8, eos=2, **common,
+        )
+    else:
+        lowered = generate.paged_prefill.lower(
+            params, cfg, rows(BF16, 1024, cfg.hidden_size), rows(jnp.int32),
+            tables, kv, rows(jnp.int32), *sampling, **common,
+        )
+    compiled = lowered.compile()
+    text = compiled.as_text()
+    assert "tpu_custom_call" in text and "ragged-dot" not in text
+    memory = compiled.memory_analysis()
+    pool_bytes = sum(
+        a.size * a.dtype.itemsize for a in jax.tree_util.tree_leaves(kv)
+    )
+    assert memory.alias_size_in_bytes == pool_bytes
+    planes = 1 if program == "paged_decode_chunk" else 2
+    assert memory.temp_size_in_bytes < planes * pool_bytes // 2
+    if program == "paged_decode_chunk":
+        # No [S, max_len] copy of a cache layer: nothing of a row's 6144
+        # x 640 stream, for all 64 rows, is ever built.
+        assert f"bf16[{slots},{ctx},640]" not in text
+        assert f"bf16[{slots},{ctx // page_size},{page_size},640]" not in text
+
+
 def test_illegal_heads_per_block_pin_raises_with_its_name(
     one_chip, monkeypatch
 ):

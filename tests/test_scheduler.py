@@ -679,3 +679,75 @@ def test_sampler_sort_counter_counts_the_dispatches_with_a_sampled_row(
         assert dispatches == len(seen[kind])
         assert sorts == sum(seen[kind])
         assert 0 < sorts < dispatches
+
+
+@pytest.mark.parametrize("mode", ["split", "ragged", "speculate", "audit"])
+def test_released_prompt_embeds_are_gathered_again_for_a_replay(pipe, mode):
+    """A text-only request's prompt embeds are released when its
+    prefill ends (a live request would hold [1, bucket, H] twice over
+    for nothing: its prompt is in the cache). An eviction replays the
+    prefill and the auditor copies the embeds at the finish: both
+    gather them again from the request's ids, under every step program
+    that reads them (the split engine's `embeds` / `embeds_p`, the
+    ragged and speculative engines' host copy `embeds_np`), and the
+    replies stay the solo pipeline's, token for token."""
+    import math
+
+    q1, q2 = "hello there", "tell me more"
+    chunk, ps = 4, 16
+    ids1 = len(pipe._prepare_request({"question": q1})[0])
+    ids2 = len(pipe._prepare_request({"question": q2})[0])
+    admit1 = math.ceil((ids1 + chunk) / ps)
+    admit2 = math.ceil((ids2 + chunk) / ps)
+    cap = (admit1 * ps - ids1) + ps  # forces one extra page per row
+    engine = {
+        "split": {}, "audit": {"audit_sample_every": 1},
+        # A prompt prefills inside one fused dispatch, so the pressure
+        # comes once both rows are live.
+        "ragged": {"ragged": True, "prefill_chunk": 32},
+        "speculate": {"ragged": True, "prefill_chunk": 32, "speculate": 3},
+    }[mode]
+    metrics = ServingMetrics()
+    sched = ContinuousScheduler(
+        pipe, num_slots=2, page_size=ps, chunk=chunk, max_ctx=512,
+        num_pages=admit1 + admit2 + 1, metrics=metrics, autostart=False,
+        prefix_cache=False, **engine,
+    )
+    held, regathered = [], []
+    activate, ensure = sched._activate, sched._ensure_embeds
+
+    def activate_and_look(s, req, *rest):
+        activate(s, req, *rest)
+        held.append((req.embeds, req.embeds_p, req.embeds_np))
+
+    def ensure_and_count(req):
+        was = req.embeds is None
+        ensure(req)
+        assert req.embeds is not None
+        if was:
+            regathered.append(req.trace.id)
+
+    sched._activate, sched._ensure_embeds = activate_and_look, ensure_and_count
+    handles = [sched.submit({"question": q}, cap, None) for q in (q1, q2)]
+    sched.start()
+    results = [h.result(timeout=600) for h in handles]
+    if mode == "audit":
+        import time
+
+        deadline = time.monotonic() + 120
+        while (sum(sched.auditor.to_dict()["verdicts"].values()) < 2
+               and time.monotonic() < deadline):
+            time.sleep(0.05)
+        verdicts = sched.auditor.to_dict()["verdicts"]
+    sched.close()
+    assert metrics.get("evicted") >= 1
+    # Every activation (the replayed one too) left nothing held ...
+    assert len(held) >= 3 and all(h == (None, None, None) for h in held)
+    # ... and the replay (and each audited finish) gathered again.
+    assert len(regathered) >= (3 if mode == "audit" else 1)
+    for q, (reply, _, usage) in zip((q1, q2), results):
+        assert reply == pipe.chat(q, max_new_tokens=cap), q
+        assert usage[1] == cap
+    if mode == "audit":
+        assert verdicts["pass"] == 2 and not (
+            verdicts["fail"] or verdicts["drift"])

@@ -299,14 +299,30 @@ PARENT_JAXPRS = {
 }
 
 
-@pytest.mark.parametrize("branch", sorted(PARENT_JAXPRS))
-def test_dense_forward_jaxpr_is_the_parents(branch):
-    """Expert layer, q/k norm, block mask and routing outputs are chosen
-    by the config alone: with the new arguments at their defaults a
-    dense configuration traces to the parent's jaxpr, character for
-    character, on every cache branch."""
+# `sdar_tiny`'s own, taken the same way on c638234 (PR 31, before the
+# expert layer learned held ranges, zero-compute experts, a selection
+# bias and a scaling factor): at SDAR's values of those the generalised
+# `_moe` is the parent's, operation for operation. ("packed" asks for
+# the routing too.)
+SDAR_PARENT_JAXPRS = {
+    "no_cache": "fd78b44923debc73", "dense_cache": "f91c0814b3bd52fa",
+    "paged": "64ccb85b35d8a8ce", "packed": "fe0c17db9b7b8274",
+}
+
+
+@pytest.mark.parametrize("model, branch", [
+    (m, b) for m in ("oryx_tiny", "sdar_tiny") for b in sorted(PARENT_JAXPRS)
+])
+def test_dense_forward_jaxpr_is_the_parents(model, branch):
+    """Expert layer, q/k norm, block mask, routing outputs, latent
+    attention and the expert layer's share are chosen by the config
+    alone: with the new arguments at their defaults a dense
+    configuration, and SDAR's expert decoder, trace to the parent's
+    jaxpr, character for character, on every cache branch."""
     assert jax.config.jax_default_matmul_precision == "highest"
-    cfg = cfg_lib.oryx_tiny().llm
+    cfg = getattr(cfg_lib, model)().llm
+    golden = PARENT_JAXPRS if model == "oryx_tiny" else SDAR_PARENT_JAXPRS
+    routing = {"return_routing": True} if model == "sdar_tiny" else {}
     p = jax.eval_shape(lambda: qwen2.init_params(cfg, jax.random.key(0)))
     ids = jax.ShapeDtypeStruct((2, 8), jnp.int32)
     pos = lambda: jnp.zeros((2, 8), jnp.int32) + jnp.arange(8)  # noqa: E731
@@ -331,9 +347,9 @@ def test_dense_forward_jaxpr_is_the_parents(branch):
         seg = jax.ShapeDtypeStruct((1, 8), jnp.int32)
         jaxpr = jax.make_jaxpr(lambda p, i, kv, bt, s: qwen2.forward(
             p, cfg, input_ids=i, kv_cache=kv, block_tables=bt,
-            positions=s, q_segments=s))(p, seg, paged, bt, seg)
+            positions=s, q_segments=s, **routing))(p, seg, paged, bt, seg)
     digest = hashlib.sha256(str(jaxpr).encode()).hexdigest()[:16]
-    assert digest == PARENT_JAXPRS[branch]
+    assert digest == golden[branch]
 
 
 def test_sdar_moe_checkpoint_names_round_trip(tiny):
