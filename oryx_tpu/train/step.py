@@ -10,6 +10,21 @@ of GSPMD given the shardings from parallel/sharding.py; remat
 Grad accumulation: a `lax.scan` over leading-axis microbatches, averaging
 losses/grads in fp32 — equivalent to DeepSpeed's accumulate-then-step with
 no Python-side loop.
+
+What is differentiated: the leaves the recipe trains, and nothing else.
+`trainable(params, cfg.train.tune)` is `params` with every leaf that
+optimizer.trainable_mask freezes replaced by None; the loss takes that
+tree as its argument and reads the frozen leaves as constants. So under
+`tune="lora"` no weight gradient of a base kernel, of the head or of the
+embedding is traced (the activation gradients through them stay), and
+with no differentiated leaf upstream of the compressor the frozen vision
+tower has no backward at all; `projector_only` and `no_vision` follow by
+the same rule, and `tune="full"` (nothing frozen) traces the program it
+always did. The gradient tree, the accumulators of the microbatch scan,
+`grad_norm`, the non-finite guard and the numerics probes are over those
+leaves: `grad_norm` is the norm of what is trained (what HF Trainer logs
+under PEFT), the norm the optimizer's clip already saw. Frozen leaves
+leave the step as the buffers they came in as.
 """
 
 from __future__ import annotations
@@ -25,6 +40,7 @@ import optax
 from oryx_tpu.config import OryxConfig
 from oryx_tpu.models import oryx
 from oryx_tpu.train.loss import chunked_causal_lm_loss
+from oryx_tpu.train.optimizer import trainable_mask
 
 Params = dict[str, Any]
 
@@ -51,6 +67,22 @@ def init_state(
         step=jnp.zeros((), jnp.int32),
         params=params,
         opt_state=tx.init(params),
+    )
+
+
+def trainable(params: Params, tune: str) -> Params:
+    """`params` with every leaf `tune` freezes replaced by None (an empty
+    subtree): the tree train_step_fn differentiates."""
+    return jax.tree.map(
+        lambda m, p: p if m else None, trainable_mask(params, tune), params
+    )
+
+
+def _over(params: Params, leaves: Params) -> Params:
+    """A trainable()-shaped tree laid back over `params`: where it holds
+    None the result holds `params`' own leaf."""
+    return jax.tree.map(
+        lambda p, new: p if new is None else new, params, leaves
     )
 
 
@@ -118,9 +150,10 @@ def train_step_fn(
     numerics=True (STATIC — the Trainer samples it every
     `--numerics-every` steps, so at most two stable compiled programs
     exist) adds the utils/numerics.py probes to the metrics dict:
-    `act_absmax` (final hidden state), `grad_absmax` (whole grad
-    tree), `param_absmax`, and `grad_layer_absmax` ([L] over the
-    stacked decoder layers — the "which layer is exploding" vector).
+    `act_absmax` (final hidden state), `grad_absmax` (the gradients of
+    the trainable leaves), `param_absmax`, and `grad_layer_absmax` ([L]
+    over the stacked decoder layers' trainable leaves — the "which layer
+    is exploding" vector; absent when the recipe trains none of them).
     Params/opt-state updates are bit-identical either way (the probes
     only read values the step already computed).
 
@@ -132,13 +165,20 @@ def train_step_fn(
     microbatch_loss) so weight all-gathers ride bf16. Harmless when it
     merely mismatches the actual placement off-mesh (constrain no-ops).
 
+    tx: optimizer.make_optimizer(cfg.train, ...)'s — its freeze mask is
+    the one this step differentiates by, so it takes a gradient tree that
+    holds None at the frozen leaves.
+
     Callers with explicit state shardings (Trainer) jit this with
     out_shardings pinned to the input state's shardings — otherwise GSPMD
     may re-shard updated params to the optimizer-state sharding (e.g.
     ZeRO-2's replicated params silently become fsdp-sharded after step 1).
     """
+    train_p = trainable(state.params, cfg.train.tune)
     grad_fn = jax.value_and_grad(
-        lambda p, c, m: microbatch_loss(p, c, m, sharding_mode, numerics),
+        lambda p, c, m: microbatch_loss(
+            _over(state.params, p), c, m, sharding_mode, numerics
+        ),
         has_aux=True,
     )
     accum = jax.tree.leaves(batch)[0].shape[0]
@@ -153,7 +193,7 @@ def train_step_fn(
         # param-sized temp — ~17 GB/device for 34B on an 8-way mesh).
         with jax.named_scope("forward_backward"):
             (loss_sum, metrics), grads = grad_fn(
-                state.params, cfg, jax.tree.map(lambda x: x[0], batch)
+                train_p, cfg, jax.tree.map(lambda x: x[0], batch)
             )
             grads = jax.tree.map(lambda g: g.astype(jnp.float32), grads)
         ntok = metrics["num_tokens"]
@@ -162,7 +202,7 @@ def train_step_fn(
     else:
         def one_micro(carry, mb):
             grads_acc, loss_acc, ntok_acc = carry
-            (loss, metrics), grads = grad_fn(state.params, cfg, mb)
+            (loss, metrics), grads = grad_fn(train_p, cfg, mb)
             grads_acc = jax.tree.map(
                 lambda a, g: a + g.astype(jnp.float32), grads_acc, grads
             )
@@ -172,7 +212,7 @@ def train_step_fn(
 
         with jax.named_scope("forward_backward_accum"):
             zeros = jax.tree.map(
-                lambda p: jnp.zeros(p.shape, jnp.float32), state.params
+                lambda p: jnp.zeros(p.shape, jnp.float32), train_p
             )
             (grads, loss_sum, ntok), micro_metrics = jax.lax.scan(
                 one_micro,
@@ -188,7 +228,7 @@ def train_step_fn(
 
     with jax.named_scope("optimizer_update"):
         updates, opt_state = tx.update(grads, state.opt_state, state.params)
-        params = optax.apply_updates(state.params, updates)
+        new_p = optax.apply_updates(train_p, updates)
         gnorm = optax.global_norm(grads)
     metrics = {
         "loss": loss_sum / accum,
@@ -214,9 +254,8 @@ def train_step_fn(
         # state under GSPMD for no real saving, while the select fuses.
         with jax.named_scope("nonfinite_guard"):
             ok = jnp.isfinite(loss_sum) & jnp.isfinite(gnorm)
-            params = jax.tree.map(
-                lambda new, old: jnp.where(ok, new, old),
-                params, state.params,
+            new_p = jax.tree.map(
+                lambda new, old: jnp.where(ok, new, old), new_p, train_p
             )
             opt_state = jax.tree.map(
                 lambda new, old: (
@@ -227,7 +266,10 @@ def train_step_fn(
             )
             metrics["skipped"] = (~ok).astype(jnp.int32)
     return (
-        TrainState(step=state.step + 1, params=params, opt_state=opt_state),
+        TrainState(
+            step=state.step + 1, params=_over(state.params, new_p),
+            opt_state=opt_state,
+        ),
         metrics,
     )
 

@@ -60,6 +60,7 @@ STEP_TIME_BUCKETS = (0.05, 0.1, 0.25, 0.5, 1.0, 2.5, 5.0, 10.0, 30.0,
 TRAIN_GAUGES = (
     "loss", "grad_norm", "lr", "tokens_per_sec", "tokens_per_sec_per_chip",
     "mfu", "model_flops_per_sec", "goodput_ratio", "last_step",
+    "trainable_params", "differentiated_params",
 )
 
 
@@ -213,8 +214,11 @@ class TrainTelemetry:
         tps = tokens / dt
 
         g["loss"].set(loss if np.isfinite(loss) else float("nan"))
-        if "grad_norm" in metrics:
-            g["grad_norm"].set(float(metrics["grad_norm"]))
+        # grad_norm: every step; the two counts: the first record only.
+        for name in ("grad_norm", "trainable_params",
+                     "differentiated_params"):
+            if name in metrics:
+                g[name].set(float(metrics[name]))
         if lr is not None:
             g["lr"].set(float(lr))
         g["tokens_per_sec"].set(tps)

@@ -22,7 +22,11 @@ from oryx_tpu.parallel import mesh as mesh_lib
 from oryx_tpu.parallel import sharding
 from oryx_tpu.train import step as step_lib
 from oryx_tpu.train import telemetry as telemetry_lib
-from oryx_tpu.train.optimizer import make_optimizer, make_schedule
+from oryx_tpu.train.optimizer import (
+    make_optimizer,
+    make_schedule,
+    trainable_mask,
+)
 from oryx_tpu.utils import faults
 from oryx_tpu.utils import trace as trace_lib
 from oryx_tpu.utils.anomaly import AnomalyThresholds
@@ -88,8 +92,8 @@ class Trainer:
         # jitted step runs its probe-armed static twin — per-layer
         # grad absmax, activation/param absmax — feeding the
         # oryx_numerics_* gauges and the absmax_explosion detector.
-        # 0 = off (the default: the probe tree-maps the whole grad
-        # tree, which is cheap but not free on giant models).
+        # 0 = off (the default: the probe tree-maps the grad tree and
+        # the params, which is cheap but not free on giant models).
         if not isinstance(numerics_every, int) or numerics_every < 0:
             raise ValueError(
                 "numerics_every must be a non-negative integer (steps "
@@ -182,6 +186,23 @@ class Trainer:
                         f"— refusing to train a silently narrower adapter"
                     )
             self.tx = make_optimizer(cfg.train, params)
+            # How much the optimizer updates and how much the step
+            # differentiates (static per compiled step; equal since the
+            # step splits the tree by the optimizer's mask): they ride
+            # the first metric record and the oryx_train_* gauges.
+            mask = trainable_mask(params, cfg.train.tune)
+            self.param_counts = {
+                "trainable_params": sum(
+                    p.size for p, m in zip(
+                        jax.tree.leaves(params), jax.tree.leaves(mask)
+                    ) if m
+                ),
+                "differentiated_params": sum(
+                    p.size for p in jax.tree.leaves(
+                        step_lib.trainable(params, cfg.train.tune)
+                    )
+                ),
+            }
             pspecs = sharding.param_shardings(self.mesh, params, sharding_mode)
             params = sharding.shard_params(params, pspecs)
             opt_state = self.tx.init(params)
@@ -341,6 +362,7 @@ class Trainer:
 
             batches = prefetcher = PrefetchIterator(batches, depth=prefetch)
         consecutive_skipped = 0
+        first_record = self.param_counts
         # Where the loop's wall time goes, in exclusive seconds: what is
         # in no named phase is `log` (the metric record, telemetry,
         # numerics, the checkpoint decision), so the phases between two
@@ -429,6 +451,11 @@ class Trainer:
                     }
                     seen = dict(phase_s)
                     host_metrics.update(step_s)
+                    if first_record and (
+                        (step_i + 1) % self.logger.log_every == 0
+                    ):
+                        host_metrics.update(first_record)
+                        first_record = None
                     self.logger.log_step(step_i + 1, host_metrics)
                     if int(host_metrics.get("skipped", 0)):
                         consecutive_skipped += 1

@@ -16,8 +16,9 @@ model computes*. Two halves:
     argument, so arming it adds exactly one more stable compiled
     program per shape class — recompile-watchdog-clean.
   * **Tree probes for the trainer** (`tree_absmax` /
-    `stacked_layer_absmax`): grad/activation absmax — whole-tree and
-    per-stacked-layer — computed inside `train_step_fn` under the same
+    `stacked_layer_absmax`): grad/activation absmax — over the tree
+    and per-stacked-layer; the gradient tree is the trainable leaves'
+    (train/step.py) — computed inside `train_step_fn` under the same
     static `numerics` flag and returned through the step's metrics
     dict.
 

@@ -174,3 +174,52 @@ def test_trainer_rejects_packed_text_under_ring():
         dataclasses.replace(cfg_lib.oryx_tiny(), attn_impl="ring_flash"),
         {"token_ids": np.zeros((1, 2, 8), np.int32)},
     )
+
+
+@pytest.mark.parametrize("mode", ["one_device", "zero2", "ddp"])
+def test_lora_step_donates_state_and_aliases_frozen_leaves(tmp_path, mode):
+    """The compiled LoRA step consumes `state` (donated) and hands every
+    frozen leaf back in the device buffers it came in: the step rebuilds
+    the params tree from a trainable part and a frozen part, and a copy
+    of the base a step is what such a merge could cost (the static
+    use-after-donate rule cannot see that). `one_device` is the module's
+    own jit on one device (the single-chip LoRA recipe's layout), the
+    others the Trainer's pinned-sharding jit over the mesh. Not under
+    `fsdp` here: across the 8-device CPU mesh XLA hands three sharded
+    leaves another donated buffer of their type, whatever the step
+    differentiates."""
+    if jax.device_count() < 8:
+        pytest.skip("needs the 8-device CPU mesh (conftest)")
+    from oryx_tpu.train import step as step_lib
+    from oryx_tpu.train.optimizer import trainable_mask
+
+    cfg = _cfg(tmp_path, f"alias_{mode}")
+    cfg = dataclasses.replace(cfg, train=dataclasses.replace(
+        cfg.train, tune="lora",
+        lora=cfg_lib.LoraConfig(enable=True, r=4, alpha=8.0),
+    ))
+    trainer = Trainer(cfg, sharding_mode="ddp" if mode == "one_device"
+                      else mode)
+    old = trainer.state
+    if mode == "one_device":
+        old = jax.device_put(old, jax.devices()[0])
+
+    def pointers(x):
+        return [s.data.unsafe_buffer_pointer() for s in x.addressable_shards]
+
+    mask = jax.tree.leaves(trainable_mask(old.params, "lora"))
+    assert not all(mask) and any(mask)
+    before = [pointers(x) for x in jax.tree.leaves(old.params)]
+    if mode == "one_device":
+        batch = {k: jnp.asarray(v)[None] for k, v in _batch(cfg).items()}
+        state, _ = step_lib.train_step(old, batch, cfg, trainer.tx)
+    else:
+        state = trainer.fit(iter([_batch(cfg)]), num_steps=1, resume=False,
+                            prefetch=0)
+    trainer.close()
+    assert all(x.is_deleted() for x in jax.tree.leaves(old))
+    for (path, x), was, m in zip(
+        jax.tree_util.tree_flatten_with_path(state.params)[0], before, mask
+    ):
+        if not m:
+            assert pointers(x) == was, jax.tree_util.keystr(path)
