@@ -10,6 +10,7 @@ from __future__ import annotations
 
 import dataclasses
 import json
+import math
 import typing
 from dataclasses import dataclass, field
 from typing import Any
@@ -64,11 +65,14 @@ class LLMConfig:
     # head_dim are then unused. mla_scale_*: the family's modelling code
     # multiplies the normed q latent by sqrt(hidden / q_lora_rank) and
     # the normed kv latent by sqrt(hidden / kv_lora_rank). A latent
-    # model's layer is the shortcut-connected double layer: two
+    # model's layer is one of two. With shortcut_double_layer: two
     # (attention, dense FFN) sublayers in series with ONE expert layer
     # whose input is taken after the first attention and whose output is
-    # added after the second FFN (`qwen2._double_block`). The dense FFNs
-    # are `intermediate_size` wide, the experts moe_intermediate_size.
+    # added after the second FFN (`qwen2._double_block`); the dense FFNs
+    # are `intermediate_size` wide, the experts moe_intermediate_size,
+    # and a model layer is two cache layers. Without it: ONE latent
+    # attention and then the expert layer on one normed input
+    # (`qwen2._latent_block`), one cache layer a model layer.
     kv_lora_rank: int = 0
     q_lora_rank: int = 0
     qk_nope_head_dim: int = 0
@@ -76,6 +80,7 @@ class LLMConfig:
     v_head_dim: int = 0
     mla_scale_q_lora: bool = False
     mla_scale_kv_lora: bool = False
+    shortcut_double_layer: bool = False
     # RoPE pairs (x[2j], x[2j+1]) (the DeepSeek-V3 lineage) instead of
     # (x[j], x[j + D/2]).
     rope_interleaved: bool = False
@@ -92,10 +97,78 @@ class LLMConfig:
     # layer computes the held experts' and the zero-compute experts'
     # part of the result and leaves out what absent experts would add.
     experts_held: tuple[int, int] | None = None
+    # Shared experts: ONE SwiGLU of width n_shared_experts *
+    # moe_intermediate_size on every token, unweighted, added to the
+    # routed experts' sum (`qwen2._moe`); whole on every chip.
+    n_shared_experts: int = 0
+    # YaRN RoPE scaling (latent attention only) when rope_scaling_factor
+    # > 1: `ops/rope.yarn_frequencies` blends inv_freq / factor with
+    # inv_freq by a linear ramp between the correction dims of
+    # rope_beta_fast and rope_beta_slow rotations over
+    # rope_original_max_position positions. The three conventions the
+    # published keys do not settle live HERE and nowhere else
+    # (`softmax_scale`, `rope_cos_sin_scale`, `query_position_scale`).
+    rope_scaling_factor: float = 1.0
+    rope_original_max_position: int = 0
+    rope_beta_fast: float = 32.0
+    rope_beta_slow: float = 1.0
+    rope_mscale: float = 1.0
+    rope_mscale_all_dim: float = 0.0
+    # q <- q * (1 + beta * ln(1 + floor(pos / original))) when > 0.
+    llama4_scaling_beta: float = 0.0
 
     @property
     def latent(self) -> bool:
         return self.kv_lora_rank > 0
+
+    @property
+    def cache_layers(self) -> int:
+        """Cache layers of the paged pool: two a model layer in the
+        shortcut-connected double layer, else one."""
+        return self.num_layers * (2 if self.shortcut_double_layer else 1)
+
+    @property
+    def yarn(self) -> bool:
+        return self.rope_scaling_factor > 1.0
+
+    @staticmethod
+    def _yarn_mscale(factor: float, mscale: float) -> float:
+        return 1.0 if factor <= 1.0 else 0.1 * mscale * math.log(factor) + 1.0
+
+    @property
+    def softmax_scale(self) -> float:
+        """Latent attention's score scale: 1 / sqrt(dn + dr), times m * m
+        under YaRN with rope_mscale_all_dim, m = 0.1 * mscale_all_dim *
+        ln(factor) + 1 (the DeepSeek-V3 lineage's convention)."""
+        scale = (self.qk_nope_head_dim + self.qk_rope_head_dim) ** -0.5
+        if self.yarn and self.rope_mscale_all_dim:
+            m = self._yarn_mscale(
+                self.rope_scaling_factor, self.rope_mscale_all_dim)
+            scale *= m * m
+        return scale
+
+    @property
+    def rope_cos_sin_scale(self) -> float:
+        """Multiplier of YaRN's cos / sin tables: mscale(factor, mscale)
+        / mscale(factor, mscale_all_dim)."""
+        if not self.yarn:
+            return 1.0
+        f = self.rope_scaling_factor
+        return self._yarn_mscale(f, self.rope_mscale) / self._yarn_mscale(
+            f, self.rope_mscale_all_dim)
+
+    def query_position_scale(self, positions):
+        """The query's scale by position, float32, or None without
+        llama4_scaling_beta: 1 + beta * ln(1 + floor(pos / original)),
+        1 below the original length and stepping at its multiples.
+        `positions` is a numpy or jax integer array."""
+        if not self.llama4_scaling_beta:
+            return None
+        import jax.numpy as jnp
+
+        steps = (positions // self.rope_original_max_position).astype(
+            jnp.float32)
+        return 1.0 + self.llama4_scaling_beta * jnp.log1p(steps)
 
     @property
     def held(self) -> tuple[int, int]:
@@ -161,9 +234,36 @@ class LLMConfig:
             or self.attention_bias or self.tie_word_embeddings
         ):
             raise ValueError(
-                "latent attention is built for the shortcut-connected "
-                "double layer only (num_experts > 0), without attention "
-                "bias, q/k norm, tied embeddings or block diffusion"
+                "latent attention is built for an expert decoder "
+                "(num_experts > 0: the shortcut-connected double layer or "
+                "the single latent block), without attention bias, q/k "
+                "norm, tied embeddings or block diffusion"
+            )
+        if not self.latent and (
+            self.shortcut_double_layer or self.yarn
+            or self.llama4_scaling_beta
+        ):
+            raise ValueError(
+                "shortcut_double_layer, RoPE scaling (rope_scaling_factor "
+                "> 1) and llama4_scaling_beta are built for latent "
+                "attention only (kv_lora_rank > 0)"
+            )
+        if self.n_shared_experts < 0 or (
+            self.n_shared_experts and (
+                not self.latent or self.shortcut_double_layer)
+        ):
+            raise ValueError(
+                "n_shared_experts is built for the single latent block "
+                "(kv_lora_rank > 0, num_experts > 0, no "
+                "shortcut_double_layer), got "
+                f"n_shared_experts={self.n_shared_experts}"
+            )
+        if (self.yarn or self.llama4_scaling_beta) and not (
+            self.rope_original_max_position > 0
+        ):
+            raise ValueError(
+                "RoPE scaling and llama4_scaling_beta need "
+                "rope_original_max_position > 0"
             )
 
 
@@ -587,6 +687,7 @@ def longcat_flash_chat() -> OryxConfig:
             v_head_dim=128,
             mla_scale_q_lora=True,
             mla_scale_kv_lora=True,
+            shortcut_double_layer=True,
             rope_interleaved=True,
             zero_experts=256,
             routed_scaling_factor=6.0,
@@ -641,6 +742,7 @@ def longcat_tiny() -> OryxConfig:
             v_head_dim=16,
             mla_scale_q_lora=True,
             mla_scale_kv_lora=True,
+            shortcut_double_layer=True,
             rope_interleaved=True,
             zero_experts=4,
             routed_scaling_factor=6.0,
@@ -650,6 +752,114 @@ def longcat_tiny() -> OryxConfig:
         vision=None,
         # Past the vocabulary, as the share's preset: seeded weights
         # would sample a real id once in 512 tokens.
+        generation=GenerationConfig(eos_token_id=512),
+        dtype="float32",
+    )
+
+
+def mistral_small_4() -> OryxConfig:
+    """Mistral-Small-4-119B-2603's language model (mistralai,
+    config.json, `model_type: mistral4`): 36 layers alike, each ONE
+    latent attention (latent 256 + a shared roped key of 64, 32 heads of
+    64 | 64 keys and 128 values) and then a shared SwiGLU of 2048 beside
+    128 routed experts of 2048, 4 a token, renormalised; YaRN positions
+    (factor 128 over 8,192) to 1,048,576. Text-only: the catalog's row
+    holds no size of the vision encoder. What the keys do not settle
+    (softmax routing, the score scale's m * m, the query's scale by
+    position) is `LLMConfig.softmax_scale` / `query_position_scale` and
+    the configuration file's `assumed`."""
+    return OryxConfig(
+        llm=LLMConfig(
+            vocab_size=131072,
+            hidden_size=4096,
+            intermediate_size=12288,  # published, unused: no dense layer
+            num_layers=36,
+            num_heads=32,
+            num_kv_heads=32,  # published, unused: one shared latent a token
+            head_dim=128,  # published qk_head_dim = 64 + 64
+            rope_theta=10_000.0,
+            rms_norm_eps=1e-6,
+            max_position_embeddings=1_048_576,
+            attention_bias=False,
+            num_experts=128,
+            num_experts_per_tok=4,
+            moe_intermediate_size=2048,
+            norm_topk_prob=True,
+            kv_lora_rank=256,
+            q_lora_rank=1024,
+            qk_nope_head_dim=64,
+            qk_rope_head_dim=64,
+            v_head_dim=128,
+            rope_interleaved=True,
+            routed_scaling_factor=1.0,
+            n_shared_experts=1,
+            rope_scaling_factor=128.0,
+            rope_original_max_position=8192,
+            rope_beta_fast=32.0,
+            rope_beta_slow=1.0,
+            rope_mscale=1.0,
+            rope_mscale_all_dim=1.0,
+            llama4_scaling_beta=0.1,
+        ),
+        vision=None,
+        generation=GenerationConfig(eos_token_id=2),
+    )
+
+
+def mistral_small_4_ep4() -> OryxConfig:
+    """One chip's share of Mistral-Small-4 where 4 chips share each
+    layer: 32 of the 128 routed experts held (the router keeps its 128
+    outputs and its 4 a token), attention and the shared expert whole, a
+    quarter of the vocabulary (rows 0..32767). The end-of-sequence id is
+    the first row another chip holds, as in `longcat_flash_chat_ep32`."""
+    cfg = mistral_small_4()
+    return dataclasses.replace(
+        cfg,
+        llm=dataclasses.replace(
+            cfg.llm, vocab_size=32768, experts_held=(0, 32)),
+        generation=dataclasses.replace(cfg.generation, eos_token_id=32768),
+    )
+
+
+def mistral4_tiny() -> OryxConfig:
+    """Tiny single-latent-block expert decoder for tests: 8 routed
+    experts of which 4 are held, one shared expert, YaRN over an
+    original length of 16 so that a prompt of a hundred tokens crosses
+    several steps of the query's scale by position."""
+    return OryxConfig(
+        llm=LLMConfig(
+            vocab_size=512,
+            hidden_size=64,
+            intermediate_size=128,  # unused
+            num_layers=2,
+            num_heads=4,
+            num_kv_heads=4,
+            head_dim=24,
+            rope_theta=10000.0,
+            rms_norm_eps=1e-6,
+            max_position_embeddings=2048,
+            attention_bias=False,
+            num_experts=8,
+            num_experts_per_tok=2,
+            moe_intermediate_size=32,
+            norm_topk_prob=True,
+            kv_lora_rank=32,
+            q_lora_rank=48,
+            qk_nope_head_dim=16,
+            qk_rope_head_dim=8,
+            v_head_dim=16,
+            rope_interleaved=True,
+            experts_held=(2, 4),
+            n_shared_experts=1,
+            rope_scaling_factor=128.0,
+            rope_original_max_position=16,
+            rope_beta_fast=32.0,
+            rope_beta_slow=1.0,
+            rope_mscale=1.0,
+            rope_mscale_all_dim=1.0,
+            llama4_scaling_beta=0.1,
+        ),
+        vision=None,
         generation=GenerationConfig(eos_token_id=512),
         dtype="float32",
     )

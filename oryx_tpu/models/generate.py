@@ -477,7 +477,8 @@ def generate_stream(
 
 @partial(
     jax.jit,
-    static_argnames=("cfg", "attn_impl", "compute_dtype", "return_routing"),
+    static_argnames=("cfg", "attn_impl", "compute_dtype", "return_routing",
+                     "held_stats"),
     donate_argnames=("kv_pages",),
 )
 def paged_prefill(
@@ -496,6 +497,7 @@ def paged_prefill(
     attn_impl: str = "xla",
     compute_dtype=None,
     return_routing: bool = False,
+    held_stats: bool = False,
 ):
     """Prompt prefill into a PAGED cache + first sampled token.
 
@@ -508,7 +510,11 @@ def paged_prefill(
     return_routing (a static twin for the benchmark's comparison) the
     expert layers' routing, `qwen2.forward`'s, as a fourth value, with
     the [B, V] logits the first token was sampled from under
-    "logits"."""
+    "logits". held_stats (static; a config whose expert layer holds a
+    share, `cfg.experts_held`) appends, before that, the
+    [len(SHARE_STATS)] int32 `share_stats` of the chunk's REAL rows
+    (`kv_tokens` counts them), which the scheduler's moe_prefill_*
+    counters read."""
     B, T, _ = inputs_embeds.shape
     start = jnp.broadcast_to(start.astype(jnp.int32), (B,))
     positions = start[:, None] + jnp.arange(T, dtype=jnp.int32)[None, :]
@@ -523,7 +529,7 @@ def paged_prefill(
         kv_cache=kv_pages, write_slots=start, kv_mask=kv_mask,
         block_tables=block_tables, kv_lengths=lengths,
         attn_impl=attn_impl, compute_dtype=compute_dtype,
-        return_routing=return_routing,
+        return_routing=return_routing or held_stats,
     )
     last = jnp.take_along_axis(
         logits, (lengths - 1 - start)[:, None, None].astype(jnp.int32),
@@ -533,8 +539,14 @@ def paged_prefill(
     tok0 = sample_token_rows(
         last, pair[:, 1], temperature=temperature, top_p=top_p, top_k=top_k
     )
-    return (kv_pages, tok0, pair[:, 0],
-            *(dict(r, logits=last) for r in routing))
+    out = (kv_pages, tok0, pair[:, 0])
+    if held_stats:
+        # The router saw rows [B * T]; a row past the prompt is padding.
+        real = (positions < lengths[:, None]).reshape(-1).astype(jnp.int32)
+        out = out + (share_stats(cfg, routing[0]["ids"], real),)
+    if return_routing:
+        out = out + (dict(routing[0], logits=last),)
+    return out
 
 
 @partial(jax.jit, static_argnames=("width",))

@@ -29,7 +29,7 @@ from jax.ad_checkpoint import checkpoint_name
 from oryx_tpu.config import LLMConfig
 from oryx_tpu.ops.attention import attention
 from oryx_tpu.ops.norms import rms_norm
-from oryx_tpu.ops.rope import apply_rope, rope_cos_sin
+from oryx_tpu.ops.rope import apply_rope, rope_cos_sin, yarn_frequencies
 from oryx_tpu.parallel.sharding import constrain
 from oryx_tpu.utils.remat import wrap_remat
 
@@ -105,9 +105,12 @@ def init_params(
 
 
 def _init_latent_params(cfg: LLMConfig, key: jax.Array, dtype) -> Params:
-    """The latent-attention double layer's weights (`_double_block`).
-    `layers["sub0"]` and `["sub1"]` hold the two (attention, dense FFN)
-    sublayers, every leaf [L, ...] so that the layer scan slices a
+    """A latent-attention model's weights. The double layer's
+    (`_double_block`): `layers["sub0"]` / `["sub1"]` hold the two
+    (attention, dense FFN) sublayers; the single block's
+    (`_latent_block`): the one attention's leaves lie in `layers` itself
+    and `layers["shared"]` is the shared expert. Every leaf is
+    [L, ...], so the layer scan slices a
     kernel out as it lies; projections that the published checkpoint
     keeps fused are stored as the parts they are used in (a fused
     kernel sliced by column inside a step is copied whole, every
@@ -132,8 +135,8 @@ def _init_latent_params(cfg: LLMConfig, key: jax.Array, dtype) -> Params:
             jax.random.normal(next(keys), shape, jnp.float32) * scale
         ).astype(dt)
 
-    def sublayer():
-        return {
+    def sublayer(ffn=True):
+        sub = {
             "input_norm": {"weight": jnp.ones((L, H), dtype)},
             "post_attn_norm": {"weight": jnp.ones((L, H), dtype)},
             "q_a_proj": {"kernel": dense((L, H, Rq))},
@@ -146,21 +149,37 @@ def _init_latent_params(cfg: LLMConfig, key: jax.Array, dtype) -> Params:
             "w_uk": dense((L, Hq, dn, R)),
             "w_uv": dense((L, Hq, R, dv)),
             "o_proj": {"kernel": dense((L, Hq * dv, H))},
-            "gate_proj": {"kernel": dense((L, H, I))},
-            "up_proj": {"kernel": dense((L, H, I))},
-            "down_proj": {"kernel": dense((L, I, H))},
         }
+        if ffn:
+            sub.update({
+                "gate_proj": {"kernel": dense((L, H, I))},
+                "up_proj": {"kernel": dense((L, H, I))},
+                "down_proj": {"kernel": dense((L, I, H))},
+            })
+        return sub
 
-    layers = {
-        "sub0": sublayer(),
-        "sub1": sublayer(),
+    if cfg.shortcut_double_layer:
+        layers = {"sub0": sublayer(), "sub1": sublayer()}
+    else:
+        # The single latent block (`_latent_block`): one attention, no
+        # dense FFN; the shared expert is ONE SwiGLU of n_shared_experts
+        # * moe_intermediate_size beside the routed experts.
+        layers = sublayer(ffn=False)
+        if cfg.n_shared_experts:
+            Is = cfg.n_shared_experts * Ie
+            layers["shared"] = {
+                "gate_proj": {"kernel": dense((L, H, Is))},
+                "up_proj": {"kernel": dense((L, H, Is))},
+                "down_proj": {"kernel": dense((L, Is, H))},
+            }
+    layers.update({
         "router": {"kernel": dense((L, H, E + Z), jnp.float32)},
         "experts": {
             "gate": dense((L, count, H, Ie)),
             "up": dense((L, count, H, Ie)),
             "down": dense((L, count, Ie, H)),
         },
-    }
+    })
     if cfg.router_bias:
         layers["router"]["bias"] = dense(
             (L, E + Z), jnp.float32, scale=0.2 / (E + Z)
@@ -210,15 +229,16 @@ def init_paged_kv_cache(
     as L*P pages while it runs (see its `block_tables` contract) and
     hands it back in this layout."""
     if cfg.latent:
-        # One plane, two cache layers a model layer, no head axis: a
-        # token's row is (latent | roped shared key | zeros to whole
-        # lane tiles). See ops/paged_kv.LATENT.
+        # One plane of cfg.cache_layers layers (two a model layer in the
+        # double layer, else one), no head axis: a token's row is
+        # (latent | roped shared key | zeros to whole lane tiles). See
+        # ops/paged_kv.LATENT.
         from oryx_tpu.ops import paged_kv
 
         if kv_dtype not in (None, "bf16", "fp"):
             raise ValueError(unsupported_for_latent(f"kv_dtype={kv_dtype!r}"))
         return {paged_kv.LATENT: jnp.zeros(
-            (2 * cfg.num_layers, num_pages, page_size, cfg.latent_page_dim),
+            (cfg.cache_layers, num_pages, page_size, cfg.latent_page_dim),
             dtype,
         )}
     shape = (
@@ -237,12 +257,13 @@ def init_paged_kv_cache(
 
 def unsupported_for_latent(mode: str) -> str:
     """The one refusal of a mode that is not built for a latent-attention
-    (MLA) config: its pool is one plane of latents with no head axis and
-    two cache layers a model layer, which only the split engine's
-    `paged_prefill` / `paged_decode_chunk` read."""
+    (MLA) config: its pool is one plane of latents with no head axis
+    (two cache layers a model layer in the double layer, else one),
+    which only the split engine's `paged_prefill` / `paged_decode_chunk`
+    read."""
     return (
         f"latent attention (kv_lora_rank > 0): {mode} is not built for a "
-        "latent pool (one [2L, P, page, latent] plane, no K/V heads); it "
+        "latent pool (one [layers, P, page, latent] plane, no K/V heads); it "
         "serves through the continuous split engine with a bf16 pool only"
     )
 
@@ -408,7 +429,8 @@ def _grouped_dot(rows: jnp.ndarray, kernels: jnp.ndarray,
 
 def _moe(cfg: LLMConfig, x: jnp.ndarray, router_kernel: jnp.ndarray,
          experts: Params, layer: jnp.ndarray, impl: str = "xla",
-         router_bias: jnp.ndarray | None = None):
+         router_bias: jnp.ndarray | None = None,
+         shared: Params | None = None):
     """Sparse expert MLP on x [N, H]: dropless, no capacity factor, no
     padding to a capacity. The N*K (token, expert) pairs are sorted by
     expert and the gate, up and down products run as grouped products
@@ -436,8 +458,26 @@ def _moe(cfg: LLMConfig, x: jnp.ndarray, router_kernel: jnp.ndarray,
     of x, no product. With every expert held and none zero-compute this
     is the plain layer, operation for operation.
 
+    `shared` (cfg.n_shared_experts): one layer's gate / up / down of the
+    shared expert, ONE SwiGLU (`_swiglu`) on every row, unweighted and
+    whole on every chip, added to the routed sum in float32: a decode
+    step reads its kernels once whatever the lanes.
+
     Returns (y [N, H], routing: {"counts": rows of each held expert
     [count] int32, "ids": the chosen experts [N, K] int32})."""
+    with jax.named_scope("moe_routed"):
+        y, idx, counts = _moe_routed(
+            cfg, x, router_kernel, experts, layer, impl, router_bias)
+    if shared is not None:
+        with jax.named_scope("moe_shared"):
+            y = y + _swiglu(x, shared).astype(jnp.float32)
+    return y.astype(x.dtype), {"counts": counts, "ids": idx}
+
+
+def _moe_routed(cfg: LLMConfig, x, router_kernel, experts, layer, impl,
+                router_bias):
+    """The routed (and zero-compute) part of `_moe`, float32:
+    (y [N, H], ids [N, K], held experts' row counts [count])."""
     N, K, E = x.shape[0], cfg.num_experts_per_tok, cfg.num_experts
     first, count = cfg.held
     whole = count == E and not cfg.zero_experts
@@ -472,7 +512,7 @@ def _moe(cfg: LLMConfig, x: jnp.ndarray, router_kernel: jnp.ndarray,
     if cfg.zero_experts:
         zero_w = jnp.sum(jnp.where(idx >= E, w, 0.0), axis=-1)
         y = y + zero_w[:, None] * x.astype(jnp.float32)
-    return y.astype(x.dtype), {"counts": counts, "ids": idx}
+    return y, idx, counts
 
 
 def _block(
@@ -645,7 +685,7 @@ def _mla_expanded(cfg: LLMConfig, q_nope, q_rope, c, kr, w_uk, w_uv, *,
         k_nope, jnp.broadcast_to(kr[:, :, None, :], (*k_nope.shape[:3], dr)),
     ], axis=-1)
     q = jnp.concatenate([q_nope, q_rope], axis=-1)
-    scale = (dn + dr) ** -0.5
+    scale = cfg.softmax_scale
     if impl == "pallas":
         from oryx_tpu.ops.pallas import flash_attention as _fa
 
@@ -707,6 +747,12 @@ def _mla(cfg: LLMConfig, a: jnp.ndarray, p: Params, cos, sin, *,
     rope = apply_rope_interleaved if cfg.rope_interleaved else (
         lambda x, cos, sin: apply_rope(x, x, cos, sin)[0])
     q_rope = rope(q_rope, cos, sin)
+    q_scale = cfg.query_position_scale(positions)
+    if q_scale is not None:
+        # The query's scale by position, on both parts of every head.
+        q_scale = q_scale[:, :, None, None]
+        q_nope = (q_nope * q_scale).astype(a.dtype)
+        q_rope = (q_rope * q_scale).astype(a.dtype)
     kr = rope(_linear(a, p["k_rope_proj"])[:, :, None], cos, sin)[:, :, 0]
     w_uk, w_uv = p["w_uk"].astype(a.dtype), p["w_uv"].astype(a.dtype)
     if pool is None:
@@ -738,7 +784,7 @@ def _mla(cfg: LLMConfig, a: jnp.ndarray, p: Params, cos, sin, *,
             else:
                 decode = paged_kv.latent_decode_attention
             o_lat = decode(
-                qf, pool, tables, kv_lengths, scale=(dn + dr) ** -0.5,
+                qf, pool, tables, kv_lengths, scale=cfg.softmax_scale,
                 value_dim=R,
             )
             o = jnp.einsum("bhc,hcd->bhd", o_lat, w_uv)[:, None]
@@ -766,11 +812,12 @@ def _double_block(cfg: LLMConfig, h, lp: Params, cos, sin, *, pool, tables,
     for i in (0, 1):
         sub = lp[f"sub{i}"]
         a = rms_norm(h, sub["input_norm"]["weight"], eps)
-        att, pool = _mla(
-            cfg, a, sub, cos, sin, pool=pool,
-            tables=None if tables is None else tables[i],
-            attn_impl=attn_impl, **attn,
-        )
+        with jax.named_scope("mla"):
+            att, pool = _mla(
+                cfg, a, sub, cos, sin, pool=pool,
+                tables=None if tables is None else tables[i],
+                attn_impl=attn_impl, **attn,
+            )
         h = h + att
         x = rms_norm(h, sub["post_attn_norm"]["weight"], eps)
         if i == 0:
@@ -780,6 +827,32 @@ def _double_block(cfg: LLMConfig, h, lp: Params, cos, sin, *, pool, tables,
             )
         h = h + _swiglu(x, sub)
     return h + s.reshape(B, T, -1), pool, routing
+
+
+def _latent_block(cfg: LLMConfig, h, lp: Params, cos, sin, *, pool, tables,
+                  experts: tuple, attn_impl: str, **attn):
+    """One single latent block on h [B, T, H]: latent attention, then
+    the expert layer (the shared expert on every token beside the
+    routed experts) on ONE normed input. `tables`: a one-element list,
+    the layer's block table (cache layer l), or None with no pool.
+    Returns (h, pool, the expert layer's routing)."""
+    B, T, _ = h.shape
+    eps = cfg.rms_norm_eps
+    a = rms_norm(h, lp["input_norm"]["weight"], eps)
+    with jax.named_scope("mla"):
+        att, pool = _mla(
+            cfg, a, lp, cos, sin, pool=pool,
+            tables=None if tables is None else tables[0],
+            attn_impl=attn_impl, **attn,
+        )
+    h = h + att
+    x = rms_norm(h, lp["post_attn_norm"]["weight"], eps)
+    y, routing = _moe(
+        cfg, x.reshape(B * T, -1), lp["router"]["kernel"], *experts,
+        impl=attn_impl, router_bias=lp["router"].get("bias"),
+        shared=lp.get("shared"),
+    )
+    return h + y.reshape(B, T, -1), pool, routing
 
 
 def forward(
@@ -884,10 +957,19 @@ def forward(
 
     if positions is None:
         positions = jnp.broadcast_to(jnp.arange(T, dtype=jnp.int32), (B, T))
+    rope_dim = cfg.qk_rope_head_dim if cfg.latent else cfg.head_dim
+    scaling = {}
+    if cfg.yarn:
+        scaling = dict(
+            inv_freq=yarn_frequencies(
+                rope_dim, cfg.rope_theta, factor=cfg.rope_scaling_factor,
+                original=cfg.rope_original_max_position,
+                beta_fast=cfg.rope_beta_fast, beta_slow=cfg.rope_beta_slow,
+            ),
+            scale=cfg.rope_cos_sin_scale,
+        )
     cos, sin = rope_cos_sin(
-        positions, cfg.qk_rope_head_dim if cfg.latent else cfg.head_dim,
-        cfg.rope_theta,
-    )  # [B,T,D]
+        positions, rope_dim, cfg.rope_theta, **scaling)  # [B,T,D]
 
     if kv_cache is not None and write_slots is None:
         write_slots = positions[:, 0]
@@ -1021,10 +1103,13 @@ def forward(
             write_mask=write_mask, kv_lengths=kv_lengths,
         )
         num_l = jnp.arange(cfg.num_layers, dtype=jnp.int32)
+        # Cache layers a model layer, and the block that reads them.
+        per = 2 if cfg.shortcut_double_layer else 1
+        latent_block = _double_block if per == 2 else _latent_block
         if kv_cache is None:
             def body(h, xs):
                 lp, layer = xs
-                h, _, routing = _double_block(
+                h, _, routing = latent_block(
                     cfg, h, lp, cos, sin, pool=None, tables=None,
                     experts=(experts_flat, layer), attn_impl=attn_impl,
                     **attn,
@@ -1035,21 +1120,21 @@ def forward(
                 wrap_remat(body, remat), h, (layers, num_l)
             )
         else:
-            # The pool is the scan's carry, one flat [2L*P, page, Dp]
+            # The pool is the scan's carry, one flat [Lc*P, page, Dp]
             # buffer behind layer-offset tables, as below; cache layer
-            # 2l + i belongs to sublayer i of model layer l.
+            # per * l + i belongs to sublayer i of model layer l.
             pool = kv_cache[paged_kv.LATENT]
-            L2, P = pool.shape[:2]
+            Lc, P = pool.shape[:2]
 
             def body(carry, xs):
                 h, pool = carry
                 lp, layer = xs
                 tables = [
-                    jnp.where(block_tables >= P, L2 * P,
-                              block_tables + (2 * layer + i) * P)
-                    for i in (0, 1)
+                    jnp.where(block_tables >= P, Lc * P,
+                              block_tables + (per * layer + i) * P)
+                    for i in range(per)
                 ]
-                h, pool, routing = _double_block(
+                h, pool, routing = latent_block(
                     cfg, h, lp, cos, sin, pool=pool, tables=tables,
                     experts=(experts_flat, layer), attn_impl=attn_impl,
                     **attn,
@@ -1058,11 +1143,11 @@ def forward(
 
             (h, pool), expert_counts = jax.lax.scan(
                 wrap_remat(body, remat),
-                (h, pool.reshape((L2 * P,) + pool.shape[2:])),
+                (h, pool.reshape((Lc * P,) + pool.shape[2:])),
                 (layers, num_l.astype(block_tables.dtype)),
             )
             new_cache = {
-                paged_kv.LATENT: pool.reshape((L2, P) + pool.shape[1:])
+                paged_kv.LATENT: pool.reshape((Lc, P) + pool.shape[1:])
             }
     elif kv_cache is not None and block_tables is not None:
         # Paged pool: the scan's CARRY, one flat [L*P, page, ...] buffer a

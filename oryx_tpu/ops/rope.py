@@ -12,6 +12,8 @@ precision catastrophically past ~4k positions).
 
 from __future__ import annotations
 
+import math
+
 import jax.numpy as jnp
 
 
@@ -21,18 +23,59 @@ def rope_frequencies(head_dim: int, theta: float) -> jnp.ndarray:
     return 1.0 / (theta**exponents)
 
 
+def yarn_correction_range(
+    head_dim: int, theta: float, original: int, beta_fast: float,
+    beta_slow: float,
+) -> tuple[int, int]:
+    """(low, high) pair indices of YaRN's ramp: the pairs that make
+    beta_fast and beta_slow whole rotations over `original` positions,
+    floored and ceiled and held inside 0..head_dim - 1."""
+
+    def dim_of(rotations: float) -> float:
+        return head_dim * math.log(
+            original / (rotations * 2 * math.pi)) / (2 * math.log(theta))
+
+    low = max(math.floor(dim_of(beta_fast)), 0)
+    high = min(math.ceil(dim_of(beta_slow)), head_dim - 1)
+    return low, high
+
+
+def yarn_frequencies(
+    head_dim: int, theta: float, *, factor: float, original: int,
+    beta_fast: float = 32.0, beta_slow: float = 1.0,
+) -> jnp.ndarray:
+    """YaRN inv_freq, shape [head_dim // 2], float32: pair j keeps its
+    own frequency below the ramp's low index (it turns beta_fast times
+    or more over the original length), takes frequency / factor above
+    the high index (it turns less than beta_slow times), and the linear
+    blend of the two between."""
+    inv_freq = rope_frequencies(head_dim, theta)
+    low, high = yarn_correction_range(
+        head_dim, theta, original, beta_fast, beta_slow)
+    ramp = (jnp.arange(head_dim // 2, dtype=jnp.float32) - low) / max(
+        high - low, 0.001)
+    keep = 1.0 - jnp.clip(ramp, 0.0, 1.0)  # 1: the pair's own frequency
+    return inv_freq / factor * (1.0 - keep) + inv_freq * keep
+
+
 def rope_cos_sin(
-    positions: jnp.ndarray, head_dim: int, theta: float
+    positions: jnp.ndarray, head_dim: int, theta: float, *,
+    inv_freq: jnp.ndarray | None = None, scale: float = 1.0,
 ) -> tuple[jnp.ndarray, jnp.ndarray]:
     """cos/sin tables for integer positions.
 
     positions: [...], int32. Returns (cos, sin) each [..., head_dim] in
     float32, with the HF "duplicated halves" layout: angles repeated as
-    concat([freqs, freqs]) along the last dim.
+    concat([freqs, freqs]) along the last dim. `inv_freq` replaces the
+    plain frequencies (`yarn_frequencies`), `scale` multiplies both
+    tables (YaRN's mscale ratio).
     """
-    inv_freq = rope_frequencies(head_dim, theta)
+    if inv_freq is None:
+        inv_freq = rope_frequencies(head_dim, theta)
     angles = positions.astype(jnp.float32)[..., None] * inv_freq  # [..., hd/2]
     angles = jnp.concatenate([angles, angles], axis=-1)  # [..., hd]
+    if scale != 1.0:
+        return jnp.cos(angles) * scale, jnp.sin(angles) * scale
     return jnp.cos(angles), jnp.sin(angles)
 
 

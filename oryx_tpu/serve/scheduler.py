@@ -171,6 +171,23 @@ _LOG = logging.getLogger("oryx.serve.scheduler")
 # implicitly available below the ladder.
 FUSE_AUTO_LADDER: tuple[int, ...] = (4, 16)
 
+# Positions below which the split prefill keeps ONE block-table width
+# (`prefill_table_buckets`).
+PREFILL_TABLE_MIN = 8192
+
+
+def prefill_table_buckets(max_pages: int, page_size: int) -> tuple[int, ...]:
+    """Block-table widths, in pages, that `paged_prefill` is compiled
+    for: powers of two of positions from PREFILL_TABLE_MIN up to the
+    table's own width, which is always the last. A table of
+    PREFILL_TABLE_MIN positions or fewer has one width, its own."""
+    out, n = [], PREFILL_TABLE_MIN
+    while n < max_pages * page_size:
+        out.append(-(-n // page_size))
+        n *= 2
+    return tuple(out) + (max_pages,)
+
+
 # The engine loop's phases (utils/profiling.PhaseClock): the labels of
 # oryx_serving_engine_phase_seconds_total{phase=} and, prefixed
 # `oryx.engine.`, the host events a profiler capture holds. Exclusive
@@ -259,6 +276,10 @@ class _Request:
     # Filled at first admission; cached so an evicted request never
     # re-runs the host-side prompt/media prep.
     embeds: Any = None
+    # Prompt token that `embeds[:, 0]` belongs to: 0, or on the chunked
+    # split path the end of the spliced prefix at the gather
+    # (scheduler._ensure_embeds).
+    embeds_base: int = 0
     length: int = 0
     key0: Any = None
     # Ragged mode: host copy of `embeds` made once at first prefill, so
@@ -513,6 +534,21 @@ class ContinuousScheduler:
                 max_ctx,
             )
         self.prefill_chunk = prefill_chunk
+        # Block-table widths (pages) a split prefill chunk is dispatched
+        # with: the narrowest that covers what the chunk reads and
+        # writes, so a chunk costs what its live prefix costs and not
+        # what max_ctx would. Powers of two of positions from
+        # PREFILL_TABLE_MIN up, the last the table's own width; a
+        # context of PREFILL_TABLE_MIN or less keeps ONE program, the
+        # whole table. One compiled `paged_prefill` a width: a warm-up
+        # has to reach each (a prompt near max_ctx passes through all).
+        self.table_buckets = prefill_table_buckets(
+            self.max_pages, page_size)
+        # The chunked split path holds a text-only prompt's embeds from
+        # its first uncached token on (`_ensure_embeds`): the cached
+        # prefix is never prefilled. (The ragged step and the single
+        # -shot prefill index the whole prompt.)
+        self._suffix_embeds = prefill_chunk is not None and not ragged
         # Ragged mode (docs/DESIGN.md "Ragged paged attention"): one
         # fused dispatch per engine step — `chunk` packed forwards,
         # each carrying every decode slot (1 token) plus `pf_width`
@@ -638,6 +674,14 @@ class ContinuousScheduler:
             "oryx_pool_kv_dtype", {"kv_dtype": kv_dtype}, raw_name=True
         )
         reg.counter("prefill_tokens_total")
+        # What the split prefill's attention needs against what it is
+        # handed, per chunk (`_advance_prefill`): query-key pairs of the
+        # chunk's causal attention, the positions its keys span (the
+        # live prefix, once a chunk), and dispatched rows x the
+        # positions of the block table the program ran with.
+        reg.counter("prefill_attn_pairs_total")
+        reg.counter("prefill_live_positions_total")
+        reg.counter("prefill_table_positions_total")
         reg.histogram("prefill_chunk_tokens", PREFILL_CHUNK_BUCKETS)
         # Dispatch accounting: how many device dispatches each engine
         # step pays (the ragged path's whole claim is kind="ragged"
@@ -711,6 +755,25 @@ class ContinuousScheduler:
         # the HELD experts' rows.
         self.share_stats = bool(
             pipe.cfg.llm.experts_held or pipe.cfg.llm.zero_experts)
+        if pipe.cfg.llm.n_shared_experts:
+            # Rows the shared expert computed, a layer-forward each.
+            reg.counter("moe_shared_rows_total")
+        # Prefill chunks count their held experts too (`paged_prefill`'s
+        # held_stats), on the single latent block alone: the shortcut-
+        # connected double layer's prefill is the program its accepted
+        # cell was measured on, and one more output is another program.
+        self.prefill_held_stats = bool(
+            pipe.cfg.llm.experts_held
+            and not pipe.cfg.llm.shortcut_double_layer)
+        # A device array a chunk, until a read that waits anyway: the
+        # decode chunk's harvest or a prompt's first token, a round
+        # later at most (`_drain_prefill_held`).
+        self._prefill_held: list = []  # thread-owned: engine
+        if self.prefill_held_stats:
+            reg.counter("moe_prefill_pairs_total")
+            reg.counter("moe_prefill_held_rows_total")
+            reg.counter("moe_prefill_held_experts_hit_total")
+            reg.counter("moe_prefill_held_expert_slots_total")
         if self.share_stats:
             reg.counter("moe_pairs_total")
             reg.counter("moe_zero_pairs_total")
@@ -1420,6 +1483,7 @@ class ContinuousScheduler:
         self.bt[:] = self._sentinel
         self._oom_episode = False
         self.slots = [None] * self.num_slots
+        self._prefill_held = []
         self.finished[:] = True
         self.lengths[:] = 0
         self.tok[:] = 0
@@ -2296,12 +2360,6 @@ class ContinuousScheduler:
                     # Patch packing, staging and the enqueue of
                     # mm_embeds (or of the embedding gather); nothing
                     # here waits for the device.
-                    with self._phase("embed"), self.pipe._mesh_scope():
-                        req.embeds, req.length = (
-                            self.pipe._prompt_embeds(
-                                self.cfg, ids, imgs, factors, caps
-                            )
-                        )
                     # Text-only prompts key the prefix cache by
                     # token ids (ids == the logical KV stream);
                     # multimodal streams key visual slots
@@ -2309,6 +2367,17 @@ class ContinuousScheduler:
                     req.cache_tokens = (
                         None if imgs else np.asarray(ids, np.int64)
                     )
+                    if self._suffix_embeds and not imgs:
+                        # Gathered at `_place`, from the first token
+                        # the prefix cache does not hold.
+                        req.length = len(ids)
+                    else:
+                        with self._phase("embed"), self.pipe._mesh_scope():
+                            req.embeds, req.length = (
+                                self.pipe._prompt_embeds(
+                                    self.cfg, ids, imgs, factors, caps
+                                )
+                            )
                 s_ = req.sampling
                 req.temp = float(
                     s_.get("temperature", gen.temperature) or 0.0
@@ -2544,15 +2613,22 @@ class ContinuousScheduler:
             ))
         return True
 
-    def _ensure_embeds(self, req: _Request) -> None:
-        """Gather a text-only prompt's embeds again where `_activate`
-        released them (an eviction or a restart replays the prefill; the
-        auditor copies them at the finish)."""
-        if req.embeds is None:
+    def _ensure_embeds(self, req: _Request, base: int = 0) -> None:
+        """Gather a text-only prompt's embeds from token `base` on where
+        they are not held: `_activate` released them (an eviction or a
+        restart replays the prefill; the auditor copies the whole prompt
+        at the finish), the chunked split path has not gathered them yet
+        (`_suffix_embeds`: base is the spliced prefix's end, so a
+        prompt's cached part is never held), or what is held starts
+        past `base` (a replay that found less in the cache)."""
+        if req.embeds is None or req.embeds_base > base:
             with self._phase("embed"), self.pipe._mesh_scope():
                 req.embeds, _ = self.pipe._prompt_embeds(
-                    self.cfg, req.cache_tokens.tolist(), None, None, None
+                    self.cfg, req.cache_tokens[base:].tolist(),
+                    None, None, None,
                 )
+            req.embeds_base = base
+            req.embeds_p = req.embeds_np = None
 
     def _place(self, s: int, req: _Request) -> None:
         """Claim slot s for `req` (pages already spliced+grown) and
@@ -2570,7 +2646,8 @@ class ContinuousScheduler:
             req.qw_span = -1
         if req.adm_span < 0:
             req.adm_span = req.trace.begin("admission", replay=True)
-        self._ensure_embeds(req)
+        suffix_only = self._suffix_embeds and req.cache_tokens is not None
+        self._ensure_embeds(req, req.spliced if suffix_only else 0)
         self.slots[s] = req
         req.activated = False
         self.finished[s] = True
@@ -2674,23 +2751,39 @@ class ContinuousScheduler:
                     req.embeds_p = generate_lib.pad_embeds_for_chunks(
                         req.embeds, width
                     )
+                    if req.cache_tokens is not None:
+                        # The chunks read the padded copy alone; a text
+                        # prompt's gather comes again if anything asks
+                        # (`_ensure_embeds`): [1, 32768, H] once, not
+                        # twice, while a document prefills.
+                        req.embeds = None
                 emb = generate_lib.slice_embeds(
-                    req.embeds_p, jnp.asarray(off, jnp.int32), width=width,
+                    req.embeds_p,
+                    jnp.asarray(off - req.embeds_base, jnp.int32),
+                    width=width,
                 )
                 end = min(off + width, L)
+            # The narrowest table that holds every position the chunk
+            # reads or writes (its padding is written too).
+            reach = -(-(off + emb.shape[1]) // self.page_size)
+            table = next(
+                (b for b in self.table_buckets if b >= reach),
+                self.max_pages,
+            )
             pf = req.trace.begin(
                 "prefill", slot=s, start=off, tokens=end - off,
                 cached=req.spliced > 0, replay=req.replay > 0,
+                table_positions=table * self.page_size,
             )
             sampled = self._profile_dispatch_begin()
             t0 = time.monotonic()
             t0_ns = trace_lib.now_ns()
             with self.pipe._mesh_scope():
-                kv, tok0, key = generate_lib.paged_prefill(
+                kv, tok0, key, *held = generate_lib.paged_prefill(
                     self.pipe.params["llm"], self.cfg.llm,
                     emb,
                     jnp.asarray([end], np.int32),
-                    jnp.asarray(self.bt[s][B1]),
+                    jnp.asarray(self.bt[s, :table][B1]),
                     self.kv_pages,
                     jnp.asarray([off], np.int32),
                     req.key0[B1],
@@ -2699,12 +2792,28 @@ class ContinuousScheduler:
                     jnp.asarray([req.topk], np.int32),
                     attn_impl=self.cfg.attn_impl,
                     compute_dtype=oryx.compute_dtype(self.cfg),
+                    **({"held_stats": True} if self.prefill_held_stats
+                       else {}),
                 )
             req.trace.end(pf)
         self.kv_pages = kv
+        self._prefill_held += held  # read later: a dispatch waits for nothing
         req.prefill_pos = end
         req.cost_prefill_tokens += end - off
         self.metrics.inc("prefill_tokens_total", end - off)
+        # Token p attends positions 0..p: the chunk's causal pairs.
+        self.metrics.inc(
+            "prefill_attn_pairs_total", (end - off) * (off + end + 1) // 2)
+        self.metrics.inc("prefill_live_positions_total", end)
+        self.metrics.inc(
+            "prefill_table_positions_total",
+            emb.shape[1] * table * self.page_size,
+        )
+        if self.cfg.llm.n_shared_experts:
+            self.metrics.inc(
+                "moe_shared_rows_total",
+                (end - off) * self.cfg.llm.num_layers,
+            )
         self.metrics.observe(
             "prefill_chunk_tokens", end - off,
             buckets=PREFILL_CHUNK_BUCKETS,
@@ -2754,6 +2863,7 @@ class ContinuousScheduler:
         # before it, must finish before this read returns.
         with self._phase("first_token", "blocked"):
             self.tok[s] = int(np.asarray(tok0)[0])
+        self._drain_prefill_held()  # ready: the first token was read
         if req.adm_span >= 0:
             req.trace.end(req.adm_span)
             req.adm_span = -1
@@ -3039,6 +3149,7 @@ class ContinuousScheduler:
         if self.share_stats:
             with self._phase("harvest", "blocked"):
                 share = np.asarray(out[-1])  # oryxlint: disable=host-sync
+        self._drain_prefill_held()  # enqueued before this chunk: ready
         dt = time.monotonic() - t0
         with self._phase("emit"):
             dev_us = self._profile_dispatch_end(sampled, "decode", t0_ns)
@@ -3162,6 +3273,29 @@ class ContinuousScheduler:
         m.inc("moe_expert_rows_max_total", st["held_rows_max"])
         m.inc("moe_expert_rows_mean_total", st["held_rows"] / count)
         m.inc("decode_kv_tokens_total", st["kv_tokens"])
+        if self.cfg.llm.n_shared_experts:
+            m.inc(
+                "moe_shared_rows_total",
+                st["pairs"] // self.cfg.llm.num_experts_per_tok,
+            )
+
+    def _drain_prefill_held(self) -> None:
+        """The moe_prefill_* families for the prefill chunks dispatched
+        since the last drain (each `generate.SHARE_STATS` of a chunk's
+        real rows; one transfer a chunk, of work that is done): the
+        (token, expert) pairs, those that landed on an expert held
+        here, and the held experts that took a row of the slots a
+        layer-forward has."""
+        pending, self._prefill_held = self._prefill_held, []
+        m = self.metrics
+        for stats in pending:
+            st = dict(zip(generate_lib.SHARE_STATS,
+                          (int(x) for x in np.asarray(stats))))
+            m.inc("moe_prefill_pairs_total", st["pairs"])
+            m.inc("moe_prefill_held_rows_total", st["held_rows"])
+            m.inc("moe_prefill_held_experts_hit_total", st["held_hit"])
+            m.inc("moe_prefill_held_expert_slots_total",
+                  st["layer_forwards"] * self.cfg.llm.held[1])
 
     def _count_dispatch(self, kind: str, rows: int, *temps) -> None:
         """ONE device dispatch happened: its kind, its rows, and whether

@@ -447,6 +447,85 @@ def test_latent_serve_program_keeps_the_pool_in_place(
         assert f"bf16[{slots},{ctx // page_size},{page_size},640]" not in text
 
 
+def test_mistral_small_4_cell_fits_the_chip_by_the_half_gigabyte_rule(
+    one_chip, mosaic
+):
+    """The two programs of `mistral-small-4.doc-qa` at the cell's full
+    size (depth 6, 32 of 128 experts, 32,768 rows of vocabulary, 16
+    slots x 32,768 positions, page 64, chunk 1024), compiled for the
+    described v5e. ISSUE 33's rule for the depth: arguments + the decode
+    program's temporaries + the WIDEST prefill table's temporaries must
+    leave 0.5 GB of the chip's 15.75 GB, else depth 5. They leave 1.33
+    GB (13.268 + 0.055 + 1.096). And the reason for the table's widths:
+    a chunk against 8,192 positions holds a fifth of the temporaries of
+    one against all 32,768 (0.20 against 1.10 GB: the expanded keys and
+    values of every position of the table, whatever the prompt's
+    length). This file, not tests/benchmark/test_bench_rehearsal_docqa.py,
+    because only one process may load the TPU's compiler."""
+    import dataclasses
+
+    from oryx_tpu import config as cfg_lib
+    from oryx_tpu.models import generate, qwen2
+    from oryx_tpu.serve import scheduler
+
+    def on_chip(make):
+        return jax.tree_util.tree_map(
+            lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=one_chip),
+            jax.eval_shape(make),
+        )
+
+    cfg = dataclasses.replace(cfg_lib.mistral_small_4_ep4().llm, num_layers=6)
+    slots, page_size, ctx = 16, 64, 32768
+    widths = scheduler.prefill_table_buckets(ctx // page_size, page_size)
+    assert widths == (128, 256, 512)
+    params = on_chip(
+        lambda: qwen2.init_params(cfg, jax.random.key(0), dtype=BF16))
+    kv = on_chip(lambda: qwen2.init_paged_kv_cache(
+        cfg, slots * ctx // page_size, page_size, dtype=BF16))
+    nbytes = lambda tree: sum(  # noqa: E731
+        a.size * a.dtype.itemsize for a in jax.tree_util.tree_leaves(tree))
+    pool_bytes = nbytes(kv)
+    assert pool_bytes == 6 * slots * ctx * 384 * 2  # 2.416 GB
+    assert 10.84e9 < nbytes(params) < 10.86e9
+
+    def rows(S, dtype, *tail):
+        return jax.ShapeDtypeStruct((S, *tail), dtype, sharding=one_chip)
+
+    def sampling(S):
+        return (on_chip(lambda: jax.random.split(jax.random.key(0), S)),
+                rows(S, jnp.float32), rows(S, jnp.float32),
+                rows(S, jnp.int32))
+
+    common = dict(attn_impl="pallas", compute_dtype=BF16)
+    S = slots
+    decode = generate.paged_decode_chunk.lower(
+        params, cfg, kv, rows(S, jnp.int32, ctx // page_size),
+        rows(S, jnp.int32), rows(S, jnp.int32), rows(S, jnp.bool_),
+        rows(S, jnp.int32, 0), *sampling(S), chunk=8, eos=32768, **common,
+    ).compile()
+    text = decode.as_text()
+    assert "tpu_custom_call" in text and "ragged-dot" not in text
+    # The absorbed walk: no [S, max_len] copy of a cache layer.
+    assert f"bf16[{slots},{ctx},384]" not in text
+    temps = {}
+    for width in (widths[0], widths[-1]):
+        prefill = generate.paged_prefill.lower(
+            params, cfg, rows(1, BF16, 1024, cfg.hidden_size),
+            rows(1, jnp.int32), rows(1, jnp.int32, width), kv,
+            rows(1, jnp.int32), *sampling(1), held_stats=True, **common,
+        ).compile()  # as the engine dispatches it for a share of experts
+        memory = prefill.memory_analysis()
+        assert memory.alias_size_in_bytes == pool_bytes
+        temps[width] = memory.temp_size_in_bytes
+    memory = decode.memory_analysis()
+    assert memory.alias_size_in_bytes == pool_bytes
+    assert memory.temp_size_in_bytes < 0.1e9
+    assert temps[widths[0]] < temps[widths[-1]] / 4
+    total = (nbytes(params) + pool_bytes + memory.temp_size_in_bytes
+             + temps[widths[-1]])
+    assert total <= 15.75e9 - 0.5e9, total
+
+
 def test_illegal_heads_per_block_pin_raises_with_its_name(
     one_chip, monkeypatch
 ):

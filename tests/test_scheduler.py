@@ -720,9 +720,9 @@ def test_released_prompt_embeds_are_gathered_again_for_a_replay(pipe, mode):
         activate(s, req, *rest)
         held.append((req.embeds, req.embeds_p, req.embeds_np))
 
-    def ensure_and_count(req):
+    def ensure_and_count(req, *base):
         was = req.embeds is None
-        ensure(req)
+        ensure(req, *base)
         assert req.embeds is not None
         if was:
             regathered.append(req.trace.id)
