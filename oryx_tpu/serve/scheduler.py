@@ -360,6 +360,24 @@ class _Request:
     adm_span: int = -1
 
 
+@dataclasses.dataclass
+class _BlockFlight:
+    """What one enqueued `paged_block_step` was given, kept until its
+    harvest: by then the host's arrays have moved on (the next block is
+    enqueued, slots were freed and filled), so the harvest reads the
+    tokens against this record."""
+    toks: Any  # [S, B] on the device
+    counts: dict[str, Any]  # generate.paged_block_step's counts
+    # slot -> admit_seq of the request it rode for: a row whose slot
+    # holds another placement at the harvest is dropped.
+    riders: dict[int, int]
+    known: np.ndarray  # blk_known as handed
+    temp: np.ndarray  # the temperatures as handed
+    t0_ns: int  # enqueue (trace_lib.now_ns)
+    sampled: bool  # a periodic device-time sample brackets it
+    captured: bool  # a capture brackets it: read at once, alone
+
+
 class ContinuousScheduler:
     """Slot map + admission queue + paged KV pool around one pipeline.
 
@@ -744,6 +762,12 @@ class ContinuousScheduler:
             reg.counter("diffusion_blocks_total")
             reg.counter("diffusion_forwards_total", ("kind",))
             reg.counter("diffusion_tokens_unmasked_total")
+            # One block in flight (DESIGN.md "Block diffusion"): blocks
+            # enqueued while another was unread (against dispatches_
+            # total{kind="block"}: the share that hid the host), and
+            # slot-blocks computed for a placement that had ended.
+            reg.counter("block_dispatches_ahead_total")
+            reg.counter("block_rows_dropped_total")
             reg.counter("moe_rows_routed_total")
             reg.counter("moe_expert_rows_max_total")
             reg.counter("moe_expert_rows_mean_total")
@@ -850,6 +874,11 @@ class ContinuousScheduler:
         # device fills the rest with the mask id) and how many are known.
         self.blk = np.zeros((S, max(self.block, 1)), np.int32)
         self.blk_known = np.zeros((S,), np.int32)
+        # The block enqueued and not yet read (at most one between two
+        # engine steps), and when the last harvest returned: the
+        # device begins a block enqueued ahead about then.
+        self._inflight: _BlockFlight | None = None  # thread-owned: engine
+        self._blk_read_ns = 0  # thread-owned: engine
         self._ragged_blanks = None
         if self.ragged:
             # The pure-decode shape class's constant prefill operands,
@@ -1490,6 +1519,7 @@ class ContinuousScheduler:
         self.recent[:] = -2
         self.blk[:] = 0
         self.blk_known[:] = 0
+        self._inflight = None  # its pool is gone
         self._check_pool_invariant()
 
     def _check_pool_invariant(self) -> None:
@@ -2210,6 +2240,12 @@ class ContinuousScheduler:
         for s, req in enumerate(self.slots):
             if req is None or req.deadline is None or now <= req.deadline:
                 continue
+            if self._inflight is not None:
+                # Block mode: the block in flight is read first, and
+                # may have ended the request by itself.
+                self._drain_block()
+                if self.slots[s] is not req:
+                    continue
             self.metrics.inc("deadline_exceeded_total")
             self._finish_error(
                 s,
@@ -2973,6 +3009,13 @@ class ContinuousScheduler:
             if self.slots[s] is None or self.finished[s]:
                 continue  # freed or evicted by an earlier iteration
             while not self._grow_slot(s, int(self.lengths[s]) + win):
+                if self._inflight is not None:
+                    # Block mode under page pressure: read the block in
+                    # flight before anyone is evicted (its finishes may
+                    # free the pages; a victim's replay count holds its
+                    # tokens), then look again.
+                    self._drain_block()
+                    return self._ensure_capacity(horizon)
                 me = self.slots[s].admit_seq
                 younger = [
                     v for v in order
@@ -3168,32 +3211,74 @@ class ContinuousScheduler:
 
     # hot-path
     def _block_step(self) -> None:
-        """Block mode's engine step: ONE `paged_block_step` dispatch in
+        """Block mode's engine step: enqueue ONE `paged_block_step`, in
         which every live slot generates its open block by diffusion
         (T denoising forwards + one commit forward of num_slots x B
-        lanes), then the one harvest. The block's new tokens go through
-        `_advance` in position order, as one emission (one SSE chunk a
-        block); EOS and max_tokens cut inside it. A slot that goes on
-        opens an all-masked block at its new length."""
+        lanes), THEN read the block enqueued a step earlier. One block
+        is always in flight, so the harvest, `emit` and the next
+        round's housekeeping, admission and prefill enqueue run while
+        the device works (docs/DESIGN.md "Block diffusion": what the
+        host changes in a slot takes effect at the next enqueue; a
+        harvest drops rows whose placement has ended; rare paths
+        drain first)."""
         faults.fault_point("decode_dispatch")
         hot_dispatch("scheduler._block_step")
+        prev, self._inflight = self._inflight, None
+        if prev is not None and (
+            self._profile_active is not None or self.profiler.due_next()
+        ):
+            # A capture's window holds its own block alone.
+            self._harvest_block(prev)
+            prev = None
+        self._inflight = self._enqueue_block(ahead=prev is not None)
+        if prev is not None:
+            self._harvest_block(prev)
+        if self._inflight is not None and (
+            self._inflight.captured
+            or not any(r is not None and r.activated for r in self.slots)
+        ):
+            # Nobody is left to ride a next block (every rider ended
+            # on an EOS, a stop or a cancel): the engine goes idle
+            # with nothing in flight.
+            self._drain_block()
+
+    # hot-path
+    def _enqueue_block(self, ahead: bool) -> _BlockFlight | None:
+        """Enqueue one block for every slot that rides it and move the
+        host's state to where the block will leave it: lengths past
+        the block, the next block all masked, and a slot whose request
+        reaches max_tokens inside this block off the next one (the
+        host counts; EOS, a stop and a cancel are learned from the
+        harvest, one block late). None when no slot rides."""
+        riders = {
+            s: r.admit_seq for s, r in enumerate(self.slots)
+            if r is not None and r.activated and not self.finished[s]
+        }
+        if not riders:
+            return None
         gen = self.cfg.generation
+        # Copies: the host changes its arrays while the block is in
+        # flight, and on the CPU `jnp.asarray` shares the numpy buffer.
+        known, temp = self.blk_known.copy(), self.temp.copy()
+
+        def handed(a):
+            return jnp.asarray(a.copy())
+
         with self._phase("denoise", "dispatch"):
             sampled = self._profile_dispatch_begin()
-            t0 = time.monotonic()
             t0_ns = trace_lib.now_ns()
             with self.pipe._mesh_scope():
                 out = generate_lib.paged_block_step(
                     self.pipe.params["llm"], self.cfg.llm, self.kv_pages,
-                    jnp.asarray(self.bt),
-                    jnp.asarray(self.blk),
-                    jnp.asarray(self.blk_known),
-                    jnp.asarray(self.lengths),
-                    jnp.asarray(self.finished),
+                    handed(self.bt),
+                    handed(self.blk),
+                    jnp.asarray(known),
+                    handed(self.lengths),
+                    handed(self.finished),
                     self.keys,
-                    jnp.asarray(self.temp),
-                    jnp.asarray(self.top_p),
-                    jnp.asarray(self.top_k),
+                    jnp.asarray(temp),
+                    handed(self.top_p),
+                    handed(self.top_k),
                     steps=gen.denoising_steps or self.block,
                     remasking=gen.remasking,
                     threshold=gen.confidence_threshold,
@@ -3202,45 +3287,87 @@ class ContinuousScheduler:
                     compute_dtype=oryx.compute_dtype(self.cfg),
                 )
         self.kv_pages, toks, _, _, _, self.keys, counts = out
+        self.lengths[~self.finished] += self.block  # the device's `live`
+        self.blk[:] = 0
+        self.blk_known[:] = 0
+        for s in riders:
+            req = self.slots[s]
+            if int(self.lengths[s]) - req.length >= req.max_new:
+                self.finished[s] = True  # its last block is this one
+        if ahead:
+            self.metrics.inc("block_dispatches_ahead_total")
+        return _BlockFlight(
+            toks=toks, counts=counts, riders=riders, known=known,
+            temp=temp, t0_ns=t0_ns, sampled=sampled,
+            captured=sampled or self._profile_active is not None,
+        )
+
+    def _drain_block(self) -> None:
+        """Read the block in flight now. The rare paths call this
+        before they act (an eviction, a deadline's error, going idle,
+        a capture's window edge), so that they see the engine as a
+        step without a block in flight leaves it."""
+        flight, self._inflight = self._inflight, None
+        if flight is not None:
+            self._harvest_block(flight)
+
+    # hot-path
+    def _harvest_block(self, flight: _BlockFlight) -> None:
+        """The one harvest of a block, against the record of what it
+        was given. Its new tokens go through `_advance` in position
+        order, as one emission (one SSE chunk a block); EOS and
+        max_tokens cut inside it. The rows of a slot that no longer
+        holds the placement it rode for are dropped."""
         self.metrics.inc("harvest_total")
-        with self._phase("harvest", "blocked"):
+        # With a block enqueued behind this one the device has work
+        # when the wait returns: no `host` event opens.
+        with self._phase(
+            "harvest", "blocked" if self._inflight is None else "wait"
+        ):
             # Three blocking copies (each costs about a millisecond
             # once the first has waited, PERF.md section 6, PR 24): the
             # tokens, the five statistics as one array, and the
             # per-slot forwards. Lengths advance on the host.
             # oryxlint: off=host-sync
-            toks = np.asarray(toks)
+            toks = np.asarray(flight.toks)
             stats = dict(zip(
                 generate_lib.BLOCK_STATS,
-                (int(x) for x in np.asarray(counts["stats"])),
+                (int(x) for x in np.asarray(flight.counts["stats"])),
             ))
-            slot_forwards = np.asarray(counts["slot_forwards"])
+            slot_forwards = np.asarray(flight.counts["slot_forwards"])
             # oryxlint: on=host-sync
-        dt = time.monotonic() - t0
+        # Harvest to harvest, the pace a client feels: a block
+        # enqueued ahead began when the one before it was read.
+        t0_ns = max(flight.t0_ns, self._blk_read_ns)
+        self._blk_read_ns = trace_lib.now_ns()
+        dt = (self._blk_read_ns - t0_ns) / 1e9
         with self._phase("emit"):
-            dev_us = self._profile_dispatch_end(sampled, "block", t0_ns)
+            dev_us = (
+                self._profile_dispatch_end(flight.sampled, "block", t0_ns)
+                if flight.captured else None
+            )
             live = [
-                s for s, r in enumerate(self.slots)
-                if r is not None and r.activated
+                s for s, seq in flight.riders.items()
+                if self.slots[s] is not None
+                and self.slots[s].admit_seq == seq
             ]
-            self.lengths[~self.finished] += self.block  # the device's `live`
-            known = self.blk_known.copy()
-            # A slot that goes on opens an all-masked block; one that
-            # finishes below is cleared by _finish.
-            self.blk[:] = 0
-            self.blk_known[:] = 0
+            self.metrics.inc(
+                "block_rows_dropped_total", len(flight.riders) - len(live)
+            )
             for s in live:
                 req = self.slots[s]
                 self._observe_ttft(req)
                 if req.adm_span >= 0:
                     req.trace.end(req.adm_span)
                     req.adm_span = -1
-            self._count_block_dispatch(len(live), stats)
+            self._count_block_dispatch(len(flight.riders), stats)
             self._finish_dispatch(
-                "block", len(live) * self.block, live,
-                {s: [int(t) for t in toks[s, known[s]:]] for s in live},
+                "block", len(flight.riders) * self.block, live,
+                {s: [int(t) for t in toks[s, flight.known[s]:]]
+                 for s in live},
                 t0_ns, dt, device_us=dev_us,
                 slot_forwards=slot_forwards, forwards=stats["forwards"],
+                temps=flight.temp,
             )
             self._occupancy_gauge()
 
@@ -3317,7 +3444,7 @@ class ContinuousScheduler:
     def _finish_dispatch(
         self, kind: str, rows: int, live: list[int], toks, t0_ns, dt,
         n_new=None, device_us=None, slot_forwards=None, forwards=None,
-        pf_temp: float = 0.0,
+        pf_temp: float = 0.0, temps=None,
     ) -> None:
         """Post-dispatch accounting shared by the split decode chunk,
         the fused ragged step and the speculative step — ONE definition
@@ -3343,7 +3470,9 @@ class ContinuousScheduler:
 
         pf_temp: the temperature of the prompt whose window rode in
         this dispatch (ragged and speculative steps), for
-        `_count_dispatch`.
+        `_count_dispatch`; temps: the slots' temperatures as the
+        dispatch was handed them, where the slots have moved on since
+        (block mode reads a block one enqueue late).
 
         slot_forwards / forwards (block mode; `toks` is then already
         {slot: the block's new tokens}): the dispatch ran `forwards`
@@ -3353,7 +3482,9 @@ class ContinuousScheduler:
         work, and TPOT is the dispatch over the tokens a slot got."""
         self.chunks_run += 1
         self.metrics.inc("chunks")
-        self._count_dispatch(kind, rows, self.temp, pf_temp)
+        self._count_dispatch(
+            kind, rows, self.temp if temps is None else temps, pf_temp
+        )
         if self.watchdog is not None:
             self.watchdog.beat()
         lane_steps = (

@@ -85,8 +85,11 @@ class PhaseClock:
     @contextlib.contextmanager
     def phase(self, name: str, kind: str = "host") -> Iterator[None]:
         """kind: "host" (the host works), "dispatch" (host work that
-        ends with a device program enqueued) or "blocked" (the host
-        waits: for the device, for data, for a request)."""
+        ends with a device program enqueued), "blocked" (the host
+        waits: for the device, for data, for a request) or "wait" (the
+        host waits for a program with another enqueued behind it: the
+        device has work when the wait returns, so no `host` event
+        opens)."""
         if kind == "blocked":
             self._end_host()
         self._switch()
@@ -265,8 +268,14 @@ class DeviceTimeSampler:
     def tick(self) -> bool:
         """Advance the engine-step counter; True when THIS step is due
         a sample (every Nth step; never with every=0)."""
+        due = self.due_next()
         self._step += 1
-        return self.every > 0 and self._step % self.every == 0
+        return due
+
+    def due_next(self) -> bool:
+        """Whether the next tick() will be due, without advancing: a
+        loop that keeps a dispatch in flight reads it out first."""
+        return self.every > 0 and (self._step + 1) % self.every == 0
 
     def begin(self) -> bool:
         """Start one capture into a fresh temp dir. False (with the

@@ -2,6 +2,9 @@
 boundaries, FIFO no-starvation, page-pressure eviction with
 deterministic replay, and the wasted-step microbench as a slow test."""
 
+import re
+import time
+
 import numpy as np
 import pytest
 
@@ -751,3 +754,354 @@ def test_released_prompt_embeds_are_gathered_again_for_a_replay(pipe, mode):
     if mode == "audit":
         assert verdicts["pass"] == 2 and not (
             verdicts["fail"] or verdicts["drift"])
+
+
+# ---------------------------------------------------------------------------
+# Block mode keeps one dispatch in flight (docs/DESIGN.md "Block
+# diffusion"): block n+1 is enqueued before block n is read
+# ---------------------------------------------------------------------------
+
+# The block engine's own test file has the configuration, the reply's
+# ids and the margin-aware comparison with the reference loop.
+from test_block_engine import (  # noqa: E402
+    _assert_tokens, _cfg as _block_cfg, _ids as _reply_ids,
+)
+
+
+class IdTokenizer:
+    """`<id>` per token out. In, `<id>` is that id again and any other
+    character its code point, so a reply re-encodes to its own ids (a
+    history turn then hits the pages its first turn donated)."""
+
+    def encode(self, text, add_special_tokens=False):
+        return [
+            int(m[1]) if m[1] else min(ord(m[0]), 500)
+            for m in re.finditer(r"<(\d+)>|.", text, re.S)
+        ]
+
+    def decode(self, ids, skip_special_tokens=True):
+        return "".join(f"<{int(i)}>" for i in ids)
+
+
+@pytest.fixture(scope="module")
+def block_params():
+    """Four times the init's scale, behind the "plain" template (the
+    user's text and a newline): at the init's 0.02 every reply is one
+    token repeated whatever the prompt, and a slot that showed its
+    previous owner's tokens would pass."""
+    params = oryx.init_params(_block_cfg(), jax.random.key(0))
+    return jax.tree.map(lambda x: x * 4 if x.ndim >= 2 else x, params)
+
+
+def _block_pipe(params, **gen):
+    return OryxInference(
+        IdTokenizer(), params, _block_cfg(**gen), template="plain")
+
+
+def _block_want(pipe, request, cap, eos=None):
+    """The reference's cache-less loop on the request's own ids:
+    ((tokens, margins), prompt length)."""
+    from benchmark.reference import sdar_moe_ref as ref
+
+    ids, *_ = pipe._prepare_request(request)
+    gen = pipe.cfg.generation
+    return ref.generate(
+        pipe.params["llm"], pipe.cfg.llm, ids, cap,
+        steps=gen.denoising_steps, remasking=gen.remasking,
+        threshold=gen.confidence_threshold, eos=eos), len(ids)
+
+
+def _serve_blocks(pipe, reqs, *, skip=(), hook=None, journal=None, **kw):
+    """`reqs` (request dict, cap, sampling) through a block engine of
+    two slots, submitted up front. The engine must end idle with
+    nothing in flight and every page accounted for."""
+    metrics = ServingMetrics()
+    kw = {"num_slots": 2, "page_size": 16, "max_ctx": 256,
+          "prefill_chunk": 32, **kw}
+    sched = ContinuousScheduler(
+        pipe, metrics=metrics, autostart=False, journal=journal, **kw)
+    handles = [sched.submit(r, cap, s) for r, cap, s in reqs]
+    if hook is not None:
+        hook(sched, handles)
+    sched.start()
+    results = [
+        None if i in skip else h.result(timeout=600)
+        for i, h in enumerate(handles)
+    ]
+    deadline = time.monotonic() + 30
+    while time.monotonic() < deadline and (
+        sched._inflight is not None
+        or any(r is not None for r in sched.slots)
+    ):
+        time.sleep(0.01)
+    idle = sched._inflight is None and all(r is None for r in sched.slots)
+    sched.close()
+    assert idle, "the engine did not go idle with nothing in flight"
+    sched._check_pool_invariant()
+    return handles, results, metrics
+
+
+def _dropped_by_rule(ends):
+    """Slot-blocks the engine computes for nothing: a request that ends
+    on something only the harvest knows (EOS, a stop) rides one block
+    more, unless that block would have been past its max_tokens, which
+    the host counts. `ends`: (prompt length, cap, new tokens up to and
+    with the one that ended it, or None for a `length` finish)."""
+    n = 0
+    for length, cap, consumed in ends:
+        if consumed is None:
+            continue
+        tail = length % 4
+        n += -(-(tail + consumed) // 4) < -(-(tail + cap) // 4)
+    return n
+
+
+BLOCK_CASES = [
+    "max_tokens_on_a_block_edge", "max_tokens_inside_a_block",
+    "eos_inside_a_block", "stop_sequence", "cancel_with_a_block_in_flight",
+    "eviction_under_a_small_pool", "slot_freed_one_block_earlier",
+    "prefix_hit_on_pages_donated_with_a_block_in_flight",
+]
+
+
+@pytest.mark.parametrize("case", BLOCK_CASES)
+def test_block_engine_with_a_block_in_flight_keeps_every_reply(
+    block_params, case
+):
+    """Staggered requests through a two-slot block engine that enqueues
+    block n+1 before it reads block n: every reply is the reference
+    loop's, token for token, whatever ends a request and whoever takes
+    its slot; `block_dispatches_ahead_total` and
+    `block_rows_dropped_total` count what the case did."""
+    pipe = _block_pipe(block_params)
+    qs = ["hello there", "what now? " * 6, "tell me more!", "and a tail.."]
+    kw, hook, skip, eos = {}, None, (), None
+    sampled_solo = None
+    if case.startswith("max_tokens"):
+        lens = [len(pipe._prepare_request({"question": q})[0]) for q in qs]
+        edge = case == "max_tokens_on_a_block_edge"
+        # tail + cap a multiple of the block, or one past it.
+        caps = [8 + (-(n + 8)) % 4 + (0 if edge else 1) for n in lens]
+        reqs = [({"question": q}, c, None) for q, c in zip(qs, caps)]
+    elif case == "eos_inside_a_block":
+        (free, _), _ = _block_want(pipe, {"question": qs[0]}, 16)
+        eos = free[5]  # the second block's second token, or earlier
+        pipe = _block_pipe(block_params, eos_token_id=eos)
+        reqs = [({"question": q}, 16, None) for q in qs[:3]]
+    elif case == "stop_sequence":
+        (free, _), _ = _block_want(pipe, {"question": qs[0]}, 16)
+        reqs = [({"question": qs[0]}, 16, {"stop": [f"<{free[6]}>"]}),
+                ({"question": qs[1]}, 9, None),
+                ({"question": qs[2]}, 7, None)]
+    elif case == "cancel_with_a_block_in_flight":
+        reqs = [({"question": qs[0]}, 24, None),
+                ({"question": qs[1]}, 40, None),
+                ({"question": qs[2]}, 10, None)]
+        skip = (1,)
+
+        def hook(sched, handles):
+            enqueue, rode = sched._enqueue_block, []
+
+            def enqueue_then_hang_up(ahead):
+                flight = enqueue(ahead)
+                rode.extend(
+                    s for s in (flight.riders if flight else ())
+                    if sched.slots[s].handle is handles[1]
+                )
+                if len(rode) == 2:
+                    # Request 1 rides this block and the one before it,
+                    # which is read next: the hang-up is seen there.
+                    assert ahead
+                    handles[1].cancelled = True
+                return flight
+
+            sched._enqueue_block = enqueue_then_hang_up
+    elif case == "eviction_under_a_small_pool":
+        sampling = {"temperature": 0.8, "top_p": 0.9, "seed": 3}
+        reqs = [({"question": "a" * 40}, 60, None),
+                ({"question": "b" * 40}, 60, sampling)]
+        _, ((sampled_solo, _, _),), _ = _serve_blocks(pipe, reqs[1:])
+        # Both are admitted (3 pages each) and neither can finish (7)
+        # without the other's pages.
+        kw = {"num_pages": 10}
+    elif case == "slot_freed_one_block_earlier":
+        (free, _), n0 = _block_want(pipe, {"question": qs[0]}, 16)
+        eos = free[5]
+        pipe = _block_pipe(block_params, eos_token_id=eos)
+        # The long request must not meet the EOS itself.
+        (long, _), n1 = _block_want(pipe, {"question": qs[1]}, 40)
+        assert eos not in long
+        # The long one first: it is live when the short one ends, so
+        # nothing drains and the third is placed at once.
+        reqs = [({"question": qs[1]}, 40, None),
+                ({"question": qs[0]}, 16, None),
+                ({"question": qs[3]}, 12, None)]
+        # Any two fit, all three do not: the third gets the pages the
+        # short one gave back with a block still writing to them.
+        pages = [-(-(n + c) // 16) + 1 for n, c in ((n0, 16), (n1, 40))]
+        kw = {"num_pages": sum(pages) + 1, "prefix_cache": False}
+        took_over = []
+
+        def hook(sched, handles):
+            harvest = sched._harvest_block
+
+            def harvest_and_look(flight):
+                took_over.extend(
+                    s for s, seq in flight.riders.items()
+                    if sched.slots[s] is not None
+                    and sched.slots[s].admit_seq != seq
+                )
+                harvest(flight)
+
+            sched._harvest_block = harvest_and_look
+    else:
+        q = "the same opening words, " * 2
+        (free, _), n0 = _block_want(pipe, {"question": q}, 40)
+        # An EOS late enough for the reply to fill a page of its own.
+        at = next(
+            i for i in range(len(free))
+            if (n0 + i) // 16 > n0 // 16 and free.index(free[i]) == i
+        )
+        eos = free[at]
+        pipe = _block_pipe(block_params, eos_token_id=eos)
+        first = pipe.tokenizer.decode(free[:at])
+        turn2 = {"question": "and then?", "history": [(q, first)]}
+        ids0 = list(pipe._prepare_request({"question": q})[0])
+        ids2 = list(pipe._prepare_request(turn2)[0])
+        stream = ids0 + free[:at]
+        assert ids2[:len(stream)] == stream
+        (long, _), _ = _block_want(pipe, {"question": qs[1]}, 60)
+        assert eos not in long
+        reqs = [({"question": qs[1]}, 60, None),
+                ({"question": q}, 40, None), (turn2, 9, None)]
+
+    handles, results, metrics = _serve_blocks(
+        pipe, reqs, skip=skip, hook=hook, **kw)
+    ends = []
+    for i, ((request, cap, sampling), res) in enumerate(zip(reqs, results)):
+        if i in skip:
+            assert not handles[i].done.is_set()
+            continue
+        reply, reason, usage = res
+        if sampling and sampling.get("temperature"):
+            assert reply == sampled_solo  # the same after an eviction
+            continue
+        (want, margins), n = _block_want(pipe, request, cap, eos=eos)
+        consumed = None if len(want) == cap else len(want) + 1
+        for stop in (sampling or {}).get("stop", ()):
+            tok = int(stop.strip("<>"))
+            if tok in want:
+                want = want[:want.index(tok)]
+                consumed = len(want) + 1
+        _assert_tokens(_reply_ids(reply), want, margins)
+        assert reason == ("length" if consumed is None else "stop")
+        assert usage[0] == n
+        ends.append((n, cap, consumed))
+    ahead = metrics.get("block_dispatches_ahead_total")
+    dropped = metrics.get("block_rows_dropped_total")
+    reg = metrics.registry
+    blocks = reg.counter("dispatches_total", ("kind",)).labels(
+        kind="block").value
+    assert 0 < ahead < blocks
+    if case == "cancel_with_a_block_in_flight":
+        assert metrics.get("cancelled") == 1 and dropped == 1
+    elif case == "eviction_under_a_small_pool":
+        # The block in flight is read before anyone is evicted.
+        assert metrics.get("evicted") >= 1 and dropped == 0
+    else:
+        assert dropped == _dropped_by_rule(ends)
+        assert (dropped > 0) == (case not in (
+            "max_tokens_on_a_block_edge", "max_tokens_inside_a_block"))
+    if case == "slot_freed_one_block_earlier":
+        # A block was read whose slot had passed to the next request.
+        assert took_over
+    if case.startswith("prefix_hit"):
+        # The second turn spliced the pages that hold the first reply.
+        assert metrics.get("prefix_cache_hit_tokens_total") >= (
+            len(stream) // 16 * 16) > n0
+
+
+def test_block_n_plus_1_is_enqueued_before_block_n_is_read(
+    block_params, monkeypatch
+):
+    """With two live slots the engine calls `paged_block_step` for
+    block n+1 before it reads block n's result, reads every block it
+    enqueued, in order, and ends with nothing in flight."""
+    from oryx_tpu.serve import scheduler as sched_lib
+
+    pipe = _block_pipe(block_params)
+    log, made = [], []
+    real = sched_lib.generate_lib.paged_block_step
+
+    def spy(*a, **k):
+        out = real(*a, **k)
+        made.append(out[1])  # the block's tokens, still on the device
+        log.append(("enqueue", len(made) - 1))
+        return out
+
+    monkeypatch.setattr(sched_lib.generate_lib, "paged_block_step", spy)
+
+    def hook(sched, handles):
+        harvest = sched._harvest_block
+
+        def harvest_and_log(flight):
+            log.append(("read", next(
+                i for i, t in enumerate(made) if t is flight.toks)))
+            harvest(flight)
+
+        sched._harvest_block = harvest_and_log
+
+    reqs = [({"question": "hello there"}, 21, None),
+            ({"question": "what now?"}, 30, None)]
+    _, _, metrics = _serve_blocks(pipe, reqs, hook=hook)
+    enq = [x for kind, x in log if kind == "enqueue"]
+    read = [x for kind, x in log if kind == "read"]
+    assert read == enq and len(enq) >= 8  # every block, in order
+    ahead = 0
+    for n, x in enumerate(read):
+        before = log[:log.index(("read", x))]
+        n_made = sum(1 for kind, _ in before if kind == "enqueue")
+        # Block n is read with block n+1 enqueued, while one was made.
+        assert n_made == min(n + 2, len(enq))
+        ahead += n_made == n + 2
+    assert ahead >= len(enq) - 3
+    assert metrics.get("block_dispatches_ahead_total") == ahead
+    assert metrics.get("block_rows_dropped_total") == 0
+
+
+def test_block_run_replays_from_its_journal(block_params, tmp_path):
+    """The journal's `step` events advance once a harvested block, in
+    enqueue order, so a block run that dropped rows and reused a slot
+    replays cold to the same decision stream and the same replies."""
+    import sys
+    from pathlib import Path
+
+    from oryx_tpu.serve import journal as journal_lib
+
+    sys.path.insert(0, str(Path(__file__).resolve().parent.parent / "scripts"))
+    import replay_journal as rj
+
+    pipe = _block_pipe(block_params)
+    (free, _), _ = _block_want(pipe, {"question": "hello there"}, 16)
+    pipe = _block_pipe(block_params, eos_token_id=free[5])
+    path = str(tmp_path / "journal.jsonl")
+    journal = journal_lib.DecisionJournal(path)
+    reqs = [({"question": "hello there"}, 16, None),
+            ({"question": "what now? " * 6}, 30, None),
+            ({"question": "and a tail.."}, 12,
+             {"temperature": 0.8, "top_p": 0.9, "seed": 5})]
+    try:
+        _, _, metrics = _serve_blocks(pipe, reqs, journal=journal)
+    finally:
+        journal.close()
+    assert metrics.get("block_rows_dropped_total") >= 1
+    header, entries = journal_lib.read_journal(path)
+    steps = [e for e in entries
+             if e["kind"] == "step" and e["dispatch"] == "block"]
+    assert len(steps) == metrics.registry.counter(
+        "dispatches_total", ("kind",)).labels(kind="block").value
+    res = rj.run_replay(header, entries, pipe=pipe, timeout_s=300)
+    assert rj.first_divergence(entries, res["entries"]) is None
+    matched, total, bad = rj.reply_match(entries, res["entries"])
+    assert matched == total == 3, bad
+    assert not res["feed_errors"] and not res["timed_out"]
