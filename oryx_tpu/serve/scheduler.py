@@ -192,11 +192,14 @@ def prefill_table_buckets(max_pages: int, page_size: int) -> tuple[int, ...]:
 # oryx_serving_engine_phase_seconds_total{phase=} and, prefixed
 # `oryx.engine.`, the host events a profiler capture holds. Exclusive
 # seconds: every instant of the loop is in exactly one. The host WAITS
-# in idle (no work), first_token (the tok0 read in _activate) and
-# harvest; it works in the rest (docs/OBSERVABILITY.md "Engine phases").
+# in idle (no request to serve), first_token (the tok0 read in
+# _activate) and harvest (a dispatch's FIRST output read, which ends
+# where the device drains); it works in the rest, copy_out (the
+# dispatch's further outputs, copied with the device drained) among
+# them (docs/OBSERVABILITY.md "Engine phases").
 ENGINE_PHASES = (
     "idle", "housekeeping", "admit", "prompt_prep", "embed", "prefill",
-    "first_token", "decode", "harvest", "emit",
+    "first_token", "decode", "harvest", "copy_out", "emit",
 )
 # A block-diffusion engine (cfg.llm.block_length > 0) dispatches
 # `paged_block_step` under `denoise` in place of `decode`, and has no
@@ -205,6 +208,14 @@ BLOCK_ENGINE_PHASES = tuple(
     "denoise" if p == "decode" else p
     for p in ENGINE_PHASES if p != "first_token"
 )
+
+# One uninterrupted stretch of one phase, `idle` apart, that lasts
+# longer than this is a stall: engine_stall_seconds_total{phase=}, and
+# an `engine_stall` event on the trace of every resident request. The
+# longest normal stretch of any benchmark cell is the harvest of a
+# 107 ms decode dispatch (PERF.md section 5); a program's first
+# execution in set-up takes seconds, and so does a stall.
+STALL_SECONDS = 0.5
 
 
 class AdmissionRejected(RuntimeError):
@@ -951,13 +962,25 @@ class ContinuousScheduler:
         # Where the engine thread's time goes (ENGINE_PHASES). The
         # children are made here so every phase renders from the first
         # scrape and a phase boundary costs one locked add.
-        fam = self.metrics.registry.counter(
-            "engine_phase_seconds_total", ("phase",)
+        # Beside them, per phase: the seconds of it in which the
+        # device had nothing queued (`oryx.engine.host` was open), and
+        # the seconds of its stretches over STALL_SECONDS.
+        phases = BLOCK_ENGINE_PHASES if self.block else ENGINE_PHASES
+
+        def per_phase(fam) -> dict:
+            return {p: fam.labels(phase=p).inc for p in phases}
+
+        reg = self.metrics.registry
+        self._phase_seconds = per_phase(
+            reg.counter("engine_phase_seconds_total", ("phase",))
         )
-        self._phase_seconds = {
-            p: fam.labels(phase=p).inc
-            for p in (BLOCK_ENGINE_PHASES if self.block else ENGINE_PHASES)
-        }
+        self._starved_seconds = per_phase(
+            reg.counter("engine_starved_seconds_total", ("phase",))
+        )
+        self._stall_seconds = per_phase(
+            reg.counter("engine_stall_seconds_total", ("phase",))
+        )
+        self._last_dispatch = ""  # thread-owned: engine
         self._phases = self._new_phase_clock()
         # Wide-event request log (utils/request_log.py): one canonical
         # JSONL event per terminal request, merging the cost ledger,
@@ -1984,20 +2007,43 @@ class ContinuousScheduler:
 
     def _new_phase_clock(self) -> profiling_lib.PhaseClock:
         return profiling_lib.PhaseClock(
-            "oryx.engine",
-            lambda name, seconds: self._phase_seconds[name](seconds),
-            base="housekeeping",
+            "oryx.engine", self._bill_phase, base="housekeeping",
+            starved=lambda name, seconds: self._starved_seconds[name](
+                seconds
+            ),
         )
+
+    def _bill_phase(self, name: str, seconds: float) -> None:
+        """One uninterrupted stretch of a phase has ended. One over
+        STALL_SECONDS is counted, and marked on the trace of every
+        request that sat in a slot through it."""
+        self._phase_seconds[name](seconds)
+        if seconds > STALL_SECONDS and name != "idle":
+            self._stall_seconds[name](seconds)
+            for req in self.slots:
+                if req is not None:
+                    req.trace.event(
+                        "engine_stall", phase=name, seconds=seconds,
+                        last_dispatch=self._last_dispatch,
+                    )
 
     def _phase(self, name: str, kind: str = "host"):
         """`with self._phase(...)`: the engine thread is in this phase
         (ENGINE_PHASES) until the block ends; loop time outside every
         block is housekeeping."""
+        if kind == "dispatch":
+            self._last_dispatch = name
         return self._phases.phase(name, kind)
 
     def _run(self) -> None:
         # A restarted loop runs on a new thread: its own clock.
         self._phases = self._new_phase_clock()
+        try:
+            self._loop()
+        finally:
+            self._phases.close()  # a loop that ends idle bills it
+
+    def _loop(self) -> None:
         while True:
             if self.replay_feeder is not None:
                 # Offline replay (scripts/replay_journal.py): feed the
@@ -2063,20 +2109,23 @@ class ContinuousScheduler:
                 self._update_degraded()
                 if self.watchdog is not None:
                     self.watchdog.set_active(False)
-                with self._phase("idle", "blocked"):
-                    if self.auditor.pending():
-                        # Idle quiesce point: run ONE queued shadow-
-                        # parity replay, then re-check for live work —
-                        # an arrival never waits behind a second
-                        # replay, and a replay can never interleave
-                        # with a live dispatch (the never-perturb
-                        # contract, serve/audit.py).
-                        self.auditor.run_one()
-                        continue
-                    with self._cond:
-                        if not self._queue and not self._shutdown:
-                            self._cond.wait(timeout=0.1)
+                # ONE `idle` phase from the first empty wait to the
+                # first request, however often the loop wakes in it.
+                self._phases.hold("idle")
+                if self.auditor.pending():
+                    # Idle quiesce point: run ONE queued shadow-
+                    # parity replay, then re-check for live work —
+                    # an arrival never waits behind a second
+                    # replay, and a replay can never interleave
+                    # with a live dispatch (the never-perturb
+                    # contract, serve/audit.py).
+                    self.auditor.run_one()
+                    continue
+                with self._cond:
+                    if not self._queue and not self._shutdown:
+                        self._cond.wait(timeout=0.1)
                 continue
+            self._phases.release()
             if self.watchdog is not None:
                 self.watchdog.set_active(True)
             # Chaos site: engine-thread DEATH (outside the containment
@@ -3190,7 +3239,7 @@ class ContinuousScheduler:
         )
         share = None
         if self.share_stats:
-            with self._phase("harvest", "blocked"):
+            with self._phase("copy_out"):
                 share = np.asarray(out[-1])  # oryxlint: disable=host-sync
         self._drain_prefill_held()  # enqueued before this chunk: ready
         dt = time.monotonic() - t0
@@ -3319,23 +3368,24 @@ class ContinuousScheduler:
         max_tokens cut inside it. The rows of a slot that no longer
         holds the placement it rode for are dropped."""
         self.metrics.inc("harvest_total")
-        # With a block enqueued behind this one the device has work
-        # when the wait returns: no `host` event opens.
+        # Three blocking copies: the tokens, which wait for the block,
+        # then the five statistics as one array and the per-slot
+        # forwards (each about a millisecond once the first has
+        # waited, PERF.md section 6, PR 24). Lengths advance on the
+        # host. With a block enqueued behind this one the device has
+        # work when the wait returns: no `host` event opens.
+        # oryxlint: off=host-sync
         with self._phase(
             "harvest", "blocked" if self._inflight is None else "wait"
         ):
-            # Three blocking copies (each costs about a millisecond
-            # once the first has waited, PERF.md section 6, PR 24): the
-            # tokens, the five statistics as one array, and the
-            # per-slot forwards. Lengths advance on the host.
-            # oryxlint: off=host-sync
             toks = np.asarray(flight.toks)
+        with self._phase("copy_out"):
             stats = dict(zip(
                 generate_lib.BLOCK_STATS,
                 (int(x) for x in np.asarray(flight.counts["stats"])),
             ))
             slot_forwards = np.asarray(flight.counts["slot_forwards"])
-            # oryxlint: on=host-sync
+        # oryxlint: on=host-sync
         # Harvest to harvest, the pace a client feels: a block
         # enqueued ahead began when the one before it was read.
         t0_ns = max(flight.t0_ns, self._blk_read_ns)
@@ -3416,8 +3466,9 @@ class ContinuousScheduler:
         pending, self._prefill_held = self._prefill_held, []
         m = self.metrics
         for stats in pending:
-            st = dict(zip(generate_lib.SHARE_STATS,
-                          (int(x) for x in np.asarray(stats))))
+            with self._phase("copy_out"):
+                st = dict(zip(generate_lib.SHARE_STATS,
+                              (int(x) for x in np.asarray(stats))))
             m.inc("moe_prefill_pairs_total", st["pairs"])
             m.inc("moe_prefill_held_rows_total", st["held_rows"])
             m.inc("moe_prefill_held_experts_hit_total", st["held_hit"])
@@ -3634,14 +3685,18 @@ class ContinuousScheduler:
         host-syncing on the step paths is a regression the host-sync
         rule catches."""
         self.metrics.inc("harvest_total")
+        # The first read waits for the program; the rest copy with the
+        # device drained, which is the host's time, not a wait.
+        # oryxlint: off=host-sync
         with self._phase("harvest", "blocked"):
-            # oryxlint: off=host-sync
-            self.tok = np.asarray(tok).copy()
+            tok = np.asarray(tok)
+        with self._phase("copy_out"):
+            self.tok = tok.copy()
             self.lengths = np.asarray(lengths).copy()
             self.finished = np.asarray(finished).copy()
             self.recent = np.asarray(recent).copy()
             out = np.asarray(toks), np.asarray(fin)
-            # oryxlint: on=host-sync
+        # oryxlint: on=host-sync
         return out
 
     # hot-path
@@ -4208,13 +4263,15 @@ class ContinuousScheduler:
         the finished vector + the EOS the accepted span carries). Same
         one-deliberate-sync-per-step contract."""
         self.metrics.inc("harvest_total")
+        # oryxlint: off=host-sync
         with self._phase("harvest", "blocked"):
-            # oryxlint: off=host-sync
-            self.tok = np.asarray(tok).copy()
+            tok = np.asarray(tok)
+        with self._phase("copy_out"):
+            self.tok = tok.copy()
             self.lengths = np.asarray(lengths).copy()
             self.finished = np.asarray(finished).copy()
             out = np.asarray(toks), np.asarray(n_new), np.asarray(acc)
-            # oryxlint: on=host-sync
+        # oryxlint: on=host-sync
         return out
 
     def _occupancy_gauge(self) -> None:

@@ -1,11 +1,11 @@
-"""Tracing / profiling: jax.profiler glue + per-step timing.
+"""Tracing / profiling: jax.profiler glue + a loop's phases.
 
 Reference parity: the reference has no first-class tracing — ad-hoc torch
 profiler + DeepSpeed wall-clock timers / flops_profiler toggles
 (SURVEY.md §5 "Tracing / profiling"). Here profiling is first-class:
-Perfetto/TensorBoard traces via jax.profiler, named annotations around the
-ViT / compressor / decoder phases, and a step timer that reports the
-north-star metric (tokens/sec/chip) continuously.
+Perfetto/TensorBoard traces via jax.profiler, the engine's and the
+trainer's loop phases as named host events and exclusive seconds
+(PhaseClock), and device time attributed to dispatch kinds.
 """
 
 from __future__ import annotations
@@ -16,11 +16,6 @@ import time
 from typing import Iterator
 
 import jax
-
-
-def start_server(port: int = 9999) -> None:
-    """Start the profiler RPC server (connect TensorBoard / xprof to it)."""
-    jax.profiler.start_server(port)
 
 
 def _start_trace(logdir: str, *, host_tracer_level: int = 2) -> None:
@@ -52,29 +47,38 @@ class PhaseClock:
     last boundary to the phase that was running (`base` when none is
     open), so a nested phase's seconds are not counted again in its
     parent and the recorded seconds of a window add up to its wall
-    time.
+    time. `record` is called once a boundary, so the seconds of one
+    call are one uninterrupted stretch of one phase.
 
     `kind` places the one enclosing annotation, `<prefix>.host`: it
     opens when a top-level "blocked" phase returns (the device has
     drained, the host holds the pace) and closes after the next
     "dispatch" phase (the device has work again) or before the next
     blocked one. A device idle gap made of several short phases is
-    then covered by one named host event instead of none.
+    then covered by one named host event instead of none. The seconds
+    under it are the ones the device waited for this thread: each
+    stretch billed while it is open also goes to
+    `starved(name, seconds)`, where one is given.
 
     Bound to the thread that enters its phases (a TraceAnnotation
     cannot cross threads); a loop that restarts on a new thread makes
     a new clock."""
 
-    def __init__(self, prefix: str, record, *, base: str):
+    def __init__(self, prefix: str, record, *, base: str, starved=None):
         self._prefix = prefix + "."
         self._record = record
+        self._starved = starved
         self._open = [base]  # innermost last; base never closes
         self._t = time.perf_counter()
         self._host = None
+        self._held = None
 
     def _switch(self) -> None:
         now = time.perf_counter()
-        self._record(self._open[-1], now - self._t)
+        name, seconds = self._open[-1], now - self._t
+        self._record(name, seconds)
+        if self._host is not None and self._starved is not None:
+            self._starved(name, seconds)
         self._t = now
 
     def _end_host(self) -> None:
@@ -90,9 +94,9 @@ class PhaseClock:
         host waits for a program with another enqueued behind it: the
         device has work when the wait returns, so no `host` event
         opens)."""
+        self._switch()  # before the host event ends: its seconds starved
         if kind == "blocked":
             self._end_host()
-        self._switch()
         self._open.append(name)
         try:
             with jax.profiler.TraceAnnotation(self._prefix + name):
@@ -107,6 +111,30 @@ class PhaseClock:
                     self._prefix + "host"
                 )
                 self._host.__enter__()
+
+    def hold(self, name: str) -> None:
+        """Enter the blocked phase `name` and stay in it however often
+        the loop comes round: the first call opens it (ONE annotation
+        for the whole stretch), a later one bills the seconds so far,
+        so a reader of the counter is never further behind than the
+        loop's own period. `release()` ends it."""
+        if self._held is None:
+            self._held = self.phase(name, "blocked")
+            self._held.__enter__()
+        else:
+            self._switch()
+
+    def release(self) -> None:
+        """End the held phase, if one is open."""
+        if self._held is not None:
+            held, self._held = self._held, None
+            held.__exit__(None, None, None)
+
+    def close(self) -> None:
+        """The loop ends: a held phase is billed, no annotation of the
+        clock's own stays open."""
+        self.release()
+        self._end_host()
 
 
 @dataclasses.dataclass
@@ -400,42 +428,3 @@ class DeviceTimeSampler:
         body["other_us"] = att["other_us"]
         body["source"] = att["source"]
         return body
-
-
-class StepTimer:
-    """Rolling wall-clock step stats: step time and tokens/sec/chip.
-
-    Call `tick(num_tokens)` once per optimizer step AFTER the host has
-    synchronized on the step's results (e.g. after device_get of metrics —
-    under async dispatch an unsynced tick measures only dispatch time).
-    """
-
-    def __init__(self, window: int = 20, n_chips: int | None = None) -> None:
-        self.window = window
-        self.n_chips = n_chips or jax.device_count()
-        self._times: list[float] = []
-        self._tokens: list[int] = []
-        self._last: float | None = None
-
-    def tick(self, num_tokens: int) -> dict[str, float] | None:
-        """Record a step boundary; returns rolling stats (None on the first
-        tick, which only arms the timer)."""
-        now = time.perf_counter()
-        if self._last is None:
-            self._last = now
-            return None
-        dt = now - self._last
-        self._last = now
-        self._times.append(dt)
-        self._tokens.append(num_tokens)
-        if len(self._times) > self.window:
-            self._times.pop(0)
-            self._tokens.pop(0)
-        total_t = sum(self._times)
-        total_tok = sum(self._tokens)
-        return {
-            "step_time_s": dt,
-            "step_time_avg_s": total_t / len(self._times),
-            "tokens_per_sec": total_tok / total_t,
-            "tokens_per_sec_per_chip": total_tok / total_t / self.n_chips,
-        }

@@ -361,6 +361,35 @@ def test_sampled_request_is_reproducible_by_seed(pipe):
     assert results[0][0] != results[2][0]
 
 
+def test_a_block_in_flight_starves_neither_its_harvest_nor_the_emit(pipe):
+    """With block n+1 enqueued behind it the harvest of block n is a
+    wait for a device that has work when it returns: none of the
+    seconds of `harvest`, `copy_out` and `emit` that pass while a block
+    is in flight are billed as starved; the harvest that drains the
+    engine is followed by starved ones."""
+    metrics = ServingMetrics()
+    sched = _sched(pipe, metrics=metrics)
+    seen = {"phase": [], "starved": []}
+    for kind, billed in (("phase", sched._phase_seconds),
+                         ("starved", sched._starved_seconds)):
+        for name in ("harvest", "copy_out", "emit"):
+            def spy(s, _real=billed[name], _to=seen[kind], _name=name):
+                _to.append((_name, sched._inflight is not None))
+                _real(s)
+            billed[name] = spy
+    _run_all(sched, [("hello there", 24, None), ("what now?", 16, None)])
+    assert metrics.get("block_dispatches_ahead_total") >= 4
+    flying = {n for n, inflight in seen["phase"] if inflight}
+    assert flying == {"harvest", "copy_out", "emit"}
+    assert not [n for n, inflight in seen["starved"] if inflight]
+    # the drain's harvest returns to a drained device
+    drained = {n for n, inflight in seen["starved"] if not inflight}
+    assert {"copy_out", "emit"} <= drained and "harvest" not in drained
+    fam = metrics.registry.existing("engine_starved_seconds_total")
+    assert fam.labels(phase="harvest").value == 0
+    assert fam.labels(phase="copy_out").value > 0
+
+
 def test_a_dense_model_keeps_its_phases_and_families():
     cfg = cfg_lib.oryx_tiny()
     pipe = OryxInference(

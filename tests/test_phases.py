@@ -1,7 +1,11 @@
 """The host's time, named (PR 24): the engine and trainer loops' phases
 (utils/profiling.PhaseClock) as exclusive seconds in /metrics and as
 host events in a profiler capture, read back with the benchmark's own
-trace reader; and `prefill_s`, which now is the `admission` span."""
+trace reader; and `prefill_s`, which now is the `admission` span.
+PR 35: the seconds in which the device waited for the engine
+(`engine_starved_seconds_total`), `harvest` ending where the device
+drains (`copy_out`), a request-less stretch as ONE `idle` event, and
+stalls (`engine_stall_seconds_total`)."""
 
 import dataclasses
 import statistics
@@ -11,12 +15,15 @@ import numpy as np
 import pytest
 
 import jax
+import jax.numpy as jnp
 
 from benchmark import trace as bench_trace
 from oryx_tpu import config as cfg_lib
 from oryx_tpu.models import oryx
 from oryx_tpu.serve.pipeline import OryxInference
-from oryx_tpu.serve.scheduler import ENGINE_PHASES, ContinuousScheduler
+from oryx_tpu.serve.scheduler import (
+    ENGINE_PHASES, STALL_SECONDS, ContinuousScheduler,
+)
 from oryx_tpu.train.trainer import Trainer
 from oryx_tpu.utils import faults
 from oryx_tpu.utils.metrics import ServingMetrics
@@ -53,24 +60,40 @@ def requests():
     ]
 
 
-def run_engine(pipe, engine: str, *, idle_s: float = 0.0):
-    """One scheduler's life: submit, start, collect, close. Returns
-    (replies, phase seconds, the loop's wall seconds, handles)."""
-    metrics = ServingMetrics()
-    sched = ContinuousScheduler(
+def new_engine(pipe, engine: str, metrics, **kw):
+    return ContinuousScheduler(
         pipe, num_slots=2, page_size=16, chunk=4, max_ctx=512,
-        metrics=metrics, autostart=False, **ENGINES[engine],
+        metrics=metrics, autostart=False, **{**ENGINES[engine], **kw},
     )
-    handles = [sched.submit(req, cap, None) for req, cap in requests()]
+
+
+def by_phase(metrics, family: str) -> dict:
+    fam = metrics.registry.existing(family)
+    return {p: fam.labels(phase=p).value for p in ENGINE_PHASES}
+
+
+def run_engine(pipe, engine: str, *, idle_s: float = 0.0,
+               idle_first_s: float = 0.0):
+    """One scheduler's life: submit, start, collect, close (with
+    `idle_first_s` the loop starts that long before the first submit).
+    Returns (replies, phase seconds, the loop's wall seconds, handles,
+    the metrics)."""
+    metrics = ServingMetrics()
+    sched = new_engine(pipe, engine, metrics)
     t0 = time.perf_counter()
-    sched.start()
+    if idle_first_s:
+        sched.start()
+        time.sleep(idle_first_s)
+    handles = [sched.submit(req, cap, None) for req, cap in requests()]
+    if not idle_first_s:
+        t0 = time.perf_counter()
+        sched.start()
     replies = [h.result(timeout=600) for h in handles]
     time.sleep(idle_s)
     sched.close()  # joins the engine thread: its last phase is billed
     wall = time.perf_counter() - t0
-    fam = metrics.registry.existing("engine_phase_seconds_total")
-    seconds = {p: fam.labels(phase=p).value for p in ENGINE_PHASES}
-    return replies, seconds, wall, handles
+    seconds = by_phase(metrics, "engine_phase_seconds_total")
+    return replies, seconds, wall, handles, metrics
 
 
 # ---- the primitive -------------------------------------------------------
@@ -110,6 +133,67 @@ def test_a_phase_with_no_capture_running_costs_microseconds():
     assert statistics.median(cost) < 50e-6
 
 
+def test_starved_seconds_are_those_under_the_host_event():
+    """What `starved` is handed adds up to the time `<prefix>.host` was
+    open: from a top-level blocked phase's return to the end of the
+    next dispatch phase, and to the entry of a blocked phase that comes
+    first; a "wait" opens nothing. The recorded seconds still add up
+    to the wall time."""
+    got, starved = {}, {}
+
+    def into(d):
+        return lambda n, s: d.__setitem__(n, d.get(n, 0) + s)
+
+    t0 = time.perf_counter()
+    clock = PhaseClock("oryx.test", into(got), base="rest",
+                       starved=into(starved))
+    time.sleep(0.01)  # nothing has drained yet: not starved
+    with clock.phase("wait_a", "blocked"):
+        time.sleep(0.01)
+    time.sleep(0.02)  # rest, starved
+    with clock.phase("work"):
+        time.sleep(0.01)  # starved
+        with clock.phase("inner"):
+            time.sleep(0.01)  # starved, and not again in its parent
+    with clock.phase("enqueue", "dispatch"):
+        time.sleep(0.02)  # starved to its end
+    time.sleep(0.01)  # the device has work: not starved
+    with clock.phase("wait_b", "wait"):
+        time.sleep(0.01)
+    time.sleep(0.01)  # a "wait" returned: still not starved
+    with clock.phase("wait_c", "blocked"):
+        time.sleep(0.01)
+    time.sleep(0.02)  # starved, up to the next blocked phase's entry
+    with clock.phase("wait_d", "blocked"):
+        time.sleep(0.01)
+    wall = time.perf_counter() - t0
+    assert sum(got.values()) == pytest.approx(wall, abs=1e-3)
+    # whole stretches, so the very seconds the phase was billed
+    assert set(starved) == {"rest", "work", "inner", "enqueue"}
+    assert all(starved[p] == got[p] for p in ("work", "inner", "enqueue"))
+    # of `rest`, the two stretches after a wait's return (a sleep never
+    # ends early), and not the three with work queued or none drained
+    assert 0.039 <= starved["rest"] <= got["rest"] - 0.029
+
+
+def test_a_held_phase_is_entered_once_and_billed_at_every_call():
+    calls = []
+    clock = PhaseClock("oryx.test", lambda n, s: calls.append((n, s)),
+                       base="rest")
+    clock.release()  # nothing held: nothing happens
+    assert calls == []
+    for _ in range(3):
+        clock.hold("quiet")
+        time.sleep(0.01)
+    clock.release()
+    with clock.phase("work"):
+        pass
+    names = [n for n, _ in calls]
+    assert names == ["rest", "quiet", "quiet", "quiet", "rest", "work"]
+    assert all(s >= 0.009 for n, s in calls if n == "quiet")
+    clock.close()
+
+
 # ---- (a) the counters are exhaustive -------------------------------------
 
 
@@ -122,11 +206,126 @@ def plain_runs(pipe):
 def test_engine_phase_seconds_add_up_to_the_loops_wall_time(
     plain_runs, engine
 ):
-    _, seconds, wall, _ = plain_runs[engine]
+    _, seconds, wall, _, _ = plain_runs[engine]
     # every phase of the table ran on this path (idle: the wait before
     # close; prompt_prep / embed: the image; first_token: each admission)
     assert all(seconds[p] > 0 for p in ENGINE_PHASES), seconds
     assert sum(seconds.values()) == pytest.approx(wall, rel=0.02)
+
+
+@pytest.mark.parametrize("engine", list(ENGINES))
+def test_starved_seconds_stay_inside_their_phase_and_no_wait_has_any(
+    plain_runs, engine
+):
+    _, seconds, _, _, metrics = plain_runs[engine]
+    starved = by_phase(metrics, "engine_starved_seconds_total")
+    assert all(starved[p] <= seconds[p] + 1e-9 for p in ENGINE_PHASES)
+    # the device has work, or nobody asks for any, while the host waits
+    assert [starved[p] for p in ("harvest", "first_token", "idle")] == [
+        0.0, 0.0, 0.0]
+    # ... and between a wait's return and the next enqueue it has none
+    assert all(starved[p] > 0 for p in ("emit", "housekeeping", "decode"))
+    assert sum(starved.values()) < sum(seconds.values()) - seconds["idle"]
+
+
+@pytest.mark.parametrize("engine", list(ENGINES))
+def test_copy_out_is_counted_and_starved_after_every_harvest(
+    plain_runs, engine
+):
+    _, seconds, _, _, metrics = plain_runs[engine]
+    starved = by_phase(metrics, "engine_starved_seconds_total")
+    assert 0 < starved["copy_out"] == pytest.approx(
+        seconds["copy_out"], rel=1e-6)
+    assert metrics.get("harvest_total") > 0
+
+
+def _device_outputs(sched):
+    """A dispatch's outputs as the harvest is handed them."""
+    n, rng = sched.num_slots, np.random.default_rng(3)
+    tok = jnp.asarray(rng.integers(0, 99, n), jnp.int32)
+    lengths = jnp.asarray(rng.integers(1, 99, n), jnp.int32)
+    finished = jnp.asarray([True, False])
+    recent = jnp.asarray(rng.integers(0, 99, (n, 4)), jnp.int32)
+    toks = jnp.asarray(rng.integers(0, 99, (n, 4)), jnp.int32)
+    fin = jnp.asarray(rng.integers(0, 2, (n, 4)).astype(bool))
+    return tok, lengths, finished, recent, toks, fin
+
+
+@pytest.mark.parametrize("which", ["chunk", "spec"])
+def test_the_split_harvest_returns_what_the_one_phase_harvest_did(
+    pipe, which
+):
+    metrics = ServingMetrics()
+    sched = new_engine(pipe, "split", metrics)
+    tok, lengths, finished, recent, toks, fin = _device_outputs(sched)
+    try:
+        if which == "chunk":
+            out = sched._harvest_chunk(
+                tok, lengths, finished, recent, toks, fin)
+            want_out = (toks, fin)
+            state = {"tok": tok, "lengths": lengths,
+                     "finished": finished, "recent": recent}
+        else:
+            n_new, acc = lengths, tok
+            out = sched._harvest_spec(
+                tok, lengths, finished, toks, n_new, acc)
+            want_out = (toks, n_new, acc)
+            state = {"tok": tok, "lengths": lengths, "finished": finished}
+    finally:
+        sched.close()
+    assert len(out) == len(want_out)
+    for got, want in zip(out, want_out):
+        np.testing.assert_array_equal(got, np.asarray(want))
+    for name, want in state.items():
+        got = getattr(sched, name)
+        np.testing.assert_array_equal(got, np.asarray(want))
+        assert got.dtype == np.asarray(want).dtype
+        assert got.flags.writeable  # the engine's own copy
+    seconds = by_phase(metrics, "engine_phase_seconds_total")
+    assert seconds["harvest"] > 0 and seconds["copy_out"] > 0
+    assert metrics.get("harvest_total") == 1
+
+
+# ---- (a2) stalls ---------------------------------------------------------
+
+
+@pytest.mark.parametrize("delay,stalled", [(0.7, True), (0.2, False)])
+def test_one_stretch_over_the_limit_is_a_stall(
+    pipe, plain_runs, delay, stalled
+):
+    """A decode dispatch held up by `delay` s (the fault point sits in
+    the loop's base phase, housekeeping): counted under that phase and
+    marked on the resident request's trace when it is over
+    STALL_SECONDS, and not otherwise."""
+    assert 0.2 < STALL_SECONDS < 0.7
+    metrics = ServingMetrics()
+    sched = new_engine(pipe, "split", metrics)
+    sched.start()
+    try:
+        # first, whatever this process has yet to compile
+        sched.submit({"question": "hello there"}, 6, None).result(
+            timeout=600)
+        before = by_phase(metrics, "engine_stall_seconds_total")
+        faults.configure(f"decode_dispatch:delay={delay},times=1")
+        h = sched.submit({"question": "what now?"}, 7, None)
+        h.result(timeout=600)
+    finally:
+        faults.reset()
+        sched.close()
+    after = by_phase(metrics, "engine_stall_seconds_total")
+    added = after["housekeeping"] - before["housekeeping"]
+    marks = [s for s in h.trace.spans if s.name == "engine_stall"]
+    if not stalled:
+        assert added == 0 and not marks
+        return
+    assert delay <= added < delay + 1.0
+    (mark,) = marks
+    assert mark.args["phase"] == "housekeeping"
+    assert mark.args["seconds"] == pytest.approx(added)
+    assert mark.args["last_dispatch"] in ("prefill", "decode")
+    seconds = by_phase(metrics, "engine_phase_seconds_total")
+    assert all(after[p] <= seconds[p] + 1e-9 for p in ENGINE_PHASES)
+    assert after["idle"] == 0  # however long nobody asks
 
 
 # ---- (b) the phases are in a profiler capture ----------------------------
@@ -163,6 +362,41 @@ def captured(pipe, tmp_path_factory):
     spans = [s for s in bench_trace.host_span_list(planes)
              if s[2].startswith("oryx.")]
     return {"replies": replies, "spans": spans, "length_ps": length_ps}
+
+
+def test_a_request_less_stretch_is_one_idle_event(pipe, tmp_path, captured):
+    """The loop wakes every 0.1 s while nobody asks; a capture holds
+    the stretch before the first request as ONE `oryx.engine.idle`
+    event with no `oryx.engine.host` inside it, and the counter has its
+    seconds."""
+    opts = jax.profiler.ProfileOptions()
+    opts.python_tracer_level = 0
+    jax.profiler.start_trace(str(tmp_path), profiler_options=opts)
+    try:
+        *_, metrics = run_engine(pipe, "split", idle_first_s=0.35)
+    finally:
+        jax.profiler.stop_trace()
+    planes = bench_trace.parse_xspace(
+        bench_trace.find_xplane_files(str(tmp_path))[-1]
+    )
+    spans = bench_trace.host_span_list(planes)
+    idles = sorted(s for s in spans if s[2] == "oryx.engine.idle")
+    first = idles[0]
+    assert (first[1] - first[0]) / 1e12 >= 0.3
+    # one event before the first dispatch, one after the last reply
+    enqueues = [s for s in spans if s[2] in (
+        "oryx.engine.prefill", "oryx.engine.decode")]
+    assert [s for s in idles if s[0] < min(e[0] for e in enqueues)] == [
+        first]
+    assert len(idles) <= 2
+    hosts = [s for s in spans if s[2] == "oryx.engine.host"]
+    assert hosts
+    assert not any(s < first[1] and e > first[0] for s, e, _ in hosts)
+    # a gap the length of the stretch is named by it
+    assert bench_trace._covering_span(
+        spans, first[0], first[1]) == "oryx.engine.idle"
+    idle_s = by_phase(metrics, "engine_phase_seconds_total")["idle"]
+    assert idle_s >= (first[1] - first[0]) / 1e12 - 0.01
 
 
 def _nested(spans) -> bool:
