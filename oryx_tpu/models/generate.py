@@ -1534,34 +1534,44 @@ def block_unmask(
 
 
 def _block_lanes_forward(
-    params, cfg: LLMConfig, kv_pages, block_tables, ids, lengths, live,
-    *, attn_impl, compute_dtype,
+    params, cfg: LLMConfig, kv_pages, block_tables, ids, lengths, write,
+    *, attn_impl, compute_dtype, before: int = 0,
 ):
-    """One forward of S x B packed lanes, slot-major: slot s's block
-    `ids[s]` at positions lengths[s]..lengths[s]+B-1 of its own pages,
-    through the (segment, position) ragged path; live slots' lanes write
-    their K/V there. Returns (logits [S*B, V], kv_pages, routing or
+    """One forward of S x W packed lanes, slot-major: lane j of slot s
+    holds `ids[s, j]` at position lengths[s] - before + j of the slot's
+    own pages (never under 0: a lane that would lie there is a dead
+    one), through the (segment, position) ragged path; the lanes that
+    `write` [S, W] marks write their K/V there. The head runs over the
+    lanes from `lengths` on alone. Returns (their logits
+    [S * (W - before), V], kv_pages, routing of all S * W lanes or
     None on a dense config)."""
     from oryx_tpu.parallel.sharding import constrain
 
-    S, B = ids.shape
-    seg = jnp.repeat(jnp.arange(S, dtype=jnp.int32), B)
-    pos = (
+    S, W = ids.shape
+    seg = jnp.repeat(jnp.arange(S, dtype=jnp.int32), W)
+    pos = jnp.maximum(
         lengths[:, None].astype(jnp.int32)
-        + jnp.arange(B, dtype=jnp.int32)[None, :]
+        + jnp.arange(-before, W - before, dtype=jnp.int32)[None, :], 0
     ).reshape(-1)
     e = constrain(params["embed"]["weight"], None, None)[ids.reshape(-1)]
     if compute_dtype is not None:
         e = e.astype(compute_dtype)
-    logits, kv_pages, *routing = qwen2.forward(
+    h, kv_pages, *routing = qwen2.forward(
         params, cfg,
         inputs_embeds=e[None], positions=pos[None],
         kv_cache=kv_pages, block_tables=block_tables,
-        q_segments=seg[None], write_mask=jnp.repeat(live, B)[None],
+        q_segments=seg[None], write_mask=write.reshape(1, -1),
         attn_impl=attn_impl, compute_dtype=compute_dtype,
-        return_routing=bool(cfg.num_experts),
+        return_hidden=True, return_routing=bool(cfg.num_experts),
     )
-    return logits[0], kv_pages, routing[0] if routing else None
+    # The head, as `qwen2.forward` ends, over the lanes that are read.
+    h = h[0].reshape(S, W, -1)[:, before:].reshape(S * (W - before), -1)
+    head = (
+        params["embed"]["weight"].T if cfg.tie_word_embeddings
+        else params["lm_head"]["kernel"]
+    )
+    logits = (h @ head.astype(h.dtype)).astype(jnp.float32)
+    return logits, kv_pages, routing[0] if routing else None
 
 
 @partial(
@@ -1573,11 +1583,14 @@ def paged_block_forward(
     params, cfg: LLMConfig, kv_pages, block_tables, ids, lengths, live,
     *, attn_impl: str = "xla", compute_dtype=None,
 ):
-    """`paged_block_step`'s own forward as a program of its own, for the
-    comparisons that need a forward's logits (benchmark/
-    correctness_sdar.py, the tests): `_block_lanes_forward`, jitted."""
+    """`paged_block_step`'s plain forward as a program of its own, for
+    the comparisons that need a forward's logits or a stand-alone commit
+    (benchmark/correctness_sdar.py, the tests): slot s's block `ids[s]`
+    at positions lengths[s]..lengths[s]+B-1, live slots' lanes writing
+    their K/V. Returns (logits [S*B, V], kv_pages, routing or None)."""
     return _block_lanes_forward(
-        params, cfg, kv_pages, block_tables, ids, lengths, live,
+        params, cfg, kv_pages, block_tables, ids, lengths,
+        jnp.broadcast_to(live[:, None], ids.shape),
         attn_impl=attn_impl, compute_dtype=compute_dtype,
     )
 
@@ -1597,12 +1610,14 @@ def paged_block_step(
     block_tables: jnp.ndarray,  # [S, max_pages] int32
     block: jnp.ndarray,  # [S, B] the block's known tokens, then anything
     n_known: jnp.ndarray,  # [S] int32 known tokens at the block's head
-    lengths: jnp.ndarray,  # [S] committed kv tokens, a multiple of B
+    lengths: jnp.ndarray,  # [S] kv tokens before the block, a multiple of B
     finished: jnp.ndarray,  # [S] bool (True for finished AND empty slots)
     keys: jax.Array,  # [S] per-slot PRNG keys
     temperature: jnp.ndarray,  # [S]
     top_p: jnp.ndarray,  # [S]
     top_k: jnp.ndarray,  # [S]
+    pending: jnp.ndarray | None = None,  # [S, B] the block before, final
+    pending_live: jnp.ndarray | None = None,  # [S] bool: commit it
     *,
     steps: int,
     remasking: str,
@@ -1623,27 +1638,44 @@ def paged_block_step(
     prompt's tail `len % B`) and cfg.mask_token_id elsewhere. An
     on-device loop runs denoising forwards, at most `steps` of them
     under the static rule and at most B under the dynamic one, and
-    stops when no live slot has a masked position left; a slot that has
-    none rides along unchanged. Each forward takes x0 (argmax, or a
-    sample) and its softmax probability in float32 as confidence at
-    every lane, and `block_unmask` fixes some of the masked ones.
-    Greedy rows cost an argmax and a logsumexp: `sample_token_rows`
-    sorts only in a dispatch in which some row has temperature > 0
-    (retired rows carry 0). Then ONE commit forward over the final
-    tokens writes the block's K/V (every forward writes the lanes' K/V
-    at their positions, past the committed length; the last write is
-    what stays), so a block costs T + 1 forwards of S x B lanes.
+    stops when no live slot has a masked position left; a slot that has none rides along unchanged. Each forward
+    takes x0 (argmax, or a sample) and its softmax probability in
+    float32 as confidence at every lane, and `block_unmask` fixes some
+    of the masked ones. Greedy rows cost an argmax and a logsumexp:
+    `sample_token_rows` sorts only in a dispatch in which some row has
+    temperature > 0 (retired rows carry 0).
+
+    **The commit is deferred.** Every forward writes the lanes' K/V at
+    their positions, past `lengths`, and the last denoising forward
+    still saw masks, so what a block leaves in the pages is NOT its
+    K/V. The block's final tokens are an output (`tokens`), and the
+    NEXT dispatch takes them as `pending`: its first forward carries
+    2B lanes a slot, `pending[s]` at lengths[s]-B..lengths[s]-1 with
+    their write on where `pending_live[s]` (and no head), then the
+    opening block. The block mask is a function of position alone and
+    every layer writes the lanes' K/V before its attention reads the
+    pages, so the new block's lanes see the block before them as
+    committed, layer by layer: each row is what a commit forward
+    followed by a denoising forward gives, and a block costs T
+    forwards where it cost T + 1. A slot's first block has nothing
+    pending, and a request's last block is never committed (nothing
+    reads its K/V; docs/DESIGN.md "Block diffusion"). Without
+    `pending` (the comparisons, which commit forward by forward
+    themselves) no forward carries commit lanes and NOTHING commits
+    the block: the loop alone, a smaller program.
 
     Returns (kv_pages, tokens [S, B], n_new [S] = B - n_known for live
     slots, lengths + B for live slots, finished | a new token is EOS,
     keys, counts). counts holds what the counters read: `stats` [5]
     int32 in the order of BLOCK_STATS (forwards run; tokens fixed by
     denoising; (token, expert) pairs routed; the fullest expert's rows
-    and the experts that took a row, each summed over layer-forwards;
-    the last three 0 on a dense config), one array so that the host
-    reads them in one copy; `slot_forwards` [S], the forwards a live
-    slot took part in with work to do (its denoising forwards with a
-    mask left, and the commit); `expert_rows` [L, E], the rows every
+    and the experts that took a row, each summed over layer-forwards
+    and over ALL the lanes a forward carried, commit lanes and dead
+    ones included, since the grouped products read an expert for
+    them; the last three 0 on a dense config), one array so that the
+    host reads them in one copy; `slot_forwards` [S], the forwards a
+    live slot took part in with a mask left (once a forward, whatever
+    else the forward did for it); `expert_rows` [L, E], the rows every
     expert took over all forwards."""
     S, B = block.shape
     if B != cfg.block_length:
@@ -1654,11 +1686,12 @@ def paged_block_step(
     lane = jnp.arange(B, dtype=jnp.int32)
     masked0 = (lane[None, :] >= n_known[:, None]) & live[:, None]
     block = jnp.where(masked0, cfg.mask_token_id, block).astype(jnp.int32)
+    write = jnp.broadcast_to(live[:, None], (S, B))
 
-    def forward(kv, ids):
+    def forward(kv, ids, write, **lanes):
         lg, kv, routing = _block_lanes_forward(
-            params, cfg, kv, block_tables, ids, lengths, live,
-            attn_impl=attn_impl, compute_dtype=compute_dtype,
+            params, cfg, kv, block_tables, ids, lengths, write,
+            attn_impl=attn_impl, compute_dtype=compute_dtype, **lanes,
         )
         rows = (
             jnp.zeros((L, E), jnp.int32) if routing is None
@@ -1666,13 +1699,9 @@ def paged_block_step(
         )
         return lg, kv, rows
 
-    def stats(fixed, rows):
-        """One forward's share of BLOCK_STATS."""
-        return jnp.stack([
-            1, fixed, jnp.sum(rows), jnp.sum(jnp.max(rows, axis=-1)),
-            jnp.sum(rows > 0),
-        ]).astype(jnp.int32)
-
+    # Called after the first forward and in the loop's body: an inner
+    # jit, so that the sampler is traced and lowered once.
+    @jax.jit
     def pick(lg, keys):
         """x0 and its confidence at every lane: [S*B, V] -> [S, B] x2."""
         pair = jax.vmap(lambda k: jax.random.split(k, 2))(keys)
@@ -1691,11 +1720,9 @@ def paged_block_step(
 
     max_steps = steps if remasking == "low_confidence_static" else B
 
-    def cond(c):
-        return (c["t"] < max_steps) & jnp.any(c["masked"])
-
-    def body(c):
-        lg, kv, rows = forward(c["kv"], c["block"])
+    def denoise(c, lg, kv, rows):
+        """The carry after one forward that gave the open block's lanes
+        the logits `lg`."""
         x0, conf, keys = pick(lg, c["keys"])
         fix = block_unmask(
             c["masked"], conf, c["t"],
@@ -1708,30 +1735,43 @@ def paged_block_step(
             "slot_forwards": c["slot_forwards"] + jnp.any(
                 c["masked"], axis=-1),
             "expert_rows": c["expert_rows"] + rows,
-            "stats": c["stats"] + stats(jnp.sum(fix), rows),
+            "stats": c["stats"] + jnp.stack([
+                1, jnp.sum(fix), jnp.sum(rows),
+                jnp.sum(jnp.max(rows, axis=-1)), jnp.sum(rows > 0),
+            ]).astype(jnp.int32),
         }
 
-    c = jax.lax.while_loop(cond, body, {
+    def cond(c):
+        return (c["t"] < max_steps) & jnp.any(c["masked"])
+
+    def body(c):
+        return denoise(c, *forward(c["kv"], c["block"], write))
+
+    c = {
         "t": jnp.zeros((), jnp.int32), "kv": kv_pages, "keys": keys,
         "block": block, "masked": masked0,
         "slot_forwards": jnp.zeros((S,), jnp.int32),
         "expert_rows": jnp.zeros((L, E), jnp.int32),
         "stats": jnp.zeros((len(BLOCK_STATS),), jnp.int32),
-    })
-    # The commit: its logits are not read, so the head is not computed.
-    _, kv_pages, rows = forward(c["kv"], c["block"])
+    }
+    if pending is not None:
+        # The first forward: the pending block's commit lanes before
+        # the open block's, and the head over the open block's alone.
+        commit = jnp.broadcast_to((pending_live & live)[:, None], (S, B))
+        c = denoise(c, *forward(
+            kv_pages,
+            jnp.concatenate([pending.astype(jnp.int32), block], axis=1),
+            jnp.concatenate([commit, write], axis=1), before=B,
+        ))
+    c = jax.lax.while_loop(cond, body, c)
     toks = c["block"]
     n_new = jnp.where(live, B - n_known, 0).astype(jnp.int32)
     new_eos = jnp.any(
         (toks == eos) & (lane[None, :] >= n_known[:, None]), axis=-1
     )
-    counts = {
-        "stats": c["stats"] + stats(0, rows),
-        "slot_forwards": c["slot_forwards"] + live,
-        "expert_rows": c["expert_rows"] + rows,
-    }
+    counts = {k: c[k] for k in ("stats", "slot_forwards", "expert_rows")}
     return (
-        kv_pages, toks, n_new, lengths + jnp.where(live, B, 0),
+        c["kv"], toks, n_new, lengths + jnp.where(live, B, 0),
         finished | (live & new_eos), c["keys"], counts,
     )
 

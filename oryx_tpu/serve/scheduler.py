@@ -382,6 +382,7 @@ class _BlockFlight:
     # slot -> admit_seq of the request it rode for: a row whose slot
     # holds another placement at the harvest is dropped.
     riders: dict[int, int]
+    fused: int  # riders whose pending block its first forward commits
     known: np.ndarray  # blk_known as handed
     temp: np.ndarray  # the temperatures as handed
     t0_ns: int  # enqueue (trace_lib.now_ns)
@@ -771,8 +772,15 @@ class ContinuousScheduler:
             # imbalance; experts_hit sizes the weight bytes a forward
             # had to read.
             reg.counter("diffusion_blocks_total")
-            reg.counter("diffusion_forwards_total", ("kind",))
             reg.counter("diffusion_tokens_unmasked_total")
+            # A block's commit rides the next block's first forward
+            # (DESIGN.md "Block diffusion"), or never happens: no
+            # forward only commits, and the series says so at 0.
+            reg.counter("diffusion_forwards_total", ("kind",)).labels(
+                kind="commit")
+            for how in ("fused", "dropped"):
+                reg.counter("diffusion_commits_total", ("how",)).labels(
+                    how=how)
             # One block in flight (DESIGN.md "Block diffusion"): blocks
             # enqueued while another was unread (against dispatches_
             # total{kind="block"}: the share that hid the host), and
@@ -885,6 +893,13 @@ class ContinuousScheduler:
         # device fills the rest with the mask id) and how many are known.
         self.blk = np.zeros((S, max(self.block, 1)), np.int32)
         self.blk_known = np.zeros((S,), np.int32)
+        # The slot's PENDING block: generated by the last dispatch, its
+        # K/V not yet in the pages; the next dispatch's first forward
+        # commits it. It belongs to a placement (the admit_seq here, -1
+        # for none), and its tokens are slot s's row of the last
+        # dispatch's output, which never leaves the device for this.
+        self.blk_pending = np.full((S,), -1, np.int64)
+        self._pending_toks = None  # thread-owned: engine
         # The block enqueued and not yet read (at most one between two
         # engine steps), and when the last harvest returned: the
         # device begins a block enqueued ahead about then.
@@ -1542,6 +1557,8 @@ class ContinuousScheduler:
         self.recent[:] = -2
         self.blk[:] = 0
         self.blk_known[:] = 0
+        self.blk_pending[:] = -1
+        self._pending_toks = None  # a failed dispatch's output is lost
         self._inflight = None  # its pool is gone
         self._check_pool_invariant()
 
@@ -1941,6 +1958,16 @@ class ContinuousScheduler:
         self.recent[s] = -2
         self.blk[s] = 0
         self.blk_known[s] = 0
+        self._drop_pending(s)
+
+    def _drop_pending(self, s: int) -> None:
+        """Slot s's pending block will never be committed: its request
+        ended (nothing reads a last block's K/V) or lost the slot, and
+        its pages may be another's by the next enqueue."""
+        if self.blk_pending[s] >= 0:
+            self.blk_pending[s] = -1
+            self.metrics.inc("diffusion_commits_total",
+                             labels={"how": "dropped"})
 
     def _grow_slot(self, s: int, tokens: int,
                    req: _Request | None = None) -> bool:
@@ -3262,8 +3289,9 @@ class ContinuousScheduler:
     def _block_step(self) -> None:
         """Block mode's engine step: enqueue ONE `paged_block_step`, in
         which every live slot generates its open block by diffusion
-        (T denoising forwards + one commit forward of num_slots x B
-        lanes), THEN read the block enqueued a step earlier. One block
+        (T denoising forwards of num_slots x B lanes, the first with B
+        more lanes a slot that commit the block before), THEN read the
+        block enqueued a step earlier. One block
         is always in flight, so the harvest, `emit` and the next
         round's housekeeping, admission and prefill enqueue run while
         the device works (docs/DESIGN.md "Block diffusion": what the
@@ -3309,6 +3337,14 @@ class ContinuousScheduler:
         # Copies: the host changes its arrays while the block is in
         # flight, and on the CPU `jnp.asarray` shares the numpy buffer.
         known, temp = self.blk_known.copy(), self.temp.copy()
+        # The riders whose block before this one is still theirs to
+        # commit. Its tokens are the last dispatch's output as it lies
+        # on the device: nothing waits for them.
+        commit = np.zeros((self.num_slots,), bool)
+        for s, seq in riders.items():
+            commit[s] = self.blk_pending[s] == seq
+        if self._pending_toks is None:
+            self._pending_toks = jnp.zeros(self.blk.shape, jnp.int32)
 
         def handed(a):
             return jnp.asarray(a.copy())
@@ -3328,6 +3364,8 @@ class ContinuousScheduler:
                     jnp.asarray(temp),
                     handed(self.top_p),
                     handed(self.top_k),
+                    self._pending_toks,
+                    jnp.asarray(commit),
                     steps=gen.denoising_steps or self.block,
                     remasking=gen.remasking,
                     threshold=gen.confidence_threshold,
@@ -3336,17 +3374,21 @@ class ContinuousScheduler:
                     compute_dtype=oryx.compute_dtype(self.cfg),
                 )
         self.kv_pages, toks, _, _, _, self.keys, counts = out
+        self._pending_toks = toks
         self.lengths[~self.finished] += self.block  # the device's `live`
         self.blk[:] = 0
         self.blk_known[:] = 0
-        for s in riders:
+        for s, seq in riders.items():
             req = self.slots[s]
+            self.blk_pending[s] = seq
             if int(self.lengths[s]) - req.length >= req.max_new:
-                self.finished[s] = True  # its last block is this one
+                self.finished[s] = True  # its last block is this one,
+                self._drop_pending(s)  # which nothing will read
         if ahead:
             self.metrics.inc("block_dispatches_ahead_total")
         return _BlockFlight(
-            toks=toks, counts=counts, riders=riders, known=known,
+            toks=toks, counts=counts, riders=riders,
+            fused=int(commit.sum()), known=known,
             temp=temp, t0_ns=t0_ns, sampled=sampled,
             captured=sampled or self._profile_active is not None,
         )
@@ -3410,7 +3452,7 @@ class ContinuousScheduler:
                 if req.adm_span >= 0:
                     req.trace.end(req.adm_span)
                     req.adm_span = -1
-            self._count_block_dispatch(len(flight.riders), stats)
+            self._count_block_dispatch(flight, stats)
             self._finish_dispatch(
                 "block", len(flight.riders) * self.block, live,
                 {s: [int(t) for t in toks[s, flight.known[s]:]]
@@ -3421,14 +3463,18 @@ class ContinuousScheduler:
             )
             self._occupancy_gauge()
 
-    def _count_block_dispatch(self, live: int, stats: dict) -> None:
+    def _count_block_dispatch(self, flight: _BlockFlight,
+                              stats: dict) -> None:
         """The diffusion_* and moe_* families for one block dispatch
-        (`stats`: generate.BLOCK_STATS by name)."""
+        (`stats`: generate.BLOCK_STATS by name). Every forward has a
+        head and denoises; the first also commits `flight.fused`
+        slots' blocks before it."""
         m = self.metrics
-        m.inc("diffusion_blocks_total", live)
-        m.inc("diffusion_forwards_total", stats["forwards"] - 1,
+        m.inc("diffusion_blocks_total", len(flight.riders))
+        m.inc("diffusion_forwards_total", stats["forwards"],
               labels={"kind": "denoise"})
-        m.inc("diffusion_forwards_total", 1, labels={"kind": "commit"})
+        m.inc("diffusion_commits_total", flight.fused,
+              labels={"how": "fused"})
         m.inc("diffusion_tokens_unmasked_total", stats["unmasked"])
         m.inc("moe_rows_routed_total", stats["moe_rows_routed"])
         m.inc("moe_expert_rows_max_total", stats["moe_rows_max"])
@@ -4389,9 +4435,13 @@ class ContinuousScheduler:
         # the device never fed back (tok0 of a max_tokens=1 request
         # finishing at activation) has no KV at its slot, and donating
         # it would poison the cache with prefill pad garbage.
+        # In block mode the last block a slot was enqueued for is not
+        # in the pages (it is pending, or it opened behind the reply's
+        # last block and holds nothing final): `lengths` less a block.
         self._donate_prefix(
             s, req,
-            min(req.length + len(req.emitted), int(self.lengths[s])),
+            min(req.length + len(req.emitted),
+                int(self.lengths[s]) - self.block),
         )
         self._clear_slot(s)
         req.handle.reply = req.text_done

@@ -109,11 +109,12 @@ def test_scheduler_generates_the_reference_loops_tokens(pipe):
     assert metrics.get("prefix_cache_hit_tokens_total") > 0
     blocks = metrics.get("diffusion_blocks_total")
     assert blocks >= sum(-(-cap // 4) for _, cap, _ in reqs)
-    # T + 1 = 3 forwards a dispatch; slot-forwards of live slots with
-    # work against slot-forwards dispatched.
+    # T = 2 forwards a dispatch (a block's commit rides the next
+    # block's first); slot-forwards of live slots with work against
+    # slot-forwards dispatched.
     useful = metrics.get("decode_steps_useful")
     total = metrics.get("decode_steps_total")
-    assert 0 < useful <= blocks * 3 and useful <= total
+    assert 0 < useful <= blocks * 2 and useful <= total
     assert total % 2 == 0  # every forward runs both slots
     assert metrics.get("diffusion_tokens_unmasked_total") <= blocks * 4
     routed = metrics.get("moe_rows_routed_total")
@@ -124,7 +125,10 @@ def test_scheduler_generates_the_reference_loops_tokens(pipe):
         "moe_expert_rows_mean_total")
     assert metrics.get("moe_experts_hit_total") > 0
     text = metrics.registry.render()
-    assert 'diffusion_forwards_total{kind="commit"}' in text
+    assert 'diffusion_forwards_total{kind="commit"} 0' in text
+    how = metrics.registry.counter("diffusion_commits_total", ("how",))
+    assert how.labels(how="fused").value + how.labels(
+        how="dropped").value == blocks
     assert 'engine_phase_seconds_total{phase="denoise"}' in text
     assert 'phase="decode"' not in text
     assert 'dispatches_total{kind="block"}' in text
@@ -132,7 +136,7 @@ def test_scheduler_generates_the_reference_loops_tokens(pipe):
 
 def test_whole_page_prefix_hit_gives_the_cold_prefills_tokens(pipe):
     """A page cached by one request (its K/V written under the block
-    mask, by prefill and by commit forwards) serves a later request
+    mask, by prefill and by the commit lanes of later blocks) serves a later request
     with the same prefix: same tokens as that request served cold."""
     q, longer = "the same opening words, ", "the same opening words, and more"
     cold = _sched(pipe, prefix_cache=False)
@@ -346,7 +350,10 @@ def test_metrics_endpoint_has_the_block_families(server):
                  "oryx_serving_moe_rows_routed_total",
                  "oryx_serving_moe_expert_rows_max_total",
                  "oryx_serving_moe_expert_rows_mean_total",
-                 "oryx_serving_moe_experts_hit_total"):
+                 "oryx_serving_moe_experts_hit_total",
+                 'oryx_serving_diffusion_forwards_total{kind="commit"} 0',
+                 'oryx_serving_diffusion_commits_total{how="fused"}',
+                 'oryx_serving_diffusion_commits_total{how="dropped"}'):
         assert name in text
     assert 'phase="denoise"' in text
 

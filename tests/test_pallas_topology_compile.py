@@ -301,8 +301,8 @@ def test_serve_program_keeps_the_kv_pool_in_place(
 
 def test_block_step_passes_the_expert_kernels_whole(one_chip, mosaic):
     """`paged_block_step` of the SDAR-30B-A3B widths at depth 2 (32
-    slots x 4 lanes, 128 experts top-8 at width 768; vocabulary 1024 as
-    above): the grouped expert products are Mosaic kernels (the grouped
+    slots x 4 lanes and the 4 commit lanes a slot of its first forward,
+    128 experts top-8 at width 768; vocabulary 1024 as above): the grouped expert products are Mosaic kernels (the grouped
     matmul jax ships, which `_grouped_dot` picks under attn_impl
     "pallas" at these widths; no `ragged-dot` of XLA's is left and no
     masked dense product), the donated pool is aliased, and no
@@ -340,6 +340,7 @@ def test_block_step_passes_the_expert_kernels_whole(one_chip, mosaic):
         rows(jnp.bool_),
         on_chip(lambda: jax.random.split(jax.random.key(0), S)),
         rows(jnp.float32), rows(jnp.float32), rows(jnp.int32),
+        rows(jnp.int32, cfg.block_length), rows(jnp.bool_),  # the pending block
         steps=2, remasking="low_confidence_static", threshold=0.9,
         eos=0, attn_impl="pallas", compute_dtype=BF16,
     ).compile()

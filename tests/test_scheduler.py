@@ -1105,3 +1105,231 @@ def test_block_run_replays_from_its_journal(block_params, tmp_path):
     matched, total, bad = rj.reply_match(entries, res["entries"])
     assert matched == total == 3, bad
     assert not res["feed_errors"] and not res["timed_out"]
+
+
+# ---------------------------------------------------------------------------
+# Block mode's deferred commit (docs/DESIGN.md "Block diffusion"): block
+# n's K/V is written by commit lanes of block n+1's first forward, and a
+# pending block belongs to the placement that generated it
+# ---------------------------------------------------------------------------
+
+
+def _commit_counts(metrics):
+    how = metrics.registry.counter("diffusion_commits_total", ("how",))
+    return (how.labels(how="fused").value, how.labels(how="dropped").value,
+            metrics.get("diffusion_blocks_total"))
+
+
+def _read_one_dispatch_at_a_time(sched):
+    """The same engine with no block in flight behind a harvest: every
+    dispatch is read before the next is enqueued."""
+    def step():
+        sched._drain_block()
+        sched._inflight = sched._enqueue_block(ahead=False)
+        sched._drain_block()
+
+    sched._block_step = step
+
+
+PENDING_CASES = [
+    "eos_learned_late", "cancel", "eviction_and_replay", "forced_drains",
+]
+
+
+@pytest.mark.parametrize("case", PENDING_CASES)
+def test_pending_blocks_leave_the_streams_of_a_serial_engine(
+    block_params, case
+):
+    """Whatever ends a placement while its block is pending (an EOS
+    learned a block late, a hang-up, an eviction and its replay) and
+    wherever the engine drains, the replies are byte for byte those of
+    the engine that reads one dispatch at a time, and once the engine
+    is idle every slot-block was committed by a fused forward or
+    dropped, never both, never neither."""
+    pipe = _block_pipe(block_params)
+    qs = ["hello there", "what now? " * 6, "tell me more!", "and a tail.."]
+    kw, skip, cancel_after = {}, (), None
+    reqs = [({"question": q}, cap, None)
+            for q, cap in zip(qs, (16, 40, 10, 12))]
+    if case == "eos_learned_late":
+        (free, _), _ = _block_want(pipe, {"question": qs[0]}, 16)
+        pipe = _block_pipe(block_params, eos_token_id=free[5])
+    elif case == "cancel":
+        skip, cancel_after = (1,), 2
+    elif case == "eviction_and_replay":
+        reqs = [({"question": "a" * 40}, 60, None),
+                ({"question": "b" * 40}, 60,
+                 {"temperature": 0.8, "top_p": 0.9, "seed": 3})]
+        kw = {"num_pages": 10}
+
+    def serve(serial):
+        def hook(sched, handles):
+            if serial:
+                _read_one_dispatch_at_a_time(sched)
+            enqueue, step, calls = sched._enqueue_block, sched._block_step, []
+            rode = []
+
+            def enqueue_and_hang_up(ahead):
+                flight = enqueue(ahead)
+                rode.extend(
+                    s for s in (flight.riders if flight else ())
+                    if sched.slots[s].handle is handles[1])
+                if len(rode) == cancel_after:
+                    handles[1].cancelled = True
+                return flight
+
+            def drain_every_third():
+                calls.append(0)
+                if len(calls) % 3 == 0:
+                    sched._drain_block()
+                step()
+
+            if cancel_after:
+                sched._enqueue_block = enqueue_and_hang_up
+            if case == "forced_drains":
+                sched._block_step = drain_every_third
+
+        _, results, metrics = _serve_blocks(
+            pipe, reqs, skip=skip, hook=hook, **kw)
+        fused, dropped, blocks = _commit_counts(metrics)
+        assert fused + dropped == blocks and fused > 0 and dropped > 0
+        return results, metrics
+
+    piped, metrics = serve(serial=False)
+    serial, _ = serve(serial=True)
+    assert piped == serial
+    assert sum(r is not None for r in piped) == len(reqs) - len(skip)
+    assert metrics.get("block_dispatches_ahead_total") > 0
+    if case == "cancel":
+        assert metrics.get("cancelled") == 1
+    if case == "eviction_and_replay":
+        assert metrics.get("evicted") >= 1
+    if case == "eos_learned_late":
+        assert metrics.get("block_rows_dropped_total") >= 1
+
+
+@pytest.mark.parametrize("stale", [False, True])
+def test_a_refilled_slot_is_not_written_by_its_last_owners_pending_block(
+    block_params, stale
+):
+    """A request ends on an EOS learned one block late; the next one
+    takes its slot, is given the pages it freed (poisoned as they are
+    freed) and is prefilled while a block is in flight. The prompt's
+    K/V the newcomer prefilled is byte for byte there when it finishes:
+    the old request's pending block, whose commit lanes would land on
+    the newcomer's last prompt block, went with its placement. `stale`
+    is the engine that forgets to clear it (the pending block kept as a
+    flag of the slot): the same scenario must show the pages
+    overwritten and the reply changed."""
+    pipe = _block_pipe(block_params)
+    qs = ["hello there", "what now? " * 6, "and a tail.."]
+    (free, _), n0 = _block_want(pipe, {"question": qs[0]}, 16)
+    eos = free[5]
+    pipe = _block_pipe(block_params, eos_token_id=eos)
+    (long, _), n1 = _block_want(pipe, {"question": qs[1]}, 40)
+    assert eos not in long
+    reqs = [({"question": qs[1]}, 40, None), ({"question": qs[0]}, 16, None),
+            ({"question": qs[2]}, 12, None)]
+    pages = [-(-(n + c) // 16) + 1 for n, c in ((n0, 16), (n1, 40))]
+    seen = {"first_commit": [], "poisoned": 0}
+
+    def hook(sched, handles):
+        activate, finish, free_pages = (
+            sched._activate_block, sched._finish, sched._free_slot_pages)
+        enqueue = sched._enqueue_block
+
+        def prompt_kv(s, req):
+            head = req.length - req.length % 4
+            pos = np.arange(head)
+            held = sched.bt[s, pos // 16]
+            return np.stack([
+                np.asarray(sched.kv_pages[p])[:, held, pos % 16]
+                for p in ("k", "v")])
+
+        def activate_and_look(s, req):
+            if stale and sched.blk_pending[s] >= 0:
+                sched.blk_pending[s] = req.admit_seq
+            activate(s, req)
+            if req.handle is handles[2]:
+                seen["slot"], seen["before"] = s, prompt_kv(s, req)
+
+        def enqueue_and_look(ahead):
+            first = [
+                s for s, r in enumerate(sched.slots)
+                if r is not None and r.handle is handles[2] and r.activated
+                and "rode" not in seen]
+            flight = enqueue(ahead)
+            if first:
+                seen["rode"] = True
+                seen["first_commit"].append(flight.fused)
+                seen["riders"] = len(flight.riders)
+            return flight
+
+        def finish_and_look(s, reason, completion):
+            req = sched.slots[s]
+            if req.handle is handles[2]:
+                seen["after"] = prompt_kv(s, req)
+            finish(s, reason, completion)
+
+        def free_and_poison(s, owner=None):
+            held = [int(p) for p in sched.bt[s] if p != sched._sentinel]
+            free_pages(s, owner)
+            if held and sched.slots[s].handle is handles[1]:
+                seen["poisoned"] = len(held)
+                sched.kv_pages = {
+                    k: v.at[:, np.asarray(held)].set(1e4)
+                    for k, v in sched.kv_pages.items()}
+
+        sched._activate_block = activate_and_look
+        sched._finish = finish_and_look
+        sched._free_slot_pages = free_and_poison
+        sched._enqueue_block = enqueue_and_look
+        if stale:
+            sched._drop_pending = lambda s: None
+
+    _, results, metrics = _serve_blocks(
+        pipe, reqs, hook=hook, num_pages=sum(pages) + 1, prefix_cache=False)
+    assert seen["poisoned"] and "after" in seen
+    (want, margins), _ = _block_want(pipe, reqs[2][0], 12, eos=eos)
+    got = _reply_ids(results[2][0])
+    if stale:
+        # The newcomer's first ride committed somebody else's block.
+        assert seen["first_commit"] == [seen["riders"]]
+        assert not np.array_equal(seen["after"], seen["before"])
+        assert got != want[:len(got)] or len(got) != len(want)
+        return
+    # Its first ride commits the long request's block and not its own.
+    assert seen["first_commit"] == [seen["riders"] - 1]
+    np.testing.assert_array_equal(seen["after"], seen["before"])
+    _assert_tokens(got, want, margins)
+    fused, dropped, blocks = _commit_counts(metrics)
+    assert fused + dropped == blocks
+
+
+def test_block_counters_after_the_deferred_commit(block_params):
+    """No forward only commits, and the series says so at 0; a commit
+    is fused or dropped; a slot-block of B masked positions costs its
+    slot T forwards, not T + 1."""
+    pipe = _block_pipe(block_params)
+    reqs = []
+    for q in ("hello there", "what now? " * 6, "tell me more!"):
+        while len(pipe._prepare_request({"question": q})[0]) % 4:
+            q += "!"
+        reqs.append(({"question": q}, 16, None))  # 4 whole blocks each
+    _, results, metrics = _serve_blocks(pipe, reqs)
+    assert all(reason == "length" for _, reason, _ in results)
+    reg = metrics.registry
+    kinds = reg.counter("diffusion_forwards_total", ("kind",))
+    text = reg.render()
+    assert 'diffusion_forwards_total{kind="commit"} 0' in text
+    assert 'diffusion_commits_total{how="fused"}' in text
+    assert 'diffusion_commits_total{how="dropped"}' in text
+    fused, dropped, blocks = _commit_counts(metrics)
+    assert (fused, dropped, blocks) == (9, 3, 12)  # a request's last: dropped
+    T = pipe.cfg.generation.denoising_steps
+    assert metrics.get("decode_steps_useful") == blocks * T
+    assert metrics.get("diffusion_tokens_unmasked_total") == blocks * 4
+    dispatches = reg.counter("dispatches_total", ("kind",)).labels(
+        kind="block").value
+    assert kinds.labels(kind="denoise").value == dispatches * T
+    assert metrics.get("decode_steps_total") == dispatches * T * 2

@@ -171,11 +171,16 @@ def _generate_through_the_programs(cfg, params, ids, new, remasking, steps,
     known = np.asarray([n - head], np.int32)
     length = np.asarray([head], np.int32)
     out, forwards = [], []
+    # Every block's tokens go on, as they lie on the device, to the next
+    # dispatch, whose first forward commits them; the first has none.
+    toks, pending = jnp.zeros((1, B), jnp.int32), jnp.zeros((1,), bool)
     while len(out) < new:
         kv, toks, n_new, length, _, keys, counts = generate.paged_block_step(
             params, cfg, kv, bt, jnp.asarray(blk), jnp.asarray(known),
             jnp.asarray(length), jnp.zeros((1,), bool), keys, *one,
+            toks, pending,
             steps=steps, remasking=remasking, threshold=threshold, eos=-1)
+        pending = jnp.ones((1,), bool)
         assert int(n_new[0]) == B - known[0]
         out += [int(t) for t in np.asarray(toks)[0][known[0]:]]
         forwards.append(int(counts["stats"][0]))
@@ -188,7 +193,8 @@ def _generate_through_the_programs(cfg, params, ids, new, remasking, steps,
 def test_prefill_and_block_step_match_the_reference_loop(
         tiny, remasking, steps, tail):
     """`paged_prefill` + `paged_block_step` (paged pool, packed lanes,
-    the on-device denoising loop, the commit forward) generate the
+    the on-device denoising loop, each block's commit riding the next
+    block's first forward) generate the
     tokens of the reference's cache-less loop, for every unmasking rule
     and every prompt tail."""
     cfg, params = tiny
@@ -200,10 +206,10 @@ def test_prefill_and_block_step_match_the_reference_loop(
         cfg, params, ids, 10, remasking, steps, threshold)
     assert got == want
     if remasking == "low_confidence_static":
-        # T + 1 forwards a block; a tail of 3 leaves one position, which
+        # T forwards a block; a tail of 3 leaves one position, which
         # one denoising forward fills whatever T is.
-        first = min(steps, cfg.block_length - tail) + 1
-        assert forwards == [first] + [steps + 1] * (len(forwards) - 1)
+        first = min(steps, cfg.block_length - tail)
+        assert forwards == [first] + [steps] * (len(forwards) - 1)
 
 
 def test_block_step_skips_finished_slots_and_counts(tiny):
@@ -226,13 +232,15 @@ def test_block_step_skips_finished_slots_and_counts(tiny):
     assert list(np.asarray(finished)) == [False, True]
     np.testing.assert_array_equal(np.asarray(kv["k"][:, 2:4]), before)
     stats = dict(zip(generate.BLOCK_STATS, (int(x) for x in counts["stats"])))
-    assert stats["forwards"] == 3 and stats["unmasked"] == B
-    assert list(np.asarray(counts["slot_forwards"])) == [3, 0]
+    # Two forwards, no commit-only one; without a pending block none
+    # carries commit lanes.
+    assert stats["forwards"] == 2 and stats["unmasked"] == B
+    assert list(np.asarray(counts["slot_forwards"])) == [2, 0]
     routed, rows_max, hit = (stats[k] for k in generate.BLOCK_STATS[2:])
-    pairs = 3 * cfg.num_layers * 2 * B * cfg.num_experts_per_tok
+    pairs = 2 * cfg.num_layers * 2 * B * cfg.num_experts_per_tok
     assert routed == pairs == int(np.asarray(counts["expert_rows"]).sum())
     assert pairs / cfg.num_experts <= rows_max <= pairs
-    assert 0 < hit <= 3 * cfg.num_layers * cfg.num_experts
+    assert 0 < hit <= 2 * cfg.num_layers * cfg.num_experts
 
 
 def test_sampled_rows_go_through_the_sampler_and_are_reproducible(tiny):
