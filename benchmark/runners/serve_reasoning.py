@@ -1,0 +1,175 @@
+"""Parent side of the long-reasoning cell: a closed loop of single-turn
+requests (a short prompt, a thousand and more streamed tokens) over a
+text-only state-space hybrid whose slots hold a recurrent state beside
+their pages. The shape of runners/serve_docqa.py's `run` (child holds
+the chip, traffic made meanwhile, every shape warmed, window, scrape,
+reduce, then the comparison on what the window served):
+
+  - `client_lists`: the general generator's sessions (`traffic.
+    build_sessions`: one turn each here) are dealt to clients in turn
+    and client i of n starts i/n of the way through its list AT EVERY
+    SEED: the seed makes the words and the weights, never the order
+    (serve_latent's docstring says why).
+  - the child is `serve_reasoning_child.py` (its configuration keys,
+    its comparison, its traced slice's window on the device's clock).
+  - no prefix cache, so no copy-on-write program to warm.
+  - `correct` is decided AFTER the window, on what it served: the
+    `stop` that ends the child is answered with a `logit_check` event
+    (correctness_jamba.py). Nothing of it is inside `setup_s`.
+
+Never imports jax."""
+
+from __future__ import annotations
+
+import json
+import os
+import shutil
+import subprocess
+import sys
+import threading
+import time
+
+from benchmark import loadgen, traffic
+from benchmark.runners import serve
+
+HERE = os.path.dirname(os.path.abspath(__file__))
+ROOT = os.path.dirname(os.path.dirname(HERE))
+
+
+class Child(serve.Child):
+    """serve.Child around this cell's own child script."""
+
+    def __init__(self, conf: dict, seed: int, chips: int, rehearse: bool,
+                 trace_dir: str, log_path: str):
+        env = dict(os.environ)
+        env["PYTHONPATH"] = ROOT + os.pathsep + env.get("PYTHONPATH", "")
+        self.log = open(log_path, "w")
+        self.proc = subprocess.Popen(
+            [sys.executable, os.path.join(HERE, "serve_reasoning_child.py"),
+             "--config", json.dumps(conf), "--seed", str(seed),
+             "--chips", str(chips), "--rehearse", str(int(rehearse)),
+             "--trace-dir", trace_dir],
+            stdin=subprocess.PIPE, stdout=subprocess.PIPE, stderr=self.log,
+            text=True, cwd=ROOT, env=env,
+        )
+        self.events: list[dict] = []
+
+
+def client_lists(params: dict, seed: int, seconds: float) -> list[list]:
+    """What each client sends in the window: see the module's
+    docstring."""
+    clients = params["clients"]
+    n = int(clients * seconds * params.get("max_requests_per_client_s", 1.0))
+    per_client = [[] for _ in range(clients)]
+    for i, s in enumerate(traffic.build_sessions(params, seed, n)):
+        per_client[i % clients].extend(s)
+    return [traffic.rotated(c, i * len(c) // clients)
+            for i, c in enumerate(per_client)]
+
+
+def run(ctx: dict) -> dict:
+    wl, conf = ctx["workload"], ctx["config"]
+    seconds, seed = ctx["seconds"], ctx["seed"]
+    p = wl["traffic"]
+    trace_dir = os.path.join(ctx["out_dir"], "trace")
+    shutil.rmtree(trace_dir, ignore_errors=True)
+    child = Child(conf, seed, ctx["chips"], ctx["rehearse"], trace_dir,
+                  os.path.join(ctx["out_dir"], "serve_child.log"))
+    try:
+        # Traffic is made while the child initialises and compiles.
+        client_items = [loadgen.encode_bodies(c)
+                        for c in client_lists(p, seed, seconds)]
+        dev = child.wait_for("device", 600)
+        warm = loadgen.encode_bodies(
+            traffic.warmup_bodies(p, dev["embed_buckets"], seed))
+        ready = child.wait_for("ready", ctx["setup_timeout"])
+        port = ready["port"]
+
+        t_w = time.monotonic()
+        for payload, want in warm:
+            r = loadgen.send_stream("127.0.0.1", port, payload,
+                                    time.monotonic(), 900.0, want)
+            if not r["ok"]:
+                raise SystemExit(
+                    f"serve_reasoning: warm-up request failed: {r}")
+        burst = [warm[i % len(warm)] for i in range(
+            min(4, conf["layout"]["num_slots"]))]
+        loadgen.run_closed_loop(
+            "127.0.0.1", port, [[b] for b in burst], 600.0, until_done=True
+        )
+        warm_s = time.monotonic() - t_w
+
+        child.tell("arm", "armed")
+        scraped = serve.scrape(port)
+        setup_s = time.monotonic() - ctx["t_start"]
+        tracer, slice_ = None, {}
+        if ctx["trace"]:
+            tracer = threading.Thread(
+                target=serve._trace_slice, daemon=True,
+                args=(child, port, seconds, wl.get("trace_seconds", 3.0),
+                      slice_),
+            )
+            tracer.start()
+        res = loadgen.run_closed_loop(
+            "127.0.0.1", port, client_items, seconds,
+            start_gap_s=p.get("start_gap_s", 0.0),
+        )
+        after = serve.scrape(port)
+        if tracer is not None:
+            tracer.join()
+        end = child.tell("disarm", "disarmed", 300.0)
+        # The comparison comes after the window, on what it served.
+        check = child.tell("stop", "logit_check", ctx["setup_timeout"])
+    finally:
+        child.stop()
+    red = serve.reduce_requests(
+        res, first_token_limit_s=p.get("first_token_limit_s"))
+    delta = {k: after.get(k, 0.0) - scraped.get(k, 0.0) for k in after}
+    compiles = end.get("compiles")
+    raw = {"ttft_ms": red.pop("ttft_ms"), "tpot_ms": red.pop("tpot_ms")}
+    lateness = red.pop("lateness_ms")
+    problems = []
+    if not check["ok"]:
+        failed = [k for k, v in check.get("passed", {}).items() if not v]
+        problems.append(f"the served tokens' check failed: {', '.join(failed)}")
+    kinds = {w["kind"] for w in check.get("sample", [])}
+    want = set(p.get("check_sample_kinds", ()))
+    if not want <= kinds:
+        problems.append(f"the window finished no {sorted(want - kinds)} "
+                        "request to compare")
+    if compiles:
+        problems.append(f"{compiles} compiles inside the window: "
+                        f"{end.get('compile_counts')}")
+    if red["failed"]:
+        problems.append(f"{red['failed']} requests failed: {red['errors']}")
+    if red["completed"] == 0:
+        problems.append("no request completed")
+    if res.get("exhausted_clients"):
+        problems.append("a client ran out of requests before the window "
+                        "ended: raise max_requests_per_client_s")
+    device = dict(dev["device"], memory_peak_bytes=end["memory_peak_bytes"])
+    tr = end.get("trace") or {}
+    if tr:
+        tr["slice_counters"] = slice_.get("counters", {})
+        device["busy_s"], device["window_s"] = tr["busy_s"], tr["window_s"]
+    return {
+        "correct": not problems, "problems": problems,
+        "attempted": red["attempted"], "failed": red["failed"],
+        "end_to_end": {
+            "ttft_p90_ms": red["ttft_p90_ms"],
+            "tpot_p90_ms": red["tpot_p90_ms"],
+            "serve_tok_s": red["serve_tok_s"], "setup_s": setup_s,
+        },  # the manifest says which of these a cell reports
+        "device": device,
+        "requests": red, "requests_raw": raw, "lateness_ms": lateness,
+        "counters": delta, "trace": tr, "logit_check": check,
+        "setup": {
+            "events": [e for e in child.events
+                       if e["event"] in ("device", "init", "ready")],
+            "warmup_s": warm_s,
+            # Not set-up: here because run.py's info line carries this
+            # block, and the comparison's numbers belong on it.
+            "check_after_window": check,
+        },
+        "compiles_in_window": compiles,
+    }

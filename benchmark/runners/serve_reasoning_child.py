@@ -1,0 +1,326 @@
+"""The process that holds the chip in the long-reasoning cell of a
+state-space hybrid (AI21-Jamba2-3B).
+
+    configuration -> seeded weights (no vision tower) -> OryxInference
+    -> api_server.build_server(engine="continuous") -> serve_forever
+
+and then the same one-line commands on stdin and JSON events on stdout
+as runners/serve_docqa_child.py, whose `Served` record and tokenizer it
+uses as they are: between `arm` and `disarm` every request handed to
+the engine is kept with its handle; on `stop` the server is closed and
+its pool given back, a sample of the requests the window FINISHED is
+taken (`sample_served`) and the tokens the engine streamed for them go
+to correctness_jamba.logit_check with their prompts; the `logit_check`
+event follows `stop`, before `stopped`. Nothing of the comparison is
+inside `setup_s`. A program that lacks the configuration's preset (the
+parent commit) leaves at once, before it touches the device.
+
+What differs from the other children: the traced slice's `window_s` is
+the device planes' OWN extent (first op's start to last op's end on
+the trace's clock), not the host's stamp around start_trace /
+stop_trace: `busy_s` is a sum over the same events, so it can never
+read over `window_s` (ROADMAP B0(a)(i)).
+"""
+
+from __future__ import annotations
+
+import argparse
+import contextlib
+import gc
+import json
+import os
+import sys
+import threading
+import time
+
+HERE = os.path.dirname(os.path.abspath(__file__))
+ROOT = os.path.dirname(os.path.dirname(HERE))
+sys.path.insert(0, ROOT)
+
+from benchmark.runners.serve_docqa_child import _TOKEN, Served  # noqa: E402
+from benchmark.runners.serve_latent_child import (  # noqa: E402
+    PrefixTokenizer, say,
+)
+
+T_START = time.monotonic()
+TINY = "jamba_tiny"  # the rehearsal: no width holds
+
+# Configuration-file key (the source's own name) -> the program's, for
+# what program.check_widths does not know: a file whose layer order or
+# mixer geometry the program would not run is refused.
+_KEYS = {
+    "num_hidden_layers": "num_layers",
+    "attn_layer_period": "attn_layer_period",
+    "attn_layer_offset": "attn_layer_offset",
+    "mamba_d_state": "mamba_d_state",
+    "mamba_d_conv": "mamba_d_conv",
+    "mamba_expand": "mamba_expand",
+    "mamba_dt_rank": "mamba_dt_rank",
+    "mamba_conv_bias": "mamba_conv_bias",
+    "mamba_proj_bias": "mamba_proj_bias",
+    "tie_word_embeddings": "tie_word_embeddings",
+    "rms_norm_eps": "rms_norm_eps",
+    "max_position_embeddings": "max_position_embeddings",
+}
+
+
+def build_config(conf: dict):
+    """The named preset with the file's layout, through program.py's own
+    builder; then the file's layer order and mixer keys against what
+    the program will run."""
+    from oryx_tpu import config as cfg_lib
+
+    from benchmark import program
+
+    lay = conf["layout"]
+    if not hasattr(cfg_lib, lay["preset"]):
+        raise SystemExit(
+            f"config {conf.get('name')}: this program has no preset "
+            f"{lay['preset']!r}"
+        )
+    tiny = lay["preset"] == TINY
+    cfg = program.build_config(
+        {k: v for k, v in conf.items() if k not in program._WIDTHS}
+        if tiny else conf
+    )
+    if not tiny:
+        for key, attr in _KEYS.items():
+            got = getattr(cfg.llm, attr)
+            if key in conf and conf[key] != got:
+                raise SystemExit(
+                    f"config {conf.get('name')}: {key} {conf[key]} in the "
+                    f"file, {got} in the program"
+                )
+    return cfg
+
+
+def ref_sizes(conf: dict, cfg) -> dict:
+    """What the plain reference reads: the configuration file's
+    published keys (the tiny preset's own in the rehearsal, where the
+    file's widths do not hold)."""
+    from benchmark.reference import jamba_ref
+
+    if conf["layout"]["preset"] != TINY:
+        return jamba_ref.sizes_from_keys(conf)
+    llm = cfg.llm
+    return jamba_ref.sizes_from_keys({
+        "hidden_size": llm.hidden_size, "num_attention_heads": llm.num_heads,
+        "num_hidden_layers": llm.num_layers,
+        "attn_layer_period": llm.attn_layer_period,
+        "attn_layer_offset": llm.attn_layer_offset,
+        "mamba_expand": llm.mamba_expand, "mamba_d_state": llm.mamba_d_state,
+        "mamba_d_conv": llm.mamba_d_conv, "mamba_dt_rank": llm.mamba_dt_rank,
+        "num_key_value_heads": llm.num_kv_heads, "head_dim": llm.head_dim,
+        "rms_norm_eps": llm.rms_norm_eps,
+    })
+
+
+def device_extent_s(planes) -> float:
+    """Seconds from the first device event's start to the last one's
+    end, over every line of every device plane."""
+    from benchmark import trace as trace_lib
+
+    lo, hi = None, None
+    for plane in trace_lib.device_planes(planes):
+        for ln in plane.lines:
+            for ev in ln.events:
+                s, e = trace_lib._abs_ps(ln, ev)
+                lo = s if lo is None else min(lo, s)
+                hi = e if hi is None else max(hi, e)
+    return 0.0 if lo is None else (hi - lo) / 1e12
+
+
+def reduce_trace(trace_dir: str, host_window_s: float) -> dict:
+    """trace.reduce_planes with `window_s` from the device's own clock
+    (the host's stamp where the capture holds no device event: the CPU
+    rehearsal)."""
+    from benchmark import trace as trace_lib
+
+    files = trace_lib.find_xplane_files(trace_dir)
+    if not files:
+        return {}
+    planes = trace_lib.parse_xspace(files[-1])
+    extent = device_extent_s(planes)
+    out = trace_lib.reduce_planes(
+        planes, window_s=extent or host_window_s)
+    out["host_window_s"] = host_window_s
+    return out
+
+
+def serve_commands(trace_dir: str) -> None:
+    """Obey the parent's one-line commands until `stop`
+    (serve_latent_child.serve_commands with the trace's own window)."""
+    import jax
+
+    from oryx_tpu.analysis.sanitizers import recompile_watchdog
+
+    from benchmark import program
+
+    stack = contextlib.ExitStack()
+    wd = None
+    trace_t = {}
+    for line in sys.stdin:
+        cmd = line.strip()
+        if cmd == "arm":
+            wd = stack.enter_context(
+                recompile_watchdog(budget=10**9, action="record"))
+            say(event="armed")
+        elif cmd == "trace_start":
+            opts = jax.profiler.ProfileOptions()
+            opts.python_tracer_level = 0  # host spans, no py stacks
+            jax.profiler.start_trace(trace_dir, profiler_options=opts)
+            trace_t["start"] = time.monotonic()
+            say(event="trace_started")
+        elif cmd == "trace_stop":
+            trace_t["stop"] = time.monotonic()
+            jax.profiler.stop_trace()
+            say(event="trace_stopped",
+                seconds=trace_t["stop"] - trace_t["start"])
+        elif cmd == "disarm":
+            stack.close()
+            out = {
+                "event": "disarmed",
+                "compiles": int(wd.total) if wd else None,
+                "compile_counts": dict(wd.counts) if wd else {},
+                "memory_peak_bytes": program.memory_peak_bytes(),
+            }
+            if trace_t:
+                out["trace"] = reduce_trace(
+                    trace_dir, trace_t["stop"] - trace_t["start"])
+            say(**out)
+        elif cmd == "stop":
+            break
+
+
+def sample_served(served: Served, pipe, *, long_answer: int,
+                  prefill_chunk: int, max_positions: int):
+    """(prompts, streams, what each is): of the requests the window
+    finished in full, each a request of its own, while their positions
+    fit `max_positions`: the LONGEST answer of `long_answer` tokens or
+    more (two hundred and more decode chunks through the slot's
+    state), then the shortest request with a prompt of two prefill
+    chunks (the state carried from chunk to chunk, the second
+    right-padded) and the shortest with a prompt of one. The lists are
+    the same at every seed, so the sample is too, as far as the window
+    gets: which of the longest answers end inside it turns on a second
+    or two of pace."""
+    done = []
+    for request, max_new, h in served.items:
+        if not h.done.is_set() or h.error is not None or h.cancelled \
+                or h.finish_reason != "length":
+            continue
+        stream = [int(t) for t in _TOKEN.findall(h.reply or "")]
+        if len(stream) != max_new:
+            continue
+        ids = pipe._prepare_request(request)[0]
+        done.append(([int(t) for t in ids], stream))
+    size = lambda r: len(r[0]) + len(r[1])  # noqa: E731
+    kinds = (
+        ("long_answer", lambda r: len(r[1]) >= long_answer,
+         lambda r: -len(r[1])),
+        ("two_chunk", lambda r: len(r[0]) > prefill_chunk, size),
+        ("one_chunk", lambda r: len(r[0]) <= prefill_chunk, size),
+    )
+    prompts, streams, what, left = [], [], [], max_positions
+    for kind, fits, order in kinds:
+        for r in sorted(done, key=order):
+            if fits(r) and size(r) <= left:
+                done.remove(r)
+                prompts.append(r[0])
+                streams.append(r[1])
+                what.append({"kind": kind, "prompt_tokens": len(r[0]),
+                             "served_tokens": len(r[1])})
+                left -= size(r)
+                break
+    return prompts, streams, what
+
+
+def main(argv=None) -> int:
+    ap = argparse.ArgumentParser()
+    ap.add_argument("--config", required=True)  # resolved json, inline
+    ap.add_argument("--seed", type=int, required=True)
+    ap.add_argument("--chips", type=int, default=1)
+    ap.add_argument("--rehearse", type=int, default=0)
+    ap.add_argument("--trace-dir", default="")
+    args = ap.parse_args(argv)
+    conf = json.loads(args.config)
+
+    from benchmark import program
+
+    cfg = build_config(conf)  # leaves here where the preset is missing
+    cache_dir = program.configure_cache()
+    device = program.device_record(args.chips, rehearse=bool(args.rehearse))
+    from oryx_tpu.ops import packing
+
+    say(event="device", device=device, cache_dir=cache_dir,
+        embed_buckets=list(packing.DEFAULT_BUCKETS),
+        t=time.monotonic() - T_START)
+
+    import jax
+
+    from oryx_tpu.serve import api_server
+    from oryx_tpu.serve.pipeline import OryxInference
+
+    from benchmark import correctness_jamba
+
+    lay = conf["layout"]
+    t0 = time.monotonic()
+    params = program.seeded_params(cfg, args.seed, lay["dtype"])
+    say(event="init", seconds=time.monotonic() - t0,
+        params=int(sum(x.size for x in jax.tree.leaves(params))))
+
+    pipe = OryxInference(PrefixTokenizer(cfg.llm.vocab_size), params, cfg,
+                         template="plain")
+    srv = api_server.build_server(
+        pipe, port=0, engine="continuous", num_slots=lay["num_slots"],
+        page_size=lay["page_size"], decode_chunk=lay["decode_chunk"],
+        max_ctx=lay["max_ctx"], prefill_chunk=lay["prefill_chunk"],
+        kv_dtype=lay.get("kv_dtype", "bf16"),
+        prefix_cache=bool(lay.get("prefix_cache", False)),
+        max_tokens_limit=lay["max_ctx"], max_queue=lay.get("max_queue", 256),
+    )
+    threading.Thread(target=srv.serve_forever, daemon=True).start()
+    served = Served(srv.scheduler, sys.stdin)
+    sys.stdin = served  # serve_commands reads its lines through it
+    say(event="ready", port=srv.server_address[1],
+        t=time.monotonic() - T_START)
+    try:
+        serve_commands(args.trace_dir)
+    finally:
+        if srv.supervisor is not None:
+            srv.supervisor.stop()
+        srv.scheduler.close()
+        srv.shutdown()
+        srv.server_close()
+    if served.window_closed:
+        # The engine's pool goes before the reference's float32 layers
+        # and the twin's own pool come.
+        srv.scheduler.kv_pages = None
+        del srv
+        gc.collect()
+        about = conf["logit_check"]
+        t0 = time.monotonic()
+        prompts, streams, what = sample_served(
+            served, pipe, prefill_chunk=lay["prefill_chunk"],
+            **about["sample"])
+        if prompts:
+            check = correctness_jamba.logit_check(
+                params["llm"], cfg, args.seed, sizes=ref_sizes(conf, cfg),
+                page_size=lay["page_size"],
+                prefill_chunk=lay["prefill_chunk"],
+                decode_chunk=lay["decode_chunk"], max_ctx=lay["max_ctx"],
+                head=about["head"], tail=about["tail"],
+                prompts=prompts, served=streams,
+            )
+        else:
+            check = {"ok": False, "passed": {"sampled": False}}
+        say(event="logit_check", seconds=time.monotonic() - t0,
+            finished_in_window=sum(
+                1 for _, _, h in served.items if h.done.is_set()),
+            sample=what, **check)
+    say(event="stopped")
+    return 0
+
+
+if __name__ == "__main__":
+    sys.exit(main())

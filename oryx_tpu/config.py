@@ -16,6 +16,21 @@ from dataclasses import dataclass, field
 from typing import Any
 
 
+def unsupported_for_recurrent(mode: str) -> str:
+    """The one refusal of a mode that is not built for a config with
+    state-space layers (`LLMConfig.recurrent`): beside the paged K/V of
+    its attention layers its pool holds ONE fixed-size state a slot a
+    Mamba layer, which no block table addresses and which only the
+    split engine's `paged_prefill` / `paged_decode_chunk` carry."""
+    return (
+        f"state-space layers (attn_layer_period > 0): {mode} is not built "
+        "for a recurrent state beside the paged pool (one conv window and "
+        "one [d_state, d_inner] state a slot a Mamba layer, addressed by "
+        "slot and not through a block table); it serves through the "
+        "continuous split engine with a bf16 pool and no prefix cache only"
+    )
+
+
 @dataclass(frozen=True)
 class LLMConfig:
     """Qwen2/Yi-class decoder geometry.
@@ -116,15 +131,64 @@ class LLMConfig:
     rope_mscale_all_dim: float = 0.0
     # q <- q * (1 + beta * ln(1 + floor(pos / original))) when > 0.
     llama4_scaling_beta: float = 0.0
+    # Layer kinds by position when attn_layer_period > 0 (the Jamba
+    # lineage): layer i attends iff i % attn_layer_period ==
+    # attn_layer_offset, every other layer is a Mamba-1 mixer
+    # (`models/mamba.py`: d_inner = mamba_expand * hidden_size channels,
+    # a depthwise causal conv of mamba_d_conv taps, a [mamba_d_state]
+    # state a channel, dt through a mamba_dt_rank bottleneck, RMSNorm on
+    # dt, B and C); every layer keeps the dense FFN. num_layers is a
+    # whole number of periods. 0 = every layer attends.
+    attn_layer_period: int = 0
+    attn_layer_offset: int = 0
+    mamba_d_state: int = 16
+    mamba_d_conv: int = 4
+    mamba_expand: int = 2
+    mamba_dt_rank: int = 0
+    mamba_conv_bias: bool = True
+    mamba_proj_bias: bool = False
+    # False: attention without rotary (or any other) position term; the
+    # state-space layers carry the order.
+    use_rope: bool = True
 
     @property
     def latent(self) -> bool:
         return self.kv_lora_rank > 0
 
     @property
+    def recurrent(self) -> bool:
+        """Some layers are state-space mixers with a per-slot state."""
+        return self.attn_layer_period > 0
+
+    @property
+    def mamba_d_inner(self) -> int:
+        return self.mamba_expand * self.hidden_size
+
+    @property
+    def num_attn_layers(self) -> int:
+        if not self.recurrent:
+            return self.num_layers
+        return self.num_layers // self.attn_layer_period
+
+    @property
+    def num_mamba_layers(self) -> int:
+        return self.num_layers - self.num_attn_layers
+
+    def state_bytes_per_slot(self, dtype_bytes: int = 2) -> int:
+        """Bytes of recurrent state ONE slot holds: a float32
+        [d_state, d_inner] state and a [d_conv - 1, d_inner] window in
+        the compute dtype, a Mamba layer."""
+        per = self.mamba_d_inner * (
+            4 * self.mamba_d_state + dtype_bytes * (self.mamba_d_conv - 1))
+        return self.num_mamba_layers * per
+
+    @property
     def cache_layers(self) -> int:
         """Cache layers of the paged pool: two a model layer in the
-        shortcut-connected double layer, else one."""
+        shortcut-connected double layer, the attention layers alone
+        where the others are state-space mixers, else one a layer."""
+        if self.recurrent:
+            return self.num_attn_layers
         return self.num_layers * (2 if self.shortcut_double_layer else 1)
 
     @property
@@ -264,6 +328,34 @@ class LLMConfig:
             raise ValueError(
                 "RoPE scaling and llama4_scaling_beta need "
                 "rope_original_max_position > 0"
+            )
+        if self.recurrent:
+            P = self.attn_layer_period
+            if not (0 <= self.attn_layer_offset < P
+                    and self.num_layers % P == 0 and P > 1
+                    and self.mamba_dt_rank > 0 and self.mamba_d_state > 0
+                    and self.mamba_d_conv > 1 and self.mamba_expand > 0):
+                raise ValueError(
+                    "state-space layers need 0 <= attn_layer_offset < "
+                    "attn_layer_period > 1, num_layers a whole number of "
+                    "periods, mamba_dt_rank, mamba_d_state, mamba_expand "
+                    f"> 0 and mamba_d_conv > 1, got {self}"
+                )
+            for bad, mode in (
+                (self.block_length, "generation by diffusion over blocks "
+                 "(block_length > 0: the block step program)"),
+                (self.latent, "latent attention (kv_lora_rank > 0)"),
+                (self.num_experts, "an expert layer (num_experts > 0)"),
+                (self.qk_norm or self.attention_bias,
+                 "q/k norm or attention bias"),
+            ):
+                if bad:
+                    raise ValueError(unsupported_for_recurrent(mode))
+        elif not self.use_rope:
+            raise ValueError(
+                "use_rope=False (attention without a position term) is "
+                "built for a config with state-space layers only "
+                "(attn_layer_period > 0)"
             )
 
 
@@ -466,6 +558,17 @@ class OryxConfig:
     # span. None/"" = off — the plain contiguous-sentinel layout. See
     # models/splice.expand_video_sentinels.
     frame_separator: str | None = None
+
+    def __post_init__(self):
+        if self.llm.recurrent:
+            m = self.mesh
+            for bad, mode in (
+                (m.num_devices > 1, f"a mesh ({m})"),
+                (self.attn_impl not in ("xla", "pallas"),
+                 f"attn_impl={self.attn_impl!r} (ring attention)"),
+            ):
+                if bad:
+                    raise ValueError(unsupported_for_recurrent(mode))
 
     # ---- (de)serialization -------------------------------------------------
 
@@ -858,6 +961,75 @@ def mistral4_tiny() -> OryxConfig:
             rope_mscale=1.0,
             rope_mscale_all_dim=1.0,
             llama4_scaling_beta=0.1,
+        ),
+        vision=None,
+        generation=GenerationConfig(eos_token_id=512),
+        dtype="float32",
+    )
+
+
+def jamba2_3b() -> OryxConfig:
+    """AI21-Jamba2-3B (ai21labs, config.json, `model_type: jamba`): 28
+    layers in two periods of 14, layers 7 and 21 attention (20 query
+    heads of 128 over ONE key/value head, no position term), the other
+    26 Mamba-1 mixers (5120 channels, state 16, conv 4, dt rank 160),
+    every layer with a dense SwiGLU of 8192 (`num_experts` 1: no
+    router), tied embedding of 65,536. Text-only. The layer order (i %
+    14 == 7 attends) is the family's modelling code, as the
+    configuration file's `assumed` says."""
+    return OryxConfig(
+        llm=LLMConfig(
+            vocab_size=65536,
+            hidden_size=2560,
+            intermediate_size=8192,
+            num_layers=28,
+            num_heads=20,
+            num_kv_heads=1,
+            head_dim=128,
+            rms_norm_eps=1e-6,
+            max_position_embeddings=262144,
+            tie_word_embeddings=True,
+            attention_bias=False,
+            attn_layer_period=14,
+            attn_layer_offset=7,
+            mamba_d_state=16,
+            mamba_d_conv=4,
+            mamba_expand=2,
+            mamba_dt_rank=160,
+            mamba_conv_bias=True,
+            mamba_proj_bias=False,
+            use_rope=False,
+        ),
+        vision=None,
+        # Past the vocabulary: seeded weights would sample a real id
+        # once in 65,536 tokens and end a request the traffic sized.
+        generation=GenerationConfig(eos_token_id=65536),
+    )
+
+
+def jamba_tiny() -> OryxConfig:
+    """Tiny state-space hybrid for tests: two periods of (2 Mamba, 1
+    attention, 1 Mamba), one key/value head, no positions."""
+    return OryxConfig(
+        llm=LLMConfig(
+            vocab_size=512,
+            hidden_size=64,
+            intermediate_size=128,
+            num_layers=8,
+            num_heads=4,
+            num_kv_heads=1,
+            head_dim=16,
+            rms_norm_eps=1e-6,
+            max_position_embeddings=2048,
+            tie_word_embeddings=True,
+            attention_bias=False,
+            attn_layer_period=4,
+            attn_layer_offset=2,
+            mamba_d_state=8,
+            mamba_d_conv=4,
+            mamba_expand=2,
+            mamba_dt_rank=8,
+            use_rope=False,
         ),
         vision=None,
         generation=GenerationConfig(eos_token_id=512),

@@ -478,7 +478,7 @@ def generate_stream(
 @partial(
     jax.jit,
     static_argnames=("cfg", "attn_impl", "compute_dtype", "return_routing",
-                     "held_stats"),
+                     "held_stats", "return_logits"),
     donate_argnames=("kv_pages",),
 )
 def paged_prefill(
@@ -498,6 +498,8 @@ def paged_prefill(
     compute_dtype=None,
     return_routing: bool = False,
     held_stats: bool = False,
+    slots: jnp.ndarray | None = None,  # [B] int32, see below
+    return_logits: bool = False,
 ):
     """Prompt prefill into a PAGED cache + first sampled token.
 
@@ -514,8 +516,23 @@ def paged_prefill(
     share, `cfg.experts_held`) appends, before that, the
     [len(SHARE_STATS)] int32 `share_stats` of the chunk's REAL rows
     (`kv_tokens` counts them), which the scheduler's moe_prefill_*
-    counters read."""
+    counters read.
+
+    `slots` (a config with state-space layers, `cfg.recurrent`, which
+    requires it): the engine slot each row's recurrent state lives at.
+    A chunk with start 0 begins from a zero state whatever the slot
+    held, any other from what the chunk before left there, and a
+    right-padded chunk leaves the state of its last REAL token
+    (`qwen2.forward`'s `state_slots`). return_logits (static; a twin
+    for the benchmark's comparison on a config without experts) appends
+    the [B, V] logits the first token was sampled from, last."""
     B, T, _ = inputs_embeds.shape
+    state = {}
+    if cfg.recurrent:
+        if slots is None:
+            raise ValueError(qwen2.unsupported_for_recurrent(
+                "a prefill without the rows' slot indices (slots=)"))
+        state = {"state_slots": slots.astype(jnp.int32)}
     start = jnp.broadcast_to(start.astype(jnp.int32), (B,))
     positions = start[:, None] + jnp.arange(T, dtype=jnp.int32)[None, :]
     page_size = paged_kv_lib.pool_plane(kv_pages).shape[2]
@@ -529,7 +546,7 @@ def paged_prefill(
         kv_cache=kv_pages, write_slots=start, kv_mask=kv_mask,
         block_tables=block_tables, kv_lengths=lengths,
         attn_impl=attn_impl, compute_dtype=compute_dtype,
-        return_routing=return_routing or held_stats,
+        return_routing=return_routing or held_stats, **state,
     )
     last = jnp.take_along_axis(
         logits, (lengths - 1 - start)[:, None, None].astype(jnp.int32),
@@ -546,6 +563,8 @@ def paged_prefill(
         out = out + (share_stats(cfg, routing[0]["ids"], real),)
     if return_routing:
         out = out + (dict(routing[0], logits=last),)
+    if return_logits:
+        out = out + (last,)
     return out
 
 
@@ -638,7 +657,7 @@ def paged_prefill_chunks(
     jax.jit,
     static_argnames=(
         "cfg", "chunk", "eos", "attn_impl", "compute_dtype", "numerics",
-        "return_routing",
+        "return_routing", "return_logits",
     ),
     donate_argnames=("kv_pages",),
 )
@@ -663,6 +682,7 @@ def paged_decode_chunk(
     compute_dtype=None,
     numerics: bool = False,
     return_routing: bool = False,
+    return_logits: bool = False,
 ):
     """`chunk` decode steps over a FIXED-SLOT batch with a paged cache —
     the continuous-batching inner loop. One compiled program per
@@ -691,7 +711,13 @@ def paged_decode_chunk(
     and decode_kv_tokens counters read. return_routing=True (the static
     twin for the benchmark's comparison, as `paged_prefill`'s) appends
     every step's logits [S, chunk, V] and expert ids [chunk, L, S, K],
-    last."""
+    last. return_logits=True (the same twin for a config without
+    experts) appends the logits alone.
+
+    A config with state-space layers (`cfg.recurrent`): lane s IS slot
+    s, so its recurrent state is row s of the pool's per-slot planes; a
+    live lane's state advances a token a step, a lane with `finished`
+    (ended, empty, or still prefilling) keeps its state untouched."""
     page_size = paged_kv_lib.pool_plane(kv_pages).shape[2]
     shared = bool(cfg.experts_held or cfg.zero_experts)
     K = block_tables.shape[1] * page_size
@@ -749,6 +775,8 @@ def paged_decode_chunk(
         ys = (tok, finished)
         if return_routing:
             ys = ys + (logits[:, 0], routing[0]["ids"])
+        if return_logits:
+            ys = ys + (logits[:, 0],)
         return out, ys
 
     carry0 = (kv_pages, tok, lengths, finished, recent, keys)
@@ -762,6 +790,8 @@ def paged_decode_chunk(
     out = out + carry[6:]
     if return_routing:
         out = out + (jnp.moveaxis(seen[0], 0, 1), seen[1])
+    if return_logits:
+        out = out + (jnp.moveaxis(seen[-1], 0, 1),)
     return out
 
 

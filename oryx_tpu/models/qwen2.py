@@ -26,7 +26,7 @@ import jax
 import jax.numpy as jnp
 from jax.ad_checkpoint import checkpoint_name
 
-from oryx_tpu.config import LLMConfig
+from oryx_tpu.config import LLMConfig, unsupported_for_recurrent
 from oryx_tpu.ops.attention import attention
 from oryx_tpu.ops.norms import rms_norm
 from oryx_tpu.ops.rope import apply_rope, rope_cos_sin, yarn_frequencies
@@ -47,6 +47,8 @@ def init_params(
     """Random-normal init (scale 0.02, zero biases) in the stacked layout."""
     if cfg.latent:
         return _init_latent_params(cfg, key, dtype)
+    if cfg.recurrent:
+        return _init_recurrent_params(cfg, key, dtype)
     L, H = cfg.num_layers, cfg.hidden_size
     Dq = cfg.num_heads * cfg.head_dim
     Dkv = cfg.num_kv_heads * cfg.head_dim
@@ -101,6 +103,57 @@ def init_params(
         params["layers"]["v_proj"]["bias"] = jnp.zeros((L, Dkv), dtype)
     if not cfg.tie_word_embeddings:
         params["lm_head"] = {"kernel": dense(next(keys), (H, cfg.vocab_size))}
+    return params
+
+
+def _init_recurrent_params(cfg: LLMConfig, key: jax.Array, dtype) -> Params:
+    """A state-space hybrid's weights: two homogeneous stacks, in layer
+    order within each. `layers["attn"]` [La, ...] is `_block`'s own set
+    (norms, q/k/v/o, the dense FFN) for the layers that attend;
+    `layers["mamba"]` [Lm, ...] holds the two norms, the FFN and under
+    `"mixer"` the mixer (`mamba.init_mixer_params`) of the others. The
+    head is the embedding, transposed (tied)."""
+    from oryx_tpu.models import mamba
+
+    H, I = cfg.hidden_size, cfg.intermediate_size
+    Dq = cfg.num_heads * cfg.head_dim
+    Dkv = cfg.num_kv_heads * cfg.head_dim
+    keys = iter(jax.random.split(key, 16))
+
+    def dense(shape):
+        return (
+            jax.random.normal(next(keys), shape, jnp.float32) * 0.02
+        ).astype(dtype)
+
+    def common(L):
+        return {
+            "input_norm": {"weight": jnp.ones((L, H), dtype)},
+            "post_attn_norm": {"weight": jnp.ones((L, H), dtype)},
+            "gate_proj": {"kernel": dense((L, H, I))},
+            "up_proj": {"kernel": dense((L, H, I))},
+            "down_proj": {"kernel": dense((L, I, H))},
+        }
+
+    La, Lm = cfg.num_attn_layers, cfg.num_mamba_layers
+    params: Params = {
+        "embed": {"weight": dense((cfg.vocab_size, H))},
+        "layers": {
+            "attn": dict(
+                common(La),
+                q_proj={"kernel": dense((La, H, Dq))},
+                k_proj={"kernel": dense((La, H, Dkv))},
+                v_proj={"kernel": dense((La, H, Dkv))},
+                o_proj={"kernel": dense((La, Dq, H))},
+            ),
+            "mamba": dict(
+                common(Lm),
+                mixer=mamba.init_mixer_params(cfg, next(keys), Lm, dtype),
+            ),
+        },
+        "final_norm": {"weight": jnp.ones((H,), dtype)},
+    }
+    if not cfg.tie_word_embeddings:
+        params["lm_head"] = {"kernel": dense((H, cfg.vocab_size))}
     return params
 
 
@@ -208,6 +261,7 @@ def init_paged_kv_cache(
     cfg: LLMConfig, num_pages: int, page_size: int,
     dtype: jnp.dtype = jnp.bfloat16,
     kv_dtype: str | None = None,
+    num_slots: int | None = None,
 ) -> Params:
     """Page-pool KV cache (ops/paged_kv.py): one pool of fixed-size
     pages shared by every sequence; rows address it through per-row
@@ -227,7 +281,35 @@ def init_paged_kv_cache(
     `copy_pages`, `fetch_page`, `upload_page`, the prefix cache, the
     spill tier and `sharding.paged_kv_spec` address. `forward` views it
     as L*P pages while it runs (see its `block_tables` contract) and
-    hands it back in this layout."""
+    hands it back in this layout.
+
+    A config with state-space layers (`cfg.recurrent`) has TWO kinds of
+    state in the one pytree: paged `k` / `v` over its attention layers
+    alone ([La, P, page, Hk, D]) and per-SLOT planes that no block
+    table addresses, `conv` [Lm, S, (K-1) * d] in `dtype` (the last
+    K - 1 conv inputs, flat so that the plane has no 3-row tile to pad)
+    and `ssm` [Lm, S, N, d] float32 (channels in the lanes), S =
+    `num_slots`. `ops/paged_kv.paged_planes` tells them apart."""
+    if cfg.recurrent:
+        from oryx_tpu.models import mamba
+
+        if kv_dtype not in (None, "bf16", "fp"):
+            raise ValueError(
+                unsupported_for_recurrent(f"kv_dtype={kv_dtype!r}"))
+        if num_slots is None:
+            raise ValueError(
+                "a config with state-space layers keeps a state a slot: "
+                "init_paged_kv_cache needs num_slots")
+        La, Lm = cfg.num_attn_layers, cfg.num_mamba_layers
+        d = cfg.mamba_d_inner
+        shape = (La, num_pages, page_size, cfg.num_kv_heads, cfg.head_dim)
+        return {
+            "k": jnp.zeros(shape, dtype), "v": jnp.zeros(shape, dtype),
+            mamba.CONV: jnp.zeros(
+                (Lm, num_slots, (cfg.mamba_d_conv - 1) * d), dtype),
+            mamba.SSM: jnp.zeros(
+                (Lm, num_slots, cfg.mamba_d_state, d), jnp.float32),
+        }
     if cfg.latent:
         # One plane of cfg.cache_layers layers (two a model layer in the
         # double layer, else one), no head axis: a token's row is
@@ -555,7 +637,8 @@ def _block(
     if cfg.qk_norm:
         q = rms_norm(q, lp["q_norm"]["weight"], cfg.rms_norm_eps)
         k = rms_norm(k, lp["k_norm"]["weight"], cfg.rms_norm_eps)
-    q, k = apply_rope(q, k, cos, sin)
+    if cos is not None:  # None: attention without a position term
+        q, k = apply_rope(q, k, cos, sin)
     # Post-rope tags for the "attn_qkv" remat policy (utils/remat.py):
     # saving here spares the backward both the projections and the rope.
     q = checkpoint_name(q, "attn_q")
@@ -855,6 +938,110 @@ def _latent_block(cfg: LLMConfig, h, lp: Params, cos, sin, *, pool, tables,
     return h + y.reshape(B, T, -1), pool, routing
 
 
+def _hybrid_layers(cfg: LLMConfig, layers: Params, h, *, block, kv_cache,
+                   block_tables, positions, kv_lengths, kv_mask,
+                   state_slots, attn_impl: str, remat):
+    """The layer stack of a config with state-space layers: a scan over
+    PERIODS of cfg.attn_layer_period layers whose body runs the
+    period's layers in order: an inner scan over the Mamba layers ahead
+    of the attention layer, the attention layer (`block`, forward's own
+    closure over `_block`), an inner scan over the Mamba layers behind
+    it. Every pool plane is CARRIED through both levels, never scanned
+    (forward's "THE POOL IS CARRIED" rule): `k` / `v` flat [La*P, ...]
+    behind layer-offset tables, `conv` / `ssm` whole, a layer reading
+    and writing its [S, ...] row in place. Layer weights are indexed by
+    the layer's number within its kind, which is what a scan does with
+    its xs. See forward's `state_slots` for whose state a row starts
+    from and leaves. Returns (h, the pool or None)."""
+    from oryx_tpu.models import mamba
+
+    per, off = cfg.attn_layer_period, cfg.attn_layer_offset
+    B, T, _ = h.shape
+    K1, d, N = cfg.mamba_d_conv - 1, cfg.mamba_d_inner, cfg.mamba_d_state
+    paged = kv_cache is not None
+    ck = cv = conv_pl = ssm_pl = None
+    if paged:
+        valid = positions < kv_lengths[:, None]
+        La, P = kv_cache["k"].shape[:2]
+        ck, cv = (kv_cache[n].reshape((La * P,) + kv_cache[n].shape[2:])
+                  for n in ("k", "v"))
+        conv_pl, ssm_pl = kv_cache[mamba.CONV], kv_cache[mamba.SSM]
+        fresh = (positions[:, 0] == 0)[:, None, None]
+    elif kv_mask is not None:
+        valid = kv_mask.astype(bool)
+    else:
+        valid = jnp.ones((B, T), bool)
+
+    def at(tree, i):
+        return jax.tree_util.tree_map(
+            lambda a: jax.lax.dynamic_index_in_dim(a, i, keepdims=False),
+            tree)
+
+    def mamba_layer(carry, li):
+        h, conv_pl, ssm_pl = carry
+        lp = at(layers["mamba"], li)
+        if paged:
+            conv0, h0 = at(conv_pl, li), at(ssm_pl, li)  # [S, ...]
+            if state_slots is not None:
+                conv0, h0 = mamba.rows_state(
+                    conv0, h0, state_slots, fresh, (B, K1, d))
+            else:
+                conv0 = conv0.reshape(B, K1, d)
+        else:
+            conv0 = jnp.zeros((B, K1, d), h.dtype)
+            h0 = jnp.zeros((B, N, d), jnp.float32)
+        u = rms_norm(h, lp["input_norm"]["weight"], cfg.rms_norm_eps)
+        with jax.named_scope("mamba"):
+            if paged and state_slots is None and T == 1:
+                out, (conv1, h1) = mamba.mixer_step(
+                    cfg, lp["mixer"], u, (conv0, h0), valid[:, 0])
+            else:
+                out, (conv1, h1) = mamba.mixer_prefill(
+                    cfg, lp["mixer"], u, (conv0, h0), valid, impl=attn_impl)
+        h = h + out
+        x = rms_norm(h, lp["post_attn_norm"]["weight"], cfg.rms_norm_eps)
+        h = h + _swiglu(x, lp)
+        if paged:
+            conv1 = conv1.reshape(B, K1 * d)
+            if state_slots is None:
+                conv_pl = jax.lax.dynamic_update_index_in_dim(
+                    conv_pl, conv1, li, 0)
+                ssm_pl = jax.lax.dynamic_update_index_in_dim(
+                    ssm_pl, h1, li, 0)
+            else:
+                conv_pl = conv_pl.at[li, state_slots].set(conv1)
+                ssm_pl = ssm_pl.at[li, state_slots].set(h1)
+        return (h, conv_pl, ssm_pl), None
+
+    def period(carry, p):
+        h, ck, cv, conv_pl, ssm_pl = carry
+        m0 = p * (per - 1)
+        (h, conv_pl, ssm_pl), _ = jax.lax.scan(
+            mamba_layer, (h, conv_pl, ssm_pl),
+            m0 + jnp.arange(off, dtype=jnp.int32))
+        tables = None
+        if paged:
+            tables = jnp.where(
+                block_tables >= P, La * P,
+                block_tables + p.astype(block_tables.dtype) * P)
+        h, ck, cv, _ = block(h, at(layers["attn"], p), ck, cv, tables)
+        (h, conv_pl, ssm_pl), _ = jax.lax.scan(
+            mamba_layer, (h, conv_pl, ssm_pl),
+            m0 + off + jnp.arange(per - 1 - off, dtype=jnp.int32))
+        return (h, ck, cv, conv_pl, ssm_pl), None
+
+    (h, ck, cv, conv_pl, ssm_pl), _ = jax.lax.scan(
+        wrap_remat(period, remat), (h, ck, cv, conv_pl, ssm_pl),
+        jnp.arange(cfg.num_layers // per, dtype=jnp.int32))
+    if not paged:
+        return h, None
+    return h, {
+        "k": ck.reshape((La, P) + ck.shape[1:]),
+        "v": cv.reshape((La, P) + cv.shape[1:]),
+        mamba.CONV: conv_pl, mamba.SSM: ssm_pl,
+    }
+
+
 def forward(
     params: Params,
     cfg: LLMConfig,
@@ -878,6 +1065,7 @@ def forward(
     return_hidden: bool = False,
     segment_ids: jnp.ndarray | None = None,
     return_routing: bool = False,
+    state_slots: jnp.ndarray | None = None,
 ) -> tuple[jnp.ndarray, Params | None]:
     """Full decoder forward.
 
@@ -928,6 +1116,15 @@ def forward(
         unused (the causal mask at each row's position IS the validity
         mask). This is the one-dispatch mixed prefill+decode serving
         path (models/generate.paged_ragged_step).
+      state_slots: a config with state-space layers (`cfg.recurrent`)
+        and a paged kv_cache only. [B] int32: row b's recurrent state
+        is row state_slots[b] of the pool's per-slot planes, a row
+        whose first position is 0 starts from zeros whatever the slot
+        held, and the state written back is the one after the row's
+        last REAL token (positions < kv_lengths). None: the batch IS the
+        slot array (B = S, row b = slot b; the decode chunk), a row
+        with kv_lengths 0 (a finished, empty or still-prefilling lane)
+        keeps its state untouched, and nothing is zeroed.
       segment_ids: [B, T] int32 SAMPLE ids for sequence-packed training
         (0 = pad): attention is causal in SLOT order and masked on
         segment equality, so samples packed into one row never attend
@@ -968,8 +1165,10 @@ def forward(
             ),
             scale=cfg.rope_cos_sin_scale,
         )
-    cos, sin = rope_cos_sin(
-        positions, rope_dim, cfg.rope_theta, **scaling)  # [B,T,D]
+    cos = sin = None  # attention without a position term
+    if cfg.use_rope:
+        cos, sin = rope_cos_sin(
+            positions, rope_dim, cfg.rope_theta, **scaling)  # [B,T,D]
 
     if kv_cache is not None and write_slots is None:
         write_slots = positions[:, 0]
@@ -1149,6 +1348,22 @@ def forward(
             new_cache = {
                 paged_kv.LATENT: pool.reshape((Lc, P) + pool.shape[1:])
             }
+    elif cfg.recurrent:
+        from oryx_tpu.ops import paged_kv
+
+        if (q_segments is not None or segment_ids is not None
+                or (kv_cache is not None and (
+                    block_tables is None or kv_lengths is None
+                    or paged_kv.paged_planes(kv_cache) is kv_cache))):
+            raise ValueError(unsupported_for_recurrent(
+                "a packed ragged step, packed training or a dense cache"
+            ))
+        h, new_cache = _hybrid_layers(
+            cfg, layers, h, block=block, kv_cache=kv_cache,
+            block_tables=block_tables, positions=positions,
+            kv_lengths=kv_lengths, kv_mask=kv_mask,
+            state_slots=state_slots, attn_impl=attn_impl, remat=remat,
+        )
     elif kv_cache is not None and block_tables is not None:
         # Paged pool: the scan's CARRY, one flat [L*P, page, ...] buffer a
         # plane behind layer-offset tables (the `block_tables` contract

@@ -434,6 +434,28 @@ def is_latent_pool(kv_pages) -> bool:
     return isinstance(kv_pages, dict) and LATENT in kv_pages
 
 
+# The per-SLOT planes of a pool whose model has state-space layers
+# (`qwen2.init_paged_kv_cache`): [Lm, S, ...], addressed by slot, never
+# through a block table. Everything that moves PAGES leaves them alone.
+SLOT_PLANES = ("conv", "ssm")
+
+
+def paged_planes(kv_pages):
+    """The pool without its per-slot planes: what a page index means
+    something in. `kv_pages` itself where it has none."""
+    if isinstance(kv_pages, dict) and SLOT_PLANES[1] in kv_pages:
+        return {k: v for k, v in kv_pages.items() if k not in SLOT_PLANES}
+    return kv_pages
+
+
+def _with_paged(kv_pages, paged):
+    """`paged` (an edited `paged_planes(kv_pages)`) back beside the
+    per-slot planes."""
+    if paged_planes(kv_pages) is kv_pages:
+        return paged
+    return {**kv_pages, **paged}
+
+
 def kv_pool_dtype(kv_pages) -> str:
     """The pool's wire format: "int8" / "fp8_e4m3" for a quantized
     pool, else the dense leaf dtype's name (e.g. "float32")."""
@@ -459,9 +481,9 @@ def copy_pages(kv_pages, src: jnp.ndarray, dst: jnp.ndarray):
     the page axis at position 1 — so COW moves the raw quantized bytes
     AND the page's scale block verbatim: share/splice/eviction-replay/
     spec-rollback semantics are untouched by the storage format."""
-    return jax.tree_util.tree_map(
-        lambda a: a.at[:, dst].set(a[:, src]), kv_pages
-    )
+    return _with_paged(kv_pages, jax.tree_util.tree_map(
+        lambda a: a.at[:, dst].set(a[:, src]), paged_planes(kv_pages)
+    ))
 
 
 def fetch_page(kv_pages, page: int):
@@ -472,7 +494,7 @@ def fetch_page(kv_pages, page: int):
     `upload_page` is its exact inverse, so spill -> reload is lossless
     by construction (same dtype, same bytes, no re-encode)."""
     return jax.tree_util.tree_map(
-        lambda a: np.asarray(a[:, page]), kv_pages
+        lambda a: np.asarray(a[:, page]), paged_planes(kv_pages)
     )
 
 
@@ -490,9 +512,10 @@ def upload_page(kv_pages, dst: jnp.ndarray, blob):
     (donated, in place; dst is a traced scalar — one compiled program
     per pool shape). The astype is a no-op by contract (same dtype
     both ways): the reload is byte-verbatim."""
-    return jax.tree_util.tree_map(
-        lambda a, b: a.at[:, dst].set(b.astype(a.dtype)), kv_pages, blob
-    )
+    return _with_paged(kv_pages, jax.tree_util.tree_map(
+        lambda a, b: a.at[:, dst].set(b.astype(a.dtype)),
+        paged_planes(kv_pages), blob,
+    ))
 
 
 def write_pages(

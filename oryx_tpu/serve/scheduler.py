@@ -543,6 +543,33 @@ class ContinuousScheduler:
             ):
                 if bad:
                     raise ValueError(qwen2.unsupported_for_latent(mode))
+        # A model with state-space layers keeps a recurrent state a slot
+        # beside its pages, which only the split engine's two programs
+        # carry: every other step program, pool format and tier is
+        # refused here, by name. Its prefix cache is constructed OFF (a
+        # hit hands over pages and no state; snapshots at page
+        # boundaries are ROADMAP R3(a)), with the refusal's words in
+        # the log.
+        self.recurrent = bool(pipe.cfg.llm.recurrent)
+        if self.recurrent:
+            for bad, mode in (
+                (ragged, "ragged=True"),
+                (speculate, "speculate"),
+                (fuse_steps != 1, "fuse_steps"),
+                (kv_dtype != "bf16", f"kv_dtype={kv_dtype!r}"),
+                (host_cache_bytes, "host_cache_bytes (the host spill tier)"),
+                (audit_sample_every, "audit_sample_every (the auditor's "
+                 "replay holds no state)"),
+                (getattr(pipe, "mesh", None) is not None,
+                 "the tensor-parallel engine (--engine sharded)"),
+            ):
+                if bad:
+                    raise ValueError(qwen2.unsupported_for_recurrent(mode))
+            if prefix_cache:
+                _LOG.info("prefix cache off: %s",
+                          qwen2.unsupported_for_recurrent(
+                              "prefix-cache splicing"))
+                prefix_cache = False
         # Optional SLO watcher (utils/anomaly.py): TTFT and queue-depth
         # breaches fire oryx_anomaly_total{kind=} + events.jsonl.
         self.anomaly = anomaly
@@ -825,6 +852,20 @@ class ContinuousScheduler:
             reg.counter("moe_expert_rows_max_total")
             reg.counter("moe_expert_rows_mean_total")
             reg.counter("decode_kv_tokens_total")
+        if self.recurrent:
+            # The recurrent state's accounting (docs/OBSERVABILITY.md
+            # "Recurrent state"): prompt tokens the prefill scan ran
+            # over, live lanes x steps of the decode update and the
+            # cached tokens their attention read (both from the lengths
+            # a chunk ran with and came back with), sequences that
+            # started from a zero state, and the planes' bytes.
+            reg.counter("ssm_prefill_tokens_total")
+            reg.counter("ssm_decode_lane_steps_total")
+            reg.counter("ssm_state_resets_total")
+            reg.counter("decode_kv_tokens_total")
+            reg.gauge("ssm_state_bytes").set(
+                num_slots * pipe.cfg.llm.state_bytes_per_slot(
+                    jnp.dtype(oryx.compute_dtype(pipe.cfg)).itemsize))
         self.allocator = paged_kv.PageAllocator(self.num_pages, page_size)
         # Page-pool observatory (utils/pagemap.py): oryx_pool_* gauges
         # refreshed at scrape time + the free-time page-lifetime/idle
@@ -869,11 +910,7 @@ class ContinuousScheduler:
         self.prefix_cache = (
             self._build_prefix_cache() if prefix_cache else None
         )
-        dtype = oryx.compute_dtype(self.cfg)
-        self.kv_pages = self._place_kv(qwen2.init_paged_kv_cache(
-            self.cfg.llm, self.num_pages, page_size, dtype=dtype,
-            kv_dtype=self._pool_kv_dtype(),
-        ))
+        self.kv_pages = self._new_pool()
         S = num_slots
         self._sentinel = self.allocator.sentinel
         self.bt = np.full((S, self.max_pages), self._sentinel, np.int32)
@@ -1115,6 +1152,16 @@ class ContinuousScheduler:
         (None = dense pages in the compute dtype)."""
         return None if self.kv_dtype == "bf16" else self.kv_dtype
 
+    def _new_pool(self):
+        """A zeroed pool, placed: the pages and, for a model with
+        state-space layers, the per-slot state planes."""
+        return self._place_kv(qwen2.init_paged_kv_cache(
+            self.cfg.llm, self.num_pages, self.page_size,
+            dtype=oryx.compute_dtype(self.cfg),
+            kv_dtype=self._pool_kv_dtype(),
+            **({"num_slots": self.num_slots} if self.recurrent else {}),
+        ))
+
     def _build_prefix_cache(self) -> PagedPrefixCache:
         """The prefix cache over the CURRENT allocator, host spill
         tier wired when --host-cache-bytes asked for one. The spill
@@ -1122,6 +1169,9 @@ class ContinuousScheduler:
         identity changes at every donated dispatch), and upload runs
         under the pipe's mesh scope so a heads-sharded pool re-places
         the page correctly."""
+        if self.recurrent:
+            raise ValueError(qwen2.unsupported_for_recurrent(
+                "prefix-cache splicing"))
         return PagedPrefixCache(
             self.allocator, metrics=self.metrics,
             host_cache_bytes=self.host_cache_bytes,
@@ -1542,11 +1592,7 @@ class ContinuousScheduler:
             # them into a fresh trie buys little against the complexity
             # of a partial-trust tier after a crash).
             self.prefix_cache = self._build_prefix_cache()
-        self.kv_pages = self._place_kv(qwen2.init_paged_kv_cache(
-            self.cfg.llm, self.num_pages, self.page_size,
-            dtype=oryx.compute_dtype(self.cfg),
-            kv_dtype=self._pool_kv_dtype(),
-        ))
+        self.kv_pages = self._new_pool()
         self.bt[:] = self._sentinel
         self._oom_episode = False
         self.slots = [None] * self.num_slots
@@ -2906,6 +2952,10 @@ class ContinuousScheduler:
                     compute_dtype=oryx.compute_dtype(self.cfg),
                     **({"held_stats": True} if self.prefill_held_stats
                        else {}),
+                    # The slot's state: zeroed by the chunk that starts
+                    # at 0, carried by every other.
+                    **({"slots": jnp.asarray([s], np.int32)}
+                       if self.recurrent else {}),
                 )
             req.trace.end(pf)
         self.kv_pages = kv
@@ -2913,6 +2963,10 @@ class ContinuousScheduler:
         req.prefill_pos = end
         req.cost_prefill_tokens += end - off
         self.metrics.inc("prefill_tokens_total", end - off)
+        if self.recurrent:
+            self.metrics.inc("ssm_prefill_tokens_total", end - off)
+            if off == 0:
+                self.metrics.inc("ssm_state_resets_total")
         # Token p attends positions 0..p: the chunk's causal pairs.
         self.metrics.inc(
             "prefill_attn_pairs_total", (end - off) * (off + end + 1) // 2)
@@ -3261,9 +3315,19 @@ class ContinuousScheduler:
         nstats = out[8] if numer else None
         (self.kv_pages, tok, lengths, finished, recent, self.keys,
          toks, fin) = out[:8]
+        ran_with = self.lengths
         toks, fin = self._harvest_chunk(
             tok, lengths, finished, recent, toks, fin
         )
+        if self.recurrent:
+            # A lane that was live for n steps advanced n positions and
+            # read a + 1 .. a + n cached tokens, a what it ran with.
+            a = ran_with.astype(np.int64)
+            n = self.lengths.astype(np.int64) - a
+            self.metrics.inc("ssm_decode_lane_steps_total", int(n.sum()))
+            self.metrics.inc(
+                "decode_kv_tokens_total",
+                int((n * a + n * (n + 1) // 2).sum()))
         share = None
         if self.share_stats:
             with self._phase("copy_out"):

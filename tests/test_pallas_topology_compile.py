@@ -547,3 +547,123 @@ def test_illegal_heads_per_block_pin_raises_with_its_name(
             ((64, 64, HK, D), BF16), ((4, 16), jnp.int32),
             ((16,), jnp.int32), ((16,), jnp.int32),
         )
+
+
+# --- AI21-Jamba2-3B (PR 40): ONE key/value head, the selective scan,
+# and the two step programs over a pool with per-slot state planes ---
+
+
+def test_page_walk_compiles_at_one_kv_head_with_twenty_query_heads(one_chip):
+    """`_ragged_paged` at Jamba2-3B's attention geometry: 20 query
+    heads over ONE key/value head (`legal_heads_per_block(1) = (1,)`),
+    64 decode rows, 64 pages of 64 a row, the two attention layers'
+    flat pool. Never compiled for the chip before this model."""
+    from oryx_tpu.ops.pallas import paged_attention as ppa
+
+    rows, Hq, page_size, maxp, layers = 64, 20, 64, 64, 2
+    r = ((rows,), jnp.int32)
+    kv = ((layers * rows * maxp, page_size, 1, D), BF16)
+    _compiled_text(
+        lambda q, k, v, bt, seg, pos: ppa.ragged_paged_attention(
+            q, k, v, bt, seg, pos, interpret=False),
+        one_chip, ((rows, Hq, D), BF16), kv, kv,
+        ((rows, maxp), jnp.int32), r, r,
+    )
+    _compiled_text(
+        lambda q, k, v, bt, lens: ppa.ragged_decode_attention(
+            q, k, v, bt, lens, interpret=False),
+        one_chip, ((rows, 1, Hq, D), BF16), kv, kv,
+        ((rows, maxp), jnp.int32), r,
+    )
+
+
+def test_selective_scan_compiles_at_the_cells_chunk(one_chip, mosaic):
+    """`_selective_scan` over one 512-token prefill chunk of 5120
+    channels with a [16, 5120] state: the [16, 512] state tile stays in
+    registers across the chunk, x / dt / z / y are [512, 512] blocks and
+    the lane-broadcast B and C slabs [512, 16, 128], all inside the 64
+    MiB the kernel asks of VMEM."""
+    from oryx_tpu.ops.pallas import selective_scan as ss
+
+    T, d, N = 512, 5120, 16
+    f32 = jnp.float32
+    seq, sel = ((1, T, d), f32), ((1, T, N), f32)
+    _compiled_text(
+        lambda x, dt, z, B, C, A, D_, h0: ss.selective_scan(
+            x, dt, z, B, C, A, D_, h0, impl="pallas"),
+        one_chip, seq, seq, seq, sel, sel, ((N, d), f32), ((d,), f32),
+        ((1, N, d), f32),
+    )
+
+
+@pytest.mark.parametrize("program", ["paged_decode_chunk", "paged_prefill"])
+def test_jamba_serve_programs_keep_pages_and_state_in_place(
+    one_chip, mosaic, program, capsys
+):
+    """The two programs of `jamba2-3b.reasoning` at the cell's FULL size
+    (28 layers, the whole vocabulary, 64 slots x 4,096, page 64, chunk
+    512 / 8), compiled for the described v5e: the donated pool (paged
+    K/V of the two attention layers AND the per-slot conv and state
+    planes of the 26 Mamba layers) is aliased to the output whole, and
+    the temporaries stay under five layers' state rows for all slots
+    (21 MB each, of the 26 layers' 545 MB: nothing copies a [26, 64,
+    ...] plane, which the period scan and its inner scans CARRY). The numbers printed here
+    are the configuration file's `memory` block and PERF.md section 4's
+    (arguments + temporaries under half the chip)."""
+    from oryx_tpu import config as cfg_lib
+    from oryx_tpu.models import generate, qwen2
+
+    def on_chip(make):
+        return jax.tree_util.tree_map(
+            lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=one_chip),
+            jax.eval_shape(make),
+        )
+
+    cfg = cfg_lib.jamba2_3b().llm
+    slots, page_size, ctx = 64, 64, 4096
+    S = slots if program == "paged_decode_chunk" else 1
+    rows = lambda dtype, *tail: jax.ShapeDtypeStruct(  # noqa: E731
+        (S, *tail), dtype, sharding=one_chip
+    )
+    params = on_chip(
+        lambda: qwen2.init_params(cfg, jax.random.key(0), dtype=BF16))
+    kv = on_chip(lambda: qwen2.init_paged_kv_cache(
+        cfg, slots * ctx // page_size, page_size, dtype=BF16,
+        num_slots=slots))
+    tables = rows(jnp.int32, ctx // page_size)
+    sampling = (
+        on_chip(lambda: jax.random.split(jax.random.key(0), S)),
+        rows(jnp.float32), rows(jnp.float32), rows(jnp.int32),
+    )
+    common = dict(attn_impl="pallas", compute_dtype=BF16)
+    if program == "paged_decode_chunk":
+        lowered = generate.paged_decode_chunk.lower(
+            params, cfg, kv, tables, rows(jnp.int32), rows(jnp.int32),
+            rows(jnp.bool_), rows(jnp.int32, 0), *sampling,
+            chunk=8, eos=65536, **common,
+        )
+    else:
+        lowered = generate.paged_prefill.lower(
+            params, cfg, rows(BF16, 512, cfg.hidden_size), rows(jnp.int32),
+            tables, kv, rows(jnp.int32), *sampling,
+            slots=rows(jnp.int32), **common,
+        )
+    compiled = lowered.compile()
+    text = compiled.as_text()
+    assert "tpu_custom_call" in text
+    assert ("_selective_scan" in text) == (program == "paged_prefill")
+    memory = compiled.memory_analysis()
+    nbytes = lambda t: sum(  # noqa: E731
+        a.size * a.dtype.itemsize for a in jax.tree_util.tree_leaves(t))
+    pool_bytes, weight_bytes = nbytes(kv), nbytes(params)
+    with capsys.disabled():
+        print(f"\n{program}: weights {weight_bytes} B, pool {pool_bytes} B, "
+              f"arguments {memory.argument_size_in_bytes} B, temporaries "
+              f"{memory.temp_size_in_bytes} B")
+    assert weight_bytes == 6_063_467_264
+    assert pool_bytes == 64 * 9_318_400 + 64 * ctx * 1024
+    assert memory.alias_size_in_bytes == pool_bytes
+    one_layers_state = slots * 16 * 5120 * 4
+    assert memory.temp_size_in_bytes < 5 * one_layers_state
+    total = memory.argument_size_in_bytes + memory.temp_size_in_bytes
+    assert 0.25 * 16e9 < total < 0.5 * 16e9
