@@ -32,6 +32,7 @@ import jax.numpy as jnp
 from oryx_tpu.config import LLMConfig
 from oryx_tpu.ops.norms import rms_norm
 from oryx_tpu.ops.paged_kv import SLOT_PLANES
+from oryx_tpu.ops.pallas import ssm_step
 from oryx_tpu.ops.pallas.selective_scan import selective_scan
 
 Params = dict[str, Any]
@@ -180,3 +181,63 @@ def mixer_step(cfg: LLMConfig, lp: Params, u, state, live):
     conv1 = jnp.where(keep, win[:, 1:].astype(conv0.dtype), conv0)
     h1 = jnp.where(keep, h1, h0)
     return _dense(y.astype(u.dtype), lp["out_proj"])[:, None], (conv1, h1)
+
+
+def step_fits(cfg: LLMConfig, S: int) -> bool:
+    """Whether `mixer_step_inplace`'s kernels take a pool of S slots."""
+    N = cfg.mamba_d_state
+    return ssm_step.fits(S, cfg.mamba_d_inner, N, cfg.mamba_dt_rank + 2 * N)
+
+
+def step_invariants(mp: Params, live, dtype) -> Params:
+    """What `mixer_step_inplace` reads that no layer's number enters,
+    made ONCE a step from the STACKED mixers `mp`, outside the scan
+    over layers (XLA leaves such ops inside a loop's body: there they
+    were eight device ops a layer): `chan` [Lm, rows, d] float32, the
+    per-channel plane of `ops/pallas/ssm_step.py`, the conv's bias and
+    taps rounded to `dtype` as `mixer_step` rounds them; `wdt`, the
+    stacked `dt_proj` as it is; `live` [B] bool as int32, [B] and
+    [B, 1]."""
+    conv = mp["conv"]
+    L, K, d = conv["kernel"].shape
+    bias = conv["bias"] if "bias" in conv else jnp.zeros((L, d), dtype)
+    norm = jnp.concatenate(
+        [mp[n]["weight"] for n in ("dt_norm", "b_norm", "c_norm")], axis=-1)
+    rows = [  # ssm_step.DT_BIAS, D_SKIP, CONV_BIAS, NORM, CONV_W.., A
+        mp["dt_proj"]["bias"][:, None], mp["D"][:, None],
+        bias.astype(dtype)[:, None],
+        jnp.pad(norm, ((0, 0), (0, d - norm.shape[1])))[:, None],
+        conv["kernel"].astype(dtype),
+        jnp.zeros((L, ssm_step.head_rows(K) - ssm_step.CONV_W - K, d), dtype),
+        -jnp.exp(mp["A_log"]),
+    ]
+    live = live.astype(jnp.int32)
+    return {
+        "chan": jnp.concatenate(
+            [r.astype(jnp.float32) for r in rows], axis=1),
+        "wdt": mp["dt_proj"]["kernel"], "live": live,
+        "live_col": live[:, None],
+    }
+
+
+def mixer_step_inplace(cfg: LLMConfig, lp: Params, inv: Params, li, u,
+                       planes):
+    """`mixer_step` on the pool's planes WHOLE, which is the decode
+    step's form under `attn_impl="pallas"` where `step_fits`: between
+    `in_proj`, `x_proj` and `out_proj` (layer li's slices `lp`, XLA's
+    matmuls) run the two kernels of `ops/pallas/ssm_step.py`, which
+    read and write layer li's rows of planes = (conv [Lm, S, (K-1) d],
+    ssm [Lm, S, N, d]) in place and take their small weights from the
+    stacked ones in `inv` (`step_invariants`) by the layer's number.
+    Lane b is slot b (S == B). Returns (out [B, 1, H], the planes)."""
+    conv_pl, ssm_pl = planes
+    xz = _dense(u[:, 0], lp["in_proj"])
+    xc, conv_pl = ssm_step.ssm_conv(
+        xz, inv["chan"], inv["live_col"], conv_pl, li)
+    rbc = xc @ lp["x_proj"]["kernel"].astype(xc.dtype)
+    with jax.named_scope("ssm_step"):
+        y, ssm_pl = ssm_step.ssm_step(
+            xc, xz, rbc, inv["wdt"], inv["chan"], inv["live"], ssm_pl, li,
+            eps=cfg.rms_norm_eps)
+    return _dense(y.astype(u.dtype), lp["out_proj"])[:, None], (
+        conv_pl, ssm_pl)

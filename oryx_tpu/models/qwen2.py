@@ -971,6 +971,16 @@ def _hybrid_layers(cfg: LLMConfig, layers: Params, h, *, block, kv_cache,
         valid = kv_mask.astype(bool)
     else:
         valid = jnp.ones((B, T), bool)
+    # One token a lane, lane b slot b. Under "pallas" the mixer's step
+    # is two kernels that take the planes whole and update layer li's
+    # rows in place (`mamba.mixer_step_inplace`); every other path, and
+    # a shape their tiles do not fit, slices the layer's rows out and
+    # writes them back around the mixer.
+    decode = paged and state_slots is None and T == 1
+    inplace = decode and attn_impl == "pallas" and mamba.step_fits(cfg, B)
+    if inplace:
+        step_inv = mamba.step_invariants(
+            layers["mamba"]["mixer"], valid[:, 0], h.dtype)
 
     def at(tree, i):
         return jax.tree_util.tree_map(
@@ -980,19 +990,22 @@ def _hybrid_layers(cfg: LLMConfig, layers: Params, h, *, block, kv_cache,
     def mamba_layer(carry, li):
         h, conv_pl, ssm_pl = carry
         lp = at(layers["mamba"], li)
-        if paged:
+        if not paged:
+            conv0 = jnp.zeros((B, K1, d), h.dtype)
+            h0 = jnp.zeros((B, N, d), jnp.float32)
+        elif not inplace:  # (the kernels index the planes by li)
             conv0, h0 = at(conv_pl, li), at(ssm_pl, li)  # [S, ...]
             if state_slots is not None:
                 conv0, h0 = mamba.rows_state(
                     conv0, h0, state_slots, fresh, (B, K1, d))
             else:
                 conv0 = conv0.reshape(B, K1, d)
-        else:
-            conv0 = jnp.zeros((B, K1, d), h.dtype)
-            h0 = jnp.zeros((B, N, d), jnp.float32)
         u = rms_norm(h, lp["input_norm"]["weight"], cfg.rms_norm_eps)
         with jax.named_scope("mamba"):
-            if paged and state_slots is None and T == 1:
+            if inplace:
+                out, (conv_pl, ssm_pl) = mamba.mixer_step_inplace(
+                    cfg, lp["mixer"], step_inv, li, u, (conv_pl, ssm_pl))
+            elif decode:
                 out, (conv1, h1) = mamba.mixer_step(
                     cfg, lp["mixer"], u, (conv0, h0), valid[:, 0])
             else:
@@ -1001,7 +1014,7 @@ def _hybrid_layers(cfg: LLMConfig, layers: Params, h, *, block, kv_cache,
         h = h + out
         x = rms_norm(h, lp["post_attn_norm"]["weight"], cfg.rms_norm_eps)
         h = h + _swiglu(x, lp)
-        if paged:
+        if paged and not inplace:
             conv1 = conv1.reshape(B, K1 * d)
             if state_slots is None:
                 conv_pl = jax.lax.dynamic_update_index_in_dim(

@@ -319,6 +319,39 @@ def test_engine_serves_four_requests_on_two_slots_with_the_counters(pipe):
         "num_attention_heads": 4, "num_key_value_heads": 1}, 4)
 
 
+@pytest.mark.parametrize("q,cap", QUESTIONS[:3])
+def test_engine_streams_are_the_same_under_both_impls(
+        pipe, q, cap, monkeypatch):
+    """`attn_impl="pallas"` (every kernel in interpret mode here: the
+    decode step of a Mamba layer is `_ssm_conv` and `_ssm_step` on the
+    planes whole, in place) serves what "xla" serves, which is the
+    reference's greedy continuation: two requests share the two slots,
+    so that the case's stream decodes beside another lane that prefills,
+    finishes and leaves its slot dead."""
+    traced = []
+    step = mamba.mixer_step_inplace
+    monkeypatch.setattr(
+        mamba, "mixer_step_inplace",
+        lambda *a: traced.append(serving) or step(*a))
+    jax.clear_caches()  # the decode program traces anew, through the spy
+    replies = {}
+    for serving in ("xla", "pallas"):
+        served = OryxInference(
+            IdTokenizer(), pipe.params,
+            dataclasses.replace(pipe.cfg, attn_impl=serving),
+            template="plain")
+        sched = _engine(served)
+        assert sched.pipe.cfg.attn_impl == serving
+        sched.start()
+        handles = [sched.submit({"question": text}, n, None)
+                   for text, n in ((q, cap), ("w" * 21, 3))]
+        replies[serving] = [h.result(timeout=600)[0] for h in handles]
+        sched.close()
+    assert traced and set(traced) == {"pallas"}  # "xla": mixer_step
+    assert replies["pallas"] == replies["xla"]
+    assert _ids(replies["pallas"][0]) == _want(pipe, q, cap)[0]
+
+
 def test_a_reused_slot_serves_what_a_fresh_engine_serves(pipe):
     """One slot, two requests in turn: the second finds the first's
     state at its slot and must start from zeros."""

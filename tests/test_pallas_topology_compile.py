@@ -596,6 +596,57 @@ def test_selective_scan_compiles_at_the_cells_chunk(one_chip, mosaic):
     )
 
 
+@pytest.mark.parametrize("slots", [64, 3])
+def test_ssm_step_kernels_compile_at_the_cells_shapes_in_place(
+    one_chip, mosaic, slots
+):
+    """`mamba.mixer_step_inplace` (`_ssm_conv` and `_ssm_step` around
+    `x_proj`, between `in_proj` and `out_proj`) for one Mamba layer of
+    `jamba2-3b.reasoning`'s decode step (d 5120, N 16, K 4, R 160,
+    the 26 layers' planes and stacked mixers WHOLE, the layer's number
+    an argument), over the engine's 64 lanes (blocks of 16) and the
+    comparison's twin pool of 3 (one block): Mosaic takes both, the two
+    donated planes are aliased to the outputs whole, and the only
+    temporaries are the step's invariants (`mamba.step_invariants`: 24
+    float32 rows a layer) and the [B, d] activations, far under ONE
+    layer's state rows: the alias held, nothing copies a plane or a
+    layer of it."""
+    from oryx_tpu import config as cfg_lib
+    from oryx_tpu.models import mamba
+
+    cfg = cfg_lib.jamba2_3b().llm
+    Lm, d, N, K = 26, cfg.mamba_d_inner, cfg.mamba_d_state, cfg.mamba_d_conv
+    on_chip = lambda shape, dtype: jax.ShapeDtypeStruct(  # noqa: E731
+        shape, dtype, sharding=one_chip)
+    mp = jax.tree_util.tree_map(
+        lambda a: on_chip(a.shape, a.dtype),
+        jax.eval_shape(lambda: mamba.init_mixer_params(
+            cfg, jax.random.key(0), Lm, BF16)))
+    assert mamba.step_fits(cfg, slots)
+
+    def step(u, mp, live, conv_pl, ssm_pl, li):
+        lp = jax.tree_util.tree_map(lambda a: a[li], mp)
+        return mamba.mixer_step_inplace(
+            cfg, lp, mamba.step_invariants(mp, live, u.dtype), li, u,
+            (conv_pl, ssm_pl))
+
+    planes = (on_chip((Lm, slots, (K - 1) * d), BF16),
+              on_chip((Lm, slots, N, d), jnp.float32))
+    compiled = jax.jit(step, donate_argnums=(3, 4)).lower(
+        on_chip((slots, 1, cfg.hidden_size), BF16), mp,
+        on_chip((slots,), jnp.bool_), *planes,
+        on_chip((), jnp.int32)).compile()
+    text = compiled.as_text()
+    assert text.count("tpu_custom_call") == 2
+    assert "_ssm_conv" in text and "_ssm_step" in text
+    memory = compiled.memory_analysis()
+    # (== at 64 slots; 3 rows of the conv plane pad to a sublane tile)
+    assert memory.alias_size_in_bytes >= sum(
+        p.size * p.dtype.itemsize for p in planes)
+    invariants = Lm * (8 + N) * d * 4
+    assert memory.temp_size_in_bytes < invariants + 16 * slots * d
+
+
 @pytest.mark.parametrize("program", ["paged_decode_chunk", "paged_prefill"])
 def test_jamba_serve_programs_keep_pages_and_state_in_place(
     one_chip, mosaic, program, capsys
@@ -607,7 +658,11 @@ def test_jamba_serve_programs_keep_pages_and_state_in_place(
     planes of the 26 Mamba layers) is aliased to the output whole, and
     the temporaries stay under five layers' state rows for all slots
     (21 MB each, of the 26 layers' 545 MB: nothing copies a [26, 64,
-    ...] plane, which the period scan and its inner scans CARRY). The numbers printed here
+    ...] plane, which the period scan and its inner scans CARRY; the
+    decode step also keeps its kernels' invariants, 24 float32 rows a
+    Mamba layer = 12.8 MB, made once a step: PR 42). The decode step of
+    a Mamba layer is the kernels `_ssm_conv` and `_ssm_step`, the
+    prefill's is `_selective_scan`. The numbers printed here
     are the configuration file's `memory` block and PERF.md section 4's
     (arguments + temporaries under half the chip)."""
     from oryx_tpu import config as cfg_lib
@@ -652,6 +707,8 @@ def test_jamba_serve_programs_keep_pages_and_state_in_place(
     text = compiled.as_text()
     assert "tpu_custom_call" in text
     assert ("_selective_scan" in text) == (program == "paged_prefill")
+    for kernel in ("_ssm_conv", "_ssm_step"):
+        assert (kernel in text) == (program == "paged_decode_chunk")
     memory = compiled.memory_analysis()
     nbytes = lambda t: sum(  # noqa: E731
         a.size * a.dtype.itemsize for a in jax.tree_util.tree_leaves(t))
@@ -664,6 +721,7 @@ def test_jamba_serve_programs_keep_pages_and_state_in_place(
     assert pool_bytes == 64 * 9_318_400 + 64 * ctx * 1024
     assert memory.alias_size_in_bytes == pool_bytes
     one_layers_state = slots * 16 * 5120 * 4
-    assert memory.temp_size_in_bytes < 5 * one_layers_state
+    invariants = 26 * 24 * 5120 * 4 * (program == "paged_decode_chunk")
+    assert memory.temp_size_in_bytes < 5 * one_layers_state + invariants
     total = memory.argument_size_in_bytes + memory.temp_size_in_bytes
     assert 0.25 * 16e9 < total < 0.5 * 16e9
