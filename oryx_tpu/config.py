@@ -31,6 +31,22 @@ def unsupported_for_recurrent(mode: str) -> str:
     )
 
 
+def unsupported_for_window(mode: str) -> str:
+    """The one refusal of a mode that is not built for a config with
+    window layers (`LLMConfig.windowed`): its pool is TWO paged planes,
+    one a layer kind, each behind its own allocator and block table,
+    and a window layer's table is re-based as the lane moves on, which
+    only the split engine's `paged_prefill` / `paged_decode_chunk`
+    carry."""
+    return (
+        f"window layers (sliding_window > 0): {mode} is not built for a "
+        "pool of two paged planes (one block table a layer kind, the "
+        "window plane's re-based as pages older than the window are "
+        "given back); it serves through the continuous split engine "
+        "with a bf16 pool and no prefix cache only"
+    )
+
+
 @dataclass(frozen=True)
 class LLMConfig:
     """Qwen2/Yi-class decoder geometry.
@@ -150,6 +166,40 @@ class LLMConfig:
     # False: attention without rotary (or any other) position term; the
     # state-space layers carry the order.
     use_rope: bool = True
+    # Attention layer kinds by position when sliding_window > 0: layer i
+    # is GLOBAL (sees the whole context) iff i % global_layer_period ==
+    # global_layer_offset, every other layer is a WINDOW layer whose
+    # query at t sees the keys u <= t with t - u < sliding_window.
+    # num_layers is a whole number of periods. The paged pool then holds
+    # one plane a kind (`qwen2.init_paged_kv_cache`), so that a window
+    # layer keeps no page its queries can no longer see. With
+    # rope_window_only the position term is applied on window layers
+    # alone (a global layer has none). 0 = every layer is global.
+    sliding_window: int = 0
+    global_layer_period: int = 0
+    global_layer_offset: int = 0
+    rope_window_only: bool = False
+    # The experts' gate activation: "silu" (SwiGLU) or "relu" (ReGLU).
+    moe_activation: str = "silu"
+    # What the router reads: "post_attn" (the normed state after
+    # attention, the expert layer's own input) or "layer_input" (the
+    # residual stream at the layer's input, before its norm).
+    router_input: str = "post_attn"
+
+    @property
+    def windowed(self) -> bool:
+        """Some attention layers see a sliding window only."""
+        return self.sliding_window > 0
+
+    @property
+    def num_global_layers(self) -> int:
+        if not self.windowed:
+            return self.num_layers
+        return self.num_layers // self.global_layer_period
+
+    @property
+    def num_window_layers(self) -> int:
+        return self.num_layers - self.num_global_layers
 
     @property
     def latent(self) -> bool:
@@ -351,7 +401,45 @@ class LLMConfig:
             ):
                 if bad:
                     raise ValueError(unsupported_for_recurrent(mode))
-        elif not self.use_rope:
+        if self.moe_activation not in ("silu", "relu") or (
+                self.router_input not in ("post_attn", "layer_input")):
+            raise ValueError(
+                "moe_activation is 'silu' or 'relu' and router_input "
+                f"'post_attn' or 'layer_input', got {self.moe_activation!r}"
+                f", {self.router_input!r}"
+            )
+        if (self.moe_activation != "silu"
+                or self.router_input != "post_attn") and (
+                not self.num_experts or self.latent):
+            raise ValueError(
+                "moe_activation and router_input are built for the "
+                "per-head expert decoder (num_experts > 0, no latent "
+                "attention)"
+            )
+        if self.windowed:
+            P = self.global_layer_period
+            if not (P > 1 and 0 <= self.global_layer_offset < P
+                    and self.num_layers % P == 0):
+                raise ValueError(
+                    "window layers need 0 <= global_layer_offset < "
+                    "global_layer_period > 1 and num_layers a whole "
+                    f"number of periods, got {self}"
+                )
+            for bad, mode in (
+                (self.block_length, "generation by diffusion over blocks "
+                 "(block_length > 0: the block step program)"),
+                (self.latent, "latent attention (kv_lora_rank > 0)"),
+                (self.recurrent,
+                 "state-space layers (attn_layer_period > 0)"),
+            ):
+                if bad:
+                    raise ValueError(unsupported_for_window(mode))
+        elif self.global_layer_period or self.rope_window_only:
+            raise ValueError(
+                "global_layer_period and rope_window_only need window "
+                "layers (sliding_window > 0)"
+            )
+        if not self.recurrent and not self.use_rope:
             raise ValueError(
                 "use_rope=False (attention without a position term) is "
                 "built for a config with state-space layers only "
@@ -569,6 +657,15 @@ class OryxConfig:
             ):
                 if bad:
                     raise ValueError(unsupported_for_recurrent(mode))
+        if self.llm.windowed:
+            m = self.mesh
+            for bad, mode in (
+                (m.num_devices > 1, f"a mesh ({m}: tp, fsdp or dp)"),
+                (self.attn_impl not in ("xla", "pallas"),
+                 f"attn_impl={self.attn_impl!r} (ring attention)"),
+            ):
+                if bad:
+                    raise ValueError(unsupported_for_window(mode))
 
     # ---- (de)serialization -------------------------------------------------
 
@@ -1030,6 +1127,82 @@ def jamba_tiny() -> OryxConfig:
             mamba_expand=2,
             mamba_dt_rank=8,
             use_rope=False,
+        ),
+        vision=None,
+        generation=GenerationConfig(eos_token_id=512),
+        dtype="float32",
+    )
+
+
+def smallthinker_21b() -> OryxConfig:
+    """SmallThinker-21BA3B-Instruct (PowerInfer, config.json): 52
+    layers in periods of 4, layer i GLOBAL iff i % 4 == 0 (no position
+    term, the whole context), the other three WINDOW layers (RoPE,
+    theta 1.5e6, the last 4,096 positions); 28 query heads of 128 over
+    4 key/value heads, no bias; every layer 64 ReGLU experts of 768,
+    top 6, softmax then renormalised, the router fed the layer's raw
+    input; an untied head of 151,936. Text-only. The two conventions
+    the keys do not settle (the router's input, no attention bias) are
+    the configuration file's `assumed`."""
+    return OryxConfig(
+        llm=LLMConfig(
+            vocab_size=151936,
+            hidden_size=2560,
+            intermediate_size=768,  # unused: every layer is sparse
+            num_layers=52,
+            num_heads=28,
+            num_kv_heads=4,
+            head_dim=128,
+            rope_theta=1_500_000.0,
+            rms_norm_eps=1e-6,
+            max_position_embeddings=16384,
+            tie_word_embeddings=False,
+            attention_bias=False,
+            num_experts=64,
+            num_experts_per_tok=6,
+            moe_intermediate_size=768,
+            norm_topk_prob=True,
+            sliding_window=4096,
+            global_layer_period=4,
+            global_layer_offset=0,
+            rope_window_only=True,
+            moe_activation="relu",
+            router_input="layer_input",
+        ),
+        vision=None,
+        # Past the vocabulary: seeded weights would sample a real id
+        # once in 151,936 tokens and end a request the traffic sized.
+        generation=GenerationConfig(eos_token_id=151936),
+    )
+
+
+def smallthinker_tiny() -> OryxConfig:
+    """Tiny window / global hybrid for tests: two periods of (global,
+    window, window, window), window 32, 8 ReGLU experts top 2."""
+    return OryxConfig(
+        llm=LLMConfig(
+            vocab_size=512,
+            hidden_size=64,
+            intermediate_size=32,
+            num_layers=8,
+            num_heads=4,
+            num_kv_heads=2,
+            head_dim=16,
+            rope_theta=10_000.0,
+            rms_norm_eps=1e-6,
+            max_position_embeddings=2048,
+            tie_word_embeddings=False,
+            attention_bias=False,
+            num_experts=8,
+            num_experts_per_tok=2,
+            moe_intermediate_size=32,
+            norm_topk_prob=True,
+            sliding_window=32,
+            global_layer_period=4,
+            global_layer_offset=0,
+            rope_window_only=True,
+            moe_activation="relu",
+            router_input="layer_input",
         ),
         vision=None,
         generation=GenerationConfig(eos_token_id=512),

@@ -475,6 +475,17 @@ def generate_stream(
 # ---------------------------------------------------------------------------
 
 
+def _window_planes(window_tables, window_base, what: str) -> dict:
+    """`qwen2.forward`'s two arguments for the window plane, which a
+    config with window layers requires of `what`."""
+    if window_tables is None or window_base is None:
+        raise ValueError(qwen2.unsupported_for_window(
+            f"{what} without the window plane's tables (window_tables=, "
+            "window_base=)"))
+    return {"window_tables": window_tables,
+            "window_base": window_base.astype(jnp.int32)}
+
+
 @partial(
     jax.jit,
     static_argnames=("cfg", "attn_impl", "compute_dtype", "return_routing",
@@ -500,6 +511,8 @@ def paged_prefill(
     held_stats: bool = False,
     slots: jnp.ndarray | None = None,  # [B] int32, see below
     return_logits: bool = False,
+    window_tables: jnp.ndarray | None = None,  # [B, window pages] int32
+    window_base: jnp.ndarray | None = None,  # [B] int32
 ):
     """Prompt prefill into a PAGED cache + first sampled token.
 
@@ -525,9 +538,19 @@ def paged_prefill(
     right-padded chunk leaves the state of its last REAL token
     (`qwen2.forward`'s `state_slots`). return_logits (static; a twin
     for the benchmark's comparison on a config without experts) appends
-    the [B, V] logits the first token was sampled from, last."""
+    the [B, V] logits the first token was sampled from, last.
+
+    `window_tables`, `window_base` (a config with window layers,
+    `cfg.windowed`, which requires both): the window plane's block
+    table a row and the position its slot 0 holds (`qwen2.forward`);
+    `block_tables` is then the global plane's. The caller keeps
+    positions max(0, start - sliding_window + 1) .. start + T inside the
+    window table. held_stats is taken for such a config too (every
+    expert is held)."""
     B, T, _ = inputs_embeds.shape
     state = {}
+    if cfg.windowed:
+        state = _window_planes(window_tables, window_base, "a prefill")
     if cfg.recurrent:
         if slots is None:
             raise ValueError(qwen2.unsupported_for_recurrent(
@@ -547,11 +570,22 @@ def paged_prefill(
         block_tables=block_tables, kv_lengths=lengths,
         attn_impl=attn_impl, compute_dtype=compute_dtype,
         return_routing=return_routing or held_stats, **state,
+        # A config with window layers projects the ONE row it samples
+        # from: its head is 151,936 wide and a chunk 1,024 rows, whose
+        # logits would be 0.9 GB of temporaries and as many operations
+        # again as the layers'.
+        **({"return_hidden": True} if cfg.windowed else {}),
     )
     last = jnp.take_along_axis(
         logits, (lengths - 1 - start)[:, None, None].astype(jnp.int32),
         axis=1,
     )[:, 0]
+    if cfg.windowed:
+        # (eight copies of the row: a product of ONE row is computed on
+        # a float32 copy of the whole head, 1.5 GB of temporaries.)
+        last = qwen2.lm_head(
+            params, cfg, jnp.broadcast_to(last[:, None], (B, 8, last.shape[-1]))
+        )[:, 0]
     pair = jax.vmap(lambda k: jax.random.split(k, 2))(keys)
     tok0 = sample_token_rows(
         last, pair[:, 1], temperature=temperature, top_p=top_p, top_k=top_k
@@ -683,6 +717,8 @@ def paged_decode_chunk(
     numerics: bool = False,
     return_routing: bool = False,
     return_logits: bool = False,
+    window_tables: jnp.ndarray | None = None,  # [S, window pages] int32
+    window_base: jnp.ndarray | None = None,  # [S] int32
 ):
     """`chunk` decode steps over a FIXED-SLOT batch with a paged cache —
     the continuous-batching inner loop. One compiled program per
@@ -717,9 +753,20 @@ def paged_decode_chunk(
     A config with state-space layers (`cfg.recurrent`): lane s IS slot
     s, so its recurrent state is row s of the pool's per-slot planes; a
     live lane's state advances a token a step, a lane with `finished`
-    (ended, empty, or still prefilling) keeps its state untouched."""
+    (ended, empty, or still prefilling) keeps its state untouched.
+
+    A config with window layers (`cfg.windowed`): `window_tables` and
+    `window_base` are the window plane's block table a lane and the
+    position its slot 0 holds, fixed for the chunk (the caller keeps
+    lengths - sliding_window + 1 .. lengths + chunk inside the table);
+    an expert config of that kind appends the `share_stats` sums too
+    (held = every expert)."""
     page_size = paged_kv_lib.pool_plane(kv_pages).shape[2]
-    shared = bool(cfg.experts_held or cfg.zero_experts)
+    shared = bool(cfg.experts_held or cfg.zero_experts
+                  or (cfg.windowed and cfg.num_experts))
+    planes = {}
+    if cfg.windowed:
+        planes = _window_planes(window_tables, window_base, "a decode chunk")
     K = block_tables.shape[1] * page_size
     slot_ar = jnp.arange(K, dtype=jnp.int32)[None, :]
 
@@ -748,7 +795,7 @@ def paged_decode_chunk(
             kv_lengths=(kv_lengths := jnp.where(finished, 0, cur_len + 1)),
             attn_impl=attn_impl, compute_dtype=compute_dtype,
             **({"return_routing": True} if shared or return_routing
-               else {}),
+               else {}), **planes,
         )
         if numerics:
             # Live-row logit probe on the logits the sampler is about

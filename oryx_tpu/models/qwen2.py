@@ -20,13 +20,16 @@ transposes torch's [out, in].
 
 from __future__ import annotations
 
+import contextlib
 from typing import Any
 
 import jax
 import jax.numpy as jnp
 from jax.ad_checkpoint import checkpoint_name
 
-from oryx_tpu.config import LLMConfig, unsupported_for_recurrent
+from oryx_tpu.config import (
+    LLMConfig, unsupported_for_recurrent, unsupported_for_window,
+)
 from oryx_tpu.ops.attention import attention
 from oryx_tpu.ops.norms import rms_norm
 from oryx_tpu.ops.rope import apply_rope, rope_cos_sin, yarn_frequencies
@@ -289,7 +292,33 @@ def init_paged_kv_cache(
     table addresses, `conv` [Lm, S, (K-1) * d] in `dtype` (the last
     K - 1 conv inputs, flat so that the plane has no 3-row tile to pad)
     and `ssm` [Lm, S, N, d] float32 (channels in the lanes), S =
-    `num_slots`. `ops/paged_kv.paged_planes` tells them apart."""
+    `num_slots`. `ops/paged_kv.paged_planes` tells them apart.
+
+    A config with window layers (`cfg.windowed`) has TWO paged planes,
+    one a layer kind, each with its own page count, allocator and block
+    table: `num_pages` is then the pair (global pages, window pages),
+    `k` / `v` hold the global layers' [Lg, Pg, page, Hk, D] and
+    `ops/paged_kv.WINDOW_PLANES` (`wk` / `wv`) the window layers'
+    [Lw, Pw, page, Hk, D]. A page index means something in ONE of the
+    two."""
+    if cfg.windowed:
+        from oryx_tpu.ops import paged_kv
+
+        if kv_dtype not in (None, "bf16", "fp"):
+            raise ValueError(unsupported_for_window(f"kv_dtype={kv_dtype!r}"))
+        try:
+            Pg, Pw = num_pages
+        except TypeError:
+            raise ValueError(
+                "a config with window layers keeps a plane a layer kind: "
+                "init_paged_kv_cache needs num_pages = (global pages, "
+                "window pages)") from None
+        tail = (page_size, cfg.num_kv_heads, cfg.head_dim)
+        shapes = dict(zip(
+            ("k", "v") + paged_kv.WINDOW_PLANES,
+            2 * [(cfg.num_global_layers, Pg) + tail]
+            + 2 * [(cfg.num_window_layers, Pw) + tail]))
+        return {n: jnp.zeros(sh, dtype) for n, sh in shapes.items()}
     if cfg.recurrent:
         from oryx_tpu.models import mamba
 
@@ -445,10 +474,22 @@ def moe_route(cfg: LLMConfig, x: jnp.ndarray, router_kernel: jnp.ndarray,
     renormalized to sum 1 when cfg.norm_topk_prob. `router_bias` [E]
     is added for the SELECTION only (the weights stay the
     probabilities); cfg.routed_scaling_factor multiplies the weights."""
-    r = jnp.matmul(
+    return moe_select(cfg, router_logits(x, router_kernel), router_bias)
+
+
+def router_logits(x: jnp.ndarray, router_kernel: jnp.ndarray):
+    """x [N, H] -> the router's logits [N, E], float32 at full matmul
+    precision."""
+    return jnp.matmul(
         x.astype(jnp.float32), router_kernel.astype(jnp.float32),
         precision=jax.lax.Precision.HIGHEST,
     )
+
+
+def moe_select(cfg: LLMConfig, r: jnp.ndarray,
+               router_bias: jnp.ndarray | None = None):
+    """`moe_route` from the logits r [N, E] on: wherever they were
+    taken (cfg.router_input)."""
     p = jax.nn.softmax(r, axis=-1)
     if router_bias is None:
         w, idx = jax.lax.top_k(p, cfg.num_experts_per_tok)
@@ -512,7 +553,8 @@ def _grouped_dot(rows: jnp.ndarray, kernels: jnp.ndarray,
 def _moe(cfg: LLMConfig, x: jnp.ndarray, router_kernel: jnp.ndarray,
          experts: Params, layer: jnp.ndarray, impl: str = "xla",
          router_bias: jnp.ndarray | None = None,
-         shared: Params | None = None):
+         shared: Params | None = None,
+         logits: jnp.ndarray | None = None):
     """Sparse expert MLP on x [N, H]: dropless, no capacity factor, no
     padding to a capacity. The N*K (token, expert) pairs are sorted by
     expert and the gate, up and down products run as grouped products
@@ -545,11 +587,16 @@ def _moe(cfg: LLMConfig, x: jnp.ndarray, router_kernel: jnp.ndarray,
     whole on every chip, added to the routed sum in float32: a decode
     step reads its kernels once whatever the lanes.
 
+    `logits` [N, E]: the router's logits where they were taken
+    elsewhere (cfg.router_input "layer_input": `_block` reads them off
+    the layer's raw input); None: the router reads x. The experts' gate
+    activation is cfg.moe_activation.
+
     Returns (y [N, H], routing: {"counts": rows of each held expert
     [count] int32, "ids": the chosen experts [N, K] int32})."""
     with jax.named_scope("moe_routed"):
         y, idx, counts = _moe_routed(
-            cfg, x, router_kernel, experts, layer, impl, router_bias)
+            cfg, x, router_kernel, experts, layer, impl, router_bias, logits)
     if shared is not None:
         with jax.named_scope("moe_shared"):
             y = y + _swiglu(x, shared).astype(jnp.float32)
@@ -557,13 +604,17 @@ def _moe(cfg: LLMConfig, x: jnp.ndarray, router_kernel: jnp.ndarray,
 
 
 def _moe_routed(cfg: LLMConfig, x, router_kernel, experts, layer, impl,
-                router_bias):
+                router_bias, logits=None):
     """The routed (and zero-compute) part of `_moe`, float32:
     (y [N, H], ids [N, K], held experts' row counts [count])."""
     N, K, E = x.shape[0], cfg.num_experts_per_tok, cfg.num_experts
     first, count = cfg.held
     whole = count == E and not cfg.zero_experts
-    w, idx = moe_route(cfg, x, router_kernel, router_bias)
+    if logits is None:
+        w, idx = moe_route(cfg, x, router_kernel, router_bias)
+    else:
+        w, idx = moe_select(cfg, logits, router_bias)
+    act = jax.nn.relu if cfg.moe_activation == "relu" else jax.nn.silu
     flat = idx.reshape(N * K)
     if not whole:
         # Group id of a pair: its held expert's place, or `count`, a
@@ -580,7 +631,7 @@ def _moe_routed(cfg: LLMConfig, x, router_kernel, experts, layer, impl,
     gate = _grouped_dot(xs, experts["gate"].astype(x.dtype), groups, impl)
     up = _grouped_dot(xs, experts["up"].astype(x.dtype), groups, impl)
     ys = _grouped_dot(
-        jax.nn.silu(gate) * up, experts["down"].astype(x.dtype), groups, impl
+        act(gate) * up, experts["down"].astype(x.dtype), groups, impl
     )
     # Unsort: pair p = token * K + slot sits at sorted row inv[p].
     inv = jnp.zeros((N * K,), jnp.int32).at[order].set(
@@ -617,6 +668,7 @@ def _block(
     attn_impl: str = "xla",
     mask_positions: jnp.ndarray | None = None,
     experts: tuple | None = None,
+    window: int = 0,
 ):
     """One decoder block. h: [B, T, H]. Returns (h, new_k, new_v), and
     on an expert config the expert layer's routing as a fourth value.
@@ -626,10 +678,22 @@ def _block(
     `positions` place the token: its RoPE angle and its cache slot.
     `mask_positions` (default: the same) are what its query is masked
     at, `kv_pos <= mask_position`: the last position of the token's
-    block under the block-diffusion mask (see `forward`)."""
+    block under the block-diffusion mask (see `forward`).
+
+    `window` > 0: a WINDOW layer (cfg.windowed), whose query at t sees
+    the keys at t - window < u <= t; its cache, tables, positions and
+    lengths are then the window plane's, relative to the table's base
+    (`_window_layers`), and cos / sin are still the absolute
+    positions'."""
     B, T, _ = h.shape
     if mask_positions is None:
         mask_positions = positions
+    win = {"window": window} if window else {}
+    moe_in = {}
+    if cfg.router_input == "layer_input":
+        # The router reads the residual stream as the layer found it.
+        moe_in["logits"] = router_logits(
+            h.reshape(B * T, -1), lp["router"]["kernel"])
     x = rms_norm(h, lp["input_norm"]["weight"], cfg.rms_norm_eps)
     q = _linear(x, lp["q_proj"]).reshape(B, T, cfg.num_heads, cfg.head_dim)
     k = _linear(x, lp["k_proj"]).reshape(B, T, cfg.num_kv_heads, cfg.head_dim)
@@ -645,6 +709,39 @@ def _block(
     k = checkpoint_name(k, "attn_k")
     v = checkpoint_name(v, "attn_v")
 
+    with (jax.named_scope("attn_window" if window else "attn_global")
+          if cfg.windowed else contextlib.nullcontext()):
+        attn_out, cache_k, cache_v = _block_attention(
+            q, k, v, positions=positions, cache_k=cache_k, cache_v=cache_v,
+            write_slots=write_slots, kv_mask=kv_mask, attn_fn=attn_fn,
+            block_tables=block_tables, write_mask=write_mask,
+            kv_lengths=kv_lengths, q_segments=q_segments,
+            attn_impl=attn_impl, mask_positions=mask_positions, win=win,
+        )
+    attn_out = attn_out.reshape(B, T, -1)
+    # "attn_o" tag: with remat_policy="attn_o" the residual-stream value
+    # h_mid = h + o_out is rebuilt from this saved projection, so the
+    # backward recomputes neither the attention nor o_proj.
+    h = h + checkpoint_name(_linear(attn_out, lp["o_proj"]), "attn_o")
+
+    x = rms_norm(h, lp["post_attn_norm"]["weight"], cfg.rms_norm_eps)
+    if cfg.num_experts:
+        y, routing = _moe(
+            cfg, x.reshape(B * T, -1), lp["router"]["kernel"], *experts,
+            impl=attn_impl, **moe_in,
+        )
+        return h + y.reshape(B, T, -1), cache_k, cache_v, routing
+    return h + _swiglu(x, lp), cache_k, cache_v
+
+
+def _block_attention(q, k, v, *, positions, cache_k, cache_v, write_slots,
+                     kv_mask, attn_fn, block_tables, write_mask, kv_lengths,
+                     q_segments, attn_impl, mask_positions, win):
+    """`_block`'s attention over whichever cache it was given: writes
+    this call's K/V, returns (attention output [B, T, Hq, D], the
+    cache's two planes). `win`: {"window": W} on a window layer, else
+    {}."""
+    T = q.shape[1]
     if cache_k is not None and block_tables is not None and (
         q_segments is not None
     ):
@@ -693,7 +790,7 @@ def _block(
             from oryx_tpu.ops.pallas import paged_attention as _ppa
 
             attn_out = _ppa.ragged_decode_attention(
-                q, cache_k, cache_v, block_tables, kv_lengths
+                q, cache_k, cache_v, block_tables, kv_lengths, **win
             )
         else:
             # Reference path (and any T > 1 paged prefill): materialize
@@ -705,7 +802,7 @@ def _block(
                 q, kc, vc,
                 q_positions=mask_positions,
                 kv_positions=None,
-                kv_mask=kv_mask,
+                kv_mask=kv_mask, **win,
             )
     elif cache_k is not None:
         cache_k = _cache_write(cache_k, k, write_slots)
@@ -714,7 +811,7 @@ def _block(
             q, cache_k, cache_v,
             q_positions=mask_positions,
             kv_positions=None,  # arange over cache slots == absolute positions
-            kv_mask=kv_mask,
+            kv_mask=kv_mask, **win,
         )
     else:
         # Right-padded prefill: every valid token's position equals its
@@ -727,22 +824,9 @@ def _block(
             q_positions=mask_positions,
             kv_positions=positions,
             kv_mask=kv_mask,
-            slot_positions=mask_positions is positions,
+            slot_positions=mask_positions is positions, **win,
         )
-    attn_out = attn_out.reshape(B, T, -1)
-    # "attn_o" tag: with remat_policy="attn_o" the residual-stream value
-    # h_mid = h + o_out is rebuilt from this saved projection, so the
-    # backward recomputes neither the attention nor o_proj.
-    h = h + checkpoint_name(_linear(attn_out, lp["o_proj"]), "attn_o")
-
-    x = rms_norm(h, lp["post_attn_norm"]["weight"], cfg.rms_norm_eps)
-    if cfg.num_experts:
-        y, routing = _moe(
-            cfg, x.reshape(B * T, -1), lp["router"]["kernel"], *experts,
-            impl=attn_impl,
-        )
-        return h + y.reshape(B, T, -1), cache_k, cache_v, routing
-    return h + _swiglu(x, lp), cache_k, cache_v
+    return attn_out, cache_k, cache_v
 
 
 def _swiglu(x: jnp.ndarray, lp: Params) -> jnp.ndarray:
@@ -1055,6 +1139,111 @@ def _hybrid_layers(cfg: LLMConfig, layers: Params, h, *, block, kv_cache,
     }
 
 
+def lm_head(params: Params, cfg: LLMConfig, h: jnp.ndarray,
+            logits_dtype: jnp.dtype = jnp.float32) -> jnp.ndarray:
+    """Logits of final hidden states h [..., H] (`forward`'s
+    return_hidden): the tied embedding, transposed, or the head."""
+    if cfg.tie_word_embeddings:
+        return (h @ params["embed"]["weight"].astype(h.dtype).T).astype(
+            logits_dtype
+        )
+    return (h @ params["lm_head"]["kernel"].astype(h.dtype)).astype(
+        logits_dtype
+    )
+
+
+def _window_layers(cfg: LLMConfig, layers: Params, h, *, block, kv_cache,
+                   block_tables, window_tables, window_view: dict, remat):
+    """The layer stack of a config with window layers: a scan over
+    PERIODS of cfg.global_layer_period layers whose body runs the
+    period's layers in order through `block` (forward's closure over
+    `_block`), the window layers ahead of and behind the global one
+    each as an inner scan (`_hybrid_layers`' shape): the global layer
+    sees the whole context (and has no position term under
+    cfg.rope_window_only), the others the last cfg.sliding_window
+    positions. Both paged planes are CARRIED
+    (forward's "THE POOL IS CARRIED" rule), each flat behind tables
+    offset by the layer's number WITHIN ITS KIND: `k` / `v` behind
+    `block_tables`, `wk` / `wv` behind `window_tables`, whose slot 0 is
+    the lane's `window_base` position: `window_view` holds a window
+    layer's positions, write slots, lengths and mask relative to it, so
+    that neither `_block` nor a kernel knows a page was ever given back.
+    Layer weights stay stacked [L, ...], scanned as [L / period, period,
+    ...]. Returns (h, the pool or None, the expert layers' routing
+    [L, ...] or None)."""
+    from oryx_tpu.ops import paged_kv
+
+    per, off = cfg.global_layer_period, cfg.global_layer_offset
+    W = cfg.sliding_window
+    paged = kv_cache is not None
+    gk = gv = wk = wv = None
+    if paged:
+        wkn, wvn = paged_kv.WINDOW_PLANES
+        Lg, Pg = kv_cache["k"].shape[:2]
+        Lw, Pw = kv_cache[wkn].shape[:2]
+        gk, gv, wk, wv = (
+            kv_cache[n].reshape((-1,) + kv_cache[n].shape[2:])
+            for n in ("k", "v", wkn, wvn))
+    no_rope = {"rope": (None, None)} if cfg.rope_window_only else {}
+
+    def offset(tables, P, L, n):
+        return jnp.where(tables >= P, L * P, tables + n.astype(tables.dtype) * P)
+
+    def window_layer(carry, xs):
+        h, wk, wv = carry
+        lp, w, layer = xs
+        tables = offset(window_tables, Pw, Lw, w) if paged else None
+        h, wk, wv, r = block(
+            h, lp, wk, wv, tables, layer, window=W, **window_view)
+        return (h, wk, wv), r
+
+    def window_run(h, wk, wv, lp_all, p, lo, hi):
+        """The period's layers lo .. hi - 1, all window layers: an inner
+        scan, so that a plane is written and read ONCE an iteration
+        (three writes and reads of one carried plane in one body made
+        the prefill program keep a copy of it)."""
+        if lo == hi:
+            return h, wk, wv, None
+        js = jnp.arange(lo, hi, dtype=jnp.int32)
+        (h, wk, wv), r = jax.lax.scan(
+            window_layer, (h, wk, wv),
+            (jax.tree_util.tree_map(lambda a: a[lo:hi], lp_all),
+             p * (per - 1) + js - (lo > off), p * per + js))
+        return h, wk, wv, r
+
+    def period(carry, xs):
+        h, gk, gv, wk, wv = carry
+        lp_all, p = xs
+        h, wk, wv, before = window_run(h, wk, wv, lp_all, p, 0, off)
+        tables = offset(block_tables, Pg, Lg, p) if paged else None
+        h, gk, gv, r = block(
+            h, jax.tree_util.tree_map(lambda a: a[off], lp_all), gk, gv,
+            tables, p * per + off, **no_rope)
+        h, wk, wv, after = window_run(h, wk, wv, lp_all, p, off + 1, per)
+        routes = [x for x in (
+            before, jax.tree_util.tree_map(lambda a: a[None], r), after)
+            if x is not None]
+        return (h, gk, gv, wk, wv), jax.tree_util.tree_map(
+            lambda *a: jnp.concatenate(a), *routes)
+
+    n_per = cfg.num_layers // per
+    (h, gk, gv, wk, wv), routing = jax.lax.scan(
+        wrap_remat(period, remat), (h, gk, gv, wk, wv),
+        (jax.tree_util.tree_map(
+            lambda a: a.reshape((n_per, per) + a.shape[1:]), layers),
+         jnp.arange(n_per, dtype=jnp.int32)))
+    routing = jax.tree_util.tree_map(
+        lambda a: a.reshape((cfg.num_layers,) + a.shape[2:]), routing)
+    if not paged:
+        return h, None, routing
+    return h, {
+        "k": gk.reshape((Lg, Pg) + gk.shape[1:]),
+        "v": gv.reshape((Lg, Pg) + gv.shape[1:]),
+        wkn: wk.reshape((Lw, Pw) + wk.shape[1:]),
+        wvn: wv.reshape((Lw, Pw) + wv.shape[1:]),
+    }, routing
+
+
 def forward(
     params: Params,
     cfg: LLMConfig,
@@ -1079,6 +1268,8 @@ def forward(
     segment_ids: jnp.ndarray | None = None,
     return_routing: bool = False,
     state_slots: jnp.ndarray | None = None,
+    window_tables: jnp.ndarray | None = None,
+    window_base: jnp.ndarray | None = None,
 ) -> tuple[jnp.ndarray, Params | None]:
     """Full decoder forward.
 
@@ -1138,6 +1329,18 @@ def forward(
         slot array (B = S, row b = slot b; the decode chunk), a row
         with kv_lengths 0 (a finished, empty or still-prefilling lane)
         keeps its state untouched, and nothing is zeroed.
+      window_tables, window_base: a config with window layers
+        (`cfg.windowed`) and a paged kv_cache only, both required
+        there. `block_tables` [B, max_pages] address the GLOBAL layers'
+        planes from position 0, as ever; window_tables [B, window
+        pages] address the window layers' planes from position
+        window_base[b] [B] int32 (a multiple of the page size): logical
+        slot s of row b's window table holds position window_base[b] +
+        s. The caller keeps every position a query of this call can
+        see, t - sliding_window < u <= t, inside the table; what lies
+        before the base was given back to the window plane's allocator.
+        kv_lengths is required (a window layer's validity mask is made
+        from it).
       segment_ids: [B, T] int32 SAMPLE ids for sequence-packed training
         (0 = pad): attention is causal in SLOT order and masked on
         segment equality, so samples packed into one row never attend
@@ -1279,21 +1482,25 @@ def forward(
             params["layers"]["experts"],
         )
 
-    def block(h, lp, ck, cv, tables, layer=None):
+    def block(h, lp, ck, cv, tables, layer=None, rope=(cos, sin), **view):
+        # `view` (a window layer's, `_window_layers`): its own
+        # positions, write slots, lengths and mask, and its window.
+        kw = dict(
+            positions=attn_positions, write_slots=write_slots,
+            kv_mask=kv_mask, kv_lengths=kv_lengths,
+            mask_positions=mask_positions,
+        )
+        kw.update(view)
         h, ck, cv, *routing = _block(
-            cfg, h, lp, cos, sin,
-            positions=attn_positions,
+            cfg, h, lp, *rope,
             cache_k=ck, cache_v=cv,
-            write_slots=write_slots,
-            kv_mask=kv_mask,
             attn_fn=attn_fn,
             block_tables=tables,
             write_mask=write_mask,
-            kv_lengths=kv_lengths,
             q_segments=q_segments,
             attn_impl=attn_impl,
-            mask_positions=mask_positions,
             experts=None if experts_flat is None else (experts_flat, layer),
+            **kw,
         )
         return constrain(h, *hs_spec), ck, cv, routing[0] if routing else None
 
@@ -1377,6 +1584,36 @@ def forward(
             kv_lengths=kv_lengths, kv_mask=kv_mask,
             state_slots=state_slots, attn_impl=attn_impl, remat=remat,
         )
+    elif cfg.windowed:
+        from oryx_tpu.ops import paged_kv
+
+        window_view = {}
+        if kv_cache is not None:
+            if (q_segments is not None or block_tables is None
+                    or window_tables is None or window_base is None
+                    or kv_lengths is None
+                    or paged_kv.WINDOW_PLANES[0] not in kv_cache):
+                raise ValueError(unsupported_for_window(
+                    "a packed ragged step, a dense cache or a pool of "
+                    "one plane (forward needs block_tables, "
+                    "window_tables, window_base and kv_lengths)"
+                ))
+            base = window_base.astype(jnp.int32)
+            w_len = jnp.where(kv_lengths > 0, kv_lengths - base, 0)
+            Kw = window_tables.shape[1] * kv_cache["k"].shape[2]
+            window_view = dict(
+                positions=positions - base[:, None],
+                write_slots=write_slots - base, kv_lengths=w_len,
+                kv_mask=(jnp.arange(Kw, dtype=jnp.int32)[None, :]
+                         < w_len[:, None]).astype(jnp.int32),
+            )
+        elif segment_ids is not None:
+            raise ValueError(unsupported_for_window("packed training"))
+        h, new_cache, expert_counts = _window_layers(
+            cfg, layers, h, block=block, kv_cache=kv_cache,
+            block_tables=block_tables, window_tables=window_tables,
+            window_view=window_view, remat=remat,
+        )
     elif kv_cache is not None and block_tables is not None:
         # Paged pool: the scan's CARRY, one flat [L*P, page, ...] buffer a
         # plane behind layer-offset tables (the `block_tables` contract
@@ -1428,14 +1665,8 @@ def forward(
         # (train/loss.chunked_causal_lm_loss) projects to the vocab
         # per-chunk instead of materializing [B, T, V] logits.
         out = h
-    elif cfg.tie_word_embeddings:
-        out = (h @ params["embed"]["weight"].astype(h.dtype).T).astype(
-            logits_dtype
-        )
     else:
-        out = (h @ params["lm_head"]["kernel"].astype(h.dtype)).astype(
-            logits_dtype
-        )
+        out = lm_head(params, cfg, h, logits_dtype)
     if return_routing:
         return out, new_cache, expert_counts
     return out, new_cache

@@ -55,6 +55,7 @@ def _attention_dense(
     kv_segment_ids: jnp.ndarray | None,
     kv_mask: jnp.ndarray | None,
     scale: float,
+    window: int = 0,
 ) -> jnp.ndarray:
     B, Tq, Hq, D = q.shape
     _, Tk, Hk, _ = k.shape
@@ -77,6 +78,11 @@ def _attention_dense(
         mask = _and(
             mask, q_positions[:, :, None] >= kv_positions[:, None, :]
         )
+        if window:
+            mask = _and(
+                mask,
+                q_positions[:, :, None] - kv_positions[:, None, :] < window,
+            )
     if q_segment_ids is not None:
         assert kv_segment_ids is not None
         mask = _and(
@@ -111,12 +117,14 @@ def attention(
     kv_segment_ids: jnp.ndarray | None = None,
     kv_mask: jnp.ndarray | None = None,
     scale: float | None = None,
+    window: int = 0,
 ) -> jnp.ndarray:
     """General GQA attention. Returns [B, Tq, Hq, D] in q.dtype.
 
     For causal masking with a KV cache, pass `q_positions`/`kv_positions`
     (absolute token positions, int32 [B, T*]); without them, positions
-    default to arange (pure prefill).
+    default to arange (pure prefill). `window` > 0 (causal only): a
+    query at position t sees the keys at u <= t with t - u < window.
     """
     B, Tq, Hq, D = q.shape
     _, Tk, Hk, _ = k.shape
@@ -133,6 +141,9 @@ def attention(
         causal=causal, kv_positions=kv_positions,
         kv_segment_ids=kv_segment_ids, kv_mask=kv_mask, scale=scale,
     )
+    if window:
+        assert causal, "a sliding window is a bound beside the causal one"
+        kwargs["window"] = int(window)
 
     # Pick the largest power-of-two query chunk that keeps the logits
     # buffer under MAX_LOGITS_ELEMS and divides Tq (buckets are powers of

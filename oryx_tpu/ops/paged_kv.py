@@ -101,9 +101,12 @@ class PageAllocator:
     allocator does, and an untagged transition stamps "?".
     """
 
-    def __init__(self, num_pages: int, page_size: int):
+    def __init__(self, num_pages: int, page_size: int, plane: str = ""):
         if num_pages < 1 or page_size < 1:
             raise ValueError(f"need >= 1 page/slot, got {num_pages=} {page_size=}")
+        # Which plane of a pool of several this list is over ("global",
+        # "window"): said in OutOfPagesError, nothing else.
+        self.plane = plane
         self.num_pages = num_pages
         self.page_size = page_size
         self._free: list[int] = list(range(num_pages - 1, -1, -1))
@@ -155,6 +158,7 @@ class PageAllocator:
         if n > len(self._free):
             raise OutOfPagesError(
                 f"need {n} pages, {len(self._free)} free of {self.num_pages}"
+                + (f" in the {self.plane} plane" if self.plane else "")
             )
         if n <= 0:
             return []
@@ -320,6 +324,106 @@ class PageAllocator:
         }
 
 
+def window_table_pages(window: int, reach: int, page_size: int) -> int:
+    """Width (pages) of a window layer's block table: a dispatch whose
+    first query is at n reads from n - window + 1 (its page's start is
+    at most page_size - 1 before) and writes up to n + reach - 1
+    (`reach`: the longest dispatch, a prefill chunk with its padding or
+    a decode chunk)."""
+    return -(-(window + reach + page_size - 2) // page_size)
+
+
+class WindowPlane:
+    """Host-side cache manager of the WINDOW layers' plane of a pool of
+    two planes (`qwen2.init_paged_kv_cache`, `WINDOW_PLANES`): its own
+    `PageAllocator` over `num_pages` pages, one block table a slot
+    [num_slots, table_pages] whose first `count[s]` entries are lane
+    s's pages, and the position `base[s]` (a multiple of the page size)
+    that slot 0 of lane s's table holds. A window layer's query at t
+    sees t - window < u <= t, so before a dispatch whose first query is
+    at n a page whose last token lies before n - window + 1 will never
+    be read again: `advance` gives such pages back, shifts the table
+    and moves the base. The device programs get the table and the base
+    and work in positions relative to it (`qwen2.forward`), so no
+    kernel knows a page was ever released. The global plane keeps the
+    scheduler's own allocator and table, untouched by any of this."""
+
+    def __init__(self, num_pages: int, page_size: int, num_slots: int,
+                 table_pages: int, window: int):
+        self.allocator = PageAllocator(num_pages, page_size, plane="window")
+        self.page_size, self.window = page_size, window
+        self.sentinel = self.allocator.sentinel
+        self.tables = np.full((num_slots, table_pages), self.sentinel,
+                              np.int32)
+        self.count = np.zeros((num_slots,), np.int64)
+        self.base = np.zeros((num_slots,), np.int32)
+
+    def held(self, s: int) -> list[int]:
+        return self.tables[s, :self.count[s]].tolist()
+
+    def advance(self, s: int, first_query: int,
+                owner: str | None = None) -> list[int]:
+        """Lane s's next dispatch has its first query at position
+        `first_query`: free every page wholly older than its window,
+        shift the table, move the base. Returns the pages freed."""
+        ps = self.page_size
+        base = max(0, first_query - self.window + 1) // ps * ps
+        drop = (base - int(self.base[s])) // ps
+        if drop <= 0:
+            return []
+        row = self.tables[s]
+        freed = row[:min(drop, self.count[s])].tolist()
+        if freed:
+            self.allocator.free(freed, owner=owner)
+        row[:len(row) - drop] = row[drop:]
+        row[len(row) - drop:] = self.sentinel
+        self.count[s] -= len(freed)
+        self.base[s] = base
+        return freed
+
+    def need(self, s: int, tokens: int) -> int:
+        """Pages lane s lacks to hold positions base .. tokens - 1 (what
+        the table's width allows of them)."""
+        want = min(self.allocator.pages_for(tokens - int(self.base[s])),
+                   self.tables.shape[1])
+        return max(0, want - int(self.count[s]))
+
+    def grow(self, s: int, tokens: int, owner: str | None = None) -> bool:
+        """Cover positions up to `tokens` - 1; False, nothing taken,
+        when the plane's free list cannot."""
+        n = self.need(s, tokens)
+        if n > self.allocator.num_free:
+            return False
+        if n:
+            try:
+                pages = self.allocator.alloc(n, owner=owner)
+            except OutOfPagesError:
+                return False
+            self.tables[s, self.count[s]:self.count[s] + n] = pages
+            self.count[s] += n
+        return True
+
+    def release(self, s: int, owner: str | None = None) -> None:
+        """Lane s leaves: every page back, the base at 0."""
+        pages = self.held(s)
+        if pages:
+            self.allocator.free(pages, owner=owner)
+        self.tables[s] = self.sentinel
+        self.count[s] = self.base[s] = 0
+
+    def check_invariant(self) -> None:
+        """The plane's allocator against the tables, and every table
+        packed from its slot 0 (a hole would shift positions)."""
+        slots = range(len(self.base))
+        self.allocator.check_invariant([self.held(s) for s in slots])
+        width = np.arange(self.tables.shape[1])
+        for s in slots:
+            if ((self.tables[s] != self.sentinel)
+                    != (width < self.count[s])).any():
+                raise RuntimeError(
+                    f"window table of slot {s} has a hole: {self.tables[s]}")
+
+
 @jax.tree_util.register_pytree_node_class
 class QuantPages:
     """A quantized paged KV pool (one plane — K or V — of the pool
@@ -438,6 +542,16 @@ def is_latent_pool(kv_pages) -> bool:
 # (`qwen2.init_paged_kv_cache`): [Lm, S, ...], addressed by slot, never
 # through a block table. Everything that moves PAGES leaves them alone.
 SLOT_PLANES = ("conv", "ssm")
+
+
+# The window layers' paged planes of a pool whose model has window
+# layers (`qwen2.init_paged_kv_cache`): [Lw, Pw, page, Hk, D], behind a
+# page count, an allocator and a block table of their own; `k` / `v`
+# are then the GLOBAL layers' planes alone. A page index means
+# something in one of the two, so nothing that moves a page by ONE index
+# through every plane (`copy_pages`, `fetch_page`, `upload_page`) is
+# built for such a pool.
+WINDOW_PLANES = ("wk", "wv")
 
 
 def paged_planes(kv_pages):

@@ -114,6 +114,7 @@ def _kernel(
     has_segments: bool,
     kv_arange: bool,
     block_k: int,
+    window: int = 0,
 ):
     ik, nk = pl.program_id(3), pl.num_programs(3)
 
@@ -131,6 +132,10 @@ def _kernel(
         # position contribute nothing; skip their compute (data is still
         # prefetched — grid-level skipping is a later optimization).
         run = ik * block_k <= jnp.max(qpos_ref[0])
+        if window:
+            # ... and tiles wholly older than every query's window.
+            run = run & (
+                (ik + 1) * block_k - 1 + window > jnp.min(qpos_ref[0]))
     else:
         run = True
 
@@ -149,6 +154,11 @@ def _kernel(
             mask = jnp.logical_and(
                 mask, qpos_ref[0, :, :1] >= kpos_ref[0, :1, :]
             )
+            if window:  # the lower bound beside the causal one
+                mask = jnp.logical_and(
+                    mask,
+                    qpos_ref[0, :, :1] - kpos_ref[0, :1, :] < window,
+                )
         if has_segments:
             mask = jnp.logical_and(
                 mask, qseg_ref[0, :, :1] == kseg_ref[0, :1, :]
@@ -200,7 +210,7 @@ def _pad_axis(x, axis: int, target: int, fill=0):
 @functools.partial(
     jax.jit,
     static_argnames=("causal", "has_segments", "kv_arange", "q_arange",
-                     "scale", "interpret", "with_lse"),
+                     "scale", "interpret", "with_lse", "window"),
 )
 def _mha_forward(
     q, k, v, q_pos, kv_pos, q_seg, kv_seg, kv_valid,
@@ -212,11 +222,14 @@ def _mha_forward(
     scale: float,
     interpret: bool,
     with_lse: bool = False,
+    window: int = 0,
 ):
     """Core pallas call. Layouts: q [B, Hq, Tq, D]; k/v [B, Hk, Tk, D];
     int arrays [B, T*] (already padded to block multiples). with_lse emits
     the logsumexp residual for the backward pass (skipped at inference —
     its lane-broadcast output buffer is the price of the grad path only).
+    window > 0 (causal): a query at t sees the keys u with t - u <
+    window only; forward alone (serving), no backward kernel has it.
     """
     B, Hq, Tq, D = q.shape
     _, Hk, Tk, _ = k.shape
@@ -240,6 +253,7 @@ def _mha_forward(
     kern_full = functools.partial(
         _kernel, scale=scale, causal=causal, has_segments=has_segments,
         kv_arange=kv_arange, block_k=block_k,
+        **({"window": window} if window else {}),
     )
     if with_lse:
         kern = kern_full
@@ -634,6 +648,7 @@ def flash_attention(
     kv_mask=None,
     scale: float | None = None,
     slot_positions: bool = False,
+    window: int = 0,
 ):
     """Drop-in for ops.attention.attention with identical masking model.
 
@@ -644,9 +659,21 @@ def flash_attention(
     positions are per-row arange with masked pads). Enables the causal
     tile skips (compute + DMA) that plain arange layouts get, while the
     mask math still uses the explicit position arrays.
+
+    window > 0 (causal): the sliding-window lower bound, t - u < window,
+    in the forward kernel alone: the serving path of a config with
+    window layers. It has no backward kernel (train such a config under
+    attn_impl="xla").
     """
     if scale is None:
         scale = q.shape[-1] ** -0.5
+    if window:
+        assert causal, "a sliding window is a bound beside the causal one"
+        return _flash_attention_impl(
+            q, k, v, q_positions, kv_positions, q_segment_ids,
+            kv_segment_ids, kv_mask, causal, float(scale),
+            slot_positions=slot_positions, window=int(window),
+        )[0]
     return _flash_vjp(
         q, k, v, q_positions, kv_positions, q_segment_ids, kv_segment_ids,
         kv_mask, causal, float(scale), slot_positions,
@@ -721,12 +748,14 @@ def _prepare(q, k, v, q_positions, kv_positions, q_segment_ids,
 
 def _flash_attention_impl(
     q, k, v, q_positions, kv_positions, q_segment_ids, kv_segment_ids,
-    kv_mask, causal, scale, with_lse=False, slot_positions=False,
+    kv_mask, causal, scale, with_lse=False, slot_positions=False, window=0,
 ):
     padded, flags, Tq = _prepare(
         q, k, v, q_positions, kv_positions, q_segment_ids, kv_segment_ids,
         kv_mask, causal, scale, slot_positions=slot_positions,
     )
+    if window:
+        flags["window"] = window
     out, lse = _mha_forward(*padded, with_lse=with_lse, **flags)
     return out[:, :, :Tq].swapaxes(1, 2), lse
 

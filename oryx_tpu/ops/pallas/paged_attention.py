@@ -68,22 +68,25 @@ def ragged_decode_attention(
     *,
     scale: float | None = None,
     interpret: bool | None = None,
+    window: int = 0,
 ):
     """Drop-in for ops.paged_kv.ragged_decode_attention (same contract).
     A decode row is a length-1 ragged lane: row b is packed row b of
     segment b at position kv_lengths[b] - 1, so this runs the packed
     kernel below (a row with kv_lengths == 0 sees no tile and returns
-    zeros)."""
+    zeros). window > 0: the row sees its last `window` keys only, its
+    first visible position max(0, kv_lengths - window)."""
     squeezed = q.ndim == 3
     if squeezed:
         q = q[:, None]
     B, Tq, Hq, D = q.shape
     assert Tq == 1, f"paged decode is single-token (got Tq={Tq})"
+    lengths = kv_lengths.astype(jnp.int32)
     out = ragged_paged_attention(
         q[:, 0], k_pages, v_pages, block_tables,
-        jnp.arange(B, dtype=jnp.int32),
-        kv_lengths.astype(jnp.int32) - 1,
+        jnp.arange(B, dtype=jnp.int32), lengths - 1,
         scale=scale, interpret=interpret,
+        **({"q_first": jnp.maximum(lengths - window, 0)} if window else {}),
     )
     return out if squeezed else out[:, None]
 
@@ -256,6 +259,7 @@ def _walk_row(
     block_copies,  # (seg, blk, slot) -> a block's further copies, or None
     zero,  # () -> None: buffers a masked column must find finite
     block_step,  # (length) -> ((i, slot) -> None): fold block i in
+    first_ref=None,  # [R] SMEM: a row's first visible position, or None
 ):
     """One grid step of a page walk: packed row r reads its own live
     pages, `pages_per_block` at a time, double-buffered. A block's
@@ -264,7 +268,9 @@ def _walk_row(
     0) starts the NEXT row's first copy, the buffer slot handed across
     grid steps in `slot_ref`. What a page is and what a block adds to
     the running softmax are the caller's (`_ragged_kernel`,
-    `_latent_kernel`)."""
+    `_latent_kernel`). With `first_ref` the walk starts at the block
+    that holds the row's first visible position and copies no page
+    wholly before it (the caller masks the keys before it)."""
     r, R = pl.program_id(0), pl.num_programs(0)
     S, maxp = bt_ref.shape
     P, ps, npb = num_pages, page_size, pages_per_block
@@ -274,6 +280,13 @@ def _walk_row(
         """(kv tokens, pages) packed row `row` reads."""
         length = jnp.clip(pos_ref[row] + 1, 0, maxp * ps)
         return length, pl.cdiv(length, ps)
+
+    def first_page(row):
+        """The page that holds `row`'s first visible position."""
+        return jnp.clip(first_ref[row], 0, maxp * ps - 1) // ps
+
+    def first_block(row):
+        return 0 if first_ref is None else first_page(row) // npb
 
     def copies(row, blk, slot):
         """[(live, copies)] of block `blk` of `row`'s walk into buffer
@@ -286,7 +299,10 @@ def _walk_row(
             pg = blk * npb + j
             page = jnp.clip(bt_ref[seg, jnp.minimum(pg, maxp - 1)], 0, P - 1)
             cps = page_copies(page, j, slot)
-            out.append((pg < pages, cps))
+            live = pg < pages
+            if first_ref is not None:
+                live = live & (pg >= first_page(row))
+            out.append((live, cps))
         if block_copies is not None:
             cps = block_copies(seg, blk, slot)
             out.append((blk * npb < pages, cps))
@@ -309,7 +325,7 @@ def _walk_row(
     def _first():
         zero()
         slot_ref[0] = 0
-        start(0, 0, 0)
+        start(0, first_block(0), 0)
 
     m_scr[...] = jnp.full_like(m_scr, NEG)
     l_scr[...] = jnp.zeros_like(l_scr)
@@ -330,7 +346,8 @@ def _walk_row(
         @pl.when(in_row | has_next)
         def _():
             start(
-                jnp.where(in_row, r, nxt), jnp.where(in_row, i + 1, 0),
+                jnp.where(in_row, r, nxt),
+                jnp.where(in_row, i + 1, first_block(nxt)),
                 1 - slot,
             )
 
@@ -341,9 +358,9 @@ def _walk_row(
     # A row with nothing to read still hands the walk on.
     @pl.when((nblk == 0) & has_next)
     def _():
-        start(nxt, 0, slot0)
+        start(nxt, first_block(nxt), slot0)
 
-    slot_ref[0] = jax.lax.fori_loop(0, nblk, step, slot0)
+    slot_ref[0] = jax.lax.fori_loop(first_block(r), nblk, step, slot0)
 
 
 def _fold_block(s, v, rows, scratch):
@@ -386,7 +403,13 @@ def _ragged_kernel(
     pages_per_block: int,
     heads_per_block: int,
     dequant_dtype: str | None = None,
+    windowed: bool = False,
 ):
+    # `windowed`: a fourth scalar-prefetched array leads `refs`, the
+    # rows' first visible positions.
+    first_ref = None
+    if windowed:
+        first_ref, *refs = refs
     # Quantized pool: a page arrives as storage-dtype codes, a block's
     # scales as one [1, brow] row (`_block_scales`), and the dequant
     # happens HERE, in the page walk — int8 is what crossed HBM. The
@@ -436,6 +459,8 @@ def _ragged_kernel(
         # c % Hk.
         col = jax.lax.broadcasted_iota(jnp.int32, (1, brow), 1)
         col_tok, col_head = col // Hk, col % Hk
+        if windowed:
+            first = first_ref[pl.program_id(0)]
 
         def fold(i, slot):
             k, v = k_buf[slot], v_buf[slot]  # [brow, D]
@@ -444,6 +469,8 @@ def _ragged_kernel(
                 k = k.astype(dq) * _scale_column(ks_buf[slot]).astype(dq)
                 v = v.astype(dq) * _scale_column(vs_buf[slot]).astype(dq)
             seen = i * (npb * ps) + col_tok < length
+            if windowed:  # the keys before the row's window
+                seen = seen & (i * (npb * ps) + col_tok >= first)
             for t in range(Hk // HB):  # static unroll over kv-head tiles
                 lo, n = t * HB * G, HB * G
                 row_head = t * HB + jax.lax.broadcasted_iota(
@@ -468,6 +495,7 @@ def _ragged_kernel(
         page_copies=page_copies,
         block_copies=scale_copies if quant else None,
         zero=zero, block_step=block_step,
+        **({"first_ref": first_ref} if windowed else {}),
     )
     _write_row(o_ref, scratch)
 
@@ -475,7 +503,7 @@ def _ragged_kernel(
 @functools.partial(
     jax.jit,
     static_argnames=(
-        "scale", "heads_per_block", "interpret", "dequant_dtype",
+        "scale", "heads_per_block", "interpret", "dequant_dtype", "windowed",
     ),
 )
 def _ragged_paged(
@@ -487,11 +515,13 @@ def _ragged_paged(
     q_positions,  # [R] int32
     k_scale=None,  # [P, ps] fp32 per-token scales (quantized pool)
     v_scale=None,
+    q_first=None,  # [R] int32 first visible position (window layers)
     *,
     scale: float,
     heads_per_block: int,
     interpret: bool,
     dequant_dtype: str | None = None,
+    windowed: bool = False,  # q_first is given
 ):
     R, Hq, D = q.shape
     P, ps, Hk, _ = k_pages.shape
@@ -530,14 +560,17 @@ def _ragged_paged(
         pltpu.VMEM((Hp, 128), jnp.float32),
         pltpu.VMEM((Hp, D), jnp.float32),
     ]
+    prefetch = [block_tables, q_segments, q_positions] + (
+        [q_first] if windowed else [])
     out = pl.pallas_call(
         functools.partial(
             _ragged_kernel, scale=scale, page_size=ps, num_kv_heads=Hk,
             pages_per_block=npb, heads_per_block=heads_per_block,
             dequant_dtype=dequant_dtype,
+            **({"windowed": True} if windowed else {}),
         ),
         grid_spec=pltpu.PrefetchScalarGridSpec(
-            num_scalar_prefetch=3,
+            num_scalar_prefetch=len(prefetch),
             grid=(R,),
             in_specs=in_specs,
             out_specs=row,
@@ -549,8 +582,7 @@ def _ragged_paged(
             dimension_semantics=("arbitrary",)
         ),
         interpret=interpret,
-    )(block_tables.astype(jnp.int32), q_segments.astype(jnp.int32),
-      q_positions.astype(jnp.int32), *operands)
+    )(*(a.astype(jnp.int32) for a in prefetch), *operands)
     return out
 
 
@@ -565,6 +597,7 @@ def ragged_paged_attention(
     scale: float | None = None,
     interpret: bool | None = None,
     heads_per_block: int | None = None,
+    q_first=None,  # [R] first visible position per packed row
 ):
     """Drop-in for ops.paged_kv.ragged_paged_attention (same contract):
     R packed query rows with mixed query lengths, each reading its own
@@ -572,7 +605,11 @@ def ragged_paged_attention(
     tile is `ragged_heads_per_block`'s unless pinned; a pin the TPU
     lowering would refuse raises. A quantized pool
     (ops.paged_kv.QuantPages planes) is read as codes + per-token
-    scales and dequantized inside the page walk."""
+    scales and dequantized inside the page walk. `q_first` (window
+    layers): row r sees positions q_first[r] .. q_positions[r] only; its
+    walk starts at the block that holds q_first[r], a page wholly
+    before it is not copied and the keys before it are masked. None:
+    every row sees from position 0, the kernel as it was."""
     R, Hq, D = q.shape
     Hk = k_pages.shape[2]
     assert Hq % Hk == 0, f"GQA requires Hq % Hk == 0, got {Hq=} {Hk=}"
@@ -596,6 +633,8 @@ def ragged_paged_attention(
     return _ragged_paged(
         q, k_pages, v_pages, block_tables, q_segments, q_positions,
         k_scale, v_scale,
+        *(() if q_first is None else (q_first,)),
+        **({} if q_first is None else {"windowed": True}),
         scale=float(scale), heads_per_block=int(heads_per_block),
         interpret=bool(interpret),
         dequant_dtype=dequant,

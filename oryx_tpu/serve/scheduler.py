@@ -570,6 +570,38 @@ class ContinuousScheduler:
                           qwen2.unsupported_for_recurrent(
                               "prefix-cache splicing"))
                 prefix_cache = False
+        # A model with window layers keeps TWO paged planes, one a layer
+        # kind, each behind its own allocator and block table, and the
+        # window plane's table is re-based as pages older than the
+        # window are given back (`paged_kv.WindowPlane`): only the split
+        # engine's two programs carry that. Everything else is refused
+        # here, by name; the prefix cache is constructed OFF (a hit
+        # would have to hand over the window plane's pages as they
+        # stood at the hit's last token), with the refusal's words in
+        # the log.
+        self.windowed = bool(pipe.cfg.llm.windowed)
+        if self.windowed:
+            for bad, mode in (
+                (ragged, "ragged=True"),
+                (speculate, "speculate"),
+                (fuse_steps != 1, "fuse_steps"),
+                (kv_dtype != "bf16", f"kv_dtype={kv_dtype!r}"),
+                (host_cache_bytes, "host_cache_bytes (the host spill tier)"),
+                (audit_sample_every, "audit_sample_every (the auditor's "
+                 "replay holds one table a slot)"),
+                (prefill_chunk is None, "an unchunked prefill "
+                 "(prefill_chunk=None: a prompt longer than the window "
+                 "plane's table)"),
+                (getattr(pipe, "mesh", None) is not None,
+                 "the tensor-parallel engine (--engine sharded)"),
+            ):
+                if bad:
+                    raise ValueError(qwen2.unsupported_for_window(mode))
+            if prefix_cache:
+                _LOG.info("prefix cache off: %s",
+                          qwen2.unsupported_for_window(
+                              "prefix-cache splicing"))
+                prefix_cache = False
         # Optional SLO watcher (utils/anomaly.py): TTFT and queue-depth
         # breaches fire oryx_anomaly_total{kind=} + events.jsonl.
         self.anomaly = anomaly
@@ -823,8 +855,10 @@ class ContinuousScheduler:
         # routing the step returns (generate.SHARE_STATS; docs/
         # OBSERVABILITY.md "Expert share"). rows_max / rows_mean count
         # the HELD experts' rows.
+        whole_stats = bool(self.windowed and pipe.cfg.llm.num_experts)
         self.share_stats = bool(
-            pipe.cfg.llm.experts_held or pipe.cfg.llm.zero_experts)
+            pipe.cfg.llm.experts_held or pipe.cfg.llm.zero_experts
+            or whole_stats)
         if pipe.cfg.llm.n_shared_experts:
             # Rows the shared expert computed, a layer-forward each.
             reg.counter("moe_shared_rows_total")
@@ -833,8 +867,8 @@ class ContinuousScheduler:
         # connected double layer's prefill is the program its accepted
         # cell was measured on, and one more output is another program.
         self.prefill_held_stats = bool(
-            pipe.cfg.llm.experts_held
-            and not pipe.cfg.llm.shortcut_double_layer)
+            (pipe.cfg.llm.experts_held
+             and not pipe.cfg.llm.shortcut_double_layer) or whole_stats)
         # A device array a chunk, until a read that waits anyway: the
         # decode chunk's harvest or a prompt's first token, a round
         # later at most (`_drain_prefill_held`).
@@ -866,7 +900,34 @@ class ContinuousScheduler:
             reg.gauge("ssm_state_bytes").set(
                 num_slots * pipe.cfg.llm.state_bytes_per_slot(
                     jnp.dtype(oryx.compute_dtype(pipe.cfg)).itemsize))
-        self.allocator = paged_kv.PageAllocator(self.num_pages, page_size)
+        self.wplane = None
+        if self.windowed:
+            # Both planes' accounting (docs/OBSERVABILITY.md "Window
+            # layers"): what a window layer's decode rows and prefill
+            # chunks could see, beside decode_kv_tokens_total and
+            # prefill_attn_pairs_total, which count what a GLOBAL layer
+            # sees; the window pages given back; the pages each plane
+            # holds. moe_* (held = every expert) from decode dispatches
+            # and prefill chunks, under the block step's names.
+            reg.counter("decode_window_kv_tokens_total")
+            reg.counter("prefill_window_attn_pairs_total")
+            reg.counter("kv_window_pages_released_total")
+            reg.counter("moe_experts_hit_total")
+            live = reg.gauge("kv_pages_live", ("plane",))
+            self._pages_live = {
+                p: live.labels(plane=p).set for p in ("global", "window")}
+            # The longest dispatch a lane rides: a prefill chunk with
+            # its padding, or a decode chunk.
+            self._wtable_pages = paged_kv.window_table_pages(
+                pipe.cfg.llm.sliding_window, max(prefill_chunk, chunk),
+                page_size)
+            # As many pages a slot as its table is wide, times the share
+            # of a full pool that num_pages asks for of the global one.
+            self.num_window_pages = max(self._wtable_pages, -(
+                -num_slots * self._wtable_pages * self.num_pages
+                // (num_slots * self.max_pages)))
+            self.wplane = self._new_window_plane()
+        self.allocator = self._new_allocator()
         # Page-pool observatory (utils/pagemap.py): oryx_pool_* gauges
         # refreshed at scrape time + the free-time page-lifetime/idle
         # histograms the allocator feeds through its observer hook.
@@ -1156,11 +1217,26 @@ class ContinuousScheduler:
         """A zeroed pool, placed: the pages and, for a model with
         state-space layers, the per-slot state planes."""
         return self._place_kv(qwen2.init_paged_kv_cache(
-            self.cfg.llm, self.num_pages, self.page_size,
+            self.cfg.llm,
+            (self.num_pages, self.num_window_pages) if self.windowed
+            else self.num_pages,
+            self.page_size,
             dtype=oryx.compute_dtype(self.cfg),
             kv_dtype=self._pool_kv_dtype(),
             **({"num_slots": self.num_slots} if self.recurrent else {}),
         ))
+
+    def _new_allocator(self) -> paged_kv.PageAllocator:
+        """The free list over `num_pages`: every layer's pages, or the
+        GLOBAL plane's where window layers have one of their own."""
+        return paged_kv.PageAllocator(
+            self.num_pages, self.page_size,
+            **({"plane": "global"} if self.windowed else {}))
+
+    def _new_window_plane(self) -> paged_kv.WindowPlane:
+        return paged_kv.WindowPlane(
+            self.num_window_pages, self.page_size, self.num_slots,
+            self._wtable_pages, self.cfg.llm.sliding_window)
 
     def _build_prefix_cache(self) -> PagedPrefixCache:
         """The prefix cache over the CURRENT allocator, host spill
@@ -1171,6 +1247,9 @@ class ContinuousScheduler:
         the page correctly."""
         if self.recurrent:
             raise ValueError(qwen2.unsupported_for_recurrent(
+                "prefix-cache splicing"))
+        if self.windowed:
+            raise ValueError(qwen2.unsupported_for_window(
                 "prefix-cache splicing"))
         return PagedPrefixCache(
             self.allocator, metrics=self.metrics,
@@ -1579,9 +1658,9 @@ class ContinuousScheduler:
         """Fresh page pool + allocator + prefix cache + empty slot state
         (used after a device-step failure invalidated the donated pool).
         Callers have already errored-out every in-flight request."""
-        self.allocator = paged_kv.PageAllocator(
-            self.num_pages, self.page_size
-        )
+        self.allocator = self._new_allocator()
+        if self.windowed:
+            self.wplane = self._new_window_plane()
         # A fresh allocator starts with observer=None: re-attach so
         # page-lifetime telemetry keeps flowing after the rebuild.
         self.pool_observatory.attach(self.allocator)
@@ -1624,6 +1703,8 @@ class ContinuousScheduler:
             if self.prefix_cache is not None:
                 holders.append(self.prefix_cache.held_pages())
             self.allocator.check_invariant(holders)
+            if self.wplane is not None:
+                self.wplane.check_invariant()
 
     def pool_snapshot(self) -> dict[str, Any]:
         """The live page-ownership map + derived summary — the
@@ -1653,6 +1734,15 @@ class ContinuousScheduler:
             for leaf in jax.tree_util.tree_leaves(self.kv_pages)
         ))
         snap["summary"] = pagemap.summarize(snap)
+        if self.wplane is not None:
+            # The window layers' plane: its own ownership map, each
+            # slot's table base, the same summary.
+            with race_exempt("pool snapshot: debug read, quiesced by "
+                             "contract"):
+                win = self.wplane.allocator.snapshot()
+                win["base"] = [int(b) for b in self.wplane.base]
+            win["summary"] = pagemap.summarize(win)
+            snap["window_plane"] = win
         return snap
 
     def _capture_oom(self, trigger: str, detail: str, *,
@@ -1987,6 +2077,9 @@ class ContinuousScheduler:
                 pages, owner=owner or self._owner_tag(self.slots[s])
             )
         self.bt[s] = self._sentinel
+        if self.wplane is not None:
+            self.wplane.release(
+                s, owner=owner or self._owner_tag(self.slots[s]))
 
     def _clear_slot(self, s: int) -> None:
         # Last accrual point while the occupant still holds its pages
@@ -2026,10 +2119,12 @@ class ContinuousScheduler:
         request explicitly)."""
         tokens = min(tokens, self.max_ctx)
         need = self.allocator.pages_for(tokens) - self._held(s)
-        if need <= 0:
-            return True
         if req is None:
             req = self.slots[s]
+        if self.wplane is not None and not self._grow_window(s, tokens, req):
+            return False
+        if need <= 0:
+            return True
         # Page count is about to change: bank the integral at the OLD
         # held count first, or the grown pages would be backdated.
         self._accrue_page_seconds(s)
@@ -2074,6 +2169,57 @@ class ContinuousScheduler:
             return False
         self.bt[s, held: held + need] = pages
         self._oom_episode = False  # pressure episode over: pages flowed
+        return True
+
+    def _window_args(self, rows) -> dict:
+        """The window plane's table and base of slots `rows`, as the two
+        step programs take them; nothing for a model without window
+        layers."""
+        if self.wplane is None:
+            return {}
+        # Host COPIES (numpy's own: `jnp.array` of a view was still
+        # read late): `advance` shifts a table's row in place before
+        # the next dispatch, while this one may not have read its
+        # operands yet.
+        return {"window_tables": jnp.asarray(self.wplane.tables[rows].copy()),
+                "window_base": jnp.asarray(self.wplane.base[rows].copy())}
+
+    def _grow_window(self, s: int, tokens: int, req: _Request) -> bool:
+        """The window plane's part of `_grow_slot`, at a dispatch
+        boundary: the lane's next dispatch has its first query at the
+        position it stands at (its prefill offset, or its length once
+        it decodes), so first every window page wholly older than that
+        query's window goes back to the plane's allocator, the table
+        is shifted and the base moved (`WindowPlane.advance`); then the
+        table is grown to `tokens`, as far as its width reaches (a long
+        prompt's later chunks grow it again). False, nothing taken,
+        when the plane's free list is short: the caller defers or
+        evicts, as for the global plane."""
+        at = int(self.lengths[s]) if req.activated else req.prefill_pos
+        wp, tag = self.wplane, self._owner_tag(req)
+        if not req.activated and at == 0 and wp.allocator.num_free < min(
+                wp.tables.shape[1], wp.allocator.pages_for(tokens)):
+            return False  # its later chunks would not fit: not admitted
+        # What the NEXT dispatch writes; a long prompt's later chunks
+        # ask again.
+        tokens = min(tokens, at + max(self.prefill_chunk, self._win)
+                     + self._win)
+        freed = wp.advance(s, at, owner=tag)
+        if freed:
+            self.metrics.inc("kv_window_pages_released_total", len(freed))
+            req.trace.event("window_release", slot=s, pages=len(freed),
+                            base=int(wp.base[s]))
+        if not wp.grow(s, tokens, owner=tag):
+            if not self._oom_episode:
+                self._oom_episode = True
+                need = wp.need(s, tokens)
+                self._capture_oom(
+                    "pool_pressure",
+                    f"window plane shortfall: need {need} page(s), "
+                    f"{wp.allocator.num_free} free",
+                    asking=(s, req, need),
+                )
+            return False
         return True
 
     # ---- scheduling loop -------------------------------------------------
@@ -2923,6 +3069,14 @@ class ContinuousScheduler:
                 end = min(off + width, L)
             # The narrowest table that holds every position the chunk
             # reads or writes (its padding is written too).
+            if self.wplane is not None and not self._grow_slot(
+                    s, end + (self._win if end >= L else 0), req=req):
+                # The window plane cannot cover this chunk: the lane
+                # (the youngest: no other is admitted while it
+                # prefills) gives its pages back and waits at the
+                # queue's head.
+                self._evict(s)
+                return
             reach = -(-(off + emb.shape[1]) // self.page_size)
             table = next(
                 (b for b in self.table_buckets if b >= reach),
@@ -2956,6 +3110,7 @@ class ContinuousScheduler:
                     # at 0, carried by every other.
                     **({"slots": jnp.asarray([s], np.int32)}
                        if self.recurrent else {}),
+                    **self._window_args(slice(s, s + 1)),
                 )
             req.trace.end(pf)
         self.kv_pages = kv
@@ -2970,6 +3125,12 @@ class ContinuousScheduler:
         # Token p attends positions 0..p: the chunk's causal pairs.
         self.metrics.inc(
             "prefill_attn_pairs_total", (end - off) * (off + end + 1) // 2)
+        if self.windowed:
+            # ... of which a window layer's query at p sees min(p + 1, W).
+            W = self.cfg.llm.sliding_window
+            p = np.arange(off, end, dtype=np.int64)
+            self.metrics.inc("prefill_window_attn_pairs_total",
+                             int(np.minimum(p + 1, W).sum()))
         self.metrics.inc("prefill_live_positions_total", end)
         self.metrics.inc(
             "prefill_table_positions_total",
@@ -3311,6 +3472,7 @@ class ContinuousScheduler:
                     attn_impl=self.cfg.attn_impl,
                     compute_dtype=oryx.compute_dtype(self.cfg),
                     numerics=numer,
+                    **self._window_args(slice(None)),
                 )
         nstats = out[8] if numer else None
         (self.kv_pages, tok, lengths, finished, recent, self.keys,
@@ -3328,6 +3490,18 @@ class ContinuousScheduler:
             self.metrics.inc(
                 "decode_kv_tokens_total",
                 int((n * a + n * (n + 1) // 2).sum()))
+        if self.windowed:
+            # A lane live for n steps from length a fed positions a ..
+            # a + n - 1, and a window layer's row at p read min(p + 1,
+            # W) cached tokens (a global layer's p + 1: kv_tokens of
+            # the step's own statistics).
+            W = self.cfg.llm.sliding_window
+            a = ran_with.astype(np.int64)
+            n = self.lengths.astype(np.int64) - a
+            i = np.arange(self.chunk, dtype=np.int64)[None, :]
+            read = np.minimum(a[:, None] + i + 1, W) * (i < n[:, None])
+            self.metrics.inc("decode_window_kv_tokens_total",
+                             int(read.sum()))
         share = None
         if self.share_stats:
             with self._phase("copy_out"):
@@ -3560,6 +3734,8 @@ class ContinuousScheduler:
         m.inc("moe_expert_rows_max_total", st["held_rows_max"])
         m.inc("moe_expert_rows_mean_total", st["held_rows"] / count)
         m.inc("decode_kv_tokens_total", st["kv_tokens"])
+        if self.windowed:
+            m.inc("moe_experts_hit_total", st["held_hit"])
         if self.cfg.llm.n_shared_experts:
             m.inc(
                 "moe_shared_rows_total",
@@ -3584,6 +3760,13 @@ class ContinuousScheduler:
             m.inc("moe_prefill_held_experts_hit_total", st["held_hit"])
             m.inc("moe_prefill_held_expert_slots_total",
                   st["layer_forwards"] * self.cfg.llm.held[1])
+            if self.windowed:
+                # A whole-expert config's chunks under the block step's
+                # names too, beside its decode dispatches'.
+                m.inc("moe_expert_rows_max_total", st["held_rows_max"])
+                m.inc("moe_expert_rows_mean_total",
+                      st["held_rows"] / self.cfg.llm.held[1])
+                m.inc("moe_experts_hit_total", st["held_hit"])
 
     def _count_dispatch(self, kind: str, rows: int, *temps) -> None:
         """ONE device dispatch happened: its kind, its rows, and whether
@@ -4390,6 +4573,10 @@ class ContinuousScheduler:
             if r is not None and not self.finished[s]
         )
         self.metrics.set_gauge("slot_occupancy", live / self.num_slots)
+        if self.wplane is not None:
+            for plane, alloc in (("global", self.allocator),
+                                 ("window", self.wplane.allocator)):
+                self._pages_live[plane](alloc.num_pages - alloc.num_free)
         u = self.metrics.get("decode_steps_useful")
         t = self.metrics.get("decode_steps_total")
         if t:

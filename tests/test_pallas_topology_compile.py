@@ -725,3 +725,93 @@ def test_jamba_serve_programs_keep_pages_and_state_in_place(
     assert memory.temp_size_in_bytes < 5 * one_layers_state + invariants
     total = memory.argument_size_in_bytes + memory.temp_size_in_bytes
     assert 0.25 * 16e9 < total < 0.5 * 16e9
+
+
+@pytest.mark.parametrize("program", ["paged_decode_chunk", "paged_prefill"])
+def test_smallthinker_serve_programs_keep_both_planes_in_place(
+    one_chip, mosaic, program, capsys
+):
+    """The two programs of `smallthinker-21b-a3b.mixed-queue` at the
+    cell's FULL size (8 layers = two periods of global, window, window,
+    window; 64 experts of 768; the whole vocabulary of 151,936; 32 slots
+    x 16,384, page 64, chunk 1,024 / 8), compiled for the described
+    v5e: the donated pool is TWO paged planes, the global layers' [2,
+    8192, 64, 4, 128] behind a table of 256 pages a slot and the window
+    layers' [6, 2592, 64, 4, 128] behind a table of 81, and both are
+    aliased to the output whole; the temporaries stay far under one
+    plane (nothing copies a plane: the period scan CARRIES all four
+    arrays). The attention kernels are `_ragged_paged` (decode, with a
+    fourth scalar-prefetched array on window layers) and `_mha_forward`
+    (prefill, the lower bound in its mask), the experts `gmm`. The
+    numbers printed here are the configuration file's `memory` block:
+    the pool is 2.15 + 2.04 GB where one table for every layer would
+    be 8.59 GB."""
+    import dataclasses
+
+    from oryx_tpu import config as cfg_lib
+    from oryx_tpu.models import generate, qwen2
+    from oryx_tpu.ops import paged_kv
+
+    def on_chip(make):
+        return jax.tree_util.tree_map(
+            lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=one_chip),
+            jax.eval_shape(make),
+        )
+
+    cfg = dataclasses.replace(cfg_lib.smallthinker_21b().llm, num_layers=8)
+    slots, page_size, ctx, chunk = 32, 64, 16384, 1024
+    wide = paged_kv.window_table_pages(cfg.sliding_window, chunk, page_size)
+    assert wide == 81
+    S = slots if program == "paged_decode_chunk" else 1
+    rows = lambda dtype, *tail: jax.ShapeDtypeStruct(  # noqa: E731
+        (S, *tail), dtype, sharding=one_chip
+    )
+    params = on_chip(
+        lambda: qwen2.init_params(cfg, jax.random.key(0), dtype=BF16))
+    kv = on_chip(lambda: qwen2.init_paged_kv_cache(
+        cfg, (slots * ctx // page_size, slots * wide), page_size, dtype=BF16))
+    tables = rows(jnp.int32, ctx // page_size)
+    window = dict(window_tables=rows(jnp.int32, wide),
+                  window_base=rows(jnp.int32))
+    sampling = (
+        on_chip(lambda: jax.random.split(jax.random.key(0), S)),
+        rows(jnp.float32), rows(jnp.float32), rows(jnp.int32),
+    )
+    common = dict(attn_impl="pallas", compute_dtype=BF16, **window)
+    if program == "paged_decode_chunk":
+        lowered = generate.paged_decode_chunk.lower(
+            params, cfg, kv, tables, rows(jnp.int32), rows(jnp.int32),
+            rows(jnp.bool_), rows(jnp.int32, 0), *sampling,
+            chunk=8, eos=151936, **common,
+        )
+    else:
+        lowered = generate.paged_prefill.lower(
+            params, cfg, rows(BF16, chunk, cfg.hidden_size), rows(jnp.int32),
+            tables, kv, rows(jnp.int32), *sampling, held_stats=True, **common,
+        )
+    compiled = lowered.compile()
+    text = compiled.as_text()
+    assert "tpu_custom_call" in text and "gmm" in text
+    kernel = {"paged_decode_chunk": "_ragged_paged",
+              "paged_prefill": "_mha_forward"}[program]
+    assert kernel in text
+    memory = compiled.memory_analysis()
+    nbytes = lambda t: sum(  # noqa: E731
+        a.size * a.dtype.itemsize for a in jax.tree_util.tree_leaves(t))
+    pool_bytes, weight_bytes = nbytes(kv), nbytes(params)
+    with capsys.disabled():
+        print(f"\n{program}: weights {weight_bytes} B, pool {pool_bytes} B, "
+              f"arguments {memory.argument_size_in_bytes} B, temporaries "
+              f"{memory.temp_size_in_bytes} B")
+    # 3,966,937,600 parameters, the routers' 8 x 2560 x 64 in float32.
+    assert weight_bytes == 2 * 3_966_937_600 + 2 * 8 * 2560 * 64
+    global_bytes = slots * ctx * 4096  # 2 layers x 2,048 B a token
+    window_bytes = slots * wide * page_size * 12288  # 6 layers
+    assert pool_bytes == global_bytes + window_bytes
+    assert pool_bytes < 0.5 * slots * ctx * 16384  # one table for all: 8.59 GB
+    assert memory.alias_size_in_bytes == pool_bytes
+    # (the window plane's K alone is 1.02 GB; a body that wrote and read
+    # one carried plane three times kept such a copy, 1.375 GB.)
+    assert memory.temp_size_in_bytes < 0.45e9
+    total = memory.argument_size_in_bytes + memory.temp_size_in_bytes
+    assert 0.25 * 16e9 < total < 0.8 * 16e9
