@@ -147,6 +147,9 @@ def main(argv=None) -> int:
         from benchmark import trace as trace_lib
 
         line["breakdown"] = trace_lib.breakdown(run["trace"])
+        # beside device.window_s (the device planes' own extent): the
+        # host's stamp of the same slice, for whoever wants both clocks
+        line["device"]["host_window_s"] = run["trace"].get("host_window_s")
     if rehearse:
         line["rehearsal"] = True
     if args.set:

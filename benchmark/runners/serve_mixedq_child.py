@@ -6,9 +6,8 @@ global hybrid with routed experts (SmallThinker-21BA3B-Instruct).
 
 and then the same one-line commands on stdin and JSON events on stdout
 as runners/serve_reasoning_child.py, whose command loop
-(`serve_commands`: the traced slice's `window_s` and `busy_s` both off
-the device planes' ONE clock, `device_extent_s`), `Served` record and
-tokenizer it uses as they are. What is this cell's own: the
+(serve_latent_child's `serve_commands`), `Served` record and tokenizer
+it uses as they are. What is this cell's own: the
 configuration keys it holds the program to (`build_config`), the sizes
 the plain reference reads (`ref_sizes`), the KINDS of finished request
 it samples for the comparison (`sample_served`: a long document, a
@@ -35,10 +34,7 @@ sys.path.insert(0, ROOT)
 
 from benchmark.runners.serve_docqa_child import _TOKEN, Served  # noqa: E402
 from benchmark.runners.serve_latent_child import (  # noqa: E402
-    PrefixTokenizer, say,
-)
-from benchmark.runners.serve_reasoning_child import (  # noqa: E402
-    serve_commands,
+    PrefixTokenizer, say, serve_commands,
 )
 
 T_START = time.monotonic()
@@ -220,7 +216,7 @@ def main(argv=None) -> int:
         window_pages=int(srv.scheduler.num_window_pages),
         window_table_pages=int(srv.scheduler.wplane.tables.shape[1]))
     try:
-        serve_commands(args.trace_dir)
+        serve_commands(srv, args.trace_dir)
     finally:
         if srv.supervisor is not None:
             srv.supervisor.stop()

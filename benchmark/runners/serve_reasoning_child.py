@@ -15,17 +15,14 @@ event follows `stop`, before `stopped`. Nothing of the comparison is
 inside `setup_s`. A program that lacks the configuration's preset (the
 parent commit) leaves at once, before it touches the device.
 
-What differs from the other children: the traced slice's `window_s` is
-the device planes' OWN extent (first op's start to last op's end on
-the trace's clock), not the host's stamp around start_trace /
-stop_trace: `busy_s` is a sum over the same events, so it can never
-read over `window_s` (ROADMAP B0(a)(i)).
+The command loop is serve_latent_child's: as in every runner, the
+traced slice's `window_s` is the device planes' OWN extent
+(benchmark/trace.py `reduce_dir`), so `busy_s` can never read over it.
 """
 
 from __future__ import annotations
 
 import argparse
-import contextlib
 import gc
 import json
 import os
@@ -39,7 +36,7 @@ sys.path.insert(0, ROOT)
 
 from benchmark.runners.serve_docqa_child import _TOKEN, Served  # noqa: E402
 from benchmark.runners.serve_latent_child import (  # noqa: E402
-    PrefixTokenizer, say,
+    PrefixTokenizer, say, serve_commands,
 )
 
 T_START = time.monotonic()
@@ -113,83 +110,6 @@ def ref_sizes(conf: dict, cfg) -> dict:
         "num_key_value_heads": llm.num_kv_heads, "head_dim": llm.head_dim,
         "rms_norm_eps": llm.rms_norm_eps,
     })
-
-
-def device_extent_s(planes) -> float:
-    """Seconds from the first device event's start to the last one's
-    end, over every line of every device plane."""
-    from benchmark import trace as trace_lib
-
-    lo, hi = None, None
-    for plane in trace_lib.device_planes(planes):
-        for ln in plane.lines:
-            for ev in ln.events:
-                s, e = trace_lib._abs_ps(ln, ev)
-                lo = s if lo is None else min(lo, s)
-                hi = e if hi is None else max(hi, e)
-    return 0.0 if lo is None else (hi - lo) / 1e12
-
-
-def reduce_trace(trace_dir: str, host_window_s: float) -> dict:
-    """trace.reduce_planes with `window_s` from the device's own clock
-    (the host's stamp where the capture holds no device event: the CPU
-    rehearsal)."""
-    from benchmark import trace as trace_lib
-
-    files = trace_lib.find_xplane_files(trace_dir)
-    if not files:
-        return {}
-    planes = trace_lib.parse_xspace(files[-1])
-    extent = device_extent_s(planes)
-    out = trace_lib.reduce_planes(
-        planes, window_s=extent or host_window_s)
-    out["host_window_s"] = host_window_s
-    return out
-
-
-def serve_commands(trace_dir: str) -> None:
-    """Obey the parent's one-line commands until `stop`
-    (serve_latent_child.serve_commands with the trace's own window)."""
-    import jax
-
-    from oryx_tpu.analysis.sanitizers import recompile_watchdog
-
-    from benchmark import program
-
-    stack = contextlib.ExitStack()
-    wd = None
-    trace_t = {}
-    for line in sys.stdin:
-        cmd = line.strip()
-        if cmd == "arm":
-            wd = stack.enter_context(
-                recompile_watchdog(budget=10**9, action="record"))
-            say(event="armed")
-        elif cmd == "trace_start":
-            opts = jax.profiler.ProfileOptions()
-            opts.python_tracer_level = 0  # host spans, no py stacks
-            jax.profiler.start_trace(trace_dir, profiler_options=opts)
-            trace_t["start"] = time.monotonic()
-            say(event="trace_started")
-        elif cmd == "trace_stop":
-            trace_t["stop"] = time.monotonic()
-            jax.profiler.stop_trace()
-            say(event="trace_stopped",
-                seconds=trace_t["stop"] - trace_t["start"])
-        elif cmd == "disarm":
-            stack.close()
-            out = {
-                "event": "disarmed",
-                "compiles": int(wd.total) if wd else None,
-                "compile_counts": dict(wd.counts) if wd else {},
-                "memory_peak_bytes": program.memory_peak_bytes(),
-            }
-            if trace_t:
-                out["trace"] = reduce_trace(
-                    trace_dir, trace_t["stop"] - trace_t["start"])
-            say(**out)
-        elif cmd == "stop":
-            break
 
 
 def sample_served(served: Served, pipe, *, long_answer: int,
@@ -285,7 +205,7 @@ def main(argv=None) -> int:
     say(event="ready", port=srv.server_address[1],
         t=time.monotonic() - T_START)
     try:
-        serve_commands(args.trace_dir)
+        serve_commands(srv, args.trace_dir)
     finally:
         if srv.supervisor is not None:
             srv.supervisor.stop()

@@ -4,8 +4,11 @@ seeds in both sets; per metric the wider of the two sets' spreads,
 spread = IQR / median by statistics.quantiles(n=4)).
 
     python benchmark/tools/measure.py --workload <cell> [--sets 2] [--runs 6]
-        [--seconds S] [--trace-run 1] [--base-seed N]
+        [--seconds S] [--trace-run N] [--base-seed N]
 
+`--trace-run N` adds N traced runs, one on each of the first N seeds
+(`--sets 0 --runs 6 --trace-run 6`: the device instrument alone, with
+`busy_s`, `window_s` and `host_window_s` of every slice).
 Writes every last line and the summary to chiprun_out/measure.<cell>.json.
 Each run is a fresh process, as in the driver's check.
 """
@@ -69,7 +72,7 @@ def main(argv=None) -> int:
     out_dir = os.path.join(ROOT, "chiprun_out")
     os.makedirs(out_dir, exist_ok=True)
     seeds = [args.base_seed + 104729 * i for i in range(args.runs)]
-    record = {"cell": args.workload, "sets": [], "traced": None}
+    record = {"cell": args.workload, "sets": [], "traced": []}
     path = os.path.join(out_dir, f"measure.{args.workload}{args.tag}.json")
     for s in range(args.sets):
         runs = []
@@ -93,11 +96,24 @@ def main(argv=None) -> int:
             with open(path, "w") as f:
                 json.dump({**record, "partial": runs}, f)
         record["sets"].append(runs)
-    if args.trace_run:
-        record["traced"] = one(
-            args.workload, seeds[0], args.seconds, 1, extra,
-            os.path.join(out_dir, "runs", args.workload + args.tag))
-        print(json.dumps({"traced": record["traced"]["last"]}), flush=True)
+    for seed in seeds[:args.trace_run]:
+        r = one(args.workload, seed, args.seconds, 1, extra,
+                os.path.join(out_dir, "runs", args.workload + args.tag))
+        record["traced"].append(r)
+        last = r["last"] or {}
+        dev = last.get("device", {})
+        print(json.dumps({
+            "traced": seed, "rc": r["rc"], "wall_s": round(r["wall_s"], 1),
+            "correct": last.get("correct"), "problems": last.get("problems"),
+            **{k: dev.get(k) for k in ("busy_s", "window_s", "host_window_s",
+                                       "memory_peak_bytes")},
+            "metrics": {k: v["value"]
+                        for k, v in last.get("metrics", {}).items()},
+            "breakdown": last.get("breakdown"),
+            "err": r["stderr_tail"][-600:],
+        }), flush=True)
+        with open(path, "w") as f:
+            json.dump(record, f)
     summary = {}
     names = set()
     for runs in record["sets"]:

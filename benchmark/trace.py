@@ -264,8 +264,8 @@ def reduce_planes(planes, *, window_s: float, plane_prefix=DEVICE_PLANE,
     """The numbers every per-layer reader works from:
 
     busy_s      union of device-op intervals, averaged over the chips
-    window_s    length of the traced window (given: the host's clock
-                around start_trace/stop_trace)
+    window_s    length of the traced window (as given; reduce_dir gives
+                the device planes' own extent)
     ops         {op name: [SELF seconds, count]} summed over chips / chips
                 (self time: see self_times; a kernel or a fusion is a
                 leaf, so its self time is its whole time)
@@ -349,11 +349,48 @@ def _covering_span(spans, s: int, e: int) -> str:
     return best
 
 
+def device_extent_s(planes, plane_prefix: str = DEVICE_PLANE) -> float:
+    """Seconds from the first device event's start to the last one's
+    end, over every line of every device plane; 0.0 where the capture
+    holds no device event."""
+    lo = hi = None
+    for plane in device_planes(planes, plane_prefix):
+        for ln in plane.lines:
+            for ev in ln.events:
+                s, e = _abs_ps(ln, ev)
+                lo = s if lo is None else min(lo, s)
+                hi = e if hi is None else max(hi, e)
+    return 0.0 if lo is None else (hi - lo) / 1e12
+
+
 def reduce_dir(trace_dir: str, *, window_s: float) -> dict:
+    """reduce_planes over the capture in `trace_dir`, with ONE clock for
+    the slice and what it is divided into: `window_s` is the device
+    planes' own extent (device_extent_s), and the caller's `window_s`,
+    the host's stamp around start_trace / stop_trace, is kept beside it
+    as `host_window_s`. The stamp is the window only where the capture
+    holds no device event (the CPU rehearsal).
+
+    The capture and the stamp differ by some ms either side (start_trace
+    has returned before the first stamp, stop_trace is called after the
+    second), so against the stamp a device that idles less than that
+    reads busy over its window. Against the extent it cannot: `busy_s`
+    is the union of intervals that all lie inside [first start, last
+    end], on every chip, so busy_s <= window_s, asserted here. What the
+    extent leaves out is idle time before the first device event and
+    after the last."""
     files = find_xplane_files(trace_dir)
     if not files:
         return {}
-    return reduce_planes(parse_xspace(files[-1]), window_s=window_s)
+    planes = parse_xspace(files[-1])
+    extent = device_extent_s(planes)
+    out = reduce_planes(planes, window_s=extent or window_s)
+    out["host_window_s"] = window_s
+    if extent:
+        assert out["busy_s"] <= out["window_s"], (
+            f"trace.py: busy_s {out['busy_s']} outside the device "
+            f"extent {out['window_s']}")
+    return out
 
 
 def match_seconds(table: dict, patterns) -> tuple[float, float]:

@@ -47,10 +47,11 @@ def test_rehearsal_traced_line_has_the_counter_metrics(checkout):
     line = last_line(p)
     assert line["correct"] is True, line["problems"]
     m = line["metrics"]
-    # T = 2: a block is two denoising forwards and a commit; a prompt's
-    # tail can leave a first block fewer positions than forwards.
-    assert 1.0 < m["diff.tokens_per_forward"]["value"] <= 4 / 3 + 1e-9
-    assert 33.0 < m["diff.commit_share"]["value"] <= 50.0
+    # B = 4, T = 2: a block is two denoising forwards, its commit rides
+    # the next block's first (PR 39) and no forward only commits; a
+    # prompt's tail can leave a first block fewer positions than forwards.
+    assert 1.0 < m["diff.tokens_per_forward"]["value"] <= 4 / 2 + 1e-9
+    assert 0.0 <= m["diff.commit_share"]["value"] < 1.0
     assert m["moe.expert_imbalance"]["value"] >= 1.0
     assert 0 < m["sched.decode_util.batch"]["value"] <= 100
     # No device plane on the CPU: the trace readers find nothing.
@@ -137,6 +138,18 @@ def _hand_bytes(forwards_with_head, forwards_without, hit, rows=None):
     ("moe.expert_imbalance", {"counters": {}}, None),
     ("moe.step_weight_bw", RUN,
      100.0 * _hand_bytes(6, 3, 9 * 7 * 128) / 0.18 / 819e9),
+    # since PR 39 no forward only commits: the series is there and 0,
+    # a count like any other (every forward has a head)
+    ("moe.step_weight_bw", dict(RUN, trace={
+        "modules": RUN["trace"]["modules"],
+        "slice_counters": dict(SLICE, **{FWD % "denoise": 9.0,
+                                         FWD % "commit": 0.0})}),
+     100.0 * _hand_bytes(9, 0, 9 * 7 * 128) / 0.18 / 819e9),
+    # a program without the series (before PR 26): nothing to bill
+    ("moe.step_weight_bw", dict(RUN, trace={
+        "modules": RUN["trace"]["modules"],
+        "slice_counters": {k: v for k, v in SLICE.items()
+                           if k != FWD % "commit"}}), None),
     ("moe.step_weight_bw", dict(RUN, trace={
         "modules": {}, "slice_counters": SLICE}), None),
     ("moe.step_weight_bw", dict(RUN, trace={
@@ -194,14 +207,14 @@ def test_manifest_entries_for_the_cell():
     assert conf["source"] == CONF["source"]
     by_name = {e["name"]: e for e in m["per_layer"]}
     for name in NEW:
-        assert by_name[name]["workloads"] == [CELL]
+        assert CELL in by_name[name]["workloads"]
         assert by_name[name]["moves"] == "serve_tok_s"
         assert os.path.exists(os.path.join(
             ROOT, "benchmark", "layer_metrics", name + ".py"))
     for name in SHARED:
-        assert by_name[name]["workloads"][-1] == CELL
+        assert CELL in by_name[name]["workloads"]
     e2e = {e["name"]: e for e in m["end_to_end"]}
-    assert e2e["serve_tok_s"]["workloads"] == ["oryx-7b.visual-batch", CELL]
+    assert CELL in e2e["serve_tok_s"]["workloads"]
 
 
 def test_configuration_file_keeps_every_published_width():

@@ -166,25 +166,23 @@ def test_costs_mla_against_hand_counted_bytes_and_flops():
 
 def test_manifest_entries_for_the_cell():
     m = json.load(open(os.path.join(ROOT, "BENCHMARK.json")))
-    assert m["workloads"][-1]["name"] == CELL
-    cell = m["workloads"][-1]
+    cell = {w["name"]: w for w in m["workloads"]}[CELL]
     assert cell["chips"] == 1 and cell["config"] == CONFIG
     assert cell["traffic"] == "tool-sessions"
-    conf = m["configs"][-1]
-    assert conf["name"] == CONFIG and conf["source"] == CONF["source"]
+    conf = {c["name"]: c for c in m["configs"]}[CONFIG]
+    assert conf["source"] == CONF["source"]
     assert conf["reduced"] == ["num_layers", "n_routed_experts", "vocab_size"]
     assert set(conf["reduced"]) == set(CONF["reduced"])
     by_name = {e["name"]: e for e in m["per_layer"]}
-    assert [e["name"] for e in m["per_layer"][-len(NEW):]] == list(NEW)
     for name in NEW:
-        assert by_name[name]["workloads"] == [CELL]
+        assert CELL in by_name[name]["workloads"]
         assert by_name[name]["moves"] == "serve_tok_s"
         assert metric_files.load(name).LAYER == by_name[name]["layer"]
     for name in SHARED:
-        assert by_name[name]["workloads"][-1] == CELL
+        assert CELL in by_name[name]["workloads"]
         assert by_name[name]["moves"] == "serve_tok_s"
     e2e = {e["name"]: e for e in m["end_to_end"]}
-    assert e2e["serve_tok_s"]["workloads"][-1] == CELL
+    assert CELL in e2e["serve_tok_s"]["workloads"]
     assert "workloads" not in e2e["setup_s"]
     wl = json.load(open(os.path.join(
         ROOT, "benchmark", "workloads", CELL + ".json")))

@@ -280,18 +280,16 @@ def test_child_builds_the_config_and_refuses_another_geometry():
 def test_the_traced_window_is_the_device_planes_own_extent():
     """`busy_s` sums device events; the window is first start to last
     end of the same events, so busy can never read over it."""
-    from benchmark.runners import serve_reasoning_child as child
-
     ops = trace.Line("XLA Ops", [trace.Event("a", 400, 0),
                                  trace.Event("b", 300, 700)], 5)
     mods = trace.Line("XLA Modules", [trace.Event("jit_x(1)", 1100, 0)], 5)
     planes = [trace.Plane("/device:TPU:0", [ops, mods]),
               trace.Plane("/host:CPU", [trace.Line("t", [
                   trace.Event("span", 10**9, 0)], 1)])]
-    assert child.device_extent_s(planes) == pytest.approx(1100e-12)
-    assert child.device_extent_s(planes[1:]) == 0.0
+    assert trace.device_extent_s(planes) == pytest.approx(1100e-12)
+    assert trace.device_extent_s(planes[1:]) == 0.0
     red = trace.reduce_planes(
-        planes, window_s=child.device_extent_s(planes))
+        planes, window_s=trace.device_extent_s(planes))
     assert red["busy_s"] == pytest.approx(700e-12) and (
         red["busy_s"] <= red["window_s"])
 
