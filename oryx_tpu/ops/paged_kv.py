@@ -111,6 +111,11 @@ class PageAllocator:
         self.page_size = page_size
         self._free: list[int] = list(range(num_pages - 1, -1, -1))
         self._refs: list[int] = [0] * num_pages
+        # 1 / holders of each page (1.0 while it is free), and 0.0 at
+        # the sentinel's index: what a holder is charged for the page,
+        # kept beside `_refs` so that a block table's charge is one
+        # C-level sum (`charge`), sentinels and all.
+        self._share: list[float] = [1.0] * num_pages + [0.0]
         # Ownership map state (one tag per live reference, in grant
         # order) + tenancy clocks, all monotonic-clock based.
         self._owners: list[list[str]] = [[] for _ in range(num_pages)]
@@ -143,6 +148,12 @@ class PageAllocator:
             raise ValueError(f"page {page} outside pool of {self.num_pages}")
         return self._refs[page]
 
+    def charge(self, table: list[int]) -> float:
+        """Sum over `table` (page ids; the sentinel counts nothing) of
+        1 / the page's holders: a block table's refcount-weighted page
+        count, without a Python-level walk of it."""
+        return sum(map(self._share.__getitem__, table))
+
     def alloc(self, n: int, *, owner: str | None = None) -> list[int]:
         if n > 0:
             # Chaos site: simulated pool exhaustion. Every caller must
@@ -168,6 +179,7 @@ class PageAllocator:
         tag = owner or "?"
         for p in out:
             self._refs[p] = 1
+            self._share[p] = 1.0
             self._owners[p] = [tag]
             self._born[p] = self._touched[p] = now
         self.min_free = min(self.min_free, len(self._free))
@@ -186,6 +198,7 @@ class PageAllocator:
         tag = owner or "?"
         for p in pages:
             self._refs[p] += 1
+            self._share[p] = 1.0 / self._refs[p]
             self._owners[p].append(tag)
             self._touched[p] = now
 
@@ -214,6 +227,7 @@ class PageAllocator:
         released = []
         for p in pages:
             self._refs[p] -= 1
+            self._share[p] = 1.0 / max(1, self._refs[p])
             tags = self._owners[p]
             if owner is not None and owner in tags:
                 tags.remove(owner)
@@ -251,6 +265,8 @@ class PageAllocator:
         allocated = {p for p, r in enumerate(self._refs) if r > 0}
         if any(r < 0 for r in self._refs):
             raise RuntimeError(f"negative refcount: {self._refs}")
+        if self._share != [1.0 / max(1, r) for r in self._refs] + [0.0]:
+            raise RuntimeError(f"page shares out of step: {self._share}")
         free_set = set(self._free)
         if len(free_set) != len(self._free):
             raise RuntimeError(f"duplicate pages in free list: {self._free}")

@@ -241,7 +241,7 @@ class RequestHandle:
     """
 
     def __init__(self) -> None:
-        self.events: queue.Queue[tuple] = queue.Queue()
+        self.events: queue.SimpleQueue[tuple] = queue.SimpleQueue()
         self.done = threading.Event()
         self.reply: str | None = None
         self.finish_reason: str = "stop"
@@ -316,7 +316,12 @@ class _Request:
     # Host text state (survives eviction: replay re-derives the same
     # tokens and `replay` skips re-processing them).
     emitted: list[int] = dataclasses.field(default_factory=list)
-    text_done: str = ""
+    # The reply's text as it goes (what was sent, and up to which token
+    # the text is decided): `_advance` pays for a chunk's tokens, never
+    # for `emitted` whole.
+    text: pipeline_lib.ReplyText = dataclasses.field(
+        default_factory=pipeline_lib.ReplyText
+    )
     processed: int = 0  # tokens consumed from the device stream
     replay: int = 0  # tokens to skip after an eviction re-admission
     admit_seq: int = -1  # admission order (eviction picks the youngest)
@@ -369,6 +374,26 @@ class _Request:
     trace: trace_lib.Trace | None = None
     qw_span: int = -1
     adm_span: int = -1
+
+    @property
+    def text_done(self) -> str:
+        """The reply text the client has so far."""
+        return self.text.done
+
+
+class _CountedDecode:
+    """`tokenizer.decode` for the emit phase, with every token it is
+    handed counted (`emit_decoded_tokens_total`: beside
+    `decode_steps_useful` it says how many tokens the host decodes for
+    one it emits)."""
+
+    def __init__(self, tokenizer, counter):
+        self.tokenizer = tokenizer
+        self.counter = counter
+
+    def decode(self, ids, **kw) -> str:
+        self.counter.inc(len(ids))
+        return self.tokenizer.decode(ids, **kw)
 
 
 @dataclasses.dataclass
@@ -822,6 +847,13 @@ class ContinuousScheduler:
         # (the chaos suite reconciles it against the injection
         # schedule) backing the bounded ring /debug/oom serves.
         reg.counter("oom_forensics_total", ("trigger",))
+        # Tokens the emit phase hands to `tokenizer.decode` (over
+        # decode_steps_useful: the tokens decoded for one emitted, a
+        # small constant whatever the reply's length).
+        self._emit_decode = _CountedDecode(
+            pipe.tokenizer,
+            reg.counter("emit_decoded_tokens_total").labels(),
+        )
         if self.block:
             # Block-diffusion and expert-layer accounting, per block
             # dispatch (docs/OBSERVABILITY.md "Block diffusion"). The
@@ -1891,12 +1923,14 @@ class ContinuousScheduler:
         if req is None or not req.pages_t:
             return
         now = time.monotonic()
-        held = 0
-        weight = 0.0
-        for p in self.bt[s]:
-            if p != self._sentinel:
-                held += 1
-                weight += 1.0 / max(1, self.allocator.refcount(int(p)))
+        # No walk of the table in Python, however wide it is, and no
+        # numpy on the row either: beside 64 streams' handler threads
+        # the boolean index, gather and sum of a 64-entry row cost
+        # 192 us a lane on the chip's host where this costs 12 and the
+        # walk cost 29 (PERF.md section 5, PR 46).
+        row = self.bt[s].tolist()
+        held = len(row) - row.count(self._sentinel)
+        weight = self.allocator.charge(row)
         req.cost_page_seconds += weight * (now - req.pages_t)
         req.pages_t = now
         if held > req.peak_pages:
@@ -4590,13 +4624,15 @@ class ContinuousScheduler:
         machine; returns the number of USEFUL steps consumed (replayed
         steps count as wasted — they are eviction overhead). Mirrors
         chat_stream's emission rules (stop trim, stable prefix, EOS
-        fill, length cap) AND its cost profile: token-level checks (EOS,
-        max_new) run per token, the tokenizer decode + stop trim run
-        once per CHUNK — host work is linear in the reply, not
-        quadratic."""
+        fill, length cap) at a cost bounded by the CHUNK: token-level
+        checks (EOS, max_new) run per token, and once per chunk
+        `ReplyText.advance` decodes the chunk's tokens behind a few
+        tokens of context, scans for a stop where one could have
+        completed and holds back by the text's end. Nothing here reads
+        `req.emitted` whole or the text sent so far, however many
+        tokens the dispatch gave the lane (8, a block, 1..k+1)."""
         req = self.slots[s]
         eos = self.cfg.generation.eos_token_id
-        tokenizer = self.pipe.tokenizer
         useful = 0
         if req.handle.cancelled:
             self._cancel_slot(s, req, "mid-decode")
@@ -4619,17 +4655,17 @@ class ContinuousScheduler:
         if len(req.emitted) == chunk_start and finish is None:
             return useful  # pure replay skip: nothing new to decode
         t_emit = trace_lib.now_ns()
-        text = tokenizer.decode(req.emitted, skip_special_tokens=True)
-        text, hit = pipeline_lib.stop_cut(text, req.stops)
-        if hit:
+        # On a finish the held-back tail (whitespace, a stop-string
+        # prefix) is flushed exactly as chat_stream does.
+        delta, n = req.text.advance(
+            self._emit_decode, req.emitted, req.stops, chunk_start,
+            final=finish is not None,
+        )
+        if n is not None and (finish is None or n <= finish[1]):
             # The stop completed in THIS chunk (earlier chunks were
             # checked clean); it precedes any EOS/length finish seen
             # later in the same chunk.
-            n = pipeline_lib.stop_token_count(
-                tokenizer, req.emitted, req.stops, chunk_start
-            )
-            if finish is None or n <= finish[1]:
-                finish = ("stop", n)
+            finish = ("stop", n)
         if finish is not None:
             # Wasted-step honesty: a stop STRING is detected host-side,
             # so the token loop above consumed (and billed as useful)
@@ -4647,32 +4683,15 @@ class ContinuousScheduler:
         # step (replay skips excluded, post-stop tokens clamped away) —
         # the "decode_tokens" half of the steps-vs-tokens split.
         req.cost_decode_tokens += useful
+        if delta and req.handle.streaming:
+            # Only streaming consumers drain the event queue; for plain
+            # requests the reply accumulates in `text` and queued
+            # fragments would just sit there.
+            req.handle.events.put(("delta", delta))
+        req.trace.add_complete("emission", t_emit, chars=len(req.text_done))
         if finish is not None:
-            # Flush the held-back tail (stable_text_prefix may have
-            # withheld whitespace / a stop-string prefix) exactly as
-            # chat_stream does on finish.
-            self._emit_text(req, text.strip())
-            req.trace.add_complete(
-                "emission", t_emit, chars=len(req.text_done)
-            )
             self._finish(s, finish[0], completion=finish[1])
-        else:
-            self._emit_text(
-                req, pipeline_lib.stable_text_prefix(text, req.stops)
-            )
-            req.trace.add_complete(
-                "emission", t_emit, chars=len(req.text_done)
-            )
         return useful
-
-    def _emit_text(self, req: _Request, safe: str) -> None:
-        if len(safe) > len(req.text_done):
-            if req.handle.streaming:
-                # Only streaming consumers drain the event queue; for
-                # plain requests the reply accumulates in text_done and
-                # queued fragments would just sit there.
-                req.handle.events.put(("delta", safe[len(req.text_done):]))
-            req.text_done = safe
 
     # obligations: _finalize_cost, _clear_slot, _emit_request_event, completed
     def _finish(self, s: int, reason: str, completion: int) -> None:
