@@ -876,6 +876,39 @@ def share_stats(cfg: LLMConfig, ids: jnp.ndarray, kv_lengths: jnp.ndarray):
     ]).astype(jnp.int32)
 
 
+@jax.jit
+def overlay_lanes(
+    lengths: jnp.ndarray,  # [S] as the last decode chunk left them
+    finished: jnp.ndarray,  # [S]
+    recent: jnp.ndarray,  # [S, stop_L]
+    edited: jnp.ndarray,  # [S] bool: the host changed this lane since
+    host_lengths: jnp.ndarray,  # [S] int32
+    host_finished: jnp.ndarray,  # [S] bool
+):
+    """The host's edits since the last enqueue laid over the lane state
+    a decode chunk left ON THE DEVICE, so that the next chunk can be
+    enqueued before the last one is read: a lane the host activated,
+    cleared or counted off by `max_tokens` takes the host's length and
+    flag and an empty stop window, every other lane goes on from where
+    the chunk in flight leaves it. Operands of one shape and dtype at
+    every call (a mask and values, never a list of slots): one compiled
+    program, met by the first decode dispatch a server runs."""
+    return (
+        jnp.where(edited, host_lengths, lengths),
+        jnp.where(edited, host_finished, finished),
+        jnp.where(edited[:, None], -2, recent),
+    )
+
+
+@jax.jit
+def seat_first_token(tok, keys, slot, tok0, key):
+    """A prompt's first token and its advanced key (`paged_prefill`'s
+    outputs, [1] each) into lane `slot` ([] int32, traced) of the next
+    decode chunk's `tok` and `keys`, on the device: the chunk is
+    enqueued behind the prefill without the host reading either."""
+    return tok.at[slot].set(tok0[0]), keys.at[slot].set(key[0])
+
+
 # ---------------------------------------------------------------------------
 # Ragged fused prefill+decode step (one dispatch per engine step)
 # ---------------------------------------------------------------------------

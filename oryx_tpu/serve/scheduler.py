@@ -396,23 +396,44 @@ class _CountedDecode:
         return self.tokenizer.decode(ids, **kw)
 
 
+def _handed(a: np.ndarray):
+    """A host array as a dispatch is handed it: a COPY, because the
+    host changes its arrays while the dispatch is in flight, and on the
+    CPU `jnp.asarray` shares the numpy buffer."""
+    return jnp.asarray(a.copy())
+
+
 @dataclasses.dataclass
-class _BlockFlight:
-    """What one enqueued `paged_block_step` was given, kept until its
-    harvest: by then the host's arrays have moved on (the next block is
-    enqueued, slots were freed and filled), so the harvest reads the
-    tokens against this record."""
-    toks: Any  # [S, B] on the device
-    counts: dict[str, Any]  # generate.paged_block_step's counts
+class _Flight:
+    """What one enqueued decode dispatch (a `paged_block_step`, or the
+    split engine's `paged_decode_chunk`) was given, kept until its
+    harvest: by then the host's arrays have moved on (the next dispatch
+    is enqueued, slots were freed and filled), so the harvest reads the
+    outputs against this record."""
+    toks: Any  # [S, B] / [S, chunk] on the device
     # slot -> admit_seq of the request it rode for: a row whose slot
     # holds another placement at the harvest is dropped.
     riders: dict[int, int]
-    fused: int  # riders whose pending block its first forward commits
-    known: np.ndarray  # blk_known as handed
     temp: np.ndarray  # the temperatures as handed
     t0_ns: int  # enqueue (trace_lib.now_ns)
     sampled: bool  # a periodic device-time sample brackets it
     captured: bool  # a capture brackets it: read at once, alone
+    # A block:
+    counts: dict[str, Any] | None = None  # paged_block_step's counts
+    fused: int = 0  # riders whose pending block its first forward commits
+    known: np.ndarray | None = None  # blk_known as handed
+    # A decode chunk:
+    lengths: Any = None  # [S] on the device, as the chunk leaves them
+    finished: Any = None  # [S] on the device
+    # The host's edits the chunk ran with (generate.overlay_lanes): the
+    # lengths it started from are these over the chunk before's.
+    edited: np.ndarray | None = None
+    ran_lengths: np.ndarray | None = None
+    nstats: Any = None  # the numerics probe's accumulator, if armed
+    share: Any = None  # generate.SHARE_STATS sums, if the config has them
+    # Statistics of the prefill chunks enqueued before it: ready when
+    # it is.
+    held: list = dataclasses.field(default_factory=list)
 
 
 class ContinuousScheduler:
@@ -671,6 +692,11 @@ class ContinuousScheduler:
         # while residents decode `chunk` tokens. Two compiled shape
         # classes total (prefill lanes present / absent), both static.
         self.ragged = bool(ragged)
+        # The split engine (and block mode, by its own pair of
+        # functions) keeps one decode dispatch in flight; the ragged,
+        # fused and speculative steps read every dispatch before the
+        # next.
+        self._ahead = not (self.block or self.ragged)
         self.pf_width = (
             -(-prefill_chunk // chunk) if ragged else 0
         )
@@ -872,7 +898,7 @@ class ContinuousScheduler:
             for how in ("fused", "dropped"):
                 reg.counter("diffusion_commits_total", ("how",)).labels(
                     how=how)
-            # One block in flight (DESIGN.md "Block diffusion"): blocks
+            # One dispatch in flight (DESIGN.md, the section of that name): blocks
             # enqueued while another was unread (against dispatches_
             # total{kind="block"}: the share that hid the host), and
             # slot-blocks computed for a placement that had ended.
@@ -882,6 +908,11 @@ class ContinuousScheduler:
             reg.counter("moe_expert_rows_max_total")
             reg.counter("moe_expert_rows_mean_total")
             reg.counter("moe_experts_hit_total")
+        elif self._ahead:
+            # The split engine's twins (DESIGN.md "One dispatch in
+            # flight"), against dispatches_total{kind="decode"}.
+            reg.counter("decode_dispatches_ahead_total")
+            reg.counter("decode_rows_dropped_total")
         # A model whose expert layer holds a share of the routed experts
         # or has zero-compute experts: per decode dispatch, from the
         # routing the step returns (generate.SHARE_STATS; docs/
@@ -1030,11 +1061,32 @@ class ContinuousScheduler:
         # dispatch's output, which never leaves the device for this.
         self.blk_pending = np.full((S,), -1, np.int64)
         self._pending_toks = None  # thread-owned: engine
-        # The block enqueued and not yet read (at most one between two
-        # engine steps), and when the last harvest returned: the
-        # device begins a block enqueued ahead about then.
-        self._inflight: _BlockFlight | None = None  # thread-owned: engine
-        self._blk_read_ns = 0  # thread-owned: engine
+        # The decode dispatch enqueued and not yet read (a block, or the
+        # split engine's chunk: at most one between two engine steps),
+        # and when the last harvest returned: the device begins a
+        # dispatch enqueued ahead about then.
+        self._inflight: _Flight | None = None  # thread-owned: engine
+        self._read_ns = 0  # thread-owned: engine
+        # The split engine's lane state lives ON THE DEVICE between
+        # chunks (tok, lengths, finished, recent as the last enqueued
+        # chunk leaves them; `keys` beside them), so chunk n+1 is
+        # enqueued before chunk n is read. The host's own arrays above
+        # say where the chunk in flight WILL leave a live lane
+        # (`lengths` advanced at the enqueue: exact for a lane that is
+        # live at the next one, an upper bound for one that meets EOS);
+        # `_edited` marks the lanes it changed since the last enqueue,
+        # which `generate.overlay_lanes` lays over the device's.
+        self._lanes = None  # thread-owned: engine
+        self._edited = np.zeros((S,), bool)
+        # What the device has CONFIRMED: the last harvested chunk's
+        # lengths for every lane (what the next one to be read ran
+        # with), and for a slot's own placement the length its K/V is
+        # known to reach (what `_finish` may donate).
+        self._seen_lengths = np.zeros((S,), np.int32)
+        self.confirmed = np.zeros((S,), np.int32)
+        # slot -> (request, tok0 on the device): first tokens seated in
+        # the next chunk and not yet read by the host.
+        self._first: dict[int, tuple] = {}  # thread-owned: engine
         self._ragged_blanks = None
         if self.ragged:
             # The pure-decode shape class's constant prefill operands,
@@ -1717,6 +1769,9 @@ class ContinuousScheduler:
         self.blk_pending[:] = -1
         self._pending_toks = None  # a failed dispatch's output is lost
         self._inflight = None  # its pool is gone
+        self._lanes = None  # built again from the host's arrays
+        self._edited[:] = False
+        self._first.clear()
         self._check_pool_invariant()
 
     def _check_pool_invariant(self) -> None:
@@ -2131,6 +2186,8 @@ class ContinuousScheduler:
         self.recent[s] = -2
         self.blk[s] = 0
         self.blk_known[s] = 0
+        self._edited[s] = True
+        self._first.pop(s, None)  # a first token nobody will read
         self._drop_pending(s)
 
     def _drop_pending(self, s: int) -> None:
@@ -2307,8 +2364,12 @@ class ContinuousScheduler:
                 self.replay_feeder(self)
             drain_drop: list[_Request] = []
             with self._cond:
-                if self._shutdown:
-                    return
+                shutdown = self._shutdown
+            if shutdown:
+                # close(): the dispatch in flight is read, not left.
+                self._drain_flight()
+                return
+            with self._cond:
                 if self._draining and self._queue:
                     # Drain: admission is over — queued-but-unadmitted
                     # requests hold no pages; error them out so their
@@ -2543,9 +2604,9 @@ class ContinuousScheduler:
             if req is None or req.deadline is None or now <= req.deadline:
                 continue
             if self._inflight is not None:
-                # Block mode: the block in flight is read first, and
-                # may have ended the request by itself.
-                self._drain_block()
+                # The dispatch in flight is read first, and may have
+                # ended the request by itself.
+                self._drain_flight()
                 if self.slots[s] is not req:
                     continue
             self.metrics.inc("deadline_exceeded_total")
@@ -2974,8 +3035,8 @@ class ContinuousScheduler:
         chunks skip it — until `_activate` flips it live; the prefill
         itself advances chunk-by-chunk in `_prefill_step`."""
         # The "admission" span opened at the queue head stays open
-        # until the first token is read (_activate): it holds the
-        # prompt prep, the wait for pages, every prefill chunk and
+        # until the first token is read (_read_first_tokens): it holds
+        # the prompt prep, the wait for pages, every prefill chunk and
         # whatever ran between them. A re-admission after eviction
         # closes the reopened "queue_wait" and opens an admission span
         # of its own around the replayed prefill.
@@ -3066,8 +3127,8 @@ class ContinuousScheduler:
         # The phase and the request's `prefill` span both end when the
         # ENQUEUE returns: they mark a dispatch, not the device's work.
         # The wait for this prompt's first token is `first_token`
-        # (_activate); queue head -> first token is the request's
-        # `admission` span, which the cost ledger's prefill_s reads.
+        # (_read_first_tokens); queue head -> first token is the
+        # request's `admission` span, which the ledger's prefill_s reads.
         with self._phase("prefill", "dispatch"):
             B1 = np.newaxis
             off = req.prefill_pos
@@ -3219,35 +3280,83 @@ class ContinuousScheduler:
         if self.block:
             self._activate_block(s, req)
             return
-        # The engine's SECOND blocking point (the first is the harvest):
-        # the prompt's last prefill chunk, and every dispatch enqueued
-        # before it, must finish before this read returns.
-        with self._phase("first_token", "blocked"):
-            self.tok[s] = int(np.asarray(tok0)[0])
-        self._drain_prefill_held()  # ready: the first token was read
-        if req.adm_span >= 0:
-            req.trace.end(req.adm_span)
-            req.adm_span = -1
         with self._phase("emit"):
-            self.lengths[s] = req.length
-            self.finished[s] = False
+            self.lengths[s] = self.confirmed[s] = req.length
+            # A max_tokens=1 request ends on its first token: it never
+            # occupies a chunk.
+            self.finished[s] = self._ahead and req.max_new <= 1
             self.temp[s] = req.temp
             self.top_p[s] = req.topp
             self.top_k[s] = req.topk
             self.recent[s] = -2
-            self.keys = self.keys.at[s].set(key[0])
-            self._observe_ttft(req)
             self.metrics.inc("admitted")
+            # The prompt's pages are written by programs already
+            # enqueued, and whoever splices them is enqueued later: a
+            # look-alike in this same admission round hits at once.
             self._donate_prefix(s, req, req.length)
+            self._first[s] = (req, tok0)
+            if self._ahead:
+                # The first token goes into the next chunk's `tok` on
+                # the device; the host reads it once that chunk is
+                # enqueued behind the prefill (`_read_first_tokens`).
+                tok, *rest = self._lane_state()
+                with self.pipe._mesh_scope():
+                    tok, self.keys = generate_lib.seat_first_token(
+                        tok, self.keys, jnp.asarray(s, jnp.int32), tok0, key)
+                self._lanes = (tok, *rest)
+                self._edited[s] = True
+            else:
+                self.keys = self.keys.at[s].set(key[0])
             self._occupancy_gauge()
-            # tok0 is this slot's first generated token — process it now so
-            # a max_tokens=1 request never occupies a chunk. The chunk
-            # program re-emits tok0 as its first output (the scan step emits
-            # the token it was FED, dense-path semantics), so one extra
-            # replay skip keeps the stream exactly-once.
-            self._advance(s, [int(self.tok[s])])
-            if self.slots[s] is not None:
-                req.replay += 1
+        if not self._ahead:
+            # The ragged, fused and speculative steps read every
+            # dispatch before the next: the token is there.
+            self._read_first_tokens()
+
+    def _lane_state(self) -> tuple:
+        """The split engine's lane state on the device (tok, lengths,
+        finished, recent): the last enqueued chunk's outputs, or the
+        host's arrays before the first one and after a pool reset."""
+        if self._lanes is None:
+            self._lanes = tuple(
+                jnp.asarray(a.copy()) for a in
+                (self.tok, self.lengths, self.finished, self.recent))
+            self._seen_lengths = self.lengths.copy()
+            self._edited[:] = False
+        return self._lanes
+
+    # hot-path
+    def _read_first_tokens(self) -> None:
+        """Read the first tokens of the prompts activated since the
+        last call: observe TTFT, end the admission span, emit. The engine's second point of waiting (the
+        first is the harvest): the prompt's last prefill chunk, and
+        every dispatch enqueued before it, must finish before the read
+        returns. In the split engine the chunk the lane joined is
+        enqueued behind it by then, so the device has work when the
+        wait returns (`wait`, not `blocked`) and the token is NOT a
+        chunk late."""
+        for s in list(self._first):
+            req, tok0 = self._first.pop(s)
+            with self._phase(
+                "first_token",
+                "blocked" if self._inflight is None else "wait",
+            ):
+                first = int(np.asarray(tok0)[0])  # oryxlint: disable=host-sync
+            self.tok[s] = first
+            self._drain_prefill_held()  # ready: the first token was read
+            if req.adm_span >= 0:
+                req.trace.end(req.adm_span)
+                req.adm_span = -1
+            with self._phase("emit"):
+                self._observe_ttft(req)
+                # tok0 is this slot's first generated token. The chunk
+                # program re-emits it as its first output (the scan
+                # step emits the token it was FED, dense-path
+                # semantics), so one extra replay skip keeps the stream
+                # exactly-once.
+                self._advance(s, [first])
+                if self.slots[s] is not None:
+                    req.replay += 1
 
     def _observe_ttft(self, req: _Request) -> None:
         """The request's first token exists: observe its TTFT once."""
@@ -3335,11 +3444,11 @@ class ContinuousScheduler:
                 continue  # freed or evicted by an earlier iteration
             while not self._grow_slot(s, int(self.lengths[s]) + win):
                 if self._inflight is not None:
-                    # Block mode under page pressure: read the block in
-                    # flight before anyone is evicted (its finishes may
-                    # free the pages; a victim's replay count holds its
-                    # tokens), then look again.
-                    self._drain_block()
+                    # Page pressure: read the dispatch in flight before
+                    # anyone is evicted (its finishes may free the
+                    # pages; a victim's replay count holds its tokens),
+                    # then look again.
+                    self._drain_flight()
                     return self._ensure_capacity(horizon)
                 me = self.slots[s].admit_seq
                 younger = [
@@ -3474,6 +3583,9 @@ class ContinuousScheduler:
 
     # hot-path
     def _step_chunk(self) -> None:
+        """The split engine's decode step: enqueue ONE
+        `paged_decode_chunk` for the resident streams, THEN read the
+        chunk enqueued a step earlier (`_step_ahead`)."""
         # Chaos site: decode dispatch failure (raise -> every in-flight
         # request errors, pool resets, serving continues) or hang
         # (delay= -> the stall watchdog and per-request deadlines are
@@ -3483,23 +3595,93 @@ class ContinuousScheduler:
         # held would serialize submit()/scrapes/debug reads on device
         # latency — the runtime twin of the static hot-path rule.
         hot_dispatch("scheduler._step_chunk")
+        self._step_ahead()
+
+    # hot-path
+    def _block_step(self) -> None:
+        """Block mode's engine step: enqueue ONE `paged_block_step`, in
+        which every live slot generates its open block by diffusion
+        (T denoising forwards of num_slots x B lanes, the first with B
+        more lanes a slot that commit the block before), THEN read the
+        block enqueued a step earlier (`_step_ahead`)."""
+        faults.fault_point("decode_dispatch")
+        hot_dispatch("scheduler._block_step")
+        self._step_ahead()
+
+    # hot-path
+    def _step_ahead(self) -> None:
+        """One decode dispatch is always in flight (docs/DESIGN.md "One
+        dispatch in flight"): dispatch n+1 is enqueued before dispatch
+        n is read, so the harvest, `emit` and the next round's
+        housekeeping, admission and prefill enqueue run while the
+        device works. What the host changes in a slot takes effect at
+        the next enqueue; a harvest drops rows whose placement has
+        ended; rare paths drain first. The enqueue / harvest pair is
+        the mode's (`_enqueue_block` / `_harvest_block`,
+        `_enqueue_chunk` / `_harvest_chunk`), looked up at the call so
+        that a test can hook either on the instance."""
+        enqueue, harvest = (
+            (self._enqueue_block, self._harvest_block) if self.block
+            else (self._enqueue_chunk, self._harvest_chunk)
+        )
+        prev, self._inflight = self._inflight, None
+        if prev is not None and (
+            self._profile_active is not None or self.profiler.due_next()
+        ):
+            # A capture's window holds its own dispatch alone.
+            harvest(prev)
+            prev = None
+        self._inflight = enqueue(ahead=prev is not None)
+        if prev is not None:
+            harvest(prev)
+        self._read_first_tokens()
+        if self._inflight is not None and (
+            self._inflight.captured
+            or not any(r is not None and r.activated for r in self.slots)
+        ):
+            # Nobody is left to ride a next dispatch (every rider ended
+            # on an EOS, a stop or a cancel): the engine goes idle
+            # with nothing in flight.
+            self._drain_flight()
+
+    # hot-path
+    def _enqueue_chunk(self, ahead: bool) -> _Flight | None:
+        """Enqueue one decode chunk for every lane that rides it, on
+        the lane state the chunk before left on the device with the
+        host's edits laid over it, and move the host's state to where
+        the chunk will leave a live lane: lengths a chunk further, and
+        a lane whose request reaches max_tokens inside this chunk off
+        the next one (the host counts; EOS, a stop string and a cancel
+        are learned from the harvest, one chunk late). None when no
+        lane rides."""
+        riders = {
+            s: r.admit_seq for s, r in enumerate(self.slots)
+            if r is not None and r.activated and not self.finished[s]
+        }
+        if not riders:
+            return None
+        tok, lengths, finished, recent = self._lane_state()
+        # Copies of its own (`_handed`): the record keeps them.
+        edited, ran_lengths = self._edited.copy(), self.lengths.copy()
+        temp = self.temp.copy()
+
         with self._phase("decode", "dispatch"):
             sampled = self._profile_dispatch_begin()
             numer = self._numerics_due()
-            t0 = time.monotonic()
             t0_ns = trace_lib.now_ns()
             with self.pipe._mesh_scope():
+                lengths, finished, recent = generate_lib.overlay_lanes(
+                    lengths, finished, recent, jnp.asarray(edited),
+                    jnp.asarray(ran_lengths), _handed(self.finished),
+                )
                 out = generate_lib.paged_decode_chunk(
                     self.pipe.params["llm"], self.cfg.llm, self.kv_pages,
-                    jnp.asarray(self.bt),
-                    jnp.asarray(self.tok),
-                    jnp.asarray(self.lengths),
-                    jnp.asarray(self.finished),
-                    jnp.asarray(self.recent),
+                    _handed(self.bt),
+                    tok, lengths, finished, recent,
                     self.keys,
-                    jnp.asarray(self.temp),
-                    jnp.asarray(self.top_p),
-                    jnp.asarray(self.top_k),
+                    jnp.asarray(temp),
+                    _handed(self.top_p),
+                    _handed(self.top_k),
                     self.stop_sequences,
                     chunk=self.chunk,
                     eos=self.cfg.generation.eos_token_id,
@@ -3508,91 +3690,124 @@ class ContinuousScheduler:
                     numerics=numer,
                     **self._window_args(slice(None)),
                 )
-        nstats = out[8] if numer else None
         (self.kv_pages, tok, lengths, finished, recent, self.keys,
-         toks, fin) = out[:8]
-        ran_with = self.lengths
-        toks, fin = self._harvest_chunk(
-            tok, lengths, finished, recent, toks, fin
+         toks) = out[:7]
+        self._lanes = (tok, lengths, finished, recent)
+        self._edited[:] = False
+        for s in riders:
+            self.lengths[s] += self.chunk  # the device's, while it lives
+            req = self.slots[s]
+            if int(self.lengths[s]) - req.length >= req.max_new:
+                self.finished[s] = self._edited[s] = True  # its last chunk
+        held, self._prefill_held = self._prefill_held, []
+        if ahead:
+            self.metrics.inc("decode_dispatches_ahead_total")
+        return _Flight(
+            toks=toks, riders=riders, temp=temp, t0_ns=t0_ns,
+            sampled=sampled,
+            captured=sampled or self._profile_active is not None,
+            lengths=lengths, finished=finished, edited=edited,
+            ran_lengths=ran_lengths,
+            nstats=out[8] if numer else None,
+            share=out[-1] if self.share_stats else None, held=held,
         )
+
+    # hot-path
+    def _harvest_chunk(self, flight: _Flight) -> None:
+        """The one harvest of a decode chunk, against the record of
+        what it was given: its tokens go through `_advance`, the rows
+        of a slot that no longer holds the placement it rode for are
+        dropped, and the chunk's steps are counted ONCE, here, from
+        the lengths it ran with and the lengths it confirmed."""
+        self.metrics.inc("harvest_total")
+        # The first read waits for the program; the rest copy what is
+        # done (the per-step `fin`, `tok` and `recent` stay on the
+        # device: nothing on the host reads them). With a chunk
+        # enqueued behind this one the device has work when the wait
+        # returns: no `host` event opens.
+        # oryxlint: off=host-sync
+        with self._phase(
+            "harvest", "blocked" if self._inflight is None else "wait"
+        ):
+            toks = np.asarray(flight.toks)
+        with self._phase("copy_out"):
+            lengths = np.asarray(flight.lengths).copy()
+            finished = np.asarray(flight.finished)
+            share = None if flight.share is None else np.asarray(flight.share)
+        # oryxlint: on=host-sync
+        self._count_lane_steps(
+            np.where(flight.edited, flight.ran_lengths, self._seen_lengths),
+            lengths,
+        )
+        self._seen_lengths = lengths
+        self._drain_prefill_held(flight.held)
+        t0_ns, dt = self._flight_window(flight)
+        with self._phase("emit"):
+            dev_us = (
+                self._profile_dispatch_end(flight.sampled, "decode", t0_ns)
+                if flight.captured else None
+            )
+            self._record_numerics(flight.nstats)
+            if share is not None:
+                self._count_share(share)
+            live = self._seated_riders(flight, "decode_rows_dropped_total")
+            # What `_finish` may donate: never the advanced length.
+            self.confirmed[live] = lengths[live]
+            self.finished[live] |= finished[live]
+            self._finish_dispatch(
+                "decode", len(flight.riders), live, toks, t0_ns, dt,
+                device_us=dev_us, temps=flight.temp,
+            )
+            self._occupancy_gauge()
+
+    def _count_lane_steps(self, ran, lengths) -> None:
+        """The decode reads of one chunk, for the families a state or a
+        window makes the host reckon: a lane that was live for n steps
+        from length a (`ran`) to `lengths`."""
+        if not (self.recurrent or self.windowed):
+            return
+        a = ran.astype(np.int64)
+        n = lengths.astype(np.int64) - a
         if self.recurrent:
-            # A lane that was live for n steps advanced n positions and
-            # read a + 1 .. a + n cached tokens, a what it ran with.
-            a = ran_with.astype(np.int64)
-            n = self.lengths.astype(np.int64) - a
+            # It advanced n positions and read a + 1 .. a + n cached
+            # tokens.
             self.metrics.inc("ssm_decode_lane_steps_total", int(n.sum()))
             self.metrics.inc(
                 "decode_kv_tokens_total",
                 int((n * a + n * (n + 1) // 2).sum()))
         if self.windowed:
-            # A lane live for n steps from length a fed positions a ..
-            # a + n - 1, and a window layer's row at p read min(p + 1,
-            # W) cached tokens (a global layer's p + 1: kv_tokens of
-            # the step's own statistics).
+            # It fed positions a .. a + n - 1, and a window layer's row
+            # at p read min(p + 1, W) cached tokens (a global layer's
+            # p + 1: kv_tokens of the step's own statistics).
             W = self.cfg.llm.sliding_window
-            a = ran_with.astype(np.int64)
-            n = self.lengths.astype(np.int64) - a
             i = np.arange(self.chunk, dtype=np.int64)[None, :]
             read = np.minimum(a[:, None] + i + 1, W) * (i < n[:, None])
             self.metrics.inc("decode_window_kv_tokens_total",
                              int(read.sum()))
-        share = None
-        if self.share_stats:
-            with self._phase("copy_out"):
-                share = np.asarray(out[-1])  # oryxlint: disable=host-sync
-        self._drain_prefill_held()  # enqueued before this chunk: ready
-        dt = time.monotonic() - t0
-        with self._phase("emit"):
-            dev_us = self._profile_dispatch_end(sampled, "decode", t0_ns)
-            self._record_numerics(nstats)
-            if share is not None:
-                self._count_share(share)
-            live = [
-                s for s, r in enumerate(self.slots)
-                if r is not None and r.activated
-            ]
-            self._finish_dispatch(
-                "decode", len(live), live, toks, t0_ns, dt,
-                device_us=dev_us,
-            )
-            self._occupancy_gauge()
+
+    def _seated_riders(self, flight: _Flight, dropped: str) -> list[int]:
+        """The riders of a dispatch whose slot still holds the
+        placement they rode for; the others' rows are dropped, and
+        counted under `dropped`."""
+        live = [
+            s for s, seq in flight.riders.items()
+            if self.slots[s] is not None
+            and self.slots[s].admit_seq == seq
+        ]
+        self.metrics.inc(dropped, len(flight.riders) - len(live))
+        return live
+
+    def _flight_window(self, flight: _Flight) -> tuple[int, float]:
+        """(start ns, seconds) of a dispatch that was just read,
+        harvest to harvest, the pace a client feels: one enqueued
+        ahead began when the one before it was read, the first after a
+        drain at its enqueue."""
+        t0_ns = max(flight.t0_ns, self._read_ns)
+        self._read_ns = trace_lib.now_ns()
+        return t0_ns, (self._read_ns - t0_ns) / 1e9
 
     # hot-path
-    def _block_step(self) -> None:
-        """Block mode's engine step: enqueue ONE `paged_block_step`, in
-        which every live slot generates its open block by diffusion
-        (T denoising forwards of num_slots x B lanes, the first with B
-        more lanes a slot that commit the block before), THEN read the
-        block enqueued a step earlier. One block
-        is always in flight, so the harvest, `emit` and the next
-        round's housekeeping, admission and prefill enqueue run while
-        the device works (docs/DESIGN.md "Block diffusion": what the
-        host changes in a slot takes effect at the next enqueue; a
-        harvest drops rows whose placement has ended; rare paths
-        drain first)."""
-        faults.fault_point("decode_dispatch")
-        hot_dispatch("scheduler._block_step")
-        prev, self._inflight = self._inflight, None
-        if prev is not None and (
-            self._profile_active is not None or self.profiler.due_next()
-        ):
-            # A capture's window holds its own block alone.
-            self._harvest_block(prev)
-            prev = None
-        self._inflight = self._enqueue_block(ahead=prev is not None)
-        if prev is not None:
-            self._harvest_block(prev)
-        if self._inflight is not None and (
-            self._inflight.captured
-            or not any(r is not None and r.activated for r in self.slots)
-        ):
-            # Nobody is left to ride a next block (every rider ended
-            # on an EOS, a stop or a cancel): the engine goes idle
-            # with nothing in flight.
-            self._drain_block()
-
-    # hot-path
-    def _enqueue_block(self, ahead: bool) -> _BlockFlight | None:
+    def _enqueue_block(self, ahead: bool) -> _Flight | None:
         """Enqueue one block for every slot that rides it and move the
         host's state to where the block will leave it: lengths past
         the block, the next block all masked, and a slot whose request
@@ -3618,24 +3833,21 @@ class ContinuousScheduler:
         if self._pending_toks is None:
             self._pending_toks = jnp.zeros(self.blk.shape, jnp.int32)
 
-        def handed(a):
-            return jnp.asarray(a.copy())
-
         with self._phase("denoise", "dispatch"):
             sampled = self._profile_dispatch_begin()
             t0_ns = trace_lib.now_ns()
             with self.pipe._mesh_scope():
                 out = generate_lib.paged_block_step(
                     self.pipe.params["llm"], self.cfg.llm, self.kv_pages,
-                    handed(self.bt),
-                    handed(self.blk),
+                    _handed(self.bt),
+                    _handed(self.blk),
                     jnp.asarray(known),
-                    handed(self.lengths),
-                    handed(self.finished),
+                    _handed(self.lengths),
+                    _handed(self.finished),
                     self.keys,
                     jnp.asarray(temp),
-                    handed(self.top_p),
-                    handed(self.top_k),
+                    _handed(self.top_p),
+                    _handed(self.top_k),
                     self._pending_toks,
                     jnp.asarray(commit),
                     steps=gen.denoising_steps or self.block,
@@ -3658,24 +3870,29 @@ class ContinuousScheduler:
                 self._drop_pending(s)  # which nothing will read
         if ahead:
             self.metrics.inc("block_dispatches_ahead_total")
-        return _BlockFlight(
+        return _Flight(
             toks=toks, counts=counts, riders=riders,
             fused=int(commit.sum()), known=known,
             temp=temp, t0_ns=t0_ns, sampled=sampled,
             captured=sampled or self._profile_active is not None,
         )
 
-    def _drain_block(self) -> None:
-        """Read the block in flight now. The rare paths call this
-        before they act (an eviction, a deadline's error, going idle,
-        a capture's window edge), so that they see the engine as a
-        step without a block in flight leaves it."""
+    def _drain_flight(self) -> None:
+        """Read the dispatch in flight now, and the first tokens not
+        yet read. The rare paths call this before they act (an
+        eviction, a deadline's error, going idle, a capture's window
+        edge, close()), so that they see the engine as a step without
+        a dispatch in flight leaves it."""
         flight, self._inflight = self._inflight, None
+        # First tokens first: a chunk's rows for a lane that joined it
+        # begin with the first token again (`_read_first_tokens`).
+        self._read_first_tokens()
         if flight is not None:
-            self._harvest_block(flight)
+            (self._harvest_block if self.block
+             else self._harvest_chunk)(flight)
 
     # hot-path
-    def _harvest_block(self, flight: _BlockFlight) -> None:
+    def _harvest_block(self, flight: _Flight) -> None:
         """The one harvest of a block, against the record of what it
         was given. Its new tokens go through `_advance` in position
         order, as one emission (one SSE chunk a block); EOS and
@@ -3700,24 +3917,13 @@ class ContinuousScheduler:
             ))
             slot_forwards = np.asarray(flight.counts["slot_forwards"])
         # oryxlint: on=host-sync
-        # Harvest to harvest, the pace a client feels: a block
-        # enqueued ahead began when the one before it was read.
-        t0_ns = max(flight.t0_ns, self._blk_read_ns)
-        self._blk_read_ns = trace_lib.now_ns()
-        dt = (self._blk_read_ns - t0_ns) / 1e9
+        t0_ns, dt = self._flight_window(flight)
         with self._phase("emit"):
             dev_us = (
                 self._profile_dispatch_end(flight.sampled, "block", t0_ns)
                 if flight.captured else None
             )
-            live = [
-                s for s, seq in flight.riders.items()
-                if self.slots[s] is not None
-                and self.slots[s].admit_seq == seq
-            ]
-            self.metrics.inc(
-                "block_rows_dropped_total", len(flight.riders) - len(live)
-            )
+            live = self._seated_riders(flight, "block_rows_dropped_total")
             for s in live:
                 req = self.slots[s]
                 self._observe_ttft(req)
@@ -3735,7 +3941,7 @@ class ContinuousScheduler:
             )
             self._occupancy_gauge()
 
-    def _count_block_dispatch(self, flight: _BlockFlight,
+    def _count_block_dispatch(self, flight: _Flight,
                               stats: dict) -> None:
         """The diffusion_* and moe_* families for one block dispatch
         (`stats`: generate.BLOCK_STATS by name). Every forward has a
@@ -3776,14 +3982,15 @@ class ContinuousScheduler:
                 st["pairs"] // self.cfg.llm.num_experts_per_tok,
             )
 
-    def _drain_prefill_held(self) -> None:
+    def _drain_prefill_held(self, pending: list | None = None) -> None:
         """The moe_prefill_* families for the prefill chunks dispatched
         since the last drain (each `generate.SHARE_STATS` of a chunk's
         real rows; one transfer a chunk, of work that is done): the
         (token, expert) pairs, those that landed on an expert held
         here, and the held experts that took a row of the slots a
         layer-forward has."""
-        pending, self._prefill_held = self._prefill_held, []
+        if pending is None:
+            pending, self._prefill_held = self._prefill_held, []
         m = self.metrics
         for stats in pending:
             with self._phase("copy_out"):
@@ -3999,16 +4206,18 @@ class ContinuousScheduler:
             ))
 
     # hot-path
-    def _harvest_chunk(self, tok, lengths, finished, recent, toks, fin):
+    def _read_chunk(self, tok, lengths, finished, recent, toks, fin):
         """Blocking host copies of a dispatch's outputs, shared by the
-        split and fused step paths. Host copies BLOCK on the device
-        result — callers measure dt AFTER this, or async dispatch makes
-        the window (and the per-token histogram) cover only dispatch
-        time, and the span<->xplane join would land the decode ops
-        outside every window. This is the engine's deliberate sync
-        point per chunk (the harvest the chunk exists to amortize).
-        There is a second one, per admission: the read of the first
-        token in `_activate` (phase `first_token`). Anything else
+        ragged and fused step paths, which read every dispatch before
+        the next (the split engine's harvest is `_harvest_chunk`).
+        Host copies BLOCK on the device result — callers measure dt
+        AFTER this, or async dispatch makes the window (and the
+        per-token histogram) cover only dispatch time, and the
+        span<->xplane join would land the decode ops outside every
+        window. This is the engine's deliberate sync point per chunk
+        (the harvest the chunk exists to amortize). There is a second
+        one, per admission: the read of the first token
+        (`_read_first_tokens`, phase `first_token`). Anything else
         host-syncing on the step paths is a regression the host-sync
         rule catches."""
         self.metrics.inc("harvest_total")
@@ -4219,7 +4428,7 @@ class ContinuousScheduler:
             nstats = out[10] if numer else None
             (self.kv_pages, tok, lengths, finished, recent, self.keys,
              toks, fin, pf_tok0, pf_key) = out[:10]
-            toks, fin = self._harvest_chunk(
+            toks, fin = self._read_chunk(
                 tok, lengths, finished, recent, toks, fin
             )
             dt = time.monotonic() - t0
@@ -4393,7 +4602,7 @@ class ContinuousScheduler:
                         chunk=self.chunk, k_steps=k_steps, eos=eos,
                         attn_impl=self.cfg.attn_impl, compute_dtype=dtype,
                     )
-            toks, fin = self._harvest_chunk(
+            toks, fin = self._read_chunk(
                 tok, lengths, finished, recent, toks, fin
             )
             dt = time.monotonic() - t0
@@ -4585,7 +4794,7 @@ class ContinuousScheduler:
     # hot-path
     def _harvest_spec(self, tok, lengths, finished, toks, n_new, acc):
         """Blocking host copies of a speculative dispatch's outputs —
-        the spec twin of `_harvest_chunk` (no `recent` window: stop
+        the spec twin of `_read_chunk` (no `recent` window: stop
         strings are host-detected in this mode, and fin is subsumed by
         the finished vector + the EOS the accepted span carries). Same
         one-deliberate-sync-per-step contract."""
@@ -4708,10 +4917,14 @@ class ContinuousScheduler:
         # In block mode the last block a slot was enqueued for is not
         # in the pages (it is pending, or it opened behind the reply's
         # last block and holds nothing final): `lengths` less a block.
+        # In the split engine `lengths` has run ahead with the chunk in
+        # flight, whose K/V past the harvest's length is not this
+        # reply's: `confirmed` is the harvest's.
         self._donate_prefix(
             s, req,
             min(req.length + len(req.emitted),
-                int(self.lengths[s]) - self.block),
+                int(self.confirmed[s]) if self._ahead
+                else int(self.lengths[s]) - self.block),
         )
         self._clear_slot(s)
         req.handle.reply = req.text_done

@@ -64,10 +64,13 @@ def test_short_row_frees_slot_and_admits_within_chunk(pipe):
         assert reason == "length"  # tiny vocab never emits EOS
         assert usage[1] == cap
     # Request 3 waited for a slot, then entered at the chunk boundary
-    # where request 1 finished (no full-batch drain in between).
+    # where request 1 finished (no full-batch drain in between). With
+    # one chunk in flight the chunk enqueued BEFORE that finish was
+    # read is harvested before request 3's first token is: one more
+    # chunk counted at its admission, never two.
     finish_1 = handles[0].debug["finish_chunk"]
     admit_3 = handles[2].debug["admit_chunk"]
-    assert admit_3 <= finish_1, (admit_3, finish_1)
+    assert admit_3 <= finish_1 + 1, (admit_3, finish_1)
     assert metrics.get("admitted") == 3
     assert metrics.get("completed") == 3
     assert metrics.get("decode_steps_wasted") < metrics.get(
@@ -1124,9 +1127,9 @@ def _read_one_dispatch_at_a_time(sched):
     """The same engine with no block in flight behind a harvest: every
     dispatch is read before the next is enqueued."""
     def step():
-        sched._drain_block()
+        sched._drain_flight()
         sched._inflight = sched._enqueue_block(ahead=False)
-        sched._drain_block()
+        sched._drain_flight()
 
     sched._block_step = step
 
@@ -1181,7 +1184,7 @@ def test_pending_blocks_leave_the_streams_of_a_serial_engine(
             def drain_every_third():
                 calls.append(0)
                 if len(calls) % 3 == 0:
-                    sched._drain_block()
+                    sched._drain_flight()
                 step()
 
             if cancel_after:
