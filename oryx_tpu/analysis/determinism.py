@@ -2,8 +2,8 @@
 
 The PR 18 flight recorder's whole contract is that replaying the
 decision journal byte-reproduces the incident: every journaled value
-and every journal-consulted decision (fuse-plan K, eviction victim
-order) must be a function of journal state, never of wall-clock time,
+and every journal-consulted decision (eviction victim order) must
+be a function of journal state, never of wall-clock time,
 process-local identity, or iteration order. One `time.monotonic()`
 laundered into a journal field silently breaks `replay_journal.py`
 forever after.
@@ -28,12 +28,12 @@ This rule runs a may-taint dataflow over the function CFG:
     arguments, `.append(...)`/`.stamp_header(...)` on a receiver whose
     name mentions `journal`, functions the scan pass discovered to
     forward parameters into those (the scheduler's
-    `_journal_submit`/`_journal_fault`/`_finish_megastep` wrappers —
+    `_journal_submit`/`_journal_fault` wrappers —
     found transitively and PER PARAMETER, the lockorder call-summary
     idiom: `_timeline_record(dur_s=...)` is clean because `dur_s`
     never reaches the journal entry it writes, while its `rows=` does
     and is checked), and `return`s from a function marked
-    `# replay-decision` (fuse-plan / eviction-order choosers).
+    `# replay-decision` (eviction-order choosers).
 
 Escapes: a `# replay-exempt: <why>` comment (non-empty reason
 required) on the sink line or the line above exempts a DELIBERATELY

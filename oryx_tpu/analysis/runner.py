@@ -72,18 +72,24 @@ FIXTURE_RULE_MODULES: dict[str, str] = {
 # Directories that are not our python (vendored assets, fixtures that
 # are DELIBERATELY dirty, caches, CI-dropped snapshots of older trees
 # — linting a frozen copy double-counts every suppression against the
-# ratchet).
+# ratchet), and the scratch that `.gitignore` lists: a second checkout
+# under `.bench_check/` or a chip call's outputs are not the tree.
 _EXCLUDE_DIRS = {
     ".git", "__pycache__", ".claude", "native", "assets",
-    "lint_fixtures", ".seedcheck",
+    "lint_fixtures", ".seedcheck", ".bench_check", "chiprun_out",
+    ".jax_cache", ".smoke_tmp",
 }
+# By path from the root: `out` alone would name too much.
+_EXCLUDE_PATHS = {os.path.join("benchmark", "out")}
 
 
 def default_files(root: str) -> list[str]:
     out: list[str] = []
     for dirpath, dirnames, filenames in os.walk(root):
         dirnames[:] = sorted(
-            d for d in dirnames if d not in _EXCLUDE_DIRS
+            d for d in dirnames
+            if d not in _EXCLUDE_DIRS and os.path.relpath(
+                os.path.join(dirpath, d), root) not in _EXCLUDE_PATHS
         )
         for fn in sorted(filenames):
             if fn.endswith(".py"):

@@ -1022,8 +1022,6 @@ def paged_ragged_step(
     W = pf_width
 
     def stop_hit(recent):
-        # Shared device-side stop predicate (ops/paged_kv.py): the
-        # fused megastep must match these semantics bit-for-bit.
         return paged_kv_lib.stop_window_hit(recent, stop_sequences)
 
     def embed(ids):
@@ -1128,112 +1126,6 @@ def paged_ragged_step(
     return out
 
 
-@partial(
-    jax.jit,
-    static_argnames=("cfg", "chunk", "k_steps", "eos", "attn_impl",
-                     "compute_dtype"),
-    donate_argnames=("kv_pages",),
-)
-def paged_fused_steps(
-    params,
-    cfg: LLMConfig,
-    kv_pages: dict,  # donated
-    block_tables: jnp.ndarray,  # [S, max_pages] int32
-    tok: jnp.ndarray,  # [S] next token to feed per slot
-    lengths: jnp.ndarray,  # [S] kv tokens held per slot (frozen on finish)
-    finished: jnp.ndarray,  # [S] bool (True for finished AND empty slots)
-    recent: jnp.ndarray,  # [S, stop_L] rolling stop window (-2 init)
-    keys: jax.Array,  # [S] per-slot PRNG keys
-    temperature: jnp.ndarray,  # [S]
-    top_p: jnp.ndarray,  # [S]
-    top_k: jnp.ndarray,  # [S]
-    stop_sequences: jnp.ndarray | None,  # [Sq, L] (shared, static)
-    *,
-    chunk: int,
-    k_steps: int,
-    eos: int,
-    attn_impl: str = "xla",
-    compute_dtype=None,
-):
-    """ONE device dispatch for K=`k_steps` PURE-DECODE engine steps —
-    the decode megastep (docs/DESIGN.md "Fused multi-step decode").
-
-    The scan body is `paged_ragged_step`'s pure-decode iteration
-    (pf_width=0), run k_steps*chunk times instead of chunk: sampling,
-    packed KV writes, the per-iteration RNG pair split and the
-    EOS/stop-window freeze all stay device-side, and the host harvests
-    ONCE per K logical steps instead of once per step. Columns
-    [j*chunk, (j+1)*chunk) of the returned toks are logical step j's
-    chunk — the host processes them as K sequential harvests (billing,
-    journal entries, stop-string detection all per LOGICAL step).
-
-    Bit-parity contract: K dispatches of the pure-decode
-    `paged_ragged_step` program and one dispatch of this program
-    produce identical carries and identical toks, because the per-
-    iteration math is the same expression — the K=1 path's host
-    round-trip between steps copies values it uploads back unchanged.
-    Rows the HOST would have frozen between steps (max_new cap,
-    per-request stop strings — both invisible to the device) keep
-    decoding inside the megastep; their later logical chunks are
-    garbage the host discards after the finish point, exactly like the
-    intra-chunk overshoot the K=1 path already discards, and their KV
-    overshoot self-confines to the row's own pages (the sentinel
-    routing of write_pages_packed drops anything past them).
-
-    Dispatched only when no admission is in flight: the megastep is
-    the idle-resident fast path, and the shape class is one compiled
-    program per k_steps ladder value (the recompile watchdog's bounded
-    -class contract).
-
-    Returns (kv_pages, tok, lengths, finished, recent, keys,
-    toks [S, k_steps*chunk], fin [S, k_steps*chunk])."""
-    from oryx_tpu.parallel.sharding import constrain
-
-    S = tok.shape[0]
-
-    def embed(ids):
-        e = constrain(params["embed"]["weight"], None, None)[ids]
-        return e.astype(compute_dtype) if compute_dtype is not None else e
-
-    def step(carry, _):
-        kv_pages, tok, cur_len, finished, recent, keys = carry
-        pair = jax.vmap(lambda k: jax.random.split(k, 2))(keys)
-        emb = embed(tok)  # [S, H]
-        seg = jnp.arange(S, dtype=jnp.int32)
-        logits, kv_pages = qwen2.forward(
-            params, cfg,
-            inputs_embeds=emb[None], positions=cur_len[None],
-            kv_cache=kv_pages, block_tables=block_tables,
-            q_segments=seg[None], write_mask=(~finished)[None],
-            attn_impl=attn_impl, compute_dtype=compute_dtype,
-        )
-        lg = logits[0]  # [S, V]
-        nxt = sample_token_rows(
-            lg[:S], pair[:, 1],
-            temperature=temperature, top_p=top_p, top_k=top_k,
-        )
-        if recent.shape[1]:
-            recent = jnp.concatenate([recent[:, 1:], tok[:, None]], axis=1)
-        finished = finished | (tok == eos) | paged_kv_lib.stop_window_hit(
-            recent, stop_sequences
-        )
-        nxt = jnp.where(finished, eos, nxt)
-        cur_len = cur_len + (~finished).astype(jnp.int32)
-        return (
-            kv_pages, nxt, cur_len, finished, recent, pair[:, 0]
-        ), (tok, finished)
-
-    carry, (toks, fin) = jax.lax.scan(
-        step, (kv_pages, tok, lengths, finished, recent, keys),
-        None, length=k_steps * chunk,
-    )
-    kv_pages, tok, lengths, finished, recent, keys = carry
-    return (
-        kv_pages, tok, lengths, finished, recent, keys,
-        jnp.moveaxis(toks, 0, 1), jnp.moveaxis(fin, 0, 1),
-    )
-
-
 # ---------------------------------------------------------------------------
 # Speculative decoding: self-drafted multi-token steps, verified in one
 # packed dispatch (docs/DESIGN.md "Speculative decoding")
@@ -1269,19 +1161,6 @@ class Drafter:
 
     def propose(self, context, k: int) -> list[int]:
         raise NotImplementedError
-
-    # Device-side contract (opt-in): a drafter that can run ON the
-    # accelerator — inside `paged_fused_steps`' speculative scan —
-    # exposes its parameters as a pytree plus a module-level
-    # `device_apply(params, ctx, ctx_len, fed, k) -> (drafts, draft_len)`
-    # pure function. device_params() returning None means host-only:
-    # the drafter works on the per-step path but cannot ride a fused
-    # megastep (the scheduler rejects --fuse-steps > 1 + --speculate
-    # for such drafters rather than silently falling back).
-    device_apply = None
-
-    def device_params(self):
-        return None
 
 
 class NgramDrafter(Drafter):
@@ -1887,9 +1766,8 @@ def paged_block_step(
 
 
 # ---------------------------------------------------------------------------
-# Trained draft model: tiny device-resident proposer behind the Drafter
-# seam (docs/DESIGN.md "Fused multi-step decode" — the draft chain runs
-# INSIDE the fused scan so propose->verify never leaves the chip)
+# Trained draft model: a tiny proposer behind the Drafter seam
+# (docs/DESIGN.md "Speculative decoding")
 # ---------------------------------------------------------------------------
 
 # Positional decay of the context-mixing weights: token at distance d
@@ -1905,9 +1783,8 @@ def _draft_logits(params, buf: jnp.ndarray, n: jnp.ndarray) -> jnp.ndarray:
     anything; `n` [S] counts the valid tail entries). The model embeds
     the window, mixes it with exponentially-decayed weights anchored at
     the right edge, and projects to the vocabulary — one matmul pair,
-    cheap enough to run k times per verify lane inside the fused scan.
-    Pure function of (params, valid tail), so host and device callers
-    produce bit-identical proposals from the same window."""
+    cheap enough to run k times per proposal. Pure function of (params,
+    valid tail), which is the Drafter replay contract."""
     W = buf.shape[1]
     idx = jnp.arange(W, dtype=jnp.int32)[None, :]
     valid = idx >= (W - n[:, None].astype(jnp.int32))
@@ -1944,37 +1821,10 @@ def _draft_chain(params, buf: jnp.ndarray, n: jnp.ndarray, *, k: int):
 _draft_chain_jit = jax.jit(_draft_chain, static_argnames=("k",))
 
 
-def neural_draft_propose(
-    draft_params,
-    ctx: jnp.ndarray,  # [S, W] right-aligned confirmed tail, EXCLUDING fed
-    ctx_len: jnp.ndarray,  # [S] valid entries in ctx (0..W)
-    fed: jnp.ndarray,  # [S] the fed token (lane 0 of the verify dispatch)
-    k: int,
-) -> tuple[jnp.ndarray, jnp.ndarray]:
-    """Device-side Drafter.device_apply for `NeuralDrafter`: shift the
-    fed token into the window (the host Drafter contract hands propose()
-    the confirmed stream INCLUDING the pending fed token) and run the
-    greedy chain. Module-level so it is hashable as a jit static arg in
-    `paged_fused_spec_steps`. Returns (drafts [S, k], draft_len [S]) —
-    the chain always emits exactly k proposals."""
-    buf = jnp.concatenate(
-        [ctx[:, 1:], fed[:, None].astype(jnp.int32)], axis=1
-    )
-    n = jnp.minimum(ctx_len.astype(jnp.int32) + 1, ctx.shape[1])
-    drafts = _draft_chain(draft_params, buf, n, k=k)
-    return drafts, jnp.full(fed.shape, k, jnp.int32)
-
-
 class NeuralDrafter(Drafter):
     """Tiny trained draft model (decayed-bag-of-embeddings -> vocab
-    projection) implementing BOTH halves of the Drafter seam: the
-    host-side `propose()` used by the per-step speculative path, and
-    the `device_params()`/`device_apply` contract that lets
-    `paged_fused_spec_steps` run the same chain inside the fused scan.
-    Host and device call the SAME jitted `_draft_chain` math on the
-    same right-aligned window, so proposals are bit-identical — the
-    fused-vs-K=1 byte-parity claim for speculative serving rests on
-    exactly that.
+    projection) behind the Drafter seam: `propose()` runs the jitted
+    greedy `_draft_chain` on the context's right-aligned tail.
 
     Checkpoints are .npz files (embed [V, D] f32, proj [D, V] f32,
     window). `from_spec` accepts either a checkpoint path or
@@ -2045,14 +1895,6 @@ class NeuralDrafter(Drafter):
             return cls.init(v, d, window=w, seed=s)
         return cls.load(spec)
 
-    def device_params(self) -> dict:
-        return {
-            "embed": jnp.asarray(self.params["embed"]),
-            "proj": jnp.asarray(self.params["proj"]),
-        }
-
-    device_apply = staticmethod(neural_draft_propose)
-
     def propose(self, context, k: int) -> list[int]:
         a = np.asarray(context, np.int64).reshape(-1)[-self.window:]
         if k <= 0 or a.size == 0:
@@ -2060,7 +1902,7 @@ class NeuralDrafter(Drafter):
         buf = np.zeros((1, self.window), np.int32)
         buf[0, self.window - a.size:] = a
         drafts = _draft_chain_jit(
-            self.device_params(), jnp.asarray(buf),
+            self.params, jnp.asarray(buf),
             jnp.asarray([a.size], jnp.int32), k=k,
         )
         return [int(x) for x in np.asarray(drafts)[0]]
@@ -2099,7 +1941,7 @@ def fit_neural_drafter(
     drafter = NeuralDrafter.init(
         vocab_size, dim, window=window, seed=seed
     )
-    params = drafter.device_params()
+    params = {k: jnp.asarray(v) for k, v in drafter.params.items()}
 
     def loss_fn(p):
         lg = _draft_logits(p, buf, n)
@@ -2122,138 +1964,6 @@ def fit_neural_drafter(
         source=f"fit:{vocab_size}:{dim}:{window}:{seed}",
     )
     return out, losses
-
-
-@partial(
-    jax.jit,
-    static_argnames=(
-        "cfg", "k", "k_steps", "eos", "attn_impl", "compute_dtype",
-        "draft_apply",
-    ),
-    donate_argnames=("kv_pages",),
-)
-def paged_fused_spec_steps(
-    params,
-    cfg: LLMConfig,
-    kv_pages: dict,  # donated
-    block_tables: jnp.ndarray,  # [S, max_pages] int32
-    tok: jnp.ndarray,  # [S] next token to feed per slot
-    lengths: jnp.ndarray,  # [S] kv tokens held per slot
-    finished: jnp.ndarray,  # [S] bool
-    keys: jax.Array,  # [S] per-slot PRNG keys
-    temperature: jnp.ndarray,  # [S]
-    top_p: jnp.ndarray,  # [S]
-    top_k: jnp.ndarray,  # [S]
-    draft_params,  # drafter.device_params() pytree
-    draft_ctx: jnp.ndarray,  # [S, CW] right-aligned confirmed tail (no fed)
-    draft_ctx_len: jnp.ndarray,  # [S] valid entries in draft_ctx
-    *,
-    k: int,
-    k_steps: int,
-    eos: int,
-    attn_impl: str = "xla",
-    compute_dtype=None,
-    draft_apply,
-):
-    """ONE device dispatch for K=`k_steps` SPECULATIVE pure-decode
-    engine steps: each scan iteration drafts k tokens on-device via
-    `draft_apply` (the Drafter's device contract — same math as its
-    host `propose()`), verifies them through the same packed forward
-    as `paged_spec_step`'s pure-decode branch, splices accepts /
-    rolls back rejects, and shifts the confirmed tokens into the
-    draft-context carry. Propose->verify->rollback never touches the
-    host until the K-step harvest.
-
-    Parity contract: iteration j's math is `paged_spec_step` (W=0)
-    verbatim — same spec_verify_rows key discipline (fixed 2k+3 split
-    per slot per step), same accept/EOS-truncation/rollback algebra —
-    and the in-scan context update reproduces exactly the confirmed
-    stream the host-side `_propose_drafts` would have assembled
-    between dispatches. So K fused speculative steps emit the same
-    bytes as K sequential `paged_spec_step` dispatches with the same
-    drafter. The context carry is NOT returned: the host rebuilds it
-    from its own confirmed stream before the next megastep, which
-    keeps the harvest surface identical to the per-step spec path.
-
-    Returns (kv_pages, tok, lengths, finished, keys,
-    toks [S, k_steps*(k+1)], n_new [S, k_steps], acc [S, k_steps]) —
-    logical step j owns toks[:, j*(k+1):(j+1)*(k+1)], of which the
-    first n_new[:, j] are real emissions."""
-    from oryx_tpu.parallel.sharding import constrain
-
-    S = tok.shape[0]
-    lanes = k + 1
-    CW = draft_ctx.shape[1]
-
-    def embed(ids):
-        e = constrain(params["embed"]["weight"], None, None)[ids]
-        return e.astype(compute_dtype) if compute_dtype is not None else e
-
-    def step(carry, _):
-        kv_pages, tok, lengths, finished, keys, ctx, clen = carry
-        drafts, dlen = draft_apply(draft_params, ctx, clen, tok, k)
-        ids = jnp.concatenate(
-            [tok[:, None], drafts.astype(jnp.int32)], axis=1
-        )
-        dec_emb = embed(ids.reshape(S * lanes))
-        seg, pos = paged_kv_lib.spec_lane_metadata(lengths, k)
-        lane_j = jnp.tile(jnp.arange(lanes, dtype=jnp.int32), (S,))
-        wm = (
-            jnp.repeat(~finished, lanes)
-            & (lane_j <= jnp.repeat(dlen.astype(jnp.int32), lanes))
-        )
-        logits, kv_pages = qwen2.forward(
-            params, cfg,
-            inputs_embeds=dec_emb[None], positions=pos[None],
-            kv_cache=kv_pages, block_tables=block_tables,
-            q_segments=seg[None], write_mask=wm[None],
-            attn_impl=attn_impl, compute_dtype=compute_dtype,
-        )
-        lg = logits[0][: S * lanes].reshape(S, lanes, -1)
-        acc, cand, keys_next = spec_verify_rows(
-            lg, tok, drafts, dlen, keys,
-            temperature=temperature, top_p=top_p, top_k=top_k, eos=eos,
-        )
-        jr = jnp.arange(k, dtype=jnp.int32)[None, :]
-        accepted = jr < acc[:, None]
-        out_toks = jnp.concatenate(
-            [tok[:, None], jnp.where(accepted, drafts, eos)], axis=1
-        )
-        acc_eos = jnp.any(accepted & (drafts == eos), axis=1)
-        fed_eos = tok == eos
-        new_finished = finished | fed_eos | acc_eos
-        n_new = jnp.where(finished, 0, 1 + acc)
-        inc = jnp.where(
-            finished | fed_eos, 0, 1 + acc - acc_eos.astype(jnp.int32)
-        )
-        nxt = jnp.where(new_finished, eos, cand)
-        # Shift this step's confirmed tokens (fed + accepted drafts)
-        # into the right-aligned window — what the host would have fed
-        # the drafter next step. Frozen rows have n_new == 0: no shift.
-        ext = jnp.concatenate([ctx, out_toks.astype(jnp.int32)], axis=1)
-        ctx = jnp.take_along_axis(
-            ext,
-            n_new[:, None] + jnp.arange(CW, dtype=jnp.int32)[None, :],
-            axis=1,
-        )
-        clen = jnp.minimum(clen + n_new, CW)
-        return (
-            kv_pages, nxt, lengths + inc, new_finished, keys_next, ctx,
-            clen,
-        ), (out_toks, n_new, acc)
-
-    carry, (toks, n_new, acc) = jax.lax.scan(
-        step,
-        (kv_pages, tok, lengths, finished, keys, draft_ctx,
-         draft_ctx_len.astype(jnp.int32)),
-        None, length=k_steps,
-    )
-    kv_pages, tok, lengths, finished, keys, _, _ = carry
-    return (
-        kv_pages, tok, lengths, finished, keys,
-        jnp.moveaxis(toks, 0, 1).reshape(S, k_steps * lanes),
-        jnp.moveaxis(n_new, 0, 1), jnp.moveaxis(acc, 0, 1),
-    )
 
 
 @dataclasses.dataclass

@@ -880,13 +880,9 @@ def stop_window_hit(
     """In-scan stop mask over the per-slot recent-token windows: row s
     is True when its window's tail matches ANY template stop sequence
     (right-aligned; -1 template slots are wildcards, which is also how
-    shorter sequences left-pad). This is the ONE device-side stop
-    predicate — the per-step scan (`paged_ragged_step`) and the fused
-    K-step megastep (`paged_fused_steps`) both call it, so multi-step
-    fusion can never drift from the single-step stop semantics (the
-    window initializes at -2, matching nothing, and carries across
-    dispatches AND across the fused scan's iterations identically).
-    Returns [S] bool."""
+    shorter sequences left-pad). The window initializes at -2,
+    matching nothing, and carries across dispatches. Returns [S]
+    bool."""
     if stop_sequences is None:
         return jnp.zeros((recent.shape[0],), bool)
     m = (stop_sequences[None] == -1) | (

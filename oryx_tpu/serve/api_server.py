@@ -419,7 +419,6 @@ def build_server(
     prefix_cache: bool = True,
     ragged: bool = False,
     speculate: int = 0,
-    fuse_steps: int | str = 1,
     draft_model: str | None = None,
     kv_dtype: str = "bf16",
     host_cache_bytes: int = 0,
@@ -492,21 +491,6 @@ def build_server(
             "--speculate requires --ragged (draft tokens ride the "
             "fused packed dispatch as extra verify lanes)"
         )
-    if fuse_steps != 1:
-        # Fused multi-step decode (docs/DESIGN.md "Fused multi-step
-        # decode"): the megastep is a scan over the fused ragged step,
-        # so it needs that step to exist — same fail-fast contract.
-        if not ragged:
-            raise ValueError(
-                "--fuse-steps requires --ragged (the megastep is a "
-                "scan over the fused ragged step)"
-            )
-        if speculate and not draft_model:
-            raise ValueError(
-                "--fuse-steps with --speculate needs --draft-model: "
-                "the host-side n-gram drafter cannot ride the fused "
-                "scan (propose->verify must stay on-device)"
-            )
     if draft_model and not speculate:
         raise ValueError(
             "--draft-model requires --speculate (the draft model "
@@ -574,10 +558,8 @@ def build_server(
         )
     # Trained draft model (models/generate.NeuralDrafter): a checkpoint
     # path or an "init:V:D:W:SEED" spec. Replaces the default n-gram
-    # drafter and — because it implements the device params/apply
-    # contract — unlocks fused speculative megasteps. Its `source`
-    # string lands in the journal header (draft_model) so replay
-    # rebuilds the identical proposer.
+    # drafter. Its `source` string lands in the journal header
+    # (draft_model) so replay rebuilds the identical proposer.
     drafter = None
     if draft_model:
         from oryx_tpu.models import generate as generate_lib
@@ -592,7 +574,7 @@ def build_server(
         tracer=tracer, stall_timeout=stall_timeout, anomaly=anomaly,
         prefill_chunk=prefill_chunk, prefix_cache=prefix_cache,
         ragged=ragged, speculate=speculate,
-        fuse_steps=fuse_steps, drafter=drafter,
+        drafter=drafter,
         kv_dtype=kv_dtype, host_cache_bytes=host_cache_bytes,
         audit_tol_maxdiff=audit_tol_maxdiff,
         audit_tol_kl=audit_tol_kl,
@@ -1221,26 +1203,12 @@ def main(argv: list[str] | None = None) -> None:
         "rejection sampling (distribution-exact). Requires --ragged.",
     )
     ap.add_argument(
-        "--fuse-steps", default="1", metavar="K|auto",
-        help="continuous engine: fused multi-step decode — run K "
-        "engine steps per device dispatch (a donating on-device scan: "
-        "sampling, KV writes and EOS/stop-window detection stay "
-        "device-side; the host harvests once per K logical steps). "
-        "'auto' adapts K from queue depth within a small fixed ladder "
-        "of compiled shape classes (backlog -> K=1 so admission "
-        "latency never degrades; idle residents -> large K). Replies "
-        "are byte-identical to K=1. Requires --ragged; with "
-        "--speculate also requires --draft-model (propose->verify "
-        "runs inside the fused scan)",
-    )
-    ap.add_argument(
         "--draft-model", default=None, metavar="PATH|init:V:D:W:SEED",
         help="continuous engine: trained draft model for speculative "
         "decoding (models/generate.NeuralDrafter) replacing the "
         "default n-gram drafter — an .npz checkpoint path (see "
         "generate.fit_neural_drafter) or an init:V:D:W:SEED spec for "
-        "a random init. Implements the device-side drafting contract "
-        "required by --fuse-steps + --speculate. Requires --speculate",
+        "a random init. Requires --speculate",
     )
     ap.add_argument(
         "--kv-dtype", choices=["bf16", "int8"], default="bf16",
@@ -1419,23 +1387,6 @@ def main(argv: list[str] | None = None) -> None:
                  "lanes of the fused dispatch)")
     if args.speculate < 0:
         ap.error("--speculate must be >= 0")
-    # --fuse-steps: "auto" stays a string; anything else must parse as
-    # a positive int (build_server re-validates the ragged pairing).
-    if args.fuse_steps == "auto":
-        fuse_steps: int | str = "auto"
-    else:
-        try:
-            fuse_steps = int(args.fuse_steps)
-        except ValueError:
-            ap.error("--fuse-steps must be a positive integer or 'auto'")
-        if fuse_steps < 1:
-            ap.error("--fuse-steps must be a positive integer or 'auto'")
-    if fuse_steps != 1 and not args.ragged:
-        ap.error("--fuse-steps requires --ragged (the megastep is a "
-                 "scan over the fused ragged step)")
-    if fuse_steps != 1 and args.speculate and not args.draft_model:
-        ap.error("--fuse-steps with --speculate requires --draft-model "
-                 "(on-device drafting)")
     if args.draft_model and not args.speculate:
         ap.error("--draft-model requires --speculate")
 
@@ -1461,7 +1412,6 @@ def main(argv: list[str] | None = None) -> None:
         prefix_cache=not args.no_prefix_cache,
         ragged=args.ragged,
         speculate=args.speculate,
-        fuse_steps=fuse_steps,
         draft_model=args.draft_model,
         kv_dtype=args.kv_dtype,
         host_cache_bytes=args.host_cache_bytes,

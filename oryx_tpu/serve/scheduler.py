@@ -163,13 +163,56 @@ from oryx_tpu.utils.metrics import (
 # /debug/trace).
 _LOG = logging.getLogger("oryx.serve.scheduler")
 
-# The adaptive-K ladder for --fuse-steps auto: every value is a
-# separate compiled shape class of the megastep program, so the ladder
-# stays SHORT and FIXED (the recompile watchdog's bounded-class
-# contract — a warmup that touches each rung compiles everything the
-# engine will ever run). K=1 — the plain per-step program — is always
-# implicitly available below the ladder.
-FUSE_AUTO_LADDER: tuple[int, ...] = (4, 16)
+def _unsupported_for_block(mode: str) -> str:
+    """The one refusal of a mode that is not built for block mode (a
+    model that generates by diffusion over blocks, docs/DESIGN.md "Block
+    diffusion")."""
+    return (
+        f"block mode (block_length > 0): {mode} is not built for the "
+        "block step (a dispatch denoises one block a slot, "
+        "`paged_block_step`, and commits it in the next); it serves "
+        "through the continuous split engine with chunked prefill and a "
+        "bf16 pool only"
+    )
+
+
+# The options an engine may be asked for that some cache kind has no
+# engine for: (option, "is it set", the words a refusal names it by).
+# In the order they are looked at.
+_ENGINE_OPTIONS = (
+    ("ragged", bool, "ragged=True"),
+    ("speculate", bool, "speculate"),
+    ("kv_dtype", lambda v: v != "bf16", "kv_dtype={!r} (a quantized KV pool)"),
+    ("host_cache_bytes", bool, "host_cache_bytes (the host spill tier)"),
+    ("audit_sample_every", bool, "audit_sample_every (the output auditor's "
+     "replay: a one-token decode step over one paged pool of its own)"),
+    ("numerics_every", bool, "numerics_every (the numerics probe)"),
+    ("prefill_chunk", lambda v: v is None, "an unchunked prefill "
+     "(prefill_chunk=None; set prefill_chunk)"),
+    ("mesh", lambda v: v is not None,
+     "the tensor-parallel engine (--engine sharded)"),
+)
+
+# What each cache kind's engine is NOT built for. A row a kind: the
+# `LLMConfig` attribute that is truthy for a model of the kind, the
+# kind's sentence, and the options above it refuses. Every kind serves
+# through the split engine's two programs (block mode: `paged_prefill`
+# and `paged_block_step`) and a bf16 pool; a new kind adds a row. What a
+# row leaves out is served and held to the default engine's bytes by
+# `test_the_engine_serves_what_it_does_not_refuse` in the kind's tests.
+_CACHE_KINDS = (
+    ("block_length", _unsupported_for_block, frozenset({
+        "ragged", "speculate", "kv_dtype", "audit_sample_every",
+        "numerics_every", "prefill_chunk", "mesh"})),
+    ("latent", qwen2.unsupported_for_latent, frozenset({
+        "ragged", "speculate", "kv_dtype", "mesh"})),
+    ("recurrent", qwen2.unsupported_for_recurrent, frozenset({
+        "ragged", "speculate", "kv_dtype", "host_cache_bytes",
+        "audit_sample_every", "mesh"})),
+    ("windowed", qwen2.unsupported_for_window, frozenset({
+        "ragged", "speculate", "kv_dtype", "host_cache_bytes",
+        "audit_sample_every", "prefill_chunk", "mesh"})),
+)
 
 # Positions below which the split prefill keeps ONE block-table width
 # (`prefill_table_buckets`).
@@ -467,7 +510,6 @@ class ContinuousScheduler:
         ragged: bool = False,
         speculate: int = 0,
         drafter=None,
-        fuse_steps: int | str = 1,
         timeline: StepTimeline | None = None,
         request_log: request_log_lib.RequestLog | None = None,
         engine_label: str = "continuous",
@@ -525,39 +567,31 @@ class ContinuousScheduler:
                 "packed lanes of the fused ragged dispatch (the split "
                 "engine has no packed buffer to extend)"
             )
+        # What this model's cache kind has no engine for is refused
+        # here, by name, never silently run (`_CACHE_KINDS`).
+        llm = pipe.cfg.llm
+        given = dict(
+            ragged=ragged, speculate=speculate, kv_dtype=kv_dtype,
+            host_cache_bytes=host_cache_bytes,
+            audit_sample_every=audit_sample_every,
+            numerics_every=numerics_every, prefill_chunk=prefill_chunk,
+            mesh=getattr(pipe, "mesh", None),
+        )
+        for kind, sentence, refused in _CACHE_KINDS:
+            if not getattr(llm, kind):
+                continue
+            for option, is_set, words in _ENGINE_OPTIONS:
+                if option in refused and is_set(given[option]):
+                    raise ValueError(
+                        sentence(words.format(given[option])))
         # Block mode (docs/DESIGN.md "Block diffusion"): on iff the
         # model generates by diffusion over blocks. The split engine's
         # admission and chunked prefill stay; the decode chunk's program
         # is `paged_block_step`, and a dispatch advances a slot by one
-        # block. What is not built for it is refused here, one sentence
-        # each, never silently run.
-        self.block = int(pipe.cfg.llm.block_length)
+        # block.
+        self.block = int(llm.block_length)
         if self.block:
             gen = pipe.cfg.generation
-            refusals = (
-                (ragged, "ragged=True fuses prefill into a one-token-a-"
-                 "lane decode dispatch that block mode does not have"),
-                (speculate, "speculate drafts a causal stream; a block "
-                 "is not one"),
-                (fuse_steps != 1, "fuse_steps scans the ragged decode "
-                 "step, which block mode does not run"),
-                (getattr(pipe, "mesh", None) is not None, "the tensor-"
-                 "parallel engine's sharded pool is not built for the "
-                 "block step"),
-                (prefill_chunk is None, "block mode admits through "
-                 "chunked prefill only; set prefill_chunk"),
-                (kv_dtype != "bf16", "a quantized KV pool is not built "
-                 "for the block step"),
-                (audit_sample_every, "the output auditor replays one-"
-                 "token decode steps, which block mode does not run"),
-                (numerics_every, "the block step carries no numerics "
-                 "probe"),
-            )
-            for bad, why in refusals:
-                if bad:
-                    raise ValueError(
-                        f"block mode (block_length={self.block}): {why}"
-                    )
             for name, v in (
                 ("page_size", page_size), ("prefill_chunk", prefill_chunk),
                 ("max_ctx", max_ctx),
@@ -575,78 +609,22 @@ class ContinuousScheduler:
                     f"block_length={self.block}: a step fixes at least "
                     "one position"
                 )
-        # A latent-attention (MLA) model's pool is one plane of latents
-        # that only the split engine's two programs read; every other
-        # step program and pool format is refused here, by name.
-        if pipe.cfg.llm.latent:
-            for bad, mode in (
-                (ragged, "ragged=True"),
-                (speculate, "speculate"),
-                (fuse_steps != 1, "fuse_steps"),
-                (kv_dtype != "bf16", f"kv_dtype={kv_dtype!r}"),
-                (getattr(pipe, "mesh", None) is not None,
-                 "the tensor-parallel engine (--engine sharded)"),
-            ):
-                if bad:
-                    raise ValueError(qwen2.unsupported_for_latent(mode))
-        # A model with state-space layers keeps a recurrent state a slot
-        # beside its pages, which only the split engine's two programs
-        # carry: every other step program, pool format and tier is
-        # refused here, by name. Its prefix cache is constructed OFF (a
-        # hit hands over pages and no state; snapshots at page
-        # boundaries are ROADMAP R3(a)), with the refusal's words in
-        # the log.
-        self.recurrent = bool(pipe.cfg.llm.recurrent)
-        if self.recurrent:
-            for bad, mode in (
-                (ragged, "ragged=True"),
-                (speculate, "speculate"),
-                (fuse_steps != 1, "fuse_steps"),
-                (kv_dtype != "bf16", f"kv_dtype={kv_dtype!r}"),
-                (host_cache_bytes, "host_cache_bytes (the host spill tier)"),
-                (audit_sample_every, "audit_sample_every (the auditor's "
-                 "replay holds no state)"),
-                (getattr(pipe, "mesh", None) is not None,
-                 "the tensor-parallel engine (--engine sharded)"),
-            ):
-                if bad:
-                    raise ValueError(qwen2.unsupported_for_recurrent(mode))
-            if prefix_cache:
+        # A recurrent state a slot and a window plane's re-based table
+        # are each carried by the split engine's two programs alone. The
+        # prefix cache of either is constructed OFF, with the refusal's
+        # words in the log: a hit hands over pages and no state
+        # (snapshots at page boundaries are ROADMAP R3(a)), or would
+        # have to hand over the window plane's pages as they stood at
+        # the hit's last token.
+        self.recurrent = bool(llm.recurrent)
+        self.windowed = bool(llm.windowed)
+        for on, sentence in (
+            (self.recurrent, qwen2.unsupported_for_recurrent),
+            (self.windowed, qwen2.unsupported_for_window),
+        ):
+            if on and prefix_cache:
                 _LOG.info("prefix cache off: %s",
-                          qwen2.unsupported_for_recurrent(
-                              "prefix-cache splicing"))
-                prefix_cache = False
-        # A model with window layers keeps TWO paged planes, one a layer
-        # kind, each behind its own allocator and block table, and the
-        # window plane's table is re-based as pages older than the
-        # window are given back (`paged_kv.WindowPlane`): only the split
-        # engine's two programs carry that. Everything else is refused
-        # here, by name; the prefix cache is constructed OFF (a hit
-        # would have to hand over the window plane's pages as they
-        # stood at the hit's last token), with the refusal's words in
-        # the log.
-        self.windowed = bool(pipe.cfg.llm.windowed)
-        if self.windowed:
-            for bad, mode in (
-                (ragged, "ragged=True"),
-                (speculate, "speculate"),
-                (fuse_steps != 1, "fuse_steps"),
-                (kv_dtype != "bf16", f"kv_dtype={kv_dtype!r}"),
-                (host_cache_bytes, "host_cache_bytes (the host spill tier)"),
-                (audit_sample_every, "audit_sample_every (the auditor's "
-                 "replay holds one table a slot)"),
-                (prefill_chunk is None, "an unchunked prefill "
-                 "(prefill_chunk=None: a prompt longer than the window "
-                 "plane's table)"),
-                (getattr(pipe, "mesh", None) is not None,
-                 "the tensor-parallel engine (--engine sharded)"),
-            ):
-                if bad:
-                    raise ValueError(qwen2.unsupported_for_window(mode))
-            if prefix_cache:
-                _LOG.info("prefix cache off: %s",
-                          qwen2.unsupported_for_window(
-                              "prefix-cache splicing"))
+                          sentence("prefix-cache splicing"))
                 prefix_cache = False
         # Optional SLO watcher (utils/anomaly.py): TTFT and queue-depth
         # breaches fire oryx_anomaly_total{kind=} + events.jsonl.
@@ -693,9 +671,8 @@ class ContinuousScheduler:
         # classes total (prefill lanes present / absent), both static.
         self.ragged = bool(ragged)
         # The split engine (and block mode, by its own pair of
-        # functions) keeps one decode dispatch in flight; the ragged,
-        # fused and speculative steps read every dispatch before the
-        # next.
+        # functions) keeps one decode dispatch in flight; the ragged
+        # and speculative steps read every dispatch before the next.
         self._ahead = not (self.block or self.ragged)
         self.pf_width = (
             -(-prefill_chunk // chunk) if ragged else 0
@@ -717,53 +694,6 @@ class ContinuousScheduler:
         self._win = (1 + self.speculate) if self.speculate else chunk
         if self.block:
             self._win = self.block  # a dispatch writes one block a slot
-        # Fused multi-step decode (docs/DESIGN.md "Fused multi-step
-        # decode"): K engine steps per device dispatch — the decode
-        # megastep. An int K pins the fusion depth; "auto" adapts K
-        # from queue depth between a small bounded LADDER of compiled
-        # shape classes (deep backlog -> K=1 so admission/cancel
-        # latency never degrades by more than K-1 steps; idle
-        # residents -> large K so the per-step harvest sync amortizes).
-        # K collapses to 1 whenever an admission rides the step, so
-        # the prefill-present shape class never multiplies by K.
-        if fuse_steps != "auto" and (
-            isinstance(fuse_steps, bool) or not isinstance(fuse_steps, int)
-            or fuse_steps < 1
-        ):
-            raise ValueError(
-                "fuse_steps must be a positive integer (engine steps "
-                f"per decode dispatch) or 'auto', got {fuse_steps!r}"
-            )
-        if fuse_steps != 1 and not ragged:
-            raise ValueError(
-                "fuse_steps > 1 requires ragged=True: the megastep is "
-                "a scan over the fused ragged step (the split engine "
-                "has no single program to iterate)"
-            )
-        if fuse_steps != 1 and self.speculate and (
-            self.drafter.device_params() is None
-            or self.drafter.device_apply is None
-        ):
-            raise ValueError(
-                "fuse_steps > 1 with speculate>0 needs a drafter "
-                "implementing the device contract (device_params()/"
-                "device_apply) so propose->verify can run inside the "
-                "fused scan — pass a generate.NeuralDrafter "
-                "(--draft-model), or drop --fuse-steps"
-            )
-        self.fuse_steps = fuse_steps
-        self._fuse_ladder: tuple[int, ...] = (
-            FUSE_AUTO_LADDER if fuse_steps == "auto"
-            else ((fuse_steps,) if fuse_steps > 1 else ())
-        )
-        # Replay override (scripts/replay_journal.py): a dict mapping
-        # the steps_run value a megastep STARTED at -> its journaled K.
-        # Live serving leaves it None and picks K from the ladder;
-        # replay substitutes the captured plan because live K reads
-        # queue depth, which is wall-clock-coupled and NOT part of the
-        # deterministic replay state (same treatment as the degraded
-        # ladder: journaled, not re-derived).
-        self.replay_fuse_plan: dict[int, int] | None = None  # thread-owned: engine
         if ragged and not self.speculate and prefill_chunk % chunk:
             # The prefill lanes advance chunk*pf_width tokens per fused
             # step — ceil-rounding silently raises the configured
@@ -833,14 +763,7 @@ class ContinuousScheduler:
         # (generate.sample_token_rows decides on the same array).
         reg.counter("sampler_sort_dispatches_total", ("kind",))
         reg.histogram("dispatch_rows", DISPATCH_ROWS_BUCKETS)
-        # Fused-decode observability: the K currently in effect (gauge,
-        # so a dashboard sees adaptive-K transitions) and how many
-        # times the host actually harvested device outputs — with
-        # fusion, dispatches == harvests but BOTH run at 1/K of the
-        # logical step rate, and the separate counter is what makes a
-        # harvest-cadence regression diagnosable (docs/OBSERVABILITY.md
-        # "Fused multi-step decode").
-        reg.gauge("fused_k")
+        # How many times the host read a dispatch's outputs.
         reg.counter("harvest_total")
         # Speculation accounting: tokens a slot advanced per engine
         # step (sum/count mean is THE speculation headline — the
@@ -1209,7 +1132,6 @@ class ContinuousScheduler:
                 prefill_chunk=prefill_chunk,
                 prefix_cache=bool(prefix_cache),
                 ragged=self.ragged, speculate=self.speculate,
-                fuse_steps=fuse_steps,
                 draft_model=getattr(self.drafter, "source", None),
                 kv_dtype=kv_dtype, host_cache_bytes=host_cache_bytes,
                 max_queue=max_queue,
@@ -3309,8 +3231,8 @@ class ContinuousScheduler:
                 self.keys = self.keys.at[s].set(key[0])
             self._occupancy_gauge()
         if not self._ahead:
-            # The ragged, fused and speculative steps read every
-            # dispatch before the next: the token is there.
+            # The ragged and speculative steps read every dispatch
+            # before the next: the token is there.
             self._read_first_tokens()
 
     def _lane_state(self) -> tuple:
@@ -3421,20 +3343,13 @@ class ContinuousScheduler:
                 [int(p) for p in self.bt[s, :full]],
             )
 
-    def _ensure_capacity(self, horizon: int | None = None) -> None:
-        """Every live slot must own pages for lengths + `horizon`
-        (default: one dispatch window, `_win`) before the next
-        dispatch; under page pressure, preempt YOUNGER slots only —
-        a slot with no younger victim preempts ITSELF (vLLM-style), so
-        the oldest request always makes progress and eviction can never
-        ping-pong two slots at the same growth point forever.
-
-        A fused megastep passes horizon=_win*K: the device writes up
-        to K windows of KV before the host sees any of it, so every
-        page a row could touch must exist BEFORE the dispatch. Evicting
-        here (pre-dispatch, deterministic in journaled state) is what
-        keeps eviction replay exact under fusion."""
-        win = self._win if horizon is None else horizon
+    def _ensure_capacity(self) -> None:
+        """Every live slot must own pages for lengths + one dispatch
+        window (`_win`) before the next dispatch; under page pressure,
+        preempt YOUNGER slots only — a slot with no younger victim
+        preempts ITSELF (vLLM-style), so the oldest request always
+        makes progress and eviction can never ping-pong two slots at
+        the same growth point forever."""
         order = sorted(
             (s for s, r in enumerate(self.slots) if r is not None),
             key=lambda s: self.slots[s].admit_seq,
@@ -3442,14 +3357,14 @@ class ContinuousScheduler:
         for s in order:
             if self.slots[s] is None or self.finished[s]:
                 continue  # freed or evicted by an earlier iteration
-            while not self._grow_slot(s, int(self.lengths[s]) + win):
+            while not self._grow_slot(s, int(self.lengths[s]) + self._win):
                 if self._inflight is not None:
                     # Page pressure: read the dispatch in flight before
                     # anyone is evicted (its finishes may free the
                     # pages; a victim's replay count holds its tokens),
                     # then look again.
                     self._drain_flight()
-                    return self._ensure_capacity(horizon)
+                    return self._ensure_capacity()
                 me = self.slots[s].admit_seq
                 younger = [
                     v for v in order
@@ -4207,9 +4122,9 @@ class ContinuousScheduler:
 
     # hot-path
     def _read_chunk(self, tok, lengths, finished, recent, toks, fin):
-        """Blocking host copies of a dispatch's outputs, shared by the
-        ragged and fused step paths, which read every dispatch before
-        the next (the split engine's harvest is `_harvest_chunk`).
+        """Blocking host copies of the ragged step's outputs, which
+        are read before the next dispatch (the split engine's harvest
+        is `_harvest_chunk`).
         Host copies BLOCK on the device result — callers measure dt
         AFTER this, or async dispatch makes the window (and the
         per-token histogram) cover only dispatch time, and the
@@ -4283,16 +4198,6 @@ class ContinuousScheduler:
                 pf_s, pf_req = s, req
                 break
         if pf_req is None and not live:
-            return
-        # Fused multi-step decode: when the adaptive-K policy (or the
-        # replay plan) picks K>1, the whole engine step becomes a
-        # megastep — K logical steps in one dispatch — and everything
-        # below (prefill lanes, per-step dispatch, harvest) is the K=1
-        # path this step didn't take.
-        fuse_k = self._select_fuse_k(live, pf_req)
-        self.metrics.set_gauge("fused_k", fuse_k)
-        if fuse_k > 1:
-            self._fused_megastep(fuse_k)
             return
         # Chaos sites: the fused dispatch is both the admission's
         # prefill work and the residents' decode beat, so both named
@@ -4462,287 +4367,6 @@ class ContinuousScheduler:
             if pf_req.prefill_pos >= pf_len:
                 self._activate(pf_s, pf_req, pf_tok0[np.newaxis], pf_key)
         self._occupancy_gauge()
-
-    # replay-decision
-    def _select_fuse_k(self, live: list[int], pf_req) -> int:
-        """Pick K — logical engine steps for the next decode dispatch
-        (docs/DESIGN.md "Fused multi-step decode").
-
-        Replay consults the journaled plan FIRST: live K reads queue
-        depth, which is wall-clock-coupled and not replay state (the
-        degraded ladder gets the same journaled-not-re-derived
-        treatment). Live policy: K>1 only for a pure-decode step
-        (admission in flight -> 1, so the prefill-present shape class
-        never multiplies), only when the queue is EMPTY (a waiting
-        request must not eat a K-step admission delay), and never with
-        the numerics probe armed (the megastep program doesn't carry
-        it). K is then clamped to every live row's remaining max_new
-        budget in dispatch windows — a row the HOST will finish
-        (length cap, custom stop string) overruns at most one window
-        past its budget, the same max_ctx exposure as K=1 — and the
-        largest ladder rung that fits wins. "auto" uses the small rung
-        when residents share the step (a mid-megastep finish idles its
-        lanes for the remainder) and the large rung for a solo
-        resident."""
-        if self.replay_fuse_plan is not None:
-            return self.replay_fuse_plan.get(self.steps_run, 1)
-        if not self._fuse_ladder or pf_req is not None or not live:
-            return 1
-        if self.numerics_every:
-            return 1
-        with self._cond:
-            if self._queue:
-                return 1
-        desired = (
-            self._fuse_ladder[-1] if len(live) == 1
-            else self._fuse_ladder[0]
-        )
-        cap = desired
-        for s in live:
-            req = self.slots[s]
-            rem = max(1, req.replay + req.max_new - len(req.emitted))
-            cap = min(cap, -(-rem // self._win))
-        k = 1
-        for rung in self._fuse_ladder:
-            if rung <= cap:
-                k = max(k, rung)
-        return k
-
-    def _fused_megastep(self, k_steps: int) -> None:
-        """ONE device dispatch for K logical engine steps — the decode
-        megastep. Pure-decode by construction (`_select_fuse_k` returns
-        1 whenever an admission is in flight), so the dispatch is the
-        `paged_fused_steps` scan (or its speculative twin, with the
-        drafter's device chain folded into each iteration) and the
-        host pays ONE harvest sync for K steps. Everything host-side —
-        billing, journal entries, stop-string detection, finishes —
-        then runs as K sequential logical steps over column slices of
-        the harvested outputs (`_finish_megastep`), so every per-step
-        meaning (TPOT, wasted fraction, the journal's step clock) is
-        preserved bit-for-bit against the K=1 path."""
-        faults.fault_point("decode_dispatch")
-        hot_dispatch("scheduler._fused_megastep")
-        # Pages for K dispatch windows must exist BEFORE the scan (the
-        # device cannot grow tables mid-flight); eviction under this
-        # larger horizon is deterministic in journaled state, so replay
-        # re-derives it exactly.
-        with self._phase("housekeeping"):
-            self._ensure_capacity(self._win * k_steps)
-        live = [
-            s for s, r in enumerate(self.slots)
-            if r is not None and r.activated
-        ]
-        if not live:
-            return
-        dtype = oryx.compute_dtype(self.cfg)
-        eos = self.cfg.generation.eos_token_id
-        sampled = self._profile_dispatch_begin()
-        t0 = time.monotonic()
-        t0_ns = trace_lib.now_ns()
-        if self.speculate:
-            with self._phase("decode", "dispatch"):
-                draft_ctx, draft_clen = self._build_draft_ctx(live)
-                with self.pipe._mesh_scope():
-                    (self.kv_pages, tok, lengths, finished, self.keys,
-                     toks, n_new, acc) = generate_lib.paged_fused_spec_steps(
-                        self.pipe.params["llm"], self.cfg.llm, self.kv_pages,
-                        jnp.asarray(self.bt),
-                        jnp.asarray(self.tok),
-                        jnp.asarray(self.lengths),
-                        jnp.asarray(self.finished),
-                        self.keys,
-                        jnp.asarray(self.temp),
-                        jnp.asarray(self.top_p),
-                        jnp.asarray(self.top_k),
-                        self.drafter.device_params(),
-                        jnp.asarray(draft_ctx),
-                        jnp.asarray(draft_clen),
-                        k=self.speculate, k_steps=k_steps, eos=eos,
-                        attn_impl=self.cfg.attn_impl, compute_dtype=dtype,
-                        draft_apply=self.drafter.device_apply,
-                    )
-            toks, n_new, acc = self._harvest_spec(
-                tok, lengths, finished, toks, n_new, acc
-            )
-            dt = time.monotonic() - t0
-            with self._phase("emit"):
-                dev_us = self._profile_dispatch_end(
-                    sampled, "fused_spec", t0_ns
-                )
-                # Draft economics: the device chain proposes k tokens for
-                # every row still decoding at that logical step (n_new==0
-                # marks a row that entered the step frozen — its masked
-                # lanes proposed nothing, same as the K=1 accounting).
-                self.metrics.inc(
-                    "draft_proposed_total",
-                    int(self.speculate * (n_new[live] > 0).sum()),
-                )
-                self.metrics.inc("draft_accepted_total", int(acc[live].sum()))
-                rows = len(live) * (1 + self.speculate)
-                self._finish_megastep(
-                    "fused_spec", rows, live, toks, t0_ns, dt, k_steps,
-                    n_new=n_new, device_us=dev_us,
-                )
-        else:
-            with self._phase("decode", "dispatch"):
-                with self.pipe._mesh_scope():
-                    (self.kv_pages, tok, lengths, finished, recent,
-                     self.keys, toks, fin) = generate_lib.paged_fused_steps(
-                        self.pipe.params["llm"], self.cfg.llm, self.kv_pages,
-                        jnp.asarray(self.bt),
-                        jnp.asarray(self.tok),
-                        jnp.asarray(self.lengths),
-                        jnp.asarray(self.finished),
-                        jnp.asarray(self.recent),
-                        self.keys,
-                        jnp.asarray(self.temp),
-                        jnp.asarray(self.top_p),
-                        jnp.asarray(self.top_k),
-                        self.stop_sequences,
-                        chunk=self.chunk, k_steps=k_steps, eos=eos,
-                        attn_impl=self.cfg.attn_impl, compute_dtype=dtype,
-                    )
-            toks, fin = self._read_chunk(
-                tok, lengths, finished, recent, toks, fin
-            )
-            dt = time.monotonic() - t0
-            with self._phase("emit"):
-                dev_us = self._profile_dispatch_end(sampled, "fused", t0_ns)
-                self._finish_megastep(
-                    "fused", len(live), live, toks, t0_ns, dt, k_steps,
-                    device_us=dev_us,
-                )
-        self._occupancy_gauge()
-
-    def _finish_megastep(
-        self, kind: str, rows: int, live: list[int], toks, t0_ns, dt,
-        k_steps: int, n_new=None, device_us=None,
-    ) -> None:
-        """Post-megastep accounting: the dispatch-level numbers land
-        ONCE (one device dispatch happened — dispatches_total, the
-        rows histogram, the watchdog beat, one timeline record), then
-        the harvested outputs are processed as K sequential LOGICAL
-        steps — logical step j owns columns [j*width, (j+1)*width) of
-        `toks` — so the per-step billing (`_advance`, cost ledger,
-        TPOT, the decode_steps family, the journal's step clock) keeps
-        its K=1 meaning exactly. A row the host finishes at logical
-        step j (EOS, max_new, stop string) drops out of live_j for
-        j+1.. — its remaining device columns are frozen filler the
-        sequential path would never have dispatched, discarded here
-        the same way."""
-        self._count_dispatch(kind, rows, self.temp)
-        if self.watchdog is not None:
-            self.watchdog.beat()
-        width = (1 + self.speculate) if n_new is not None else self.chunk
-        total_accepted = 0
-        for j in range(k_steps):
-            self.chunks_run += 1
-            self.metrics.inc("chunks")
-            live_j = [s for s in live if self.slots[s] is not None]
-            useful = 0
-            emitted = 0
-            for s, tokens in generate_lib.unpack_ragged_rows(
-                toks[:, j * width:(j + 1) * width], live_j
-            ).items():
-                req = self.slots[s]
-                if req is None:
-                    continue
-                if n_new is not None:
-                    tokens = tokens[: int(n_new[s, j])]
-                    emitted += len(tokens)
-                    self.metrics.observe(
-                        "accepted_tokens_per_step", len(tokens),
-                        buckets=SPEC_ACCEPT_BUCKETS,
-                    )
-                req.trace.add_complete(
-                    "decode_chunk", t0_ns, int(dt * 1e9),
-                    chunk=self.chunks_run, slot=s,
-                )
-                req.cost_decode_steps += width
-                self._accrue_page_seconds(s)
-                useful += self._advance(s, tokens)
-            if live_j and n_new is not None and self.anomaly is not None:
-                self.anomaly.observe_spec_accept(
-                    emitted / len(live_j), step=self.chunks_run,
-                )
-            if live_j:
-                per_tok = (
-                    emitted / len(live_j) if n_new is not None
-                    else self.chunk
-                )
-                self.metrics.observe(
-                    "time_per_output_token_seconds",
-                    (dt / k_steps) / max(1.0, per_tok),
-                )
-                total = self.num_slots * width
-                self.metrics.inc("decode_steps_total", total)
-                self.metrics.inc("decode_steps_useful", useful)
-                self.metrics.inc("decode_steps_wasted", total - useful)
-            step_accepted = emitted if n_new is not None else useful
-            total_accepted += step_accepted
-            # The journal's step clock advances per LOGICAL step — K
-            # entries per megastep, each stamped with (fused_k,
-            # fused_j) so replay can reconstruct the fuse plan and a
-            # K=1 replay of a fused capture diverges on the `dispatch`
-            # field by name instead of silently.
-            self.steps_run += 1
-            if self.journal is not None:
-                self.journal.append(journal_lib.build_journal_event(
-                    kind="step", step=self.steps_run, dispatch=kind,
-                    rows=rows, live_slots=len(live_j),
-                    accepted_tokens=step_accepted,
-                    free_pages=self.allocator.num_free,
-                    fused_k=k_steps, fused_j=j,
-                ))
-        live_now = sum(
-            1 for r in self.slots if r is not None and r.activated
-        )
-        self.timeline.record(
-            dur_s=dt, kind=kind, rows=rows, live_slots=live_now,
-            accepted_tokens=total_accepted,
-            queue_depth=int(self.metrics.get("queue_depth")),
-            free_pages=self.allocator.num_free,
-            degraded_mode=int(self.metrics.get("degraded_mode")),
-            device_us=device_us,
-        )
-
-    def _build_draft_ctx(self, live: list[int]):
-        """Right-aligned confirmed-stream windows for the device draft
-        chain — `_propose_drafts`'s context assembly MINUS the fed
-        token (the fused program shifts each step's fed token into the
-        window itself, so one upload serves all K logical steps).
-        Rebuilt from host truth before every megastep: the device's
-        in-scan context carry is deliberately NOT round-tripped back
-        (no new host-sync surface beyond the one harvest), and
-        rebuilding from the DEVICE-CONFIRMED stream — not the full
-        host `emitted`, which runs ahead during eviction replay — is
-        what keeps replayed proposals identical to the original run's.
-        Returns (ctx [S, window] int32, ctx_len [S] int32)."""
-        CW = self.drafter.window
-        ctx = np.zeros((self.num_slots, CW), np.int32)
-        clen = np.zeros((self.num_slots,), np.int32)
-        for s in live:
-            req = self.slots[s]
-            confirmed = max(0, int(self.lengths[s]) - req.length)
-            prompt = (
-                req.cache_tokens if req.cache_tokens is not None
-                else np.zeros((0,), np.int64)
-            )
-            reply = req.emitted[:confirmed]
-            keep = max(0, CW - len(reply))
-            prompt = (
-                prompt[max(0, len(prompt) - keep):] if keep
-                else prompt[:0]
-            )
-            reply = reply[max(0, len(reply) - CW):]
-            tail = np.concatenate([
-                np.asarray(prompt, np.int64),
-                np.asarray(reply, np.int64),
-            ])[-CW:].astype(np.int32)
-            if len(tail):
-                ctx[s, CW - len(tail):] = tail
-            clen[s] = len(tail)
-        return ctx, clen
 
     def _propose_drafts(self, live: list[int]):
         """Host-side draft proposal for every live slot: the drafter

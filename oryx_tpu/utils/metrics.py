@@ -430,8 +430,8 @@ PREFILL_CHUNK_BUCKETS = (1.0, 2.0, 4.0, 8.0, 16.0, 32.0, 64.0, 128.0,
 # Valid query rows per device dispatch (the occupancy of the packed
 # ragged buffer, or the live-row count of a split prefill/decode
 # dispatch): powers of two up to the largest plausible packed buffer
-# (num_slots + prefill lanes). A fused path that is working shows this
-# distribution shifted right vs the split path at equal load —
+# (num_slots + prefill lanes). A ragged path that is working shows
+# this distribution shifted right vs the split path at equal load —
 # prefill and decode rows ride the SAME dispatch.
 DISPATCH_ROWS_BUCKETS = (1.0, 2.0, 4.0, 8.0, 16.0, 32.0, 64.0, 128.0,
                          256.0, 512.0, 1024.0, 2048.0, 4096.0)
@@ -610,15 +610,11 @@ JOURNAL_EVENT_KEYS = (
     "host_reload_pages",    # host-tier pages re-uploaded for the splice
     "victim_request_id",    # evict: whose pages were taken
     # -- step -------------------------------------------------------------
-    "dispatch",             # prefill | decode | ragged | spec | fused
-                            # | fused_spec
+    "dispatch",             # prefill | decode | ragged | spec | block
     "rows",
     "live_slots",
     "accepted_tokens",
     "free_pages",
-    "fused_k",              # megastep: logical steps in this dispatch
-    "fused_j",              # megastep: this entry's index within it
-                            # (0..fused_k-1; absent on K=1 dispatches)
     # -- degraded / fault / restart ---------------------------------------
     "mode",                 # degraded-mode ladder level
     "site",                 # fault-point site name

@@ -399,24 +399,6 @@ def compare_paged(
             sb, sc if sc is not None else 0.0,
             "higher", TOL_DETERMINISTIC,
         ))
-    # Fused megastep cell: dispatches per pure-decode token must stay
-    # at its K-fold cut (lower is better; the bench itself hard-gates
-    # the 1/K*(1+eps) bound, this row catches drift inside it), and
-    # mode-wide byte parity is part of the cell's identity claim.
-    fb = _dig(baseline, "fused.fused.pure_decode.dispatches_per_token")
-    fc = _dig(current, "fused.fused.pure_decode.dispatches_per_token")
-    if fb is not None:
-        rows.append(Row(
-            "paged_attention fused dispatches_per_token (pure decode)",
-            fb, fc if fc is not None else 1.0,
-            "lower", TOL_DETERMINISTIC,
-        ))
-        rows.append(Row(
-            "paged_attention fused replies_bit_identical (all modes)",
-            _dig(baseline, "fused.replies_bit_identical"),
-            _dig(current, "fused.replies_bit_identical"),
-            "equal", TOL_EXACT,
-        ))
     # Informational only: wall-clock ratio of the two engines (shared
     # CI cores make this a weather report, not a gate).
     def mean_ratio(rep: dict) -> float | None:

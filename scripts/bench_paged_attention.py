@@ -24,15 +24,6 @@ engine, gating accepted-tokens/step > 1.5, dispatches/step still
 exactly 1.0 (kind="spec" only), zero recompiles after warmup, and
 byte parity — "speculation changes nothing but speed", measured.
 
-A FUSED MEGASTEP cell (--fuse-steps K; docs/DESIGN.md "Fused
-multi-step decode") measures DISPATCHES PER TOKEN over the pure-decode
-phase — plain ragged vs K=1 speculation vs the K-step megastep vs the
-megastep with device-side draft speculation — from each run's decision
-journal (pure-decode step entries only, so admission dispatches don't
-launder the decode economics). Gates: the fused engine pays at most
-1/K of plain ragged's dispatches-per-token (x 1+eps for ladder tails),
-byte parity across EVERY mode, and zero recompiles after warmup.
-
 Writes BENCH_paged_attention.json. On a CPU host the numbers are a
 labeled cpu_proxy (structure claims — dispatch counts, recompiles,
 parity — are backend-independent; steps/s is not).
@@ -75,9 +66,7 @@ def _prompts(batch: int, context: int) -> list[str]:
     return out
 
 
-DISPATCH_KINDS = (
-    "ragged", "spec", "fused", "fused_spec", "prefill", "decode",
-)
+DISPATCH_KINDS = ("ragged", "spec", "prefill", "decode")
 
 
 def _counter(metrics, kind: str) -> float:
@@ -97,63 +86,28 @@ def _accept_state(metrics) -> tuple[float, float]:
     return (0.0, 0.0) if h is None else (h[3], float(h[2]))
 
 
-def _pure_decode_stats(entries, after_step, lane_width):
-    """(dispatches, tokens) over the PURE-DECODE step entries past the
-    warmup watermark: rows == live_slots * lane_width means zero
-    prefill lanes rode the dispatch, so admission traffic can't launder
-    the decode economics; a megastep's K entries count as ONE dispatch
-    (fused_j == 0) while every entry's accepted tokens count."""
-    dispatches = 0
-    tokens = 0
-    for e in entries:
-        if e.get("kind") != "step" or (e.get("step") or 0) <= after_step:
-            continue
-        live = e.get("live_slots") or 0
-        if not live or e.get("rows") != live * lane_width:
-            continue
-        if e.get("fused_j") in (None, 0):
-            dispatches += 1
-        tokens += e.get("accepted_tokens") or 0
-    return dispatches, tokens
-
-
 def _run_mode(pipe, prompts, max_new, *, ragged, prefill_chunk,
-              num_slots, watch, speculate=0, fuse_steps=1,
-              drafter=None):
+              num_slots, watch, speculate=0):
     """One measured cell: fresh scheduler, warmup workload (compiles
     the shape classes), then the measured burst under the recompile
     watchdog. Returns (result dict, replies)."""
     from oryx_tpu.analysis.sanitizers import recompile_watchdog
-    from oryx_tpu.serve import journal as journal_lib
     from oryx_tpu.serve.scheduler import ContinuousScheduler
     from oryx_tpu.utils.metrics import ServingMetrics
 
     metrics = ServingMetrics()
-    journal = journal_lib.DecisionJournal(None, keep=65536)
     sched = ContinuousScheduler(
         pipe, num_slots=num_slots, page_size=16, chunk=4, max_ctx=1024,
         metrics=metrics, autostart=False, prefill_chunk=prefill_chunk,
-        ragged=ragged, speculate=speculate, fuse_steps=fuse_steps,
-        journal=journal, **({"drafter": drafter} if drafter else {}),
+        ragged=ragged, speculate=speculate,
     )
     sched.start()
     # Warmup: one short and one long admission so both shape classes
-    # (prefill lanes present / absent) and the COW path compile; a
-    # megastep engine additionally needs one request with K windows of
-    # budget so its fused rung compiles before the measured burst.
-    warm = [("warm up the compiler", 5), (prompts[0], 2)]
-    if fuse_steps != 1:
-        win = (1 + speculate) if speculate else 4
-        warm.append(("warm the fused megastep rung", fuse_steps * win))
-    for q, cap in warm:
+    # (prefill lanes present / absent) and the COW path compile.
+    for q, cap in (("warm up the compiler", 5), (prompts[0], 2)):
         sched.submit({"question": q}, cap).result(timeout=600)
     stats = None
     t0 = time.monotonic()
-    steps0 = max(
-        (e.get("step") or 0 for e in journal.snapshot()
-         if e.get("kind") == "step"),
-        default=0,
-    )
     dsteps0 = metrics.get("decode_steps_total")
     chunks0 = metrics.get("chunks")
     disp0 = {k: _counter(metrics, k) for k in DISPATCH_KINDS}
@@ -182,11 +136,7 @@ def _run_mode(pipe, prompts, max_new, *, ragged, prefill_chunk,
         (acc1[0] - acc0[0]) / (acc1[1] - acc0[1])
         if acc1[1] > acc0[1] else None
     )
-    pd_disp, pd_tokens = _pure_decode_stats(
-        journal.snapshot(), steps0, 1 + speculate
-    )
     sched.close()
-    journal.close()
     total_disp = sum(disp.values())
     out = {
         "wall_s": round(wall, 4),
@@ -200,19 +150,10 @@ def _run_mode(pipe, prompts, max_new, *, ragged, prefill_chunk,
         "new_tokens": new_tokens,
         "dispatches": disp,
         "dispatches_per_step": round(total_disp / max(beats, 1), 4),
-        "pure_decode": {
-            "dispatches": pd_disp,
-            "tokens": pd_tokens,
-            "dispatches_per_token": (
-                round(pd_disp / pd_tokens, 4) if pd_tokens else None
-            ),
-        },
         "recompiles_after_warmup": (
             dict(stats.counts) if stats is not None else None
         ),
     }
-    if fuse_steps != 1:
-        out["fuse_steps"] = fuse_steps
     if speculate:
         out["speculate"] = speculate
         out["accepted_tokens_per_step"] = (
@@ -235,12 +176,6 @@ def run(argv=None) -> dict:
         "--speculate", type=int, default=6, metavar="K",
         help="draft depth for the speculation cell (repetitive-text "
         "fixture, spec engine vs plain ragged; 0 skips the cell)",
-    )
-    ap.add_argument(
-        "--fuse-steps", type=int, default=4, metavar="K",
-        help="megastep depth for the fused-decode cell (dispatches "
-        "per pure-decode token, fused vs spec vs plain ragged; "
-        "1 skips the cell)",
     )
     ap.add_argument(
         "--smoke", action="store_true",
@@ -366,84 +301,6 @@ def run(argv=None) -> dict:
                 "speculation cell: recompiled after warmup: "
                 f"{spec['recompiles_after_warmup']}"
             )
-    fused_cell = None
-    if args.fuse_steps and args.fuse_steps > 1:
-        # Fused megastep cell: dispatches per PURE-DECODE token across
-        # the four engine modes on one fixture. The structural claim is
-        # the K-fold dispatch cut — the megastep pays 1 dispatch where
-        # the sequential engine pays K — with byte parity everywhere
-        # and zero recompiles after warmup (each rung is one static
-        # shape class). eps absorbs the K=1 ladder tail (a remaining
-        # budget under K windows falls back to sequential dispatches).
-        from oryx_tpu.models import generate as generate_lib
-
-        # Solo resident, budget an exact multiple of K dispatch windows:
-        # the pure-decode phase is megasteps end to end, so the measured
-        # ratio IS the structural 1/K claim (a second resident staggers
-        # admission and drags min-budget K=1 tails into the mean — the
-        # engine-level mixes live in tests/test_fused_decode.py).
-        K = args.fuse_steps
-        rep = ("the quick brown fox jumps over the lazy dog " * 3).strip()
-        prompts = [rep]
-        fused_new = 12 * K
-        mk_drafter = lambda: generate_lib.NeuralDrafter.init(  # noqa: E731
-            cfg.llm.vocab_size, dim=8, window=8, seed=0
-        )
-        common = dict(
-            ragged=True, prefill_chunk=32, num_slots=2, watch=True,
-        )
-        plain, r_plain = _run_mode(pipe, prompts, fused_new, **common)
-        spec1, r_spec1 = _run_mode(
-            pipe, prompts, fused_new, speculate=args.speculate or 6,
-            **common,
-        )
-        fused, r_fused = _run_mode(
-            pipe, prompts, fused_new, fuse_steps=K, **common,
-        )
-        fspec, r_fspec = _run_mode(
-            pipe, prompts, fused_new, fuse_steps=K,
-            speculate=args.speculate or 6, drafter=mk_drafter(),
-            **common,
-        )
-        fused_cell = {
-            "prompts": len(prompts), "max_new": fused_new,
-            "fuse_steps": K,
-            "plain_ragged": plain, "spec": spec1, "fused": fused,
-            "fused_spec": fspec,
-            "replies_bit_identical": (
-                r_plain == r_spec1 == r_fused == r_fspec
-            ),
-        }
-        if not fused_cell["replies_bit_identical"]:
-            failures.append(
-                "fused cell: replies differ across engine modes"
-            )
-        if not fused["dispatches"]["fused"]:
-            failures.append("fused cell: no megastep dispatches paid")
-        if not fspec["dispatches"]["fused_spec"]:
-            failures.append(
-                "fused cell: no speculative megastep dispatches paid"
-            )
-        plain_pt = plain["pure_decode"]["dispatches_per_token"]
-        fused_pt = fused["pure_decode"]["dispatches_per_token"]
-        eps = 0.15
-        if plain_pt is None or fused_pt is None:
-            failures.append(
-                "fused cell: no pure-decode phase measured "
-                f"(plain={plain_pt} fused={fused_pt})"
-            )
-        elif fused_pt > plain_pt / K * (1 + eps):
-            failures.append(
-                f"fused cell: {fused_pt} dispatches/token vs gate "
-                f"{plain_pt}/{K}*(1+{eps}) = "
-                f"{round(plain_pt / K * (1 + eps), 4)}"
-            )
-        for mode, res in (("fused", fused), ("fused_spec", fspec)):
-            if res["recompiles_after_warmup"]:
-                failures.append(
-                    f"fused cell {mode}: recompiled after warmup: "
-                    f"{res['recompiles_after_warmup']}"
-                )
     out = {
         "bench": "paged_attention_ragged",
         "backend": backend if backend == "tpu" else "cpu_proxy",
@@ -453,7 +310,6 @@ def run(argv=None) -> dict:
         },
         "cells": cells,
         "speculation": spec_cell,
-        "fused": fused_cell,
         "gates": {"failures": failures, "passed": not failures},
     }
     if args.json:

@@ -10,15 +10,10 @@
 set -u
 cd "$(dirname "$0")/.."
 
-# 700 = the 680 recorded at PR 18 plus the fused-megastep suite added
-# in PR 19 (tests/test_fused_decode.py: fused-vs-K=1 byte parity
-# across greedy/sampled/stop-string/eviction/int8/tp=2/speculative
-# runs, per-logical-step billing, adaptive-K zero-recompile warmup,
-# K-entry journaling with byte-exact fused replay, the journaled
-# fuse-plan on auto replay, K=1-replay first-divergence naming, and
-# the NeuralDrafter host/device bit-identity + checkpoint contracts;
-# ~731 observed), with headroom for load-dependent flakes
-# (bench-supervisor probes on one CPU core).
+# 700 = the count of PR 19's day (~731 observed then) with headroom for
+# load-dependent flakes; the suite has grown since, and what a PR is
+# held to now is the driver's `floor` less `allowance` on the lines of
+# PERF_LEDGER.jsonl (1,732 less 17 at PR 47), not this number.
 BASELINE_DOTS=${ORYX_TIER1_BASELINE:-700}
 
 # --- oryxlint static analysis (fast, jax-free: fail before pytest) ----------

@@ -68,7 +68,7 @@ VOLATILE_FIELDS = ("seq", "ts_unix_s")
 GEOMETRY_KEYS = (
     "num_slots", "page_size", "chunk", "max_ctx", "num_pages", "seed",
     "prefill_chunk", "prefix_cache", "ragged", "speculate", "kv_dtype",
-    "host_cache_bytes", "degraded_clamp_tokens", "fuse_steps",
+    "host_cache_bytes", "degraded_clamp_tokens",
 )
 OVERRIDE_KEYS = GEOMETRY_KEYS + ("faults_spec",)
 
@@ -200,6 +200,19 @@ def run_replay(header: dict[str, Any], entries: list[dict[str, Any]], *,
     from oryx_tpu.serve.scheduler import ContinuousScheduler
 
     cfg = dict(header.get("config") or {})
+    # A journal is input from outside the program: one recorded under
+    # the K-step megastep (a header with fuse_steps other than 1, or
+    # step entries stamped fused_k) would replay into a divergence with
+    # no name, so it is refused by name.
+    if cfg.get("fuse_steps", 1) != 1 or any(
+        "fused_k" in e for e in entries if e.get("kind") == "step"
+    ):
+        raise ValueError(
+            "this journal was recorded with --fuse-steps "
+            f"(fuse_steps={cfg.get('fuse_steps', 1)!r} in its header, or "
+            "fused_k on its step entries), which this tree no longer "
+            "has; replay it on the tree that recorded it"
+        )
     if overrides:
         cfg.update(overrides)
     plan, skipped = plan_feed(entries)
@@ -259,19 +272,6 @@ def run_replay(header: dict[str, Any], entries: list[dict[str, Any]], *,
                 )
 
     sched.replay_feeder = feeder
-    # Adaptive fused-K reads queue depth, which is wall-clock-coupled:
-    # the journal records the K actually chosen at each megastep
-    # (fused_k on the fused_j==0 step entry), and replay re-applies that
-    # plan instead of re-deriving it. A fuse_steps override drops the
-    # plan — the what-if runs the overridden policy from scratch.
-    if not (overrides and "fuse_steps" in overrides):
-        plan_k = {
-            int(e["step"]) - 1: int(e["fused_k"])
-            for e in entries
-            if e.get("kind") == "step" and e.get("fused_j") == 0
-        }
-        if plan_k:
-            sched.replay_fuse_plan = plan_k
     sched.start()
     # The supervisor is part of the recorded machine: a journaled
     # engine_crash fault must revive and restart-replay exactly as the

@@ -46,6 +46,8 @@ jax.config.update("jax_default_matmul_precision", "highest")
 # that legitimately vary with test parametrization.
 # ---------------------------------------------------------------------------
 
+import time  # noqa: E402
+
 import pytest  # noqa: E402  (after the platform-pinning prologue)
 
 _WATCHDOG_FILES = ("test_paged_decode.py", "test_prefix_cache.py")
@@ -116,3 +118,67 @@ def _opt_in_lock_sanitizer(request):
             "race detector recorded violations during this test: "
             f"{race_violations()}"
         )
+
+
+# ---------------------------------------------------------------------------
+# What a cache kind's engine does not refuse, it serves
+# (`scheduler._CACHE_KINDS`): the one body of
+# `test_the_engine_serves_what_it_does_not_refuse` in the block, latent,
+# recurrent and window engines' tests, each on its own tiny fixture.
+# ---------------------------------------------------------------------------
+
+
+@pytest.fixture
+def serves_like_the_default():
+    def quiesce(sched, timeout=120.0):
+        end = time.monotonic() + timeout
+        while time.monotonic() < end:
+            if sched.queue_len() == 0 and all(r is None for r in sched.slots):
+                return
+            time.sleep(0.02)
+        raise TimeoutError("the engine did not come to rest")
+
+    def check(build, option, question, cap):
+        """One greedy request through `build(**option)` is, byte for
+        byte, what `build()` serves, and the option did its work: the
+        host tier gave the prompt's pages back, the auditor's replay
+        passed, the probe took a sample."""
+        def ask(sched):
+            return sched.submit({"question": question}, cap, None).result(
+                timeout=600)
+
+        plain = build()
+        plain.start()
+        want = ask(plain)
+        plain.close()
+        sched = build(**option)
+        sched.start()
+        try:
+            assert ask(sched) == want
+            quiesce(sched)
+            reg = sched.metrics.registry
+            if option.get("host_cache_bytes"):
+                cache = sched.prefix_cache
+                cache.evict(cache.evictable_pages())
+                assert cache.spilled_pages > 0 and cache.pages == 0
+                assert ask(sched) == want
+                assert reg.get(
+                    "oryx_cache_reload_hit_total", raw_name=True) == 1
+                quiesce(sched)
+                sched._check_pool_invariant()
+            if option.get("audit_sample_every"):
+                end = time.monotonic() + 300
+                while time.monotonic() < end and not sched.auditor.to_dict()[
+                        "total"]:
+                    time.sleep(0.05)
+                assert sched.auditor.to_dict()["verdicts"] == {
+                    "pass": 1, "drift": 0, "fail": 0}
+            if option.get("numerics_every"):
+                assert reg.get(
+                    "oryx_numerics_samples_total", raw_name=True) >= 1
+                assert reg.get(
+                    "oryx_numerics_logits_finite_frac", raw_name=True) == 1
+        finally:
+            sched.close()
+
+    return check

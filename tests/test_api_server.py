@@ -672,6 +672,24 @@ def test_cli_refuses_the_window_engines_flags(flag):
     assert e.value.code == 2
 
 
+@pytest.mark.parametrize("flag", [
+    ["--fuse-steps", "4"], ["--fuse-steps", "auto"],
+])
+def test_cli_refuses_removed_flags(flag, capsys):
+    """The K-step megastep went with its flag (PR 48): the spelling is
+    an error, not accepted and ignored. `--decode-chunk` sets how many
+    forwards a dispatch runs."""
+    with pytest.raises(SystemExit) as e:
+        api_server.main(["--model-path", "x", "--ragged", *flag])
+    assert e.value.code == 2
+    assert "unrecognized arguments: --fuse-steps" in capsys.readouterr().err
+
+
+def test_build_server_refuses_the_removed_keyword():
+    with pytest.raises(TypeError, match="fuse_steps"):
+        api_server.build_server(None, port=0, fuse_steps=2)
+
+
 def test_continuous_request_id_and_debug_endpoints(continuous_server):
     """Acceptance: a request through --engine continuous yields (a) an
     X-Request-Id header, (b) a /debug/trace?id= span tree covering

@@ -243,6 +243,21 @@ def test_block_mode_refuses_what_is_not_built(pipe, kw, match):
         _sched(pipe, **kw)
 
 
+@pytest.mark.parametrize("option", [{"host_cache_bytes": 1 << 24}])
+def test_the_engine_serves_what_it_does_not_refuse(
+        pipe, option, serves_like_the_default):
+    """Block mode keeps the K/V pool and the prefix cache of the split
+    engine, so the host tier moves its pages as it moves theirs. The
+    weights are four times the init's: at 0.02 a reply is one token
+    repeated whatever the pages hold."""
+    loud = OryxInference(IdTokenizer(), jax.tree.map(
+        lambda x: x * 4 if x.ndim >= 2 else x, pipe.params), pipe.cfg,
+        template="plain")
+    serves_like_the_default(
+        lambda **kw: _sched(loud, **kw), option,
+        "the same opening words, and more of them", 8)
+
+
 def test_block_mode_refuses_a_mesh_and_too_many_steps(pipe):
     class Meshed:
         cfg, params, mesh = pipe.cfg, pipe.params, object()
@@ -254,12 +269,10 @@ def test_block_mode_refuses_a_mesh_and_too_many_steps(pipe):
         _sched(many)
 
 
-def test_fuse_steps_and_speculate_without_ragged_keep_their_own_errors(pipe):
-    """The scheduler's older checks still speak first."""
-    with pytest.raises(ValueError, match="ragged"):
+def test_speculate_without_ragged_keeps_its_own_error(pipe):
+    """The scheduler's older check still speaks first."""
+    with pytest.raises(ValueError, match="speculate requires ragged"):
         _sched(pipe, speculate=2)
-    with pytest.raises(ValueError, match="ragged"):
-        _sched(pipe, fuse_steps=4)
 
 
 def test_the_pipes_own_loops_are_refused(pipe):

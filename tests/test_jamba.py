@@ -279,9 +279,9 @@ QUESTIONS = [("hello there, how are you doing today my friend?", 9),
 
 
 def _engine(pipe, metrics=None, **kw):
-    return ContinuousScheduler(
-        pipe, num_slots=2, page_size=PS, max_ctx=256, prefill_chunk=16,
-        autostart=False, metrics=metrics, **kw)
+    return ContinuousScheduler(pipe, **{
+        "num_slots": 2, "page_size": PS, "max_ctx": 256, "prefill_chunk": 16,
+        "autostart": False, "metrics": metrics, **kw})
 
 
 def test_engine_serves_four_requests_on_two_slots_with_the_counters(pipe):
@@ -401,12 +401,24 @@ def test_eviction_and_replay_reproduce_the_stream(pipe):
 
 @pytest.mark.parametrize("kw", [
     {"ragged": True}, {"ragged": True, "speculate": 2},
-    {"ragged": True, "fuse_steps": 2}, {"kv_dtype": "int8"},
+    {"kv_dtype": "int8"},
     {"host_cache_bytes": 1 << 20}, {"audit_sample_every": 4},
 ])
 def test_the_engine_refuses_what_is_not_built_for_a_state(pipe, kw):
     with pytest.raises(ValueError, match=REFUSAL):
         _engine(pipe, **kw)
+
+
+@pytest.mark.parametrize("option", [
+    {"numerics_every": 1}, {"prefill_chunk": None},
+])
+def test_the_engine_serves_what_it_does_not_refuse(
+        pipe, option, serves_like_the_default):
+    """The probe reads the decode chunk's logits, whatever made them;
+    an unchunked prefill is one `paged_prefill` from position 0, which
+    zeroes the slot's state as a first chunk does."""
+    serves_like_the_default(
+        lambda **kw: _engine(pipe, **kw), option, QUESTIONS[0][0], 8)
 
 
 @pytest.mark.parametrize("bad", [

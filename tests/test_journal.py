@@ -459,3 +459,60 @@ def test_journal_seq_joins_wide_events(pipe, tmp_path):
     ev = rlog.snapshot(1)[0]
     assert ev["journal_seq"] == submit["seq"]
     assert ev["request_id"] == submit["request_id"]
+
+
+# ---------------------------------------------------------------------------
+# Journals from a tree that still had --fuse-steps (before PR 48)
+# ---------------------------------------------------------------------------
+
+
+@pytest.fixture(scope="module")
+def default_journal(pipe, tmp_path_factory):
+    path, _ = _capture(
+        pipe, tmp_path_factory.mktemp("default_journal"),
+        [("hello there", 6, None), ("what now then", 5, None)],
+        num_slots=2, page_size=16, chunk=4, max_ctx=512,
+    )
+    return journal_lib.read_journal(path)
+
+
+def _with_header(header, **config):
+    return {**header, "config": {**header["config"], **config}}
+
+
+def _stamp_first_step(entries):
+    steps = [i for i, e in enumerate(entries) if e["kind"] == "step"]
+    out = [dict(e) for e in entries]
+    out[steps[0]].update(fused_k=4, fused_j=0)
+    return out
+
+
+@pytest.mark.parametrize("edit_header, edit_entries", [
+    (lambda h: _with_header(h, fuse_steps=4), list),
+    (lambda h: _with_header(h, fuse_steps="auto"), list),
+    (lambda h: _with_header(h, fuse_steps=1), _stamp_first_step),
+], ids=["header-4", "header-auto", "entry-fused_k"])
+def test_replay_refuses_a_journal_recorded_with_fuse_steps(
+        pipe, default_journal, edit_header, edit_entries):
+    """A journal is input from outside the program: one recorded under
+    the K-step megastep is refused by name, never replayed into a
+    divergence nobody can explain."""
+    header, entries = default_journal
+    with pytest.raises(ValueError, match="--fuse-steps.*no longer has"):
+        rj.run_replay(
+            edit_header(header), edit_entries(entries), pipe=pipe)
+
+
+def test_replay_of_a_header_that_says_fuse_steps_1(pipe, default_journal):
+    """Every journal the parent wrote by default says `fuse_steps: 1`
+    in its header: it replays byte-exact, and the key is not a
+    constructor argument any more."""
+    header, entries = default_journal
+    assert "fuse_steps" not in header["config"]
+    assert "fuse_steps" not in rj.GEOMETRY_KEYS
+    res = rj.run_replay(
+        _with_header(header, fuse_steps=1), entries, pipe=pipe,
+        timeout_s=300)
+    assert rj.first_divergence(entries, res["entries"]) is None
+    matched, total, bad = rj.reply_match(entries, res["entries"])
+    assert matched == total == 2, bad

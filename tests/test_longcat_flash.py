@@ -311,7 +311,6 @@ def test_build_server_serves_the_latent_model_over_http(pipe):
 @pytest.mark.parametrize("kw, names", [
     ({"ragged": True}, "ragged=True"),
     ({"ragged": True, "speculate": 2}, "ragged=True"),
-    ({"fuse_steps": 2, "ragged": False}, "fuse_steps"),
     ({"kv_dtype": "int8"}, "kv_dtype='int8'"),
 ])
 def test_scheduler_refuses_the_modes_a_latent_pool_is_not_built_for(
@@ -320,6 +319,24 @@ def test_scheduler_refuses_the_modes_a_latent_pool_is_not_built_for(
         ContinuousScheduler(pipe, num_slots=2, page_size=PS, max_ctx=256,
                             prefill_chunk=32, autostart=False, **kw)
     assert names in str(e.value)
+
+
+@pytest.mark.parametrize("option", [
+    {"host_cache_bytes": 1 << 24}, {"audit_sample_every": 1},
+    {"numerics_every": 1}, {"prefill_chunk": None},
+])
+def test_the_engine_serves_what_it_does_not_refuse(
+        pipe, option, serves_like_the_default):
+    """A latent pool's page axis is where a K/V pool's is, so the host
+    tier moves its pages; the auditor's one-token step and an unchunked
+    prefill read it through `qwen2.forward` as the two programs do."""
+    def build(**kw):
+        return ContinuousScheduler(pipe, **{
+            "num_slots": 2, "page_size": PS, "max_ctx": 256,
+            "prefill_chunk": 32, "autostart": False, **kw})
+
+    serves_like_the_default(
+        build, option, "a tool said: " + "x, y and z; " * 4, 6)
 
 
 def test_sharded_engine_refuses_a_latent_model(pipe):

@@ -264,7 +264,7 @@ def test_out_of_pages_names_the_plane():
 
 @pytest.mark.parametrize("kw", [
     {"ragged": True}, {"ragged": True, "speculate": 2},
-    {"fuse_steps": 2}, {"kv_dtype": "int8"},
+    {"kv_dtype": "int8"},
     {"host_cache_bytes": 1 << 20}, {"audit_sample_every": 4},
     {"prefill_chunk": None},
 ])
@@ -273,3 +273,13 @@ def test_the_engine_refuses_what_is_not_built_for_two_planes(pipe, kw):
         ContinuousScheduler(pipe, **{
             "num_slots": 2, "page_size": PS, "max_ctx": 256,
             "prefill_chunk": PF, "autostart": False, **kw})
+
+
+@pytest.mark.parametrize("option", [{"numerics_every": 1}])
+def test_the_engine_serves_what_it_does_not_refuse(
+        pipe, option, serves_like_the_default):
+    """The probe reads the decode chunk's logits, whatever made them: a
+    reply long enough that the window plane gives pages back."""
+    serves_like_the_default(
+        lambda **kw: _engine(pipe, **kw), option,
+        "a question of some length, longer than a window", 12)
