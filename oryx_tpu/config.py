@@ -47,6 +47,16 @@ def unsupported_for_window(mode: str) -> str:
     )
 
 
+def unsupported_for_indexer(mode: str) -> str:
+    """The one refusal of a mode that learned sparse attention
+    (`LLMConfig.index_topk` > 0) is not built beside."""
+    return (
+        f"learned sparse attention (index_topk > 0): {mode} is not built "
+        "beside an indexer; it selects rows of a latent (MLA) pool in "
+        "the single latent block with unscaled interleaved RoPE only"
+    )
+
+
 @dataclass(frozen=True)
 class LLMConfig:
     """Qwen2/Yi-class decoder geometry.
@@ -185,6 +195,37 @@ class LLMConfig:
     # attention, the expert layer's own input) or "layer_input" (the
     # residual stream at the layer's input, before its norm).
     router_input: str = "post_attn"
+    # How the router turns its logits into probabilities: "softmax" over
+    # the experts, or "sigmoid" of each logit on its own (the
+    # DeepSeek-V3 lineage's `scoring_func`); selection, renormalising
+    # and the scaling factor are the same for both (`qwen2.moe_select`).
+    router_scoring: str = "softmax"
+    # Leading dense layers of a single-latent-block model
+    # (`first_k_dense_replace`): the first dense_layers of num_layers
+    # have ONE SwiGLU of intermediate_size in the expert layer's place
+    # (`qwen2._latent_block` with no experts); the rest are expert
+    # layers.
+    dense_layers: int = 0
+    # Learned sparse attention over the latent pool when index_topk > 0
+    # (`qwen2._mla`, ops/paged_kv.py): an indexer of index_heads
+    # heads of index_head_dim scores every visible key (a weighted sum
+    # of the heads' relu'd products, float32), each query attends the
+    # index_topk best alone, and a cached token keeps its index key
+    # [index_head_dim] in a plane of its own beside its latent. The
+    # first qk_rope_head_dim columns of an index head are roped.
+    index_heads: int = 0
+    index_head_dim: int = 0
+    index_topk: int = 0
+
+    @property
+    def indexed(self) -> bool:
+        """Attention reads the keys its indexer selected."""
+        return self.index_topk > 0
+
+    @property
+    def moe_layers(self) -> int:
+        """Layers with an expert layer: all but the leading dense ones."""
+        return self.num_layers - self.dense_layers
 
     @property
     def windowed(self) -> bool:
@@ -439,6 +480,45 @@ class LLMConfig:
                 "global_layer_period and rope_window_only need window "
                 "layers (sliding_window > 0)"
             )
+        if self.router_scoring not in ("softmax", "sigmoid") or (
+                self.router_scoring != "softmax" and not self.num_experts):
+            raise ValueError(
+                "router_scoring is 'softmax' or 'sigmoid' and needs an "
+                f"expert config, got {self.router_scoring!r}"
+            )
+        if self.dense_layers and not (
+                self.latent and not self.shortcut_double_layer
+                and 0 < self.dense_layers < self.num_layers
+                and self.intermediate_size > 0):
+            raise ValueError(
+                "dense_layers (leading dense layers) is built for the "
+                "single latent block (kv_lora_rank > 0, no "
+                "shortcut_double_layer) and needs 0 < dense_layers < "
+                f"num_layers, got {self.dense_layers} of {self.num_layers}"
+            )
+        if self.indexed or self.index_heads or self.index_head_dim:
+            for bad, mode in (
+                (not self.latent, "per-head K/V attention (the indexer "
+                 "reads the query latent and selects rows of the latent "
+                 "pool; kv_lora_rank = 0)"),
+                (self.shortcut_double_layer,
+                 "the shortcut-connected double layer"),
+                (self.yarn or self.llama4_scaling_beta,
+                 "RoPE scaling (rope_scaling_factor > 1, "
+                 "llama4_scaling_beta)"),
+                (not self.rope_interleaved,
+                 "RoPE over split halves (rope_interleaved=False)"),
+            ):
+                if bad:
+                    raise ValueError(unsupported_for_indexer(mode))
+            if not (self.index_topk > 0 and self.index_heads > 0
+                    and self.index_head_dim >= self.qk_rope_head_dim):
+                raise ValueError(
+                    "learned sparse attention needs index_topk, "
+                    "index_heads > 0 and index_head_dim >= "
+                    f"qk_rope_head_dim, got {self.index_topk}, "
+                    f"{self.index_heads}, {self.index_head_dim}"
+                )
         if not self.recurrent and not self.use_rope:
             raise ValueError(
                 "use_rope=False (attention without a position term) is "
@@ -1058,6 +1138,119 @@ def mistral4_tiny() -> OryxConfig:
             rope_mscale=1.0,
             rope_mscale_all_dim=1.0,
             llama4_scaling_beta=0.1,
+        ),
+        vision=None,
+        generation=GenerationConfig(eos_token_id=512),
+        dtype="float32",
+    )
+
+
+def glm5() -> OryxConfig:
+    """GLM-5's language model (zai-org, config.json, `model_type:
+    glm_moe_dsa`, 744B-A40B): 78 layers, the first 3 dense (SwiGLU of
+    12,288), the rest a shared SwiGLU of 2048 beside 256 routed experts
+    of 2048, 8 a token, scored by SIGMOID, selected with a bias,
+    renormalised and scaled by 2.5. Every layer's attention is latent
+    (latent 512 + a shared roped key of 64, 64 heads of 192 | 64 keys
+    and 256 values) and reads the 2,048 keys an indexer (32 heads of
+    128) selected a query. RoPE theta 1e6 over interleaved pairs, no
+    scaling. The multi-token-prediction module
+    (`num_nextn_predict_layers` 1) is not built: speculation is refused
+    over a latent pool. What the keys do not settle is the
+    configuration file's `assumed`."""
+    return OryxConfig(
+        llm=LLMConfig(
+            vocab_size=154880,
+            hidden_size=6144,
+            intermediate_size=12288,
+            num_layers=78,
+            num_heads=64,
+            num_kv_heads=64,  # published, unused: one shared latent a token
+            head_dim=64,  # published, unused: heads are 192 + 64 | 256
+            rope_theta=1_000_000.0,
+            rms_norm_eps=1e-5,
+            max_position_embeddings=202752,
+            attention_bias=False,
+            num_experts=256,
+            num_experts_per_tok=8,
+            moe_intermediate_size=2048,
+            norm_topk_prob=True,
+            kv_lora_rank=512,
+            q_lora_rank=2048,
+            qk_nope_head_dim=192,
+            qk_rope_head_dim=64,
+            v_head_dim=256,
+            rope_interleaved=True,
+            routed_scaling_factor=2.5,
+            router_bias=True,
+            router_scoring="sigmoid",
+            n_shared_experts=1,
+            dense_layers=3,
+            index_heads=32,
+            index_head_dim=128,
+            index_topk=2048,
+        ),
+        vision=None,
+        generation=GenerationConfig(eos_token_id=154820),
+    )
+
+
+def glm5_ep16() -> OryxConfig:
+    """One chip's share of GLM-5 where 16 chips share each layer: 16 of
+    the 256 routed experts held (the router keeps its 256 outputs and
+    its 8 a token), attention, the indexer and the shared expert whole,
+    an eighth of the vocabulary (rows 0..19359), ONE leading dense layer
+    (they count once; `num_layers` is the configuration file's). The
+    end-of-sequence id is the first row another chip holds, as in
+    `mistral_small_4_ep4`."""
+    cfg = glm5()
+    return dataclasses.replace(
+        cfg,
+        llm=dataclasses.replace(
+            cfg.llm, vocab_size=19360, experts_held=(0, 16),
+            dense_layers=1),
+        generation=dataclasses.replace(cfg.generation, eos_token_id=19360),
+    )
+
+
+def glm5_tiny() -> OryxConfig:
+    """Tiny learned-sparse-attention decoder for tests: 1 dense + 3
+    expert layers, an indexer of 3 heads of 24 that keeps the 16 best
+    keys, 8 routed experts (sigmoid, bias, scaled) of which 4 are held
+    and one shared; no two widths equal. A page of 8 divides the
+    top-k."""
+    return OryxConfig(
+        llm=LLMConfig(
+            vocab_size=512,
+            hidden_size=64,
+            intermediate_size=96,
+            num_layers=4,
+            num_heads=4,
+            num_kv_heads=4,
+            head_dim=24,
+            rope_theta=10000.0,
+            rms_norm_eps=1e-5,
+            max_position_embeddings=2048,
+            attention_bias=False,
+            num_experts=8,
+            num_experts_per_tok=2,
+            moe_intermediate_size=32,
+            norm_topk_prob=True,
+            kv_lora_rank=40,
+            q_lora_rank=48,
+            qk_nope_head_dim=16,
+            qk_rope_head_dim=8,
+            v_head_dim=20,
+            rope_interleaved=True,
+            routed_scaling_factor=2.5,
+            router_bias=True,
+            router_scoring="sigmoid",
+            experts_held=(2, 4),
+            n_shared_experts=1,
+            dense_layers=1,
+            index_heads=3,
+            index_head_dim=24,
+            index_topk=16,
         ),
         vision=None,
         generation=GenerationConfig(eos_token_id=512),

@@ -570,6 +570,10 @@ def paged_prefill(
         block_tables=block_tables, kv_lengths=lengths,
         attn_impl=attn_impl, compute_dtype=compute_dtype,
         return_routing=return_routing or held_stats, **state,
+        # The twin of a config with an indexer sees every layer's
+        # selection too, packed, under routing["selected"].
+        **({"return_selected": True} if return_routing and cfg.indexed
+           else {}),
         # A config with window layers projects the ONE row it samples
         # from: its head is 151,936 wide and a chunk 1,024 rows, whose
         # logits would be 0.9 GB of temporaries and as many operations
@@ -747,8 +751,10 @@ def paged_decode_chunk(
     and decode_kv_tokens counters read. return_routing=True (the static
     twin for the benchmark's comparison, as `paged_prefill`'s) appends
     every step's logits [S, chunk, V] and expert ids [chunk, L, S, K],
-    last. return_logits=True (the same twin for a config without
-    experts) appends the logits alone.
+    last (and behind them, for a config with an indexer, the rows each
+    layer selected [chunk, L, S, k]: ascending, the first
+    min(length, k) real). return_logits=True (the same twin for a config
+    without experts) appends the logits alone.
 
     A config with state-space layers (`cfg.recurrent`): lane s IS slot
     s, so its recurrent state is row s of the pool's per-slot planes; a
@@ -796,6 +802,8 @@ def paged_decode_chunk(
             attn_impl=attn_impl, compute_dtype=compute_dtype,
             **({"return_routing": True} if shared or return_routing
                else {}), **planes,
+            **({"return_selected": True} if return_routing and cfg.indexed
+               else {}),
         )
         if numerics:
             # Live-row logit probe on the logits the sampler is about
@@ -822,6 +830,8 @@ def paged_decode_chunk(
         ys = (tok, finished)
         if return_routing:
             ys = ys + (logits[:, 0], routing[0]["ids"])
+            if cfg.indexed:
+                ys = ys + (routing[0]["selected"],)
         if return_logits:
             ys = ys + (logits[:, 0],)
         return out, ys
@@ -837,6 +847,8 @@ def paged_decode_chunk(
     out = out + carry[6:]
     if return_routing:
         out = out + (jnp.moveaxis(seen[0], 0, 1), seen[1])
+        if cfg.indexed:  # every step's selection [chunk, L, S, k], last
+            out = out + (seen[2],)
     if return_logits:
         out = out + (jnp.moveaxis(seen[-1], 0, 1),)
     return out

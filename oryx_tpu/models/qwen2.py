@@ -21,6 +21,7 @@ transposes torch's [out, in].
 from __future__ import annotations
 
 import contextlib
+from functools import partial
 from typing import Any
 
 import jax
@@ -184,6 +185,8 @@ def _init_latent_params(cfg: LLMConfig, key: jax.Array, dtype) -> Params:
     dn, dr, dv = cfg.qk_nope_head_dim, cfg.qk_rope_head_dim, cfg.v_head_dim
     E, Z, Ie = cfg.num_experts, cfg.zero_experts, cfg.moe_intermediate_size
     count = cfg.held[1]
+    Ld = cfg.dense_layers
+    L = L - Ld  # the expert layers; the leading dense ones lie beside them
     keys = iter(jax.random.split(key, 40))
 
     def dense(shape, dt=dtype, scale=0.02):
@@ -191,7 +194,7 @@ def _init_latent_params(cfg: LLMConfig, key: jax.Array, dtype) -> Params:
             jax.random.normal(next(keys), shape, jnp.float32) * scale
         ).astype(dt)
 
-    def sublayer(ffn=True):
+    def sublayer(ffn=True, L=L):
         sub = {
             "input_norm": {"weight": jnp.ones((L, H), dtype)},
             "post_attn_norm": {"weight": jnp.ones((L, H), dtype)},
@@ -213,6 +216,19 @@ def _init_latent_params(cfg: LLMConfig, key: jax.Array, dtype) -> Params:
                 "down_proj": {"kernel": dense((L, I, H))},
             })
         return sub
+
+    def indexer(L):
+        # The indexer's leaves (`_index_inputs`): queries off the query
+        # latent, ONE key a token off the layer's normed input through a
+        # LayerNorm, the heads' weights off the same input.
+        Hi, Di = cfg.index_heads, cfg.index_head_dim
+        return {
+            "q_b": {"kernel": dense((L, Rq, Hi * Di))},
+            "k_proj": {"kernel": dense((L, H, Di))},
+            "k_norm": {"weight": jnp.ones((L, Di), dtype),
+                       "bias": dense((L, Di))},
+            "head_weights": {"kernel": dense((L, H, Hi))},
+        }
 
     if cfg.shortcut_double_layer:
         layers = {"sub0": sublayer(), "sub1": sublayer()}
@@ -237,15 +253,29 @@ def _init_latent_params(cfg: LLMConfig, key: jax.Array, dtype) -> Params:
         },
     })
     if cfg.router_bias:
+        # (a sigmoid's probabilities are near 1/2 each, not 1 / (E + Z).)
         layers["router"]["bias"] = dense(
-            (L, E + Z), jnp.float32, scale=0.2 / (E + Z)
+            (L, E + Z), jnp.float32,
+            scale=0.05 if cfg.router_scoring == "sigmoid" else 0.2 / (E + Z)
         )
-    return {
+    params = {
         "embed": {"weight": dense((cfg.vocab_size, H))},
         "layers": layers,
         "final_norm": {"weight": jnp.ones((H,), dtype)},
         "lm_head": {"kernel": dense((H, cfg.vocab_size))},
     }
+    if cfg.indexed or Ld:
+        # Keys of their own, off one the leaves above leave unused:
+        # every leaf above is what it was without these.
+        keys = iter(jax.random.split(next(keys), 40))
+        if cfg.indexed:
+            layers["indexer"] = indexer(L)
+        if Ld:
+            # [Ld, ...]: `_latent_block` with a dense FFN and no experts.
+            params["dense_layers"] = sublayer(L=Ld)
+            if cfg.indexed:
+                params["dense_layers"]["indexer"] = indexer(Ld)
+    return params
 
 
 # ---------------------------------------------------------------------------
@@ -348,10 +378,17 @@ def init_paged_kv_cache(
 
         if kv_dtype not in (None, "bf16", "fp"):
             raise ValueError(unsupported_for_latent(f"kv_dtype={kv_dtype!r}"))
-        return {paged_kv.LATENT: jnp.zeros(
+        pool = {paged_kv.LATENT: jnp.zeros(
             (cfg.cache_layers, num_pages, page_size, cfg.latent_page_dim),
             dtype,
         )}
+        if cfg.indexed:
+            # A token's index key lies where its latent does.
+            pool[paged_kv.INDEX_K] = jnp.zeros(
+                (cfg.cache_layers, num_pages, page_size, cfg.index_head_dim),
+                dtype,
+            )
+        return pool
     shape = (
         cfg.num_layers, num_pages, page_size, cfg.num_kv_heads, cfg.head_dim
     )
@@ -490,7 +527,10 @@ def moe_select(cfg: LLMConfig, r: jnp.ndarray,
                router_bias: jnp.ndarray | None = None):
     """`moe_route` from the logits r [N, E] on: wherever they were
     taken (cfg.router_input)."""
-    p = jax.nn.softmax(r, axis=-1)
+    if cfg.router_scoring == "sigmoid":
+        p = jax.nn.sigmoid(r)
+    else:
+        p = jax.nn.softmax(r, axis=-1)
     if router_bias is None:
         w, idx = jax.lax.top_k(p, cfg.num_experts_per_tok)
     else:
@@ -846,11 +886,7 @@ def _mla_expanded(cfg: LLMConfig, q_nope, q_rope, c, kr, w_uk, w_uv, *,
     whenever many queries meet the same keys (prefill). Returns
     [B, T, Hq, dv]."""
     dn, dr = cfg.qk_nope_head_dim, cfg.qk_rope_head_dim
-    k_nope = jnp.einsum("bkc,hdc->bkhd", c, w_uk)
-    v = jnp.einsum("bkc,hcd->bkhd", c, w_uv)
-    k = jnp.concatenate([
-        k_nope, jnp.broadcast_to(kr[:, :, None, :], (*k_nope.shape[:3], dr)),
-    ], axis=-1)
+    k, v = _expand_keys(c, kr, w_uk, w_uv)
     q = jnp.concatenate([q_nope, q_rope], axis=-1)
     scale = cfg.softmax_scale
     if impl == "pallas":
@@ -881,7 +917,7 @@ def _mla_expanded(cfg: LLMConfig, q_nope, q_rope, c, kr, w_uk, w_uv, *,
 
 def _mla(cfg: LLMConfig, a: jnp.ndarray, p: Params, cos, sin, *,
          positions, pool, tables, write_slots, kv_mask, write_mask,
-         kv_lengths, attn_impl: str):
+         kv_lengths, attn_impl: str, selected: dict | None = None):
     """One latent-attention sublayer on the normed input a [B, T, H].
     Returns (its output [B, T, H], the pool). What a token leaves in
     the cache is (its kv latent after norm and scale, its ONE roped
@@ -895,7 +931,15 @@ def _mla(cfg: LLMConfig, a: jnp.ndarray, p: Params, cos, sin, *,
     read once for both products and no per-head key or value ever
     exists (ops/pallas/paged_attention._latent_paged, or its XLA twin).
     Anything longer (a prefill chunk over its cached prefix, a forward
-    with no cache) runs the EXPANDED form, `_mla_expanded`."""
+    with no cache) runs the EXPANDED form, `_mla_expanded`.
+
+    With an indexer (cfg.indexed) `pool` is the pair (latents, index
+    keys), a token's index key is written where its latent is, and both
+    paths read the keys the indexer selected a query
+    (`_sparse_decode`, `_sparse_prefill`). `selected` (the comparison's
+    twin): a dict that takes what was selected under "selected", a
+    decode step's indices [B, k] (ascending, the first min(length, k)
+    real) or a chunk's mask packed along the keys [B, T, K / 8]."""
     from oryx_tpu.ops.rope import apply_rope_interleaved
 
     B, T, H = a.shape
@@ -922,7 +966,17 @@ def _mla(cfg: LLMConfig, a: jnp.ndarray, p: Params, cos, sin, *,
         q_rope = (q_rope * q_scale).astype(a.dtype)
     kr = rope(_linear(a, p["k_rope_proj"])[:, :, None], cos, sin)[:, :, 0]
     w_uk, w_uv = p["w_uk"].astype(a.dtype), p["w_uv"].astype(a.dtype)
-    if pool is None:
+    ipool = None
+    if cfg.indexed:
+        with jax.named_scope("dsa_index"):
+            qi, ki, wi = _index_inputs(cfg, a, cq, p["indexer"], cos, sin)
+        if pool is not None:
+            pool, ipool = pool
+    if pool is None and cfg.indexed:
+        o = _sparse_dense(
+            cfg, q_nope, q_rope, c, kr, w_uk, w_uv, qi, ki, wi,
+            positions=positions, kv_mask=kv_mask)
+    elif pool is None:
         o = _mla_expanded(
             cfg, q_nope, q_rope, c, kr, w_uk, w_uv, q_positions=positions,
             kv_positions=positions, kv_mask=kv_mask, impl=attn_impl,
@@ -938,6 +992,11 @@ def _mla(cfg: LLMConfig, a: jnp.ndarray, p: Params, cos, sin, *,
             pool[:, :, None, :], row[:, :, None, :], tables, write_slots,
             write_mask=write_mask,
         )[:, :, 0, :]
+        if ipool is not None:
+            ipool = paged_kv.write_pages(
+                ipool[:, :, None, :], ki[:, :, None, :], tables, write_slots,
+                write_mask=write_mask,
+            )[:, :, 0, :]
         if T == 1 and kv_lengths is not None:
             q_lat = jnp.einsum("bhd,hdc->bhc", q_nope[:, 0], w_uk)
             qf = jnp.concatenate([
@@ -950,11 +1009,24 @@ def _mla(cfg: LLMConfig, a: jnp.ndarray, p: Params, cos, sin, *,
                 decode = _ppa.latent_decode_attention
             else:
                 decode = paged_kv.latent_decode_attention
-            o_lat = decode(
-                qf, pool, tables, kv_lengths, scale=cfg.softmax_scale,
-                value_dim=R,
-            )
+            if ipool is not None:
+                o_lat, idx = _sparse_decode(
+                    cfg, qf, qi[:, 0], wi[:, 0], pool, ipool, tables,
+                    kv_lengths, decode, attn_impl)
+                if selected is not None:
+                    selected["selected"] = idx
+            else:
+                o_lat = decode(
+                    qf, pool, tables, kv_lengths, scale=cfg.softmax_scale,
+                    value_dim=R,
+                )
             o = jnp.einsum("bhc,hcd->bhd", o_lat, w_uv)[:, None]
+        elif ipool is not None:
+            o, seen = _sparse_prefill(
+                cfg, q_nope, q_rope, w_uk, w_uv, qi, wi, pool, ipool, tables,
+                positions=positions, kv_mask=kv_mask)
+            if selected is not None:
+                selected["selected"] = jnp.packbits(seen, axis=-1)
         else:
             lat = paged_kv.gather_pages(pool[:, :, None, :], tables)[:, :, 0]
             o = _mla_expanded(
@@ -962,7 +1034,208 @@ def _mla(cfg: LLMConfig, a: jnp.ndarray, p: Params, cos, sin, *,
                 w_uk, w_uv, q_positions=positions, kv_positions=None,
                 kv_mask=kv_mask, impl=attn_impl,
             )
+        if ipool is not None:
+            pool = (pool, ipool)
     return _linear(o.reshape(B, T, Hq * dv), p["o_proj"]), pool
+
+
+def _index_inputs(cfg: LLMConfig, a, cq, p: Params, cos, sin):
+    """The indexer's view of a layer's tokens, from what `_mla` already
+    has (the normed input a [B, T, H], the normed query latent cq):
+    (index queries [B, T, Hi, Di], ONE index key a token [B, T, Di],
+    the heads' weights [B, T, Hi] float32, scaled by Hi^-1/2 Di^-1/2).
+    The first qk_rope_head_dim columns of a query head and of the key
+    are roped at the token's position (interleaved pairs)."""
+    from oryx_tpu.ops.norms import layer_norm
+    from oryx_tpu.ops.rope import apply_rope_interleaved
+
+    B, T, _ = a.shape
+    Hi, Di, dr = cfg.index_heads, cfg.index_head_dim, cfg.qk_rope_head_dim
+
+    def rope_head(x):  # [B, T, n, Di]
+        return jnp.concatenate(
+            [apply_rope_interleaved(x[..., :dr], cos, sin), x[..., dr:]],
+            axis=-1)
+
+    qi = rope_head(_linear(cq, p["q_b"]).reshape(B, T, Hi, Di))
+    ki = layer_norm(
+        _linear(a, p["k_proj"]), p["k_norm"]["weight"], p["k_norm"]["bias"],
+        eps=1e-6)
+    ki = rope_head(ki[:, :, None])[:, :, 0]
+    wi = jnp.matmul(
+        a.astype(jnp.float32), p["head_weights"]["kernel"].astype(jnp.float32),
+        precision=jax.lax.Precision.HIGHEST,
+    ) * (Hi ** -0.5 * Di ** -0.5)
+    return qi, ki, wi
+
+
+# Keys one step of the sparse prefill's two loops handles.
+_SPARSE_TILE_TOKENS = 1024
+
+
+def _masked_attend(q, k, v, seen, scale):
+    """softmax(q k^T * scale over `seen`) v with NO query-key pair
+    outside `seen` [B, T, K]: q [B, T, Hq, d], k [B, K, Hq, d],
+    v [B, K, Hq, dv] -> (o [B, T, Hq, dv] float32 unnormalised, the
+    row maxima and sums [B, Hq, T]): one tile of an online softmax."""
+    s = jnp.einsum(
+        "bqhd,bkhd->bhqk", q, k, preferred_element_type=jnp.float32) * scale
+    s = jnp.where(seen[:, None], s, jnp.finfo(jnp.float32).min)
+    m = jnp.max(s, axis=-1)
+    p = jnp.where(seen[:, None], jnp.exp(s - m[..., None]), 0.0)
+    o = jnp.einsum(
+        "bhqk,bkhd->bqhd", p.astype(v.dtype), v,
+        preferred_element_type=jnp.float32)
+    return o, m, jnp.sum(p, axis=-1)
+
+
+def _expand_keys(c, kr, w_uk, w_uv):
+    """Latents c [B, K, R] and shared roped keys kr [B, K, dr] -> per-head
+    keys [B, K, Hq, dn + dr] and values [B, K, Hq, dv]."""
+    k_nope = jnp.einsum("bkc,hdc->bkhd", c, w_uk)
+    v = jnp.einsum("bkc,hcd->bkhd", c, w_uv)
+    k = jnp.concatenate([
+        k_nope,
+        jnp.broadcast_to(kr[:, :, None, :], (*k_nope.shape[:3], kr.shape[-1])),
+    ], axis=-1)
+    return k, v
+
+
+def _sparse_dense(cfg: LLMConfig, q_nope, q_rope, c, kr, w_uk, w_uv, qi, ki,
+                  wi, *, positions, kv_mask):
+    """Learned sparse attention with no cache (a whole sequence at
+    once): index scores of every causal pair, the top k a query, the
+    expanded form over what was selected."""
+    from oryx_tpu.ops import paged_kv
+
+    seen = positions[:, :, None] >= positions[:, None, :]
+    if kv_mask is not None:
+        seen = seen & kv_mask[:, None, :].astype(bool)
+    with jax.named_scope("dsa_index"):
+        scores = jnp.where(
+            seen, paged_kv.index_tile(qi, wi, ki), -jnp.inf)
+    with jax.named_scope("dsa_select"):
+        seen = seen & paged_kv.topk_mask(scores, cfg.index_topk)
+    with jax.named_scope("dsa_attend"):
+        k, v = _expand_keys(c, kr, w_uk, w_uv)
+        o, _, l = _masked_attend(
+            jnp.concatenate([q_nope, q_rope], axis=-1), k, v, seen,
+            cfg.softmax_scale)
+    l = jnp.moveaxis(l, 1, 2)[..., None]
+    return (o / jnp.where(l == 0.0, 1.0, l)).astype(q_nope.dtype)
+
+
+def _sparse_decode(cfg: LLMConfig, qf, qi, wi, pool, ipool, tables,
+                   kv_lengths, decode, attn_impl: str):
+    """One decode row a lane: index scores over the lane's paged index
+    keys, the exact top k (ascending), and the absorbed latent attention
+    (`decode`, the dense path's own) over the SELECTED rows alone, which
+    are gathered out of the pool. A lane at length n reads n index keys
+    and min(n, k) latent rows; under k it reads rows 0..n-1 in their
+    order, what the dense walk reads. Returns (o_lat [B, Hq, R], the
+    selected indices [B, k])."""
+    from oryx_tpu.ops import paged_kv
+
+    B, ps = qf.shape[0], pool.shape[1]
+    with jax.named_scope("dsa_index"):
+        if attn_impl == "pallas":
+            from oryx_tpu.ops.pallas import paged_attention as _ppa
+
+            scores = _ppa.index_scores(qi, wi, ipool, tables, kv_lengths)
+        else:
+            scores = paged_kv.index_scores(qi, wi, ipool, tables, kv_lengths)
+    with jax.named_scope("dsa_select"):
+        idx = paged_kv.topk_indices(scores, cfg.index_topk)
+    k = idx.shape[1]
+    if k % ps:
+        raise ValueError(
+            f"learned sparse attention: a page of {ps} positions does not "
+            f"divide the {k} selected rows of a decode step")
+    with jax.named_scope("dsa_attend"):
+        rows = paged_kv.gather_rows(pool, tables, idx)
+        return decode(
+            qf, rows.reshape(B * k // ps, ps, -1),
+            jnp.arange(B * k // ps, dtype=jnp.int32).reshape(B, k // ps),
+            jnp.minimum(kv_lengths, k), scale=cfg.softmax_scale,
+            value_dim=cfg.kv_lora_rank,
+        ), idx
+
+
+def _sparse_prefill(cfg: LLMConfig, q_nope, q_rope, w_uk, w_uv, qi, wi, pool,
+                    ipool, tables, *, positions, kv_mask):
+    """A chunk of queries [B, T] over its rows' paged prefix and itself
+    (both already in the pool): index scores against the index keys a
+    tile of `_SPARSE_TILE_TOKENS` at a time, the top k a query as a
+    MASK (`paged_kv.topk_mask`), then the expanded form a tile of keys
+    at a time under an online softmax, so that per-head keys and values
+    exist for ONE tile and the temporaries that grow with the table are
+    the scores and the mask alone. Tiles past the chunk's last position
+    are not visited; a chunk that ends inside the first k positions
+    selects everything and scores nothing. Returns ([B, T, Hq, dv],
+    the pairs attended [B, T, K] bool)."""
+    from oryx_tpu.ops import paged_kv
+
+    B, T, Hq, _ = q_nope.shape
+    R, dr, dv = cfg.kv_lora_rank, cfg.qk_rope_head_dim, cfg.v_head_dim
+    ps = pool.shape[1]
+    tp = max(1, _SPARSE_TILE_TOKENS // ps)  # pages a tile
+    sentinel = pool.shape[0]
+    tables = jnp.pad(
+        tables, ((0, 0), (0, -tables.shape[1] % tp)),
+        constant_values=sentinel)
+    Kt, K = tp * ps, tables.shape[1] * ps
+    u = jnp.arange(K, dtype=jnp.int32)
+    seen = positions[:, :, None] >= u[None, None, :]
+    if kv_mask is not None:
+        seen = seen & jnp.pad(
+            kv_mask.astype(bool), ((0, 0), (0, K - kv_mask.shape[1]))
+        )[:, None, :]
+    end = jnp.max(positions) + 1
+    tiles = jnp.minimum(-(-end // Kt), K // Kt)
+
+    def tile_rows(plane, i):
+        pages = jax.lax.dynamic_slice_in_dim(tables, i * tp, tp, axis=1)
+        return plane[jnp.clip(pages, 0, sentinel - 1)].reshape(B, Kt, -1)
+
+    def selected():
+        def score(i, scores):
+            tile = paged_kv.index_tile(qi, wi, tile_rows(ipool, i))
+            return jax.lax.dynamic_update_slice_in_dim(
+                scores, tile, i * Kt, axis=2)
+
+        with jax.named_scope("dsa_index"):
+            scores = jax.lax.fori_loop(
+                0, tiles, score, jnp.full((B, T, K), -jnp.inf, jnp.float32))
+            scores = jnp.where(seen, scores, -jnp.inf)
+        with jax.named_scope("dsa_select"):
+            return seen & paged_kv.topk_mask(scores, cfg.index_topk)
+
+    # Under k visible keys the selection is everything a query sees.
+    seen = jax.lax.cond(end <= cfg.index_topk, lambda: seen, selected)
+    q = jnp.concatenate([q_nope, q_rope], axis=-1)
+
+    def attend(i, carry):
+        m, l, acc = carry
+        lat = tile_rows(pool, i)
+        k, v = _expand_keys(lat[..., :R], lat[..., R:R + dr], w_uk, w_uv)
+        o, m_t, l_t = _masked_attend(
+            q, k, v,
+            jax.lax.dynamic_slice_in_dim(seen, i * Kt, Kt, axis=2),
+            cfg.softmax_scale)
+        m_new = jnp.maximum(m, m_t)
+        a_old, a_new = jnp.exp(m - m_new), jnp.exp(m_t - m_new)
+        scale_o = lambda x: jnp.moveaxis(x, 1, 2)[..., None]  # noqa: E731
+        return (m_new, l * a_old + l_t * a_new,
+                acc * scale_o(a_old) + o * scale_o(a_new))
+
+    with jax.named_scope("dsa_attend"):
+        m, l, acc = jax.lax.fori_loop(0, tiles, attend, (
+            jnp.full((B, Hq, T), jnp.finfo(jnp.float32).min, jnp.float32),
+            jnp.zeros((B, Hq, T), jnp.float32),
+            jnp.zeros((B, T, Hq, dv), jnp.float32),
+        ))
+    l = jnp.moveaxis(l, 1, 2)[..., None]
+    return (acc / jnp.where(l == 0.0, 1.0, l)).astype(q_nope.dtype), seen
 
 
 def _double_block(cfg: LLMConfig, h, lp: Params, cos, sin, *, pool, tables,
@@ -1002,24 +1275,33 @@ def _latent_block(cfg: LLMConfig, h, lp: Params, cos, sin, *, pool, tables,
     the expert layer (the shared expert on every token beside the
     routed experts) on ONE normed input. `tables`: a one-element list,
     the layer's block table (cache layer l), or None with no pool.
-    Returns (h, pool, the expert layer's routing)."""
+    `experts` None: a leading dense layer, whose FFN is `lp`'s own.
+    Returns (h, pool, the expert layer's routing or None)."""
     B, T, _ = h.shape
     eps = cfg.rms_norm_eps
     a = rms_norm(h, lp["input_norm"]["weight"], eps)
+    # What the twin asked to see of the selection (`_mla`), if anything.
+    seen = {} if attn.pop("return_selected", False) else None
     with jax.named_scope("mla"):
         att, pool = _mla(
             cfg, a, lp, cos, sin, pool=pool,
             tables=None if tables is None else tables[0],
             attn_impl=attn_impl, **attn,
+            **({} if seen is None else {"selected": seen}),
         )
     h = h + att
     x = rms_norm(h, lp["post_attn_norm"]["weight"], eps)
+    if experts is None:
+        # A leading dense layer (cfg.dense_layers): one SwiGLU of
+        # intermediate_size where the expert layer would be.
+        with jax.named_scope("dense_ffn"):
+            return h + _swiglu(x, lp), pool, seen
     y, routing = _moe(
         cfg, x.reshape(B * T, -1), lp["router"]["kernel"], *experts,
         impl=attn_impl, router_bias=lp["router"].get("bias"),
         shared=lp.get("shared"),
     )
-    return h + y.reshape(B, T, -1), pool, routing
+    return h + y.reshape(B, T, -1), pool, dict(routing, **(seen or {}))
 
 
 def _hybrid_layers(cfg: LLMConfig, layers: Params, h, *, block, kv_cache,
@@ -1267,6 +1549,7 @@ def forward(
     return_hidden: bool = False,
     segment_ids: jnp.ndarray | None = None,
     return_routing: bool = False,
+    return_selected: bool = False,
     state_slots: jnp.ndarray | None = None,
     window_tables: jnp.ndarray | None = None,
     window_base: jnp.ndarray | None = None,
@@ -1521,52 +1804,80 @@ def forward(
             positions=positions, write_slots=write_slots, kv_mask=kv_mask,
             write_mask=write_mask, kv_lengths=kv_lengths,
         )
-        num_l = jnp.arange(cfg.num_layers, dtype=jnp.int32)
+        if return_selected:
+            # With return_routing, a paged cache and an indexer: every
+            # layer's selection under routing["selected"] [L, ...].
+            attn["return_selected"] = True
+        num_l = jnp.arange(cfg.moe_layers, dtype=jnp.int32)
         # Cache layers a model layer, and the block that reads them.
         per = 2 if cfg.shortcut_double_layer else 1
         latent_block = _double_block if per == 2 else _latent_block
+        Ld = cfg.dense_layers
         if kv_cache is None:
-            def body(h, xs):
+            def body(h, xs, experts=True):
                 lp, layer = xs
                 h, _, routing = latent_block(
                     cfg, h, lp, cos, sin, pool=None, tables=None,
-                    experts=(experts_flat, layer), attn_impl=attn_impl,
-                    **attn,
+                    experts=(experts_flat, layer) if experts else None,
+                    attn_impl=attn_impl, **attn,
                 )
                 return constrain(h, *hs_spec), routing
 
+            if Ld:
+                # The leading dense layers, then the expert layers.
+                h, _ = jax.lax.scan(
+                    wrap_remat(partial(body, experts=False), remat), h,
+                    (params["dense_layers"], num_l[:Ld]))
             h, expert_counts = jax.lax.scan(
                 wrap_remat(body, remat), h, (layers, num_l)
             )
         else:
             # The pool is the scan's carry, one flat [Lc*P, page, Dp]
             # buffer behind layer-offset tables, as below; cache layer
-            # per * l + i belongs to sublayer i of model layer l.
-            pool = kv_cache[paged_kv.LATENT]
-            Lc, P = pool.shape[:2]
+            # per * l + i belongs to sublayer i of model layer l. With
+            # an indexer the carry is the pair (latents, index keys),
+            # both behind the same tables.
+            names = (paged_kv.LATENT,) + (
+                (paged_kv.INDEX_K,) if cfg.indexed else ())
+            planes = tuple(kv_cache[n] for n in names)
+            Lc, P = planes[0].shape[:2]
+            pool = tuple(
+                a.reshape((Lc * P,) + a.shape[2:]) for a in planes)
+            if not cfg.indexed:
+                pool = pool[0]
 
-            def body(carry, xs):
+            def body(carry, xs, experts=True, first=Ld):
                 h, pool = carry
                 lp, layer = xs
+                # (an expert layer's cache layer lies behind the dense.)
+                cl = layer + first if first else layer
                 tables = [
                     jnp.where(block_tables >= P, Lc * P,
-                              block_tables + (per * layer + i) * P)
+                              block_tables + (per * cl + i) * P)
                     for i in range(per)
                 ]
                 h, pool, routing = latent_block(
                     cfg, h, lp, cos, sin, pool=pool, tables=tables,
-                    experts=(experts_flat, layer), attn_impl=attn_impl,
-                    **attn,
+                    experts=(experts_flat, layer) if experts else None,
+                    attn_impl=attn_impl, **attn,
                 )
                 return (constrain(h, *hs_spec), pool), routing
 
+            num_l = num_l.astype(block_tables.dtype)
+            led = None
+            if Ld:
+                (h, pool), led = jax.lax.scan(
+                    wrap_remat(partial(body, experts=False, first=0), remat),
+                    (h, pool), (params["dense_layers"], num_l[:Ld]))
             (h, pool), expert_counts = jax.lax.scan(
-                wrap_remat(body, remat),
-                (h, pool.reshape((Lc * P,) + pool.shape[2:])),
-                (layers, num_l.astype(block_tables.dtype)),
+                wrap_remat(body, remat), (h, pool), (layers, num_l),
             )
+            if led:  # the dense layers' selections ahead of the others'
+                expert_counts["selected"] = jnp.concatenate(
+                    [led["selected"], expert_counts["selected"]])
             new_cache = {
-                paged_kv.LATENT: pool.reshape((Lc, P) + pool.shape[1:])
+                n: a.reshape((Lc, P) + a.shape[1:])
+                for n, a in zip(names, pool if cfg.indexed else (pool,))
             }
     elif cfg.recurrent:
         from oryx_tpu.ops import paged_kv

@@ -554,6 +554,14 @@ def is_latent_pool(kv_pages) -> bool:
     return isinstance(kv_pages, dict) and LATENT in kv_pages
 
 
+# The indexer's plane of a latent pool whose model attends the keys an
+# indexer selected (`LLMConfig.indexed`): [L, P, page, index_head_dim],
+# a token's roped index key at the page and offset its latent has, behind
+# the SAME block table and allocator. Whatever moves a page by its index
+# (`copy_pages`, `fetch_page`, `upload_page`) moves both planes.
+INDEX_K = "index_k"
+
+
 # The per-SLOT planes of a pool whose model has state-space layers
 # (`qwen2.init_paged_kv_cache`): [Lm, S, ...], addressed by slot, never
 # through a block table. Everything that moves PAGES leaves them alone.
@@ -973,3 +981,102 @@ def latent_decode_attention(
         preferred_element_type=jnp.float32,
     )
     return (out / jnp.where(l == 0.0, 1.0, l)).astype(q.dtype)
+
+
+# ---------------------------------------------------------------------------
+# Learned sparse attention: index scores, the exact top k, the selected rows
+# ---------------------------------------------------------------------------
+
+
+def index_tile(q: jnp.ndarray, w: jnp.ndarray, keys: jnp.ndarray):
+    """The indexer's scores, the one plain copy of their arithmetic:
+    I[b, t, u] = sum_j w[b, t, j] relu(q[b, t, j] . keys[b, u]),
+    float32. q [B, T, Hi, Di], w [B, T, Hi] float32, keys [B, K, Di]."""
+    s = jnp.einsum(
+        "bthd,bkd->bthk", q, keys, preferred_element_type=jnp.float32)
+    return jnp.einsum("bthk,bth->btk", jax.nn.relu(s), w.astype(jnp.float32))
+
+
+def index_scores(
+    q: jnp.ndarray,  # [B, Hi, Di] a decode row's index queries
+    w: jnp.ndarray,  # [B, Hi] float32 weights of the index heads
+    pages: jnp.ndarray,  # [P, page_size, Di] index keys of one cache layer
+    block_tables: jnp.ndarray,  # [B, max_pages]
+    kv_lengths: jnp.ndarray,  # [B] valid kv count INCLUDING the current token
+) -> jnp.ndarray:
+    """Pure-JAX reference of one query a row against its paged index
+    keys: `index_tile`, -inf at u >= kv_lengths[b]. Returns
+    [B, max_pages * page_size]. The Pallas twin
+    (ops/pallas/paged_attention.index_scores) walks the live pages in
+    place; this one gathers them."""
+    keys = gather_pages(pages[:, :, None, :], block_tables)[:, :, 0]
+    s = index_tile(q[:, None], w[:, None], keys)[:, 0]
+    seen = (jnp.arange(keys.shape[1], dtype=jnp.int32)[None, :]
+            < kv_lengths[:, None])
+    return jnp.where(seen, s, -jnp.inf)
+
+
+def _order_key(scores: jnp.ndarray) -> jnp.ndarray:
+    """float32 -> uint32 whose unsigned order is the floats' order (no
+    NaN; -0.0 counts as 0.0)."""
+    b = jax.lax.bitcast_convert_type(
+        jnp.where(scores == 0, 0.0, scores).astype(jnp.float32), jnp.uint32)
+    return jnp.where(b >> 31 == 1, ~b, b | jnp.uint32(1 << 31))
+
+
+def topk_mask(scores: jnp.ndarray, k: int) -> jnp.ndarray:
+    """[..., K] float32 -> bool [..., K]: True at the k largest scores
+    of a row, ties to the lower index, the SET `jax.lax.top_k` returns
+    (everything where K <= k). No sort and no index array, so it serves
+    a [chunk, table] block of scores: the k-th largest value is found
+    bit by bit (32 counts over the row), then the last index that
+    still belongs among the values equal to it (one count a bit of
+    K)."""
+    K = scores.shape[-1]
+    if K <= k:
+        return jnp.ones(scores.shape, bool)
+    key = _order_key(scores)
+    rows = scores.shape[:-1]
+
+    def value_bit(i, thr):
+        cand = thr | (jnp.uint32(1 << 31) >> i.astype(jnp.uint32))
+        enough = jnp.sum(key >= cand[..., None], axis=-1) >= k
+        return jnp.where(enough, cand, thr)
+
+    thr = jax.lax.fori_loop(0, 32, value_bit, jnp.zeros(rows, jnp.uint32))
+    above = key > thr[..., None]
+    equal = key == thr[..., None]
+    need = k - jnp.sum(above, axis=-1)  # >= 1 of the equal ones
+    u = jnp.arange(K, dtype=jnp.int32)
+    bits = max(1, (K - 1).bit_length())
+
+    def index_bit(i, cut):
+        cand = cut | (jnp.int32(1 << (bits - 1)) >> i)
+        few = jnp.sum(equal & (u < cand[..., None]), axis=-1) < need
+        return jnp.where(few, cand, cut)
+
+    cut = jax.lax.fori_loop(0, bits, index_bit, jnp.zeros(rows, jnp.int32))
+    return above | (equal & (u <= cut[..., None]))
+
+
+def topk_indices(scores: jnp.ndarray, k: int) -> jnp.ndarray:
+    """[B, K] float32 -> int32 [B, min(k, K)]: the indices of the k
+    largest scores of a row in ASCENDING order (`jax.lax.top_k`: exact,
+    ties to the lower index). A row with n < k finite scores at its head
+    (the rest -inf) returns 0..n-1 first."""
+    _, idx = jax.lax.top_k(scores, min(k, scores.shape[-1]))
+    return jnp.sort(idx.astype(jnp.int32), axis=-1)
+
+
+def gather_rows(
+    pages: jnp.ndarray,  # [P, page_size, D]
+    block_tables: jnp.ndarray,  # [B, max_pages]
+    idx: jnp.ndarray,  # [B, k] logical slots of a row's stream
+) -> jnp.ndarray:
+    """Rows `idx` of each row's logical stream, [B, k, D]: the selected
+    rows alone leave the pool. A slot behind a sentinel entry clips to a
+    real row, which the caller masks."""
+    P, ps, D = pages.shape
+    page = jnp.take_along_axis(block_tables, idx // ps, axis=1)
+    flat = jnp.clip(page * ps + idx % ps, 0, P * ps - 1)
+    return pages.reshape(P * ps, D)[flat]

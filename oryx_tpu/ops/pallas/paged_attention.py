@@ -772,3 +772,123 @@ def latent_decode_attention(
         scale=float(scale), value_dim=int(value_dim),
         interpret=bool(interpret),
     )
+
+
+# ---------------------------------------------------------------------------
+# Learned sparse attention: the indexer's scores over a row's paged keys
+# ---------------------------------------------------------------------------
+
+
+def _index_kernel(
+    bt_ref,  # [S, maxp] SMEM (scalar prefetch)
+    seg_ref,  # [R] SMEM
+    pos_ref,  # [R] SMEM
+    q_ref,  # [1, Hi, Di] index queries
+    w_ref,  # [1, Hi, 128] float32 head weights, one value a row of lanes
+    k_hbm,  # [P, ps, Di] the cache layer's index keys
+    o_ref,  # [1, 1, maxp * ps] float32
+    k_buf, sems, slot_ref, *scratch,
+    page_size: int,
+    pages_per_block: int,
+):
+    """`_walk_row` with no softmax: block i of a row's live pages gives
+    sum_j w_j relu(q_j . k) for its tokens, written to the row's
+    columns of the output as they come; what the walk never reaches, and
+    the columns past the row's length, are -inf."""
+    ps, npb = page_size, pages_per_block
+    brow = npb * ps
+
+    def page_copies(page, j, slot):
+        return [pltpu.make_async_copy(
+            k_hbm.at[page], k_buf.at[slot, pl.ds(j * ps, ps)], sems.at[slot],
+        )]
+
+    def zero():
+        k_buf[...] = jnp.zeros_like(k_buf)
+
+    o_ref[...] = jnp.full(o_ref.shape, -jnp.inf, o_ref.dtype)
+
+    def block_step(length):
+        col = jax.lax.broadcasted_iota(jnp.int32, (1, brow), 1)
+
+        def fold(i, slot):
+            s = jax.lax.dot_general(
+                q_ref[0], k_buf[slot], (((1,), (1,)), ((), ())),
+                preferred_element_type=jnp.float32,
+            )  # [Hi, brow] fp32
+            s = jnp.sum(
+                jnp.maximum(s, 0.0) * w_ref[0][:, :1], axis=0, keepdims=True)
+            o_ref[0, :, pl.ds(pl.multiple_of(i * brow, brow), brow)] = (
+                jnp.where(i * brow + col < length, s, -jnp.inf))
+
+        return fold
+
+    _walk_row(
+        bt_ref, seg_ref, pos_ref, slot_ref, scratch,
+        num_pages=k_hbm.shape[0], page_size=ps, pages_per_block=npb,
+        page_copies=page_copies, block_copies=None,
+        zero=zero, block_step=block_step,
+    )
+
+
+@functools.partial(jax.jit, static_argnames=("interpret",))
+def _dsa_index(q, w, pages, block_tables, q_segments, q_positions, *,
+               interpret: bool):
+    R, Hi, Di = q.shape
+    P, ps, _ = pages.shape
+    maxp = block_tables.shape[1]
+    npb = max(1, min(maxp, _LATENT_BLOCK_TOKENS // ps))
+    while maxp % npb:  # whole blocks: a block's columns are one store
+        npb -= 1
+    row = lambda d: pl.BlockSpec((1, Hi, d), lambda r, *_: (r, 0, 0))  # noqa: E731
+    out = pl.pallas_call(
+        functools.partial(_index_kernel, page_size=ps, pages_per_block=npb),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=3,
+            grid=(R,),
+            in_specs=[row(Di), row(128),
+                      pl.BlockSpec(memory_space=pltpu.HBM)],
+            out_specs=pl.BlockSpec(
+                (1, 1, maxp * ps), lambda r, *_: (r, 0, 0)),
+            scratch_shapes=[
+                pltpu.VMEM((2, npb * ps, Di), pages.dtype),
+                pltpu.SemaphoreType.DMA((2,)),
+                pltpu.SMEM((1,), jnp.int32),  # buffer slot in flight
+                # `_walk_row`'s running softmax, which this walk has not.
+                pltpu.VMEM((8, 128), jnp.float32),
+                pltpu.VMEM((8, 128), jnp.float32),
+                pltpu.VMEM((8, 128), jnp.float32),
+            ],
+        ),
+        out_shape=jax.ShapeDtypeStruct((R, 1, maxp * ps), jnp.float32),
+        # Rows run in order: a row starts the next row's first copy.
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("arbitrary",)
+        ),
+        interpret=interpret,
+    )(block_tables.astype(jnp.int32), q_segments.astype(jnp.int32),
+      q_positions.astype(jnp.int32), q,
+      jnp.broadcast_to(w.astype(jnp.float32)[..., None], (R, Hi, 128)),
+      pages)
+    return out[:, 0]
+
+
+def index_scores(
+    q,  # [B, Hi, Di] index queries of one decode row a lane
+    w,  # [B, Hi] float32 head weights
+    pages,  # [P, page_size, Di] index keys
+    block_tables,  # [B, max_pages]
+    kv_lengths,  # [B] valid kv count INCLUDING the current token
+    *,
+    interpret: bool | None = None,
+):
+    """Drop-in for ops.paged_kv.index_scores: each row walks its own live
+    pages of index keys in place; a row of length 0 takes no step and
+    returns -inf everywhere."""
+    if interpret is None:
+        interpret = _flash._use_interpret()
+    B = q.shape[0]
+    return _dsa_index(
+        q, w, pages, block_tables, jnp.arange(B, dtype=jnp.int32),
+        kv_lengths.astype(jnp.int32) - 1, interpret=bool(interpret),
+    )

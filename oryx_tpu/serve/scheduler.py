@@ -618,6 +618,7 @@ class ContinuousScheduler:
         # the hit's last token.
         self.recurrent = bool(llm.recurrent)
         self.windowed = bool(llm.windowed)
+        self.indexed = bool(llm.indexed)
         for on, sentence in (
             (self.recurrent, qwen2.unsupported_for_recurrent),
             (self.windowed, qwen2.unsupported_for_window),
@@ -750,6 +751,19 @@ class ContinuousScheduler:
         # live prefix, once a chunk), and dispatched rows x the
         # positions of the block table the program ran with.
         reg.counter("prefill_attn_pairs_total")
+        if pipe.cfg.llm.indexed:
+            # Learned sparse attention (docs/OBSERVABILITY.md "Learned
+            # sparse attention"), a cache layer each: the pairs a
+            # chunk's indexer has to score (a query that sees more keys
+            # than index_topk scores them all) and the pairs its
+            # attention reads (min(visible, index_topk) a query), beside
+            # prefill_attn_pairs_total, which counts every causal pair;
+            # and the latent rows the decode rows read,
+            # min(length, index_topk) a row, beside
+            # decode_kv_tokens_total, the index keys they scored.
+            reg.counter("prefill_index_pairs_total")
+            reg.counter("prefill_selected_pairs_total")
+            reg.counter("decode_selected_tokens_total")
         reg.counter("prefill_live_positions_total")
         reg.counter("prefill_table_positions_total")
         reg.histogram("prefill_chunk_tokens", PREFILL_CHUNK_BUCKETS)
@@ -3142,6 +3156,15 @@ class ContinuousScheduler:
         # Token p attends positions 0..p: the chunk's causal pairs.
         self.metrics.inc(
             "prefill_attn_pairs_total", (end - off) * (off + end + 1) // 2)
+        if self.indexed:
+            # ... of which the indexer scores a query's p + 1 where they
+            # are more than it keeps, and attention reads what it keeps.
+            k = self.cfg.llm.index_topk
+            seen = np.arange(off, end, dtype=np.int64) + 1
+            self.metrics.inc("prefill_index_pairs_total",
+                             int(seen[seen > k].sum()))
+            self.metrics.inc("prefill_selected_pairs_total",
+                             int(np.minimum(seen, k).sum()))
         if self.windowed:
             # ... of which a window layer's query at p sees min(p + 1, W).
             W = self.cfg.llm.sliding_window
@@ -3679,10 +3702,23 @@ class ContinuousScheduler:
         """The decode reads of one chunk, for the families a state or a
         window makes the host reckon: a lane that was live for n steps
         from length a (`ran`) to `lengths`."""
-        if not (self.recurrent or self.windowed):
+        if not (self.recurrent or self.windowed or self.indexed):
             return
         a = ran.astype(np.int64)
         n = lengths.astype(np.int64) - a
+
+        def capped(limit: int) -> int:
+            """Rows the lanes' steps read where a row at length
+            a + i + 1 reads min(that, limit)."""
+            i = np.arange(self.chunk, dtype=np.int64)[None, :]
+            read = np.minimum(a[:, None] + i + 1, limit)
+            return int((read * (i < n[:, None])).sum())
+
+        if self.indexed:
+            # The latent rows the indexer kept (it scored a + i + 1
+            # index keys: kv_tokens of the step's own statistics).
+            self.metrics.inc("decode_selected_tokens_total",
+                             capped(self.cfg.llm.index_topk))
         if self.recurrent:
             # It advanced n positions and read a + 1 .. a + n cached
             # tokens.
@@ -3694,11 +3730,8 @@ class ContinuousScheduler:
             # It fed positions a .. a + n - 1, and a window layer's row
             # at p read min(p + 1, W) cached tokens (a global layer's
             # p + 1: kv_tokens of the step's own statistics).
-            W = self.cfg.llm.sliding_window
-            i = np.arange(self.chunk, dtype=np.int64)[None, :]
-            read = np.minimum(a[:, None] + i + 1, W) * (i < n[:, None])
             self.metrics.inc("decode_window_kv_tokens_total",
-                             int(read.sum()))
+                             capped(self.cfg.llm.sliding_window))
 
     def _seated_riders(self, flight: _Flight, dropped: str) -> list[int]:
         """The riders of a dispatch whose slot still holds the

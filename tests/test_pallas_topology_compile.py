@@ -527,6 +527,96 @@ def test_mistral_small_4_cell_fits_the_chip_by_the_half_gigabyte_rule(
     assert total <= 15.75e9 - 0.5e9, total
 
 
+def test_glm5_cell_fits_the_chip_and_expands_one_tile_of_keys(
+    one_chip, mosaic
+):
+    """The two programs of `glm-5.long-sessions` at the cell's full size
+    (1 dense + 4 expert layers, 16 of 256 experts, 19,360 rows of
+    vocabulary, 12 slots x 65,536 positions, page 64, chunk 1024),
+    compiled for the described v5e. ISSUE 49's rule for the slots:
+    arguments + the decode program's temporaries + the WIDEST prefill
+    table's temporaries must leave 0.5 GB of the 16.91 GB (15.75 GiB) a
+    program may use, else 8 slots. They leave 1.77 GB (13.872 + 0.434 +
+    0.833). And point 4's outcome: the widest bucket's temporaries are a
+    fifth of the 4.3 GB that per-head keys and values of the whole table
+    would take, and what grows with the table is the index scores and
+    the mask (0.37 GB at 8,192 positions, 0.83 at 65,536); the decode
+    program builds neither a lane's whole latent stream nor its index
+    keys' (a page walk and a gather of 2,048 rows)."""
+    import dataclasses
+
+    from oryx_tpu import config as cfg_lib
+    from oryx_tpu.models import generate, qwen2
+    from oryx_tpu.serve import scheduler
+
+    def on_chip(make):
+        return jax.tree_util.tree_map(
+            lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=one_chip),
+            jax.eval_shape(make),
+        )
+
+    cfg = dataclasses.replace(cfg_lib.glm5_ep16().llm, num_layers=5)
+    slots, page_size, ctx = 12, 64, 65536
+    widths = scheduler.prefill_table_buckets(ctx // page_size, page_size)
+    assert widths == (128, 256, 512, 1024)
+    params = on_chip(
+        lambda: qwen2.init_params(cfg, jax.random.key(0), dtype=BF16))
+    kv = on_chip(lambda: qwen2.init_paged_kv_cache(
+        cfg, slots * ctx // page_size, page_size, dtype=BF16))
+    nbytes = lambda tree: sum(  # noqa: E731
+        a.size * a.dtype.itemsize for a in jax.tree_util.tree_leaves(tree))
+    pool_bytes = nbytes(kv)
+    assert pool_bytes == 5 * slots * ctx * (640 + 128) * 2  # 6.040 GB
+    assert nbytes(params) == 7_831_850_496
+
+    def rows(S, dtype, *tail):
+        return jax.ShapeDtypeStruct((S, *tail), dtype, sharding=one_chip)
+
+    def sampling(S):
+        return (on_chip(lambda: jax.random.split(jax.random.key(0), S)),
+                rows(S, jnp.float32), rows(S, jnp.float32),
+                rows(S, jnp.int32))
+
+    common = dict(attn_impl="pallas", compute_dtype=BF16)
+    S = slots
+    decode = generate.paged_decode_chunk.lower(
+        params, cfg, kv, rows(S, jnp.int32, ctx // page_size),
+        rows(S, jnp.int32), rows(S, jnp.int32), rows(S, jnp.bool_),
+        rows(S, jnp.int32, 0), *sampling(S), chunk=8, eos=19360, **common,
+    ).compile()
+    text = decode.as_text()
+    assert text.count("tpu_custom_call") >= 3 and "ragged-dot" not in text
+    # Index scores by a page walk, latent rows by a gather of the top k.
+    assert f"bf16[{slots},{ctx},640]" not in text
+    assert f"bf16[{slots},{ctx},128]" not in text
+    assert f"bf16[{slots},2048,640]" in text
+    temps = {}
+    for width in (widths[0], widths[-1]):
+        prefill = generate.paged_prefill.lower(
+            params, cfg, rows(1, BF16, 1024, cfg.hidden_size),
+            rows(1, jnp.int32), rows(1, jnp.int32, width), kv,
+            rows(1, jnp.int32), *sampling(1), held_stats=True, **common,
+        ).compile()  # as the engine dispatches it for a share of experts
+        memory = prefill.memory_analysis()
+        assert memory.alias_size_in_bytes == pool_bytes
+        temps[width] = memory.temp_size_in_bytes
+        # Per-head keys and values of ONE tile of 1,024 positions.
+        ptext = prefill.as_text()
+        assert "bf16[1,1024,64,256]" in ptext
+        assert f"bf16[1,{width * page_size},64,256]" not in ptext
+    memory = decode.memory_analysis()
+    assert memory.alias_size_in_bytes == pool_bytes
+    assert memory.temp_size_in_bytes < 0.5e9
+    assert temps[widths[-1]] < 4.3e9 / 4
+    # What grows with the table: the float32 scores of a chunk and their
+    # order keys (2 x 4 B a pair) and the mask, not keys and values.
+    grown = temps[widths[-1]] - temps[widths[0]]
+    assert grown < 1024 * (65536 - 8192) * 9
+    total = (nbytes(params) + pool_bytes + memory.temp_size_in_bytes
+             + temps[widths[-1]])
+    assert total <= 16.91e9 - 0.5e9, total
+
+
 def test_illegal_heads_per_block_pin_raises_with_its_name(
     one_chip, monkeypatch
 ):
