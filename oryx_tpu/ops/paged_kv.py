@@ -1017,26 +1017,20 @@ def index_scores(
 
 
 def _order_key(scores: jnp.ndarray) -> jnp.ndarray:
-    """float32 -> uint32 whose unsigned order is the floats' order (no
-    NaN; -0.0 counts as 0.0)."""
-    b = jax.lax.bitcast_convert_type(
-        jnp.where(scores == 0, 0.0, scores).astype(jnp.float32), jnp.uint32)
+    """float32 -> uint32 whose unsigned order is the floats' total
+    order, `jax.lax.top_k`'s (no NaN; -0.0 under 0.0)."""
+    b = jax.lax.bitcast_convert_type(scores.astype(jnp.float32), jnp.uint32)
     return jnp.where(b >> 31 == 1, ~b, b | jnp.uint32(1 << 31))
 
 
-def topk_mask(scores: jnp.ndarray, k: int) -> jnp.ndarray:
-    """[..., K] float32 -> bool [..., K]: True at the k largest scores
-    of a row, ties to the lower index, the SET `jax.lax.top_k` returns
-    (everything where K <= k). No sort and no index array, so it serves
-    a [chunk, table] block of scores: the k-th largest value is found
-    bit by bit (32 counts over the row), then the last index that
-    still belongs among the values equal to it (one count a bit of
-    K)."""
-    K = scores.shape[-1]
-    if K <= k:
-        return jnp.ones(scores.shape, bool)
-    key = _order_key(scores)
-    rows = scores.shape[:-1]
+def _kth_mask(key: jnp.ndarray, k: int) -> jnp.ndarray:
+    """uint32 [..., K], K > k -> bool [..., K] with exactly k True a
+    row, at the k largest keys, ties to the lower index: the k-th
+    largest key found bit by bit (32 counts over the row), then the
+    last index that still belongs among the keys equal to it (one count
+    a bit of K). What `topk_mask` and `topk_indices` share."""
+    K = key.shape[-1]
+    rows = key.shape[:-1]
 
     def value_bit(i, thr):
         cand = thr | (jnp.uint32(1 << 31) >> i.astype(jnp.uint32))
@@ -1059,13 +1053,80 @@ def topk_mask(scores: jnp.ndarray, k: int) -> jnp.ndarray:
     return above | (equal & (u <= cut[..., None]))
 
 
+def topk_mask(scores: jnp.ndarray, k: int) -> jnp.ndarray:
+    """[..., K] float32 -> bool [..., K]: True at the k largest scores
+    of a row, ties to the lower index, the SET `jax.lax.top_k` returns
+    (everything where K <= k) but for one rule: -0.0 counts as 0.0. No
+    sort and no index array, so it serves a [chunk, table] block of
+    scores (`_kth_mask`: 32 + log2 K counts over the row)."""
+    if scores.shape[-1] <= k:
+        return jnp.ones(scores.shape, bool)
+    return _kth_mask(_order_key(jnp.where(scores == 0, 0.0, scores)), k)
+
+
+# `_set_indices` views a row page by page: a page of 64 positions (the
+# pool's page as served; a width it does not divide is padded with unset
+# bits) is four words of 16 bits, and pages go eight to a group, so that
+# a group's row of the float32 table holds whole numbers under 2**16,
+# which a one-hot product returns exactly.
+_WORD_BITS, _GROUP_WORDS = 16, 32
+
+
+def _set_indices(mask: jnp.ndarray, k: int) -> jnp.ndarray:
+    """bool [B, K] with exactly k True a row -> their indices ascending,
+    int32 [B, k], with no sort, scan or search along K: one pass packs
+    the bits into words, and output place j is then found by counting,
+    three times over: the group whose running count of set bits first
+    passes j (k places against K / 512 running sums), the word of that
+    group likewise (32 sums), the bit of that word likewise (16). A
+    group's words and sums reach its places as a one-hot product, exact
+    in float32 at the highest precision: 0.02 ms on a v5e at [12,
+    65536], k 2048, where a gather of as many scalars takes 0.25 (chip
+    run, PR 51)."""
+    B, K = mask.shape
+    bits = jnp.pad(mask, ((0, 0), (0, -K % (_WORD_BITS * _GROUP_WORDS))))
+    bits = bits.reshape(B, -1, _GROUP_WORDS, _WORD_BITS).astype(jnp.int32)
+    shifts = jnp.arange(_WORD_BITS, dtype=jnp.int32)
+    words = jnp.sum(bits << shifts, axis=-1)  # [B, G, 32]
+    word_ends = jnp.cumsum(jax.lax.population_count(words), axis=-1)
+    group_ends = jnp.cumsum(word_ends[..., -1], axis=-1)  # [B, G]
+    place = jnp.arange(k, dtype=jnp.int32)
+
+    def passed(ends, rank):
+        """How many running sums `rank` has reached, and their last."""
+        done = ends <= rank[..., None]
+        return (jnp.sum(done, axis=-1, dtype=jnp.int32),
+                jnp.max(jnp.where(done, ends, 0), axis=-1))
+
+    group, before = passed(group_ends[:, None, :], place[None, :])
+    hot = group[..., None] == jnp.arange(words.shape[1], dtype=jnp.int32)
+    row = jnp.einsum(
+        "bkg,bgw->bkw", hot.astype(jnp.float32),
+        jnp.concatenate([word_ends, words], axis=-1).astype(jnp.float32),
+        precision=jax.lax.Precision.HIGHEST).astype(jnp.int32)
+    rank = place - before  # among the group's set bits
+    word, before = passed(row[..., :_GROUP_WORDS], rank)
+    held = jnp.sum(
+        jnp.where(word[..., None] == jnp.arange(_GROUP_WORDS),
+                  row[..., _GROUP_WORDS:], 0), axis=-1)  # the word's bits
+    bit, _ = passed(
+        jax.lax.population_count(held[..., None] & ((2 << shifts) - 1)),
+        rank - before)
+    return (group * _GROUP_WORDS + word) * _WORD_BITS + bit
+
+
 def topk_indices(scores: jnp.ndarray, k: int) -> jnp.ndarray:
     """[B, K] float32 -> int32 [B, min(k, K)]: the indices of the k
-    largest scores of a row in ASCENDING order (`jax.lax.top_k`: exact,
-    ties to the lower index). A row with n < k finite scores at its head
-    (the rest -inf) returns 0..n-1 first."""
-    _, idx = jax.lax.top_k(scores, min(k, scores.shape[-1]))
-    return jnp.sort(idx.astype(jnp.int32), axis=-1)
+    largest scores of a row in ASCENDING order: what `jax.lax.top_k`
+    followed by a sort of its indices returns (exact, ties to the lower
+    index, -0.0 under 0.0), found without either: the set by
+    `_kth_mask`'s counts, its indices by `_set_indices`'. A row with
+    n < k finite scores at its head (the rest -inf) returns 0..n-1
+    first and the lowest-numbered -inf places after."""
+    B, K = scores.shape
+    if K <= k:
+        return jnp.broadcast_to(jnp.arange(K, dtype=jnp.int32), (B, K))
+    return _set_indices(_kth_mask(_order_key(scores), k), k)
 
 
 def gather_rows(

@@ -248,6 +248,12 @@ def test_a_lane_under_the_top_k_reads_what_the_dense_walk_reads(tiny):
     assert np.all(np.diff(np.asarray(idx[1])) > 0) and int(idx[1, -1]) < 30
 
 
+def _sorted_top_k(x, k):
+    """The formula `topk_indices` replaced and is held to."""
+    _, idx = jax.lax.top_k(x, min(k, x.shape[-1]))
+    return jnp.sort(idx.astype(jnp.int32), axis=-1)
+
+
 @pytest.mark.parametrize("ties", [False, True])
 def test_topk_mask_is_lax_top_ks_set(ties):
     rng = np.random.default_rng(1)
@@ -263,10 +269,90 @@ def test_topk_mask_is_lax_top_ks_set(ties):
         want = np.zeros_like(got)
         np.put_along_axis(want, np.asarray(idx), True, axis=-1)
         assert np.array_equal(got, want), k
-    idx = paged_kv.topk_indices(jnp.asarray(x[:, 0]), 16)
-    assert np.array_equal(
-        np.asarray(idx), np.sort(np.asarray(
-            jax.lax.top_k(jnp.asarray(x[:, 0]), 16)[1]), -1))
+    row = jnp.asarray(x[:, 0])
+    assert np.array_equal(np.asarray(paged_kv.topk_indices(row, 16)),
+                          np.asarray(_sorted_top_k(row, 16)))
+
+
+def _topk_case(name):
+    rng = np.random.default_rng(3)
+    if name == "random":
+        return rng.normal(size=(5, 1000)).astype(np.float32), 64
+    if name == "eight levels":  # heavy ties across pages and groups
+        return np.round(rng.normal(size=(5, 1400)) * 2).clip(-4, 3).astype(
+            np.float32), 64
+    if name == "all equal":
+        return np.full((3, 1100), 0.25, np.float32), 48
+    if name.startswith("head of "):  # n finite scores, the rest -inf
+        x = rng.normal(size=(3, 200)).astype(np.float32)
+        x[:, {"0": 0, "k - 1": 15, "k": 16, "k + 1": 17}[name[8:]]:] = -np.inf
+        return x, 16
+    if name == "K <= k":
+        return rng.normal(size=(3, 16)).astype(np.float32), 16
+    if name == "K under k":
+        return rng.normal(size=(3, 11)).astype(np.float32), 16
+    if name == "the served width":  # 1,024 pages of 64, the cell's k
+        x = rng.normal(size=(2, 65536)).astype(np.float32)
+        x[1] = np.round(x[1] * 4) / 4
+        x[1, 40000:] = -np.inf
+        return x, 2048
+    if name == "no page divides the width":
+        return rng.normal(size=(4, 1531)).astype(np.float32), 100
+    if name == "negative zero beside zero":
+        x = -np.abs(rng.normal(size=(4, 700))).astype(np.float32)
+        zeros = rng.random((4, 700)) < 0.2
+        x[zeros] = np.where(rng.random(int(zeros.sum())) < 0.5, 0.0, -0.0)
+        return x, 64  # fewer places than zeros: the cut falls among them
+    raise KeyError(name)
+
+
+@pytest.mark.parametrize("name", [
+    "random", "eight levels", "all equal", "head of 0", "head of k - 1",
+    "head of k", "head of k + 1", "K <= k", "K under k", "the served width",
+    "no page divides the width", "negative zero beside zero"])
+def test_topk_indices_is_lax_top_k_then_sorted(name):
+    """The same set in the same order as `jax.lax.top_k` followed by a
+    sort of its indices, under `jit` as the decode program runs it."""
+    x, k = _topk_case(name)
+    got = jax.jit(paged_kv.topk_indices, static_argnums=1)(jnp.asarray(x), k)
+    want = _sorted_top_k(jnp.asarray(x), k)
+    assert got.dtype == jnp.int32 and got.shape == want.shape
+    assert np.array_equal(np.asarray(got), np.asarray(want))
+
+
+@pytest.mark.parametrize("impl", ["xla", "pallas"])
+def test_sparse_decode_is_the_sorted_top_ks_to_the_bit(
+        tiny, impl, monkeypatch):
+    """`_sparse_decode` with the counted selection returns the indices
+    and the attention output it returned with `jax.lax.top_k` and a
+    sort, bit for bit: lanes under, at and over the top k, tied scores
+    (index keys repeated) and a lane of length 0."""
+    cfg, _ = tiny
+    rng = np.random.default_rng(5)
+    P, maxp, S = 40, 8, 4
+    Dp, Di, R = 128, cfg.index_head_dim, cfg.kv_lora_rank
+    decode = (ppa if impl == "pallas" else paged_kv).latent_decode_attention
+    pool = jnp.asarray(rng.normal(size=(P, PS, Dp)), F32)
+    keys = rng.normal(size=(P, PS, Di)).astype(np.float32)
+    keys[:, ::2] = keys[:, 1::2]  # pairs of equal index keys: tied scores
+    bt = jnp.asarray(rng.permutation(P)[:S * maxp].reshape(S, maxp), jnp.int32)
+    qf = jnp.asarray(rng.normal(size=(S, cfg.num_heads, Dp)), F32)
+    qi = jnp.asarray(rng.normal(size=(S, cfg.index_heads, Di)), F32)
+    wi = jnp.asarray(rng.normal(size=(S, cfg.index_heads)), F32)
+    lengths = jnp.asarray([11, TOPK, 0, maxp * PS - 3], jnp.int32)
+
+    def run():
+        return qwen2._sparse_decode(
+            cfg, qf, qi, wi, pool, jnp.asarray(keys), bt, lengths, decode,
+            impl)
+
+    got, idx = run()
+    monkeypatch.setattr(paged_kv, "topk_indices", _sorted_top_k)
+    want, want_idx = run()
+    assert idx.shape == (S, TOPK) and idx.dtype == want_idx.dtype
+    assert np.array_equal(np.asarray(idx), np.asarray(want_idx))
+    assert np.array_equal(np.asarray(got), np.asarray(want))
+    assert np.isfinite(np.asarray(got)).all()
 
 
 @pytest.mark.parametrize("maxp", [4, 70])

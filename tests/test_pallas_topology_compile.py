@@ -590,6 +590,9 @@ def test_glm5_cell_fits_the_chip_and_expands_one_tile_of_keys(
     assert f"bf16[{slots},{ctx},640]" not in text
     assert f"bf16[{slots},{ctx},128]" not in text
     assert f"bf16[{slots},2048,640]" in text
+    # ... whose indices come from counts: no sort as wide as the table.
+    assert not [line for line in text.splitlines()
+                if " sort(" in line and f"[{slots},{ctx}]" in line]
     temps = {}
     for width in (widths[0], widths[-1]):
         prefill = generate.paged_prefill.lower(
