@@ -69,6 +69,8 @@ streams are made here by the decode program as the engine dispatches it.
 
 from __future__ import annotations
 
+import time
+
 import numpy as np
 
 # Each limit lies between two readings at the published widths on the
@@ -195,6 +197,17 @@ def logit_check(params, cfg, seed: int, *, page_size: int,
     from benchmark.reference import glm5_dsa_ref as ref
 
     llm = cfg.llm
+    # Where the comparison's seconds go, part by part (every part ends
+    # in values read back to the host): a first run on a machine pays
+    # each part's compiles, a later one does not. Not compared.
+    parts, t_part = {}, time.monotonic()
+
+    def part(name):
+        nonlocal t_part
+        now = time.monotonic()
+        parts[name] = parts.get(name, 0.0) + now - t_part
+        t_part = now
+
     p_params, p_cfg = program or (params, cfg)
     prefill, decode = (generate_lib.paged_prefill,
                        generate_lib.paged_decode_chunk)
@@ -259,6 +272,7 @@ def logit_check(params, cfg, seed: int, *, page_size: int,
                 picked[s][l][off:end, :w] = sel[l, :, :w]
         got[s].append(np.asarray(routing["logits"], np.float32)[0])
         twin[s].append(int(np.asarray(tok)[0]))
+    part("twin_prefill")
 
     def state_of(tok, length):
         return (jnp.asarray(tok, jnp.int32), jnp.asarray(length, jnp.int32),
@@ -282,6 +296,7 @@ def logit_check(params, cfg, seed: int, *, page_size: int,
         for s in range(S):
             served[s].append(np.asarray(state[0])[s])
         del kv_d
+        part("streams_made_here")
     served = [[int(t) for t in toks] for toks in served]
     assert min(len(t) for t in served) > steps, "a stream shorter than the twin"
 
@@ -304,6 +319,7 @@ def logit_check(params, cfg, seed: int, *, page_size: int,
                 picked[s][l][t] = _pack_row(
                     sel[0, l, s], min(t + 1, sel.shape[-1]), cols[s])
     del kv
+    part("twin_decode")
 
     sq = {"forced": 0.0, "free": 0.0, "ref": 0.0}
     worst = {"forced": 0.0, "free": 0.0}
@@ -334,6 +350,7 @@ def logit_check(params, cfg, seed: int, *, page_size: int,
         by_prompt[str(n)] = float(np.sqrt(d2 / max(r2, 1e-30)))
         compared += steps + 1
         del want, scores
+        part("reference_forced")
         # (C): the reference by itself.
         free, chosen = ref.logits(
             params, llm, given, rows=rows, return_experts=True,
@@ -346,9 +363,11 @@ def logit_check(params, cfg, seed: int, *, page_size: int,
         sq["free"] += float(np.sum(np.square(lg - free, dtype=np.float64)))
         worst["free"] = max(worst["free"], float(np.max(np.abs(lg - free))))
         del free, chosen
+        part("reference_free")
     rms = {k: float(np.sqrt(v / max(1, compared * llm.vocab_size)))
            for k, v in sq.items()}
     expert_rms_rel = expert_layer_check(params, cfg, seed, program=program)
+    part("expert_layer")
 
     hit = total = 0
     for a, b in zip(served, twin):
@@ -383,4 +402,5 @@ def logit_check(params, cfg, seed: int, *, page_size: int,
         "ref_absmax": absmax, "ref_rms": rms["ref"],
         "positions": compared, "slots": S, "decode_steps": steps,
         "prompt_tokens": lens, "table_positions": sorted(used),
+        "seconds_by_part": parts,
     }

@@ -20,10 +20,12 @@ device record, and is never a measurement.
 from __future__ import annotations
 
 import argparse
+import atexit
 import importlib
 import json
 import math
 import os
+import signal
 import sys
 import time
 
@@ -60,6 +62,24 @@ def metrics_for(manifest: dict, kind: str, cell: str) -> list[dict]:
         m for m in manifest[kind]
         if "workloads" not in m or cell in m["workloads"]
     ]
+
+
+def end_on_signals() -> None:
+    """SIGTERM, SIGINT and SIGHUP end the run as an exit does: whatever
+    child holds the chip is killed with its group FIRST (it may be deep
+    in a comparison that nobody will read), then SystemExit unwinds the
+    runner through its `finally`. The `atexit` covers every other way
+    out. (SIGKILL cannot be caught: the child ends itself then,
+    runners/lifeline.py.)"""
+    from benchmark.runners import serve
+
+    def end(signum, frame):
+        serve.kill_live()
+        raise SystemExit(128 + signum)
+
+    for sig in (signal.SIGTERM, signal.SIGINT, signal.SIGHUP):
+        signal.signal(sig, end)
+    atexit.register(serve.kill_live)
 
 
 def main(argv=None) -> int:
@@ -103,6 +123,7 @@ def main(argv=None) -> int:
     out_dir = os.path.join(HERE, "out", cell["name"])
     os.makedirs(out_dir, exist_ok=True)
 
+    end_on_signals()
     runner = importlib.import_module("benchmark.runners." + wl["runner"])
     ctx = {
         "workload": wl, "config": conf, "seed": args.seed,
@@ -158,7 +179,9 @@ def main(argv=None) -> int:
     tag = f"seed{args.seed}.trace{args.trace}"
     with open(os.path.join(out_dir, f"run.{tag}.json"), "w") as f:
         json.dump({k: v for k, v in run.items()}, f, default=str)
-    info = {k: run.get(k) for k in ("requests", "setup", "compiles_in_window")}
+    info = {k: run.get(k) for k in ("requests", "setup", "compiles_in_window",
+                                    "phases")}
+    info["wall_s"] = time.monotonic() - T_START  # process start to here
     if run.get("train"):
         info["train"] = {k: v for k, v in run["train"].items()
                          if not isinstance(v, list) or len(v) <= 8}

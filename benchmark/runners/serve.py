@@ -4,12 +4,14 @@ the counters, and reduces what came back. Never imports jax."""
 
 from __future__ import annotations
 
+import contextlib
 import json
 import math
 import os
 import random
 import re
 import shutil
+import signal
 import subprocess
 import sys
 import threading
@@ -22,21 +24,57 @@ HERE = os.path.dirname(os.path.abspath(__file__))
 BENCH = os.path.dirname(HERE)
 ROOT = os.path.dirname(BENCH)
 
+# How long a parent waits for the comparison that follows `stop` in the
+# cells that decide `correct` after their window: three times the
+# slowest healthy one, which is mistral-small-4.doc-qa's on a machine
+# that has compiled none of the reference's programs, 270 s (glm-5.
+# long-sessions 221-234 s, reasoning 143 s, mixed-queue 88 s; 63 s and
+# under once compiled; my chip runs, PR 55, PERF.md section 6).
+CHECK_TIMEOUT_S = 810.0
+KILL_WAIT_S = 60.0
+
+# The children this process has started and not yet seen dead. A module
+# list, because what reads it is the process's own end (`kill_live`, from
+# run.py's signal handlers and `atexit`), which has no object to ask.
+LIVE: list["Child"] = []
+
+
+def kill_live() -> None:
+    for child in list(LIVE):
+        child.kill()
+
+
+class ChildSilent(SystemExit):
+    """The child did not say what was waited for, and has been killed."""
+
+
 class Child:
+    """The process that holds the chip, and the ONE place that starts
+    and ends one. It gets a session (so a process group) of its own:
+    `kill` signals the group and returns when nothing of it is alive. It
+    is told this process's pid and dies with it (`lifeline.
+    tie_to_parent`), whatever this process dies of. A runner names its
+    own child script in a subclass and changes nothing else."""
+
+    script = "serve_child.py"
+
     def __init__(self, conf: dict, seed: int, chips: int, rehearse: bool,
                  trace_dir: str, log_path: str):
         env = dict(os.environ)
         env["PYTHONPATH"] = ROOT + os.pathsep + env.get("PYTHONPATH", "")
         self.log = open(log_path, "w")
+        self.events: list[dict] = []
+        self.asked_to_stop = False
         self.proc = subprocess.Popen(
-            [sys.executable, os.path.join(HERE, "serve_child.py"),
+            [sys.executable, os.path.join(HERE, self.script),
              "--config", json.dumps(conf), "--seed", str(seed),
              "--chips", str(chips), "--rehearse", str(int(rehearse)),
-             "--trace-dir", trace_dir],
+             "--trace-dir", trace_dir, "--parent-pid", str(os.getpid())],
             stdin=subprocess.PIPE, stdout=subprocess.PIPE, stderr=self.log,
-            text=True, cwd=ROOT, env=env,
+            text=True, cwd=ROOT, env=env, start_new_session=True,
         )
-        self.events: list[dict] = []
+        self.pgid = self.proc.pid  # a session leader leads its group
+        LIVE.append(self)
 
     def wait_for(self, event: str, timeout: float) -> dict:
         """Next `event` line from the child (other stdout is logged)."""
@@ -62,23 +100,37 @@ class Child:
         t.start()
         t.join(max(0.0, deadline - time.monotonic()))
         if not box or box[0] is None:
+            how = (f"within {timeout:.0f} s" if not box
+                   else f"(exit {self.proc.poll()})")
             self.kill()
-            raise SystemExit(
-                f"serve child: no {event!r} (exit {self.proc.poll()}); "
-                f"see {self.log.name}"
-            )
+            raise ChildSilent(
+                f"serve child: no {event!r} {how}; see {self.log.name}")
         return box[0]
 
     def tell(self, cmd: str, event: str, timeout: float = 120.0) -> dict:
+        self.asked_to_stop |= cmd == "stop"
         self.proc.stdin.write(cmd + "\n")
         self.proc.stdin.flush()
         return self.wait_for(event, timeout)
 
+    def check_after_window(self, timeout: float = CHECK_TIMEOUT_S) -> dict:
+        """`stop` after `disarm`: the child closes its server and holds
+        what the window served to the reference. A comparison that has
+        not ended in `timeout` seconds is a run that is not correct,
+        with a problem that says so; the child is dead by then."""
+        try:
+            return self.tell("stop", "logit_check", timeout)
+        except ChildSilent as e:
+            return {"ok": False, "problem": f"no comparison: {e}"}
+
     def stop(self) -> None:
-        if self.proc.poll() is None:
+        """Ask, wait 60 s, kill. On the way out of a fault (an exception
+        in flight) nobody will read an answer: kill at once."""
+        if self.proc.poll() is None and sys.exc_info()[0] is None:
             try:
-                self.proc.stdin.write("stop\n")
-                self.proc.stdin.flush()
+                if not self.asked_to_stop:
+                    self.proc.stdin.write("stop\n")
+                    self.proc.stdin.flush()
                 self.proc.wait(timeout=60)
             except (OSError, ValueError, subprocess.TimeoutExpired):
                 # fault-boundary: a child that is gone or deaf is killed
@@ -87,10 +139,84 @@ class Child:
         self.kill()
 
     def kill(self) -> None:
-        if self.proc.poll() is None:
-            self.proc.kill()
+        """SIGKILL to the child's group; returns when the child is
+        reaped and no process of the group is left alive."""
+        if self not in LIVE:
+            return
+        deadline = time.monotonic() + KILL_WAIT_S
+        while group_alive(self.pgid):
+            with contextlib.suppress(ProcessLookupError, PermissionError):
+                os.killpg(self.pgid, signal.SIGKILL)
+            self.proc.poll()  # reaps the child once it is dead
+            if time.monotonic() > deadline:
+                self.log.write(f"group {self.pgid} outlived SIGKILL by "
+                               f"{KILL_WAIT_S:.0f} s\n")
+                break
+            time.sleep(0.02)
         self.proc.wait()
+        with contextlib.suppress(ValueError):  # a signal's kill_live got here first
+            LIVE.remove(self)
         self.log.flush()
+
+
+def group_alive(pgid: int) -> bool:
+    """Whether any process of the group is alive. A zombie is not: it
+    holds no chip and no port, and whoever inherits it reaps it."""
+    try:
+        pids = [d for d in os.listdir("/proc") if d.isdigit()]
+    except OSError:  # no /proc: ask the kernel, which counts zombies too
+        with contextlib.suppress(ProcessLookupError):
+            os.killpg(pgid, 0)
+            return True
+        return False
+    for pid in pids:
+        try:
+            with open(f"/proc/{pid}/stat") as f:
+                # pid (comm) state ppid pgrp ...: comm may hold spaces
+                state, _, pgrp = f.read().rsplit(")", 1)[1].split()[:3]
+        except (OSError, ValueError):
+            continue  # gone while we looked
+        if int(pgrp) == pgid and state not in "ZX":
+            return True
+    return False
+
+
+class Phases:
+    """Wall seconds of a run's phases, for run.py's info line: `mark`
+    gives the named phase the time since the mark before it (the first
+    one: since the process started), so the phases add up to the run as
+    far as the last mark. Each mark is also a line `phase <name>
+    <seconds>` in the child's log, the moment it is made."""
+
+    def __init__(self, t_start: float, log):
+        self._last = t_start
+        self._log = log
+        self.seconds: dict[str, float] = {}
+
+    def mark(self, name: str) -> None:
+        now = time.monotonic()
+        self.seconds[name] = now - self._last
+        self._last = now
+        # as it happens, for whoever watches the run
+        self._log.write(f"phase {name} {self.seconds[name]:.3f}\n")
+        self._log.flush()
+
+
+def check_problems(check: dict, want_kinds) -> list[str]:
+    """What a comparison after the window (`Child.check_after_window`)
+    leaves to say: that it never came, which clauses failed, which kinds
+    of request the window finished none of."""
+    if check.get("problem"):
+        return [check["problem"]]
+    problems = []
+    if not check["ok"]:
+        failed = [k for k, v in check.get("passed", {}).items() if not v]
+        problems.append(f"the served tokens' check failed: {', '.join(failed)}")
+    kinds = {w["kind"] for w in check.get("sample", [])}
+    if not set(want_kinds) <= kinds:
+        problems.append(f"the window finished no "
+                        f"{sorted(set(want_kinds) - kinds)} request to compare")
+    return problems
 
 
 _SAMPLE = re.compile(r"^([a-zA-Z_:][\w:]*)(\{[^}]*\})?\s+([0-9.eE+-]+|NaN)$")
@@ -211,6 +337,7 @@ def run(ctx: dict, *, open_loop: bool) -> dict:
     shutil.rmtree(trace_dir, ignore_errors=True)
     child = Child(conf, seed, ctx["chips"], ctx["rehearse"], trace_dir,
                   os.path.join(ctx["out_dir"], "serve_child.log"))
+    ph = Phases(ctx["t_start"], child.log)
     try:
         # Traffic is made while the child initialises and compiles.
         if open_loop:
@@ -245,6 +372,7 @@ def run(ctx: dict, *, open_loop: bool) -> dict:
                 for c in per_client
             ]
         dev = child.wait_for("device", 600)
+        ph.mark("device")
         # The embed widths a prompt can be padded to are the program's
         # own (each a small compiled program): the child reads them.
         warm = loadgen.encode_bodies(
@@ -252,7 +380,9 @@ def run(ctx: dict, *, open_loop: bool) -> dict:
         )
         ready = child.wait_for("ready", ctx["setup_timeout"])
         port = ready["port"]
+        ph.mark("ready")
         check = next(e for e in child.events if e["event"] == "logit_check")
+        ph.seconds["comparison_in_ready"] = check["seconds"]
 
         # Warm-up: every shape once, then a few at once so that the
         # engine has admitted into a running batch.
@@ -270,10 +400,12 @@ def run(ctx: dict, *, open_loop: bool) -> dict:
             "127.0.0.1", port, [[b] for b in burst], 600.0, until_done=True
         )
         warm_s = time.monotonic() - t_w
+        ph.mark("warmup")
 
         child.tell("arm", "armed")
         before = scrape(port)
         setup_s = time.monotonic() - ctx["t_start"]
+        ph.mark("arm")
         tracer, slice_ = None, {}
         if ctx["trace"]:
             tracer = threading.Thread(
@@ -295,9 +427,12 @@ def run(ctx: dict, *, open_loop: bool) -> dict:
         after = scrape(port)
         if tracer is not None:
             tracer.join()
+        ph.mark("window")
         end = child.tell("disarm", "disarmed", 300.0)
+        ph.mark("disarm")
     finally:
         child.stop()
+    ph.mark("stop")
     red = reduce_requests(
         res, first_token_limit_s=p.get("first_token_limit_s"))
     delta = {k: after.get(k, 0.0) - before.get(k, 0.0) for k in after}
@@ -343,6 +478,7 @@ def run(ctx: dict, *, open_loop: bool) -> dict:
             "warmup_s": warm_s,
         },
         "compiles_in_window": compiles,
+        "phases": ph.seconds,
     }
 
 

@@ -7,39 +7,18 @@ reference (`serve_blockdiff_child.py`). Never imports jax."""
 
 from __future__ import annotations
 
-import json
 import os
 import random
 import shutil
-import subprocess
-import sys
 import threading
 import time
 
 from benchmark import loadgen, traffic
 from benchmark.runners import serve
 
-HERE = os.path.dirname(os.path.abspath(__file__))
-ROOT = os.path.dirname(os.path.dirname(HERE))
-
 
 class Child(serve.Child):
-    """serve.Child around this cell's own child script."""
-
-    def __init__(self, conf: dict, seed: int, chips: int, rehearse: bool,
-                 trace_dir: str, log_path: str):
-        env = dict(os.environ)
-        env["PYTHONPATH"] = ROOT + os.pathsep + env.get("PYTHONPATH", "")
-        self.log = open(log_path, "w")
-        self.proc = subprocess.Popen(
-            [sys.executable, os.path.join(HERE, "serve_blockdiff_child.py"),
-             "--config", json.dumps(conf), "--seed", str(seed),
-             "--chips", str(chips), "--rehearse", str(int(rehearse)),
-             "--trace-dir", trace_dir],
-            stdin=subprocess.PIPE, stdout=subprocess.PIPE, stderr=self.log,
-            text=True, cwd=ROOT, env=env,
-        )
-        self.events: list[dict] = []
+    script = "serve_blockdiff_child.py"
 
 
 def warm_copy_on_write(port: int, page_size: int, seed: int) -> None:
@@ -67,6 +46,7 @@ def run(ctx: dict) -> dict:
     shutil.rmtree(trace_dir, ignore_errors=True)
     child = Child(conf, seed, ctx["chips"], ctx["rehearse"], trace_dir,
                   os.path.join(ctx["out_dir"], "serve_child.log"))
+    ph = serve.Phases(ctx["t_start"], child.log)
     try:
         # Traffic is made while the child initialises and compiles.
         clients = p["clients"]
@@ -80,12 +60,15 @@ def run(ctx: dict) -> dict:
             for c in per_client
         ]
         dev = child.wait_for("device", 600)
+        ph.mark("device")
         warm = loadgen.encode_bodies(
             traffic.warmup_bodies(p, dev["embed_buckets"], seed)
         )
         ready = child.wait_for("ready", ctx["setup_timeout"])
         port = ready["port"]
+        ph.mark("ready")
         check = next(e for e in child.events if e["event"] == "logit_check")
+        ph.seconds["comparison_in_ready"] = check["seconds"]
 
         t_w = time.monotonic()
         for payload, want in warm:
@@ -102,10 +85,12 @@ def run(ctx: dict) -> dict:
             "127.0.0.1", port, [[b] for b in burst], 600.0, until_done=True
         )
         warm_s = time.monotonic() - t_w
+        ph.mark("warmup")
 
         child.tell("arm", "armed")
         before = serve.scrape(port)
         setup_s = time.monotonic() - ctx["t_start"]
+        ph.mark("arm")
         tracer, slice_ = None, {}
         if ctx["trace"]:
             tracer = threading.Thread(
@@ -121,9 +106,12 @@ def run(ctx: dict) -> dict:
         after = serve.scrape(port)
         if tracer is not None:
             tracer.join()
+        ph.mark("window")
         end = child.tell("disarm", "disarmed", 300.0)
+        ph.mark("disarm")
     finally:
         child.stop()
+    ph.mark("stop")
     red = serve.reduce_requests(
         res, first_token_limit_s=p.get("first_token_limit_s"))
     delta = {k: after.get(k, 0.0) - before.get(k, 0.0) for k in after}
@@ -167,4 +155,5 @@ def run(ctx: dict) -> dict:
             "warmup_s": warm_s,
         },
         "compiles_in_window": compiles,
+        "phases": ph.seconds,
     }

@@ -13,7 +13,6 @@ parent commit) leaves at once, before it touches the device.
 from __future__ import annotations
 
 import argparse
-import contextlib
 import dataclasses
 import json
 import os
@@ -24,6 +23,9 @@ import time
 HERE = os.path.dirname(os.path.abspath(__file__))
 ROOT = os.path.dirname(os.path.dirname(HERE))
 sys.path.insert(0, ROOT)
+
+from benchmark.runners import lifeline  # noqa: E402
+from benchmark.runners.lifeline import say  # noqa: E402
 
 T_START = time.monotonic()
 
@@ -85,11 +87,6 @@ class SpreadTokenizer:
         return "".join(f"<{int(i)}>" for i in ids)
 
 
-def say(**kw) -> None:
-    sys.stdout.write(json.dumps(kw) + "\n")
-    sys.stdout.flush()
-
-
 def build_config(conf: dict):
     """The named preset with the file's layout, through program.py's own
     builder, plus the server-side block settings; then the expert keys
@@ -136,7 +133,9 @@ def main(argv=None) -> int:
     ap.add_argument("--chips", type=int, default=1)
     ap.add_argument("--rehearse", type=int, default=0)
     ap.add_argument("--trace-dir", default="")
+    lifeline.add_parent_pid(ap)
     args = ap.parse_args(argv)
+    lifeline.tie_to_parent(args.parent_pid)  # before jax, before the chip
     conf = json.loads(args.config)
 
     from benchmark import program
@@ -152,7 +151,6 @@ def main(argv=None) -> int:
 
     import jax
 
-    from oryx_tpu.analysis.sanitizers import recompile_watchdog
     from oryx_tpu.serve import api_server
     from oryx_tpu.serve.pipeline import OryxInference
 
@@ -189,54 +187,8 @@ def main(argv=None) -> int:
     say(event="ready", port=srv.server_address[1],
         t=time.monotonic() - T_START)
 
-    stack = contextlib.ExitStack()
-    wd = None
-    trace_t = {}
-    try:
-        for line in sys.stdin:
-            cmd = line.strip()
-            if cmd == "arm":
-                wd = stack.enter_context(
-                    recompile_watchdog(budget=10**9, action="record")
-                )
-                say(event="armed")
-            elif cmd == "trace_start":
-                opts = jax.profiler.ProfileOptions()
-                opts.python_tracer_level = 0  # host spans, no py stacks
-                jax.profiler.start_trace(
-                    args.trace_dir, profiler_options=opts
-                )
-                trace_t["start"] = time.monotonic()
-                say(event="trace_started")
-            elif cmd == "trace_stop":
-                trace_t["stop"] = time.monotonic()
-                jax.profiler.stop_trace()
-                say(event="trace_stopped",
-                    seconds=trace_t["stop"] - trace_t["start"])
-            elif cmd == "disarm":
-                stack.close()
-                out = {
-                    "event": "disarmed",
-                    "compiles": int(wd.total) if wd else None,
-                    "compile_counts": dict(wd.counts) if wd else {},
-                    "memory_peak_bytes": program.memory_peak_bytes(),
-                }
-                if trace_t:
-                    from benchmark import trace as trace_lib
-
-                    out["trace"] = trace_lib.reduce_dir(
-                        args.trace_dir,
-                        window_s=trace_t["stop"] - trace_t["start"],
-                    )
-                say(**out)
-            elif cmd == "stop":
-                break
-    finally:
-        if srv.supervisor is not None:
-            srv.supervisor.stop()
-        srv.scheduler.close()
-        srv.shutdown()
-        srv.server_close()
+    if not lifeline.serve_until_stopped(srv, args.trace_dir):
+        return lifeline.ORPHANED
     say(event="stopped")
     return 0
 

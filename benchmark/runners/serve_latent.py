@@ -19,41 +19,21 @@ Never imports jax."""
 
 from __future__ import annotations
 
-import json
 import os
 import random
 import shutil
 import string
-import subprocess
-import sys
 import threading
 import time
 
 from benchmark import loadgen, traffic
 from benchmark.runners import serve, serve_blockdiff
 
-HERE = os.path.dirname(os.path.abspath(__file__))
-ROOT = os.path.dirname(os.path.dirname(HERE))
 _ALPHABET = string.ascii_letters + string.digits
 
 
 class Child(serve.Child):
-    """serve.Child around this cell's own child script."""
-
-    def __init__(self, conf: dict, seed: int, chips: int, rehearse: bool,
-                 trace_dir: str, log_path: str):
-        env = dict(os.environ)
-        env["PYTHONPATH"] = ROOT + os.pathsep + env.get("PYTHONPATH", "")
-        self.log = open(log_path, "w")
-        self.proc = subprocess.Popen(
-            [sys.executable, os.path.join(HERE, "serve_latent_child.py"),
-             "--config", json.dumps(conf), "--seed", str(seed),
-             "--chips", str(chips), "--rehearse", str(int(rehearse)),
-             "--trace-dir", trace_dir],
-            stdin=subprocess.PIPE, stdout=subprocess.PIPE, stderr=self.log,
-            text=True, cwd=ROOT, env=env,
-        )
-        self.events: list[dict] = []
+    script = "serve_latent_child.py"
 
 
 def tagged_sessions(params: dict, seed: int, n_requests: int) -> list[list]:
@@ -112,17 +92,21 @@ def run(ctx: dict) -> dict:
     shutil.rmtree(trace_dir, ignore_errors=True)
     child = Child(conf, seed, ctx["chips"], ctx["rehearse"], trace_dir,
                   os.path.join(ctx["out_dir"], "serve_child.log"))
+    ph = serve.Phases(ctx["t_start"], child.log)
     try:
         # Traffic is made while the child initialises and compiles.
         window, before = client_lists(p, seed, seconds)
         client_items = [loadgen.encode_bodies(c) for c in window]
         dev = child.wait_for("device", 600)
+        ph.mark("device")
         warm = loadgen.encode_bodies(
             traffic.warmup_bodies(p, dev["embed_buckets"], seed)
         )
         ready = child.wait_for("ready", ctx["setup_timeout"])
         port = ready["port"]
+        ph.mark("ready")
         check = next(e for e in child.events if e["event"] == "logit_check")
+        ph.seconds["comparison_in_ready"] = check["seconds"]
 
         t_w = time.monotonic()
         for payload, want in warm:
@@ -138,6 +122,7 @@ def run(ctx: dict) -> dict:
         loadgen.run_closed_loop(
             "127.0.0.1", port, [[b] for b in burst], 600.0, until_done=True
         )
+        ph.mark("warmup")
         # Sessions in progress: their histories, after every shape.
         res = loadgen.run_closed_loop(
             "127.0.0.1", port,
@@ -148,10 +133,12 @@ def run(ctx: dict) -> dict:
         if bad:
             raise SystemExit(f"serve_latent: a history failed: {bad[0]}")
         warm_s = time.monotonic() - t_w
+        ph.mark("histories")
 
         child.tell("arm", "armed")
         scraped = serve.scrape(port)
         setup_s = time.monotonic() - ctx["t_start"]
+        ph.mark("arm")
         tracer, slice_ = None, {}
         if ctx["trace"]:
             tracer = threading.Thread(
@@ -167,9 +154,12 @@ def run(ctx: dict) -> dict:
         after = serve.scrape(port)
         if tracer is not None:
             tracer.join()
+        ph.mark("window")
         end = child.tell("disarm", "disarmed", 300.0)
+        ph.mark("disarm")
     finally:
         child.stop()
+    ph.mark("stop")
     red = serve.reduce_requests(
         res, first_token_limit_s=p.get("first_token_limit_s"))
     delta = {k: after.get(k, 0.0) - scraped.get(k, 0.0) for k in after}
@@ -213,4 +203,5 @@ def run(ctx: dict) -> dict:
             "warmup_s": warm_s, "histories_sent": len(before),
         },
         "compiles_in_window": compiles,
+        "phases": ph.seconds,
     }

@@ -14,7 +14,6 @@ parent commit) leaves at once, before it touches the device.
 from __future__ import annotations
 
 import argparse
-import contextlib
 import json
 import os
 import sys
@@ -24,6 +23,9 @@ import time
 HERE = os.path.dirname(os.path.abspath(__file__))
 ROOT = os.path.dirname(os.path.dirname(HERE))
 sys.path.insert(0, ROOT)
+
+from benchmark.runners import lifeline  # noqa: E402
+from benchmark.runners.lifeline import say  # noqa: E402
 
 T_START = time.monotonic()
 
@@ -91,9 +93,32 @@ class PrefixTokenizer:
         return "".join(f"<{int(i)}>" for i in ids)
 
 
-def say(**kw) -> None:
-    sys.stdout.write(json.dumps(kw) + "\n")
-    sys.stdout.flush()
+class NoStopPrefixTokenizer(PrefixTokenizer):
+    """PrefixTokenizer for a cell whose every request has to run to its
+    `max_tokens` at every seed. The program turns the chat template's
+    stop string ("\n" under `plain`) into token ids ONCE, with this
+    tokenizer (`generate.make_stop_sequences`), and ends a lane on the
+    device when the ids it emitted match them. PrefixTokenizer gives
+    that one character one id below `hi`, the same at every seed, and
+    seeded random weights emit every id about once in `hi` greedy
+    tokens: about one request in twenty of glm-5.long-sessions ended
+    early with `finish_reason` "stop", at another place at every seed,
+    its client went on to its next prompt, and `serve_tok_s` read up to
+    3 % apart between seeds whose runs repeat to five digits (my chip
+    runs, PR 55; PERF.md section 6). Here a text that IS a stop string
+    gets ids `hi + 1`, which the head has no row for (and which is not
+    the preset's EOS id, `hi`): the stop can never match. Every other
+    text, so every prompt, gets PrefixTokenizer's ids."""
+
+    def __init__(self, hi: int, stop_strs=("\n",)):
+        super().__init__(hi)
+        self._never = hi + 1
+        self._stop_strs = frozenset(stop_strs)
+
+    def encode(self, text, add_special_tokens=False):
+        if text in self._stop_strs:
+            return [self._never] * len(text)
+        return super().encode(text, add_special_tokens)
 
 
 def build_config(conf: dict):
@@ -127,52 +152,6 @@ def build_config(conf: dict):
     return cfg
 
 
-def serve_commands(srv, trace_dir: str) -> None:
-    """Obey the parent's one-line commands until `stop`."""
-    import jax
-
-    from oryx_tpu.analysis.sanitizers import recompile_watchdog
-
-    from benchmark import program
-
-    stack = contextlib.ExitStack()
-    wd = None
-    trace_t = {}
-    for line in sys.stdin:
-        cmd = line.strip()
-        if cmd == "arm":
-            wd = stack.enter_context(
-                recompile_watchdog(budget=10**9, action="record"))
-            say(event="armed")
-        elif cmd == "trace_start":
-            opts = jax.profiler.ProfileOptions()
-            opts.python_tracer_level = 0  # host spans, no py stacks
-            jax.profiler.start_trace(trace_dir, profiler_options=opts)
-            trace_t["start"] = time.monotonic()
-            say(event="trace_started")
-        elif cmd == "trace_stop":
-            trace_t["stop"] = time.monotonic()
-            jax.profiler.stop_trace()
-            say(event="trace_stopped",
-                seconds=trace_t["stop"] - trace_t["start"])
-        elif cmd == "disarm":
-            stack.close()
-            out = {
-                "event": "disarmed",
-                "compiles": int(wd.total) if wd else None,
-                "compile_counts": dict(wd.counts) if wd else {},
-                "memory_peak_bytes": program.memory_peak_bytes(),
-            }
-            if trace_t:
-                from benchmark import trace as trace_lib
-
-                out["trace"] = trace_lib.reduce_dir(
-                    trace_dir, window_s=trace_t["stop"] - trace_t["start"])
-            say(**out)
-        elif cmd == "stop":
-            break
-
-
 def main(argv=None) -> int:
     ap = argparse.ArgumentParser()
     ap.add_argument("--config", required=True)  # resolved json, inline
@@ -180,7 +159,9 @@ def main(argv=None) -> int:
     ap.add_argument("--chips", type=int, default=1)
     ap.add_argument("--rehearse", type=int, default=0)
     ap.add_argument("--trace-dir", default="")
+    lifeline.add_parent_pid(ap)
     args = ap.parse_args(argv)
+    lifeline.tie_to_parent(args.parent_pid)  # before jax, before the chip
     conf = json.loads(args.config)
 
     from benchmark import program
@@ -228,14 +209,8 @@ def main(argv=None) -> int:
     threading.Thread(target=srv.serve_forever, daemon=True).start()
     say(event="ready", port=srv.server_address[1],
         t=time.monotonic() - T_START)
-    try:
-        serve_commands(srv, args.trace_dir)
-    finally:
-        if srv.supervisor is not None:
-            srv.supervisor.stop()
-        srv.scheduler.close()
-        srv.shutdown()
-        srv.server_close()
+    if not lifeline.serve_until_stopped(srv, args.trace_dir):
+        return lifeline.ORPHANED
     say(event="stopped")
     return 0
 

@@ -14,45 +14,27 @@ client order it uses as they are, with its own session builder:
     failing there: a context of 49k tokens has room for five turns, one
     of 13k for all twelve.
   - the child is `serve_longctx_child.py` (its configuration keys, its
-    comparison: correctness_glm5.py).
+    comparison: correctness_glm5.py, a tokenizer under which no emitted
+    id is the template's stop).
+  - a request that ends before its `max_tokens` makes the run
+    incorrect: the work of a window is the same at every seed.
 
 Never imports jax."""
 
 from __future__ import annotations
 
-import json
 import os
 import random
 import shutil
-import subprocess
-import sys
 import threading
 import time
 
 from benchmark import loadgen, traffic
 from benchmark.runners import serve, serve_blockdiff, serve_docqa
 
-HERE = os.path.dirname(os.path.abspath(__file__))
-ROOT = os.path.dirname(os.path.dirname(HERE))
-
 
 class Child(serve.Child):
-    """serve.Child around this cell's own child script."""
-
-    def __init__(self, conf: dict, seed: int, chips: int, rehearse: bool,
-                 trace_dir: str, log_path: str):
-        env = dict(os.environ)
-        env["PYTHONPATH"] = ROOT + os.pathsep + env.get("PYTHONPATH", "")
-        self.log = open(log_path, "w")
-        self.proc = subprocess.Popen(
-            [sys.executable, os.path.join(HERE, "serve_longctx_child.py"),
-             "--config", json.dumps(conf), "--seed", str(seed),
-             "--chips", str(chips), "--rehearse", str(int(rehearse)),
-             "--trace-dir", trace_dir],
-            stdin=subprocess.PIPE, stdout=subprocess.PIPE, stderr=self.log,
-            text=True, cwd=ROOT, env=env,
-        )
-        self.events: list[dict] = []
+    script = "serve_longctx_child.py"
 
 
 def long_sessions(params: dict, seed: int) -> list[list]:
@@ -128,16 +110,19 @@ def run(ctx: dict) -> dict:
     shutil.rmtree(trace_dir, ignore_errors=True)
     child = Child(conf, seed, ctx["chips"], ctx["rehearse"], trace_dir,
                   os.path.join(ctx["out_dir"], "serve_child.log"))
+    ph = serve.Phases(ctx["t_start"], child.log)
     try:
         # Traffic is made while the child initialises and compiles.
         window, before = client_lists(p, seed)
         client_items = [loadgen.encode_bodies(c) for c in window]
         dev = child.wait_for("device", 600)
+        ph.mark("device")
         warm = loadgen.encode_bodies(serve_docqa.warmup_bodies(
             {**p, "question_tokens": p["user_tokens"]},
             dev["embed_buckets"], seed))
         ready = child.wait_for("ready", ctx["setup_timeout"])
         port = ready["port"]
+        ph.mark("ready")
 
         t_w = time.monotonic()
         for payload, want in warm:
@@ -152,6 +137,7 @@ def run(ctx: dict) -> dict:
         loadgen.run_closed_loop(
             "127.0.0.1", port, [[b] for b in burst], 600.0, until_done=True
         )
+        ph.mark("warmup")
         # Sessions in progress: their contexts, after every shape.
         res = loadgen.run_closed_loop(
             "127.0.0.1", port,
@@ -162,10 +148,12 @@ def run(ctx: dict) -> dict:
         if bad:
             raise SystemExit(f"serve_longctx: a history failed: {bad[0]}")
         warm_s = time.monotonic() - t_w
+        ph.mark("histories")
 
         child.tell("arm", "armed")
         scraped = serve.scrape(port)
         setup_s = time.monotonic() - ctx["t_start"]
+        ph.mark("arm")
         tracer, slice_ = None, {}
         if ctx["trace"]:
             tracer = threading.Thread(
@@ -181,26 +169,23 @@ def run(ctx: dict) -> dict:
         after = serve.scrape(port)
         if tracer is not None:
             tracer.join()
+        ph.mark("window")
         end = child.tell("disarm", "disarmed", 300.0)
+        ph.mark("disarm")
         # The comparison comes after the window, on what it served.
-        check = child.tell("stop", "logit_check", ctx["setup_timeout"])
+        check = child.check_after_window()
+        ph.mark("comparison")
     finally:
         child.stop()
+    ph.mark("stop")
     red = serve.reduce_requests(
         res, first_token_limit_s=p.get("first_token_limit_s"))
     delta = {k: after.get(k, 0.0) - scraped.get(k, 0.0) for k in after}
     compiles = end.get("compiles")
     raw = {"ttft_ms": red.pop("ttft_ms"), "tpot_ms": red.pop("tpot_ms")}
     lateness = red.pop("lateness_ms")
-    problems = []
-    if not check["ok"]:
-        failed = [k for k, v in check.get("passed", {}).items() if not v]
-        problems.append(f"the served tokens' check failed: {', '.join(failed)}")
-    kinds = {w["kind"] for w in check.get("sample", [])}
-    want = set(p.get("check_sample_kinds", ()))
-    if not want <= kinds:
-        problems.append(f"the window finished no {sorted(want - kinds)} "
-                        "request to compare")
+    problems = serve.check_problems(
+        check, p.get("check_sample_kinds", ()))
     if compiles:
         problems.append(f"{compiles} compiles inside the window: "
                         f"{end.get('compile_counts')}")
@@ -211,6 +196,17 @@ def run(ctx: dict) -> dict:
     if res.get("exhausted_clients"):
         problems.append("a client ran out of requests before the window "
                         "ended: raise sessions_per_client")
+    stopped = [r for r in res["records"] if r.get("finish") == "stop"]
+    if stopped:
+        # Every request of this mix runs to its max_tokens (the child's
+        # tokenizer keeps the template's stop out of reach): one that
+        # stops sooner sends its client on early, and the seed has
+        # changed the work.
+        problems.append(
+            f"{len(stopped)} requests ended before their max_tokens "
+            f"(finish_reason stop, {stopped[0]['tokens']} of "
+            f"{stopped[0]['want_tokens']} tokens the first): the window's "
+            "work depends on the seed")
     device = dict(dev["device"], memory_peak_bytes=end["memory_peak_bytes"])
     tr = end.get("trace") or {}
     if tr:
@@ -234,4 +230,5 @@ def run(ctx: dict) -> dict:
             "check_after_window": check,
         },
         "compiles_in_window": compiles,
+        "phases": ph.seconds,
     }

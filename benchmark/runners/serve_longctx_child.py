@@ -6,9 +6,10 @@ learned-sparse-attention model (GLM-5).
 
 and then the same one-line commands on stdin and JSON events on stdout
 as runners/serve_docqa_child.py, whose `Served` and `sample_served` it
-uses as they are (with serve_latent_child's tokenizer and command
-loop): between `arm` and `disarm` every request handed to the engine is
-kept with its handle; on `stop` the server is closed and its pool given
+uses as they are (with serve_latent_child's tokenizer, in the form
+whose stop string no emitted id can match, and its command loop):
+between `arm` and `disarm` every request handed to the engine is kept
+with its handle; on `stop` the server is closed and its pool given
 back, a sample of the requests the window FINISHED is taken and the
 tokens the engine streamed for them go to correctness_glm5.logit_check
 with their prompts; the `logit_check` event follows `stop`, before
@@ -30,11 +31,13 @@ HERE = os.path.dirname(os.path.abspath(__file__))
 ROOT = os.path.dirname(os.path.dirname(HERE))
 sys.path.insert(0, ROOT)
 
+from benchmark.runners import lifeline  # noqa: E402
+from benchmark.runners.lifeline import say  # noqa: E402
 from benchmark.runners.serve_docqa_child import (  # noqa: E402
     Served, sample_served,
 )
 from benchmark.runners.serve_latent_child import (  # noqa: E402
-    PrefixTokenizer, say, serve_commands,
+    NoStopPrefixTokenizer,
 )
 
 T_START = time.monotonic()
@@ -117,7 +120,9 @@ def main(argv=None) -> int:
     ap.add_argument("--chips", type=int, default=1)
     ap.add_argument("--rehearse", type=int, default=0)
     ap.add_argument("--trace-dir", default="")
+    lifeline.add_parent_pid(ap)
     args = ap.parse_args(argv)
+    lifeline.tie_to_parent(args.parent_pid)  # before jax, before the chip
     conf = json.loads(args.config)
 
     from benchmark import program
@@ -144,8 +149,10 @@ def main(argv=None) -> int:
     say(event="init", seconds=time.monotonic() - t0,
         params=int(sum(x.size for x in jax.tree.leaves(params))))
 
-    pipe = OryxInference(PrefixTokenizer(cfg.llm.vocab_size), params, cfg,
-                         template="plain")
+    # No id the model can emit is the template's stop: every request
+    # runs to its max_tokens, so every seed serves the same work.
+    pipe = OryxInference(NoStopPrefixTokenizer(cfg.llm.vocab_size), params,
+                         cfg, template="plain")
     srv = api_server.build_server(
         pipe, port=0, engine="continuous", num_slots=lay["num_slots"],
         page_size=lay["page_size"], decode_chunk=lay["decode_chunk"],
@@ -159,14 +166,8 @@ def main(argv=None) -> int:
     sys.stdin = served  # serve_commands reads its lines through it
     say(event="ready", port=srv.server_address[1],
         t=time.monotonic() - T_START)
-    try:
-        serve_commands(srv, args.trace_dir)
-    finally:
-        if srv.supervisor is not None:
-            srv.supervisor.stop()
-        srv.scheduler.close()
-        srv.shutdown()
-        srv.server_close()
+    if not lifeline.serve_until_stopped(srv, args.trace_dir):
+        return lifeline.ORPHANED
     if served.window_closed:
         # The engine's pool goes before the reference's float32
         # forwards come: both do not fit beside the weights.

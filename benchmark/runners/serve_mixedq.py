@@ -27,36 +27,14 @@ Never imports jax."""
 
 from __future__ import annotations
 
-import json
-import os
 import random
-import subprocess
-import sys
 
 from benchmark import traffic
 from benchmark.runners import serve
 
-HERE = os.path.dirname(os.path.abspath(__file__))
-ROOT = os.path.dirname(os.path.dirname(HERE))
-
 
 class Child(serve.Child):
-    """serve.Child around this cell's own child script."""
-
-    def __init__(self, conf: dict, seed: int, chips: int, rehearse: bool,
-                 trace_dir: str, log_path: str):
-        env = dict(os.environ)
-        env["PYTHONPATH"] = ROOT + os.pathsep + env.get("PYTHONPATH", "")
-        self.log = open(log_path, "w")
-        self.proc = subprocess.Popen(
-            [sys.executable, os.path.join(HERE, "serve_mixedq_child.py"),
-             "--config", json.dumps(conf), "--seed", str(seed),
-             "--chips", str(chips), "--rehearse", str(int(rehearse)),
-             "--trace-dir", trace_dir],
-            stdin=subprocess.PIPE, stdout=subprocess.PIPE, stderr=self.log,
-            text=True, cwd=ROOT, env=env,
-        )
-        self.events: list[dict] = []
+    script = "serve_mixedq_child.py"
 
 
 def mixed_requests(p: dict, seed: int, n: int) -> list[dict]:

@@ -32,9 +32,11 @@ HERE = os.path.dirname(os.path.abspath(__file__))
 ROOT = os.path.dirname(os.path.dirname(HERE))
 sys.path.insert(0, ROOT)
 
+from benchmark.runners import lifeline  # noqa: E402
+from benchmark.runners.lifeline import say  # noqa: E402
 from benchmark.runners.serve_docqa_child import _TOKEN, Served  # noqa: E402
 from benchmark.runners.serve_latent_child import (  # noqa: E402
-    PrefixTokenizer, say, serve_commands,
+    PrefixTokenizer,
 )
 
 T_START = time.monotonic()
@@ -171,7 +173,9 @@ def main(argv=None) -> int:
     ap.add_argument("--chips", type=int, default=1)
     ap.add_argument("--rehearse", type=int, default=0)
     ap.add_argument("--trace-dir", default="")
+    lifeline.add_parent_pid(ap)
     args = ap.parse_args(argv)
+    lifeline.tie_to_parent(args.parent_pid)  # before jax, before the chip
     conf = json.loads(args.config)
 
     from benchmark import program
@@ -215,14 +219,8 @@ def main(argv=None) -> int:
         t=time.monotonic() - T_START,
         window_pages=int(srv.scheduler.num_window_pages),
         window_table_pages=int(srv.scheduler.wplane.tables.shape[1]))
-    try:
-        serve_commands(srv, args.trace_dir)
-    finally:
-        if srv.supervisor is not None:
-            srv.supervisor.stop()
-        srv.scheduler.close()
-        srv.shutdown()
-        srv.server_close()
+    if not lifeline.serve_until_stopped(srv, args.trace_dir):
+        return lifeline.ORPHANED
     if served.window_closed:
         # The engine's pool goes before the reference's float32 layers
         # and the twin's own pool come.

@@ -21,38 +21,17 @@ Never imports jax."""
 
 from __future__ import annotations
 
-import json
 import os
 import shutil
-import subprocess
-import sys
 import threading
 import time
 
 from benchmark import loadgen, traffic
 from benchmark.runners import serve
 
-HERE = os.path.dirname(os.path.abspath(__file__))
-ROOT = os.path.dirname(os.path.dirname(HERE))
-
 
 class Child(serve.Child):
-    """serve.Child around this cell's own child script."""
-
-    def __init__(self, conf: dict, seed: int, chips: int, rehearse: bool,
-                 trace_dir: str, log_path: str):
-        env = dict(os.environ)
-        env["PYTHONPATH"] = ROOT + os.pathsep + env.get("PYTHONPATH", "")
-        self.log = open(log_path, "w")
-        self.proc = subprocess.Popen(
-            [sys.executable, os.path.join(HERE, "serve_reasoning_child.py"),
-             "--config", json.dumps(conf), "--seed", str(seed),
-             "--chips", str(chips), "--rehearse", str(int(rehearse)),
-             "--trace-dir", trace_dir],
-            stdin=subprocess.PIPE, stdout=subprocess.PIPE, stderr=self.log,
-            text=True, cwd=ROOT, env=env,
-        )
-        self.events: list[dict] = []
+    script = "serve_reasoning_child.py"
 
 
 def client_lists(params: dict, seed: int, seconds: float) -> list[list]:
@@ -75,15 +54,18 @@ def run(ctx: dict) -> dict:
     shutil.rmtree(trace_dir, ignore_errors=True)
     child = Child(conf, seed, ctx["chips"], ctx["rehearse"], trace_dir,
                   os.path.join(ctx["out_dir"], "serve_child.log"))
+    ph = serve.Phases(ctx["t_start"], child.log)
     try:
         # Traffic is made while the child initialises and compiles.
         client_items = [loadgen.encode_bodies(c)
                         for c in client_lists(p, seed, seconds)]
         dev = child.wait_for("device", 600)
+        ph.mark("device")
         warm = loadgen.encode_bodies(
             traffic.warmup_bodies(p, dev["embed_buckets"], seed))
         ready = child.wait_for("ready", ctx["setup_timeout"])
         port = ready["port"]
+        ph.mark("ready")
 
         t_w = time.monotonic()
         for payload, want in warm:
@@ -98,10 +80,12 @@ def run(ctx: dict) -> dict:
             "127.0.0.1", port, [[b] for b in burst], 600.0, until_done=True
         )
         warm_s = time.monotonic() - t_w
+        ph.mark("warmup")
 
         child.tell("arm", "armed")
         scraped = serve.scrape(port)
         setup_s = time.monotonic() - ctx["t_start"]
+        ph.mark("arm")
         tracer, slice_ = None, {}
         if ctx["trace"]:
             tracer = threading.Thread(
@@ -117,26 +101,23 @@ def run(ctx: dict) -> dict:
         after = serve.scrape(port)
         if tracer is not None:
             tracer.join()
+        ph.mark("window")
         end = child.tell("disarm", "disarmed", 300.0)
+        ph.mark("disarm")
         # The comparison comes after the window, on what it served.
-        check = child.tell("stop", "logit_check", ctx["setup_timeout"])
+        check = child.check_after_window()
+        ph.mark("comparison")
     finally:
         child.stop()
+    ph.mark("stop")
     red = serve.reduce_requests(
         res, first_token_limit_s=p.get("first_token_limit_s"))
     delta = {k: after.get(k, 0.0) - scraped.get(k, 0.0) for k in after}
     compiles = end.get("compiles")
     raw = {"ttft_ms": red.pop("ttft_ms"), "tpot_ms": red.pop("tpot_ms")}
     lateness = red.pop("lateness_ms")
-    problems = []
-    if not check["ok"]:
-        failed = [k for k, v in check.get("passed", {}).items() if not v]
-        problems.append(f"the served tokens' check failed: {', '.join(failed)}")
-    kinds = {w["kind"] for w in check.get("sample", [])}
-    want = set(p.get("check_sample_kinds", ()))
-    if not want <= kinds:
-        problems.append(f"the window finished no {sorted(want - kinds)} "
-                        "request to compare")
+    problems = serve.check_problems(
+        check, p.get("check_sample_kinds", ()))
     if compiles:
         problems.append(f"{compiles} compiles inside the window: "
                         f"{end.get('compile_counts')}")
@@ -172,4 +153,5 @@ def run(ctx: dict) -> dict:
             "check_after_window": check,
         },
         "compiles_in_window": compiles,
+        "phases": ph.seconds,
     }

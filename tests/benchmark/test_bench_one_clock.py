@@ -127,5 +127,5 @@ def test_every_runner_reduces_through_the_one_function():
                 callers.append(f)
             assert "reduce_planes(" not in src or f == "trace.py", f
     assert defs == ["trace.py"]
-    assert {"serve_blockdiff_child.py", "serve_child.py",
-            "serve_latent_child.py", "train.py"} <= set(callers)
+    # Every serve child's command loop is runners/lifeline.py's (PR 55).
+    assert {"lifeline.py", "train.py"} <= set(callers)

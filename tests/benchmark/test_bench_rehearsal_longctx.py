@@ -336,6 +336,66 @@ def _prompt_tokens(body):
     return sum(len(m["content"]) + 1 for m in body["messages"])
 
 
+def test_no_id_the_model_emits_is_the_templates_stop():
+    """The program ends a lane when the ids it emitted match the chat
+    template's stop string as the cell's tokenizer encodes it.
+    PrefixTokenizer gives that string an id the seeded model emits (one
+    greedy token in `vocab_size`: a request in twenty ended early, at
+    another place at every seed); the tokenizer the cell's child builds
+    gives it one past the head's rows and the preset's EOS id, and every
+    other text PrefixTokenizer's ids."""
+    import numpy as np
+
+    from benchmark.runners import serve_latent_child, serve_longctx_child
+    from oryx_tpu.conversation import conv_templates
+    from oryx_tpu.models import generate
+
+    hi = CONF["vocab_size"]
+    stop = conv_templates["plain"].stop_str
+    plain = serve_latent_child.PrefixTokenizer(hi)
+    tok = serve_longctx_child.NoStopPrefixTokenizer(hi)
+    assert 3 <= plain.encode(stop)[0] < hi  # what went wrong
+    rows = np.asarray(generate.make_stop_sequences([stop], tok))
+    assert rows.shape == np.asarray(
+        generate.make_stop_sequences([stop], plain)).shape  # same programs
+    ids = rows[rows >= 0]
+    assert ids.size == len(stop) and (ids > hi).all()
+    text = "0123456789abcdef a context\nand a question\n"
+    assert tok.encode(text) == plain.encode(text)
+    assert max(tok.encode(text)) < hi
+
+
+def test_a_request_that_stops_early_makes_the_run_incorrect(checkout):
+    """`serve_longctx.run` holds the window to the same work at every
+    seed: a record with `finish` "stop" is a problem on the line. The
+    rehearsal in the temporary copy, with one finished record of the
+    window altered where the load generator hands them over."""
+    import subprocess
+    import sys
+
+    env = {k: v for k, v in os.environ.items() if k != "XLA_FLAGS"}
+    env.update(JAX_PLATFORMS="cpu", PYTHONPATH=ROOT)
+    code = (
+        "import sys; sys.argv = ['run.py', '--workload', %r, '--seed', '7', "
+        "'--rehearse', '1']\n"
+        "from benchmark import loadgen, run\n"
+        "real = loadgen.run_closed_loop\n"
+        "def stopped(*a, **kw):\n"
+        "    res = real(*a, **kw)\n"
+        "    if not kw.get('until_done'):\n"
+        "        done = [r for r in res['records'] if r.get('t_done')]\n"
+        "        done[0]['finish'] = 'stop'\n"
+        "    return res\n"
+        "loadgen.run_closed_loop = stopped\n"
+        "sys.exit(run.main())\n" % CELL)
+    p = subprocess.run([sys.executable, "-c", code], capture_output=True,
+                       text=True, env=env, timeout=240, cwd=checkout)
+    line = last_line(p)
+    assert line["correct"] is False
+    assert any("ended before their max_tokens" in x
+               for x in line["problems"]), line["problems"]
+
+
 def test_sessions_end_before_the_limit_and_open_the_window_on_both_kinds():
     """Every seed sends the same lengths from the same places; a session
     ends before 65,400 positions; the window opens with a cold context
