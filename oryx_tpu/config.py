@@ -18,16 +18,23 @@ from typing import Any
 
 def unsupported_for_recurrent(mode: str) -> str:
     """The one refusal of a mode that is not built for a config with
-    state-space layers (`LLMConfig.recurrent`): beside the paged K/V of
-    its attention layers its pool holds ONE fixed-size state a slot a
-    Mamba layer, which no block table addresses and which only the
-    split engine's `paged_prefill` / `paged_decode_chunk` carry."""
+    state layers (`LLMConfig.recurrent`: Mamba mixers or gated short
+    convolutions): beside the paged K/V of its attention layers its
+    pool holds ONE fixed-size state a slot a state layer, which no block
+    table addresses and which only the split engine's `paged_prefill` /
+    `paged_decode_chunk` carry. A prefix-cache hit hands a state over
+    where the pool keeps a snapshot a page (`conv_edge`: a gated short
+    convolution's two rows); a Mamba layer's [d_state, d_inner] state
+    has none."""
     return (
-        f"state-space layers (attn_layer_period > 0): {mode} is not built "
-        "for a recurrent state beside the paged pool (one conv window and "
-        "one [d_state, d_inner] state a slot a Mamba layer, addressed by "
-        "slot and not through a block table); it serves through the "
-        "continuous split engine with a bf16 pool and no prefix cache only"
+        f"state-space layers (attn_layer_period > 0 or layer_types): "
+        f"{mode} is not built "
+        "for a recurrent state beside the paged pool (one conv window and, "
+        "a Mamba layer, one [d_state, d_inner] state a slot a layer, "
+        "addressed by slot and not through a block table); it serves "
+        "through the continuous split engine with a bf16 pool only, and "
+        "with a prefix cache only where the state is a gated short "
+        "convolution's (a snapshot a page)"
     )
 
 
@@ -167,6 +174,16 @@ class LLMConfig:
     # whole number of periods. 0 = every layer attends.
     attn_layer_period: int = 0
     attn_layer_offset: int = 0
+    # Layer kinds by position from the source's own LIST (the LFM2
+    # lineage's `layer_types`), where no period and offset give them:
+    # entry i is "full_attention" or "conv", a gated short convolution
+    # (`models/short_conv.py`: conv_L_cache taps, its whole state the
+    # last conv_L_cache - 1 gated inputs [hidden_size] a lane). The
+    # first num_layers entries are the model's, so a list may be longer
+    # than a depth cut. `layer_kinds` is the one table both rules fill;
+    # the FFN kind is by position too (`ffn_kinds`).
+    layer_types: tuple[str, ...] = ()
+    conv_L_cache: int = 3
     mamba_d_state: int = 16
     mamba_d_conv: int = 4
     mamba_expand: int = 2
@@ -200,12 +217,17 @@ class LLMConfig:
     # DeepSeek-V3 lineage's `scoring_func`); selection, renormalising
     # and the scaling factor are the same for both (`qwen2.moe_select`).
     router_scoring: str = "softmax"
-    # Leading dense layers of a single-latent-block model
-    # (`first_k_dense_replace`): the first dense_layers of num_layers
-    # have ONE SwiGLU of intermediate_size in the expert layer's place
-    # (`qwen2._latent_block` with no experts); the rest are expert
-    # layers.
+    # Leading dense layers of an expert model (`first_k_dense_replace`,
+    # `num_dense_layers`): the first dense_layers of num_layers have ONE
+    # SwiGLU of intermediate_size in the expert layer's place; the rest
+    # are expert layers. Built for the single latent block
+    # (`qwen2._latent_block` with no experts) and for a config with
+    # state layers (`qwen2._hybrid_layers`).
     dense_layers: int = 0
+    # Added to the sum of the chosen experts' probabilities before
+    # norm_topk_prob divides by it (the LFM2 lineage's 1e-6; 0: the bare
+    # sum).
+    norm_topk_eps: float = 0.0
     # Learned sparse attention over the latent pool when index_topk > 0
     # (`qwen2._mla`, ops/paged_kv.py): an indexer of index_heads
     # heads of index_head_dim scores every visible key (a weighted sum
@@ -247,9 +269,43 @@ class LLMConfig:
         return self.kv_lora_rank > 0
 
     @property
+    def layer_kinds(self) -> tuple[str, ...]:
+        """THE table of layer kinds, one entry a layer: "attn", "mamba"
+        or "conv". From `layer_types` where the source lists them, from
+        the period and offset where it gives those, else every layer
+        attends."""
+        if self.layer_types:
+            return tuple(
+                "attn" if t == "full_attention" else t
+                for t in self.layer_types[:self.num_layers])
+        if self.attn_layer_period:
+            return tuple(
+                "attn" if i % self.attn_layer_period == self.attn_layer_offset
+                else "mamba" for i in range(self.num_layers))
+        return ("attn",) * self.num_layers
+
+    @property
+    def ffn_kinds(self) -> tuple[str, ...]:
+        """The FFN kind of each layer of a config with state layers:
+        "own" (no experts anywhere: the dense SwiGLU's kernels lie in
+        the layer's own stack), else "dense" for the leading
+        dense_layers and "moe" behind them."""
+        if not self.num_experts:
+            return ("own",) * self.num_layers
+        return tuple("dense" if i < self.dense_layers else "moe"
+                     for i in range(self.num_layers))
+
+    @property
+    def state_kind(self) -> str | None:
+        """The kind of the layers that keep a per-slot state: "mamba",
+        "conv", or None where every layer attends."""
+        kinds = set(self.layer_kinds) - {"attn"}
+        return next(iter(kinds)) if kinds else None
+
+    @property
     def recurrent(self) -> bool:
-        """Some layers are state-space mixers with a per-slot state."""
-        return self.attn_layer_period > 0
+        """Some layers keep a per-slot state and no K/V."""
+        return self.state_kind is not None
 
     @property
     def mamba_d_inner(self) -> int:
@@ -257,21 +313,45 @@ class LLMConfig:
 
     @property
     def num_attn_layers(self) -> int:
-        if not self.recurrent:
-            return self.num_layers
-        return self.num_layers // self.attn_layer_period
+        return self.layer_kinds.count("attn")
 
     @property
-    def num_mamba_layers(self) -> int:
+    def num_state_layers(self) -> int:
+        """Layers that keep a per-slot state (of either kind) and no K/V."""
         return self.num_layers - self.num_attn_layers
 
+    @property
+    def kv_pack(self) -> int:
+        """Key/value heads a cached token's ROW holds side by side in a
+        hybrid's pool (`qwen2.init_paged_kv_cache`): the most that
+        divide num_kv_heads and fit 128 lanes, so that a head of 64
+        leaves no lane of a row unused (the chip pads a row to 128
+        lanes, and the page walk copies nothing narrower). 1 at a head
+        of 128 and for every model that is no hybrid: the pool as it
+        always was. The ONE place the layout is decided: the pool is
+        built from it and `qwen2._block` hands it to its attention."""
+        if not self.recurrent:
+            return 1
+        fits = [r for r in range(1, self.num_kv_heads + 1)
+                if self.num_kv_heads % r == 0 and r * self.head_dim <= 128]
+        return max(fits, default=1)
+
+    @property
+    def conv_state_width(self) -> int:
+        """Values of ONE slot's row of the pool's `conv` plane, a state
+        layer: the last taps - 1 conv inputs, flat."""
+        if self.state_kind == "conv":
+            return (self.conv_L_cache - 1) * self.hidden_size
+        return (self.mamba_d_conv - 1) * self.mamba_d_inner
+
     def state_bytes_per_slot(self, dtype_bytes: int = 2) -> int:
-        """Bytes of recurrent state ONE slot holds: a float32
-        [d_state, d_inner] state and a [d_conv - 1, d_inner] window in
-        the compute dtype, a Mamba layer."""
-        per = self.mamba_d_inner * (
-            4 * self.mamba_d_state + dtype_bytes * (self.mamba_d_conv - 1))
-        return self.num_mamba_layers * per
+        """Bytes of recurrent state ONE slot holds: the conv window
+        (`conv_state_width`) in the compute dtype a state layer, and for
+        a Mamba layer a float32 [d_state, d_inner] state beside it."""
+        per = dtype_bytes * self.conv_state_width
+        if self.state_kind == "mamba":
+            per += 4 * self.mamba_d_state * self.mamba_d_inner
+        return self.num_state_layers * per
 
     @property
     def cache_layers(self) -> int:
@@ -281,6 +361,27 @@ class LLMConfig:
         if self.recurrent:
             return self.num_attn_layers
         return self.num_layers * (2 if self.shortcut_double_layer else 1)
+
+    def layer_plan(self):
+        """`layer_kinds` with `ffn_kinds` as (lead, period, repeats,
+        tail): the longest stretch that is one run of layers repeated
+        (scanned, `qwen2._hybrid_layers`), with what lies ahead of and
+        behind it (unrolled). Each of lead, period and tail is a tuple
+        of (kind, ffn kind) a layer."""
+        layers = tuple(zip(self.layer_kinds, self.ffn_kinds))
+        L = len(layers)
+        best = (0, 0, L, 1)  # (covered, -period, lead, repeats)
+        for a in range(L):
+            for P in range(1, (L - a) // 2 + 1):
+                n = 1
+                while layers[a + n * P:a + (n + 1) * P] == layers[a:a + P]:
+                    n += 1
+                if n > 1 and (n * P, -P) > best[:2]:
+                    best = (n * P, -P, a, n)
+        covered, negP, a, n = best
+        if not covered:
+            return layers, (), 0, ()
+        return (layers[:a], layers[a:a - negP], n, layers[a + covered:])
 
     @property
     def yarn(self) -> bool:
@@ -420,7 +521,18 @@ class LLMConfig:
                 "RoPE scaling and llama4_scaling_beta need "
                 "rope_original_max_position > 0"
             )
-        if self.recurrent:
+        if self.layer_types:
+            object.__setattr__(self, "layer_types", tuple(self.layer_types))
+            if self.attn_layer_period or len(
+                    self.layer_types) < self.num_layers or not set(
+                    self.layer_types) <= {"full_attention", "conv"} or (
+                    self.conv_L_cache < 2):
+                raise ValueError(
+                    "layer_types lists 'full_attention' or 'conv' for at "
+                    "least num_layers layers, without attn_layer_period, "
+                    f"and conv_L_cache > 1, got {self.layer_types}"
+                )
+        if self.attn_layer_period:
             P = self.attn_layer_period
             if not (0 <= self.attn_layer_offset < P
                     and self.num_layers % P == 0 and P > 1
@@ -432,13 +544,17 @@ class LLMConfig:
                     "periods, mamba_dt_rank, mamba_d_state, mamba_expand "
                     f"> 0 and mamba_d_conv > 1, got {self}"
                 )
+        if self.recurrent:
             for bad, mode in (
                 (self.block_length, "generation by diffusion over blocks "
                  "(block_length > 0: the block step program)"),
                 (self.latent, "latent attention (kv_lora_rank > 0)"),
-                (self.num_experts, "an expert layer (num_experts > 0)"),
-                (self.qk_norm or self.attention_bias,
-                 "q/k norm or attention bias"),
+                (self.attention_bias, "attention bias"),
+                (self.experts_held is not None or self.zero_experts
+                 or self.router_input != "post_attn"
+                 or self.moe_activation != "silu",
+                 "a share of the experts, zero-compute experts, a router "
+                 "on the layer's input or ReGLU experts"),
             ):
                 if bad:
                     raise ValueError(unsupported_for_recurrent(mode))
@@ -487,13 +603,15 @@ class LLMConfig:
                 f"expert config, got {self.router_scoring!r}"
             )
         if self.dense_layers and not (
-                self.latent and not self.shortcut_double_layer
+                (self.recurrent and self.num_experts
+                 or self.latent and not self.shortcut_double_layer)
                 and 0 < self.dense_layers < self.num_layers
                 and self.intermediate_size > 0):
             raise ValueError(
                 "dense_layers (leading dense layers) is built for the "
                 "single latent block (kv_lora_rank > 0, no "
-                "shortcut_double_layer) and needs 0 < dense_layers < "
+                "shortcut_double_layer) and for an expert config with "
+                "state layers, and needs 0 < dense_layers < "
                 f"num_layers, got {self.dense_layers} of {self.num_layers}"
             )
         if self.indexed or self.index_heads or self.index_head_dim:
@@ -522,8 +640,8 @@ class LLMConfig:
         if not self.recurrent and not self.use_rope:
             raise ValueError(
                 "use_rope=False (attention without a position term) is "
-                "built for a config with state-space layers only "
-                "(attn_layer_period > 0)"
+                "built for a config with state layers only "
+                "(attn_layer_period > 0 or layer_types)"
             )
 
 
@@ -1320,6 +1438,95 @@ def jamba_tiny() -> OryxConfig:
             mamba_expand=2,
             mamba_dt_rank=8,
             use_rope=False,
+        ),
+        vision=None,
+        generation=GenerationConfig(eos_token_id=512),
+        dtype="float32",
+    )
+
+
+# LFM2-24B-A2B's published `layer_types` (40 entries): two gated short
+# convolutions, then `A c c c` nine times, then `A c`.
+_LFM2_LAYER_TYPES = ("conv", "conv") + 9 * (
+    "full_attention", "conv", "conv", "conv") + ("full_attention", "conv")
+
+
+def lfm2_24b_a2b() -> OryxConfig:
+    """LFM2-24B-A2B (LiquidAI, config.json, `model_type: lfm2_moe`): 40
+    layers by the published list, 30 gated short convolutions (3 taps,
+    no bias: two rows of 2,048 of state a lane) and 10 GQA attention
+    layers (32 query heads of 64 over 8 key/value heads, RMSNorm on q
+    and k, RoPE at theta 1e6); layers 0 and 1 keep a dense SwiGLU of
+    11,776, the other 38 have 64 experts of 1,536, 4 a token, behind a
+    sigmoid router with a selection bias and weights divided by their
+    sum + 1e-6. Tied embedding of 65,536. Text-only. 23.84 B
+    parameters. What the keys do not settle (head size 64, the order
+    of the conv's three thirds) is under `assumed` in the benchmark's
+    configuration file."""
+    return OryxConfig(
+        llm=LLMConfig(
+            vocab_size=65536,
+            hidden_size=2048,
+            intermediate_size=11776,
+            num_layers=40,
+            num_heads=32,
+            num_kv_heads=8,
+            head_dim=64,
+            rope_theta=1_000_000.0,
+            rms_norm_eps=1e-5,
+            max_position_embeddings=128000,
+            tie_word_embeddings=True,
+            attention_bias=False,
+            qk_norm=True,
+            layer_types=_LFM2_LAYER_TYPES,
+            conv_L_cache=3,
+            num_experts=64,
+            num_experts_per_tok=4,
+            moe_intermediate_size=1536,
+            norm_topk_prob=True,
+            norm_topk_eps=1e-6,
+            router_scoring="sigmoid",
+            router_bias=True,
+            routed_scaling_factor=1.0,
+            dense_layers=2,
+        ),
+        vision=None,
+        # Past the vocabulary: seeded weights would sample a real id
+        # once in 65,536 tokens and end a request the traffic sized.
+        generation=GenerationConfig(eos_token_id=65536),
+    )
+
+
+def lfm2_tiny() -> OryxConfig:
+    """Tiny LFM2 for tests: the published 40-entry layer list at width
+    64 (two dense conv layers, then experts: 8 of width 32, 2 a token),
+    2 key/value heads of 16. `dataclasses.replace(llm, num_layers=10)`
+    is the benchmark's depth cut."""
+    return OryxConfig(
+        llm=LLMConfig(
+            vocab_size=512,
+            hidden_size=64,
+            intermediate_size=96,
+            num_layers=40,
+            num_heads=4,
+            num_kv_heads=2,
+            head_dim=16,
+            rope_theta=1_000_000.0,
+            rms_norm_eps=1e-5,
+            max_position_embeddings=2048,
+            tie_word_embeddings=True,
+            attention_bias=False,
+            qk_norm=True,
+            layer_types=_LFM2_LAYER_TYPES,
+            conv_L_cache=3,
+            num_experts=8,
+            num_experts_per_tok=2,
+            moe_intermediate_size=32,
+            norm_topk_prob=True,
+            norm_topk_eps=1e-6,
+            router_scoring="sigmoid",
+            router_bias=True,
+            dense_layers=2,
         ),
         vision=None,
         generation=GenerationConfig(eos_token_id=512),

@@ -766,10 +766,11 @@ def paged_decode_chunk(
     position its slot 0 holds, fixed for the chunk (the caller keeps
     lengths - sliding_window + 1 .. lengths + chunk inside the table);
     an expert config of that kind appends the `share_stats` sums too
-    (held = every expert)."""
+    (held = every expert), and so does an expert config with state
+    layers."""
     page_size = paged_kv_lib.pool_plane(kv_pages).shape[2]
     shared = bool(cfg.experts_held or cfg.zero_experts
-                  or (cfg.windowed and cfg.num_experts))
+                  or ((cfg.windowed or cfg.recurrent) and cfg.num_experts))
     planes = {}
     if cfg.windowed:
         planes = _window_planes(window_tables, window_base, "a decode chunk")

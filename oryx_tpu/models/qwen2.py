@@ -111,34 +111,48 @@ def init_params(
 
 
 def _init_recurrent_params(cfg: LLMConfig, key: jax.Array, dtype) -> Params:
-    """A state-space hybrid's weights: two homogeneous stacks, in layer
-    order within each. `layers["attn"]` [La, ...] is `_block`'s own set
-    (norms, q/k/v/o, the dense FFN) for the layers that attend;
-    `layers["mamba"]` [Lm, ...] holds the two norms, the FFN and under
-    `"mixer"` the mixer (`mamba.init_mixer_params`) of the others. The
-    head is the embedding, transposed (tied)."""
-    from oryx_tpu.models import mamba
+    """A hybrid's weights (a config with state layers): homogeneous
+    stacks, in layer order within each. `layers["attn"]` [La, ...] is
+    `_block`'s own set (norms, q/k/v/o, q/k norm) for the layers that
+    attend; `layers[cfg.state_kind]` ("mamba" or "conv") [Ls, ...]
+    holds the two norms and under `"mixer"` the mixer
+    (`mamba.init_mixer_params` / `short_conv.init_mixer_params`) of
+    the others. The FFN is by position (`cfg.ffn_kinds`). Without
+    experts every layer's dense SwiGLU lies in its own stack, beside
+    its norms. With experts: `layers["dense"]` [dense_layers, ...] the
+    leading dense SwiGLUs and `layers["router"]` / `layers["experts"]`
+    [moe layers, ...] the expert layers', each indexed by the layer's
+    number within its FFN kind. The head is the embedding, transposed
+    (tied), unless the config unties it."""
+    from oryx_tpu.models import mamba, short_conv
 
     H, I = cfg.hidden_size, cfg.intermediate_size
     Dq = cfg.num_heads * cfg.head_dim
     Dkv = cfg.num_kv_heads * cfg.head_dim
     keys = iter(jax.random.split(key, 16))
+    own = not cfg.num_experts
 
-    def dense(shape):
+    def dense(shape, dt=dtype, scale=0.02):
         return (
-            jax.random.normal(next(keys), shape, jnp.float32) * 0.02
-        ).astype(dtype)
+            jax.random.normal(next(keys), shape, jnp.float32) * scale
+        ).astype(dt)
 
-    def common(L):
+    def swiglu(L):
         return {
-            "input_norm": {"weight": jnp.ones((L, H), dtype)},
-            "post_attn_norm": {"weight": jnp.ones((L, H), dtype)},
             "gate_proj": {"kernel": dense((L, H, I))},
             "up_proj": {"kernel": dense((L, H, I))},
             "down_proj": {"kernel": dense((L, I, H))},
         }
 
-    La, Lm = cfg.num_attn_layers, cfg.num_mamba_layers
+    def common(L):
+        return dict({
+            "input_norm": {"weight": jnp.ones((L, H), dtype)},
+            "post_attn_norm": {"weight": jnp.ones((L, H), dtype)},
+        }, **(swiglu(L) if own else {}))
+
+    La, Ls = cfg.num_attn_layers, cfg.num_state_layers
+    kind = cfg.state_kind
+    mixers = mamba if kind == "mamba" else short_conv
     params: Params = {
         "embed": {"weight": dense((cfg.vocab_size, H))},
         "layers": {
@@ -149,15 +163,49 @@ def _init_recurrent_params(cfg: LLMConfig, key: jax.Array, dtype) -> Params:
                 v_proj={"kernel": dense((La, H, Dkv))},
                 o_proj={"kernel": dense((La, Dq, H))},
             ),
-            "mamba": dict(
-                common(Lm),
-                mixer=mamba.init_mixer_params(cfg, next(keys), Lm, dtype),
+            kind: dict(
+                common(Ls),
+                mixer=mixers.init_mixer_params(cfg, next(keys), Ls, dtype),
             ),
         },
         "final_norm": {"weight": jnp.ones((H,), dtype)},
     }
     if not cfg.tie_word_embeddings:
         params["lm_head"] = {"kernel": dense((H, cfg.vocab_size))}
+    layers = params["layers"]
+    if not own or cfg.qk_norm:
+        # Keys of their own, off one the leaves above leave unused.
+        keys = iter(jax.random.split(next(keys), 10))
+    if cfg.qk_norm:
+        # Log-normal around 1 (sigma 0.5), not ones: at these kernels q
+        # and k come out of their projections near unit rms, so a norm
+        # with a weight of 1 is nearly the identity and a program that
+        # left it out could not be told from one that has it.
+        for n in ("q_norm", "k_norm"):
+            layers["attn"][n] = {"weight": jnp.exp(0.5 * jax.random.normal(
+                next(keys), (La, cfg.head_dim), jnp.float32)).astype(dtype)}
+    if not own:
+        E, Ie = cfg.num_experts, cfg.moe_intermediate_size
+        Ld, Lm = cfg.dense_layers, cfg.moe_layers
+        if Ld:
+            layers["dense"] = swiglu(Ld)
+        layers["router"] = {"kernel": dense((Lm, H, E), jnp.float32)}
+        if cfg.router_bias:
+            # Small against a sigmoid's ~1/2 and not 0: the selection is
+            # not the scores' order at some tokens, so selecting by
+            # p + b while weighing by p can be told from weighing by
+            # p + b, and the experts' load stays as even as the scores
+            # leave it (at 0.2, the scores' own spread, a few experts
+            # with the largest bias took most rows: rows of the fullest
+            # expert over the mean 10.0 where 0.05 reads 3.6).
+            layers["router"]["bias"] = dense(
+                (Lm, E), jnp.float32,
+                scale=0.05 if cfg.router_scoring == "sigmoid" else 0.2 / E)
+        layers["experts"] = {
+            "gate": dense((Lm, E, H, Ie)),
+            "up": dense((Lm, E, H, Ie)),
+            "down": dense((Lm, E, Ie, H)),
+        }
     return params
 
 
@@ -316,13 +364,19 @@ def init_paged_kv_cache(
     as L*P pages while it runs (see its `block_tables` contract) and
     hands it back in this layout.
 
-    A config with state-space layers (`cfg.recurrent`) has TWO kinds of
+    A config with state layers (`cfg.recurrent`) has TWO kinds of
     state in the one pytree: paged `k` / `v` over its attention layers
-    alone ([La, P, page, Hk, D]) and per-SLOT planes that no block
-    table addresses, `conv` [Lm, S, (K-1) * d] in `dtype` (the last
+    alone ([La, P, page, Hk, D]; [La, P, page, Hk / r, r * D] where
+    r = `cfg.kv_pack` heads of under 128 lanes share a row of lanes) and
+    per-SLOT planes that no block
+    table addresses, `conv` [Ls, S, (K-1) * d] in `dtype` (the last
     K - 1 conv inputs, flat so that the plane has no 3-row tile to pad)
-    and `ssm` [Lm, S, N, d] float32 (channels in the lanes), S =
-    `num_slots`. `ops/paged_kv.paged_planes` tells them apart.
+    and, for Mamba layers, `ssm` [Ls, S, N, d] float32 (channels in the
+    lanes), S = `num_slots`. `ops/paged_kv.paged_planes` tells them
+    apart. Gated short convolutions (`cfg.state_kind == "conv"`) have
+    no `ssm` plane and one more PAGED plane, `conv_edge` [Ls, P,
+    (K-1) * d]: the `conv` rows as they stood after each page's last
+    token (`ops/paged_kv.CONV_EDGE`).
 
     A config with window layers (`cfg.windowed`) has TWO paged planes,
     one a layer kind, each with its own page count, allocator and block
@@ -350,7 +404,7 @@ def init_paged_kv_cache(
             + 2 * [(cfg.num_window_layers, Pw) + tail]))
         return {n: jnp.zeros(sh, dtype) for n, sh in shapes.items()}
     if cfg.recurrent:
-        from oryx_tpu.models import mamba
+        from oryx_tpu.ops import paged_kv
 
         if kv_dtype not in (None, "bf16", "fp"):
             raise ValueError(
@@ -359,16 +413,26 @@ def init_paged_kv_cache(
             raise ValueError(
                 "a config with state-space layers keeps a state a slot: "
                 "init_paged_kv_cache needs num_slots")
-        La, Lm = cfg.num_attn_layers, cfg.num_mamba_layers
-        d = cfg.mamba_d_inner
-        shape = (La, num_pages, page_size, cfg.num_kv_heads, cfg.head_dim)
-        return {
+        La, Ls = cfg.num_attn_layers, cfg.num_state_layers
+        CONV, SSM = paged_kv.SLOT_PLANES
+        # cfg.kv_pack heads to a row of lanes (`_block_attention`).
+        r = cfg.kv_pack
+        shape = (La, num_pages, page_size, cfg.num_kv_heads // r,
+                 r * cfg.head_dim)
+        pool = {
             "k": jnp.zeros(shape, dtype), "v": jnp.zeros(shape, dtype),
-            mamba.CONV: jnp.zeros(
-                (Lm, num_slots, (cfg.mamba_d_conv - 1) * d), dtype),
-            mamba.SSM: jnp.zeros(
-                (Lm, num_slots, cfg.mamba_d_state, d), jnp.float32),
+            CONV: jnp.zeros((Ls, num_slots, cfg.conv_state_width), dtype),
         }
+        if cfg.state_kind == "mamba":
+            pool[SSM] = jnp.zeros(
+                (Ls, num_slots, cfg.mamba_d_state, cfg.mamba_d_inner),
+                jnp.float32)
+        else:
+            # A gated short convolution's rows are its whole state: a
+            # snapshot of them a PAGE, behind the pages' own table.
+            pool[paged_kv.CONV_EDGE] = jnp.zeros(
+                (Ls, num_pages, cfg.conv_state_width), dtype)
+        return pool
     if cfg.latent:
         # One plane of cfg.cache_layers layers (two a model layer in the
         # double layer, else one), no head axis: a token's row is
@@ -539,7 +603,8 @@ def moe_select(cfg: LLMConfig, r: jnp.ndarray,
         )
         w = jnp.take_along_axis(p, idx, axis=-1)
     if cfg.norm_topk_prob:
-        w = w / jnp.sum(w, axis=-1, keepdims=True)
+        total = jnp.sum(w, axis=-1, keepdims=True)
+        w = w / (total + cfg.norm_topk_eps if cfg.norm_topk_eps else total)
     if cfg.routed_scaling_factor != 1.0:
         w = w * cfg.routed_scaling_factor
     return w, idx.astype(jnp.int32)
@@ -567,12 +632,16 @@ def _grouped_dot(rows: jnp.ndarray, kernels: jnp.ndarray,
     M, K = rows.shape
     N = kernels.shape[-1]
     tk, tn = K, N
+    if impl != "pallas" or K % 128 or N % 128:
+        return jax.lax.ragged_dot(rows, kernels, groups)
     if K * N > _GMM_MAX_KERNEL:
         # A kernel too large for one tile (6144 x 2048): tiles of
-        # 1024 x 1024, accumulated over the k tiles in float32.
-        tk, tn = min(K, _GMM_TILE), min(N, _GMM_TILE)
-    if impl != "pallas" or K % 128 or N % 128 or K % tk or N % tn:
-        return jax.lax.ragged_dot(rows, kernels, groups)
+        # 1024 x 1024, accumulated over the k tiles in float32; where
+        # 1024 does not divide a side (2048 x 1536), the largest whole
+        # number of 128 lanes under it that does (768).
+        tk, tn = (
+            max(t for t in range(128, _GMM_TILE + 1, 128) if n % t == 0)
+            for n in (K, N))
     import importlib
 
     from oryx_tpu.ops.pallas.flash_attention import _use_interpret
@@ -711,9 +780,10 @@ def _block(
     window: int = 0,
 ):
     """One decoder block. h: [B, T, H]. Returns (h, new_k, new_v), and
-    on an expert config the expert layer's routing as a fourth value.
-    `experts`: an expert config's (flat kernels of every layer, this
-    layer's index), see `_moe`.
+    with an expert layer its routing as a fourth value.
+    `experts`: an expert layer's (flat kernels of every layer, this
+    layer's index), see `_moe`; None: the layer's FFN is the dense
+    SwiGLU in `lp`.
 
     `positions` place the token: its RoPE angle and its cache slot.
     `mask_positions` (default: the same) are what its query is masked
@@ -757,6 +827,7 @@ def _block(
             block_tables=block_tables, write_mask=write_mask,
             kv_lengths=kv_lengths, q_segments=q_segments,
             attn_impl=attn_impl, mask_positions=mask_positions, win=win,
+            kv_pack=cfg.kv_pack,
         )
     attn_out = attn_out.reshape(B, T, -1)
     # "attn_o" tag: with remat_policy="attn_o" the residual-stream value
@@ -765,10 +836,10 @@ def _block(
     h = h + checkpoint_name(_linear(attn_out, lp["o_proj"]), "attn_o")
 
     x = rms_norm(h, lp["post_attn_norm"]["weight"], cfg.rms_norm_eps)
-    if cfg.num_experts:
+    if experts is not None:
         y, routing = _moe(
             cfg, x.reshape(B * T, -1), lp["router"]["kernel"], *experts,
-            impl=attn_impl, **moe_in,
+            impl=attn_impl, router_bias=lp["router"].get("bias"), **moe_in,
         )
         return h + y.reshape(B, T, -1), cache_k, cache_v, routing
     return h + _swiglu(x, lp), cache_k, cache_v
@@ -776,11 +847,12 @@ def _block(
 
 def _block_attention(q, k, v, *, positions, cache_k, cache_v, write_slots,
                      kv_mask, attn_fn, block_tables, write_mask, kv_lengths,
-                     q_segments, attn_impl, mask_positions, win):
+                     q_segments, attn_impl, mask_positions, win, kv_pack=1):
     """`_block`'s attention over whichever cache it was given: writes
     this call's K/V, returns (attention output [B, T, Hq, D], the
     cache's two planes). `win`: {"window": W} on a window layer, else
-    {}."""
+    {}. `kv_pack`: heads a row of the paged pool holds side by side
+    (`LLMConfig.kv_pack`)."""
     T = q.shape[1]
     if cache_k is not None and block_tables is not None and (
         q_segments is not None
@@ -818,6 +890,13 @@ def _block_attention(q, k, v, *, positions, cache_k, cache_v, write_slots,
         # row's logical stream is addressed through its block table.
         from oryx_tpu.ops import paged_kv
 
+        # A pool whose rows hold r heads side by side ([P, page, Hk / r,
+        # r * D], `LLMConfig.kv_pack`): the same bytes in the same
+        # order, so K and V are written as they lie.
+        B, _, Hk, D = k.shape
+        r = kv_pack
+        if r > 1:
+            k, v = (a.reshape(B, T, Hk // r, r * D) for a in (k, v))
         cache_k = paged_kv.write_pages(
             cache_k, k, block_tables, write_slots, write_mask=write_mask
         )
@@ -829,15 +908,32 @@ def _block_attention(q, k, v, *, positions, cache_k, cache_v, write_slots,
             # table, no contiguous gather.
             from oryx_tpu.ops.pallas import paged_attention as _ppa
 
+            if r > 1:
+                # The walk sees Hk / r heads of r * D lanes. A query of
+                # head h lies in ITS key head's lanes of the row and is
+                # zero in the others', so its scores are its own head's;
+                # of the output row it keeps its value head's lanes.
+                Hq = q.shape[2]
+                lane = (jnp.arange(Hq) // (Hq // Hk)) % r  # [Hq]
+                mine = lane[:, None] == jnp.arange(r)[None, :]  # [Hq, r]
+                q = jnp.where(mine[..., None], q[..., None, :], 0).reshape(
+                    B, T, Hq, r * D)
+                win = dict(win, scale=D ** -0.5)
             attn_out = _ppa.ragged_decode_attention(
                 q, cache_k, cache_v, block_tables, kv_lengths, **win
             )
+            if r > 1:
+                attn_out = jnp.sum(jnp.where(
+                    mine[..., None], attn_out.reshape(B, T, Hq, r, D), 0
+                ), axis=-2)
         else:
             # Reference path (and any T > 1 paged prefill): materialize
             # the logical stream, then the stock cached-attention call —
             # bit-identical math to the dense cache at equal KV width.
             kc = paged_kv.gather_pages(cache_k, block_tables)
             vc = paged_kv.gather_pages(cache_v, block_tables)
+            if r > 1:  # back to a head a row, [B, K, Hk, D]
+                kc, vc = (a.reshape(a.shape[:2] + (Hk, D)) for a in (kc, vc))
             attn_out = attn_fn(
                 q, kc, vc,
                 q_positions=mask_positions,
@@ -1306,119 +1402,255 @@ def _latent_block(cfg: LLMConfig, h, lp: Params, cos, sin, *, pool, tables,
 
 def _hybrid_layers(cfg: LLMConfig, layers: Params, h, *, block, kv_cache,
                    block_tables, positions, kv_lengths, kv_mask,
-                   state_slots, attn_impl: str, remat):
-    """The layer stack of a config with state-space layers: a scan over
-    PERIODS of cfg.attn_layer_period layers whose body runs the
-    period's layers in order: an inner scan over the Mamba layers ahead
-    of the attention layer, the attention layer (`block`, forward's own
-    closure over `_block`), an inner scan over the Mamba layers behind
-    it. Every pool plane is CARRIED through both levels, never scanned
+                   state_slots, attn_impl: str, remat, experts_flat=None):
+    """The layer stack of a config with state layers, run off ONE table
+    of layer kinds (`cfg.layer_plan`: what `cfg.layer_kinds` and
+    `cfg.ffn_kinds` say of each layer, as lead, period, repeats, tail).
+    The repeated stretch is a scan over PERIODS whose body runs the
+    period's layers in order; the ragged ends ahead of and behind it are
+    the same body unrolled. Within a stretch a run of state layers of
+    one FFN kind is an inner scan, an attention layer is `block`
+    (forward's own closure over `_block`). A state layer is a Mamba
+    mixer (`models/mamba.py`) or a gated short convolution
+    (`models/short_conv.py`), by `cfg.state_kind`; its FFN, by
+    position, the dense SwiGLU in its own stack, a leading dense one
+    (`layers["dense"]`) or the expert layer (`_moe`).
+
+    Every pool plane is CARRIED through both levels, never scanned
     (forward's "THE POOL IS CARRIED" rule): `k` / `v` flat [La*P, ...]
     behind layer-offset tables, `conv` / `ssm` whole, a layer reading
-    and writing its [S, ...] row in place. Layer weights are indexed by
+    and writing its [S, ...] row in place, and `conv_edge` whole: a
+    layer of a prefill chunk writes the window after every page's last
+    token the chunk holds into that page's row, a layer of a decode
+    step the lane's window where its token was the last of its page.
+    Layer weights are indexed by
     the layer's number within its kind, which is what a scan does with
     its xs. See forward's `state_slots` for whose state a row starts
-    from and leaves. Returns (h, the pool or None)."""
-    from oryx_tpu.models import mamba
+    from and leaves. Returns (h, the pool or None, the expert layers'
+    routing [moe layers, ...] in layer order or None)."""
+    from oryx_tpu.models import mamba, short_conv
+    from oryx_tpu.ops import paged_kv
 
-    per, off = cfg.attn_layer_period, cfg.attn_layer_offset
+    kind = cfg.state_kind
+    is_mamba = kind == "mamba"
+    lead, period_kinds, reps, tail = cfg.layer_plan()
     B, T, _ = h.shape
-    K1, d, N = cfg.mamba_d_conv - 1, cfg.mamba_d_inner, cfg.mamba_d_state
+    K1 = (cfg.mamba_d_conv if is_mamba else cfg.conv_L_cache) - 1
+    d = cfg.conv_state_width // K1
+    N = cfg.mamba_d_state
+    CONV, SSM = paged_kv.SLOT_PLANES
+    EDGE = paged_kv.CONV_EDGE
     paged = kv_cache is not None
-    ck = cv = conv_pl = ssm_pl = None
+    pl = {}
     if paged:
         valid = positions < kv_lengths[:, None]
         La, P = kv_cache["k"].shape[:2]
-        ck, cv = (kv_cache[n].reshape((La * P,) + kv_cache[n].shape[2:])
-                  for n in ("k", "v"))
-        conv_pl, ssm_pl = kv_cache[mamba.CONV], kv_cache[mamba.SSM]
+        pl = {n: kv_cache[n].reshape((La * P,) + kv_cache[n].shape[2:])
+              for n in ("k", "v")}
+        pl.update({n: kv_cache[n] for n in (CONV, SSM, EDGE)
+                   if n in kv_cache})
         fresh = (positions[:, 0] == 0)[:, None, None]
     elif kv_mask is not None:
         valid = kv_mask.astype(bool)
     else:
         valid = jnp.ones((B, T), bool)
-    # One token a lane, lane b slot b. Under "pallas" the mixer's step
-    # is two kernels that take the planes whole and update layer li's
-    # rows in place (`mamba.mixer_step_inplace`); every other path, and
-    # a shape their tiles do not fit, slices the layer's rows out and
-    # writes them back around the mixer.
+    # One token a lane, lane b slot b. Under "pallas" a Mamba mixer's
+    # step is two kernels that take the planes whole and update layer
+    # li's rows in place (`mamba.mixer_step_inplace`); every other path,
+    # and a shape their tiles do not fit, slices the layer's rows out
+    # and writes them back around the mixer.
     decode = paged and state_slots is None and T == 1
-    inplace = decode and attn_impl == "pallas" and mamba.step_fits(cfg, B)
+    inplace = (is_mamba and decode and attn_impl == "pallas"
+               and mamba.step_fits(cfg, B))
     if inplace:
         step_inv = mamba.step_invariants(
             layers["mamba"]["mixer"], valid[:, 0], h.dtype)
+    edges = None
+    if EDGE in pl and decode:
+        # A lane whose token is the last of its page leaves its window
+        # in that page's row; any other writes out of bounds (dropped).
+        ps = kv_cache["k"].shape[2]
+        pos = positions[:, 0]
+        page = jnp.take_along_axis(
+            block_tables, jnp.minimum(
+                pos // ps, block_tables.shape[1] - 1)[:, None], axis=1)[:, 0]
+        edges = jnp.where(
+            valid[:, 0] & (pos % ps == ps - 1) & (page < P), page, P)
+    elif EDGE in pl:
+        # The page edges this chunk's rows cross, once for every layer.
+        edges = short_conv.page_edges(
+            positions, kv_lengths, block_tables, P, kv_cache["k"].shape[2])
 
     def at(tree, i):
         return jax.tree_util.tree_map(
             lambda a: jax.lax.dynamic_index_in_dim(a, i, keepdims=False),
             tree)
 
-    def mamba_layer(carry, li):
-        h, conv_pl, ssm_pl = carry
-        lp = at(layers["mamba"], li)
+    def ffn(which: str, h, lp, fi):
+        """h + the layer's FFN on its normed state -> (h, routing)."""
+        x = rms_norm(h, lp["post_attn_norm"]["weight"], cfg.rms_norm_eps)
+        if which == "own":
+            return h + _swiglu(x, lp), None
+        if which == "dense":
+            return h + _swiglu(x, at(layers["dense"], fi)), None
+        router = at(layers["router"], fi)
+        y, routing = _moe(
+            cfg, x.reshape(B * T, -1), router["kernel"], experts_flat, fi,
+            impl=attn_impl, router_bias=router.get("bias"))
+        return h + y.reshape(B, T, -1), routing
+
+    def state_layer(which: str, carry, idx):
+        h, pl = carry
+        li, fi = idx
+        lp = at(layers[kind], li)
+        conv_pl, ssm_pl = pl.get(CONV), pl.get(SSM)
         if not paged:
             conv0 = jnp.zeros((B, K1, d), h.dtype)
             h0 = jnp.zeros((B, N, d), jnp.float32)
         elif not inplace:  # (the kernels index the planes by li)
-            conv0, h0 = at(conv_pl, li), at(ssm_pl, li)  # [S, ...]
-            if state_slots is not None:
+            conv0 = at(conv_pl, li)  # [S, ...]
+            h0 = at(ssm_pl, li) if is_mamba else None
+            if state_slots is None:
+                conv0 = conv0.reshape(B, K1, d)
+            elif is_mamba:
                 conv0, h0 = mamba.rows_state(
                     conv0, h0, state_slots, fresh, (B, K1, d))
             else:
-                conv0 = conv0.reshape(B, K1, d)
+                conv0 = jnp.where(
+                    fresh, 0, conv0[state_slots].reshape(B, K1, d))
         u = rms_norm(h, lp["input_norm"]["weight"], cfg.rms_norm_eps)
-        with jax.named_scope("mamba"):
+        h1 = win = None
+        with jax.named_scope("mamba" if is_mamba else "short_conv"):
             if inplace:
                 out, (conv_pl, ssm_pl) = mamba.mixer_step_inplace(
                     cfg, lp["mixer"], step_inv, li, u, (conv_pl, ssm_pl))
-            elif decode:
+            elif is_mamba and decode:
                 out, (conv1, h1) = mamba.mixer_step(
                     cfg, lp["mixer"], u, (conv0, h0), valid[:, 0])
-            else:
+            elif is_mamba:
                 out, (conv1, h1) = mamba.mixer_prefill(
                     cfg, lp["mixer"], u, (conv0, h0), valid, impl=attn_impl)
-        h = h + out
-        x = rms_norm(h, lp["post_attn_norm"]["weight"], cfg.rms_norm_eps)
-        h = h + _swiglu(x, lp)
+            elif decode:
+                out, conv1 = short_conv.mixer_step(
+                    cfg, lp["mixer"], u, conv0, valid[:, 0])
+            else:
+                out, conv1, win = short_conv.mixer_prefill(
+                    cfg, lp["mixer"], u, conv0, valid)
+        h, routing = ffn(which, h + out, lp, fi)
         if paged and not inplace:
             conv1 = conv1.reshape(B, K1 * d)
             if state_slots is None:
                 conv_pl = jax.lax.dynamic_update_index_in_dim(
                     conv_pl, conv1, li, 0)
-                ssm_pl = jax.lax.dynamic_update_index_in_dim(
-                    ssm_pl, h1, li, 0)
+                if is_mamba:
+                    ssm_pl = jax.lax.dynamic_update_index_in_dim(
+                        ssm_pl, h1, li, 0)
             else:
                 conv_pl = conv_pl.at[li, state_slots].set(conv1)
-                ssm_pl = ssm_pl.at[li, state_slots].set(h1)
-        return (h, conv_pl, ssm_pl), None
+                if is_mamba:
+                    ssm_pl = ssm_pl.at[li, state_slots].set(h1)
+        if paged:
+            pl = dict(pl, **{CONV: conv_pl},
+                      **({SSM: ssm_pl} if is_mamba else {}))
+            if edges is not None and decode:
+                pl[EDGE] = pl[EDGE].at[li, edges].set(conv1, mode="drop")
+            elif edges is not None:
+                pages, n = edges
+                pl[EDGE] = pl[EDGE].at[li, pages.reshape(-1)].set(
+                    short_conv.edge_rows(win, n, K1 + 1).astype(
+                        pl[EDGE].dtype), mode="drop")
+        return (h, pl), routing
 
-    def period(carry, p):
-        h, ck, cv, conv_pl, ssm_pl = carry
-        m0 = p * (per - 1)
-        (h, conv_pl, ssm_pl), _ = jax.lax.scan(
-            mamba_layer, (h, conv_pl, ssm_pl),
-            m0 + jnp.arange(off, dtype=jnp.int32))
+    def attn_layer(which: str, carry, ai, fi):
+        h, pl = carry
+        lp = at(layers["attn"], ai)
+        if which == "dense":
+            lp = dict(lp, **at(layers["dense"], fi))
+        elif which == "moe":
+            lp = dict(lp, router=at(layers["router"], fi))
         tables = None
         if paged:
             tables = jnp.where(
                 block_tables >= P, La * P,
-                block_tables + p.astype(block_tables.dtype) * P)
-        h, ck, cv, _ = block(h, at(layers["attn"], p), ck, cv, tables)
-        (h, conv_pl, ssm_pl), _ = jax.lax.scan(
-            mamba_layer, (h, conv_pl, ssm_pl),
-            m0 + off + jnp.arange(per - 1 - off, dtype=jnp.int32))
-        return (h, ck, cv, conv_pl, ssm_pl), None
+                block_tables + jnp.asarray(ai, block_tables.dtype) * P)
+        h, ck, cv, routing = block(
+            h, lp, pl.get("k"), pl.get("v"), tables,
+            fi if which == "moe" else None)
+        if paged:
+            pl = dict(pl, k=ck, v=cv)
+        return (h, pl), routing
 
-    (h, ck, cv, conv_pl, ssm_pl), _ = jax.lax.scan(
-        wrap_remat(period, remat), (h, ck, cv, conv_pl, ssm_pl),
-        jnp.arange(cfg.num_layers // per, dtype=jnp.int32))
+    def stretch(carry, kinds, first, p=None, per=None):
+        """The layers `kinds` in order. `first`: how many layers of each
+        kind ("attn", "state") and FFN kind lie ahead of the stretch's
+        first pass; pass p (traced) of a period with `per` of each lies
+        p * per further. Returns (carry, [routing a run, in order])."""
+        seen = dict(first)
+
+        def number(c):
+            n = jnp.asarray(seen[c], jnp.int32)
+            return n if p is None else n + p * per[c]
+
+        routes, j = [], 0
+        while j < len(kinds):
+            k, which = kinds[j]
+            if k == "attn":
+                carry, r = attn_layer(
+                    which, carry, number("attn"), number(which))
+                r = jax.tree_util.tree_map(lambda a: a[None], r)
+                run = 1
+            else:
+                run = 1
+                while j + run < len(kinds) and kinds[j + run] == kinds[j]:
+                    run += 1
+                ar = jnp.arange(run, dtype=jnp.int32)
+                carry, r = jax.lax.scan(
+                    partial(state_layer, which), carry,
+                    (number("state") + ar, number(which) + ar))
+            if r is not None:
+                routes.append(r)
+            seen["attn" if k == "attn" else "state"] += run
+            seen[which] += run
+            j += run
+        return carry, routes
+
+    def count(kinds):
+        out = dict.fromkeys(("attn", "state", "own", "dense", "moe"), 0)
+        for k, which in kinds:
+            out["attn" if k == "attn" else "state"] += 1
+            out[which] += 1
+        return out
+
+    def joined(routes):
+        return jax.tree_util.tree_map(
+            lambda *a: jnp.concatenate(a), *routes) if routes else None
+
+    carry, routes = stretch((h, pl), lead, count(()))
+    if reps:
+        per = count(period_kinds)
+
+        def period(carry, p):
+            carry, r = stretch(carry, period_kinds, count(lead), p, per)
+            return carry, joined(r)
+
+        carry, r = jax.lax.scan(
+            wrap_remat(period, remat), carry,
+            jnp.arange(reps, dtype=jnp.int32))
+        if r is not None:  # [reps, a period's moe layers, ...]
+            routes.append(jax.tree_util.tree_map(
+                lambda a: a.reshape((-1,) + a.shape[2:]), r))
+        carry, more = stretch(
+            carry, tail, count(lead + period_kinds * reps))
+        routes += more
+    h, pl = carry
+    routing = joined(routes)
     if not paged:
-        return h, None
-    return h, {
-        "k": ck.reshape((La, P) + ck.shape[1:]),
-        "v": cv.reshape((La, P) + cv.shape[1:]),
-        mamba.CONV: conv_pl, mamba.SSM: ssm_pl,
-    }
+        return h, None, routing
+    return h, dict(
+        pl,
+        k=pl["k"].reshape((La, P) + pl["k"].shape[1:]),
+        v=pl["v"].reshape((La, P) + pl["v"].shape[1:]),
+    ), routing
 
 
 def lm_head(params: Params, cfg: LLMConfig, h: jnp.ndarray,
@@ -1782,7 +2014,8 @@ def forward(
             write_mask=write_mask,
             q_segments=q_segments,
             attn_impl=attn_impl,
-            experts=None if experts_flat is None else (experts_flat, layer),
+            experts=(None if experts_flat is None or layer is None
+                     else (experts_flat, layer)),
             **kw,
         )
         return constrain(h, *hs_spec), ck, cv, routing[0] if routing else None
@@ -1889,11 +2122,12 @@ def forward(
             raise ValueError(unsupported_for_recurrent(
                 "a packed ragged step, packed training or a dense cache"
             ))
-        h, new_cache = _hybrid_layers(
+        h, new_cache, expert_counts = _hybrid_layers(
             cfg, layers, h, block=block, kv_cache=kv_cache,
             block_tables=block_tables, positions=positions,
             kv_lengths=kv_lengths, kv_mask=kv_mask,
             state_slots=state_slots, attn_impl=attn_impl, remat=remat,
+            experts_flat=experts_flat,
         )
     elif cfg.windowed:
         from oryx_tpu.ops import paged_kv

@@ -562,10 +562,22 @@ def is_latent_pool(kv_pages) -> bool:
 INDEX_K = "index_k"
 
 
-# The per-SLOT planes of a pool whose model has state-space layers
-# (`qwen2.init_paged_kv_cache`): [Lm, S, ...], addressed by slot, never
+# The per-SLOT planes of a pool whose model has state layers
+# (`qwen2.init_paged_kv_cache`): [Ls, S, ...], addressed by slot, never
 # through a block table. Everything that moves PAGES leaves them alone.
+# A Mamba hybrid's pool has both, a gated-short-convolution hybrid's
+# `conv` alone (its two rows are its whole state).
 SLOT_PLANES = ("conv", "ssm")
+
+
+# The page-edge snapshots of a pool whose state layers are gated short
+# convolutions: [Lc, P, (K-1) * d], row p the `conv` rows of the lane
+# that filled page p as they stood after the page's LAST token. A PAGED
+# plane, behind the same block table and allocator as `k` / `v`: what
+# moves, shares, evicts or reuses a page does the same to its snapshot,
+# which is what a prefix-cache hit hands over (`handover_state`). Valid
+# wherever the page is full, which is the only kind the cache indexes.
+CONV_EDGE = "conv_edge"
 
 
 # The window layers' paged planes of a pool whose model has window
@@ -581,7 +593,7 @@ WINDOW_PLANES = ("wk", "wv")
 def paged_planes(kv_pages):
     """The pool without its per-slot planes: what a page index means
     something in. `kv_pages` itself where it has none."""
-    if isinstance(kv_pages, dict) and SLOT_PLANES[1] in kv_pages:
+    if isinstance(kv_pages, dict) and any(n in kv_pages for n in SLOT_PLANES):
         return {k: v for k, v in kv_pages.items() if k not in SLOT_PLANES}
     return kv_pages
 
@@ -592,6 +604,18 @@ def _with_paged(kv_pages, paged):
     if paged_planes(kv_pages) is kv_pages:
         return paged
     return {**kv_pages, **paged}
+
+
+@partial(jax.jit, donate_argnums=0)
+def handover_state(kv_pages, page: jnp.ndarray, slot: jnp.ndarray):
+    """A prefix-cache hit's state: slot `slot`'s `conv` rows become the
+    snapshot page `page` keeps (`CONV_EDGE`), every state layer at once.
+    Donates the pool; page and slot are traced scalars (one compiled
+    program a pool shape)."""
+    with jax.named_scope("conv_handover"):
+        conv = kv_pages[SLOT_PLANES[0]].at[:, slot].set(
+            kv_pages[CONV_EDGE][:, page])
+    return {**kv_pages, SLOT_PLANES[0]: conv}
 
 
 def kv_pool_dtype(kv_pages) -> str:

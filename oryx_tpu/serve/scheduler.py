@@ -611,16 +611,21 @@ class ContinuousScheduler:
                 )
         # A recurrent state a slot and a window plane's re-based table
         # are each carried by the split engine's two programs alone. The
-        # prefix cache of either is constructed OFF, with the refusal's
-        # words in the log: a hit hands over pages and no state
-        # (snapshots at page boundaries are ROADMAP R3(a)), or would
+        # prefix cache of a Mamba hybrid or a window model is
+        # constructed OFF, with the refusal's words in the log: a hit
+        # hands over pages and no [d_state, d_inner] state, or would
         # have to hand over the window plane's pages as they stood at
-        # the hit's last token.
+        # the hit's last token. A gated short convolution's state is a
+        # snapshot a page (`paged_kv.CONV_EDGE`), which a hit that ends
+        # on a page edge hands over (`_splice_and_grow`): its cache
+        # stays on.
         self.recurrent = bool(llm.recurrent)
+        self.conv_state = llm.state_kind == "conv"
         self.windowed = bool(llm.windowed)
         self.indexed = bool(llm.indexed)
         for on, sentence in (
-            (self.recurrent, qwen2.unsupported_for_recurrent),
+            (self.recurrent and not self.conv_state,
+             qwen2.unsupported_for_recurrent),
             (self.windowed, qwen2.unsupported_for_window),
         ):
             if on and prefix_cache:
@@ -855,7 +860,8 @@ class ContinuousScheduler:
         # routing the step returns (generate.SHARE_STATS; docs/
         # OBSERVABILITY.md "Expert share"). rows_max / rows_mean count
         # the HELD experts' rows.
-        whole_stats = bool(self.windowed and pipe.cfg.llm.num_experts)
+        whole_stats = bool(
+            (self.windowed or self.recurrent) and pipe.cfg.llm.num_experts)
         self.share_stats = bool(
             pipe.cfg.llm.experts_held or pipe.cfg.llm.zero_experts
             or whole_stats)
@@ -888,18 +894,35 @@ class ContinuousScheduler:
             reg.counter("decode_kv_tokens_total")
         if self.recurrent:
             # The recurrent state's accounting (docs/OBSERVABILITY.md
-            # "Recurrent state"): prompt tokens the prefill scan ran
+            # "Recurrent state", "Gated short convolution"), under the
+            # state kind's prefix (`ssm_` a Mamba hybrid's, `conv_` a
+            # gated short convolution's): prompt tokens the prefill ran
             # over, live lanes x steps of the decode update and the
             # cached tokens their attention read (both from the lengths
             # a chunk ran with and came back with), sequences that
-            # started from a zero state, and the planes' bytes.
-            reg.counter("ssm_prefill_tokens_total")
-            reg.counter("ssm_decode_lane_steps_total")
-            reg.counter("ssm_state_resets_total")
+            # started from a zero state, and the planes' bytes. A conv
+            # state beside: sequences that started from a page-edge
+            # snapshot, snapshots written, and the snapshots' bytes.
+            self._state = "conv" if self.conv_state else "ssm"
             reg.counter("decode_kv_tokens_total")
-            reg.gauge("ssm_state_bytes").set(
-                num_slots * pipe.cfg.llm.state_bytes_per_slot(
-                    jnp.dtype(oryx.compute_dtype(pipe.cfg)).itemsize))
+            width = jnp.dtype(oryx.compute_dtype(pipe.cfg)).itemsize
+            state_bytes = num_slots * llm.state_bytes_per_slot(width)
+            if not self.conv_state:
+                reg.counter("ssm_prefill_tokens_total")
+                reg.counter("ssm_decode_lane_steps_total")
+                reg.counter("ssm_state_resets_total")
+                reg.gauge("ssm_state_bytes").set(state_bytes)
+            else:
+                reg.counter("conv_prefill_tokens_total")
+                reg.counter("conv_decode_lane_steps_total")
+                reg.counter("conv_state_resets_total")
+                reg.gauge("conv_state_bytes").set(state_bytes)
+                reg.counter("conv_state_handovers_total")
+                reg.counter("conv_edge_writes_total")
+                reg.gauge("conv_edge_bytes").set(
+                    self.num_pages * llm.state_bytes_per_slot(width))
+            if whole_stats:  # the block step's name, as a window model
+                reg.counter("moe_experts_hit_total")
         self.wplane = None
         if self.windowed:
             # Both planes' accounting (docs/OBSERVABILITY.md "Window
@@ -1265,7 +1288,7 @@ class ContinuousScheduler:
         identity changes at every donated dispatch), and upload runs
         under the pipe's mesh scope so a heads-sharded pool re-places
         the page correctly."""
-        if self.recurrent:
+        if self.recurrent and not self.conv_state:
             raise ValueError(qwen2.unsupported_for_recurrent(
                 "prefix-cache splicing"))
         if self.windowed:
@@ -2834,6 +2857,11 @@ class ContinuousScheduler:
             limit = req.length - req.length % self.block
             matched -= matched % self.block
         use = min(matched, limit)
+        if self.conv_state:
+            # A hit ends where a snapshot of the state lies: on a page
+            # edge. A prompt that ends inside a matched page prefills
+            # that page's tokens again (no copy-on-write here).
+            use -= use % ps
         full = use // ps
         # Feasibility screen BEFORE any share or COW device copy: the
         # fresh pages needed beyond the spliced prefix must be coverable
@@ -2929,6 +2957,15 @@ class ContinuousScheduler:
             req.spliced = 0
             req.prefill_pos = 0
             return False
+        if self.conv_state and spliced:
+            # The state after the hit's last token: the snapshot its
+            # last page keeps, into the slot's rows, ahead of the
+            # suffix's first chunk.
+            self.kv_pages = paged_kv.handover_state(
+                self.kv_pages,
+                jnp.asarray(int(self.bt[s, full - 1]), jnp.int32),
+                jnp.asarray(s, jnp.int32))
+            self.metrics.inc("conv_state_handovers_total")
         self.metrics.inc("prefix_cache_hit_tokens_total", spliced)
         self.metrics.inc(
             "prefix_cache_miss_tokens_total", req.length - spliced
@@ -3150,9 +3187,14 @@ class ContinuousScheduler:
         req.cost_prefill_tokens += end - off
         self.metrics.inc("prefill_tokens_total", end - off)
         if self.recurrent:
-            self.metrics.inc("ssm_prefill_tokens_total", end - off)
+            self.metrics.inc(f"{self._state}_prefill_tokens_total", end - off)
             if off == 0:
-                self.metrics.inc("ssm_state_resets_total")
+                self.metrics.inc(f"{self._state}_state_resets_total")
+            if self.conv_state:
+                # A snapshot a page whose last token the chunk held.
+                ps = self.page_size
+                self.metrics.inc(
+                    "conv_edge_writes_total", end // ps - off // ps)
         # Token p attends positions 0..p: the chunk's causal pairs.
         self.metrics.inc(
             "prefill_attn_pairs_total", (end - off) * (off + end + 1) // 2)
@@ -3722,10 +3764,19 @@ class ContinuousScheduler:
         if self.recurrent:
             # It advanced n positions and read a + 1 .. a + n cached
             # tokens.
-            self.metrics.inc("ssm_decode_lane_steps_total", int(n.sum()))
             self.metrics.inc(
-                "decode_kv_tokens_total",
-                int((n * a + n * (n + 1) // 2).sum()))
+                f"{self._state}_decode_lane_steps_total", int(n.sum()))
+            if not self.share_stats:  # (else the step's own statistics)
+                self.metrics.inc(
+                    "decode_kv_tokens_total",
+                    int((n * a + n * (n + 1) // 2).sum()))
+            if self.conv_state:
+                # It fed positions a .. a + n - 1: a snapshot where one
+                # was the last of its page.
+                ps = self.page_size
+                self.metrics.inc(
+                    "conv_edge_writes_total",
+                    int(((a + n) // ps - a // ps).sum()))
         if self.windowed:
             # It fed positions a .. a + n - 1, and a window layer's row
             # at p read min(p + 1, W) cached tokens (a global layer's
@@ -3922,7 +3973,7 @@ class ContinuousScheduler:
         m.inc("moe_expert_rows_max_total", st["held_rows_max"])
         m.inc("moe_expert_rows_mean_total", st["held_rows"] / count)
         m.inc("decode_kv_tokens_total", st["kv_tokens"])
-        if self.windowed:
+        if self.windowed or self.recurrent:
             m.inc("moe_experts_hit_total", st["held_hit"])
         if self.cfg.llm.n_shared_experts:
             m.inc(
@@ -3949,7 +4000,7 @@ class ContinuousScheduler:
             m.inc("moe_prefill_held_experts_hit_total", st["held_hit"])
             m.inc("moe_prefill_held_expert_slots_total",
                   st["layer_forwards"] * self.cfg.llm.held[1])
-            if self.windowed:
+            if self.windowed or self.recurrent:
                 # A whole-expert config's chunks under the block step's
                 # names too, beside its decode dispatches'.
                 m.inc("moe_expert_rows_max_total", st["held_rows_max"])

@@ -422,9 +422,14 @@ def test_the_engine_serves_what_it_does_not_refuse(
 
 
 @pytest.mark.parametrize("bad", [
-    {"block_length": 4, "mask_token_id": 511}, {"qk_norm": True},
+    {"block_length": 4, "mask_token_id": 511},
     {"attention_bias": True},
-    {"num_experts": 4, "num_experts_per_tok": 2, "moe_intermediate_size": 8},
+    # (an expert layer and q/k norm beside a state layer run since PR 56:
+    # tests/test_lfm2.py; these two kinds of expert layer do not.)
+    {"num_experts": 4, "num_experts_per_tok": 2, "moe_intermediate_size": 8,
+     "zero_experts": 2},
+    {"num_experts": 4, "num_experts_per_tok": 2, "moe_intermediate_size": 8,
+     "moe_activation": "relu"},
 ])
 def test_the_config_refuses_what_is_not_built_for_a_state(bad):
     with pytest.raises(ValueError, match=REFUSAL):
@@ -475,13 +480,13 @@ def test_page_movers_leave_the_slot_planes_alone(tiny):
     out = paged_kv.copy_pages(kv, jnp.asarray(3), jnp.asarray(5))
     out = paged_kv.upload_page(out, jnp.asarray(6), blob)
     assert set(out) == {"k", "v", "conv", "ssm"}
-    assert out["ssm"].shape == (cfg.num_mamba_layers, 2, 8, 128)
-    assert out["conv"].shape == (cfg.num_mamba_layers, 2, 3 * 128)
+    assert out["ssm"].shape == (cfg.num_state_layers, 2, 8, 128)
+    assert out["conv"].shape == (cfg.num_state_layers, 2, 3 * 128)
 
 
 def test_presets_state_the_published_geometry():
     llm = cfg_lib.jamba2_3b().llm
-    assert (llm.num_layers, llm.num_attn_layers, llm.num_mamba_layers) == (
+    assert (llm.num_layers, llm.num_attn_layers, llm.num_state_layers) == (
         28, 2, 26)
     assert [i for i in range(28)
             if i % llm.attn_layer_period == llm.attn_layer_offset] == [7, 21]

@@ -908,3 +908,113 @@ def test_smallthinker_serve_programs_keep_both_planes_in_place(
     assert memory.temp_size_in_bytes < 0.45e9
     total = memory.argument_size_in_bytes + memory.temp_size_in_bytes
     assert 0.25 * 16e9 < total < 0.8 * 16e9
+
+
+@pytest.mark.parametrize(
+    "program", ["paged_decode_chunk", "paged_prefill", "handover_state"])
+def test_lfm2_serve_programs_keep_pages_state_and_snapshots_in_place(
+    one_chip, mosaic, program, capsys
+):
+    """The programs of `lfm2-24b-a2b.agent-sessions` at the cell's FULL
+    size (the ten-layer cut, 64 experts of 1,536, the whole vocabulary of
+    65,536; 96 slots x 8,192, the engine's default pool of 12,288 pages of
+    64, chunk 512 / 8), compiled for the described v5e: the donated pool (paged K/V of the
+    two attention layers, two heads of 64 a row of 128 lanes; the
+    per-slot conv rows of the eight conv layers; their page-edge
+    snapshots, one row a page) is aliased to the output whole, and the
+    temporaries stay under a tenth of the snapshot plane (nothing copies
+    a plane: a decode step that wrote every layer's snapshots in ONE
+    scatter behind the layers kept a copy of it, 0.6 GB). The attention
+    kernels are `_ragged_paged` (decode: the head of 64 is no tile of
+    the page walk's copies, the packed row is) and `_mha_forward`
+    (prefill), the experts `gmm` (a kernel of 2,048 x 1,536 in tiles of
+    1,024 x 768). The numbers printed here are the configuration file's
+    `memory` block: arguments and both programs' temporaries leave over
+    half a gigabyte of the chip's 15.75 GB usable (ISSUE 56)."""
+    import dataclasses
+    import json
+    import os
+
+    from oryx_tpu import config as cfg_lib
+    from oryx_tpu.models import generate, qwen2
+    from oryx_tpu.ops import paged_kv
+
+    def on_chip(make):
+        return jax.tree_util.tree_map(
+            lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=one_chip),
+            jax.eval_shape(make),
+        )
+
+    conf = json.load(open(os.path.join(
+        os.path.dirname(os.path.dirname(os.path.abspath(__file__))),
+        "benchmark", "configs", "lfm2-24b-a2b-serve.json")))
+    lay, mem = conf["layout"], conf["memory"]
+    cfg = dataclasses.replace(
+        cfg_lib.lfm2_24b_a2b().llm, num_layers=lay["num_layers"])
+    slots, page_size, ctx = lay["num_slots"], lay["page_size"], lay["max_ctx"]
+    assert (slots, page_size, ctx) == (96, 64, 8192)
+    pages = slots * ctx // page_size  # the engine's default pool
+    assert pages == mem["num_pages"] == 12288 and "num_pages" not in lay
+    S = slots if program == "paged_decode_chunk" else 1
+    rows = lambda dtype, *tail: jax.ShapeDtypeStruct(  # noqa: E731
+        (S, *tail), dtype, sharding=one_chip
+    )
+    params = on_chip(
+        lambda: qwen2.init_params(cfg, jax.random.key(0), dtype=BF16))
+    kv = on_chip(lambda: qwen2.init_paged_kv_cache(
+        cfg, pages, page_size, dtype=BF16, num_slots=slots))
+    assert kv["k"].shape == (2, 12288, 64, 4, 128)  # two heads a row
+    assert kv["conv"].shape == (8, 96, 4096)
+    assert kv[paged_kv.CONV_EDGE].shape == (8, 12288, 4096)
+    tables = rows(jnp.int32, ctx // page_size)
+    sampling = (
+        on_chip(lambda: jax.random.split(jax.random.key(0), S)),
+        rows(jnp.float32), rows(jnp.float32), rows(jnp.int32),
+    )
+    common = dict(attn_impl="pallas", compute_dtype=BF16)
+    if program == "paged_decode_chunk":
+        lowered = generate.paged_decode_chunk.lower(
+            params, cfg, kv, tables, rows(jnp.int32), rows(jnp.int32),
+            rows(jnp.bool_), rows(jnp.int32, 0), *sampling,
+            chunk=lay["decode_chunk"], eos=65536, **common,
+        )
+    elif program == "paged_prefill":
+        lowered = generate.paged_prefill.lower(
+            params, cfg, rows(BF16, lay["prefill_chunk"], cfg.hidden_size),
+            rows(jnp.int32), tables, kv, rows(jnp.int32), *sampling,
+            slots=rows(jnp.int32), held_stats=True, **common,
+        )
+    else:
+        scalar = jax.ShapeDtypeStruct((), jnp.int32, sharding=one_chip)
+        lowered = paged_kv.handover_state.lower(kv, scalar, scalar)
+    compiled = lowered.compile()
+    text = compiled.as_text()
+    kernels = {"paged_decode_chunk": ("_ragged_paged", "gmm"),
+               "paged_prefill": ("_mha_forward", "gmm"),
+               "handover_state": ()}[program]
+    for kernel in kernels:
+        assert kernel in text
+    assert "ragged-dot" not in text  # every grouped product is the kernel
+    memory = compiled.memory_analysis()
+    nbytes = lambda t: sum(  # noqa: E731
+        a.size * a.dtype.itemsize for a in jax.tree_util.tree_leaves(t))
+    pool_bytes, weight_bytes = nbytes(kv), nbytes(params)
+    with capsys.disabled():
+        print(f"\n{program}: weights {weight_bytes} B, pool {pool_bytes} B, "
+              f"arguments {memory.argument_size_in_bytes} B, temporaries "
+              f"{memory.temp_size_in_bytes} B")
+    assert weight_bytes == mem["weights_bytes"] == 10_536_278_528
+    assert pool_bytes == mem["pool_bytes"] == (
+        12288 * (64 * 4096 + 65_536) + 96 * 65_536)
+    assert memory.alias_size_in_bytes == pool_bytes
+    edge_plane = 12288 * 65_536
+    assert memory.temp_size_in_bytes < 0.1 * edge_plane
+    if program != "handover_state":
+        key = program.split("_")[1]  # decode / prefill
+        assert memory.argument_size_in_bytes == mem[f"arguments_{key}_bytes"]
+        assert abs(memory.temp_size_in_bytes
+                   - mem[f"temporaries_{key}_bytes"]) < 8e6
+        total = (memory.argument_size_in_bytes
+                 + mem["temporaries_decode_bytes"]
+                 + mem["temporaries_prefill_bytes"])
+        assert 0.25 * 16e9 < total < 15.75e9 - 0.5e9
