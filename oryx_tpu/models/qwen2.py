@@ -1120,7 +1120,7 @@ def _mla(cfg: LLMConfig, a: jnp.ndarray, p: Params, cos, sin, *,
         elif ipool is not None:
             o, seen = _sparse_prefill(
                 cfg, q_nope, q_rope, w_uk, w_uv, qi, wi, pool, ipool, tables,
-                positions=positions, kv_mask=kv_mask)
+                positions=positions, kv_mask=kv_mask, attn_impl=attn_impl)
             if selected is not None:
                 selected["selected"] = jnp.packbits(seen, axis=-1)
         else:
@@ -1165,8 +1165,9 @@ def _index_inputs(cfg: LLMConfig, a, cq, p: Params, cos, sin):
     return qi, ki, wi
 
 
-# Keys one step of the sparse prefill's two loops handles.
-_SPARSE_TILE_TOKENS = 1024
+# Keys one step of the sparse prefill's two loops handles (the engine
+# counts the tiles a chunk visits: prefill_masked_tiles_total).
+SPARSE_TILE_TOKENS = 1024
 
 
 def _masked_attend(q, k, v, seen, scale):
@@ -1183,6 +1184,21 @@ def _masked_attend(q, k, v, seen, scale):
         "bhqk,bkhd->bqhd", p.astype(v.dtype), v,
         preferred_element_type=jnp.float32)
     return o, m, jnp.sum(p, axis=-1)
+
+
+def _attend_tile(carry, q, k, v, seen, scale):
+    """One tile of keys merged into an online softmax: `carry` is the
+    running (row maxima and sums [B, Hq, T], unnormalised output
+    [B, T, Hq, dv]) of the tiles before; `_masked_attend` over this
+    tile, then the merge. The XLA twin of
+    ops/pallas/masked_attention.masked_attend."""
+    m, l, acc = carry
+    o, m_t, l_t = _masked_attend(q, k, v, seen, scale)
+    m_new = jnp.maximum(m, m_t)
+    a_old, a_new = jnp.exp(m - m_new), jnp.exp(m_t - m_new)
+    scale_o = lambda x: jnp.moveaxis(x, 1, 2)[..., None]  # noqa: E731
+    return (m_new, l * a_old + l_t * a_new,
+            acc * scale_o(a_old) + o * scale_o(a_new))
 
 
 def _expand_keys(c, kr, w_uk, w_uv):
@@ -1258,14 +1274,17 @@ def _sparse_decode(cfg: LLMConfig, qf, qi, wi, pool, ipool, tables,
 
 
 def _sparse_prefill(cfg: LLMConfig, q_nope, q_rope, w_uk, w_uv, qi, wi, pool,
-                    ipool, tables, *, positions, kv_mask):
+                    ipool, tables, *, positions, kv_mask, attn_impl: str):
     """A chunk of queries [B, T] over its rows' paged prefix and itself
     (both already in the pool): index scores against the index keys a
-    tile of `_SPARSE_TILE_TOKENS` at a time, the top k a query as a
+    tile of `SPARSE_TILE_TOKENS` at a time, the top k a query as a
     MASK (`paged_kv.topk_mask`), then the expanded form a tile of keys
     at a time under an online softmax, so that per-head keys and values
     exist for ONE tile and the temporaries that grow with the table are
-    the scores and the mask alone. Tiles past the chunk's last position
+    the scores and the mask alone; a tile's step is `_attend_tile`, or
+    under "pallas" its kernel (ops/pallas/masked_attention._dsa_attend),
+    which keeps the tile's attention scores in VMEM and merges in
+    place. Tiles past the chunk's last position
     are not visited; a chunk that ends inside the first k positions
     selects everything and scores nothing. Returns ([B, T, Hq, dv],
     the pairs attended [B, T, K] bool)."""
@@ -1274,7 +1293,7 @@ def _sparse_prefill(cfg: LLMConfig, q_nope, q_rope, w_uk, w_uv, qi, wi, pool,
     B, T, Hq, _ = q_nope.shape
     R, dr, dv = cfg.kv_lora_rank, cfg.qk_rope_head_dim, cfg.v_head_dim
     ps = pool.shape[1]
-    tp = max(1, _SPARSE_TILE_TOKENS // ps)  # pages a tile
+    tp = max(1, SPARSE_TILE_TOKENS // ps)  # pages a tile
     sentinel = pool.shape[0]
     tables = jnp.pad(
         tables, ((0, 0), (0, -tables.shape[1] % tp)),
@@ -1309,20 +1328,20 @@ def _sparse_prefill(cfg: LLMConfig, q_nope, q_rope, w_uk, w_uv, qi, wi, pool,
     # Under k visible keys the selection is everything a query sees.
     seen = jax.lax.cond(end <= cfg.index_topk, lambda: seen, selected)
     q = jnp.concatenate([q_nope, q_rope], axis=-1)
+    if attn_impl == "pallas":
+        from oryx_tpu.ops.pallas.masked_attention import (
+            masked_attend as attend_tile,
+        )
+    else:
+        attend_tile = _attend_tile
 
     def attend(i, carry):
-        m, l, acc = carry
         lat = tile_rows(pool, i)
         k, v = _expand_keys(lat[..., :R], lat[..., R:R + dr], w_uk, w_uv)
-        o, m_t, l_t = _masked_attend(
-            q, k, v,
+        return attend_tile(
+            carry, q, k, v,
             jax.lax.dynamic_slice_in_dim(seen, i * Kt, Kt, axis=2),
             cfg.softmax_scale)
-        m_new = jnp.maximum(m, m_t)
-        a_old, a_new = jnp.exp(m - m_new), jnp.exp(m_t - m_new)
-        scale_o = lambda x: jnp.moveaxis(x, 1, 2)[..., None]  # noqa: E731
-        return (m_new, l * a_old + l_t * a_new,
-                acc * scale_o(a_old) + o * scale_o(a_new))
 
     with jax.named_scope("dsa_attend"):
         m, l, acc = jax.lax.fori_loop(0, tiles, attend, (

@@ -763,11 +763,14 @@ class ContinuousScheduler:
             # than index_topk scores them all) and the pairs its
             # attention reads (min(visible, index_topk) a query), beside
             # prefill_attn_pairs_total, which counts every causal pair;
+            # the key tiles its masked attention visits (one call of
+            # `_attend_tile`, or of the kernel `_dsa_attend`, a tile);
             # and the latent rows the decode rows read,
             # min(length, index_topk) a row, beside
             # decode_kv_tokens_total, the index keys they scored.
             reg.counter("prefill_index_pairs_total")
             reg.counter("prefill_selected_pairs_total")
+            reg.counter("prefill_masked_tiles_total")
             reg.counter("decode_selected_tokens_total")
         reg.counter("prefill_live_positions_total")
         reg.counter("prefill_table_positions_total")
@@ -3207,6 +3210,10 @@ class ContinuousScheduler:
                              int(seen[seen > k].sum()))
             self.metrics.inc("prefill_selected_pairs_total",
                              int(np.minimum(seen, k).sum()))
+            # ... a tile of keys at a time, every tile up to the chunk's
+            # last position (a chunk under k skips the selection alone).
+            self.metrics.inc("prefill_masked_tiles_total",
+                             -(-end // qwen2.SPARSE_TILE_TOKENS))
         if self.windowed:
             # ... of which a window layer's query at p sees min(p + 1, W).
             W = self.cfg.llm.sliding_window

@@ -18,6 +18,7 @@ from benchmark.reference import glm5_dsa_ref as ref
 from oryx_tpu import config as cfg_lib
 from oryx_tpu.models import generate, oryx, qwen2
 from oryx_tpu.ops import paged_kv
+from oryx_tpu.ops.pallas import masked_attention
 from oryx_tpu.ops.pallas import paged_attention as ppa
 from oryx_tpu.serve.pipeline import OryxInference
 from oryx_tpu.serve.scheduler import ContinuousScheduler
@@ -185,32 +186,33 @@ def test_two_lanes_either_side_of_the_top_k_in_one_chunk(tiny, impl):
 # ends on a chunk boundary, so that every row is computed at the place
 # in its chunk the cold request computes it at (XLA:CPU's matmul sums a
 # row of another place in another order: one ulp, whatever the model).
+@pytest.mark.parametrize("impl", ["xla", "pallas"])
 @pytest.mark.parametrize("shared", [64, 76])
-def test_a_prefix_hit_past_the_top_k_is_the_cold_request(tiny, shared):
+def test_a_prefix_hit_past_the_top_k_is_the_cold_request(tiny, shared, impl):
     cfg, params = tiny
     ids = _ids(3, 100)
     kv, bt = _pool(cfg, 1, 16)
-    kv, tok, r = _prefill(cfg, params, kv, bt, ids, chunk=32, impl="xla")
+    kv, tok, r = _prefill(cfg, params, kv, bt, ids, chunk=32, impl=impl)
     _, _, cold, _ = _decode(cfg, params, kv, bt, [int(tok[0])], [100], [True],
-                            chunks=1, chunk=4, impl="xla")
+                            chunks=1, chunk=4, impl=impl)
     cold = np.stack([np.asarray(r["logits"])[0]] + cold[0])
     # Another request left the first `shared` positions behind; this one
     # shares its whole pages and copies the page it will write into.
     kv, _ = _pool(cfg, 2, 16)
     other = jnp.arange(16, 32, dtype=jnp.int32)[None]
     kv, _, _ = _prefill(cfg, params, kv, other, ids[:shared], chunk=32,
-                        impl="xla")
+                        impl=impl)
     whole = shared // PS
     mine = np.arange(16, dtype=np.int32)
     mine[:whole] = np.asarray(other)[0, :whole]
     if shared % PS:
         kv = paged_kv.copy_pages(kv, other[0, whole], jnp.asarray(mine[whole]))
     mine = jnp.asarray(mine)[None]
-    kv, tok, r = _prefill(cfg, params, kv, mine, ids, chunk=32, impl="xla",
+    kv, tok, r = _prefill(cfg, params, kv, mine, ids, chunk=32, impl=impl,
                           start=shared)
     stale = jax.tree.map(jnp.copy, kv)
     _, _, hit, _ = _decode(cfg, params, kv, mine, [int(tok[0])], [100],
-                           [True], chunks=1, chunk=4, impl="xla")
+                           [True], chunks=1, chunk=4, impl=impl)
     hit = np.stack([np.asarray(r["logits"])[0]] + hit[0])
     if shared % 32 == 0:
         assert np.array_equal(hit, cold)
@@ -219,7 +221,7 @@ def test_a_prefix_hit_past_the_top_k_is_the_cold_request(tiny, shared):
     stale[paged_kv.INDEX_K] = stale[paged_kv.INDEX_K].at[
         :, other[0, 2]].set(0.0)
     _, _, off, _ = _decode(cfg, params, stale, mine, [int(tok[0])], [100],
-                           [True], chunks=1, chunk=4, impl="xla")
+                           [True], chunks=1, chunk=4, impl=impl)
     assert float(np.max(np.abs(np.stack(off[0]) - cold[1:]))) > 1e-3
 
 
@@ -375,6 +377,114 @@ def test_the_index_score_kernel_is_its_xla_twin(maxp):
     seen = np.isfinite(np.asarray(want))
     np.testing.assert_allclose(np.asarray(got)[seen], np.asarray(want)[seen],
                                atol=2e-5)
+
+
+def _tile_mask(name, rng, B, T, Kt):
+    if name == "full":
+        return np.ones((B, T, Kt), bool)
+    if name == "causal inside the tile":  # the chunk's own tile
+        return np.broadcast_to(
+            np.arange(T)[:, None] + (Kt - T) >= np.arange(Kt)[None], (B, T, Kt))
+    seen = rng.random((B, T, Kt)) < 0.06
+    if name == "empty rows and key blocks":
+        seen[:, 3] = seen[:, T - 8:] = False  # rows with nothing selected
+        seen[:, :, 128:384] = False  # whole blocks of keys nobody selected
+        seen[1] = False  # a lane past its length
+    return seen
+
+
+def _fresh_carry(B, Hq, T, dv):
+    """The state `_sparse_prefill`'s loop starts from."""
+    return (jnp.full((B, Hq, T), jnp.finfo(F32).min, F32),
+            jnp.zeros((B, Hq, T), F32), jnp.zeros((B, T, Hq, dv), F32))
+
+
+@pytest.mark.parametrize("T", [24, 512])
+@pytest.mark.parametrize("mask", [
+    "full", "causal inside the tile", "six percent",
+    "empty rows and key blocks"])
+def test_the_masked_attention_kernel_is_its_xla_twin(mask, T):
+    """`_dsa_attend` in interpret mode. From the loop's first state it
+    returns `_masked_attend`'s tile as it is: the row maxima, the row
+    sums and the unnormalised output, and a row with nothing selected
+    comes back as the twin's (m = finfo.min, l = 0, o = 0). From the
+    state that tile left, a second tile is `_attend_tile`'s merge, and a
+    row with nothing in it keeps its state. 512 queries are two blocks
+    of the kernel's 256."""
+    rng = np.random.default_rng(4)
+    B, Hq, d, dv, Kt = 2, 3, 24, 20, 512
+    normal = lambda *shape: jnp.asarray(rng.normal(size=shape), F32)  # noqa: E731
+    q = normal(B, T, Hq, d)
+    k, v = normal(B, Kt, Hq, d), normal(B, Kt, Hq, dv)
+    seen = jnp.asarray(_tile_mask(mask, rng, B, T, Kt))
+    o, m, l = qwen2._masked_attend(q, k, v, seen, 0.3)
+    first = masked_attention.masked_attend(
+        _fresh_carry(B, Hq, T, dv), q, k, v, seen, 0.3, interpret=True)
+    for g, w, tol in zip(first, (m, l, o), (0, 2e-5, 2e-5)):
+        assert g.shape == w.shape and g.dtype == w.dtype == F32
+        np.testing.assert_allclose(g, w, atol=tol, rtol=1e-6)
+    empty = ~np.asarray(seen).any(-1)  # [B, T]
+    assert empty.any() == (mask == "empty rows and key blocks")
+    gm, gl, go = (np.asarray(x) for x in first)
+    assert np.all(go[empty] == 0) and np.all(np.moveaxis(gl, 1, 2)[empty] == 0)
+    assert np.all(np.moveaxis(gm, 1, 2)[empty] == np.finfo(np.float32).min)
+    # A second tile, other keys under the same mask, merged into the first.
+    k2, v2 = normal(B, Kt, Hq, d) * 1.5, normal(B, Kt, Hq, dv)
+    want = qwen2._attend_tile(first, q, k2, v2, seen, 0.3)
+    got = masked_attention.masked_attend(
+        first, q, k2, v2, seen, 0.3, interpret=True)
+    for g, w, tol in zip(got, want, (0, 5e-5, 5e-5)):
+        np.testing.assert_allclose(g, w, atol=tol, rtol=1e-6)
+    for g, f in zip(got, first):  # nothing selected: the state as it was
+        g, f = (np.asarray(x) if x.shape[1] == T
+                else np.moveaxis(np.asarray(x), 1, 2) for x in (g, f))
+        assert np.array_equal(g[empty], f[empty])
+
+
+def test_the_masked_attention_kernel_refuses_what_it_cannot_tile():
+    x = jnp.zeros((1, 300, 2, 128), jnp.bfloat16)
+    kv = jnp.zeros((1, 256, 2, 128), jnp.bfloat16)
+    with pytest.raises(ValueError, match="_dsa_attend: cannot tile 300 q"):
+        masked_attention.masked_attend(
+            _fresh_carry(1, 2, 300, 128), x, kv, kv,
+            jnp.ones((1, 300, 256), bool), 1.0, interpret=True)
+    with pytest.raises(ValueError, match="_dsa_attend: cannot tile .* 200 k"):
+        masked_attention.masked_attend(
+            _fresh_carry(1, 2, 64, 128), x[:, :64], kv[:, :200], kv[:, :200],
+            jnp.ones((1, 64, 200), bool), 1.0, interpret=False)
+
+
+# A chunk that ends under the top k (the selection is everything), one
+# that passes it, and one against a cached prefix of several key tiles.
+@pytest.mark.parametrize("case, start, T", [
+    ("under the top k", 0, 8), ("across the top k", 8, 24),
+    ("over a prefix of three tiles", 2 * 1024 + 40, 32)])
+def test_sparse_prefill_under_pallas_is_the_xla_loop(tiny, case, start, T):
+    cfg, _ = tiny
+    rng = np.random.default_rng(6)
+    Hq, R = cfg.num_heads, cfg.kv_lora_rank
+    dn, dr, dv = cfg.qk_nope_head_dim, cfg.qk_rope_head_dim, cfg.v_head_dim
+    Hi, Di = cfg.index_heads, cfg.index_head_dim
+    maxp = -(-(start + T) // PS) + 3
+    P = maxp + 5
+    normal = lambda *shape: jnp.asarray(rng.normal(size=shape), F32)  # noqa: E731
+    pool, ipool = normal(P, PS, 128), normal(P, PS, Di)
+    tables = jnp.asarray(rng.permutation(P)[:maxp][None], jnp.int32)
+    args = (cfg, normal(1, T, Hq, dn), normal(1, T, Hq, dr),
+            normal(Hq, dn, R), normal(Hq, R, dv), normal(1, T, Hi, Di),
+            normal(1, T, Hi), pool, ipool, tables)
+    positions = jnp.arange(start, start + T, dtype=jnp.int32)[None]
+    kv_mask = jnp.ones((1, maxp * PS), bool)
+    want, seen = qwen2._sparse_prefill(
+        *args, positions=positions, kv_mask=kv_mask, attn_impl="xla")
+    got, seen_p = qwen2._sparse_prefill(
+        *args, positions=positions, kv_mask=kv_mask, attn_impl="pallas")
+    assert np.array_equal(np.asarray(seen), np.asarray(seen_p))
+    kept = np.asarray(seen).sum(-1)[0]
+    assert np.array_equal(kept, np.minimum(np.arange(start, start + T) + 1,
+                                           TOPK))
+    assert seen.shape[-1] == -(-(maxp * PS) // 1024) * 1024
+    np.testing.assert_allclose(got, want, atol=2e-5, rtol=1e-5)
 
 
 # (g) the router's scoring and its bias, four cases told apart.
@@ -592,6 +702,10 @@ def test_engine_serves_a_context_and_turns_through_the_prefix_cache(pipe):
     assert metrics.get("prefill_index_pairs_total") == seen[seen > TOPK].sum()
     assert metrics.get("prefill_selected_pairs_total") == np.minimum(
         seen, TOPK).sum()
+    # One key tile of 1,024 a chunk at these lengths, whichever side of
+    # the top k the chunk ends on.
+    assert metrics.get("prefill_masked_tiles_total") == (
+        -(-n0 // 32) - (-(n1 - hit) // 32))
     # Decode rows at lengths n + 1 .. n + cap - 1 (the last token is
     # sampled, never fed).
     steps = np.concatenate([np.arange(n0 + 1, n0 + 6),

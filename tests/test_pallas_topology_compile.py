@@ -607,17 +607,50 @@ def test_glm5_cell_fits_the_chip_and_expands_one_tile_of_keys(
         ptext = prefill.as_text()
         assert "bf16[1,1024,64,256]" in ptext
         assert f"bf16[1,{width * page_size},64,256]" not in ptext
+        # ... whose attention scores stay in the kernel's VMEM (PR 57):
+        # no float32 [heads, queries, keys] block in the module.
+        assert "_dsa_attend" in ptext
+        assert "f32[64,1024,1024]" not in ptext
+        assert "f32[1,64,1024,1024]" not in ptext
     memory = decode.memory_analysis()
     assert memory.alias_size_in_bytes == pool_bytes
     assert memory.temp_size_in_bytes < 0.5e9
     assert temps[widths[-1]] < 4.3e9 / 4
     # What grows with the table: the float32 scores of a chunk and their
     # order keys (2 x 4 B a pair) and the mask, not keys and values.
+    # (11.0 B a pair since PR 57: the narrowest bucket's peak was one
+    # tile's 268 MB of attention scores and 67 MB of its output, which
+    # hid 118 MB of these; it is 0.186 GB now, the widest's 0.832 as
+    # it was.)
     grown = temps[widths[-1]] - temps[widths[0]]
-    assert grown < 1024 * (65536 - 8192) * 9
+    assert grown < 1024 * (65536 - 8192) * 12
+    assert temps[widths[0]] < 0.2e9
     total = (nbytes(params) + pool_bytes + memory.temp_size_in_bytes
              + temps[widths[-1]])
     assert total <= 16.91e9 - 0.5e9, total
+
+
+@pytest.mark.parametrize("T, Kt", [(1024, 1024), (512, 1024), (16, 128)])
+def test_masked_attention_compiles_at_the_cells_tile(one_chip, T, Kt):
+    """`_dsa_attend` at `glm-5.long-sessions`' shapes (a chunk of 1,024
+    queries against one tile of 1,024 keys, 64 heads of 256), at half a
+    chunk, and at the smallest tile it takes: the [queries, keys] bias
+    in scratch, a head's keys and values whole, the running state
+    aliased in and out, its row statistics turned from lane-major rows
+    to columns and back in the kernel."""
+    from oryx_tpu.ops.pallas import masked_attention
+
+    Hq, d, dv = 64, 256, 256
+    text = _compiled_text(
+        lambda m, l, acc, q, k, v, seen: masked_attention.masked_attend(
+            (m, l, acc), q, k, v, seen, d ** -0.5, interpret=False),
+        one_chip, ((1, Hq, T), jnp.float32), ((1, Hq, T), jnp.float32),
+        ((1, T, Hq, dv), jnp.float32), ((1, T, Hq, d), BF16),
+        ((1, Kt, Hq, d), BF16), ((1, Kt, Hq, dv), BF16),
+        ((1, T, Kt), jnp.bool_),
+    )
+    assert "_dsa_attend" in text
+    assert f"f32[1,{Hq},{T},{Kt}]" not in text
 
 
 def test_illegal_heads_per_block_pin_raises_with_its_name(
