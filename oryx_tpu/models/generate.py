@@ -580,25 +580,32 @@ def paged_prefill(
         # again as the layers'.
         **({"return_hidden": True} if cfg.windowed else {}),
     )
-    last = jnp.take_along_axis(
-        logits, (lengths - 1 - start)[:, None, None].astype(jnp.int32),
-        axis=1,
-    )[:, 0]
-    if cfg.windowed:
-        # (eight copies of the row: a product of ONE row is computed on
-        # a float32 copy of the whole head, 1.5 GB of temporaries.)
-        last = qwen2.lm_head(
-            params, cfg, jnp.broadcast_to(last[:, None], (B, 8, last.shape[-1]))
+    with jax.named_scope("head"):
+        last = jnp.take_along_axis(
+            logits, (lengths - 1 - start)[:, None, None].astype(jnp.int32),
+            axis=1,
         )[:, 0]
-    pair = jax.vmap(lambda k: jax.random.split(k, 2))(keys)
-    tok0 = sample_token_rows(
-        last, pair[:, 1], temperature=temperature, top_p=top_p, top_k=top_k
-    )
-    out = (kv_pages, tok0, pair[:, 0])
-    if held_stats:
-        # The router saw rows [B * T]; a row past the prompt is padding.
-        real = (positions < lengths[:, None]).reshape(-1).astype(jnp.int32)
-        out = out + (share_stats(cfg, routing[0]["ids"], real),)
+        if cfg.windowed:
+            # (eight copies of the row: a product of ONE row is computed
+            # on a float32 copy of the whole head, 1.5 GB of
+            # temporaries.)
+            last = qwen2.lm_head(
+                params, cfg,
+                jnp.broadcast_to(last[:, None], (B, 8, last.shape[-1]))
+            )[:, 0]
+    with jax.named_scope("sample"):
+        pair = jax.vmap(lambda k: jax.random.split(k, 2))(keys)
+        tok0 = sample_token_rows(
+            last, pair[:, 1], temperature=temperature, top_p=top_p,
+            top_k=top_k,
+        )
+        out = (kv_pages, tok0, pair[:, 0])
+        if held_stats:
+            # The router saw rows [B * T]; a row past the prompt is
+            # padding.
+            real = (positions < lengths[:, None]).reshape(-1).astype(
+                jnp.int32)
+            out = out + (share_stats(cfg, routing[0]["ids"], real),)
     if return_routing:
         out = out + (dict(routing[0], logits=last),)
     if return_logits:
@@ -789,7 +796,8 @@ def paged_decode_chunk(
         kv_pages, tok, cur_len, finished, recent, keys, *more = carry
         if numerics:
             nstats = more[0]
-        pair = jax.vmap(lambda k: jax.random.split(k, 2))(keys)
+        with jax.named_scope("sample"):
+            pair = jax.vmap(lambda k: jax.random.split(k, 2))(keys)
         pos = cur_len[:, None]
         kv_mask = (slot_ar <= cur_len[:, None]).astype(jnp.int32)
         logits, kv_pages, *routing = qwen2.forward(
@@ -806,28 +814,29 @@ def paged_decode_chunk(
             **({"return_selected": True} if return_routing and cfg.indexed
                else {}),
         )
-        if numerics:
-            # Live-row logit probe on the logits the sampler is about
-            # to consume — same dispatch, zero extra device calls.
-            nstats = numerics_lib.accumulate_logit_stats(
-                nstats, logits[:, 0], ~finished
+        with jax.named_scope("sample"):
+            if numerics:
+                # Live-row logit probe on the logits the sampler is about
+                # to consume — same dispatch, zero extra device calls.
+                nstats = numerics_lib.accumulate_logit_stats(
+                    nstats, logits[:, 0], ~finished
+                )
+            nxt = sample_token_rows(
+                logits[:, 0], pair[:, 1],
+                temperature=temperature, top_p=top_p, top_k=top_k,
             )
-        nxt = sample_token_rows(
-            logits[:, 0], pair[:, 1],
-            temperature=temperature, top_p=top_p, top_k=top_k,
-        )
-        if recent.shape[1]:
-            recent = jnp.concatenate([recent[:, 1:], tok[:, None]], axis=1)
-        finished = finished | (tok == eos) | stop_hit(recent)
-        nxt = jnp.where(finished, eos, nxt)
-        cur_len = cur_len + (~finished).astype(jnp.int32)
-        out = (kv_pages, nxt, cur_len, finished, recent, pair[:, 0])
-        if numerics:
-            out = out + (nstats,)
-        if shared:
-            # `finished` here is this step's, as the forward saw it.
-            out = out + (more[-1] + share_stats(
-                cfg, routing[0]["ids"], kv_lengths),)
+            if recent.shape[1]:
+                recent = jnp.concatenate([recent[:, 1:], tok[:, None]], axis=1)
+            finished = finished | (tok == eos) | stop_hit(recent)
+            nxt = jnp.where(finished, eos, nxt)
+            cur_len = cur_len + (~finished).astype(jnp.int32)
+            out = (kv_pages, nxt, cur_len, finished, recent, pair[:, 0])
+            if numerics:
+                out = out + (nstats,)
+            if shared:
+                # `finished` here is this step's, as the forward saw it.
+                out = out + (more[-1] + share_stats(
+                    cfg, routing[0]["ids"], kv_lengths),)
         ys = (tok, finished)
         if return_routing:
             ys = ys + (logits[:, 0], routing[0]["ids"])
@@ -1555,9 +1564,10 @@ def _block_lanes_forward(
         lengths[:, None].astype(jnp.int32)
         + jnp.arange(-before, W - before, dtype=jnp.int32)[None, :], 0
     ).reshape(-1)
-    e = constrain(params["embed"]["weight"], None, None)[ids.reshape(-1)]
-    if compute_dtype is not None:
-        e = e.astype(compute_dtype)
+    with jax.named_scope("embed"):
+        e = constrain(params["embed"]["weight"], None, None)[ids.reshape(-1)]
+        if compute_dtype is not None:
+            e = e.astype(compute_dtype)
     h, kv_pages, *routing = qwen2.forward(
         params, cfg,
         inputs_embeds=e[None], positions=pos[None],
@@ -1567,12 +1577,13 @@ def _block_lanes_forward(
         return_hidden=True, return_routing=bool(cfg.num_experts),
     )
     # The head, as `qwen2.forward` ends, over the lanes that are read.
-    h = h[0].reshape(S, W, -1)[:, before:].reshape(S * (W - before), -1)
-    head = (
-        params["embed"]["weight"].T if cfg.tie_word_embeddings
-        else params["lm_head"]["kernel"]
-    )
-    logits = (h @ head.astype(h.dtype)).astype(jnp.float32)
+    with jax.named_scope("head"):
+        h = h[0].reshape(S, W, -1)[:, before:].reshape(S * (W - before), -1)
+        head = (
+            params["embed"]["weight"].T if cfg.tie_word_embeddings
+            else params["lm_head"]["kernel"]
+        )
+        logits = (h @ head.astype(h.dtype)).astype(jnp.float32)
     return logits, kv_pages, routing[0] if routing else None
 
 
@@ -1722,6 +1733,7 @@ def paged_block_step(
 
     max_steps = steps if remasking == "low_confidence_static" else B
 
+    @jax.named_scope("sample")
     def denoise(c, lg, kv, rows):
         """The carry after one forward that gave the open block's lanes
         the logits `lg`."""
@@ -1766,16 +1778,18 @@ def paged_block_step(
             jnp.concatenate([commit, write], axis=1), before=B,
         ))
     c = jax.lax.while_loop(cond, body, c)
-    toks = c["block"]
-    n_new = jnp.where(live, B - n_known, 0).astype(jnp.int32)
-    new_eos = jnp.any(
-        (toks == eos) & (lane[None, :] >= n_known[:, None]), axis=-1
-    )
-    counts = {k: c[k] for k in ("stats", "slot_forwards", "expert_rows")}
-    return (
-        c["kv"], toks, n_new, lengths + jnp.where(live, B, 0),
-        finished | (live & new_eos), c["keys"], counts,
-    )
+    with jax.named_scope("sample"):
+        toks = c["block"]
+        n_new = jnp.where(live, B - n_known, 0).astype(jnp.int32)
+        new_eos = jnp.any(
+            (toks == eos) & (lane[None, :] >= n_known[:, None]), axis=-1
+        )
+        counts = {
+            k: c[k] for k in ("stats", "slot_forwards", "expert_rows")}
+        return (
+            c["kv"], toks, n_new, lengths + jnp.where(live, B, 0),
+            finished | (live & new_eos), c["keys"], counts,
+        )
 
 
 # ---------------------------------------------------------------------------

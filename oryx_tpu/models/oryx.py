@@ -83,15 +83,17 @@ def encode_visual(
     # 256-frame 34B/v5e-64 point, AOT-measured, round 5) — so the
     # partitionable XLA segment-attention path is the right kernel
     # there, not a fallback.
-    feats = oryx_vit.forward(
-        params["vit"], cfg.vision, patches, segment_ids, pos_coords,
-        remat=remat, attn_impl=cfg.attn_impl, compute_dtype=compute_dtype,
-    )
-    return compressor_lib.forward(
-        params["compressor"], cfg.compressor, cfg.vision,
-        feats, region_ids, q_region_ids,
-        attn_impl="pallas" if cfg.attn_impl == "pallas" else "xla",
-    )
+    with jax.named_scope("vision"):
+        feats = oryx_vit.forward(
+            params["vit"], cfg.vision, patches, segment_ids, pos_coords,
+            remat=remat, attn_impl=cfg.attn_impl,
+            compute_dtype=compute_dtype,
+        )
+        return compressor_lib.forward(
+            params["compressor"], cfg.compressor, cfg.vision,
+            feats, region_ids, q_region_ids,
+            attn_impl="pallas" if cfg.attn_impl == "pallas" else "xla",
+        )
 
 
 def forward(

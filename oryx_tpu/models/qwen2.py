@@ -20,7 +20,6 @@ transposes torch's [out, in].
 
 from __future__ import annotations
 
-import contextlib
 from functools import partial
 from typing import Any
 
@@ -802,47 +801,56 @@ def _block(
     moe_in = {}
     if cfg.router_input == "layer_input":
         # The router reads the residual stream as the layer found it.
-        moe_in["logits"] = router_logits(
-            h.reshape(B * T, -1), lp["router"]["kernel"])
-    x = rms_norm(h, lp["input_norm"]["weight"], cfg.rms_norm_eps)
-    q = _linear(x, lp["q_proj"]).reshape(B, T, cfg.num_heads, cfg.head_dim)
-    k = _linear(x, lp["k_proj"]).reshape(B, T, cfg.num_kv_heads, cfg.head_dim)
-    v = _linear(x, lp["v_proj"]).reshape(B, T, cfg.num_kv_heads, cfg.head_dim)
-    if cfg.qk_norm:
-        q = rms_norm(q, lp["q_norm"]["weight"], cfg.rms_norm_eps)
-        k = rms_norm(k, lp["k_norm"]["weight"], cfg.rms_norm_eps)
-    if cos is not None:  # None: attention without a position term
-        q, k = apply_rope(q, k, cos, sin)
-    # Post-rope tags for the "attn_qkv" remat policy (utils/remat.py):
-    # saving here spares the backward both the projections and the rope.
-    q = checkpoint_name(q, "attn_q")
-    k = checkpoint_name(k, "attn_k")
-    v = checkpoint_name(v, "attn_v")
+        with jax.named_scope("moe"):
+            moe_in["logits"] = router_logits(
+                h.reshape(B * T, -1), lp["router"]["kernel"])
+    with jax.named_scope("attn"):
+        x = rms_norm(h, lp["input_norm"]["weight"], cfg.rms_norm_eps)
+        q = _linear(x, lp["q_proj"]).reshape(
+            B, T, cfg.num_heads, cfg.head_dim)
+        k = _linear(x, lp["k_proj"]).reshape(
+            B, T, cfg.num_kv_heads, cfg.head_dim)
+        v = _linear(x, lp["v_proj"]).reshape(
+            B, T, cfg.num_kv_heads, cfg.head_dim)
+        if cfg.qk_norm:
+            q = rms_norm(q, lp["q_norm"]["weight"], cfg.rms_norm_eps)
+            k = rms_norm(k, lp["k_norm"]["weight"], cfg.rms_norm_eps)
+        if cos is not None:  # None: attention without a position term
+            q, k = apply_rope(q, k, cos, sin)
+        # Post-rope tags for the "attn_qkv" remat policy
+        # (utils/remat.py): saving here spares the backward both the
+        # projections and the rope.
+        q = checkpoint_name(q, "attn_q")
+        k = checkpoint_name(k, "attn_k")
+        v = checkpoint_name(v, "attn_v")
 
-    with (jax.named_scope("attn_window" if window else "attn_global")
-          if cfg.windowed else contextlib.nullcontext()):
-        attn_out, cache_k, cache_v = _block_attention(
-            q, k, v, positions=positions, cache_k=cache_k, cache_v=cache_v,
-            write_slots=write_slots, kv_mask=kv_mask, attn_fn=attn_fn,
-            block_tables=block_tables, write_mask=write_mask,
-            kv_lengths=kv_lengths, q_segments=q_segments,
-            attn_impl=attn_impl, mask_positions=mask_positions, win=win,
-            kv_pack=cfg.kv_pack,
-        )
-    attn_out = attn_out.reshape(B, T, -1)
-    # "attn_o" tag: with remat_policy="attn_o" the residual-stream value
-    # h_mid = h + o_out is rebuilt from this saved projection, so the
-    # backward recomputes neither the attention nor o_proj.
-    h = h + checkpoint_name(_linear(attn_out, lp["o_proj"]), "attn_o")
+        with jax.named_scope("attn_window" if window else "attn_global"):
+            attn_out, cache_k, cache_v = _block_attention(
+                q, k, v, positions=positions, cache_k=cache_k,
+                cache_v=cache_v, write_slots=write_slots, kv_mask=kv_mask,
+                attn_fn=attn_fn, block_tables=block_tables,
+                write_mask=write_mask, kv_lengths=kv_lengths,
+                q_segments=q_segments, attn_impl=attn_impl,
+                mask_positions=mask_positions, win=win, kv_pack=cfg.kv_pack,
+            )
+        attn_out = attn_out.reshape(B, T, -1)
+        # "attn_o" tag: with remat_policy="attn_o" the residual-stream
+        # value h_mid = h + o_out is rebuilt from this saved projection,
+        # so the backward recomputes neither the attention nor o_proj.
+        h = h + checkpoint_name(_linear(attn_out, lp["o_proj"]), "attn_o")
 
-    x = rms_norm(h, lp["post_attn_norm"]["weight"], cfg.rms_norm_eps)
     if experts is not None:
-        y, routing = _moe(
-            cfg, x.reshape(B * T, -1), lp["router"]["kernel"], *experts,
-            impl=attn_impl, router_bias=lp["router"].get("bias"), **moe_in,
-        )
-        return h + y.reshape(B, T, -1), cache_k, cache_v, routing
-    return h + _swiglu(x, lp), cache_k, cache_v
+        with jax.named_scope("moe"):
+            x = rms_norm(h, lp["post_attn_norm"]["weight"], cfg.rms_norm_eps)
+            y, routing = _moe(
+                cfg, x.reshape(B * T, -1), lp["router"]["kernel"], *experts,
+                impl=attn_impl, router_bias=lp["router"].get("bias"),
+                **moe_in,
+            )
+            return h + y.reshape(B, T, -1), cache_k, cache_v, routing
+    with jax.named_scope("ffn"):
+        x = rms_norm(h, lp["post_attn_norm"]["weight"], cfg.rms_norm_eps)
+        return h + _swiglu(x, lp), cache_k, cache_v
 
 
 def _block_attention(q, k, v, *, positions, cache_k, cache_v, write_slots,
@@ -1366,22 +1374,29 @@ def _double_block(cfg: LLMConfig, h, lp: Params, cos, sin, *, pool, tables,
     eps = cfg.rms_norm_eps
     for i in (0, 1):
         sub = lp[f"sub{i}"]
-        a = rms_norm(h, sub["input_norm"]["weight"], eps)
-        with jax.named_scope("mla"):
-            att, pool = _mla(
-                cfg, a, sub, cos, sin, pool=pool,
-                tables=None if tables is None else tables[i],
-                attn_impl=attn_impl, **attn,
-            )
-        h = h + att
-        x = rms_norm(h, sub["post_attn_norm"]["weight"], eps)
+        with jax.named_scope("attn"):
+            a = rms_norm(h, sub["input_norm"]["weight"], eps)
+            with jax.named_scope("mla"):
+                att, pool = _mla(
+                    cfg, a, sub, cos, sin, pool=pool,
+                    tables=None if tables is None else tables[i],
+                    attn_impl=attn_impl, **attn,
+                )
+            h = h + att
+        # (one normed state feeds the expert layer and the dense FFN.)
+        with jax.named_scope("ffn"):
+            x = rms_norm(h, sub["post_attn_norm"]["weight"], eps)
         if i == 0:
-            s, routing = _moe(
-                cfg, x.reshape(B * T, -1), lp["router"]["kernel"], *experts,
-                impl=attn_impl, router_bias=lp["router"].get("bias"),
-            )
-        h = h + _swiglu(x, sub)
-    return h + s.reshape(B, T, -1), pool, routing
+            with jax.named_scope("moe"):
+                s, routing = _moe(
+                    cfg, x.reshape(B * T, -1), lp["router"]["kernel"],
+                    *experts, impl=attn_impl,
+                    router_bias=lp["router"].get("bias"),
+                )
+        with jax.named_scope("ffn"):
+            h = h + _swiglu(x, sub)
+    with jax.named_scope("moe"):
+        return h + s.reshape(B, T, -1), pool, routing
 
 
 def _latent_block(cfg: LLMConfig, h, lp: Params, cos, sin, *, pool, tables,
@@ -1394,29 +1409,33 @@ def _latent_block(cfg: LLMConfig, h, lp: Params, cos, sin, *, pool, tables,
     Returns (h, pool, the expert layer's routing or None)."""
     B, T, _ = h.shape
     eps = cfg.rms_norm_eps
-    a = rms_norm(h, lp["input_norm"]["weight"], eps)
     # What the twin asked to see of the selection (`_mla`), if anything.
     seen = {} if attn.pop("return_selected", False) else None
-    with jax.named_scope("mla"):
-        att, pool = _mla(
-            cfg, a, lp, cos, sin, pool=pool,
-            tables=None if tables is None else tables[0],
-            attn_impl=attn_impl, **attn,
-            **({} if seen is None else {"selected": seen}),
-        )
-    h = h + att
-    x = rms_norm(h, lp["post_attn_norm"]["weight"], eps)
+    with jax.named_scope("attn"):
+        a = rms_norm(h, lp["input_norm"]["weight"], eps)
+        with jax.named_scope("mla"):
+            att, pool = _mla(
+                cfg, a, lp, cos, sin, pool=pool,
+                tables=None if tables is None else tables[0],
+                attn_impl=attn_impl, **attn,
+                **({} if seen is None else {"selected": seen}),
+            )
+        h = h + att
     if experts is None:
         # A leading dense layer (cfg.dense_layers): one SwiGLU of
         # intermediate_size where the expert layer would be.
-        with jax.named_scope("dense_ffn"):
+        with jax.named_scope("ffn"), jax.named_scope("dense_ffn"):
+            x = rms_norm(h, lp["post_attn_norm"]["weight"], eps)
             return h + _swiglu(x, lp), pool, seen
-    y, routing = _moe(
-        cfg, x.reshape(B * T, -1), lp["router"]["kernel"], *experts,
-        impl=attn_impl, router_bias=lp["router"].get("bias"),
-        shared=lp.get("shared"),
-    )
-    return h + y.reshape(B, T, -1), pool, dict(routing, **(seen or {}))
+    with jax.named_scope("moe"):
+        x = rms_norm(h, lp["post_attn_norm"]["weight"], eps)
+        y, routing = _moe(
+            cfg, x.reshape(B * T, -1), lp["router"]["kernel"], *experts,
+            impl=attn_impl, router_bias=lp["router"].get("bias"),
+            shared=lp.get("shared"),
+        )
+        return (h + y.reshape(B, T, -1), pool,
+                dict(routing, **(seen or {})))
 
 
 def _hybrid_layers(cfg: LLMConfig, layers: Params, h, *, block, kv_cache,
@@ -1481,103 +1500,118 @@ def _hybrid_layers(cfg: LLMConfig, layers: Params, h, *, block, kv_cache,
     decode = paged and state_slots is None and T == 1
     inplace = (is_mamba and decode and attn_impl == "pallas"
                and mamba.step_fits(cfg, B))
-    if inplace:
-        step_inv = mamba.step_invariants(
-            layers["mamba"]["mixer"], valid[:, 0], h.dtype)
-    edges = None
-    if EDGE in pl and decode:
-        # A lane whose token is the last of its page leaves its window
-        # in that page's row; any other writes out of bounds (dropped).
-        ps = kv_cache["k"].shape[2]
-        pos = positions[:, 0]
-        page = jnp.take_along_axis(
-            block_tables, jnp.minimum(
-                pos // ps, block_tables.shape[1] - 1)[:, None], axis=1)[:, 0]
-        edges = jnp.where(
-            valid[:, 0] & (pos % ps == ps - 1) & (page < P), page, P)
-    elif EDGE in pl:
-        # The page edges this chunk's rows cross, once for every layer.
-        edges = short_conv.page_edges(
-            positions, kv_lengths, block_tables, P, kv_cache["k"].shape[2])
+    with jax.named_scope("mixer"):
+        if inplace:
+            step_inv = mamba.step_invariants(
+                layers["mamba"]["mixer"], valid[:, 0], h.dtype)
+        edges = None
+        if EDGE in pl and decode:
+            # A lane whose token is the last of its page leaves its
+            # window in that page's row; any other writes out of bounds
+            # (dropped).
+            ps = kv_cache["k"].shape[2]
+            pos = positions[:, 0]
+            page = jnp.take_along_axis(
+                block_tables, jnp.minimum(
+                    pos // ps, block_tables.shape[1] - 1)[:, None],
+                axis=1)[:, 0]
+            edges = jnp.where(
+                valid[:, 0] & (pos % ps == ps - 1) & (page < P), page, P)
+        elif EDGE in pl:
+            # The page edges this chunk's rows cross, once for every
+            # layer.
+            edges = short_conv.page_edges(
+                positions, kv_lengths, block_tables, P,
+                kv_cache["k"].shape[2])
 
     def at(tree, i):
-        return jax.tree_util.tree_map(
-            lambda a: jax.lax.dynamic_index_in_dim(a, i, keepdims=False),
-            tree)
+        with jax.named_scope("stack"):
+            return jax.tree_util.tree_map(
+                lambda a: jax.lax.dynamic_index_in_dim(
+                    a, i, keepdims=False),
+                tree)
 
     def ffn(which: str, h, lp, fi):
         """h + the layer's FFN on its normed state -> (h, routing)."""
-        x = rms_norm(h, lp["post_attn_norm"]["weight"], cfg.rms_norm_eps)
-        if which == "own":
-            return h + _swiglu(x, lp), None
-        if which == "dense":
-            return h + _swiglu(x, at(layers["dense"], fi)), None
-        router = at(layers["router"], fi)
-        y, routing = _moe(
-            cfg, x.reshape(B * T, -1), router["kernel"], experts_flat, fi,
-            impl=attn_impl, router_bias=router.get("bias"))
-        return h + y.reshape(B, T, -1), routing
+        with jax.named_scope("moe" if which == "moe" else "ffn"):
+            x = rms_norm(
+                h, lp["post_attn_norm"]["weight"], cfg.rms_norm_eps)
+            if which == "own":
+                return h + _swiglu(x, lp), None
+            if which == "dense":
+                return h + _swiglu(x, at(layers["dense"], fi)), None
+            router = at(layers["router"], fi)
+            y, routing = _moe(
+                cfg, x.reshape(B * T, -1), router["kernel"], experts_flat,
+                fi, impl=attn_impl, router_bias=router.get("bias"))
+            return h + y.reshape(B, T, -1), routing
 
     def state_layer(which: str, carry, idx):
         h, pl = carry
         li, fi = idx
         lp = at(layers[kind], li)
         conv_pl, ssm_pl = pl.get(CONV), pl.get(SSM)
-        if not paged:
-            conv0 = jnp.zeros((B, K1, d), h.dtype)
-            h0 = jnp.zeros((B, N, d), jnp.float32)
-        elif not inplace:  # (the kernels index the planes by li)
-            conv0 = at(conv_pl, li)  # [S, ...]
-            h0 = at(ssm_pl, li) if is_mamba else None
-            if state_slots is None:
-                conv0 = conv0.reshape(B, K1, d)
-            elif is_mamba:
-                conv0, h0 = mamba.rows_state(
-                    conv0, h0, state_slots, fresh, (B, K1, d))
-            else:
-                conv0 = jnp.where(
-                    fresh, 0, conv0[state_slots].reshape(B, K1, d))
-        u = rms_norm(h, lp["input_norm"]["weight"], cfg.rms_norm_eps)
-        h1 = win = None
-        with jax.named_scope("mamba" if is_mamba else "short_conv"):
-            if inplace:
-                out, (conv_pl, ssm_pl) = mamba.mixer_step_inplace(
-                    cfg, lp["mixer"], step_inv, li, u, (conv_pl, ssm_pl))
-            elif is_mamba and decode:
-                out, (conv1, h1) = mamba.mixer_step(
-                    cfg, lp["mixer"], u, (conv0, h0), valid[:, 0])
-            elif is_mamba:
-                out, (conv1, h1) = mamba.mixer_prefill(
-                    cfg, lp["mixer"], u, (conv0, h0), valid, impl=attn_impl)
-            elif decode:
-                out, conv1 = short_conv.mixer_step(
-                    cfg, lp["mixer"], u, conv0, valid[:, 0])
-            else:
-                out, conv1, win = short_conv.mixer_prefill(
-                    cfg, lp["mixer"], u, conv0, valid)
-        h, routing = ffn(which, h + out, lp, fi)
-        if paged and not inplace:
-            conv1 = conv1.reshape(B, K1 * d)
-            if state_slots is None:
-                conv_pl = jax.lax.dynamic_update_index_in_dim(
-                    conv_pl, conv1, li, 0)
-                if is_mamba:
-                    ssm_pl = jax.lax.dynamic_update_index_in_dim(
-                        ssm_pl, h1, li, 0)
-            else:
-                conv_pl = conv_pl.at[li, state_slots].set(conv1)
-                if is_mamba:
-                    ssm_pl = ssm_pl.at[li, state_slots].set(h1)
-        if paged:
-            pl = dict(pl, **{CONV: conv_pl},
-                      **({SSM: ssm_pl} if is_mamba else {}))
-            if edges is not None and decode:
-                pl[EDGE] = pl[EDGE].at[li, edges].set(conv1, mode="drop")
-            elif edges is not None:
-                pages, n = edges
-                pl[EDGE] = pl[EDGE].at[li, pages.reshape(-1)].set(
-                    short_conv.edge_rows(win, n, K1 + 1).astype(
-                        pl[EDGE].dtype), mode="drop")
+        with jax.named_scope("mixer"):
+            if not paged:
+                conv0 = jnp.zeros((B, K1, d), h.dtype)
+                h0 = jnp.zeros((B, N, d), jnp.float32)
+            elif not inplace:  # (the kernels index the planes by li)
+                conv0 = at(conv_pl, li)  # [S, ...]
+                h0 = at(ssm_pl, li) if is_mamba else None
+                if state_slots is None:
+                    conv0 = conv0.reshape(B, K1, d)
+                elif is_mamba:
+                    conv0, h0 = mamba.rows_state(
+                        conv0, h0, state_slots, fresh, (B, K1, d))
+                else:
+                    conv0 = jnp.where(
+                        fresh, 0, conv0[state_slots].reshape(B, K1, d))
+            u = rms_norm(h, lp["input_norm"]["weight"], cfg.rms_norm_eps)
+            h1 = win = None
+            with jax.named_scope("mamba" if is_mamba else "short_conv"):
+                if inplace:
+                    out, (conv_pl, ssm_pl) = mamba.mixer_step_inplace(
+                        cfg, lp["mixer"], step_inv, li, u,
+                        (conv_pl, ssm_pl))
+                elif is_mamba and decode:
+                    out, (conv1, h1) = mamba.mixer_step(
+                        cfg, lp["mixer"], u, (conv0, h0), valid[:, 0])
+                elif is_mamba:
+                    out, (conv1, h1) = mamba.mixer_prefill(
+                        cfg, lp["mixer"], u, (conv0, h0), valid,
+                        impl=attn_impl)
+                elif decode:
+                    out, conv1 = short_conv.mixer_step(
+                        cfg, lp["mixer"], u, conv0, valid[:, 0])
+                else:
+                    out, conv1, win = short_conv.mixer_prefill(
+                        cfg, lp["mixer"], u, conv0, valid)
+            h = h + out
+        h, routing = ffn(which, h, lp, fi)
+        with jax.named_scope("mixer"):  # the state's write-back
+            if paged and not inplace:
+                conv1 = conv1.reshape(B, K1 * d)
+                if state_slots is None:
+                    conv_pl = jax.lax.dynamic_update_index_in_dim(
+                        conv_pl, conv1, li, 0)
+                    if is_mamba:
+                        ssm_pl = jax.lax.dynamic_update_index_in_dim(
+                            ssm_pl, h1, li, 0)
+                else:
+                    conv_pl = conv_pl.at[li, state_slots].set(conv1)
+                    if is_mamba:
+                        ssm_pl = ssm_pl.at[li, state_slots].set(h1)
+            if paged:
+                pl = dict(pl, **{CONV: conv_pl},
+                          **({SSM: ssm_pl} if is_mamba else {}))
+                if edges is not None and decode:
+                    pl[EDGE] = pl[EDGE].at[li, edges].set(
+                        conv1, mode="drop")
+                elif edges is not None:
+                    pages, n = edges
+                    pl[EDGE] = pl[EDGE].at[li, pages.reshape(-1)].set(
+                        short_conv.edge_rows(win, n, K1 + 1).astype(
+                            pl[EDGE].dtype), mode="drop")
         return (h, pl), routing
 
     def attn_layer(which: str, carry, ai, fi):
@@ -1676,13 +1710,14 @@ def lm_head(params: Params, cfg: LLMConfig, h: jnp.ndarray,
             logits_dtype: jnp.dtype = jnp.float32) -> jnp.ndarray:
     """Logits of final hidden states h [..., H] (`forward`'s
     return_hidden): the tied embedding, transposed, or the head."""
-    if cfg.tie_word_embeddings:
-        return (h @ params["embed"]["weight"].astype(h.dtype).T).astype(
+    with jax.named_scope("head"):
+        if cfg.tie_word_embeddings:
+            return (h @ params["embed"]["weight"].astype(h.dtype).T).astype(
+                logits_dtype
+            )
+        return (h @ params["lm_head"]["kernel"].astype(h.dtype)).astype(
             logits_dtype
         )
-    return (h @ params["lm_head"]["kernel"].astype(h.dtype)).astype(
-        logits_dtype
-    )
 
 
 def _window_layers(cfg: LLMConfig, layers: Params, h, *, block, kv_cache,
@@ -1738,10 +1773,11 @@ def _window_layers(cfg: LLMConfig, layers: Params, h, *, block, kv_cache,
         if lo == hi:
             return h, wk, wv, None
         js = jnp.arange(lo, hi, dtype=jnp.int32)
+        with jax.named_scope("stack"):
+            lp_run = jax.tree_util.tree_map(lambda a: a[lo:hi], lp_all)
         (h, wk, wv), r = jax.lax.scan(
             window_layer, (h, wk, wv),
-            (jax.tree_util.tree_map(lambda a: a[lo:hi], lp_all),
-             p * (per - 1) + js - (lo > off), p * per + js))
+            (lp_run, p * (per - 1) + js - (lo > off), p * per + js))
         return h, wk, wv, r
 
     def period(carry, xs):
@@ -1749,9 +1785,10 @@ def _window_layers(cfg: LLMConfig, layers: Params, h, *, block, kv_cache,
         lp_all, p = xs
         h, wk, wv, before = window_run(h, wk, wv, lp_all, p, 0, off)
         tables = offset(block_tables, Pg, Lg, p) if paged else None
+        with jax.named_scope("stack"):
+            lp = jax.tree_util.tree_map(lambda a: a[off], lp_all)
         h, gk, gv, r = block(
-            h, jax.tree_util.tree_map(lambda a: a[off], lp_all), gk, gv,
-            tables, p * per + off, **no_rope)
+            h, lp, gk, gv, tables, p * per + off, **no_rope)
         h, wk, wv, after = window_run(h, wk, wv, lp_all, p, off + 1, per)
         routes = [x for x in (
             before, jax.tree_util.tree_map(lambda a: a[None], r), after)
@@ -1760,11 +1797,12 @@ def _window_layers(cfg: LLMConfig, layers: Params, h, *, block, kv_cache,
             lambda *a: jnp.concatenate(a), *routes)
 
     n_per = cfg.num_layers // per
+    with jax.named_scope("stack"):
+        periods = jax.tree_util.tree_map(
+            lambda a: a.reshape((n_per, per) + a.shape[1:]), layers)
     (h, gk, gv, wk, wv), routing = jax.lax.scan(
         wrap_remat(period, remat), (h, gk, gv, wk, wv),
-        (jax.tree_util.tree_map(
-            lambda a: a.reshape((n_per, per) + a.shape[1:]), layers),
-         jnp.arange(n_per, dtype=jnp.int32)))
+        (periods, jnp.arange(n_per, dtype=jnp.int32)))
     routing = jax.tree_util.tree_map(
         lambda a: a.reshape((cfg.num_layers,) + a.shape[2:]), routing)
     if not paged:
@@ -1885,16 +1923,17 @@ def forward(
     Returns (logits [B, T, V] in logits_dtype, updated kv_cache or None).
     """
     assert (input_ids is None) != (inputs_embeds is None)
-    if inputs_embeds is None:
-        # All-gather the (fsdp-sharded) table before the lookup so the
-        # gather output doesn't inherit the table layout and force an
-        # involuntary full rematerialization to hs_spec (see
-        # splice.embed_spliced).
-        inputs_embeds = constrain(
-            params["embed"]["weight"], None, None
-        )[input_ids]
-    if compute_dtype is not None:
-        inputs_embeds = inputs_embeds.astype(compute_dtype)
+    with jax.named_scope("embed"):
+        if inputs_embeds is None:
+            # All-gather the (fsdp-sharded) table before the lookup so
+            # the gather output doesn't inherit the table layout and
+            # force an involuntary full rematerialization to hs_spec
+            # (see splice.embed_spliced).
+            inputs_embeds = constrain(
+                params["embed"]["weight"], None, None
+            )[input_ids]
+        if compute_dtype is not None:
+            inputs_embeds = inputs_embeds.astype(compute_dtype)
     # Pin the hidden-state sharding so GSPMD doesn't guess intermediates:
     # batch over the data axes, sequence over sp only in ring mode.
     seq_axis = "sp" if attn_impl.startswith("ring") else None
@@ -1906,19 +1945,22 @@ def forward(
         positions = jnp.broadcast_to(jnp.arange(T, dtype=jnp.int32), (B, T))
     rope_dim = cfg.qk_rope_head_dim if cfg.latent else cfg.head_dim
     scaling = {}
-    if cfg.yarn:
-        scaling = dict(
-            inv_freq=yarn_frequencies(
-                rope_dim, cfg.rope_theta, factor=cfg.rope_scaling_factor,
-                original=cfg.rope_original_max_position,
-                beta_fast=cfg.rope_beta_fast, beta_slow=cfg.rope_beta_slow,
-            ),
-            scale=cfg.rope_cos_sin_scale,
-        )
     cos = sin = None  # attention without a position term
-    if cfg.use_rope:
-        cos, sin = rope_cos_sin(
-            positions, rope_dim, cfg.rope_theta, **scaling)  # [B,T,D]
+    with jax.named_scope("attn"):  # every layer's angles, once
+        if cfg.yarn:
+            scaling = dict(
+                inv_freq=yarn_frequencies(
+                    rope_dim, cfg.rope_theta,
+                    factor=cfg.rope_scaling_factor,
+                    original=cfg.rope_original_max_position,
+                    beta_fast=cfg.rope_beta_fast,
+                    beta_slow=cfg.rope_beta_slow,
+                ),
+                scale=cfg.rope_cos_sin_scale,
+            )
+        if cfg.use_rope:
+            cos, sin = rope_cos_sin(
+                positions, rope_dim, cfg.rope_theta, **scaling)  # [B,T,D]
 
     if kv_cache is not None and write_slots is None:
         write_slots = positions[:, 0]
@@ -2223,7 +2265,8 @@ def forward(
         if kv_cache is not None:
             new_cache = {"k": ys[0], "v": ys[1]}
 
-    h = rms_norm(h, params["final_norm"]["weight"], cfg.rms_norm_eps)
+    with jax.named_scope("head"):
+        h = rms_norm(h, params["final_norm"]["weight"], cfg.rms_norm_eps)
     if return_hidden:
         # Final hidden states pre-lm_head: the chunked-CE training path
         # (train/loss.chunked_causal_lm_loss) projects to the vocab

@@ -23,6 +23,7 @@ from __future__ import annotations
 
 import dataclasses
 
+import jax
 import numpy as np
 import jax.numpy as jnp
 
@@ -207,7 +208,9 @@ def embed_spliced(
     tables first (standard FSDP use-site gather) makes the downstream
     reshard a local slice.
     """
-    text = constrain(embed_table, None, None)[token_ids]
-    vis = constrain(visual_buffer, None, None)[visual_idx].astype(text.dtype)
-    out = jnp.where(is_visual[..., None], vis, text)
-    return constrain(out, ("dp", "fsdp"), None, None)
+    with jax.named_scope("embed"):
+        text = constrain(embed_table, None, None)[token_ids]
+        vis = constrain(
+            visual_buffer, None, None)[visual_idx].astype(text.dtype)
+        out = jnp.where(is_visual[..., None], vis, text)
+        return constrain(out, ("dp", "fsdp"), None, None)

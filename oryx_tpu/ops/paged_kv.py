@@ -612,7 +612,7 @@ def handover_state(kv_pages, page: jnp.ndarray, slot: jnp.ndarray):
     snapshot page `page` keeps (`CONV_EDGE`), every state layer at once.
     Donates the pool; page and slot are traced scalars (one compiled
     program a pool shape)."""
-    with jax.named_scope("conv_handover"):
+    with jax.named_scope("mixer"), jax.named_scope("conv_handover"):
         conv = kv_pages[SLOT_PLANES[0]].at[:, slot].set(
             kv_pages[CONV_EDGE][:, page])
     return {**kv_pages, SLOT_PLANES[0]: conv}

@@ -123,10 +123,11 @@ def microbatch_loss(
         w, transpose = llm_p["embed"]["weight"], True
     else:
         w, transpose = llm_p["lm_head"]["kernel"], False
-    loss, metrics = chunked_causal_lm_loss(
-        hidden, w, mb["labels"],
-        chunk=cfg.train.loss_chunk, transpose=transpose,
-    )
+    with jax.named_scope("loss"):
+        loss, metrics = chunked_causal_lm_loss(
+            hidden, w, mb["labels"],
+            chunk=cfg.train.loss_chunk, transpose=transpose,
+        )
     if numerics:
         # Activation absmax (the final hidden state — the residual
         # stream every layer feeds): an fp16/bf16 range excursion shows

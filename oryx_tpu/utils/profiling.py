@@ -137,28 +137,65 @@ class PhaseClock:
         self._end_host()
 
 
+# The device's work by the program's own names: the top-level layers
+# of every step program, each entered with `jax.named_scope` where the
+# layer's work is (docs/OBSERVABILITY.md "Memory & device time" has
+# what each holds), and the scopes inside a layer. A capture's
+# `XLA Ops` events carry them in their op_name; `scope_table` reads
+# them back. Metadata on the HLO: nothing at run time, and there
+# whether or not anyone traces.
+DEVICE_SCOPES = (
+    "embed", "attn", "ffn", "moe", "mixer", "head", "sample", "vision",
+    "loss", "optimizer_update", "nonfinite_guard",
+    # a layer's (or a period's) weights cut out of the stacked arrays by
+    # the model's own code (`_window_layers`, `_hybrid_layers.at`): what
+    # a scan does with its xs is jax's and carries no scope.
+    "stack",
+)
+DEVICE_SUBSCOPES = (
+    "attn_global", "attn_window", "mla", "dsa_index", "dsa_select",
+    "dsa_attend", "dense_ffn", "moe_routed", "moe_shared", "mamba",
+    "short_conv", "ssm_scan", "ssm_step", "conv_handover",
+)
+
+
+def scope_table(planes) -> dict:
+    """{program: {scope path: [self seconds, count]}} of one parsed
+    capture (xplane.scope_seconds over this program's vocabulary); {}
+    where it holds no device plane."""
+    from oryx_tpu.utils import xplane
+
+    return xplane.scope_seconds(planes, DEVICE_SCOPES, DEVICE_SUBSCOPES)
+
+
 @dataclasses.dataclass
 class OpProfile:
-    """Result of op_profile: ranked (name, total_ms) plus provenance —
-    `source` distinguishes real device op time ("tpu_xla_ops") from the
-    host-event fallback ("host_fallback"), which measures python/dispatch
-    and must never be mistaken for device time when optimizing."""
+    """Result of op_profile: ranked (name, scope path, total_ms) plus
+    provenance. `source` distinguishes real device op time
+    ("tpu_xla_ops") from the host-event fallback ("host_fallback"),
+    which measures python/dispatch and must never be mistaken for
+    device time when optimizing. The name is the compiler's and changes
+    with every compile; the scope path (xplane.scope_of: "attn/mla",
+    "unscoped", "" for a host event) is the program's and does not.
+    `scopes` is the capture's `scope_table`."""
 
-    top: list[tuple[str, float]]
+    top: list[tuple[str, str, float]]
     source: str
     xplane_path: str
     plane_names: list[str]
+    scopes: dict = dataclasses.field(default_factory=dict)
 
 
 def op_profile(
     fn, *args, trace_dir: str, steps: int = 3, top_n: int = 25
 ) -> OpProfile:
     """Run `fn(*args)` `steps` times under a trace and return an
-    OpProfile: top ops by total device time — self-contained: the
-    written xplane.pb is decoded by utils/xplane.py, no TensorBoard
+    OpProfile: top ops by total device time, each with its scope path,
+    and the device's time by scope path (`.scopes`) — self-contained:
+    the written xplane.pb is decoded by utils/xplane.py, no TensorBoard
     tooling needed. On TPU this reads the 'XLA Ops' device lines; on CPU
     it falls back to host events (module aggregates excluded), flagged
-    via `.source`.
+    via `.source`, and `.scopes` is empty.
 
     fn should already be compiled (call it once beforehand) — compile
     time inside the trace would swamp the profile."""
@@ -178,13 +215,24 @@ def op_profile(
         planes, n=top_n, plane_filter="TPU", line_filter="Ops"
     )
     if device:
-        return OpProfile(device, "tpu_xla_ops", files[-1], names)
+        op_names = {
+            ev.name: ev.op_name
+            for p in planes if "TPU" in p.name
+            for ln in p.lines if "Ops" in ln.name for ev in ln.events
+        }
+        return OpProfile(
+            [(name, xplane.scope_of(
+                op_names[name], DEVICE_SCOPES, DEVICE_SUBSCOPES), ms)
+             for name, ms in device],
+            "tpu_xla_ops", files[-1], names, scope_table(planes),
+        )
     host = [
         xplane.Plane(p.name, [l for l in p.lines if "Modules" not in l.name])
         for p in planes
     ]
     return OpProfile(
-        xplane.top_ops(host, n=top_n), "host_fallback", files[-1], names
+        [(name, "", ms) for name, ms in xplane.top_ops(host, n=top_n)],
+        "host_fallback", files[-1], names,
     )
 
 
@@ -404,7 +452,8 @@ class DeviceTimeSampler:
     def finish_capture(self, windows: list[tuple[str, int, int]]) -> dict:
         """Close an on-demand multi-step capture: the /debug/profile
         response — Perfetto-loadable Chrome trace + per-kind
-        attribution over the captured dispatch windows. Errors come
+        attribution over the captured dispatch windows + the device's
+        time by scope path (`scope_table`). Errors come
         back as {"error": ...} (and the stage counter), never raised
         into the engine loop."""
         from oryx_tpu.utils import xplane
@@ -418,6 +467,7 @@ class DeviceTimeSampler:
             att = attribute_capture(planes, windows,
                                     session_end_ns=end_ns)
             body = xplane.chrome_trace(planes)
+            scopes = scope_table(planes)
         except Exception as e:
             self._err("attribute")
             return {"error": f"profile attribution failed: "
@@ -427,4 +477,6 @@ class DeviceTimeSampler:
         body["device_time_us"] = att["by_kind_us"]
         body["other_us"] = att["other_us"]
         body["source"] = att["source"]
+        # {program: {scope path: [self seconds, count]}}; {} off a TPU.
+        body["scope_seconds"] = scopes
         return body

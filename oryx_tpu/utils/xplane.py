@@ -12,14 +12,19 @@ Field numbers follow tsl/profiler/protobuf/xplane.proto:
   .stat_metadata=5(map) .stats=6;
   XLine.name=2 .timestamp_ns=3 .events=4;
   XEvent.metadata_id=1 .offset_ps=2 .duration_ps=3;
-  XEventMetadata(map value).id=1 .name=2 .display_name=4;
-  XStat.metadata_id=1 .uint64_value=3 .int64_value=4.
+  XEventMetadata(map value).id=1 .name=2 .display_name=4 .stats=5;
+  XStat.metadata_id=1 .uint64_value=3 .int64_value=4 .str_value=5.
 
 Timestamps: an event's absolute start is line.timestamp_ns +
 event.offset_ps/1000. Host work is put on that clock by the program
 itself: the engine and trainer loops' phases are
 `jax.profiler.TraceAnnotation`s (utils/profiling.PhaseClock), so a
-capture holds them in its host plane beside the device's ops.
+capture holds them in its host plane beside the device's ops. Device
+work is named by the program too: an `XLA Ops` event's metadata
+carries the HLO `op_name` (its OP_NAME_STAT), the path of
+`jax.named_scope`s the op was traced under, which `scope_seconds`
+folds onto the program's layer vocabulary
+(utils/profiling.DEVICE_SCOPES).
 
 No dependency on tensorflow or protobuf. Used by
 utils/profiling.py (op_profile, the DeviceTimeSampler).
@@ -27,8 +32,10 @@ utils/profiling.py (op_profile, the DeviceTimeSampler).
 
 from __future__ import annotations
 
+import bisect
 import glob
 import os
+import re
 from dataclasses import dataclass, field
 
 
@@ -78,6 +85,10 @@ class Event:
     name: str
     duration_ps: int
     offset_ps: int = 0  # start offset within the owning line
+    # The HLO op_name of a device op: "jit(<program>)/jit(main)/..."
+    # down the named scopes to the primitive; "" where the event's
+    # metadata carries none (host events, compiler-made ops).
+    op_name: str = ""
 
 
 @dataclass
@@ -97,6 +108,14 @@ class Plane:
     stats: dict[str, int] = field(default_factory=dict)
 
 
+# The stat of an `XLA Ops` event metadata that holds the HLO op_name
+# (what the trace viewer shows as the op's framework name): on a TPU
+# v5e under jax 0.9.0 a `str_value` that ends in ":" (seen by hand,
+# PR 58; no capture showed it interned as a `ref_value`, or under
+# another name, so neither is read).
+OP_NAME_STAT = "tf_op"
+
+
 def _parse_event(buf: bytes) -> tuple[int, int, int]:
     meta_id = dur = offset = 0
     for fnum, _, val in _fields(buf):
@@ -109,9 +128,10 @@ def _parse_event(buf: bytes) -> tuple[int, int, int]:
     return meta_id, dur, offset
 
 
-def _parse_metadata_entry(buf: bytes) -> tuple[int, str]:
-    """One map<int64, XEventMetadata> entry → (id, best name)."""
-    key, name, display = 0, "", ""
+def _parse_metadata_entry(buf: bytes) -> tuple[int, str, list]:
+    """One map<int64, X{Event,Stat}Metadata> entry → (id, best name,
+    the metadata's string-valued stats [(stat metadata id, str)])."""
+    key, name, display, stats = 0, "", "", []
     for fnum, _, val in _fields(buf):
         if fnum == 1:
             key = val
@@ -121,10 +141,20 @@ def _parse_metadata_entry(buf: bytes) -> tuple[int, str]:
                     name = v2.decode("utf-8", "replace")
                 elif f2 == 4:
                     display = v2.decode("utf-8", "replace")
-    return key, display or name
+                elif f2 == 5:
+                    mid = sval = None
+                    for f3, _, v3 in _fields(v2):
+                        if f3 == 1:
+                            mid = v3
+                        elif f3 == 5:
+                            sval = v3.decode("utf-8", "replace")
+                    if mid is not None and sval is not None:
+                        stats.append((mid, sval))
+    return key, display or name, stats
 
 
-def _parse_line(buf: bytes, names: dict[int, str]) -> Line:
+def _parse_line(buf: bytes, names: dict[int, str],
+                op_names: dict[int, str]) -> Line:
     line = Line(name="")
     for fnum, _, val in _fields(buf):
         if fnum == 2:
@@ -134,7 +164,8 @@ def _parse_line(buf: bytes, names: dict[int, str]) -> Line:
         elif fnum == 4:
             meta_id, dur, offset = _parse_event(val)
             line.events.append(
-                Event(names.get(meta_id, str(meta_id)), dur, offset)
+                Event(names.get(meta_id, str(meta_id)), dur, offset,
+                      op_names.get(meta_id, ""))
             )
     return line
 
@@ -142,6 +173,7 @@ def _parse_line(buf: bytes, names: dict[int, str]) -> Line:
 def _parse_plane(buf: bytes) -> Plane:
     name = ""
     metadata: dict[int, str] = {}
+    meta_stats: dict[int, list] = {}
     stat_names: dict[int, str] = {}
     stat_vals: list[tuple[int, int]] = []  # (metadata_id, int value)
     line_bufs: list[bytes] = []
@@ -151,10 +183,12 @@ def _parse_plane(buf: bytes) -> Plane:
         elif fnum == 3:
             line_bufs.append(val)
         elif fnum == 4:
-            k, v = _parse_metadata_entry(val)
+            k, v, stats = _parse_metadata_entry(val)
             metadata[k] = v
+            if stats:
+                meta_stats[k] = stats
         elif fnum == 5:
-            k, v = _parse_metadata_entry(val)
+            k, v, _ = _parse_metadata_entry(val)
             stat_names[k] = v
         elif fnum == 6:
             mid = ival = None
@@ -165,9 +199,16 @@ def _parse_plane(buf: bytes) -> Plane:
                     ival = v2
             if mid is not None and ival is not None:
                 stat_vals.append((mid, ival))
+    # The stat metadata may follow the event metadata in the buffer:
+    # an event metadata's op_name is looked up once both are read.
+    op_names: dict[int, str] = {}
+    for k, stats in meta_stats.items():
+        for mid, sval in stats:
+            if stat_names.get(mid) == OP_NAME_STAT:
+                op_names[k] = sval
     return Plane(
         name,
-        [_parse_line(b, metadata) for b in line_bufs],
+        [_parse_line(b, metadata, op_names) for b in line_bufs],
         {
             stat_names[mid]: v for mid, v in stat_vals
             if mid in stat_names
@@ -225,6 +266,105 @@ def top_ops(
     totals = op_totals(planes, **kw)
     ranked = sorted(totals.items(), key=lambda kv: -kv[1])[:n]
     return [(name, ps / 1e9) for name, ps in ranked]
+
+
+# A transformation's mark on a component of an op_name:
+# "transpose(jvp(attn))" is the backward of what was traced under
+# "attn", and falls under its forward's layer.
+_WRAPPED = re.compile(r"^(?:transpose|jvp|vmap|checkpoint|remat\w*)\((.*)\)$")
+_PROGRAM = re.compile(r"^jit\(([^)]*)\)")
+_FINGERPRINT = re.compile(r"\(\d+\)$")
+UNSCOPED = "unscoped"
+
+
+def scope_of(op_name: str, vocabulary, below=()) -> str:
+    """The scope path of one HLO op_name: the first of its components
+    that is a name of `vocabulary` (the program's top-level layers) and,
+    where one follows, the DEEPEST later component that is a name of
+    `below` (the scopes inside a layer), joined by "/":
+    ".../while/body/attn/mla/dsa_attend/dot_general" is
+    "attn/dsa_attend". Components are compared with their
+    transformation marks (_WRAPPED) taken off. UNSCOPED where no
+    component is in the vocabulary, or there is no op_name."""
+    top = sub = None
+    for comp in op_name.split("/"):
+        while (m := _WRAPPED.match(comp)):
+            comp = m.group(1)
+        if top is None:
+            if comp in vocabulary:
+                top = comp
+        elif comp in below:
+            sub = comp
+    if top is None:
+        return UNSCOPED
+    return top if sub is None else top + "/" + sub
+
+
+def _self_ps(line: Line):
+    """(event, SELF picoseconds) per event of one line: its duration
+    less that of the events nested directly inside it. The `XLA Ops`
+    line nests (a `while` holds its body's ops, a level or more deep),
+    so summed durations would count a loop once a level; self times add
+    up to the line's busy time (the rule benchmark/trace.self_times
+    states)."""
+    out, stack = [], []  # stack of [end, event, self]
+    for ev in sorted(line.events,
+                     key=lambda e: (e.offset_ps, -e.duration_ps)):
+        while stack and stack[-1][0] <= ev.offset_ps:
+            _, done, self_ps = stack.pop()
+            out.append((done, self_ps))
+        if stack:
+            stack[-1][2] -= ev.duration_ps
+        stack.append([ev.offset_ps + ev.duration_ps, ev, ev.duration_ps])
+    out.extend((ev, self_ps) for _, ev, self_ps in stack)
+    return out
+
+
+def scope_seconds(planes: list[Plane], vocabulary, below=()) -> dict:
+    """{program: {scope path: [self seconds, count]}}: the device's time
+    by the program's own layer names. SELF time (`_self_ps`) of every
+    event on each `/device:TPU:` plane's `XLA Ops` line, averaged over
+    the chips, under the event's `scope_of` path; `program` is the
+    `jit(<name>)` that opens the op_name, or, for an op without one (a
+    copy the compiler made), the `XLA Modules` event it ran inside. A
+    program's paths add up to its busy time; {} where the capture has
+    no device plane. A fusion carries ONE op_name, its root's: what
+    was fused into it from a neighbouring scope is counted with the
+    root."""
+    devs = [p for p in planes if p.name.startswith("/device:TPU:")]
+    out: dict = {}
+    for plane in devs:
+        lines = {ln.name: ln for ln in plane.lines}
+        if "XLA Ops" not in lines:
+            continue
+        modules = sorted(
+            (ev.offset_ps, ev.offset_ps + ev.duration_ps,
+             _FINGERPRINT.sub("", ev.name).removeprefix("jit_"))
+            for ev in getattr(lines.get("XLA Modules"), "events", ()))
+        starts = [m[0] for m in modules]
+        shift = 0  # the two lines' own timestamps may differ
+        if modules:
+            shift = (lines["XLA Ops"].timestamp_ns
+                     - lines["XLA Modules"].timestamp_ns) * 1000
+        for ev, self_ps in _self_ps(lines["XLA Ops"]):
+            m = _PROGRAM.match(ev.op_name)
+            if m:
+                program = m.group(1)
+            else:
+                at = ev.offset_ps + shift
+                i = bisect.bisect_right(starts, at) - 1
+                program = (modules[i][2]
+                           if i >= 0 and at < modules[i][1] else "")
+            cell = out.setdefault(program, {}).setdefault(
+                scope_of(ev.op_name, vocabulary, below), [0, 0])
+            cell[0] += self_ps
+            cell[1] += 1
+    n = max(1, len(devs))
+    return {
+        program: {path: [ps / n / 1e12, count / n]
+                  for path, (ps, count) in paths.items()}
+        for program, paths in out.items()
+    }
 
 
 # Line timestamps below this are clearly not unix-epoch ns (10**15 ns

@@ -308,4 +308,7 @@ def test_on_demand_request_profile(pipe):
     assert result.get("steps") == 3
     assert result.get("traceEvents")
     assert isinstance(result.get("device_time_us"), dict)
+    # The device's time by the program's layer names rides along; the
+    # table is the device's own, so empty off a TPU.
+    assert result["scope_seconds"] == {}
     sched.close()

@@ -124,8 +124,12 @@ def test_op_profile_end_to_end(tmp_path):
         f, x, trace_dir=str(tmp_path), steps=2, top_n=10,
     )
     assert prof.source in ("tpu_xla_ops", "host_fallback")
-    assert prof.top and all(ms >= 0 for _, ms in prof.top)
-    assert all(isinstance(name, str) and name for name, _ in prof.top)
+    assert prof.top and all(ms >= 0 for _, _, ms in prof.top)
+    assert all(isinstance(name, str) and name for name, _, _ in prof.top)
+    # Rows carry the scope path beside the compiler's name ("" for a
+    # host event); the table is the device's, empty off a TPU.
+    assert all(isinstance(path, str) for _, path, _ in prof.top)
+    assert (prof.scopes == {}) == (prof.source == "host_fallback")
     assert prof.xplane_path.endswith(".xplane.pb") and prof.plane_names
 
 
