@@ -17,6 +17,7 @@ to "tp" whenever cfg.mesh.tp > 1.
 from __future__ import annotations
 
 import fnmatch
+import math
 from typing import Any
 
 import jax
@@ -314,6 +315,28 @@ def constrain(x, *axes):
 
     spec = P(*(keep(a) for a in axes))
     return jax.lax.with_sharding_constraint(x, spec)
+
+
+def embed_shard_axes(mode: str | None) -> tuple[tuple[str, ...], int]:
+    """(axes, n): the ambient mesh's axes that shard the `embed` logical
+    axis under `mode`, in `mesh_rules`' order, and their total width.
+    ((), 1) off-mesh, under a mode that leaves `embed` whole (`zero2`,
+    `ddp`, None) and on a mesh that lacks those axes. The same filter
+    `constrain` applies, so it names the layout the compute-dtype
+    weights really have (`cast_params_for_compute`).
+
+    Its one reader is the trainer's loss (train/loss.py): a product
+    that CONTRACTS over `embed`, the vocabulary matrix's, is served by
+    GSPMD with a gather of the matrix at its every use; the loss
+    re-lays that matrix over these axes instead."""
+    mesh = ambient_mesh()
+    if mode is None or mesh is None or mesh.empty:
+        return (), 1
+    axes = tuple(
+        a for a in mesh_rules(mode)["embed"] or ()
+        if a in mesh.axis_names
+    )
+    return axes, math.prod(mesh.shape[a] for a in axes)
 
 
 def opt_state_specs(opt_state, params: Params, mode: str = "fsdp"):

@@ -127,6 +127,7 @@ def microbatch_loss(
         loss, metrics = chunked_causal_lm_loss(
             hidden, w, mb["labels"],
             chunk=cfg.train.loss_chunk, transpose=transpose,
+            sharding_mode=sharding_mode,
         )
     if numerics:
         # Activation absmax (the final hidden state — the residual
@@ -163,8 +164,17 @@ def train_step_fn(
 
     sharding_mode: the parallel/sharding.py mode the params are placed
     under — used to constrain the compute-dtype cast of the params (see
-    microbatch_loss) so weight all-gathers ride bf16. Harmless when it
-    merely mismatches the actual placement off-mesh (constrain no-ops).
+    microbatch_loss) so weight all-gathers ride bf16, and read by the
+    loss (train/loss.chunked_causal_lm_loss): under an ambient mesh whose
+    axes shard the vocabulary matrix's `embed` dimension in this mode
+    ("fsdp", over the fsdp x sp width n), the chunk scan runs under the
+    scope `loss/vocab_parallel` with the matrix re-laid [H, V/n] once a
+    microbatch, instead of GSPMD's gather of the whole matrix twice a
+    chunk; `zero2` / `ddp` (the matrix is whole on every device), a
+    vocabulary or batch the mesh does not divide, and T <= loss_chunk
+    run the one-device loss under plain `loss`. Harmless when it merely
+    mismatches the actual placement off-mesh (constrain no-ops, the
+    loss sees no mesh).
 
     tx: optimizer.make_optimizer(cfg.train, ...)'s — its freeze mask is
     the one this step differentiates by, so it takes a gradient tree that

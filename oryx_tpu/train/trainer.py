@@ -20,6 +20,7 @@ from oryx_tpu.config import OryxConfig
 from oryx_tpu.models import oryx
 from oryx_tpu.parallel import mesh as mesh_lib
 from oryx_tpu.parallel import sharding
+from oryx_tpu.train import loss as loss_lib
 from oryx_tpu.train import step as step_lib
 from oryx_tpu.train import telemetry as telemetry_lib
 from oryx_tpu.train.optimizer import (
@@ -238,6 +239,15 @@ class Trainer:
                 static_argnames=("cfg", "tx", "sharding_mode", "numerics"),
                 donate_argnames=("state",),
                 out_shardings=(state_shardings, None),
+            )
+            axes, n = loss_lib.vocab_parallel_axes(
+                sharding_mode, cfg.llm.vocab_size
+            )
+            rank0_print(
+                f"trainer: mesh {dict(self.mesh.shape)}, params "
+                f"{sharding_mode}; loss: vocabulary matrix "
+                + (f"split by vocabulary over {'x'.join(axes)} (n={n})"
+                   if axes else "whole at every chunk (n=1)")
             )
 
     def close(self) -> None:
