@@ -27,7 +27,8 @@ def unsupported_for_recurrent(mode: str) -> str:
     convolution's two rows); a Mamba layer's [d_state, d_inner] state
     has none."""
     return (
-        f"state-space layers (attn_layer_period > 0 or layer_types): "
+        f"state-space layers (attn_layer_period > 0, layer_types or "
+        f"hybrid_override_pattern): "
         f"{mode} is not built "
         "for a recurrent state beside the paged pool (one conv window and, "
         "a Mamba layer, one [d_state, d_inner] state a slot a layer, "
@@ -190,6 +191,21 @@ class LLMConfig:
     mamba_dt_rank: int = 0
     mamba_conv_bias: bool = True
     mamba_proj_bias: bool = False
+    # Layer kinds by position from the source's PATTERN string (the
+    # Nemotron-H lineage's `hybrid_override_pattern`), one character a
+    # layer, each layer ONE sublayer: `M` a Mamba-2 mixer alone
+    # (`models/mamba2.py`: mamba_num_heads heads of mamba_head_dim
+    # channels, ONE decay a head, B and C shared by the heads of each of
+    # mamba_n_groups groups, a [mamba_d_state] state a channel, the
+    # prefill in its chunked matmul form at mamba_chunk_size), `*`
+    # attention alone, `E` the expert layer alone (`layer_kinds` "none",
+    # `ffn_kinds` "moe"; every other layer's FFN kind is "none"). The
+    # first num_layers characters are the model's.
+    hybrid_override_pattern: str = ""
+    mamba_num_heads: int = 0
+    mamba_head_dim: int = 0
+    mamba_n_groups: int = 1
+    mamba_chunk_size: int = 128
     # False: attention without rotary (or any other) position term; the
     # state-space layers carry the order.
     use_rope: bool = True
@@ -206,8 +222,21 @@ class LLMConfig:
     global_layer_period: int = 0
     global_layer_offset: int = 0
     rope_window_only: bool = False
-    # The experts' gate activation: "silu" (SwiGLU) or "relu" (ReGLU).
+    # The experts' gate activation: "silu" (SwiGLU) or "relu" (ReGLU);
+    # "relu2": NOT gated, an expert is two matrices, relu(x W1)^2 W2
+    # (the Nemotron-H lineage's `mlp_hidden_act`), and so is the shared
+    # expert.
     moe_activation: str = "silu"
+    # Latent experts when > 0: the routed experts read and write a
+    # moe_latent_size-wide latent, x W_dn on the way in (once a token)
+    # and W_up on the way out, applied ONCE to the weighted sum of a
+    # token's experts; the router and the shared expert read the hidden
+    # state. An expert's kernels are then [moe_latent_size,
+    # moe_intermediate_size] and back.
+    moe_latent_size: int = 0
+    # The shared expert's own width where the source gives one (else
+    # n_shared_experts * moe_intermediate_size).
+    moe_shared_expert_intermediate_size: int = 0
     # What the router reads: "post_attn" (the normed state after
     # attention, the expert layer's own input) or "layer_input" (the
     # residual stream at the layer's input, before its norm).
@@ -246,7 +275,10 @@ class LLMConfig:
 
     @property
     def moe_layers(self) -> int:
-        """Layers with an expert layer: all but the leading dense ones."""
+        """Layers with an expert layer: all but the leading dense ones,
+        or the pattern's `E` layers."""
+        if self.hybrid_override_pattern:
+            return self.ffn_kinds.count("moe")
         return self.num_layers - self.dense_layers
 
     @property
@@ -270,10 +302,15 @@ class LLMConfig:
 
     @property
     def layer_kinds(self) -> tuple[str, ...]:
-        """THE table of layer kinds, one entry a layer: "attn", "mamba"
-        or "conv". From `layer_types` where the source lists them, from
-        the period and offset where it gives those, else every layer
-        attends."""
+        """THE table of layer kinds, one entry a layer: "attn", "mamba",
+        "conv", "mamba2", or "none" (no mixer here: the layer is its FFN
+        alone). From `hybrid_override_pattern` or `layer_types` where
+        the source lists them, from the period and offset where it
+        gives those, else every layer attends."""
+        if self.hybrid_override_pattern:
+            return tuple(
+                {"M": "mamba2", "*": "attn", "E": "none"}[c]
+                for c in self.hybrid_override_pattern[:self.num_layers])
         if self.layer_types:
             return tuple(
                 "attn" if t == "full_attention" else t
@@ -289,7 +326,12 @@ class LLMConfig:
         """The FFN kind of each layer of a config with state layers:
         "own" (no experts anywhere: the dense SwiGLU's kernels lie in
         the layer's own stack), else "dense" for the leading
-        dense_layers and "moe" behind them."""
+        dense_layers and "moe" behind them. Under a pattern a layer is
+        ONE sublayer: "moe" at an `E`, "none" (no FFN here) at a mixer."""
+        if self.hybrid_override_pattern:
+            return tuple(
+                "moe" if c == "E" else "none"
+                for c in self.hybrid_override_pattern[:self.num_layers])
         if not self.num_experts:
             return ("own",) * self.num_layers
         return tuple("dense" if i < self.dense_layers else "moe"
@@ -298,8 +340,9 @@ class LLMConfig:
     @property
     def state_kind(self) -> str | None:
         """The kind of the layers that keep a per-slot state: "mamba",
-        "conv", or None where every layer attends."""
-        kinds = set(self.layer_kinds) - {"attn"}
+        "conv", "mamba2", or None where every layer attends. ONE kind a
+        model."""
+        kinds = set(self.layer_kinds) - {"attn", "none"}
         return next(iter(kinds)) if kinds else None
 
     @property
@@ -309,7 +352,19 @@ class LLMConfig:
 
     @property
     def mamba_d_inner(self) -> int:
+        if self.mamba_num_heads:  # Mamba-2: heads x head size
+            return self.mamba_num_heads * self.mamba_head_dim
         return self.mamba_expand * self.hidden_size
+
+    @property
+    def mamba2_conv_dim(self) -> int:
+        """Channels a Mamba-2 mixer's conv runs over: x | B | C."""
+        return self.mamba_d_inner + 2 * self.mamba_n_groups * self.mamba_d_state
+
+    @property
+    def shared_expert_width(self) -> int:
+        return (self.moe_shared_expert_intermediate_size
+                or self.n_shared_experts * self.moe_intermediate_size)
 
     @property
     def num_attn_layers(self) -> int:
@@ -317,8 +372,9 @@ class LLMConfig:
 
     @property
     def num_state_layers(self) -> int:
-        """Layers that keep a per-slot state (of either kind) and no K/V."""
-        return self.num_layers - self.num_attn_layers
+        """Layers that keep a per-slot state (of whichever kind) and no
+        K/V."""
+        return self.layer_kinds.count(self.state_kind)
 
     @property
     def kv_pack(self) -> int:
@@ -342,14 +398,17 @@ class LLMConfig:
         layer: the last taps - 1 conv inputs, flat."""
         if self.state_kind == "conv":
             return (self.conv_L_cache - 1) * self.hidden_size
+        if self.state_kind == "mamba2":
+            return (self.mamba_d_conv - 1) * self.mamba2_conv_dim
         return (self.mamba_d_conv - 1) * self.mamba_d_inner
 
     def state_bytes_per_slot(self, dtype_bytes: int = 2) -> int:
         """Bytes of recurrent state ONE slot holds: the conv window
         (`conv_state_width`) in the compute dtype a state layer, and for
-        a Mamba layer a float32 [d_state, d_inner] state beside it."""
+        a Mamba layer (of either generation) a float32 [d_state,
+        d_inner] state beside it."""
         per = dtype_bytes * self.conv_state_width
-        if self.state_kind == "mamba":
+        if self.state_kind in ("mamba", "mamba2"):
             per += 4 * self.mamba_d_state * self.mamba_d_inner
         return self.num_state_layers * per
 
@@ -505,13 +564,15 @@ class LLMConfig:
                 "attention only (kv_lora_rank > 0)"
             )
         if self.n_shared_experts < 0 or (
-            self.n_shared_experts and (
-                not self.latent or self.shortcut_double_layer)
+            self.n_shared_experts and not (
+                self.latent and not self.shortcut_double_layer
+                or self.hybrid_override_pattern and self.num_experts)
         ):
             raise ValueError(
                 "n_shared_experts is built for the single latent block "
                 "(kv_lora_rank > 0, num_experts > 0, no "
-                "shortcut_double_layer), got "
+                "shortcut_double_layer) and for the expert layers of a "
+                "hybrid_override_pattern, got "
                 f"n_shared_experts={self.n_shared_experts}"
             )
         if (self.yarn or self.llama4_scaling_beta) and not (
@@ -532,6 +593,34 @@ class LLMConfig:
                     "least num_layers layers, without attn_layer_period, "
                     f"and conv_L_cache > 1, got {self.layer_types}"
                 )
+        if self.hybrid_override_pattern:
+            pat = self.hybrid_override_pattern[:self.num_layers]
+            heads, G = self.mamba_num_heads, self.mamba_n_groups
+            if (self.attn_layer_period or self.layer_types
+                    or len(pat) < self.num_layers or not set(pat) <= set("M*E")
+                    or "M" not in pat or ("E" in pat) != bool(self.num_experts)
+                    or self.dense_layers
+                    or not (heads > 0 and self.mamba_head_dim > 0
+                            and G > 0 and heads % G == 0
+                            and self.mamba_d_state > 0
+                            and self.mamba_d_conv > 1
+                            and self.mamba_chunk_size > 0)):
+                raise ValueError(
+                    "hybrid_override_pattern gives 'M', '*' or 'E' for at "
+                    "least num_layers layers (an 'M' among them, an 'E' iff "
+                    "num_experts > 0), without attn_layer_period, "
+                    "layer_types or dense_layers, and needs "
+                    "mamba_num_heads a multiple of mamba_n_groups, "
+                    "mamba_head_dim, mamba_d_state, mamba_chunk_size > 0 "
+                    f"and mamba_d_conv > 1, got {self}"
+                )
+        elif self.mamba_num_heads or self.moe_latent_size or (
+                self.moe_activation == "relu2"):
+            raise ValueError(
+                "mamba_num_heads (a Mamba-2 mixer), moe_latent_size and "
+                "moe_activation 'relu2' are built for a layer table from "
+                "hybrid_override_pattern"
+            )
         if self.attn_layer_period:
             P = self.attn_layer_period
             if not (0 <= self.attn_layer_offset < P
@@ -550,18 +639,17 @@ class LLMConfig:
                  "(block_length > 0: the block step program)"),
                 (self.latent, "latent attention (kv_lora_rank > 0)"),
                 (self.attention_bias, "attention bias"),
-                (self.experts_held is not None or self.zero_experts
-                 or self.router_input != "post_attn"
-                 or self.moe_activation != "silu",
-                 "a share of the experts, zero-compute experts, a router "
-                 "on the layer's input or ReGLU experts"),
+                (self.zero_experts or self.router_input != "post_attn"
+                 or self.moe_activation == "relu",
+                 "zero-compute experts, a router on the layer's input or "
+                 "ReGLU experts"),
             ):
                 if bad:
                     raise ValueError(unsupported_for_recurrent(mode))
-        if self.moe_activation not in ("silu", "relu") or (
+        if self.moe_activation not in ("silu", "relu", "relu2") or (
                 self.router_input not in ("post_attn", "layer_input")):
             raise ValueError(
-                "moe_activation is 'silu' or 'relu' and router_input "
+                "moe_activation is 'silu', 'relu' or 'relu2' and router_input "
                 f"'post_attn' or 'layer_input', got {self.moe_activation!r}"
                 f", {self.router_input!r}"
             )
@@ -1527,6 +1615,133 @@ def lfm2_tiny() -> OryxConfig:
             router_scoring="sigmoid",
             router_bias=True,
             dense_layers=2,
+        ),
+        vision=None,
+        generation=GenerationConfig(eos_token_id=512),
+        dtype="float32",
+    )
+
+
+# NVIDIA-Nemotron-3-Super-120B-A12B's published `hybrid_override_pattern`
+# (88 characters: 40 `M`, 40 `E`, 8 `*`).
+_NEMOTRON3_SUPER_PATTERN = (
+    "MEMEMEM*EMEMEMEM*EMEMEMEM*EMEMEMEMEM*EMEMEMEMEM*EMEMEMEMEM*"
+    "EMEMEMEMEM*EMEMEMEM*EMEMEMEME")
+
+
+def nemotron3_super() -> OryxConfig:
+    """NVIDIA-Nemotron-3-Super-120B-A12B-BF16 (nvidia, config.json,
+    `model_type: nemotron_h`): 88 layers of ONE sublayer each by the
+    published pattern, 40 Mamba-2 mixers (128 heads of 64, 8 groups,
+    state 128, conv 4, chunk 128), 8 GQA attention layers (32 query
+    heads of 128 over 2 key/value heads, no position term) and 40
+    latent expert layers (512 experts of 2,688 in a 1,024-wide latent,
+    22 a token behind a sigmoid router with a selection bias, weights
+    over their sum times 5, non-gated relu^2, one shared expert of
+    5,376 on the hidden state). Untied head of 131,072. Text-only.
+    120.67 B parameters; the multi-token-prediction module is not
+    built. What the keys do not settle (no position term, the router on
+    the hidden state) is under `assumed` in the benchmark's
+    configuration file."""
+    return OryxConfig(
+        llm=LLMConfig(
+            vocab_size=131072,
+            hidden_size=4096,
+            intermediate_size=2688,
+            num_layers=88,
+            num_heads=32,
+            num_kv_heads=2,
+            head_dim=128,
+            rope_theta=10000.0,
+            rms_norm_eps=1e-5,
+            max_position_embeddings=262144,
+            tie_word_embeddings=False,
+            attention_bias=False,
+            hybrid_override_pattern=_NEMOTRON3_SUPER_PATTERN,
+            mamba_num_heads=128,
+            mamba_head_dim=64,
+            mamba_n_groups=8,
+            mamba_d_state=128,
+            mamba_d_conv=4,
+            mamba_chunk_size=128,
+            mamba_conv_bias=True,
+            use_rope=False,
+            num_experts=512,
+            num_experts_per_tok=22,
+            moe_intermediate_size=2688,
+            moe_latent_size=1024,
+            moe_shared_expert_intermediate_size=5376,
+            n_shared_experts=1,
+            moe_activation="relu2",
+            norm_topk_prob=True,
+            router_scoring="sigmoid",
+            router_bias=True,
+            routed_scaling_factor=5.0,
+        ),
+        vision=None,
+        # Past the vocabulary: seeded weights would sample a real id
+        # once in 131,072 tokens and end a request the traffic sized.
+        generation=GenerationConfig(eos_token_id=131072),
+    )
+
+
+def nemotron3_super_ep4() -> OryxConfig:
+    """`nemotron3_super` as ONE of the 4 chips that share each layer of
+    a pipeline stage holds it: experts 0..127 of the 512 (the router
+    keeps its 512 outputs and its 22 a token; what the 384 absent
+    experts would add is left out before `W_up`), the mixers, the
+    attention, the latent projections and the shared expert whole, and
+    32,768 of the 131,072 vocabulary rows. The benchmark's depth cut is
+    `dataclasses.replace(llm, num_layers=11)`: the first stage of
+    eight, `MEMEMEM*EME`."""
+    cfg = nemotron3_super()
+    return dataclasses.replace(
+        cfg,
+        llm=dataclasses.replace(
+            cfg.llm, vocab_size=32768, experts_held=(0, 128)),
+        generation=GenerationConfig(eos_token_id=32768),
+    )
+
+
+def nemotron3_tiny() -> OryxConfig:
+    """Tiny Nemotron-H for tests: the published pattern's first 11
+    layers (`MEMEMEM*EME`) at width 64: 4 Mamba-2 heads of 32 in 2
+    groups, state 16, chunk 8; 8 latent experts of width 48 in a
+    32-wide latent, 3 a token, experts 2..5 held; a shared expert of
+    96; 2 key/value heads of 16."""
+    return OryxConfig(
+        llm=LLMConfig(
+            vocab_size=512,
+            hidden_size=64,
+            intermediate_size=48,
+            num_layers=11,
+            num_heads=4,
+            num_kv_heads=2,
+            head_dim=16,
+            rms_norm_eps=1e-5,
+            max_position_embeddings=2048,
+            tie_word_embeddings=False,
+            attention_bias=False,
+            hybrid_override_pattern=_NEMOTRON3_SUPER_PATTERN,
+            mamba_num_heads=4,
+            mamba_head_dim=32,
+            mamba_n_groups=2,
+            mamba_d_state=16,
+            mamba_d_conv=4,
+            mamba_chunk_size=8,
+            use_rope=False,
+            num_experts=8,
+            num_experts_per_tok=3,
+            moe_intermediate_size=48,
+            moe_latent_size=32,
+            moe_shared_expert_intermediate_size=96,
+            n_shared_experts=1,
+            moe_activation="relu2",
+            norm_topk_prob=True,
+            router_scoring="sigmoid",
+            router_bias=True,
+            routed_scaling_factor=5.0,
+            experts_held=(2, 4),
         ),
         vision=None,
         generation=GenerationConfig(eos_token_id=512),

@@ -123,13 +123,17 @@ def _init_recurrent_params(cfg: LLMConfig, key: jax.Array, dtype) -> Params:
     [moe layers, ...] the expert layers', each indexed by the layer's
     number within its FFN kind. The head is the embedding, transposed
     (tied), unless the config unties it."""
-    from oryx_tpu.models import mamba, short_conv
+    from oryx_tpu.models import mamba, mamba2, short_conv
 
     H, I = cfg.hidden_size, cfg.intermediate_size
     Dq = cfg.num_heads * cfg.head_dim
     Dkv = cfg.num_kv_heads * cfg.head_dim
     keys = iter(jax.random.split(key, 16))
     own = not cfg.num_experts
+    # One sublayer a layer (`cfg.hybrid_override_pattern`): one norm a
+    # layer, a mixer's in its own stack, an expert layer's under
+    # `layers["ffn_norm"]`.
+    single = bool(cfg.hybrid_override_pattern)
 
     def dense(shape, dt=dtype, scale=0.02):
         return (
@@ -144,6 +148,8 @@ def _init_recurrent_params(cfg: LLMConfig, key: jax.Array, dtype) -> Params:
         }
 
     def common(L):
+        if single:
+            return {"input_norm": {"weight": jnp.ones((L, H), dtype)}}
         return dict({
             "input_norm": {"weight": jnp.ones((L, H), dtype)},
             "post_attn_norm": {"weight": jnp.ones((L, H), dtype)},
@@ -151,7 +157,7 @@ def _init_recurrent_params(cfg: LLMConfig, key: jax.Array, dtype) -> Params:
 
     La, Ls = cfg.num_attn_layers, cfg.num_state_layers
     kind = cfg.state_kind
-    mixers = mamba if kind == "mamba" else short_conv
+    mixers = {"mamba": mamba, "mamba2": mamba2, "conv": short_conv}[kind]
     params: Params = {
         "embed": {"weight": dense((cfg.vocab_size, H))},
         "layers": {
@@ -200,11 +206,29 @@ def _init_recurrent_params(cfg: LLMConfig, key: jax.Array, dtype) -> Params:
             layers["router"]["bias"] = dense(
                 (Lm, E), jnp.float32,
                 scale=0.05 if cfg.router_scoring == "sigmoid" else 0.2 / E)
-        layers["experts"] = {
-            "gate": dense((Lm, E, H, Ie)),
-            "up": dense((Lm, E, H, Ie)),
-            "down": dense((Lm, E, Ie, H)),
-        }
+        # The experts this chip holds (`cfg.experts_held`), in a latent
+        # of their own where the config has one.
+        count, Hl = cfg.held[1], cfg.moe_latent_size or H
+        gated = cfg.moe_activation != "relu2"
+        layers["experts"] = dict(
+            {"gate": dense((Lm, count, Hl, Ie))} if gated else {},
+            up=dense((Lm, count, Hl, Ie)),
+            down=dense((Lm, count, Ie, Hl)),
+        )
+        if cfg.moe_latent_size:
+            layers["latent"] = {
+                "down": {"kernel": dense((Lm, H, Hl))},
+                "up": {"kernel": dense((Lm, Hl, H))},
+            }
+        if cfg.n_shared_experts:
+            Is = cfg.shared_expert_width
+            layers["shared"] = dict(
+                {"gate_proj": {"kernel": dense((Lm, H, Is))}} if gated else {},
+                up_proj={"kernel": dense((Lm, H, Is))},
+                down_proj={"kernel": dense((Lm, Is, H))},
+            )
+        if single:
+            layers["ffn_norm"] = {"weight": jnp.ones((Lm, H), dtype)}
     return params
 
 
@@ -422,7 +446,8 @@ def init_paged_kv_cache(
             "k": jnp.zeros(shape, dtype), "v": jnp.zeros(shape, dtype),
             CONV: jnp.zeros((Ls, num_slots, cfg.conv_state_width), dtype),
         }
-        if cfg.state_kind == "mamba":
+        if cfg.state_kind in ("mamba", "mamba2"):
+            # (Mamba-2: head h's [N, P] state the P lanes from h P.)
             pool[SSM] = jnp.zeros(
                 (Ls, num_slots, cfg.mamba_d_state, cfg.mamba_d_inner),
                 jnp.float32)
@@ -662,7 +687,8 @@ def _moe(cfg: LLMConfig, x: jnp.ndarray, router_kernel: jnp.ndarray,
          experts: Params, layer: jnp.ndarray, impl: str = "xla",
          router_bias: jnp.ndarray | None = None,
          shared: Params | None = None,
-         logits: jnp.ndarray | None = None):
+         logits: jnp.ndarray | None = None,
+         latent: Params | None = None):
     """Sparse expert MLP on x [N, H]: dropless, no capacity factor, no
     padding to a capacity. The N*K (token, expert) pairs are sorted by
     expert and the gate, up and down products run as grouped products
@@ -698,17 +724,46 @@ def _moe(cfg: LLMConfig, x: jnp.ndarray, router_kernel: jnp.ndarray,
     `logits` [N, E]: the router's logits where they were taken
     elsewhere (cfg.router_input "layer_input": `_block` reads them off
     the layer's raw input); None: the router reads x. The experts' gate
-    activation is cfg.moe_activation.
+    activation is cfg.moe_activation; under "relu2" an expert is NOT
+    gated, two grouped products (`up`, `down`) with relu(.)^2 between.
+
+    `latent` (cfg.moe_latent_size): one layer's `down` [H, latent] and
+    `up` [latent, H]; the experts' kernels are then latent-wide.
 
     Returns (y [N, H], routing: {"counts": rows of each held expert
     [count] int32, "ids": the chosen experts [N, K] int32})."""
+    xl = x
+    if latent is not None:
+        # Latent experts (cfg.moe_latent_size): the routed experts read
+        # x W_dn, and W_up is applied ONCE, to the weighted sum of a
+        # token's held experts (what an absent expert would add is left
+        # out before it). The router and the shared expert read x.
+        with jax.named_scope("moe_latent"):
+            xl = x @ latent["down"]["kernel"].astype(x.dtype)
     with jax.named_scope("moe_routed"):
+        if latent is not None and logits is None:
+            logits = router_logits(x, router_kernel)
         y, idx, counts = _moe_routed(
-            cfg, x, router_kernel, experts, layer, impl, router_bias, logits)
+            cfg, xl, router_kernel, experts, layer, impl, router_bias, logits)
+    if latent is not None:
+        with jax.named_scope("moe_latent"):
+            y = jnp.matmul(
+                y.astype(x.dtype), latent["up"]["kernel"].astype(x.dtype),
+                preferred_element_type=jnp.float32)
     if shared is not None:
         with jax.named_scope("moe_shared"):
-            y = y + _swiglu(x, shared).astype(jnp.float32)
+            y = y + _expert_mlp(cfg, x, shared).astype(jnp.float32)
     return y.astype(x.dtype), {"counts": counts, "ids": idx}
+
+
+def _expert_mlp(cfg: LLMConfig, x, p: Params):
+    """The shared expert: `_swiglu`, or under cfg.moe_activation "relu2"
+    the two-matrix relu(x V1)^2 V2."""
+    if cfg.moe_activation != "relu2":
+        return _swiglu(x, p)
+    up = x @ p["up_proj"]["kernel"].astype(x.dtype)
+    return jnp.square(jax.nn.relu(up)) @ p["down_proj"]["kernel"].astype(
+        x.dtype)
 
 
 def _moe_routed(cfg: LLMConfig, x, router_kernel, experts, layer, impl,
@@ -722,7 +777,6 @@ def _moe_routed(cfg: LLMConfig, x, router_kernel, experts, layer, impl,
         w, idx = moe_route(cfg, x, router_kernel, router_bias)
     else:
         w, idx = moe_select(cfg, logits, router_bias)
-    act = jax.nn.relu if cfg.moe_activation == "relu" else jax.nn.silu
     flat = idx.reshape(N * K)
     if not whole:
         # Group id of a pair: its held expert's place, or `count`, a
@@ -732,15 +786,20 @@ def _moe_routed(cfg: LLMConfig, x, router_kernel, experts, layer, impl,
     order = jnp.argsort(flat)  # stable: pairs of one expert stay in row order
     counts = jnp.bincount(flat, length=count).astype(jnp.int32)
     groups = jax.lax.dynamic_update_slice(
-        jnp.zeros((experts["gate"].shape[0],), jnp.int32), counts,
+        jnp.zeros((experts["up"].shape[0],), jnp.int32), counts,
         (layer * count,),
     )
     xs = x[order // K]
-    gate = _grouped_dot(xs, experts["gate"].astype(x.dtype), groups, impl)
-    up = _grouped_dot(xs, experts["up"].astype(x.dtype), groups, impl)
-    ys = _grouped_dot(
-        act(gate) * up, experts["down"].astype(x.dtype), groups, impl
-    )
+    if cfg.moe_activation == "relu2":
+        mid = jnp.square(jax.nn.relu(_grouped_dot(
+            xs, experts["up"].astype(x.dtype), groups, impl)))
+    else:
+        act = jax.nn.relu if cfg.moe_activation == "relu" else jax.nn.silu
+        gate = _grouped_dot(
+            xs, experts["gate"].astype(x.dtype), groups, impl)
+        up = _grouped_dot(xs, experts["up"].astype(x.dtype), groups, impl)
+        mid = act(gate) * up
+    ys = _grouped_dot(mid, experts["down"].astype(x.dtype), groups, impl)
     # Unsort: pair p = token * K + slot sits at sorted row inv[p].
     inv = jnp.zeros((N * K,), jnp.int32).at[order].set(
         jnp.arange(N * K, dtype=jnp.int32)
@@ -839,6 +898,10 @@ def _block(
         # so the backward recomputes neither the attention nor o_proj.
         h = h + checkpoint_name(_linear(attn_out, lp["o_proj"]), "attn_o")
 
+    if "post_attn_norm" not in lp:
+        # One sublayer a layer (cfg.hybrid_override_pattern): attention
+        # alone, no FFN behind it.
+        return h, cache_k, cache_v
     if experts is not None:
         with jax.named_scope("moe"):
             x = rms_norm(h, lp["post_attn_norm"]["weight"], cfg.rms_norm_eps)
@@ -1466,11 +1529,13 @@ def _hybrid_layers(cfg: LLMConfig, layers: Params, h, *, block, kv_cache,
     its xs. See forward's `state_slots` for whose state a row starts
     from and leaves. Returns (h, the pool or None, the expert layers'
     routing [moe layers, ...] in layer order or None)."""
-    from oryx_tpu.models import mamba, short_conv
+    from oryx_tpu.models import mamba, mamba2, short_conv
     from oryx_tpu.ops import paged_kv
+    from oryx_tpu.ops.pallas import ssd_step
 
     kind = cfg.state_kind
-    is_mamba = kind == "mamba"
+    is_ssd = kind == "mamba2"
+    is_mamba = kind in ("mamba", "mamba2")  # an `ssm` plane beside `conv`
     lead, period_kinds, reps, tail = cfg.layer_plan()
     B, T, _ = h.shape
     K1 = (cfg.mamba_d_conv if is_mamba else cfg.conv_L_cache) - 1
@@ -1499,9 +1564,15 @@ def _hybrid_layers(cfg: LLMConfig, layers: Params, h, *, block, kv_cache,
     # and writes them back around the mixer.
     decode = paged and state_slots is None and T == 1
     inplace = (is_mamba and decode and attn_impl == "pallas"
-               and mamba.step_fits(cfg, B))
+               and (mamba2 if is_ssd else mamba).step_fits(cfg, B))
+    # A Mamba-2 prefill row's state passes through copies of its own
+    # (`ssd_step.read_rows`): as a gather and a scatter XLA re-laid the
+    # whole plane out around every chunk.
+    rows_io = (is_ssd and paged and state_slots is not None
+               and attn_impl == "pallas"
+               and mamba2.step_fits(cfg, kv_cache[SSM].shape[1]))
     with jax.named_scope("mixer"):
-        if inplace:
+        if inplace and not is_ssd:
             step_inv = mamba.step_invariants(
                 layers["mamba"]["mixer"], valid[:, 0], h.dtype)
         edges = None
@@ -1532,7 +1603,10 @@ def _hybrid_layers(cfg: LLMConfig, layers: Params, h, *, block, kv_cache,
                 tree)
 
     def ffn(which: str, h, lp, fi):
-        """h + the layer's FFN on its normed state -> (h, routing)."""
+        """h + the layer's FFN on its normed state -> (h, routing); h as
+        it is where the layer has none ("none": one sublayer a layer)."""
+        if which == "none":
+            return h, None
         with jax.named_scope("moe" if which == "moe" else "ffn"):
             x = rms_norm(
                 h, lp["post_attn_norm"]["weight"], cfg.rms_norm_eps)
@@ -1541,10 +1615,20 @@ def _hybrid_layers(cfg: LLMConfig, layers: Params, h, *, block, kv_cache,
             if which == "dense":
                 return h + _swiglu(x, at(layers["dense"], fi)), None
             router = at(layers["router"], fi)
+            more = {n: at(layers[n], fi) for n in ("shared", "latent")
+                    if n in layers}
             y, routing = _moe(
                 cfg, x.reshape(B * T, -1), router["kernel"], experts_flat,
-                fi, impl=attn_impl, router_bias=router.get("bias"))
+                fi, impl=attn_impl, router_bias=router.get("bias"), **more)
             return h + y.reshape(B, T, -1), routing
+
+    def ffn_layer(carry, fi):
+        """A layer that is its expert layer alone: no stack of its own,
+        its norm lies with the expert layers'."""
+        h, pl = carry
+        h, routing = ffn(
+            "moe", h, {"post_attn_norm": at(layers["ffn_norm"], fi)}, fi)
+        return (h, pl), routing
 
     def state_layer(which: str, carry, idx):
         h, pl = carry
@@ -1554,22 +1638,35 @@ def _hybrid_layers(cfg: LLMConfig, layers: Params, h, *, block, kv_cache,
         with jax.named_scope("mixer"):
             if not paged:
                 conv0 = jnp.zeros((B, K1, d), h.dtype)
-                h0 = jnp.zeros((B, N, d), jnp.float32)
+                h0 = jnp.zeros((B, N, cfg.mamba_d_inner), jnp.float32)
             elif not inplace:  # (the kernels index the planes by li)
                 conv0 = at(conv_pl, li)  # [S, ...]
-                h0 = at(ssm_pl, li) if is_mamba else None
+                h0 = at(ssm_pl, li) if is_mamba and not rows_io else None
                 if state_slots is None:
                     conv0 = conv0.reshape(B, K1, d)
-                elif is_mamba:
+                elif is_mamba and not rows_io:
                     conv0, h0 = mamba.rows_state(
                         conv0, h0, state_slots, fresh, (B, K1, d))
                 else:
                     conv0 = jnp.where(
                         fresh, 0, conv0[state_slots].reshape(B, K1, d))
+                    if rows_io:
+                        h0 = jnp.where(fresh, 0, ssd_step.read_rows(
+                            ssm_pl, li, state_slots))
             u = rms_norm(h, lp["input_norm"]["weight"], cfg.rms_norm_eps)
             h1 = win = None
-            with jax.named_scope("mamba" if is_mamba else "short_conv"):
-                if inplace:
+            with jax.named_scope(kind if is_mamba else "short_conv"):
+                if is_ssd and inplace:
+                    out, (conv_pl, ssm_pl) = mamba2.mixer_step_inplace(
+                        cfg, lp["mixer"], valid[:, 0], li, u,
+                        (conv_pl, ssm_pl))
+                elif is_ssd and decode:
+                    out, (conv1, h1) = mamba2.mixer_step(
+                        cfg, lp["mixer"], u, (conv0, h0), valid[:, 0])
+                elif is_ssd:
+                    out, (conv1, h1) = mamba2.mixer_prefill(
+                        cfg, lp["mixer"], u, (conv0, h0), valid)
+                elif inplace:
                     out, (conv_pl, ssm_pl) = mamba.mixer_step_inplace(
                         cfg, lp["mixer"], step_inv, li, u,
                         (conv_pl, ssm_pl))
@@ -1599,7 +1696,10 @@ def _hybrid_layers(cfg: LLMConfig, layers: Params, h, *, block, kv_cache,
                             ssm_pl, h1, li, 0)
                 else:
                     conv_pl = conv_pl.at[li, state_slots].set(conv1)
-                    if is_mamba:
+                    if rows_io:
+                        ssm_pl = ssd_step.write_rows(
+                            ssm_pl, li, state_slots, h1)
+                    elif is_mamba:
                         ssm_pl = ssm_pl.at[li, state_slots].set(h1)
             if paged:
                 pl = dict(pl, **{CONV: conv_pl},
@@ -1647,7 +1747,11 @@ def _hybrid_layers(cfg: LLMConfig, layers: Params, h, *, block, kv_cache,
         routes, j = [], 0
         while j < len(kinds):
             k, which = kinds[j]
-            if k == "attn":
+            if k == "none":
+                carry, r = ffn_layer(carry, number(which))
+                r = jax.tree_util.tree_map(lambda a: a[None], r)
+                run = 1
+            elif k == "attn":
                 carry, r = attn_layer(
                     which, carry, number("attn"), number(which))
                 r = jax.tree_util.tree_map(lambda a: a[None], r)
@@ -1662,15 +1766,18 @@ def _hybrid_layers(cfg: LLMConfig, layers: Params, h, *, block, kv_cache,
                     (number("state") + ar, number(which) + ar))
             if r is not None:
                 routes.append(r)
-            seen["attn" if k == "attn" else "state"] += run
+            seen[k if k in ("attn", "none") else "state"] += run
             seen[which] += run
             j += run
         return carry, routes
 
     def count(kinds):
-        out = dict.fromkeys(("attn", "state", "own", "dense", "moe"), 0)
+        # ("none" counts the layers without a mixer and those without
+        # an FFN alike; nothing is indexed by it.)
+        out = dict.fromkeys(
+            ("attn", "state", "own", "dense", "moe", "none"), 0)
         for k, which in kinds:
-            out["attn" if k == "attn" else "state"] += 1
+            out[k if k in ("attn", "none") else "state"] += 1
             out[which] += 1
         return out
 

@@ -613,7 +613,8 @@ class ContinuousScheduler:
         # are each carried by the split engine's two programs alone. The
         # prefix cache of a Mamba hybrid or a window model is
         # constructed OFF, with the refusal's words in the log: a hit
-        # hands over pages and no [d_state, d_inner] state, or would
+        # hands over pages and no [d_state, d_inner] state (Mamba-2: 4
+        # MB a layer, 21 MB a snapshot of the benchmark's cut), or would
         # have to hand over the window plane's pages as they stood at
         # the hit's last token. A gated short convolution's state is a
         # snapshot a page (`paged_kv.CONV_EDGE`), which a hit that ends
@@ -621,6 +622,9 @@ class ContinuousScheduler:
         # stays on.
         self.recurrent = bool(llm.recurrent)
         self.conv_state = llm.state_kind == "conv"
+        # A Mamba-2 mixer's prefill chunk (0: no such mixer).
+        self._ssd_chunk = (
+            llm.mamba_chunk_size if llm.state_kind == "mamba2" else 0)
         self.windowed = bool(llm.windowed)
         self.indexed = bool(llm.indexed)
         for on, sentence in (
@@ -915,6 +919,11 @@ class ContinuousScheduler:
                 reg.counter("ssm_decode_lane_steps_total")
                 reg.counter("ssm_state_resets_total")
                 reg.gauge("ssm_state_bytes").set(state_bytes)
+                if self._ssd_chunk:
+                    # Chunks of mamba_chunk_size tokens a prefill
+                    # dispatch's REAL tokens fill, a mixer: what the
+                    # chunked scan's matrix products ran over.
+                    reg.counter("ssd_prefill_chunks_total")
             else:
                 reg.counter("conv_prefill_tokens_total")
                 reg.counter("conv_decode_lane_steps_total")
@@ -3191,6 +3200,10 @@ class ContinuousScheduler:
         self.metrics.inc("prefill_tokens_total", end - off)
         if self.recurrent:
             self.metrics.inc(f"{self._state}_prefill_tokens_total", end - off)
+            if self._ssd_chunk:
+                self.metrics.inc(
+                    "ssd_prefill_chunks_total",
+                    -(-(end - off) // self._ssd_chunk))
             if off == 0:
                 self.metrics.inc(f"{self._state}_state_resets_total")
             if self.conv_state:

@@ -156,7 +156,7 @@ DEVICE_SUBSCOPES = (
     "attn_global", "attn_window", "mla", "dsa_index", "dsa_select",
     "dsa_attend", "dense_ffn", "moe_routed", "moe_shared", "mamba",
     "short_conv", "ssm_scan", "ssm_step", "conv_handover",
-    "vocab_parallel",
+    "vocab_parallel", "mamba2", "ssd_chunk", "ssd_step", "moe_latent",
 )
 
 

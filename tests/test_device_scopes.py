@@ -224,6 +224,7 @@ FAMILIES = {
     "smallthinker_tiny": {"attn", "moe", "stack"},
     "glm5_tiny": {"attn", "ffn", "moe"},
     "lfm2_tiny": {"attn", "ffn", "moe", "mixer", "stack"},
+    "nemotron3_tiny": {"attn", "moe", "mixer", "stack"},
 }
 S, PAGE, PAGES, TABLE = 2, 8, 16, 4
 

@@ -447,7 +447,7 @@ def test_the_selection_bias_changes_which_experts_run_not_their_weights(cut):
 
 @pytest.mark.parametrize("bad", [
     {"block_length": 4, "mask_token_id": 511}, {"attention_bias": True},
-    {"experts_held": (0, 4)}, {"zero_experts": 2},
+    {"moe_activation": "relu"}, {"zero_experts": 2},
 ])
 def test_the_config_refuses_what_is_not_built_for_a_state(bad):
     with pytest.raises(ValueError, match=REFUSAL):

@@ -1051,3 +1051,133 @@ def test_lfm2_serve_programs_keep_pages_state_and_snapshots_in_place(
                  + mem["temporaries_decode_bytes"]
                  + mem["temporaries_prefill_bytes"])
         assert 0.25 * 16e9 < total < 15.75e9 - 0.5e9
+
+
+def test_ssd_step_kernel_compiles_at_the_cells_shapes_in_place(
+    one_chip, mosaic
+):
+    """`_ssd_step` and the two row copies of a prefill at the published
+    widths (96 slots, 5 mixers, a state of 128 x 8,192 float32 a lane a
+    layer), compiled for the described v5e with the plane aliased."""
+    from oryx_tpu.ops.pallas import ssd_step
+
+    B, G, N, d = 96, 8, 128, 8192
+    assert ssd_step.fits(B, d, N, G)
+    sd = lambda shape, dt=jnp.float32: jax.ShapeDtypeStruct(  # noqa: E731
+        shape, dt, sharding=one_chip)
+    plane = sd((5, B, N, d))
+    text = jax.jit(
+        lambda a, dtx, bc, live, pl, li: ssd_step.ssd_step(
+            a, dtx, bc, live, pl, li, G), donate_argnums=(4,),
+    ).lower(sd((B, d)), sd((B, d)), sd((B, 2 * G * N)),
+            sd((B,), jnp.int32), plane, sd((), jnp.int32)).compile().as_text()
+    assert "_ssd_step" in text and "tpu_custom_call" in text
+    compiled = jax.jit(
+        lambda pl, li, slots, rows: ssd_step.write_rows(
+            pl, li, slots, rows + ssd_step.read_rows(pl, li, slots)),
+        donate_argnums=(0,),
+    ).lower(plane, sd((), jnp.int32), sd((1,), jnp.int32),
+            sd((1, N, d))).compile()
+    assert compiled.memory_analysis().temp_size_in_bytes < 4 * N * d * 4
+
+
+@pytest.mark.parametrize("program", ["paged_decode_chunk", "paged_prefill"])
+def test_nemotron_serve_programs_keep_pages_and_state_in_place(
+    one_chip, mosaic, program, capsys
+):
+    """The programs of `nemotron-3-super.agent-reasoning` at the cell's
+    FULL size (the eleven-layer cut, 128 of 512 latent experts, 32,768
+    vocabulary rows; 96 slots x 8,448, the engine's default pool of
+    12,672 pages of 64, chunk 1,024 / 8), compiled for the described
+    v5e: the donated pool (paged K/V of the one attention layer, the
+    per-slot conv rows and the float32 `ssm` plane of the five mixers,
+    2.0 GB) is aliased to the output whole and NOTHING copies the plane
+    (as a gather and a scatter on it, the chunked prefill had XLA lay
+    all of it out anew around every chunk: 2.2 GB of temporaries; the
+    row copies of `ssd_step` keep them at 0.44). The kernels are
+    `_ssd_step` (decode), `_ragged_paged` / `_mha_forward` and `gmm`
+    (1,024 x 2,688 in tiles of 1,024 x 896). THE RULE ISSUE 60 agreed
+    on: 96 slots where arguments + the larger program's temporaries
+    leave 1.0 GB of the 16.91 GB a program may use, else 64. The
+    numbers printed here are the configuration file's `memory` block."""
+    import dataclasses
+    import json
+    import os
+
+    from oryx_tpu import config as cfg_lib
+    from oryx_tpu.models import generate, qwen2
+
+    def on_chip(make):
+        return jax.tree_util.tree_map(
+            lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=one_chip),
+            jax.eval_shape(make),
+        )
+
+    conf = json.load(open(os.path.join(
+        os.path.dirname(os.path.dirname(os.path.abspath(__file__))),
+        "benchmark", "configs", "nemotron-3-super-ep4-serve.json")))
+    lay, mem = conf["layout"], conf["memory"]
+    cfg = dataclasses.replace(
+        cfg_lib.nemotron3_super_ep4().llm, num_layers=lay["num_layers"])
+    slots, page_size, ctx = lay["num_slots"], lay["page_size"], lay["max_ctx"]
+    assert (slots, page_size, ctx) == (96, 64, 8448)
+    pages = slots * ctx // page_size  # the engine's default pool
+    assert pages == mem["num_pages"] == 12672 and "num_pages" not in lay
+    S = slots if program == "paged_decode_chunk" else 1
+    rows = lambda dtype, *tail: jax.ShapeDtypeStruct(  # noqa: E731
+        (S, *tail), dtype, sharding=one_chip
+    )
+    params = on_chip(
+        lambda: qwen2.init_params(cfg, jax.random.key(0), dtype=BF16))
+    kv = on_chip(lambda: qwen2.init_paged_kv_cache(
+        cfg, pages, page_size, dtype=BF16, num_slots=slots))
+    assert kv["k"].shape == (1, 12672, 64, 2, 128)
+    assert kv["conv"].shape == (5, 96, 30720)
+    assert kv["ssm"].shape == (5, 96, 128, 8192)
+    assert kv["ssm"].dtype == jnp.float32
+    tables = rows(jnp.int32, ctx // page_size)
+    sampling = (
+        on_chip(lambda: jax.random.split(jax.random.key(0), S)),
+        rows(jnp.float32), rows(jnp.float32), rows(jnp.int32),
+    )
+    common = dict(attn_impl="pallas", compute_dtype=BF16)
+    if program == "paged_decode_chunk":
+        lowered = generate.paged_decode_chunk.lower(
+            params, cfg, kv, tables, rows(jnp.int32), rows(jnp.int32),
+            rows(jnp.bool_), rows(jnp.int32, 0), *sampling,
+            chunk=lay["decode_chunk"], eos=32768, **common,
+        )
+    else:
+        lowered = generate.paged_prefill.lower(
+            params, cfg, rows(BF16, lay["prefill_chunk"], cfg.hidden_size),
+            rows(jnp.int32), tables, kv, rows(jnp.int32), *sampling,
+            slots=rows(jnp.int32), held_stats=True, **common,
+        )
+    compiled = lowered.compile()
+    text = compiled.as_text()
+    kernels = {"paged_decode_chunk": ("_ssd_step", "_ragged_paged", "gmm"),
+               "paged_prefill": ("_ssd_read_rows", "_ssd_write_rows",
+                                 "_mha_forward", "gmm")}[program]
+    for kernel in kernels:
+        assert kernel in text
+    assert "ragged-dot" not in text  # every grouped product is the kernel
+    memory = compiled.memory_analysis()
+    nbytes = lambda t: sum(  # noqa: E731
+        a.size * a.dtype.itemsize for a in jax.tree_util.tree_leaves(t))
+    pool_bytes, weight_bytes = nbytes(kv), nbytes(params)
+    with capsys.disabled():
+        print(f"\n{program}: weights {weight_bytes} B, pool {pool_bytes} B, "
+              f"arguments {memory.argument_size_in_bytes} B, temporaries "
+              f"{memory.temp_size_in_bytes} B")
+    assert weight_bytes == mem["weights_bytes"] == 9_317_307_904
+    assert pool_bytes == mem["pool_bytes"] == 2_873_229_312
+    assert memory.alias_size_in_bytes == pool_bytes
+    # nothing copies the state plane (2.0 GB)
+    assert memory.temp_size_in_bytes < 0.3 * nbytes(kv["ssm"])
+    key = program.split("_")[1]  # decode / prefill
+    assert memory.argument_size_in_bytes == mem[f"arguments_{key}_bytes"]
+    assert abs(memory.temp_size_in_bytes
+               - mem[f"temporaries_{key}_bytes"]) < 16e6
+    total = mem["arguments_decode_bytes"] + max(
+        mem["temporaries_decode_bytes"], mem["temporaries_prefill_bytes"])
+    assert 0.6 * 16.91e9 < total < 16.91e9 - 1.0e9
